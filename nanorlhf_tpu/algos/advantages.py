@@ -14,6 +14,8 @@ reference inlines inside a 700-line `train()` body (SURVEY.md §2.4):
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -151,11 +153,17 @@ def per_turn_terminal_rewards(
     ].add(turn_rewards.astype(jnp.float32), mode="drop")
 
 
+@partial(jax.jit, static_argnames=("gamma",))
 def discounted_returns(rewards: jnp.ndarray, gamma: float) -> jnp.ndarray:
     """Reversed cumulative sum with discount: A_t = r_t + γ A_{t+1}.
 
     γ=1 is the GRPO token-advantage broadcast (`GRPO/grpo_trainer.py:610-620`);
     γ<1 is REINFORCE (`REINFORCE/reinforce_trainer.py:583-588`).
+
+    Jitted, like `gae`: the trainer calls both from the host once per update,
+    and an eager `lax.scan` over a per-call closure is a new function to jax
+    every time — one backend compile per update, for ever (`perf/recompiles`
+    showed it on the first run that looked).
     """
 
     def step(carry, r_t):
@@ -166,6 +174,7 @@ def discounted_returns(rewards: jnp.ndarray, gamma: float) -> jnp.ndarray:
     return out.T
 
 
+@partial(jax.jit, static_argnames=("gamma", "lam"))
 def gae(
     rewards: jnp.ndarray, values: jnp.ndarray, gamma: float, lam: float
 ) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -172,8 +172,6 @@ def _spmd_call(spmd, fn, args, head_dims):
     all-gathers every operand (see ModelConfig.spmd_mesh)."""
     from jax.sharding import PartitionSpec as P
 
-    from nanorlhf_tpu.utils.shardmap_compat import shard_map
-
     mesh, batch, head = spmd
 
     def spec(x, hdim):
@@ -185,8 +183,8 @@ def _spmd_call(spmd, fn, args, head_dims):
 
     in_specs = tuple(spec(x, h) for x, h in zip(args, head_dims))
     out_specs = spec(args[0], head_dims[0])
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def gqa_attention(

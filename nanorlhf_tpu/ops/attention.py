@@ -34,14 +34,9 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU too; guarded for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 # TPU VREG tile: small per-row operands (mask, lse, delta) are carried

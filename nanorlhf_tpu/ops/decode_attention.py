@@ -30,13 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nanorlhf_tpu.ops.attention import _interpret_default
-
-try:  # pragma: no cover - pltpu import guarded like ops/attention.py
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 NEG_INF = -1e30
 

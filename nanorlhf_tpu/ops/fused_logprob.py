@@ -48,14 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU too; guarded for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from nanorlhf_tpu.ops.masking import (
     entropy_from_logits,
@@ -63,6 +56,7 @@ from nanorlhf_tpu.ops.masking import (
     logprobs_from_logits,
 )
 
+_VMEM = pltpu.VMEM
 NEG_INF = -1e30
 _LANES = 128
 _SUBLANES = 8
@@ -313,11 +307,6 @@ def _pallas_forward(hidden, unembed, labels, temperature,
     `transposed` the weight arrives [V, D] (tied embeddings) and the grid
     reads vocab-ROW blocks — the contraction flips inside the kernel, so no
     [D, V] transposed copy is staged for the custom call."""
-    if pltpu is None:  # scratch_shapes needs pltpu.VMEM — no guarded fallback
-        raise RuntimeError(
-            "fused_logprob impl='pallas' unavailable: "
-            "jax.experimental.pallas.tpu failed to import — use impl='lax'"
-        )
     R, D = hidden.shape
     V = unembed.shape[0] if transposed else unembed.shape[1]
     inv_temp = 1.0 / guard_temperature(temperature)

@@ -37,20 +37,12 @@ def initialize_multihost(
         or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS")
         or os.environ.get("TPU_WORKER_HOSTNAMES", "").count(",") > 0
     )
-    already = getattr(jax.distributed, "is_initialized", lambda: False)()
-    if should_init and not already:
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        except RuntimeError as e:
-            # jax raises "distributed.initialize should only be called once"
-            # on re-entry (wording varies by version) — treat as no-op
-            msg = str(e).lower()
-            if "once" not in msg and "already" not in msg:
-                raise
+    if should_init and not jax.distributed.is_initialized():
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     return {
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),

@@ -25,9 +25,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from nanorlhf_tpu.utils.shardmap_compat import shard_map
 
 from nanorlhf_tpu.core.config import ModelConfig
 from nanorlhf_tpu.core.model import _hidden_from_inputs, _logits, use_flash

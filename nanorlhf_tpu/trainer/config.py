@@ -283,8 +283,10 @@ class RLConfig:
     # None → bytes-budget heuristic (ops/fused_logprob.fused_chunk_rows),
     # which shrinks the chunk as vocabulary grows so peak stays ≈ constant
     fused_logprob_chunk: Optional[int] = None
-    # "auto" → Pallas online-logsumexp kernel on TPU, lax chunk scan
-    # elsewhere; "lax" | "pallas" force one (pallas interprets off-TPU)
+    # "auto" → Pallas online-logsumexp kernel on one TPU device, lax chunk
+    # scan elsewhere and under a multi-device mesh (the kernel has no
+    # shard_map wrap, trainer.fused_logprob_impl); "lax" | "pallas" force
+    # one (pallas interprets off-TPU)
     fused_logprob_impl: str = "auto"
     gradient_checkpointing: bool = True
     attention_impl: str = "auto"  # xla | pallas | auto (by seq length, on TPU)

@@ -129,9 +129,22 @@ def fused_response_logprobs(tree, mcfg, query_responses, responses, pad_id,
     w, w_transposed = unembedding(mcfg, tree)
     return fused_logprob(
         hidden, w, responses, cfg.temperature,
-        chunk=cfg.fused_logprob_chunk, impl=cfg.fused_logprob_impl,
+        chunk=cfg.fused_logprob_chunk, impl=fused_logprob_impl(cfg, mcfg),
         with_entropy=with_entropy, transposed=w_transposed,
     )
+
+
+def fused_logprob_impl(cfg, mcfg) -> str:
+    """What `cfg.fused_logprob_impl` means for this model config. "auto"
+    under a multi-device mesh (`mcfg.spmd_mesh`) is the lax chunk scan,
+    which GSPMD partitions like any XLA code: the Pallas kernel has no
+    shard_map wrap (its weight arrives vocab-sharded, so a wrap needs a
+    cross-shard logsumexp), and the TPU compiler refuses a Mosaic kernel it
+    would have to partition itself. An explicit "pallas" is passed through
+    and fails loudly there."""
+    if cfg.fused_logprob_impl == "auto" and mcfg.spmd_mesh is not None:
+        return "lax"
+    return cfg.fused_logprob_impl
 
 
 def device_peak_bytes() -> float:

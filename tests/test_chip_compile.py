@@ -1,0 +1,216 @@
+"""The chip's compiler on the main path's kernels, and chip_smoke's gate.
+
+Every Pallas kernel `attention_impl="auto"` / `fused_logprob_impl="auto"` can
+select is lowered NON-interpreted and compiled for a described (not attached)
+`v5e:2x2` device at Qwen2.5-1.5B geometry — what interpret mode on the CPU
+cannot show: a block the Mosaic tiling rule refuses, a kernel over its VMEM
+budget. A compile that passes is not a chip run; `chip_smoke.py` is that.
+`ops/attention._interpret_default()` asks the backend, which is the CPU here,
+so the cases steer it with its own `NANORLHF_PALLAS_INTERPRET=0` switch.
+Skipped where the TPU compiler cannot describe the topology. The persistent
+compile cache is off around the compiles: an entry written for a described
+device cannot be read back without one, and the next run would warn.
+"""
+
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# Qwen2.5-1.5B: 12 Q / 2 KV heads of 128, hidden 1536, vocab 151936 (tied)
+H, KV, HD, D, V = 12, 2, 128, 1536, 151936
+PAGE = 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # only the compiler is used, no chip: pytest-xdist workers may each load
+    # libtpu, which otherwise admits one process per machine (its lockfile)
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return list(topo.devices)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """interpret=False through the kernels' own switch; persistent cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("NANORLHF_PALLAS_INTERPRET", "0")
+    saved = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", saved)
+    compilation_cache.reset_cache()
+
+
+def _flash(B=4, T=2048):
+    from nanorlhf_tpu.ops.attention import flash_attention
+
+    args = [((B, H, T, HD), jnp.bfloat16), ((B, KV, T, HD), jnp.bfloat16),
+            ((B, KV, T, HD), jnp.bfloat16), ((B, T), jnp.bool_)]
+    return flash_attention, args
+
+
+def _flash_bwd():
+    fn, args = _flash()
+
+    def grads(q, k, v, valid):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v, valid).astype(jnp.float32) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return grads, args
+
+
+def _decode(B=32, T=2048):
+    from nanorlhf_tpu.ops.decode_attention import decode_attention
+
+    return decode_attention, [
+        ((B, H, HD), jnp.bfloat16), ((B, KV, T, HD), jnp.bfloat16),
+        ((B, KV, T, HD), jnp.bfloat16), ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _decode_q8(B=32, T=2048):
+    from nanorlhf_tpu.ops.decode_attention import decode_attention_q8
+
+    return decode_attention_q8, [
+        ((B, H, HD), jnp.bfloat16),
+        ((B, KV, T, HD), jnp.int8), ((B, KV, 8, T), jnp.bfloat16),
+        ((B, KV, T, HD), jnp.int8), ((B, KV, 8, T), jnp.bfloat16),
+        ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _verify(B=32, T=2048, Tq=4):
+    from nanorlhf_tpu.ops.decode_attention import decode_verify_attention
+
+    return decode_verify_attention, [
+        ((B, H, Tq, HD), jnp.bfloat16), ((B, KV, T, HD), jnp.bfloat16),
+        ((B, KV, T, HD), jnp.bfloat16), ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _paged_decode(B=32, nb=16):
+    from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
+
+    N = B * nb
+    return paged_decode_attention, [
+        ((B, H, HD), jnp.bfloat16), ((N, KV, PAGE, HD), jnp.bfloat16),
+        ((N, KV, PAGE, HD), jnp.bfloat16), ((B, nb), jnp.int32),
+        ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _paged_decode_q8(B=32, nb=16):
+    from nanorlhf_tpu.ops.decode_attention import paged_decode_attention_q8
+
+    N = B * nb
+    return paged_decode_attention_q8, [
+        ((B, H, HD), jnp.bfloat16),
+        ((N, KV, PAGE, HD), jnp.int8), ((N, KV, 8, PAGE), jnp.bfloat16),
+        ((N, KV, PAGE, HD), jnp.int8), ((N, KV, 8, PAGE), jnp.bfloat16),
+        ((B, nb), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _paged_verify(B=32, nb=16, Tq=4):
+    from nanorlhf_tpu.ops.decode_attention import paged_decode_verify_attention
+
+    N = B * nb
+    return paged_decode_verify_attention, [
+        ((B, H, Tq, HD), jnp.bfloat16), ((N, KV, PAGE, HD), jnp.bfloat16),
+        ((N, KV, PAGE, HD), jnp.bfloat16), ((B, nb), jnp.int32),
+        ((B,), jnp.int32), ((B,), jnp.int32)]
+
+
+def _fused(R=2048):
+    """Tied layout: the [V, D] embedding leaf, `transposed=True`."""
+    from nanorlhf_tpu.ops.fused_logprob import fused_logprob
+
+    def fn(h, w, labels):
+        return fused_logprob(h, w, labels, 0.9, impl="pallas",
+                             with_entropy=True, transposed=True)
+
+    return fn, [((R, D), jnp.bfloat16), ((V, D), jnp.bfloat16),
+                ((R,), jnp.int32)]
+
+
+def _fused_bwd():
+    """The op's backward is a lax chunk scan; the Pallas forward it
+    differentiates through stays in the program (its output is the loss)."""
+    fn, args = _fused()
+
+    def grads(h, w, labels):
+        return jax.grad(lambda h, w: (fn(h, w, labels)[0] ** 2).sum(),
+                        argnums=(0, 1))(h, w)
+
+    return grads, args
+
+
+CASES = {"flash_fwd": _flash, "flash_bwd": _flash_bwd, "decode": _decode,
+         "decode_q8": _decode_q8, "verify": _verify,
+         "paged_decode": _paged_decode, "paged_decode_q8": _paged_decode_q8,
+         "paged_verify": _paged_verify, "fused_fwd": _fused,
+         "fused_bwd": _fused_bwd}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_compiles_for_v5e(case, v5e, compiled_kernels):
+    fn, args = CASES[case]()
+    one_chip = SingleDeviceSharding(v5e[0])
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in args]
+    hlo = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_fused_kernel_is_not_partitionable_so_auto_takes_lax_under_a_mesh(
+        v5e, compiled_kernels):
+    """Why trainer.fused_logprob_impl routes "auto" to the lax scan on a
+    multi-device mesh: the TPU compiler refuses a Mosaic kernel it would have
+    to partition. Interpret mode and the CPU backend never show this."""
+    import dataclasses
+
+    from nanorlhf_tpu.core import ModelConfig
+    from nanorlhf_tpu.trainer import RLConfig
+    from nanorlhf_tpu.trainer.trainer import fused_logprob_impl
+
+    mesh = Mesh(np.asarray(v5e).reshape(2, 2), ("fsdp", "tensor"))
+    fn, args = _fused(R=256)
+    shardings = [P("fsdp", None), P("tensor", "fsdp"), P("fsdp")]
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, sp))
+             for (shape, dtype), sp in zip(args, shardings)]
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(fn).lower(*specs)
+
+    cfg, mcfg = RLConfig(), ModelConfig.qwen2_tiny()
+    assert fused_logprob_impl(cfg, mcfg) == "auto"
+    on_mesh = dataclasses.replace(mcfg, spmd_mesh=mesh)
+    assert fused_logprob_impl(cfg, on_mesh) == "lax"
+    cfg.fused_logprob_impl = "pallas"   # an explicit choice is passed through
+    assert fused_logprob_impl(cfg, on_mesh) == "pallas"
+
+
+def test_chip_smoke_refuses_a_cpu_backend():
+    """No accelerator → non-zero exit before any phase, and no result line."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert '"platform": "cpu"' in out.stdout  # it said what it found

@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from nanorlhf_tpu.utils.shardmap_compat import shard_map
+from jax import shard_map
 
 from nanorlhf_tpu.ops.attention import reference_attention
 from nanorlhf_tpu.parallel.ring_attention import ring_attention

@@ -220,8 +220,10 @@ def test_peak_flops_lookup():
     assert known and v5p == 459e12
     trillium, known = peak_flops_per_chip("TPU v6e", "tpu")
     assert known and trillium == 918e12
-    unknown, known = peak_flops_per_chip("TPU v99", "tpu")
-    assert not known and unknown > 0
+    v5e, known = peak_flops_per_chip("TPU v5 lite", "tpu")
+    assert known and v5e == 197e12
+    with pytest.raises(ValueError, match="TPU v99"):
+        peak_flops_per_chip("TPU v99", "tpu")  # never a silent default
     cpu, known = peak_flops_per_chip("cpu", "cpu")
     assert not known and cpu > 0  # finite so the MFU series stays plottable
 
