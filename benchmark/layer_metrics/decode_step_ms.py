@@ -1,0 +1,13 @@
+"""rollout: median `time/rollout_s` over the response length, in ms. The one
+prefill of the padded prompts is inside the rollout's seconds, so this is an
+upper bound of a decode step by about one part in the response length."""
+
+import statistics
+
+
+def read(run):
+    rows = run.get("rows")
+    if not rows:
+        return None
+    steps = run["traffic"]["response_length"]
+    return 1e3 * statistics.median(r["time/rollout_s"] for r in rows) / steps
