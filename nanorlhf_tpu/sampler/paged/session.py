@@ -78,6 +78,11 @@ from nanorlhf_tpu.sampler.sampler import (
     _sample_token,
     _token_logprob,
 )
+from nanorlhf_tpu.utils.profiling import PhaseTimer
+
+# the host phases of a beat and of an admission, each a `PhaseTimer.phase`
+# (docs/SERVING.md "engine loop account")
+SESSION_PHASES = ("prefill_tick", "dispatch", "sync", "plan", "admit_forward")
 
 # admitted rows re-key the PRNG far away from the per-iteration fold_in
 # stream (iteration counters are bounded by max_tokens << this)
@@ -443,6 +448,10 @@ class DecodeSession:
         self._hub = latency if (latency is not None
                                 and getattr(latency, "enabled", False)) \
             else None
+        # the beat's host account (`session.<phase>` in a profiler trace):
+        # names up front, the engine's metrics() copies the totals from
+        # another thread
+        self.timer = PhaseTimer(span_prefix="session.", names=SESSION_PHASES)
 
         self.T_max = self.Tp + self.max_tokens
         self.nb = blocks_per_row(self.T_max, self.page_size)
@@ -611,28 +620,29 @@ class DecodeSession:
         kelems = plan = seed = None
         if self._radix is not None:
             from nanorlhf_tpu.serving.radix import copy_page, prompt_key
-            kelems = prompt_key(toks_np, mask_np)
-            # may raise RuntimeError — before any state mutation
-            plan = self._radix.plan(kelems, pad_count=pad_count,
-                                    n_blocks=self.nb, prompt_len=self.Tp)
-            if self.seed_window:
-                seed = self._radix.matched_continuation(
-                    kelems, self.seed_window)
-            self.table_np[r] = plan.row_pages
-            if plan.cow_src is not None:
-                s = list(self.state)
-                s[3] = copy_page(s[3], plan.cow_src, plan.cow_dst)
-                self.state = tuple(s)
-            # per-row mode runs the unified suffix forward even on a cold
-            # miss (start = pad_count, pad KV never written); rollout mode
-            # keeps the cold full-row prefill so its streams stay
-            # bit-identical to the uncached scheduler
-            if plan.m > 0:
-                start = plan.m
-            elif self.per_row:
-                start = pad_count
-            else:
-                start = None
+            with self.timer.phase("plan"):
+                kelems = prompt_key(toks_np, mask_np)
+                # may raise RuntimeError — before any state mutation
+                plan = self._radix.plan(kelems, pad_count=pad_count,
+                                        n_blocks=self.nb, prompt_len=self.Tp)
+                if self.seed_window:
+                    seed = self._radix.matched_continuation(
+                        kelems, self.seed_window)
+                self.table_np[r] = plan.row_pages
+                if plan.cow_src is not None:
+                    s = list(self.state)
+                    s[3] = copy_page(s[3], plan.cow_src, plan.cow_dst)
+                    self.state = tuple(s)
+                # per-row mode runs the unified suffix forward even on a
+                # cold miss (start = pad_count, pad KV never written);
+                # rollout mode keeps the cold full-row prefill so its
+                # streams stay bit-identical to the uncached scheduler
+                if plan.m > 0:
+                    start = plan.m
+                elif self.per_row:
+                    start = pad_count
+                else:
+                    start = None
         else:
             self._pstate, ok = _alloc_jit(self._pstate, r, self.nb)
             assert bool(ok), \
@@ -671,8 +681,9 @@ class DecodeSession:
                                     self._backlog_tokens())
             self.chunked_admissions += 1
             return None
-        return self._admit_now(pend, full_cold=full_cold,
-                               start_abs=start_abs)
+        with self.timer.phase("admit_forward"):
+            return self._admit_now(pend, full_cold=full_cold,
+                                   start_abs=start_abs)
 
     def _admit_now(self, pend: _PendingPrefill, *, full_cold: bool,
                    start_abs: int):
@@ -805,33 +816,37 @@ class DecodeSession:
         and the (row, first_token_or_None) of an admission whose final
         chunk landed this beat, if any."""
         installed = None
+        phase = self.timer.phase
         if self._pending:
-            installed = self._prefill_tick()
+            with phase("prefill_tick"):
+                installed = self._prefill_tick()
         t0 = time.perf_counter()
-        table_dev = (jnp.asarray(self.table_np) if self._radix is not None
-                     else self._pstate.table)
-        if self.spec:
-            if self.seed_window:
-                self.state = _spec_chunk_seeded(
+        with phase("dispatch"):
+            table_dev = (jnp.asarray(self.table_np)
+                         if self._radix is not None else self._pstate.table)
+            if self.spec:
+                if self.seed_window:
+                    self.state = _spec_chunk_seeded(
+                        self.params, self.config, self.state, table_dev,
+                        self._prompt_rep, self._seed_rep, self._seed_len,
+                        **self._statics)
+                else:
+                    self.state = _spec_chunk(
+                        self.params, self.config, self.state, table_dev,
+                        self._prompt_rep, **self._statics)
+            elif self.per_row:
+                self.state = _serving_chunk(
                     self.params, self.config, self.state, table_dev,
-                    self._prompt_rep, self._seed_rep, self._seed_len,
-                    **self._statics)
+                    jnp.asarray(self._temp_np), jnp.asarray(self._topp_np),
+                    jnp.asarray(self._greedy_np),
+                    jnp.asarray(self._budget_np), **self._statics)
             else:
-                self.state = _spec_chunk(
+                self.state = _decode_chunk(
                     self.params, self.config, self.state, table_dev,
-                    self._prompt_rep, **self._statics)
-        elif self.per_row:
-            self.state = _serving_chunk(
-                self.params, self.config, self.state, table_dev,
-                jnp.asarray(self._temp_np), jnp.asarray(self._topp_np),
-                jnp.asarray(self._greedy_np), jnp.asarray(self._budget_np),
-                **self._statics)
-        else:
-            self.state = _decode_chunk(
-                self.params, self.config, self.state, table_dev,
-                **self._statics)
-        done_h = np.asarray(self.state[5])
-        it_now = int(self.state[0]) - 1
+                    **self._statics)
+        with phase("sync"):     # the host waits for the device here
+            done_h = np.asarray(self.state[5])
+            it_now = int(self.state[0]) - 1
         if self._hub is not None:
             # done_h forced the device sync, so the chunk's wall time is
             # fully realised here; one mean inter-token gap per sync

@@ -34,6 +34,12 @@ and only the suffix runs through `suffix_logits`. Cold admissions take
 the same path with an empty match — the suffix forward starts at the
 first real token, so pad KV is never written (and never read).
 
+The loop thread's life is accounted for (`LOOP_PHASES`, one
+`PhaseTimer.phase` each, and the session's own inside them): `metrics()`
+exports the cumulative seconds with a request timeline as sums — queue
+wait, and the lag from a first token being queued to the gateway having
+flushed it (docs/SERVING.md "Engine loop account").
+
 Threading: one background loop thread owns the session (carry, block
 table, all device dispatch). `submit()` only appends to the pending
 deque under `make_condition("serving.engine")`; the one extracted lock
@@ -58,9 +64,14 @@ import jax
 import numpy as np
 
 from nanorlhf_tpu.analysis.lockorder import make_condition
-from nanorlhf_tpu.sampler.paged.session import DecodeSession
+from nanorlhf_tpu.sampler.paged.session import SESSION_PHASES, DecodeSession
 from nanorlhf_tpu.serving.radix import RadixCache, prompt_key
 from nanorlhf_tpu.telemetry.health import SLO_RULES
+from nanorlhf_tpu.utils.profiling import PhaseTimer
+
+# what the loop thread does, one `PhaseTimer.phase` each: between them they
+# cover the loop's lifetime (docs/SERVING.md "engine loop account")
+LOOP_PHASES = ("wait", "admit", "reap", "step", "deliver")
 
 
 @dataclass
@@ -75,6 +86,7 @@ class ServingRequest:
     greedy: bool
     max_tokens: int
     t_submit: float
+    t_first_token: Optional[float] = None   # stamped as token 0 is queued
     out_q: "queue.Queue" = field(default_factory=queue.Queue)
     n_emitted: int = 0
     cancelled: bool = False       # set by cancel(); loop reaps the row
@@ -153,6 +165,13 @@ class ServingEngine:
         # scrape — dashboards can alert on rate() without init gaps
         self._shed_reasons = {"queue_full": 0, "slo_ttft_p95": 0,
                               "closed": 0, "pool": 0, "disconnect": 0}
+        # the request timeline as sums (seconds, observations): submit ->
+        # admission starts; first token queued -> written to the socket
+        self._timeline = {"serving/queue_wait_s_sum": 0.0,
+                          "serving/queue_wait_s_count": 0,
+                          "serving/first_token_lag_s_sum": 0.0,
+                          "serving/first_token_lag_s_count": 0}
+        self._timer = PhaseTimer(span_prefix="serving.", names=LOOP_PHASES)
         self._thread = threading.Thread(target=self._loop,
                                         name="serving-engine", daemon=True)
         self._thread.start()
@@ -247,16 +266,31 @@ class ServingEngine:
                 return
             yield tok
 
+    def first_token_sent(self, req: ServingRequest) -> None:
+        """The gateway's streaming handler calls this once it has written
+        and flushed the request's first token: the seconds since the loop
+        queued that token are the way out through the handler thread."""
+        lag = time.perf_counter() - req.t_first_token
+        with self._cond:
+            self._timeline["serving/first_token_lag_s_sum"] += lag
+            self._timeline["serving/first_token_lag_s_count"] += 1
+
     # ------------------------------------------------------------- #
     # engine loop (single background thread owns the session)
     # ------------------------------------------------------------- #
 
+    def _idle_locked(self) -> bool:
+        return (self._running and not self._pending
+                and self._n_active == 0)
+
     def _loop(self):
+        phase = self._timer.phase
         while True:
             with self._cond:
-                while (self._running and not self._pending
-                       and self._n_active == 0):
-                    self._cond.wait(0.05)
+                if self._idle_locked():
+                    with phase("wait"):
+                        while self._idle_locked():
+                            self._cond.wait(0.05)
                 if (not self._running and self._n_active == 0
                         and not self._pending):
                     break
@@ -268,14 +302,19 @@ class ServingEngine:
                                    self._pending.popleft()))
                 self._n_active += len(admits)
             for r, req in admits:
-                self._admit(r, req)
-            self._reap_cancelled()
+                with phase("admit"):
+                    self._admit(r, req)
+            with phase("reap"):
+                self._reap_cancelled()
             if all(o is None for o in self._owner):
                 continue
-            self._sess.step()
-            self._deliver()
+            with phase("step"):
+                self._sess.step()
+            with phase("deliver"):
+                self._deliver()
 
     def _admit(self, r: int, req: ServingRequest):
+        queue_wait = time.perf_counter() - req.t_submit
         Tp = self.prompt_len
         n = int(req.tokens.size)
         pad_count = Tp - n
@@ -302,10 +341,15 @@ class ServingEngine:
         self._owner[r] = req
         with self._cond:
             self._counters["admitted"] += 1
+            self._timeline["serving/queue_wait_s_sum"] += queue_wait
+            self._timeline["serving/queue_wait_s_count"] += 1
+        if self._hub is not None:
+            self._hub.record("latency/queue_wait_s", queue_wait)
         if tok0 is None:
             # chunked admission: the first token lands when the final
             # chunk installs the row; _deliver streams it from the carry
             return
+        req.t_first_token = time.perf_counter()
         req.out_q.put(int(tok0))
         req.n_emitted = 1
 
@@ -337,6 +381,8 @@ class ServingEngine:
             if req is None or r in pending:
                 continue
             n = int(n_gen_h[r])
+            if req.n_emitted == 0 and n > 0:    # a chunked admission's first
+                req.t_first_token = time.perf_counter()
             for tok in out_h[r, req.n_emitted:n]:
                 req.out_q.put(int(tok))
             req.n_emitted = n
@@ -368,6 +414,7 @@ class ServingEngine:
         with self._cond:
             c = dict(self._counters)
             reasons = dict(self._shed_reasons)
+            timeline = dict(self._timeline)
             pending = len(self._pending)
             active = self._n_active
         snap = self._radix.snapshot()
@@ -388,6 +435,16 @@ class ServingEngine:
         }
         for reason, n in sorted(reasons.items()):
             rows[f'serving/shed_total{{reason="{reason}"}}'] = n
+        # the loop account and the request timeline: cumulative seconds,
+        # read from the loop thread's timers (pre-seeded: no key comes or
+        # goes under this thread)
+        for name in LOOP_PHASES:
+            rows[f"serving/loop_{name}_s"] = self._timer.cumulative[name]
+        rows["serving/loop_beats"] = self._timer.cumulative_counts["step"]
+        for name in SESSION_PHASES:
+            rows[f"serving/session_{name}_s"] = (
+                self._sess.timer.cumulative[name])
+        rows.update(timeline)
         return rows
 
     def snapshot(self) -> dict:

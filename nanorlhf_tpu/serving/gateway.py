@@ -145,6 +145,8 @@ class ServingGateway:
                                 raise ConnectionResetError(
                                     "injected client disconnect")
                             self._chunk(json.dumps({"token": tok}) + "\n")
+                            if count == 0:
+                                gw.engine.first_token_sent(req)
                             count += 1
                         self._chunk(json.dumps({"done": True, "n": count})
                                     + "\n")

@@ -2590,6 +2590,11 @@ class RLTrainer:
                         if i < len(responses_decoded) else None,
                         kept=True,
                     )
+            # the whole iteration on the phases' clock, up to the row being
+            # logged: what it exceeds the sum of time/*_s by is host work
+            # between the phases (not a time/*_s key: those are summed as
+            # the phase split and folded into latency/phase_*)
+            metrics["trainer/iteration_s"] = time.perf_counter() - step_t0
             if self.state["global_step"] % cfg.logging_steps == 0:
                 self.logger.log(self.state["global_step"], self.state["episode"], metrics)
                 sample_limit = (

@@ -11,8 +11,9 @@ equivalents:
   uses `time.perf_counter()` (monotonic): wall-clock `time.time()` jumps
   under NTP steps, which corrupts phase splits and everything downstream of
   them (the cumulative MFU accounting integrates these numbers over a run).
-  With a telemetry.SpanTracer attached, every phase is also recorded as a
-  trace span on the calling thread's track.
+  Every phase is also a `jax.profiler.TraceAnnotation` (the profiler's own
+  clock: `trainer.<phase>`, `serving.<phase>`, `session.<phase>`), and with a
+  telemetry.SpanTracer attached a trace span on the calling thread's track.
 - `trace_profile`: a `jax.profiler` trace context writing a TensorBoard-
   loadable profile (XLA op breakdown, HBM usage) to a directory; start/stop
   stay balanced on exception, so a failed step doesn't wedge the profiler
@@ -36,14 +37,25 @@ import jax
 
 
 class PhaseTimer:
-    """Accumulates monotonic wall-clock per named phase; one line per update."""
+    """Accumulates monotonic wall-clock per named phase; one line per update.
 
-    def __init__(self, tracer=None, span_prefix: str = "trainer."):
+    `phase()` is the program's one span call: it also writes a
+    `jax.profiler.TraceAnnotation` named `span_prefix + name`, so while a
+    profiler session runs the phase sits in the profiler's own trace, on the
+    clock of the device events (with no session that is a flag check).
+
+    `names` pre-seeds the never-reset totals, so that a thread other than
+    the one that runs the phases can read `cumulative` / `cumulative_counts`
+    while they are being added to: no key comes or goes."""
+
+    def __init__(self, tracer=None, span_prefix: str = "trainer.",
+                 names: tuple = ()):
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
         # never reset: whole-run phase split (bench MFU accounting reads this
         # across updates while the per-update summary() resets each step)
-        self.cumulative: dict[str, float] = {}
+        self.cumulative: dict[str, float] = {n: 0.0 for n in names}
+        self.cumulative_counts: dict[str, int] = {n: 0 for n in names}
         # optional telemetry.SpanTracer: phases double as trace spans
         self.tracer = tracer
         self.span_prefix = span_prefix
@@ -53,20 +65,23 @@ class PhaseTimer:
         """Callers must block on the phase's outputs inside the block (e.g.
         `jax.block_until_ready(...)`) or async dispatch shifts time into the
         next phase."""
+        label = self.span_prefix + name
         span = (
-            self.tracer.span(self.span_prefix + name)
+            self.tracer.span(label)
             if self.tracer is not None and self.tracer.enabled
             else contextlib.nullcontext()
         )
         t0 = time.perf_counter()
         try:
-            with span:
+            with jax.profiler.TraceAnnotation(label), span:
                 yield
         finally:
             dt = time.perf_counter() - t0
             self.totals[name] = self.totals.get(name, 0.0) + dt
             self.counts[name] = self.counts.get(name, 0) + 1
             self.cumulative[name] = self.cumulative.get(name, 0.0) + dt
+            self.cumulative_counts[name] = (
+                self.cumulative_counts.get(name, 0) + 1)
 
     def summary(self, reset: bool = True) -> dict:
         out = {f"time/{k}_s": v for k, v in self.totals.items()}
