@@ -14,6 +14,15 @@ TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
   RMSNorm statistics and rotary tables run in f32 for stability.
 - **GQA without materializing repeated KV**: queries are reshaped to
   [B, KV, G, T, hd] and contracted against unrepeated KV heads.
+- **The KV cache is the layer scan's carry**: every cached forward (prefill,
+  decode step, speculative verify; contiguous and paged, exact and int8)
+  scans over (layer weights, layer index) with the STACKED cache in the
+  carry. A layer writes its new tokens into the stack at `(layer, ...)`, an
+  update the size of what is new, and reads its slab back from the updated
+  stack, so the cache that leaves the scan is the buffer that entered it:
+  a decode loop that carries the cache copies nothing (with the cache as
+  the scan's xs/ys, XLA copied both stacks whole in every decode step and
+  wrote a slab back per layer; PERF.md, PR 26).
 
 The padding-robust entrypoint `padded_forward_logits` reproduces the contract
 of the reference's shared `forward()` helper (`GRPO/grpo_trainer.py:90-120`):
@@ -251,26 +260,52 @@ def _proj(h, layer_params, lora_layer, name, lora_scale):
     return y
 
 
-def _cache_update(cache, new, idx):
-    """Write `new` [B, KV, T, hd] into `cache` [B, KV, T_max, hd] at slot
-    `idx` along the sequence axis. A scalar `idx` is the shared-slot decode/
-    prefill path; a per-row [B] `idx` (speculative verify — accepted rows
-    advance at different rates) vmaps the update over the batch."""
+def _cache_update(stack, new, layer, idx):
+    """Write `new` [B, KV, T, hd] into the STACKED cache [L, B, KV, T_max,
+    hd] at `(layer, :, :, idx, :)`, in place: the update operand is the size
+    of what is new, never a layer slab. A scalar `idx` is the shared-slot
+    decode/prefill path; a per-row [B] `idx` (speculative verify — accepted
+    rows advance at different rates) vmaps the update over the batch axis of
+    the stack.
+
+    On the TPU the single-token write of the decode loop goes through a view
+    that splits the sequence axis into (T_max / rows, rows), `rows` being the
+    sublanes one tile of this dtype holds: a bitcast in the tiled layout, the
+    same bytes written. Written against the 5-D shape, the v5e compiler
+    moves the V stack's sequence axis outside the batch (a layout that suits
+    a one-token write) and then pays a slab slice and a relayout of V in
+    every layer of every step (9.99 ms a step against 8.17, PERF.md PR 26);
+    with the view both stacks keep their layout and both reads fuse into the
+    attention matmuls. XLA:CPU instead copies the stack around the reshape,
+    so elsewhere the write stays 5-D."""
     if getattr(idx, "ndim", 0) == 1:
         return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (0, i, 0))
-        )(cache, new, idx)
-    return jax.lax.dynamic_update_slice(cache, new, (0, 0, idx, 0))
+            lambda c, n, i: jax.lax.dynamic_update_slice(
+                c, n[None], (layer, 0, i, 0)),
+            in_axes=(1, 0, 0), out_axes=1,
+        )(stack, new, idx)
+    L, B, KV, T_max, hd = stack.shape
+    rows = 32 // stack.dtype.itemsize
+    if (new.shape[2] == 1 and T_max % rows == 0
+            and jax.default_backend() == "tpu"):
+        tiled = jax.lax.dynamic_update_slice(
+            stack.reshape(L, B, KV, T_max // rows, rows, hd),
+            new[None, :, :, :, None, :],
+            (layer, 0, 0, idx // rows, idx % rows, 0))
+        return tiled.reshape(stack.shape)
+    return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, 0, idx, 0))
 
 
-def _scale_update(cache, new, idx):
-    """Same for the int8 cache's sublane-expanded scales [B, KV, 8, T_max]
+def _scale_update(stack, new, layer, idx):
+    """Same for the int8 cache's sublane-expanded scales [L, B, KV, 8, T_max]
     (sequence on the LAST axis)."""
     if getattr(idx, "ndim", 0) == 1:
         return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (0, 0, i))
-        )(cache, new, idx)
-    return jax.lax.dynamic_update_slice(cache, new, (0, 0, 0, idx))
+            lambda c, n, i: jax.lax.dynamic_update_slice(
+                c, n[None], (layer, 0, 0, i)),
+            in_axes=(1, 0, 0), out_axes=1,
+        )(stack, new, idx)
+    return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, 0, 0, idx))
 
 
 # --------------------------------------------------------------------------- #
@@ -289,11 +324,12 @@ def _paged_slots(cache_index, B, T):
 
 
 def _paged_pages(pool, table, slots, page_size):
-    """Resolve logical slots [B, T] to (physical page, offset) pairs.
+    """Resolve logical slots [B, T] to (physical page, offset) pairs of the
+    stacked pool [L, num_pages, ...].
     Out-of-table slots and sentinel table entries both map to page
     `num_pages`, which `mode="drop"` scatters discard — a row past its page
     budget (or with released pages) can never corrupt a live page."""
-    num_pages, nb = pool.shape[0], table.shape[1]
+    num_pages, nb = pool.shape[1], table.shape[1]
     lb = slots // page_size
     page = jnp.where(
         lb < nb,
@@ -303,53 +339,90 @@ def _paged_pages(pool, table, slots, page_size):
     return page, slots % page_size
 
 
-def _paged_cache_update(pool, new, table, cache_index, page_size):
-    """Write `new` [B, KV, T, hd] through the block table into the page pool
-    [num_pages, KV, page_size, hd]."""
+def _paged_cache_update(pool, new, layer, table, cache_index, page_size):
+    """Write `new` [B, KV, T, hd] through the block table into layer `layer`
+    of the stacked page pool [L, num_pages, KV, page_size, hd], in place."""
     B, KV, T, hd = new.shape
     page, off = _paged_pages(pool, table, _paged_slots(cache_index, B, T),
                              page_size)
-    return pool.at[page, :, off, :].set(
+    heads = jnp.arange(KV, dtype=jnp.int32)[None, None, :]
+    return pool.at[layer, page[:, :, None], heads, off[:, :, None], :].set(
         new.transpose(0, 2, 1, 3), mode="drop")
 
 
-def _paged_scale_update(pool, new, table, cache_index, page_size):
-    """Same for the int8 scale pool [num_pages, KV, 8, page_size]
+def _paged_scale_update(pool, new, layer, table, cache_index, page_size):
+    """Same for the int8 scale pool [L, num_pages, KV, 8, page_size]
     (offset on the LAST axis); `new` is [B, KV, 8, T]."""
     B, KV, e, T = new.shape
     page, off = _paged_pages(pool, table, _paged_slots(cache_index, B, T),
                              page_size)
-    return pool.at[page, :, :, off].set(
+    heads = jnp.arange(KV, dtype=jnp.int32)[None, None, :, None]
+    eight = jnp.arange(e, dtype=jnp.int32)[None, None, None, :]
+    return pool.at[layer, page[:, :, None, None], heads, eight,
+                   off[:, :, None, None]].set(
         new.transpose(0, 3, 1, 2), mode="drop")
 
 
-def _paged_view(pool, table, width):
-    """Gather a row-contiguous [B, KV, width, hd] cache view from the pool —
-    the off-TPU read path. Sentinel entries clamp to page num_pages-1; the
-    garbage they surface sits in slots the attention mask already excludes,
-    and NEG_INF masking zeroes its contribution exactly, so this view is
-    bit-identical to the contiguous cache under the same mask."""
-    num_pages = pool.shape[0]
-    g = pool[jnp.minimum(table, num_pages - 1)]      # [B, nb, KV, P, hd]
+def _cache_write(stacks, news, layer, cache_index, paged):
+    """Write one layer's new tokens into the stacked cache arrays, each at
+    `(layer, ...)`: `stacks`/`news` are (k, v) exact, or (k_q, k_s, v_q, v_s)
+    int8, whose odd members are scale arrays (sequence on the last axis).
+    `paged=(block_table, page_size)` routes the write through the table.
+    Returns the updated stacks."""
+    out = []
+    for i, (stack, new) in enumerate(zip(stacks, news)):
+        is_scale = len(stacks) == 4 and i % 2 == 1
+        if paged is not None:
+            update = _paged_scale_update if is_scale else _paged_cache_update
+            out.append(update(stack, new, layer, paged[0], cache_index, paged[1]))
+        else:
+            update = _scale_update if is_scale else _cache_update
+            out.append(update(stack, new, layer, cache_index))
+    return tuple(out)
+
+
+def _layer_slab(stack, layer):
+    """Layer `layer`'s slab of a stacked cache array: the one read a layer
+    makes of the stack, after its write."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def _paged_view(pool, layer, table, width):
+    """Gather a row-contiguous [B, KV, width, hd] view of layer `layer` from
+    the stacked pool [L, num_pages, KV, P, hd] — the off-TPU read path and
+    the XLA path under the kernel threshold. The gather reads the rows' pages
+    straight from the stack: no layer slab is sliced out first. Sentinel
+    entries clamp to page num_pages-1; the garbage they surface sits in slots
+    the attention mask already excludes, and NEG_INF masking zeroes its
+    contribution exactly, so this view is bit-identical to the contiguous
+    cache under the same mask."""
+    num_pages = pool.shape[1]
+    g = pool[layer, jnp.minimum(table, num_pages - 1)]   # [B, nb, KV, P, hd]
     B, nb, KV, P, hd = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, KV, nb * P, hd)[:, :, :width, :]
 
 
-def _paged_scale_view(pool, table, width):
-    """[num_pages, KV, 8, P] scale pool → [B, KV, 8, width] view."""
-    num_pages = pool.shape[0]
-    g = pool[jnp.minimum(table, num_pages - 1)]      # [B, nb, KV, 8, P]
+def _paged_scale_view(pool, layer, table, width):
+    """[L, num_pages, KV, 8, P] scale pool → [B, KV, 8, width] view."""
+    num_pages = pool.shape[1]
+    g = pool[layer, jnp.minimum(table, num_pages - 1)]   # [B, nb, KV, 8, P]
     B, nb, KV, e, P = g.shape
     return g.transpose(0, 2, 3, 1, 4).reshape(B, KV, e, nb * P)[..., :width]
 
 
 def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
-                decode_bounds=None, verify_bounds=None, paged=None):
+                decode_bounds=None, verify_bounds=None, paged=None, layer=0):
     """One decoder layer. If kv_cache is not None, operate incrementally.
 
-    Returns (x_out, new_kv_pair_or_None).
-    kv_cache: (k_cache, v_cache) each [B, KV, T_max, hd] or None.
+    Returns (x_out, new_kv_cache_or_None).
+    kv_cache: the STACKED cache of every layer (init_kv_cache /
+    init_paged_kv_cache: (k, v) each [L, B, KV, T_max, hd], or the four int8
+    arrays) or None; `layer` is this layer's index into it. The layer writes
+    its new tokens into the stack at `(layer, ...)` — an update the size of
+    what is new — and reads its slab back out of the UPDATED stack, so the
+    stack can ride the layer scan's carry and the decode loop's carry as one
+    buffer (`_run_layers`); the returned cache is the whole stack again.
     `attn_fn(q, k, v)`, when given, replaces the attention contraction (used
     by the sequence-parallel path to route through ring attention) — every
     other op stays this single implementation.
@@ -390,33 +463,24 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
         out = attn_fn(q, k, v)
     elif kv_cache is not None and len(kv_cache) == 4:
         # int8 KV cache: (k_q, k_scales, v_q, v_scales) — see init_kv_cache
-        kq_c, ks_c, vq_c, vs_c = kv_cache
-        k_q, k_s = _quantize_kv(k)
-        v_q, v_s = _quantize_kv(v)
-        if paged is not None:
-            table, psize = paged
-            kq_c = _paged_cache_update(kq_c, k_q, table, cache_index, psize)
-            vq_c = _paged_cache_update(vq_c, v_q, table, cache_index, psize)
-            ks_c = _paged_scale_update(ks_c, k_s, table, cache_index, psize)
-            vs_c = _paged_scale_update(vs_c, v_s, table, cache_index, psize)
-        else:
-            kq_c = _cache_update(kq_c, k_q, cache_index)
-            vq_c = _cache_update(vq_c, v_q, cache_index)
-            ks_c = _scale_update(ks_c, k_s, cache_index)
-            vs_c = _scale_update(vs_c, v_s, cache_index)
-        new_cache = (kq_c, ks_c, vq_c, vs_c)
+        new_cache = _cache_write(kv_cache, _quantize_kv(k) + _quantize_kv(v),
+                                 layer, cache_index, paged)
+        kq_c, ks_c, vq_c, vs_c = (_layer_slab(c, layer) for c in new_cache)
 
         def _q8_views(width):
             """Row-contiguous dequantized cache views (paged gathers through
             the table; contiguous passes the slabs through)."""
             if paged is not None:
+                kq_p, ks_p, vq_p, vs_p = new_cache
                 return (
-                    _dequantize_kv(_paged_view(kq_c, paged[0], width),
-                                   _paged_scale_view(ks_c, paged[0], width),
-                                   q.dtype),
-                    _dequantize_kv(_paged_view(vq_c, paged[0], width),
-                                   _paged_scale_view(vs_c, paged[0], width),
-                                   q.dtype),
+                    _dequantize_kv(
+                        _paged_view(kq_p, layer, paged[0], width),
+                        _paged_scale_view(ks_p, layer, paged[0], width),
+                        q.dtype),
+                    _dequantize_kv(
+                        _paged_view(vq_p, layer, paged[0], width),
+                        _paged_scale_view(vs_p, layer, paged[0], width),
+                        q.dtype),
                 )
             return (_dequantize_kv(kq_c, ks_c, q.dtype),
                     _dequantize_kv(vq_c, vs_c, q.dtype))
@@ -467,24 +531,17 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             kd, vd = _q8_views(mask.shape[-1])
             out = gqa_attention(q, kd, vd, mask)
     elif kv_cache is not None:
-        k_cache, v_cache = kv_cache
-        if paged is not None:
-            table, psize = paged
-            k_cache = _paged_cache_update(k_cache, k, table, cache_index, psize)
-            v_cache = _paged_cache_update(v_cache, v, table, cache_index, psize)
-            # logical cache length (for the kernel-eligibility threshold and
-            # the gathered view) is the mask width, not the pool shape
-            cache_len = mask.shape[-1]
-        else:
-            k_cache = _cache_update(k_cache, k, cache_index)
-            v_cache = _cache_update(v_cache, v, cache_index)
-            cache_len = k_cache.shape[2]
-        new_cache = (k_cache, v_cache)
+        new_cache = _cache_write(kv_cache, (k, v), layer, cache_index, paged)
+        k_cache, v_cache = (_layer_slab(c, layer) for c in new_cache)
+        # logical cache length (for the kernel-eligibility threshold and the
+        # gathered view): on the paged layout the mask width, not the pool
+        # shape
+        cache_len = mask.shape[-1] if paged is not None else k_cache.shape[2]
 
         def _kv_views(width):
             if paged is not None:
-                return (_paged_view(k_cache, paged[0], width),
-                        _paged_view(v_cache, paged[0], width))
+                return (_paged_view(new_cache[0], layer, paged[0], width),
+                        _paged_view(new_cache[1], layer, paged[0], width))
             return k_cache, v_cache
 
         if verify_bounds is not None:
@@ -610,21 +667,27 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
         return x, None
     else:
         # cache is a tuple of stacked arrays: (k, v) exact, or
-        # (k_q, k_s, v_q, v_s) int8 — threaded generically through the scan.
-        # `paged` (block table + page size) is closure-captured, not scanned:
-        # one table serves every layer
+        # (k_q, k_s, v_q, v_s) int8. It is the scan's CARRY, not its xs/ys:
+        # every layer writes its new tokens into the one stacked buffer at
+        # its own index and reads its slab from it, so the cache that leaves
+        # the scan is the buffer that entered it and a decode loop that
+        # carries the cache needs no copy of it. `paged` (block table + page
+        # size) is closure-captured: one table serves every layer
         def body(carry, inp):
-            layer_params, lora_layer = inp[0], inp[1]
-            y, new_cache = _layer_body(
-                config, carry, layer_params, cos, sin, mask, tuple(inp[2:]),
+            y, caches = carry
+            layer_params, lora_layer, layer = inp
+            y, caches = _layer_body(
+                config, y, layer_params, cos, sin, mask, caches,
                 cache_index, lora_layer, lora_scale,
                 decode_bounds=decode_bounds, verify_bounds=verify_bounds,
-                paged=paged,
+                paged=paged, layer=layer,
             )
-            return y, new_cache
+            return (y, caches), None
 
-        x, new_caches = jax.lax.scan(
-            body, x, (params["layers"], lora_layers, *kv_caches)
+        layers = jnp.arange(kv_caches[0].shape[0], dtype=jnp.int32)
+        (x, new_caches), _ = jax.lax.scan(
+            body, (x, tuple(kv_caches)),
+            (params["layers"], lora_layers, layers),
         )
         return x, new_caches
 
@@ -854,10 +917,12 @@ def init_paged_kv_cache(
     [L, num_pages, KV, 8, page_size] — the sublane-expanded layout of
     `init_kv_cache`, per page instead of per row.
 
-    Same tuple arity as the contiguous cache, so `_run_layers` threads it
-    through the layer scan unchanged; the block table is NOT part of the
-    cache tuple (it is shared across layers and rides as a separate
-    argument).
+    Same tuple arity and leading layer axis as the contiguous cache, so
+    `_run_layers` carries it through the layer scan the same way (the stack
+    is the scan's carry; a layer scatters its new tokens in at
+    `(layer, page, head, offset)` and gathers its rows' pages back out); the
+    block table is NOT part of the cache tuple (it is shared across layers
+    and rides as a separate argument).
     """
     shape = (
         config.num_hidden_layers,
@@ -1009,8 +1074,10 @@ def decode_verify(
     candidates are beyond `max_tokens` and are truncated before emission —
     docs/PAGED_CACHE.md walks the bound). Returns
     (logits [B, Tq, V], new caches): logits[:, i] is the next-token
-    distribution after consuming candidates 0..i, bit-matching a chain of
-    `decode_step` calls over the same tokens on the CPU mesh (test-pinned).
+    distribution after consuming candidates 0..i, equal to a chain of
+    `decode_step` calls over the same tokens to float32 roundoff (test-pinned
+    on the CPU mesh at 1e-5 of the logits' scale; not bit for bit: the T = 1
+    and the T = k+1 forwards are two compiled programs).
 
     `want_logits=False` skips the lm_head matmul and returns
     (None, new caches) — the chunked-prefill path (sampler/paged/session.py)

@@ -205,6 +205,86 @@ def test_fused_kernel_is_not_partitionable_so_auto_takes_lax_under_a_mesh(
     assert fused_logprob_impl(cfg, on_mesh) == "pallas"
 
 
+def _shapes_on(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _decode_loop(case, one_chip):
+    """(lowered program, stacked cache shapes) of a decode loop at
+    Qwen2.5-1.5B widths, two layers deep: the benchmark's rollout (16 prompts
+    x 4 samples, 256 + 512 slots), the same over an int8 cache, and the
+    serving session's chunk (64 rows, 807 pages of 128)."""
+    import dataclasses
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.sampler.sampler import generate_tokens
+
+    cfg = ModelConfig(
+        vocab_size=V, hidden_size=D, intermediate_size=8960,
+        num_hidden_layers=2, num_attention_heads=H, num_key_value_heads=KV,
+        kv_cache_quant="int8" if case == "rollout_int8" else "none")
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)),
+        one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    if case == "serving_chunk":
+        R, Tp, new, pages = 64, 1024, 512, 807
+        cache = jax.eval_shape(
+            lambda: M.init_paged_kv_cache(cfg, pages, PAGE, jnp.bfloat16))
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, spec((R, (Tp + new) // PAGE), jnp.int32),
+            spec((R,), jnp.float32), spec((R,), jnp.float32),
+            spec((R,), jnp.bool_), spec((R,), jnp.int32), Tp=Tp,
+            max_tokens=new, page_size=PAGE, sync_every=4, eos_token_id=3,
+            pad_token_id=0, temperature=1.0, top_p=1.0, greedy=False,
+            lora_scale=1.0, top_k=64, capture_logprobs=False,
+            approx_top_k=True)
+        return lowered, cache
+    B, fanout, Tp, new = 16, 4, 256, 512
+    lowered = generate_tokens.lower(
+        params, cfg, spec((B, Tp), jnp.int32), spec((B, Tp), jnp.bool_), key,
+        max_tokens=new, eos_token_id=3, pad_token_id=0, temperature=0.9,
+        capture_logprobs=True, prompt_fanout=fanout)
+    cache = jax.eval_shape(
+        lambda: M.init_kv_cache(cfg, B * fanout, Tp + new, jnp.bfloat16))
+    return lowered, cache
+
+
+@pytest.mark.parametrize("case", ["rollout", "rollout_int8", "serving_chunk"])
+def test_decode_loop_carries_the_cache_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """What tests/test_cache_carry.py holds XLA:CPU to, asked of the chip's
+    compiler at the benchmark's shapes: inside the decode loop no copy of a
+    cache stack and no slab written back into one, and on the XLA attention
+    path no layer slab set down in memory between the stack and the
+    attention matmuls either (both reads fuse; the first compile of ISSUE 26
+    sliced and relaid V per layer, 18 % of the step). The model asks
+    `jax.default_backend()` for its TPU choices, which is the CPU here, so
+    the test answers for it; the int8 case then takes the q8 Pallas kernel,
+    whose operands are slabs by construction."""
+    from test_cache_carry import decode_loop_offences, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, cache = _decode_loop(case, SingleDeviceSharding(v5e[0]))
+    hlo = lowered.compile().as_text()
+    offences, _ = decode_loop_offences(
+        hlo, hlo_stacks(cache), slabs_too=case != "rollout_int8")
+    assert not offences, "\n".join(offences)
+
+
 def test_chip_smoke_refuses_a_cpu_backend():
     """No accelerator → non-zero exit before any phase, and no result line."""
     out = subprocess.run(
