@@ -2,6 +2,7 @@
 
     python chip_smoke.py              # one chip:   kernels, train, serve
     python chip_smoke.py --multichip  # four chips: sharded GRPO vs one device
+    python chip_smoke.py --olmoe      # one chip:   the sparse-expert layer
 
 One process. It pins no platform: the first thing it does after `import jax`
 is read `jax.devices()`, print what it found, and exit non-zero unless that is
@@ -31,6 +32,15 @@ offline mode, no checkpoint and no network):
 `--multichip` runs none of those: GRPO on `MeshConfig(data=1, fsdp=2,
 tensor=2)` over four chips, then the placement, memory-balance, HLO and
 sharded-vs-one-device logprob checks of `phase_multichip`.
+
+`--olmoe` runs none of those either: OLMoE-1B-7B at its published widths and
+the benchmark cell's depth (`benchmark/configs/olmoe-1b-7b.json`, through
+`ModelConfig.from_hf_config`), bf16 weights from the seed with a non-zero
+adapter, against `benchmark/harness/reference_olmoe.py` in float32 at
+`highest`: scoring logprobs of a seeded sample, then prefill + decode steps
+through the contiguous cache (teacher-forced) and through the paged
+`DecodeSession` (greedy), each held to what the plain bf16 forward itself
+loses against float32, measured in the run (`phase_olmoe`).
 
 Sizes live in `Sizes`; a rehearsal on the CPU imports this module and passes
 smaller ones (tests and scratch scripts steer, the program grows no option).
@@ -88,6 +98,13 @@ class Sizes:
     rows: int = 8
     shared_prefix: int = 100
     seed: int = 0
+    # olmoe: the benchmark's configuration file, or a tiny preset to rehearse
+    olmoe_config: str = "benchmark/configs/olmoe-1b-7b.json"
+    olmoe_rows: int = 8            # 256 greedy tokens: a maximum over 64 swings
+    olmoe_prompt: int = 736        # prefill; + olmoe_decode = 768, the cell's row
+    olmoe_decode: int = 32
+    olmoe_context: int = 256       # scoring: responses start here
+    olmoe_last: int = 128          # logits compared on the last positions
 
 
 def emit(phase: str, **fields) -> None:
@@ -804,9 +821,142 @@ def phase_multichip(sz: Sizes, meter: Meter, out_dir: str) -> None:
 
 
 # --------------------------------------------------------------------------- #
+# the sparse-expert layer
+# --------------------------------------------------------------------------- #
 
 
-def run_phases(sz: Sizes, multichip: bool) -> None:
+def phase_olmoe(sz: Sizes, meter: Meter) -> None:
+    """OLMoE through the normal path against the plain float32 reference,
+    under `bf16_agreement`'s rule on logits or logprobs: the path under test
+    may be BF16_SLACK times further from float32 than the plain bf16 forward
+    (XLA attention, `ragged_dot`, no cache), both measured here on the same
+    positions."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from harness import agreement, reference_olmoe
+
+    from nanorlhf_tpu.core import (ModelConfig, decode_step, init_kv_cache,
+                                   init_params, padded_forward_logits, prefill)
+    from nanorlhf_tpu.core.lora import LoraConfig, init_lora_params
+    from nanorlhf_tpu.entrypoints.grpo import build_config
+    from nanorlhf_tpu.sampler.paged.session import DecodeSession
+    from nanorlhf_tpu.trainer.trainer import fused_response_logprobs
+
+    phase = Phase("olmoe", meter)
+    with open(os.path.join(ROOT, sz.olmoe_config)) as f:
+        file = json.load(f)
+    mcfg = ModelConfig.from_hf_config(file)
+    plain_mcfg = dataclasses.replace(mcfg, attention_impl="xla")
+    check(mcfg.num_experts == file["num_experts"] and mcfg.qk_norm,
+          "from_hf_config dropped the expert keys")
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    key = jax.random.PRNGKey(sz.seed)
+    lora_cfg = LoraConfig(r=64, alpha=16)
+    params = jax.jit(lambda k: init_params(mcfg, k, dtype))(key)
+    lora = init_lora_params(mcfg, lora_cfg, jax.random.fold_in(key, 1), dtype)
+    for i, name in enumerate(sorted(lora["layers"])):   # B is zero at birth
+        b = lora["layers"][name]["b"]
+        lora["layers"][name]["b"] = (0.02 * jax.random.normal(
+            jax.random.fold_in(key, 10 + i), b.shape, jnp.float32)).astype(dtype)
+    params["lora"] = lora
+    scale, pad = lora_cfg.scale, 0
+    P, n_new, T = sz.olmoe_prompt, sz.olmoe_decode, sz.olmoe_prompt + sz.olmoe_decode
+    last, ctx = sz.olmoe_last, sz.olmoe_context
+    ids = np.array(jax.random.randint(jax.random.fold_in(key, 2),
+                                      (sz.olmoe_rows, T), 3, mcfg.vocab_size))
+    ids[0, : P // 8] = pad                                 # one left-padded row
+    ids = jnp.asarray(ids, jnp.int32)
+    real = ids != pad
+
+    def reference_logits(x, mask, n):
+        with jax.default_matmul_precision("highest"):
+            return np.asarray(jax.jit(lambda p, x, m: reference_olmoe.logits(
+                p, file, x, pad, scale, last=n, mask=m))(params, x, mask))
+
+    def within(tested, plain, ref, what):
+        return bf16_agreement(phase, tested, plain, ref,
+                              np.ones(np.shape(ref), bool), (what, "plain"))
+
+    # ---- scoring logprobs: auto, plain, float32 -------------------------
+    cfg = build_config()
+    plain_cfg = dataclasses.replace(cfg, fused_logprob_impl="lax")
+    score = lambda m, c: np.asarray(jax.jit(lambda p, x: fused_response_logprobs(  # noqa: E731
+        p, m, x, x[:, ctx:], pad, ctx, c, lora_scale=scale))(params, ids))
+    with jax.default_matmul_precision("highest"):
+        ref_lp = np.asarray(jax.jit(lambda p, x: reference_olmoe.response_logprobs(
+            p, file, x, ctx, pad, cfg.temperature, scale))(params, ids))
+    resp = np.asarray(real)[:, ctx:]
+    scoring = within(score(mcfg, cfg)[resp], score(plain_mcfg, plain_cfg)[resp],
+                     ref_lp[resp], "auto_scoring")
+
+    # ---- the plain bf16 forward and the reference on the last positions --
+    ref_last = reference_logits(ids, real, last)            # [B, last, V]
+    plain_last = np.asarray(jax.jit(lambda p, x: padded_forward_logits(
+        p, plain_mcfg, x, pad, scale))(params, ids)[:, -last:], np.float32)
+    steps = slice(last - n_new - 1, last)    # positions P-1 .. T-1: n_new + 1
+
+    # ---- prefill + teacher-forced decode through the contiguous cache ----
+    caches = init_kv_cache(mcfg, sz.olmoe_rows, T, dtype)
+    lg, caches = jax.jit(lambda p, x, m, c: prefill(p, mcfg, x, m, c, scale))(
+        params, ids[:, :P], real[:, :P], caches)
+    step = jax.jit(lambda p, tok, pos, t, km, c: decode_step(
+        p, mcfg, tok, pos, t, km, c, scale))
+    got, n_real = [np.asarray(lg, np.float32)], real[:, :P].sum(axis=1)
+    key_mask = jnp.zeros((sz.olmoe_rows, T), bool).at[:, :P].set(real[:, :P])
+    for t in range(P, T):
+        key_mask = key_mask.at[:, t].set(True)
+        lg, caches = step(params, ids[:, t], n_real + (t - P), jnp.int32(t),
+                          key_mask, caches)
+        got.append(np.asarray(lg, np.float32))
+    del caches
+    contiguous = within(np.stack(got, axis=1), plain_last[:, steps],
+                        ref_last[:, steps], "contiguous_cache")
+
+    # ---- the paged session, greedy, its own tokens ------------------------
+    sess = DecodeSession(
+        params, mcfg, rows=sz.olmoe_rows, prompt_len=P, max_tokens=n_new,
+        page_size=sz.page_size, eos_token_id=mcfg.vocab_size + 1,
+        pad_token_id=pad, key=jax.random.fold_in(key, 3), greedy=True,
+        capture_logprobs=True, lora_scale=scale, sync_every=8)
+    sess.bootstrap(ids[:, :P], real[:, :P])
+    for _ in range(n_new):
+        if sess.step()[0].all():
+            break
+    out, captured = np.asarray(sess.state[1]), np.asarray(sess.state[2])
+    del sess
+    served = jnp.concatenate([ids[:, :P], jnp.asarray(out)], axis=1)
+    served_real = jnp.concatenate([real[:, :P], jnp.ones_like(out, bool)], axis=1)
+    ref_s = reference_logits(served, served_real, n_new + 1)[:, :-1]
+    plain_s = np.asarray(jax.jit(lambda p, x: padded_forward_logits(
+        p, plain_mcfg, x, pad, scale))(params, served)[:, -n_new - 1:-1],
+        np.float32)
+
+    def chosen_logprob(lg):
+        lp = lg - lg.max(-1, keepdims=True)
+        lp = lp - np.log(np.exp(lp).sum(-1, keepdims=True))
+        return np.take_along_axis(lp, out[..., None], axis=-1)[..., 0]
+
+    paged = within(captured, chosen_logprob(plain_s), chosen_logprob(ref_s),
+                   "paged_session")
+    # a greedy token may sit below the reference's top by what the plain
+    # path's own argmax does (the benchmark's rule for served tokens)
+    V = ref_s.shape[-1]
+    follows, greedy = agreement.follows_greedy(
+        ref_s.reshape(-1, V), out.reshape(-1), plain_s.reshape(-1, V))
+    phase.expect(follows, f"greedy tokens sit further under the reference's "
+                          f"top than the plain path's: {greedy}")
+    phase.finish(config=sz.olmoe_config, layers=mcfg.num_hidden_layers,
+                 hidden=mcfg.hidden_size, experts=mcfg.num_experts,
+                 per_token=mcfg.num_experts_per_tok, vocab=mcfg.vocab_size,
+                 dtype=str(jnp.dtype(dtype)), rows=sz.olmoe_rows, tokens=T,
+                 prefill=P, decode_steps=n_new, scoring=scoring,
+                 contiguous=contiguous, paged=paged, greedy=greedy)
+
+
+def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False) -> None:
     """Everything after the device gate. Raises at the first failed phase."""
     from nanorlhf_tpu import native
     from nanorlhf_tpu.core import ModelConfig
@@ -821,6 +971,9 @@ def run_phases(sz: Sizes, multichip: bool) -> None:
     if multichip:
         phase_multichip(sz, meter, os.path.join(out, "multichip"))
         return
+    if olmoe:
+        phase_olmoe(sz, meter)
+        return
     tiny = "tiny" in sz.model.lower()  # entrypoints.common.resolve_model's rule
     phase_kernels(sz, ModelConfig.qwen2_tiny(vocab_size=4096) if tiny
                   else ModelConfig.qwen2_1_5b(), meter)
@@ -832,6 +985,8 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--multichip", action="store_true",
                         help="four chips: sharded GRPO vs one device, only")
+    parser.add_argument("--olmoe", action="store_true",
+                        help="one chip: OLMoE against its float32 reference, only")
     args = parser.parse_args(argv)
 
     import jax
@@ -849,7 +1004,7 @@ def main(argv=None) -> int:
               f"{device['count']}", file=sys.stderr)
         return 1
 
-    run_phases(Sizes(), args.multichip)
+    run_phases(Sizes(), args.multichip, args.olmoe)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

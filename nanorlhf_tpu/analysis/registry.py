@@ -60,6 +60,7 @@ METRIC_SCOPES = (
     "nanorlhf_tpu/loadgen/",             # traffic harness emits loadgen/*
     "nanorlhf_tpu/envs/",                # episode driver emits env/*
     "nanorlhf_tpu/utils/profiling.py",   # PhaseTimer emits time/{k}_s
+    "nanorlhf_tpu/ops/moe.py",           # moe_counters names the trainer's moe/*
 )
 
 # slash-shaped literals that are not metric keys (HTTP content types)

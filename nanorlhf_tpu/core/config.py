@@ -1,15 +1,22 @@
-"""Model architecture config (Qwen2-family decoder).
+"""Model architecture config: one decoder layer kind per model, either the
+dense Qwen2/Llama block (GQA + SwiGLU) or OLMoE's sparse-expert block.
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
-`/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the Qwen2
+`/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
 architecture hyperparameters our JAX decoder needs. Presets mirror the HF
-configs of the model sizes the reference trains (0.5B/1.5B/7B).
+configs of the model sizes the reference trains (0.5B/1.5B/7B), the Llama
+side of the same block, and OLMoE-1B-7B (docs/MOE.md).
 """
 
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional
+
+# config.json keys that announce a sparse-expert MLP of some family
+_EXPERT_KEYS = ("num_experts", "num_local_experts", "n_routed_experts",
+                "num_experts_per_tok", "moe_intermediate_size",
+                "n_shared_experts", "shared_expert_intermediate_size")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +41,17 @@ class ModelConfig:
     # from attention_bias (a Llama with attention_bias=True is valid, ADVICE
     # r3). None (random-init configs) falls back to the bias heuristic.
     model_type: Optional[str] = None
+    # Sparse-expert MLP (ops/moe.py, docs/MOE.md). `num_experts == 0` is the
+    # dense SwiGLU layer; otherwise every layer's MLP is a float32 softmax
+    # router over `num_experts` experts of width `intermediate_size`, each
+    # token reaching its top `num_experts_per_tok` (dropless), the weights
+    # renormalised over the chosen ones only if `norm_topk_prob`.
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    # RMSNorm of q and k over their WHOLE projection width, before the head
+    # split and RoPE (OLMoE; `from_hf_config` sets it from the model type).
+    qk_norm: bool = False
     # "int8": the sampler's KV cache stores int8 values + per-token-per-head
     # bf16 scales (absmax over head_dim). At long responses the cache read is
     # the dominant decode HBM stream (≈7.5 GB/step at 8k tokens, batch 32);
@@ -124,7 +142,40 @@ class ModelConfig:
             num_attention_heads=28,
             num_key_value_heads=4,
             tie_word_embeddings=False,
+            vocab_size=152064,  # Qwen2.5-7B pads its vocabulary further than the 1.5B
         )
+
+    @classmethod
+    def olmoe_1b_7b(cls) -> "ModelConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct: 16 MHA layers, 64 experts of
+        width 1024, 8 per token, QK-norm, no biases, untied head."""
+        return cls(
+            vocab_size=50304,
+            hidden_size=2048,
+            intermediate_size=1024,
+            num_hidden_layers=16,
+            num_attention_heads=16,
+            num_key_value_heads=16,
+            rope_theta=10_000.0,
+            rms_norm_eps=1e-5,
+            tie_word_embeddings=False,
+            max_position_embeddings=4096,
+            attention_bias=False,
+            model_type="olmoe",
+            num_experts=64,
+            num_experts_per_tok=8,
+            norm_topk_prob=False,
+            qk_norm=True,
+        )
+
+    @classmethod
+    def olmoe_tiny(cls, vocab_size: int = 512) -> "ModelConfig":
+        """Test-size OLMoE: the same layer at 8 experts, 2 per token."""
+        return dataclasses.replace(
+            cls.olmoe_1b_7b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=32, num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, max_position_embeddings=1024,
+            num_experts=8, num_experts_per_tok=2)
 
     @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
@@ -165,7 +216,11 @@ class ModelConfig:
 
     @classmethod
     def from_hf_config(cls, hf_config) -> "ModelConfig":
-        """Build from a `transformers` Qwen2Config / LlamaConfig (or dict)."""
+        """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
+        (or dict). A config the decoder does not implement raises: expert keys
+        under any model type but `olmoe` (shared experts, dense leading
+        layers and the rest are other layers than ops/moe.py's), and a
+        non-null `clip_qkv`."""
         get = (lambda k, d=None: getattr(hf_config, k, d)) if not isinstance(
             hf_config, dict
         ) else (lambda k, d=None: hf_config.get(k, d))
@@ -173,6 +228,18 @@ class ModelConfig:
         # Llama-family configs expose it (default False)
         model_type = str(get("model_type", "qwen2")).lower()
         attn_bias = get("attention_bias", "qwen" in model_type)
+        olmoe = model_type == "olmoe"
+        expert_keys = [k for k in _EXPERT_KEYS if get(k)]
+        if expert_keys and not olmoe:
+            raise ValueError(
+                f"model_type={model_type!r} with expert keys {expert_keys}: "
+                "the decoder implements OLMoE's sparse-expert layer only "
+                "(docs/MOE.md); building a dense model from this config "
+                "would be another model under its name")
+        if get("clip_qkv") is not None:
+            raise ValueError(
+                f"clip_qkv={get('clip_qkv')!r}: the decoder does not clip "
+                "q/k/v (OLMoE-1B-7B-0125-Instruct publishes null)")
         return cls(
             vocab_size=get("vocab_size"),
             hidden_size=get("hidden_size"),
@@ -187,4 +254,8 @@ class ModelConfig:
             max_position_embeddings=get("max_position_embeddings", 32768),
             attention_bias=bool(attn_bias),
             model_type=model_type,
+            num_experts=get("num_experts") or 0,    # olmoe only: see above
+            num_experts_per_tok=get("num_experts_per_tok") or 0,
+            norm_topk_prob=bool(get("norm_topk_prob", False)),
+            qk_norm=olmoe,
         )

@@ -12,6 +12,14 @@ there is only one tree.
 Layout mirrors the stacked layer tree: `lora["layers"][proj] = {"a": [L, in, r],
 "b": [L, r, out]}`; contribution `(x @ A) @ B * (alpha / r)`, B zero-init so
 step 0 is exactly the base model.
+
+On a sparse-expert model (`ModelConfig.num_experts > 0`) the adapter sits on
+the four attention projections only (`lora_targets`): the router and the
+expert kernels are frozen, as is usual for expert models (an adapter per
+expert would be 64 x the parameters for an eighth of the tokens each), and
+the model has no `gate_proj` / `up_proj` / `down_proj` leaf to adapt. The
+model decides, not a user option; `merge_lora`, `trainable_mask` and the
+export therefore need no expert case.
 """
 
 from __future__ import annotations
@@ -23,7 +31,8 @@ import jax.numpy as jnp
 
 from nanorlhf_tpu.core.config import ModelConfig
 
-ALL_TARGETS = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj")
+ATTENTION_TARGETS = ("q_proj", "k_proj", "v_proj", "o_proj")
+ALL_TARGETS = ATTENTION_TARGETS + ("gate_proj", "up_proj", "down_proj")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,14 +65,25 @@ def _proj_dims(config: ModelConfig, name: str) -> tuple[int, int]:
     }[name]
 
 
+def lora_targets(config: ModelConfig, lora: LoraConfig) -> tuple[str, ...]:
+    """The projections of `lora.targets` this model adapts: all of them on a
+    dense model, the attention ones on an expert model."""
+    if config.num_experts:
+        return tuple(t for t in lora.targets if t in ATTENTION_TARGETS)
+    return tuple(lora.targets)
+
+
 def init_lora_params(
     config: ModelConfig, lora: LoraConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> dict:
     """A ~ N(0, 1/r) (kaiming-ish), B = 0 → adapter starts as identity."""
     L = config.num_hidden_layers
     keys = jax.random.split(key, len(lora.targets))
+    targets = lora_targets(config, lora)
     layers = {}
     for k, name in zip(keys, lora.targets):
+        if name not in targets:
+            continue
         d_in, d_out = _proj_dims(config, name)
         layers[name] = {
             "a": (jax.random.normal(k, (L, d_in, lora.r), jnp.float32) / jnp.sqrt(lora.r)).astype(dtype),

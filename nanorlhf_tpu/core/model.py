@@ -1,4 +1,7 @@
-"""Qwen2-family decoder as pure functions over a stacked-layer pytree.
+"""One decoder, two layer kinds, as pure functions over a stacked-layer
+pytree: the Qwen2/Llama block (GQA + SwiGLU) and OLMoE's (QK-norm + a
+sparse-expert MLP, ops/moe.py), chosen at trace time from the `ModelConfig`
+(`_mlp`); every layer of one model is the same kind.
 
 TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
 `/root/reference/GRPO/grpo.py:218-224`):
@@ -67,23 +70,37 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
             entry["bias"] = jnp.zeros((L, shape[-1]), dtype)
         return entry
 
-    params = {
-        "embed_tokens": dense(next(keys), (V, D), scale=0.02),
-        "layers": {
-            "input_layernorm": jnp.ones((L, D), dtype),
-            "q_proj": qkv(next(keys), (D, H * hd)),
-            "k_proj": qkv(next(keys), (D, KV * hd)),
-            "v_proj": qkv(next(keys), (D, KV * hd)),
-            "o_proj": {"kernel": stacked(next(keys), (H * hd, D))},
-            "post_attention_layernorm": jnp.ones((L, D), dtype),
-            "gate_proj": {"kernel": stacked(next(keys), (D, F))},
-            "up_proj": {"kernel": stacked(next(keys), (D, F))},
-            "down_proj": {"kernel": stacked(next(keys), (F, D))},
-        },
-        "norm": jnp.ones((D,), dtype),
+    # (built in the order the dense tree always was: the same jitted program)
+    embed_tokens = dense(next(keys), (V, D), scale=0.02)
+    # the MLP's three kernels are [L, D, F] dense and [L, E, D, F] per expert
+    # (docs/MOE.md), drawn from the same three keys
+    E = config.num_experts
+    mlp = lambda shape: {"kernel": stacked(next(keys), ((E,) if E else ()) + shape)}  # noqa: E731
+    layers = {
+        "input_layernorm": jnp.ones((L, D), dtype),
+        "q_proj": qkv(next(keys), (D, H * hd)),
+        "k_proj": qkv(next(keys), (D, KV * hd)),
+        "v_proj": qkv(next(keys), (D, KV * hd)),
+        "o_proj": {"kernel": stacked(next(keys), (H * hd, D))},
+        "post_attention_layernorm": jnp.ones((L, D), dtype),
     }
+    mlp_kernels = {"gate_proj": mlp((D, F)), "up_proj": mlp((D, F)),
+                   "down_proj": mlp((F, D))}
+    if E:
+        layers["experts"] = mlp_kernels
+    else:
+        layers.update(mlp_kernels)
+    params = {"embed_tokens": embed_tokens, "layers": layers,
+              "norm": jnp.ones((D,), dtype)}
     if not config.tie_word_embeddings:
         params["lm_head"] = dense(next(keys), (D, V), scale=0.02)
+    # leaves only OLMoE has draw from keys no dense model has reached
+    if E:
+        params["layers"]["router"] = {"kernel": stacked(
+            next(keys), (D, E), scale=1.0 / jnp.sqrt(D))}
+    if config.qk_norm:
+        params["layers"]["q_norm"] = jnp.ones((L, H * hd), dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, KV * hd), dtype)
     return params
 
 
@@ -152,6 +169,19 @@ def use_q8_decode_kernel(impl: str) -> bool:
     non-"xla" impl takes the kernel at any cache length ("xla" stays the
     operator escape hatch; "pallas" also exercises it in interpret mode)."""
     return impl == "pallas" or (impl != "xla" and jax.default_backend() == "tpu")
+
+
+def use_expert_kernel(config: ModelConfig) -> bool:
+    """Resolve the sparse-expert MLP's grouped matmul (ops/moe.py): the
+    megablox Pallas kernel for `"pallas"`, and for `"auto"` on a TPU; the
+    plain `ragged_dot` for `"xla"`, off the TPU, and under a multi-device
+    mesh, where GSPMD partitions XLA's own op and refuses a Mosaic kernel
+    (as `trainer.fused_logprob_impl` does for the fused logprob)."""
+    if config.spmd_mesh is not None:
+        return False
+    impl = config.attention_impl
+    return impl == "pallas" or (impl == "auto"
+                                and jax.default_backend() == "tpu")
 
 
 def _kernel_spmd(config: ModelConfig, H: int, KV: int):
@@ -410,12 +440,43 @@ def _paged_scale_view(pool, layer, table, width):
     return g.transpose(0, 2, 3, 1, 4).reshape(B, KV, e, nb * P)[..., :width]
 
 
+def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
+         expert_stack=None, layer=None):
+    """The layer's MLP on normed hidden states, chosen at trace time from
+    the config: the dense SwiGLU, or the sparse-expert MLP of ops/moe.py
+    (`config.num_experts > 0`; its router and experts carry no adapter,
+    core/lora.py). Per token, so every caller (uncached, contiguous, paged,
+    int8-KV, verify, ring attention, shared prefill) goes through it
+    untouched. `expert_stack` is the experts' subtree of EVERY layer,
+    addressed in place at `layer` instead of sliced (`_expert_xs`; ops/moe.py
+    says why). Returns `(out, aux)`; `aux` is the router's per-token record
+    (`moe_mlp`), None for the dense layer."""
+    if config.num_experts:
+        from nanorlhf_tpu.ops.moe import moe_mlp
+
+        experts = expert_stack or layer_params["experts"]
+        return moe_mlp(
+            h, layer_params["router"]["kernel"],
+            experts["gate_proj"]["kernel"], experts["up_proj"]["kernel"],
+            experts["down_proj"]["kernel"], config.num_experts_per_tok,
+            config.norm_topk_prob,
+            layer=layer if expert_stack is not None else None,
+            kernel=use_expert_kernel(config))
+    gate = _proj(h, layer_params, lora_layer, "gate_proj", lora_scale)
+    up = _proj(h, layer_params, lora_layer, "up_proj", lora_scale)
+    return _proj(
+        jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up,
+        layer_params, lora_layer, "down_proj", lora_scale,
+    ), None
+
+
 def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
-                decode_bounds=None, verify_bounds=None, paged=None, layer=0):
+                decode_bounds=None, verify_bounds=None, paged=None, layer=0,
+                expert_stack=None):
     """One decoder layer. If kv_cache is not None, operate incrementally.
 
-    Returns (x_out, new_kv_cache_or_None).
+    Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
     kv_cache: the STACKED cache of every layer (init_kv_cache /
     init_paged_kv_cache: (k, v) each [L, B, KV, T_max, hd], or the four int8
     arrays) or None; `layer` is this layer's index into it. The layer writes
@@ -451,6 +512,10 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
     k = _proj(h, layer_params, lora_layer, "k_proj", lora_scale)
     v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
+    if config.qk_norm:
+        # OLMoE: over the whole projection width, before the head split
+        q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
+        k = rms_norm(k, layer_params["k_norm"], config.rms_norm_eps)
     q = q.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
@@ -612,14 +677,22 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     x = x + out
 
     h = rms_norm(x, layer_params["post_attention_layernorm"], config.rms_norm_eps)
-    gate = _proj(h, layer_params, lora_layer, "gate_proj", lora_scale)
-    up = _proj(h, layer_params, lora_layer, "up_proj", lora_scale)
-    ff = _proj(
-        jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up,
-        layer_params, lora_layer, "down_proj", lora_scale,
-    )
+    ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
+                   expert_stack, layer)
     x = x + ff
-    return x, new_cache
+    return x, new_cache, aux
+
+
+def _expert_xs(layers: dict, in_place: bool):
+    """`(scanned layer tree, expert stack | None)`. In place, an expert
+    model's expert kernels stay OUT of the scanned xs and every layer
+    addresses the whole stack at its own index (`_mlp`): a scanned slice
+    would be copied out of the stack for the grouped matmul's custom call
+    (ops/moe.py). A dense model has no experts and nothing changes."""
+    if not in_place or "experts" not in layers:
+        return layers, None
+    layer_xs = dict(layers)
+    return layer_xs, layer_xs.pop("experts")
 
 
 def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0,
@@ -639,13 +712,20 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
     lora_layers = params.get("lora", {}).get("layers") if isinstance(params, dict) else None
 
     if kv_caches is None:
+        # (with `ragged_dot` the differentiated forward scans the experts
+        # like every other weight: `_expert_xs`)
+        layer_xs, expert_stack = _expert_xs(
+            params["layers"], in_place=use_expert_kernel(config))
+
         def body(carry, inp):
-            layer_params, lora_layer = inp
+            layer_params, lora_layer, *layer = inp
             if layer_transform is not None:
                 layer_params, lora_layer = layer_transform(layer_params, lora_layer)
-            y, _ = _layer_body(config, carry, layer_params, cos, sin, mask, None, 0,
-                               lora_layer, lora_scale, attn_fn=attn_fn)
-            return y, None
+            y, _, aux = _layer_body(config, carry, layer_params, cos, sin, mask,
+                                    None, 0, lora_layer, lora_scale,
+                                    attn_fn=attn_fn, layer=layer[0] if layer else 0,
+                                    expert_stack=expert_stack)
+            return y, aux
 
         if remat:
             if config.remat_policy == "dots":
@@ -663,8 +743,11 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
                     f"remat_policy={config.remat_policy!r}: must be "
                     "'full' or 'dots'"
                 )
-        x, _ = jax.lax.scan(body, x, (params["layers"], lora_layers))
-        return x, None
+        xs = (layer_xs, lora_layers)
+        if expert_stack is not None:    # the layer's index into the stack
+            xs += (jnp.arange(config.num_hidden_layers, dtype=jnp.int32),)
+        x, aux = jax.lax.scan(body, x, xs)
+        return x, None, aux
     else:
         # cache is a tuple of stacked arrays: (k, v) exact, or
         # (k_q, k_s, v_q, v_s) int8. It is the scan's CARRY, not its xs/ys:
@@ -676,20 +759,21 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
         def body(carry, inp):
             y, caches = carry
             layer_params, lora_layer, layer = inp
-            y, caches = _layer_body(
+            y, caches, _ = _layer_body(
                 config, y, layer_params, cos, sin, mask, caches,
                 cache_index, lora_layer, lora_scale,
                 decode_bounds=decode_bounds, verify_bounds=verify_bounds,
-                paged=paged, layer=layer,
+                paged=paged, layer=layer, expert_stack=expert_stack,
             )
             return (y, caches), None
 
+        layer_xs, expert_stack = _expert_xs(params["layers"], in_place=True)
         layers = jnp.arange(kv_caches[0].shape[0], dtype=jnp.int32)
         (x, new_caches), _ = jax.lax.scan(
             body, (x, tuple(kv_caches)),
-            (params["layers"], lora_layers, layers),
+            (layer_xs, lora_layers, layers),
         )
-        return x, new_caches
+        return x, new_caches, None
 
 
 def unembedding(config: ModelConfig, params: dict):
@@ -744,13 +828,19 @@ def model_forward(
 
 
 def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
-                        lora_scale, remat, attn_fn=None, layer_transform=None):
+                        lora_scale, remat, attn_fn=None, layer_transform=None,
+                        router_stats=False):
     """embed → rope → causal+padding mask → scanned layers. The one copy of
     this recipe; every forward entrypoint goes through it.
 
     `attn_fn` overrides the attention contraction (sequence-parallel ring
     path); the local causal mask is then unused — the override builds its own
     mask from global positions.
+
+    `router_stats=True` (expert models) returns `(x, stats)`: the per-row
+    sums of ops/moe.py's `router_stats` over the real tokens, from this very
+    forward (the scan emits each layer's router record; a few reductions,
+    no second forward).
     """
     attention_mask = attention_mask.astype(bool)
     x = params["embed_tokens"][input_ids].astype(params["embed_tokens"].dtype)
@@ -758,9 +848,13 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     cos, sin = rope_tables(position_ids, config.actual_head_dim, config.rope_theta)
     causal = jnp.tril(jnp.ones((T, T), bool))
     mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
-    x, _ = _run_layers(config, params, x, cos, sin, mask,
-                       lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
-                       layer_transform=layer_transform)
+    x, _, aux = _run_layers(config, params, x, cos, sin, mask,
+                            lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
+                            layer_transform=layer_transform)
+    if router_stats:
+        from nanorlhf_tpu.ops.moe import router_stats as reduce_stats
+
+        return x, reduce_stats(aux, attention_mask, config.num_experts)
     return x
 
 
@@ -771,6 +865,7 @@ def _padded_hidden(
     pad_token_id: int,
     lora_scale: float = 1.0,
     remat: bool = False,
+    router_stats: bool = False,
 ) -> jnp.ndarray:
     """Shared padding recipe → pre-final-norm hidden states [B, T, D].
 
@@ -783,7 +878,8 @@ def _padded_hidden(
         query_responses, pad_token_id
     )
     return _hidden_from_inputs(params, config, input_ids, attention_mask,
-                               position_ids, lora_scale, remat)
+                               position_ids, lora_scale, remat,
+                               router_stats=router_stats)
 
 
 def padding_inputs(query_responses: jnp.ndarray, pad_token_id: int):
@@ -804,6 +900,7 @@ def padded_forward_logits(
     lora_scale: float = 1.0,
     remat: bool = False,
     response_context_length: int | None = None,
+    router_stats: bool = False,
 ) -> jnp.ndarray:
     """Padding-robust forward: the reference's shared `forward()` contract.
 
@@ -813,12 +910,18 @@ def padded_forward_logits(
     reference slices logits after computing all of them,
     `GRPO/grpo_trainer.py:546`; at 152k vocab the discarded prompt logits
     are the single largest wasted tensor in the update pass). The shift-by-
-    one next-token convention lives here, in one place.
+    one next-token convention lives here, in one place. `router_stats` as in
+    `padded_forward_hidden`: `(logits, stats)`.
     """
-    x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale, remat)
+    x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale,
+                       remat, router_stats=router_stats)
+    stats = None
+    if router_stats:
+        x, stats = x
     if response_context_length is not None:
         x = x[:, response_context_length - 1 : -1]
-    return _logits(config, params, x)
+    logits = _logits(config, params, x)
+    return (logits, stats) if router_stats else logits
 
 
 def padded_forward_hidden(
@@ -829,6 +932,7 @@ def padded_forward_hidden(
     lora_scale: float = 1.0,
     remat: bool = False,
     response_context_length: int | None = None,
+    router_stats: bool = False,
 ) -> jnp.ndarray:
     """`padded_forward_logits` minus the vocab projection: FINAL-NORMED
     hidden states [B, T', D] — the input the fused hidden→logprob op
@@ -839,11 +943,19 @@ def padded_forward_hidden(
     the response slice happens at the same point (before the head; the final
     RMSNorm is positionwise, so slicing before or after it is equivalent),
     and the shift-by-one next-token convention stays in one place.
+
+    `router_stats=True` (expert models only) returns `(hidden, stats)`, the
+    router's per-row sums over the real tokens (`_hidden_from_inputs`).
     """
-    x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale, remat)
+    x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale,
+                       remat, router_stats=router_stats)
+    stats = None
+    if router_stats:
+        x, stats = x
     if response_context_length is not None:
         x = x[:, response_context_length - 1 : -1]
-    return rms_norm(x, params["norm"], config.rms_norm_eps)
+    x = rms_norm(x, params["norm"], config.rms_norm_eps)
+    return (x, stats) if router_stats else x
 
 
 def init_score_head(config: ModelConfig, key: jax.Array, num_labels: int = 1,
@@ -1004,7 +1116,7 @@ def prefill(
     # queries attend over cache positions [0, T); the rest of T_max is masked
     mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
     mask_full = jnp.zeros((B, 1, T, T_max), bool).at[:, :, :, :T].set(mask)
-    x, new_caches = _run_layers(
+    x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask_full, kv_caches=kv_caches, cache_index=0,
         lora_scale=lora_scale, paged=paged,
     )
@@ -1038,7 +1150,7 @@ def decode_step(
     start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
     filled = jnp.broadcast_to(
         jnp.asarray(cache_index, jnp.int32) + 1, (B,))
-    x, new_caches = _run_layers(
+    x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
         lora_scale=lora_scale, decode_bounds=(start, filled), paged=paged,
     )
@@ -1097,7 +1209,7 @@ def decode_verify(
     cand = (slot >= fill[:, None, None]) & (slot <= fill[:, None, None] + qi)
     mask = (key_mask[:, None, :] | cand)[:, None, :, :]      # [B, 1, Tq, T_max]
     start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
-    x, new_caches = _run_layers(
+    x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches,
         cache_index=fill.astype(jnp.int32), lora_scale=lora_scale,
         verify_bounds=(start, fill.astype(jnp.int32)), paged=paged,

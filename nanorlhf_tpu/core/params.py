@@ -6,6 +6,10 @@ state-dict layout (both families share it — Llama just drops the q/k/v
 biases) onto our scan-friendly stacked tree (core/model.py): per-layer
 tensors are stacked along a leading [L, ...] axis and torch `nn.Linear`
 weights ([out, in]) are transposed to the x @ W layout ([in, out]).
+OLMoE adds (docs/MOE.md): `mlp.experts.{e}.{gate,up,down}_proj.weight` ↔
+`layers.experts.{gate,up,down}_proj.kernel [L, E, in, out]`,
+`mlp.gate.weight` ↔ `layers.router.kernel [L, D, E]`, and
+`self_attn.{q,k}_norm.weight` ↔ `layers.{q,k}_norm`.
 
 Weight fidelity (GQA head layout, tied embeddings, RoPE) is pinned by
 tests/test_model_parity.py against the torch Qwen2 AND Llama
@@ -24,15 +28,27 @@ from nanorlhf_tpu.core.config import ModelConfig
 
 # bias presence is read off the state dict itself (Qwen2 q/k/v carry
 # biases, Llama-family none — both map onto the same optional-bias tree)
-_LINEAR_KEYS = (
+_ATTENTION_KEYS = (
     ("q_proj", "self_attn.q_proj"),
     ("k_proj", "self_attn.k_proj"),
     ("v_proj", "self_attn.v_proj"),
     ("o_proj", "self_attn.o_proj"),
-    ("gate_proj", "mlp.gate_proj"),
-    ("up_proj", "mlp.up_proj"),
-    ("down_proj", "mlp.down_proj"),
 )
+_MLP_KEYS = ("gate_proj", "up_proj", "down_proj")
+_LINEAR_KEYS = _ATTENTION_KEYS + tuple((k, f"mlp.{k}") for k in _MLP_KEYS)
+_NORM_KEYS = (
+    ("input_layernorm", "input_layernorm"),
+    ("post_attention_layernorm", "post_attention_layernorm"),
+)
+_QK_NORM_KEYS = (("q_norm", "self_attn.q_norm"), ("k_norm", "self_attn.k_norm"))
+
+
+def _layer_keys(config: ModelConfig):
+    """(linear, norm) name pairs of the layer tree this config builds: an
+    expert model's MLP is not among the per-layer Linears."""
+    linear = _ATTENTION_KEYS if config.num_experts else _LINEAR_KEYS
+    norm = _NORM_KEYS + (_QK_NORM_KEYS if config.qk_norm else ())
+    return linear, norm
 
 
 def _to_np(t) -> np.ndarray:
@@ -55,17 +71,23 @@ def params_from_hf_state_dict(
     def cast(x):
         return jnp.asarray(x, dtype)
 
+    linear_keys, norm_keys = _layer_keys(config)
     layers: dict = {
-        "input_layernorm": cast(
-            np.stack([sd[f"model.layers.{i}.input_layernorm.weight"] for i in range(L)])
-        ),
-        "post_attention_layernorm": cast(
-            np.stack(
-                [sd[f"model.layers.{i}.post_attention_layernorm.weight"] for i in range(L)]
-            )
-        ),
+        ours: cast(np.stack(
+            [sd[f"model.layers.{i}.{theirs}.weight"] for i in range(L)]))
+        for ours, theirs in norm_keys
     }
-    for ours, theirs in _LINEAR_KEYS:
+    if config.num_experts:
+        layers["router"] = {"kernel": cast(np.stack(
+            [sd[f"model.layers.{i}.mlp.gate.weight"].T for i in range(L)]))}
+        layers["experts"] = {
+            name: {"kernel": cast(np.stack([
+                np.stack([sd[f"model.layers.{i}.mlp.experts.{e}.{name}.weight"].T
+                          for e in range(config.num_experts)])
+                for i in range(L)]))}
+            for name in _MLP_KEYS
+        }
+    for ours, theirs in linear_keys:
         kernel = np.stack(
             [sd[f"model.layers.{i}.{theirs}.weight"].T for i in range(L)]
         )
@@ -102,12 +124,19 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
         sd[name] = jnp.asarray(arr, dtype)
 
     layers = params["layers"]
+    linear_keys, norm_keys = _layer_keys(config)
     for i in range(L):
-        put(f"model.layers.{i}.input_layernorm.weight",
-            layers["input_layernorm"][i])
-        put(f"model.layers.{i}.post_attention_layernorm.weight",
-            layers["post_attention_layernorm"][i])
-        for ours, theirs in _LINEAR_KEYS:
+        for ours, theirs in norm_keys:
+            put(f"model.layers.{i}.{theirs}.weight", layers[ours][i])
+        if config.num_experts:
+            put(f"model.layers.{i}.mlp.gate.weight",
+                layers["router"]["kernel"][i].T)
+            for name in _MLP_KEYS:
+                kernel = layers["experts"][name]["kernel"][i]
+                for e in range(config.num_experts):
+                    put(f"model.layers.{i}.mlp.experts.{e}.{name}.weight",
+                        kernel[e].T)
+        for ours, theirs in linear_keys:
             put(f"model.layers.{i}.{theirs}.weight", layers[ours]["kernel"][i].T)
             if "bias" in layers[ours]:
                 put(f"model.layers.{i}.{theirs}.bias", layers[ours]["bias"][i])
@@ -166,14 +195,16 @@ def export_hf_checkpoint(
 
     # echo the source family when the config carries one (from_hf_config /
     # load_hf_checkpoint set it; a Llama with attention_bias=True must not
-    # round-trip to Qwen2), but only for the two families this exporter can
-    # faithfully emit — an unknown slug (e.g. "mistral") echoed verbatim
+    # round-trip to Qwen2), but only for the families this exporter can
+    # faithfully emit (three with OLMoE) — an unknown slug (e.g. "mistral") echoed verbatim
     # would make transformers' AutoConfig apply that family's defaults
     # (sliding_window, ...) to keys we never write. Anything else falls
     # back to the attention_bias heuristic, as do random-init configs.
-    family = config.model_type if config.model_type in ("qwen2", "llama") \
-        else ("qwen2" if config.attention_bias else "llama")
-    arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM"}[family]
+    family = config.model_type if config.model_type in (
+        "qwen2", "llama", "olmoe") else (
+        "qwen2" if config.attention_bias else "llama")
+    arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
+            "olmoe": "OlmoeForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -192,6 +223,11 @@ def export_hf_checkpoint(
         "hidden_act": "silu",
         "torch_dtype": dtype,
     }
+    if config.num_experts:
+        hf_config.update(num_experts=config.num_experts,
+                         num_experts_per_tok=config.num_experts_per_tok,
+                         norm_topk_prob=config.norm_topk_prob,
+                         clip_qkv=None)
     gen_config = {"_from_model_config": True}
     for key, val in (("eos_token_id", eos_token_id),
                      ("bos_token_id", bos_token_id),

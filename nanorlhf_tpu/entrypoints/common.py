@@ -30,7 +30,8 @@ from nanorlhf_tpu.trainer import RLConfig, RLTrainer
 def resolve_model(sft_model_path: str, seed: int = 0, attention_impl: str = "auto",
                   mesh=None):
     """(ModelConfig, params, tokenizer): HF checkpoint dir → load it; else an
-    offline demo model (1.5B-shaped unless path says 'tiny').
+    offline demo model (1.5B-shaped unless the path says 'tiny', 'llama' or
+    'olmoe').
 
     `mesh`: the offline model is initialised straight into the trainer's
     sharding (one jitted init with `out_shardings`), so no device ever holds
@@ -45,7 +46,9 @@ def resolve_model(sft_model_path: str, seed: int = 0, attention_impl: str = "aut
               "random-init model + toy tokenizer")
         path = (sft_model_path or "").lower()
         llama = "llama" in path  # Llama-family geometry (no attention biases)
-        if "tiny" in path:
+        if "tiny" in path and "olmoe" in path:
+            config = ModelConfig.olmoe_tiny(vocab_size=4096)
+        elif "tiny" in path:
             config = ModelConfig.qwen2_tiny(vocab_size=4096)
             if llama:  # e.g. "TinyLlama-...": tiny shape, llama family
                 config = dataclasses.replace(
@@ -53,6 +56,8 @@ def resolve_model(sft_model_path: str, seed: int = 0, attention_impl: str = "aut
                 )
         elif llama:
             config = ModelConfig.llama3_2_1b()
+        elif "olmoe" in path:
+            config = ModelConfig.olmoe_1b_7b()
         else:
             config = ModelConfig.qwen2_1_5b()
         tokenizer = ToyTokenizer(vocab_size=min(4096, config.vocab_size))

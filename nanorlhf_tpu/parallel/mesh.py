@@ -110,6 +110,15 @@ _RULES = {
     ("layers", "gate_proj", "kernel"): P(None, "fsdp", "tensor"),
     ("layers", "up_proj", "kernel"): P(None, "fsdp", "tensor"),
     ("layers", "down_proj", "kernel"): P(None, "tensor", "fsdp"),
+    # sparse experts [L, E, in, out] (ops/moe.py): the expert axis by tensor,
+    # the contracted width by fsdp; the router is small and every token reads
+    # all of it, so it is replicated (as are OLMoE's q_norm / k_norm, which
+    # fall through to the default). No expert-parallel axis and no
+    # all-to-all yet: ROADMAP R3 brings the four-chip expert cell
+    ("layers", "experts", "gate_proj", "kernel"): P(None, "tensor", "fsdp", None),
+    ("layers", "experts", "up_proj", "kernel"): P(None, "tensor", "fsdp", None),
+    ("layers", "experts", "down_proj", "kernel"): P(None, "tensor", "fsdp", None),
+    ("layers", "router", "kernel"): P(None, None, None),
     ("layers", "input_layernorm"): P(None, None),
     ("layers", "post_attention_layernorm"): P(None, None),
     # LoRA: A shards like the input dim, B like the output dim

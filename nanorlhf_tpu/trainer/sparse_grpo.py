@@ -607,10 +607,12 @@ class SparseGRPOTrainer(RLTrainer):
                                  context_length)
                     ref_logprobs[idxs, :width] = np.asarray(rlp)[: len(idxs)]
                 else:
+                    # (an expert model's chunk scorer appends its router
+                    # sums; the sparse loop logs no moe/* counters)
                     lp, rlp = score_fn(
                         self.params, self.ref_params, jnp.asarray(padded["qr"]),
                         context_length,
-                    )
+                    )[:2]
                     logprobs[idxs, :width] = np.asarray(lp)[: len(idxs)]
                     ref_logprobs[idxs, :width] = np.asarray(rlp)[: len(idxs)]
             if ref_free:

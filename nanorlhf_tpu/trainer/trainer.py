@@ -113,25 +113,33 @@ def forward_token_budget(
 
 def fused_response_logprobs(tree, mcfg, query_responses, responses, pad_id,
                             context_length: int, cfg, lora_scale: float = 1.0,
-                            remat: bool = False, with_entropy: bool = False):
+                            remat: bool = False, with_entropy: bool = False,
+                            router_stats: bool = False):
     """The ONE fused hidden→logprob scorer call (ops/fused_logprob.py):
     response-position hidden states → per-token logprobs (+ entropy), with
     the cfg's chunk/impl knobs applied. Shared by the chunked scoring fns,
     the update-pass microbatch loss, and SparseGRPOTrainer's bucket fns so
-    fused scoring and fused update numerics can never drift apart."""
+    fused scoring and fused update numerics can never drift apart.
+    `router_stats=True` (expert models) returns `(logprobs, stats)`, the
+    router's per-row sums from the same forward (ops/moe.py)."""
     hidden = padded_forward_hidden(
         tree, mcfg, query_responses, pad_id, lora_scale=lora_scale,
         remat=remat, response_context_length=context_length,
+        router_stats=router_stats,
     )
+    stats = None
+    if router_stats:
+        hidden, stats = hidden
     # tied embeddings ride vocab-major ([V, D] + transposed=True): feeding
     # the .T view to the op's Pallas kernel would stage a full [D, V]
     # transposed copy for the custom call
     w, w_transposed = unembedding(mcfg, tree)
-    return fused_logprob(
+    out = fused_logprob(
         hidden, w, responses, cfg.temperature,
         chunk=cfg.fused_logprob_chunk, impl=fused_logprob_impl(cfg, mcfg),
         with_entropy=with_entropy, transposed=w_transposed,
     )
+    return (out, stats) if router_stats else out
 
 
 def fused_logprob_impl(cfg, mcfg) -> str:
@@ -761,6 +769,13 @@ class RLTrainer:
         # int8 rollout weights (core/quant.py): quantize the frozen base
         # projections once under LoRA; full-FT re-quantizes at each dispatch
         self._quant_layers = None
+        if config.rollout_quant == "int8" and self.mcfg.num_experts:
+            raise ValueError(
+                "rollout_quant='int8' with a sparse-expert model: the int8 "
+                "kernels of core/quant.py know dense [in, out] projections "
+                "only; the experts, most of the weights, would stay bf16 "
+                "(docs/MOE.md)"
+            )
         if config.rollout_quant == "int8":
             self._refresh_quant_layers()
         elif config.rollout_quant != "none":
@@ -1543,10 +1558,14 @@ class RLTrainer:
     def _score_chunk_fn(self):
         """Jitted policy+ref logprob scorer for one rollout chunk (cached —
         repeated train() calls must reuse the compiled executable). With an
-        sp mesh axis the forwards run ring-attention sequence-parallel."""
+        sp mesh axis the forwards run ring-attention sequence-parallel.
+        On an expert model (not under sp) the policy forward also returns
+        its router's per-row sums, `(logprobs, ref_logprobs, stats)`: the
+        `moe/*` counters of the update's row (ops/moe.py)."""
         if hasattr(self, "_score_fn_cached"):
             return self._score_fn_cached
         mcfg, cfg = self.mcfg, self.cfg
+        stats = bool(mcfg.num_experts)
         pad_id = self.tokenizer.pad_token_id
         lora_scale = self.lora_scale
 
@@ -1585,11 +1604,14 @@ class RLTrainer:
                 logprobs = fused_response_logprobs(
                     params, mcfg, query_responses, responses, pad_id,
                     context_length, cfg, lora_scale=lora_scale,
+                    router_stats=stats,
                 )
                 ref_logprobs = fused_response_logprobs(
                     ref_params, mcfg, query_responses, responses, pad_id,
                     context_length, cfg,
                 )
+                if stats:
+                    return logprobs[0], ref_logprobs, logprobs[1]
                 return logprobs, ref_logprobs
 
             self._score_fn_cached = score
@@ -1600,29 +1622,38 @@ class RLTrainer:
             responses = query_responses[:, context_length:]
             logits = padded_forward_logits(
                 params, mcfg, query_responses, pad_id, lora_scale=lora_scale,
-                response_context_length=context_length,
+                response_context_length=context_length, router_stats=stats,
             )
+            router = None
+            if stats:
+                logits, router = logits
             logprobs = logprobs_from_logits(logits, responses, cfg.temperature)
             ref_logits = padded_forward_logits(
                 ref_params, mcfg, query_responses, pad_id,
                 response_context_length=context_length,
             )
             ref_logprobs = logprobs_from_logits(ref_logits, responses, cfg.temperature)
+            if stats:
+                return logprobs, ref_logprobs, router
             return logprobs, ref_logprobs
 
         self._score_fn_cached = score
         return score
 
-    def _single_score_fn(self, lora_scale: float = 1.0):
+    def _single_score_fn(self, lora_scale: float = 1.0,
+                         router_stats: bool = False):
         """Single-model logprob scorer (jitted, cached per lora_scale) —
         scores whatever param tree it is handed. lora_scale=1.0 suits the
         (adapter-free) ref tree; pass self.lora_scale to score the POLICY
-        tree, whose adapters must be applied (the ref-free path)."""
+        tree, whose adapters must be applied (the ref-free path).
+        `router_stats` (expert models, not under sp; the training loop asks
+        for it) makes it return `(logprobs, stats)` as `_score_chunk_fn`."""
         cache = getattr(self, "_single_score_cache", None)
         if cache is None:
             cache = self._single_score_cache = {}
-        if lora_scale in cache:
-            return cache[lora_scale]
+        stats = router_stats and bool(self.mcfg.num_experts)
+        if (lora_scale, stats) in cache:
+            return cache[lora_scale, stats]
         mcfg, cfg = self.mcfg, self.cfg
         pad_id = self.tokenizer.pad_token_id
 
@@ -1647,6 +1678,7 @@ class RLTrainer:
                     tree, mcfg, query_responses,
                     query_responses[:, context_length:], pad_id,
                     context_length, cfg, lora_scale=lora_scale,
+                    router_stats=stats,
                 )
         else:
             @partial(jax.jit, static_argnums=(2,))
@@ -1656,10 +1688,14 @@ class RLTrainer:
                     tree, mcfg, query_responses, pad_id,
                     lora_scale=lora_scale,
                     response_context_length=context_length,
+                    router_stats=stats,
                 )
+                if stats:
+                    return (logprobs_from_logits(logits[0], responses,
+                                                 cfg.temperature), logits[1])
                 return logprobs_from_logits(logits, responses, cfg.temperature)
 
-        cache[lora_scale] = score_one
+        cache[lora_scale, stats] = score_one
         return score_one
 
     def _ref_score_fn(self):
@@ -1672,15 +1708,17 @@ class RLTrainer:
         replacement for the two-model chunk scorer."""
         return self._single_score_fn(self.lora_scale)
 
-    def _single_scorer_for(self, capture: bool):
+    def _single_scorer_for(self, capture: bool, router_stats: bool = False):
         """The single-model scorer the scoring loop needs, or None when no
         single-model pass runs: ref-free scores the POLICY (unless capture
         already supplies it — then nothing is left to score), ref-full +
         capture scores the REF, ref-full without capture uses the two-model
-        chunk scorer instead. Shared by the dense and sparse loops."""
+        chunk scorer instead. Shared by the dense and sparse loops; the
+        dense loop asks for an expert model's `router_stats` with it."""
         if self._ref_free:
-            return None if capture else self._policy_score_fn()
-        return self._ref_score_fn() if capture else None
+            return None if capture else self._single_score_fn(
+                self.lora_scale, router_stats)
+        return self._single_score_fn(1.0, router_stats) if capture else None
 
     # ------------------------------------------------------------------ #
     # the training loop
@@ -2163,9 +2201,22 @@ class RLTrainer:
                 // (context_length + cfg.response_length),
             )
             chunk = max(1, min(total, chunk))
-            logprobs_l, ref_logprobs_l = [], []
+            logprobs_l, ref_logprobs_l, router_l = [], [], []
             ref_free = self._ref_free
-            one_fn = self._single_scorer_for(score_capture)
+            one_fn = self._single_scorer_for(score_capture, router_stats=True)
+
+            def scored(out, n_real):
+                """Logprob arrays of one chunk, cut to its real rows; an
+                expert model's scorer appends its router sums (ops/moe.py),
+                which are kept for the row's `moe/*` counters."""
+                out = out if isinstance(out, tuple) else (out,)
+                if isinstance(out[-1], dict):
+                    router_l.append(jax.tree.map(
+                        lambda a: np.asarray(a)[:n_real] if a.ndim
+                        else np.asarray(a), out[-1]))
+                    out = out[:-1]
+                return [np.asarray(a)[:n_real] for a in out]
+
             with self.timer.phase("logprob"):
                 if ref_free and score_capture:
                     # zero scoring forwards: policy logprobs came from the
@@ -2178,20 +2229,23 @@ class RLTrainer:
                         rows_c = jnp.asarray(pad_chunk(qr[i : i + chunk], chunk))
                         if ref_free:
                             # policy-only forward (adapters applied)
-                            lp = one_fn(self.params, rows_c, context_length)
-                            logprobs_l.append(np.asarray(lp)[:n_real])
+                            lp, = scored(one_fn(
+                                self.params, rows_c, context_length), n_real)
+                            logprobs_l.append(lp)
                         elif score_capture:
                             # policy logprobs came from the sampler; only the
                             # ref pass runs — half the scoring forwards
-                            rlp = one_fn(self.ref_params, rows_c, context_length)
-                            ref_logprobs_l.append(np.asarray(rlp)[:n_real])
+                            rlp, = scored(one_fn(
+                                self.ref_params, rows_c, context_length),
+                                n_real)
+                            ref_logprobs_l.append(rlp)
                         else:
-                            lp, rlp = score_fn(
+                            lp, rlp = scored(score_fn(
                                 self.params, self.ref_params, rows_c,
                                 context_length,
-                            )
-                            logprobs_l.append(np.asarray(lp)[:n_real])
-                            ref_logprobs_l.append(np.asarray(rlp)[:n_real])
+                            ), n_real)
+                            logprobs_l.append(lp)
+                            ref_logprobs_l.append(rlp)
             logprobs = (
                 captured_lp if score_capture else np.concatenate(logprobs_l)
             ).astype(np.float32)
@@ -2423,6 +2477,10 @@ class RLTrainer:
             # (serial ≈ 0, rollout_ahead partial, orchestrator highest) —
             # the bench payload's pipelining signal
             metrics["time/rollout_overlap_frac"] = meter.overlap_fraction()
+            if router_l:
+                from nanorlhf_tpu.ops.moe import moe_counters
+
+                metrics.update(moe_counters(router_l))
             metrics.update(self._spec_decode_metrics(ro.get("spec_stats")))
             metrics.update(self._paged_metrics(ro.get("paged_stats")))
             if envp is not None:

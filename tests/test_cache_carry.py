@@ -49,14 +49,14 @@ def _unrolled_run_layers(config, params, x, cos, sin, mask, kv_caches=None,
     layer l sees only its own one-layer stack, as layer 0 of it."""
     outs = []
     for l in range(kv_caches[0].shape[0]):
-        x, new = M._layer_body(
+        x, new, _ = M._layer_body(
             config, x, jax.tree.map(lambda p: p[l], params["layers"]),
             cos, sin, mask, tuple(c[l:l + 1] for c in kv_caches), cache_index,
             None, lora_scale, decode_bounds=decode_bounds,
             verify_bounds=verify_bounds, paged=paged, layer=0,
         )
         outs.append(new)
-    return x, tuple(jnp.concatenate(cs, axis=0) for cs in zip(*outs))
+    return x, tuple(jnp.concatenate(cs, axis=0) for cs in zip(*outs)), None
 
 
 def _forward_chain(config, params, layout, per_row):

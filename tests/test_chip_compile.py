@@ -215,7 +215,8 @@ def _decode_loop(case, one_chip):
     """(lowered program, stacked cache shapes) of a decode loop at
     Qwen2.5-1.5B widths, two layers deep: the benchmark's rollout (16 prompts
     x 4 samples, 256 + 512 slots), the same over an int8 cache, and the
-    serving session's chunk (64 rows, 807 pages of 128)."""
+    serving session's chunk (64 rows, 807 pages of 128); `rollout_olmoe` is
+    the rollout at OLMoE-1B-7B's widths (64 experts, 8 a token)."""
     import dataclasses
 
     from nanorlhf_tpu.core import ModelConfig, init_params
@@ -227,6 +228,8 @@ def _decode_loop(case, one_chip):
         vocab_size=V, hidden_size=D, intermediate_size=8960,
         num_hidden_layers=2, num_attention_heads=H, num_key_value_heads=KV,
         kv_cache_quant="int8" if case == "rollout_int8" else "none")
+    if case == "rollout_olmoe":
+        cfg = dataclasses.replace(ModelConfig.olmoe_1b_7b(), num_hidden_layers=2)
     params = _shapes_on(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)),
         one_chip)
@@ -283,6 +286,44 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     offences, _ = decode_loop_offences(
         hlo, hlo_stacks(cache), slabs_too=case != "rollout_int8")
     assert not offences, "\n".join(offences)
+
+
+def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(
+        v5e, compiled_kernels, monkeypatch):
+    """The rollout at OLMoE's widths (the `grpo-olmoe-r512` cell's shapes,
+    two layers), asked of the chip's compiler: the three expert matmuls of
+    each layer are the grouped-matmul kernel `auto` takes on a TPU (`%gmm`,
+    megablox's, compiled here with its tiles at these widths), not a dense
+    product over all 64 experts for every row; no layer's
+    `[64, 2048, 1024]` slice of an expert stack is copied out for it (a
+    custom call's operands are buffers: sliced by the layer scan, each
+    kernel was copied in every layer of every step, three times the bytes
+    the step has to move; ops/moe.py); and the KV cache stack is carried in
+    place as in the dense rollout."""
+    import re
+
+    from test_cache_carry import decode_loop_offences, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, cache = _decode_loop("rollout_olmoe", SingleDeviceSharding(v5e[0]))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    offences, _ = decode_loop_offences(hlo, hlo_stacks(cache))
+    assert not offences, "\n".join(offences)
+    # prefill + decode loop bodies, three kernels each, under one layer scan
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 6
+    expert_shapes = r"bf16\[(?:\d+,)?64,(?:2048,1024|1024,2048)\]"
+    made = [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(r"= " + expert_shapes, ln)
+            and not re.search(r" (parameter|get-tuple-element|bitcast)\(", ln)]
+    assert not made, "an expert stack or slab is produced, not read:\n" + "\n".join(made)
+    # the cost analysis counts a loop's body once: one layer of the prefill
+    # (16 x 256 tokens) and of a decode step (64 rows), 8 experts a token.
+    # The whole program is 1.45 x that (attention, head); a dense product
+    # over all 64 experts would be 8 x the routed part
+    flops = compiled.cost_analysis()["flops"]
+    routed = (16 * 256 + 64) * 8 * 3 * 2 * 2048 * 1024
+    assert routed < flops < 2.5 * routed, (flops, routed)
 
 
 def test_chip_smoke_refuses_a_cpu_backend():
