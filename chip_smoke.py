@@ -237,8 +237,9 @@ def bf16_agreement(phase: Phase, tested, plain, float32, real, names) -> dict:
 def phase_kernels(sz: Sizes, mcfg, meter: Meter) -> None:
     """Each Pallas kernel of the main path against its XLA reference, at the
     model's head geometry. `auto` reaches the contiguous decode kernels only
-    from cache length 2048 and the paged/q8/verify twins only under options
-    the launcher leaves off, so this phase is what runs them on the chip."""
+    from cache length 2048 and the q8/verify twins only under options the
+    launcher leaves off, so this phase is what runs them on the chip (the
+    paged in-place read is also what the serve phase decodes through)."""
     import jax
     import jax.numpy as jnp
 
@@ -319,9 +320,21 @@ def phase_kernels(sz: Sizes, mcfg, meter: Meter) -> None:
             pages.reshape(B * nb, KV, 8, P))
 
     kp, vp = pool(kc), pool(vc)
+    # the in-place read takes whole stacks: the pools as the middle layer of
+    # three, every third row dead (it reads zero and is left out)
+    live = jnp.arange(B) % 3 != 2
+    stack = [jnp.stack([jnp.zeros_like(p_), p_, jnp.ones_like(p_)])
+             for p_ in (kp, vp)]
+    plan = dec.paged_decode_plan(
+        table, start, filled, page_size=P, num_pages=N,
+        pages_per_item=dec.paged_pages_per_item(stack[0]), live=live)
+    in_place = jax.jit(dec.paged_decode_attention)(qd, *stack, jnp.int32(1), plan)
+    check(not bool(jnp.any(in_place[~live])), "a dead row did not read zero")
     errs["paged_decode"] = rel(
-        dec.paged_decode_attention(qd, kp, vp, table, start, filled),
-        dec.reference_paged_decode_attention(qd, kp, vp, table, start, filled))
+        in_place[live],
+        dec.reference_paged_decode_attention(qd, kp, vp, table, start,
+                                             filled)[live])
+    del stack
     errs["paged_verify"] = rel(
         dec.paged_decode_verify_attention(qv, kp, vp, table, start, fill),
         dec.reference_paged_decode_verify_attention(qv, kp, vp, table, start,
@@ -554,7 +567,7 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from nanorlhf_tpu.core.model import padded_forward_logits, use_decode_kernel
+    from nanorlhf_tpu.core.model import padded_forward_logits, use_paged_decode_kernel
     from nanorlhf_tpu.sampler import SamplingParams, generate
     from nanorlhf_tpu.serving.engine import ServingEngine
     from nanorlhf_tpu.serving.gateway import ServingGateway
@@ -591,7 +604,7 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
         lora_scale=lora_scale, seed=sz.seed)
     gateway = ServingGateway(engine, port=-1)
     base = f"http://127.0.0.1:{gateway.port}"
-    paged_kernel = use_decode_kernel(mcfg.attention_impl, engine.T_max)
+    paged_kernel = use_paged_decode_kernel(mcfg)
 
     def get(path):
         return urllib.request.urlopen(base + path, timeout=60).read().decode()
@@ -692,7 +705,7 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
         tokens_returned=sum(len(a) for a in answers.values()),
         radix={k: radix[k] for k in ("hit_tokens", "cow_splits", "nodes",
                                      "shared_pages_acquired")},
-        auto={"decode_attention": "pallas-paged" if paged_kernel
+        auto={"decode_attention": "pallas-paged-in-place" if paged_kernel
               else "xla-gathered-view"},
         greedy_vs_generate=agreement)
 

@@ -66,13 +66,13 @@ class ModelConfig:
     # "xla": einsum attention fused by XLA everywhere.
     # "pallas": blockwise flash kernel (ops/attention.py) on self-attention
     #   paths + prefix-bounded decode kernel (ops/decode_attention.py).
-    # "auto" (default): picks per call site from real-TPU v5e sweeps — flash
-    #   at padded T >= _FLASH_AUTO_MIN_T (pallas-512 beats XLA 1.4x at T=512
-    #   and 21x at T=8192; ties below), decode kernel at cache
-    #   T_max >= _DECODE_AUTO_MIN_T (XLA's single fused matmul wins on short
-    #   caches; prefix-skip bandwidth wins on long ones). Off-TPU backends
-    #   always resolve to XLA (interpret-mode Pallas is a test vehicle, not
-    #   an execution path).
+    # "auto" (default): picks per call site (core/model.py's `use_*`): flash
+    #   at padded T >= _FLASH_AUTO_MIN_T, the contiguous decode kernel at
+    #   cache T_max >= _DECODE_AUTO_MIN_T (neither crossover has a record:
+    #   ROADMAP S5), the paged in-place decode read and the grouped expert
+    #   matmul on a TPU with no multi-device mesh at every size. Off-TPU
+    #   backends always resolve to XLA (interpret-mode Pallas is a test
+    #   vehicle, not an execution path).
     attention_impl: str = "auto"
     # Rematerialization policy for the training forward when gradient
     # checkpointing is on ("full" = jax.checkpoint default, save nothing and

@@ -135,12 +135,14 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     return (xf * cos + rf * sin).astype(x.dtype)
 
 
-# "auto" thresholds, from real TPU v5e sweeps (fwd+bwd, Qwen2-1.5B head
-# geometry): pallas-512 flash ties XLA at T=256 and wins from T=512 up
-# (11.9→7.4ms at T=512; 371→17ms at T=8192). The decode kernel's
-# prefix-bounded reads only pay off once the cache is large enough that
-# skipped HBM traffic beats its finer-grained grid (XLA decode is one fused
-# masked matmul and wins on short caches).
+# "auto" thresholds of the CONTIGUOUS cache. Neither crossover has a record:
+# the sweeps this comment used to cite (pallas-512 flash tying XLA at T=256
+# and winning from 512; the decode kernel paying off from 2,048 slots) are
+# in no ledger line and no PERF.md entry (ROADMAP S5). What the records do
+# back (PERF.md sections 5 and 6): under 2,048 slots XLA's decode attention
+# fuses both cache reads into the QK and PV matmuls and streams the slab at
+# ~725 GB/s (PR 26, T_max 768), over all T_max slots whatever is filled.
+# The PAGED single-token read has no threshold (`use_paged_decode_kernel`).
 _FLASH_AUTO_MIN_T = 512
 _DECODE_AUTO_MIN_T = 2048
 
@@ -182,6 +184,20 @@ def use_expert_kernel(config: ModelConfig) -> bool:
     impl = config.attention_impl
     return impl == "pallas" or (impl == "auto"
                                 and jax.default_backend() == "tpu")
+
+
+def use_paged_decode_kernel(config: ModelConfig) -> bool:
+    """Resolve the PAGED single-token decode read: the kernel that reads the
+    rows' pages from the stacked pool in place
+    (ops/decode_attention.paged_decode_attention), under `use_expert_kernel`'s
+    rule and for its reason (a Mosaic kernel, which GSPMD will not
+    partition), at every cache width: the alternative, `_paged_view`, gathers
+    and transposes every row's every page per layer per step whatever is
+    live (36 % of the serving cell's device time, PERF.md PR 28), so there
+    is no width at which it wins. `"xla"`, off the TPU and under a mesh the
+    gathered view stays: the plain form, and the kernel's oracle. The int8
+    cache has its own kernel (`use_q8_decode_kernel`)."""
+    return config.kv_cache_quant != "int8" and use_expert_kernel(config)
 
 
 def _kernel_spmd(config: ModelConfig, H: int, KV: int):
@@ -419,8 +435,11 @@ def _layer_slab(stack, layer):
 
 def _paged_view(pool, layer, table, width):
     """Gather a row-contiguous [B, KV, width, hd] view of layer `layer` from
-    the stacked pool [L, num_pages, KV, P, hd] — the off-TPU read path and
-    the XLA path under the kernel threshold. The gather reads the rows' pages
+    the stacked pool [L, num_pages, KV, P, hd] — the plain form of the paged
+    read (`"xla"`, off the TPU, under a mesh; the in-place kernel's oracle)
+    and the T > 1 reads' under the kernel threshold. It moves every table
+    entry of every row, whatever is live, so the TPU's decode step does not
+    take it (`use_paged_decode_kernel`). The gather reads the rows' pages
     straight from the stack: no layer slab is sliced out first. Sentinel
     entries clamp to page num_pages-1; the garbage they surface sits in slots
     the attention mask already excludes, and NEG_INF masking zeroes its
@@ -495,13 +514,17 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     unaffected).
     `paged=(block_table [B, nb] int32, page_size)` switches the cache to the
     paged layout (init_paged_kv_cache): writes scatter through the table
-    with `mode="drop"` (sentinel/over-budget slots discard), reads go to the
-    paged Pallas kernels on TPU or a gathered row-contiguous view sliced to
-    the mask width elsewhere — the view path reuses the exact same masked
+    with `mode="drop"` (sentinel/over-budget slots discard). The single-token
+    decode read is the in-place kernel on a TPU (`use_paged_decode_kernel`:
+    the whole stacks are its operands, `layer` a scalar, `decode_bounds` the
+    step's `PagedDecodePlan`), and elsewhere a gathered row-contiguous view
+    sliced to the mask width (`_paged_view`), as are the T > 1 reads under
+    the kernel threshold — the view path reuses the exact same masked
     gqa_attention math as the contiguous cache, which is what makes paged
     generation bit-identical to contiguous on the CPU mesh (test-pinned).
-    The paged kernels skip the shard_map wrap (`_spmd_call` shards arg dim 0,
-    which for pools is pages, not batch); GSPMD partitions them instead.
+    The int8 and verify paged kernels take the layer's slab and skip the
+    shard_map wrap (`_spmd_call` shards arg dim 0, which for pools is pages,
+    not batch); GSPMD partitions them instead.
     """
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
@@ -644,27 +667,29 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             # T_max-padded cache
             out = gqa_attention(q, k, v, mask[..., :T], impl="pallas",
                                 mask_is_causal_x_keyvalid=True, spmd=spmd)
-        elif (T == 1 and decode_bounds is not None
+        elif (T == 1 and decode_bounds is not None and paged is not None
+              and use_paged_decode_kernel(config)):
+            # paged decode on a TPU: the rows' live pages are read from the
+            # stacks in place, the layer a scalar; `decode_bounds` is the
+            # step's work list (`decode_step` made it under the same rule)
+            from nanorlhf_tpu.ops.decode_attention import (
+                paged_decode_attention,
+            )
+
+            out = paged_decode_attention(
+                q[:, :, 0, :], *new_cache, layer, decode_bounds)[:, :, None, :]
+        elif (T == 1 and decode_bounds is not None and paged is None
               and use_decode_kernel(config.attention_impl, cache_len)):
             # decode: prefix-bounded Pallas kernel reads only the filled
             # cache range instead of the masked T_max square
-            if paged is not None:
-                from nanorlhf_tpu.ops.decode_attention import (
-                    paged_decode_attention,
-                )
+            from nanorlhf_tpu.ops.decode_attention import decode_attention
 
-                out = paged_decode_attention(
-                    q[:, :, 0, :], k_cache, v_cache, paged[0],
-                    *decode_bounds)[:, :, None, :]
+            dec_args = (q[:, :, 0, :], k_cache, v_cache) + tuple(decode_bounds)
+            if spmd is not None:
+                out = _spmd_call(spmd, decode_attention, dec_args,
+                                 (1, 1, 1, None, None))[:, :, None, :]
             else:
-                from nanorlhf_tpu.ops.decode_attention import decode_attention
-
-                dec_args = (q[:, :, 0, :], k_cache, v_cache) + tuple(decode_bounds)
-                if spmd is not None:
-                    out = _spmd_call(spmd, decode_attention, dec_args,
-                                     (1, 1, 1, None, None))[:, :, None, :]
-                else:
-                    out = decode_attention(*dec_args)[:, :, None, :]
+                out = decode_attention(*dec_args)[:, :, None, :]
         else:
             kd, vd = _kv_views(mask.shape[-1])
             out = gqa_attention(q, kd, vd, mask)
@@ -1137,6 +1162,9 @@ def decode_step(
     lora_scale: float = 1.0,
     page_table=None,              # [B, nb] int32 (paged layout)
     page_size: int = 0,
+    live=None,                    # [B] bool: rows whose logits the caller
+                                  # uses (None: all). The paged in-place read
+                                  # skips the others; nothing else looks
 ):
     """One autoregressive decode step. Returns (logits [B, V], new caches)."""
     B = token.shape[0]
@@ -1150,9 +1178,20 @@ def decode_step(
     start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
     filled = jnp.broadcast_to(
         jnp.asarray(cache_index, jnp.int32) + 1, (B,))
+    bounds = (start, filled)
+    if paged is not None and use_paged_decode_kernel(config):
+        # the step's work list for the in-place read, once for every layer
+        from nanorlhf_tpu.ops.decode_attention import (
+            paged_decode_plan, paged_pages_per_item,
+        )
+
+        bounds = paged_decode_plan(
+            page_table, start, filled, page_size=page_size,
+            num_pages=kv_caches[0].shape[1],
+            pages_per_item=paged_pages_per_item(kv_caches[0]), live=live)
     x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
-        lora_scale=lora_scale, decode_bounds=(start, filled), paged=paged,
+        lora_scale=lora_scale, decode_bounds=bounds, paged=paged,
     )
     logits = _logits(config, params, x)[:, 0, :]
     return logits, new_caches
@@ -1208,7 +1247,10 @@ def decode_verify(
     qi = jnp.arange(Tq)[None, :, None]                       # [1, Tq, 1]
     cand = (slot >= fill[:, None, None]) & (slot <= fill[:, None, None] + qi)
     mask = (key_mask[:, None, :] | cand)[:, None, :, :]      # [B, 1, Tq, T_max]
-    start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
+    # first valid slot; a row with no valid prefix (a cold serving admission
+    # starts at its first real token) begins at its own candidates
+    start = jnp.where(key_mask.any(axis=1), jnp.argmax(key_mask, axis=1),
+                      fill).astype(jnp.int32)
     x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches,
         cache_index=fill.astype(jnp.int32), lora_scale=lora_scale,

@@ -26,6 +26,7 @@ the win is skipped traffic, not FLOPs.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -470,15 +471,19 @@ def decode_attention(
 # through a per-row block table instead of sitting in a per-row slab
 # --------------------------------------------------------------------------- #
 #
-# Pool layout (core/model.py:init_paged_kv_cache, per layer): [num_pages, KV,
+# Pool layout (core/model.py:init_paged_kv_cache): [L, num_pages, KV,
 # page_size, hd]; block table: [B, n_blocks] int32 mapping logical block j of
 # row b to a physical page (sentinel num_pages = unallocated, clamped here).
-# The kernel bodies are UNCHANGED — positions are logical (`actual_j * block_k
-# + iota` with block_k = page_size), only the BlockSpec index maps change: the
-# table rides along as a third scalar-prefetch operand and the kv index map
-# resolves logical block → physical page before the DMA is issued. The same
-# clamp-to-last-valid-block trick applies, so revisited blocks still skip the
-# re-fetch and HBM traffic stays proportional to the filled prefix.
+#
+# The single-token read (`paged_decode_attention`, ISSUE 28) takes the whole
+# stacks and reads pages in place: its cost follows the live rows' live pages.
+# The int8 and verify twins below take ONE layer's pool [num_pages, KV, P, hd]
+# and keep the contiguous kernels' bodies UNCHANGED — positions are logical
+# (`actual_j * block_k + iota` with block_k = page_size), only the BlockSpec
+# index maps change: the table rides along as a third scalar-prefetch operand
+# and the kv index map resolves logical block → physical page before the DMA
+# is issued. The same clamp-to-last-valid-block trick applies, so revisited
+# blocks still skip the re-fetch (the grid step itself is not skipped).
 #
 # NOTE on tiling: block_k here is the page size, so the pool's (page_size, hd)
 # trailing dims must satisfy the dtype's min tile — page_size ≥ 8 for f32,
@@ -530,15 +535,6 @@ def reference_paged_decode_verify_attention(q, k_pool, v_pool, table, start,
         start, fill)
 
 
-def _paged_decode_kernel(start_ref, filled_ref, table_ref, q_ref, k_ref,
-                         v_ref, o_ref, acc_ref, m_ref, l_ref,
-                         *, scale: float, block_k: int):
-    # the table is consumed by the index maps only — the body is identical
-    del table_ref
-    _decode_kernel(start_ref, filled_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, scale=scale, block_k=block_k)
-
-
 def _paged_decode_q8_kernel(start_ref, filled_ref, table_ref, q_ref, kq_ref,
                             ks_ref, vq_ref, vs_ref, o_ref, acc_ref, m_ref,
                             l_ref, *, scale: float, block_k: int):
@@ -576,57 +572,217 @@ def _paged_kv_index_map(num_pages, page_size, last_offset=-1):
     return kv_index_map
 
 
+class PagedDecodePlan(NamedTuple):
+    """What one decode step reads of the page pool, the same for every layer
+    (`paged_decode_plan`): a flat list of work items over the LIVE rows' live
+    blocks only, rows in order; an item is up to `paged_pages_per_item`
+    consecutive blocks of one row."""
+    row_off: jnp.ndarray    # [B + 1] items of row b are [row_off[b], row_off[b+1])
+    item_row: jnp.ndarray   # [B * ceil(nb / C)]
+    item_blk: jnp.ndarray   # same: the item's first logical block
+    table: jnp.ndarray      # [B, nb] the block table (sentinel = num_pages)
+    start: jnp.ndarray      # [B] first valid logical slot
+    filled: jnp.ndarray     # [B] one past the last valid logical slot
+
+
+# one work item's K (or V) pages fill at most this much of a buffer slot
+_PAGED_ITEM_BYTES = 512 << 10
+_PAGED_ITEM_PAGES = 4
+
+
+def paged_pages_per_item(pool) -> int:
+    """Pages one work item of `paged_decode_attention` covers, from the
+    pool's geometry. An item's fixed cost (DMA issue and wait, the softmax's
+    reductions, the branch on first/last) is paid once for all its pages, so
+    small pages are taken several at a time (Qwen2.5: 64 KB a page, 4 an
+    item); a page of many heads is an item by itself (OLMoE: 512 KB)."""
+    _, _, KV, P, hd = pool.shape
+    return max(1, min(_PAGED_ITEM_PAGES,
+                      _PAGED_ITEM_BYTES // (KV * P * hd * pool.dtype.itemsize)))
+
+
+def paged_decode_plan(table, start, filled, *, page_size: int, num_pages: int,
+                      pages_per_item: int, live=None) -> PagedDecodePlan:
+    """Work list of `paged_decode_attention` for one decode step. A row
+    contributes its blocks `[start // P, (filled - 1) // P]`, cut into items
+    of `pages_per_item`, or nothing: a row with an empty range, a row whose
+    last such block is the table's sentinel (released: it holds no pages)
+    and a row the caller marks not `live` (its output is discarded anyway)
+    cost the kernel no work and read zero. table: [B, nb] int32;
+    start/filled: [B]; live: [B] bool or None."""
+    B, nb = table.shape
+    C = pages_per_item
+    start, filled = start.astype(jnp.int32), filled.astype(jnp.int32)
+    first = start // page_size
+    last = jnp.clip((filled - 1) // page_size, 0, nb - 1)
+    has = (filled > start) & (
+        jnp.take_along_axis(table, last[:, None], axis=1)[:, 0] < num_pages)
+    if live is not None:
+        has = has & live
+    n = jnp.where(has, (last - first) // C + 1, 0)
+    row_off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                               jnp.cumsum(n, dtype=jnp.int32)])
+    i = jnp.arange(B * pl.cdiv(nb, C), dtype=jnp.int32)
+    # rows whose items end at or before i; items past the last are never read
+    row = jnp.minimum(
+        jnp.sum(i[:, None] >= row_off[None, 1:], axis=1, dtype=jnp.int32),
+        B - 1)
+    blk = jnp.clip(first[row] + (i - row_off[row]) * C, 0, nb - 1)
+    return PagedDecodePlan(row_off, row, blk, table.astype(jnp.int32), start,
+                           filled)
+
+
+def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
+                         start_ref, filled_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, sem, acc_ref, m_ref, l_ref,
+                         *, scale: float, n_rows: int):
+    """One tile of rows: walk the tile's work items. An item is up to C
+    consecutive pages of one row, each page one DMA for ALL kv heads
+    ([KV, P, hd] is contiguous in the pool), fetched from the stack in HBM
+    into a two-slot buffer while the previous item is computed; online
+    softmax per (row, kv head) as in `_decode_kernel`."""
+    tile_rows, KV, Gp, _ = q_ref.shape
+    _, _, C, P, hd = kbuf.shape
+    num_pages, nb = k_hbm.shape[1], table_ref.shape[1]
+    r0 = pl.program_id(0) * tile_rows
+    lo = off_ref[r0]
+    hi = off_ref[jnp.minimum(r0 + tile_rows, n_rows)]
+    layer = layer_ref[0]
+
+    def pages(i, act):
+        """Start or wait for the copies of item i's pages into slot i % 2."""
+        slot, row, blk = i % 2, row_ref[i], blk_ref[i]
+        n = (filled_ref[row] - 1) // P - blk + 1
+        for c in range(C):
+            @pl.when(c < n)
+            def _page():
+                page = jnp.minimum(
+                    table_ref[row, jnp.minimum(blk + c, nb - 1)], num_pages - 1)
+                for j, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                    act(pltpu.make_async_copy(
+                        pool.at[layer, page], buf.at[slot, :, c],
+                        sem.at[j, slot]))
+
+    # rows without items (dead, released, padding) read zero; a page an item
+    # does not fetch is masked, so what the V buffer holds there must be finite
+    o_ref[...] = jnp.zeros_like(o_ref)
+    if C > 1:
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(lo < hi)
+    def _first_fetch():
+        pages(lo, lambda copy: copy.start())
+
+    def item(i, carry):
+        @pl.when(i + 1 < hi)
+        def _next_fetch():
+            pages(i + 1, lambda copy: copy.start())
+
+        row, blk, slot = row_ref[i], blk_ref[i], i % 2
+        r = row - r0
+        start, filled = start_ref[row], filled_ref[row]
+
+        @pl.when(blk == start // P)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        pages(i, lambda copy: copy.wait())
+        pos = blk * P + jax.lax.broadcasted_iota(jnp.int32, (Gp, C * P), 1)
+        valid = (pos >= start) & (pos < filled)
+        for h in range(KV):
+            s = jax.lax.dot_general(
+                q_ref[r, h], kbuf[slot, h].reshape(C * P, hd),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                        # [Gp, C * P]
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h, :, :1]
+            l_prev = l_ref[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            v = vbuf[slot, h].reshape(C * P, hd)
+            # probabilities enter the PV product in the cache's dtype, as in
+            # the plain path (`gqa_attention`); the sums stay float32
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        @pl.when(blk + C > (filled - 1) // P)
+        def _finalize():
+            l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+            o_ref[r] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(lo, hi, item, None)
+
+
+# a tile of rows keeps its queries and outputs in VMEM: at most this many
+# bytes each (Qwen2.5's 64 serving rows are one tile of 0.5 MB)
+_PAGED_TILE_BYTES = 1 << 20
+
+
 def paged_decode_attention(
-    q: jnp.ndarray,       # [B, H, hd] — single decode position
-    k_pool: jnp.ndarray,  # [N, KV, P, hd] global page pool
-    v_pool: jnp.ndarray,  # [N, KV, P, hd]
-    table: jnp.ndarray,   # [B, nb] int32 block table (sentinel = N)
-    start: jnp.ndarray,   # [B] int32: first valid logical slot
-    filled: jnp.ndarray,  # [B] int32: one past the last valid logical slot
+    q: jnp.ndarray,        # [B, H, hd], the single decode position
+    k_pool: jnp.ndarray,   # [L, N, KV, P, hd], the WHOLE stacked page pool
+    v_pool: jnp.ndarray,   # [L, N, KV, P, hd]
+    layer,                 # scalar int32: which layer of the stack
+    plan: PagedDecodePlan,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Prefix-bounded decode attention over the paged KV cache: the grid
-    walks logical blocks [start//P, (filled-1)//P] and the index map routes
-    each through the block table, so a row's pages may be scattered anywhere
-    in the pool. Returns [B, H, hd]."""
+    """Single-token decode attention that reads K and V pages from the
+    stacked pool in place. The stacks stay in HBM as they are (a Pallas
+    operand is a buffer: a layer's slab handed over would be copied out of
+    the stack per layer per step) and the layer rides as a scalar; the
+    kernel's cost follows `plan`'s items, live rows x live pages, not
+    `B x KV x n_blocks`. Mask per row `[start, filled)`, float32 scores and
+    softmax state. Rows without items read zero. `plan` is made with
+    `paged_pages_per_item(k_pool)`. Returns [B, H, hd]."""
     B, H, hd = q.shape
-    N, KV, P, _ = k_pool.shape
-    nb = table.shape[1]
+    _, _, KV, P, _ = k_pool.shape
+    C = paged_pages_per_item(k_pool)
     G = H // KV
-    Gp = max(8, G)
+    sub = 32 // q.dtype.itemsize          # sublanes of one tile of this dtype
+    Gp = sub * pl.cdiv(G, sub)
+    tile_rows = max(1, min(B, _PAGED_TILE_BYTES
+                           // (KV * Gp * hd * q.dtype.itemsize)))
+    n_tiles = pl.cdiv(B, tile_rows)
 
-    qg = q.reshape(B, KV, G, hd)
-    if Gp != G:
-        qg = jnp.pad(qg, [(0, 0), (0, 0), (0, Gp - G), (0, 0)])
-
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=1.0 / (hd ** 0.5), block_k=P
-    )
+    qg = jnp.pad(q.reshape(B, KV, G, hd),
+                 [(0, n_tiles * tile_rows - B), (0, 0), (0, Gp - G), (0, 0)])
+    kernel = functools.partial(_paged_decode_kernel, scale=1.0 / (hd ** 0.5),
+                               n_rows=B)
+    rows_spec = pl.BlockSpec((tile_rows, KV, Gp, hd),
+                             lambda t, *_: (t, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, KV, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, Gp, hd),
-                         lambda b, kv, j, s, f, t: (b, kv, 0, 0)),
-            pl.BlockSpec((1, 1, P, hd), _paged_kv_index_map(N, P)),
-            pl.BlockSpec((1, 1, P, hd), _paged_kv_index_map(N, P)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Gp, hd),
-                               lambda b, kv, j, s, f, t: (b, kv, 0, 0)),
+        num_scalar_prefetch=7,
+        grid=(n_tiles,),
+        in_specs=[rows_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=rows_spec,
         scratch_shapes=[
-            pltpu.VMEM((Gp, hd), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
+            pltpu.VMEM((2, KV, C, P, hd), k_pool.dtype),
+            pltpu.VMEM((2, KV, C, P, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, Gp, hd), jnp.float32),
+            pltpu.VMEM((KV, Gp, 128), jnp.float32),
+            pltpu.VMEM((KV, Gp, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, Gp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=_interpret_default() if interpret is None else interpret,
-    )(start.astype(jnp.int32), filled.astype(jnp.int32),
-      table.astype(jnp.int32), qg, k_pool, v_pool)
-    return out[:, :, :G, :].reshape(B, H, hd)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *plan, qg, k_pool, v_pool)
+    return out[:B, :, :G, :].reshape(B, H, hd)
 
 
 def paged_decode_attention_q8(

@@ -67,7 +67,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nanorlhf_tpu.core.model import decode_step, decode_verify, prefill
+from nanorlhf_tpu.core.model import (
+    decode_step, decode_verify, prefill, use_paged_decode_kernel,
+)
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
     PageState, alloc_row, blocks_per_row, full_table, release_row,
@@ -155,9 +157,11 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
     slot = Tp + n_gen - 1                      # [R] cache slot of cur_tok
     key_mask = key_mask.at[rows, slot].set(True)
     position = prompt_len + n_gen - 1
+    live = ~done
     logits, caches = decode_step(
         params, config, cur_tok, position, slot, key_mask, caches,
         lora_scale=lora_scale, page_table=table, page_size=page_size,
+        live=live,
     )
     if row_params is None:
         tok = _sample_token(jax.random.fold_in(key, it), logits, temperature,
@@ -170,7 +174,6 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
                               approx_top_k=approx_top_k)
         limit = r_budget
     tok = jnp.where(done, pad_token_id, tok)
-    live = ~done
     wpos = jnp.where(live, n_gen, max_tokens)  # done rows drop their write
     out = out.at[rows, wpos].set(tok, mode="drop")
     if capture_logprobs:
@@ -540,6 +543,17 @@ class DecodeSession:
         self._kelems: list = [None] * R       # radix keys of resident rows
         self._pending: list[_PendingPrefill] = []
 
+        # what the paged decode read touches (`_count_attention`): the
+        # resident rows' first slot and depth as the scheduler's own events
+        # give them, never read back from the device
+        self._row_start_np = np.zeros((R,), np.int64)
+        self._row_gen_np = np.zeros((R,), np.int64)
+        self._row_live_np = np.zeros((R,), bool)
+        self.attn_live_pages = 0
+        self.attn_table_pages = 0
+        self.attn_in_place = int(not self.spec
+                                 and use_paged_decode_kernel(config))
+
         # dispatch accounting (module docstring): launches = model
         # forwards outside the decode/verify loop; decode iterations come
         # from the carry's own counter
@@ -590,6 +604,9 @@ class DecodeSession:
         self._prompt_res_np[:] = np.asarray(prompt_ids[:R])
         self._prompt_rep = jnp.asarray(self._prompt_res_np)
         self._it_prev = int(self.state[0]) - 1
+        self._row_start_np[:] = self.Tp - np.asarray(prompt_mask[:R]).sum(1)
+        self._row_gen_np[:] = 1
+        self._row_live_np[:] = True
 
     def admit(self, r: int, toks_np, mask_np, admit_index: int, *,
               budget=None, temperature=None, top_p=None, greedy=None,
@@ -743,6 +760,9 @@ class DecodeSession:
             self._topp_np[r] = p.top_p
             self._greedy_np[r] = p.greedy
             self._budget_np[r] = int(p.budget)
+        self._row_start_np[r] = p.pad_count
+        self._row_gen_np[r] = 1
+        self._row_live_np[r] = not self.per_row or int(p.budget) > 1
         if self.spec:
             self._prompt_res_np[r] = p.toks
             self._prompt_rep = jnp.asarray(self._prompt_res_np)
@@ -860,8 +880,33 @@ class DecodeSession:
                 self._hub.record("latency/intertoken_s",
                                  (time.perf_counter() - t0)
                                  / (it_now - self._it_prev))
+        self._count_attention(it_now - self._it_prev, done_h)
         self._it_prev = it_now
         return done_h, installed
+
+    def _count_attention(self, its: int, done_h) -> None:
+        """Never-reset `attn_live_pages` / `attn_table_pages`, summed over
+        the `its` decode steps of the chunk just synced: the pages the
+        in-place read touches (a live row's blocks from its first real slot
+        to the slot it writes) against the `rows x blocks` the gathered view
+        builds whatever is live; `live / table` is the share of the view the
+        kernel has to read. From the host's own record of each row (pad
+        count, tokens so far, budget); a row that ends on EOS inside a chunk
+        is counted to its budget or the chunk's end. Speculative sessions
+        verify, they take no single-token step: not counted."""
+        if self.spec or its <= 0:
+            return
+        limit = self._budget_np if self.per_row else self.max_tokens
+        steps = np.where(self._row_live_np,
+                         np.minimum(its, limit - self._row_gen_np), 0)
+        first = self._row_start_np // self.page_size
+        for s in range(its):
+            last = (self.Tp + self._row_gen_np + s - 1) // self.page_size
+            self.attn_live_pages += int(
+                np.sum(np.where(steps > s, last - first + 1, 0)))
+        self.attn_table_pages += its * self.rows * self.nb
+        self._row_gen_np += steps
+        self._row_live_np &= ~done_h
 
     # ------------------------------------------------------------- #
     # release / introspection
@@ -915,6 +960,7 @@ class DecodeSession:
         free its pages — mirrors the completion path exactly so a
         disconnect can never leak what a completion would have freed."""
         self._pending = [p for p in self._pending if p.row != r]
+        self._row_live_np[r] = False
         s = list(self.state)
         s[5] = s[5].at[r].set(True)
         self.state = tuple(s)

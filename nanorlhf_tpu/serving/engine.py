@@ -431,6 +431,9 @@ class ServingEngine:
             "serving/cow_splits": snap["cow_splits"],
             "serving/evicted_pages": snap["evicted_pages"],
             "serving/prefill_token_dispatch": self._sess.dispatch_tokens,
+            "serving/attn_live_pages": self._sess.attn_live_pages,
+            "serving/attn_table_pages": self._sess.attn_table_pages,
+            "serving/attn_in_place": self._sess.attn_in_place,
             "pages/shared": snap["shared_pages"],
         }
         for reason, n in sorted(reasons.items()):

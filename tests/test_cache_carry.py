@@ -194,6 +194,21 @@ def hlo_stacks(caches):
             for c in caches}
 
 
+def decode_loop_reach(hlo):
+    """(computations, sorted names of those a decode loop reaches, fusions
+    included). A decode loop is a `while` whose body holds the layer scan's
+    `while`."""
+    comps = _computations(hlo)
+    assert comps, "no computation parsed from the HLO text"
+    bodies = {re.search(r"body=%?([\w.\-]+)", rest).group(1)
+              for instrs in comps.values() for _, _, op, rest in instrs
+              if op == "while"}
+    loops = [b for b in bodies
+             if any(op == "while" for _, _, op, _ in comps[b])]
+    assert loops, "no decode loop (a while around the layer scan) found"
+    return comps, sorted(set().union(*(_reachable(comps, b) for b in loops)))
+
+
 def decode_loop_offences(hlo, stacks, slabs_too=False):
     """What the decode loops of a compiled program do to whole cache stacks.
 
@@ -205,14 +220,7 @@ def decode_loop_offences(hlo, stacks, slabs_too=False):
     layer slab (a slab set down in memory on its way to attention); and the
     number of `dynamic-update-slice`s into a stack or a reshaped view of
     one, so a caller can tell that the parser saw the writes at all."""
-    comps = _computations(hlo)
-    assert comps, "no computation parsed from the HLO text"
-    bodies = {re.search(r"body=%?([\w.\-]+)", rest).group(1)
-              for instrs in comps.values() for _, _, op, rest in instrs
-              if op == "while"}
-    loops = [b for b in bodies
-             if any(op == "while" for _, _, op, _ in comps[b])]
-    assert loops, "no decode loop (a while around the layer scan) found"
+    comps, reached = decode_loop_reach(hlo)
     sizes = {(d, int(np.prod(shape))): int(np.prod(shape[1:]))
              for d, shape in stacks}
     slabs = {(d, slab) for (d, _), slab in sizes.items()}
@@ -220,7 +228,7 @@ def decode_loop_offences(hlo, stacks, slabs_too=False):
              for _, _, op, rest in instrs if op == "fusion"
              for callee in re.findall(r"calls=%?([\w.\-]+)", rest)}
     offences, writes = [], 0
-    for name in sorted(set().union(*(_reachable(comps, b) for b in loops))):
+    for name in reached:
         types = {instr: result for instr, result, _, _ in comps[name]}
         for _, result, op, rest in comps[name]:
             if result.startswith("("):

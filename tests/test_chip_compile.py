@@ -106,14 +106,24 @@ def _verify(B=32, T=2048, Tq=4):
         ((B, KV, T, HD), jnp.bfloat16), ((B,), jnp.int32), ((B,), jnp.int32)]
 
 
-def _paged_decode(B=32, nb=16):
-    from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
+def _paged_decode(B=64, nb=12, L=28, N=807):
+    """The in-place read at the serving cell's shape: 64 rows of 12 blocks
+    over the whole stack of 28 x 807 pages, the layer a scalar."""
+    from nanorlhf_tpu.ops.decode_attention import (
+        paged_decode_attention, paged_decode_plan, paged_pages_per_item,
+    )
 
-    N = B * nb
-    return paged_decode_attention, [
-        ((B, H, HD), jnp.bfloat16), ((N, KV, PAGE, HD), jnp.bfloat16),
-        ((N, KV, PAGE, HD), jnp.bfloat16), ((B, nb), jnp.int32),
-        ((B,), jnp.int32), ((B,), jnp.int32)]
+    def fn(q, k_pool, v_pool, layer, table, start, filled, live):
+        plan = paged_decode_plan(
+            table, start, filled, page_size=PAGE, num_pages=N,
+            pages_per_item=paged_pages_per_item(k_pool), live=live)
+        return paged_decode_attention(q, k_pool, v_pool, layer, plan)
+
+    return fn, [
+        ((B, H, HD), jnp.bfloat16), ((L, N, KV, PAGE, HD), jnp.bfloat16),
+        ((L, N, KV, PAGE, HD), jnp.bfloat16), ((), jnp.int32),
+        ((B, nb), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32),
+        ((B,), jnp.bool_)]
 
 
 def _paged_decode_q8(B=32, nb=16):
@@ -277,8 +287,14 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     sliced and relaid V per layer, 18 % of the step). The model asks
     `jax.default_backend()` for its TPU choices, which is the CPU here, so
     the test answers for it; the int8 case then takes the q8 Pallas kernel,
-    whose operands are slabs by construction."""
-    from test_cache_carry import decode_loop_offences, hlo_stacks
+    whose operands are slabs by construction. The serving chunk reads its
+    pages in place (ISSUE 28): the kernel is in the loop, and nothing the
+    loop reaches, fused or not, has the shape of the gathered view's pieces
+    (the rows' 64 x 12 = 768 pages gathered, their transpose into row
+    order) or of a layer's slab of the pool."""
+    from test_cache_carry import (
+        _shapes, decode_loop_offences, decode_loop_reach, hlo_stacks,
+    )
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lowered, cache = _decode_loop(case, SingleDeviceSharding(v5e[0]))
@@ -286,6 +302,17 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     offences, _ = decode_loop_offences(
         hlo, hlo_stacks(cache), slabs_too=case != "rollout_int8")
     assert not offences, "\n".join(offences)
+    if case == "serving_chunk":
+        comps, reached = decode_loop_reach(hlo)
+        assert any(op == "custom-call" and "tpu_custom_call" in rest
+                   for name in reached for _, _, op, rest in comps[name])
+        view = {(768, KV, PAGE, HD), (64, KV, 12, PAGE, HD),
+                (807, KV, PAGE, HD)}
+        made = [f"{name}: {result} {op}" for name in reached
+                for _, result, op, _ in comps[name]
+                if not result.startswith("(")
+                and _shapes(result) and _shapes(result)[0][1] in view]
+        assert not made, "\n".join(made)
 
 
 def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(

@@ -134,22 +134,79 @@ def _scattered_pool(rng, B, KV, hd, P, nb, extra=2):
             N, T)
 
 
-def test_paged_decode_kernel_matches_oracle(rng):
+# rows of the in-place kernel's parity case, each (start, filled, live):
+# the layouts one serving step mixes. P = 8, five blocks a row
+_PAGED_ROWS = [
+    (0, 17, True),     # from slot 0, ends inside its third page
+    (3, 24, True),     # start inside a page, filled on a page boundary
+    (9, 10, True),     # filled - start = 1
+    (16, 40, False),   # done: the caller discards it, the kernel skips it
+    (2, 20, True),     # released: every table entry the sentinel
+    (11, 11, True),    # empty range
+    (8, 33, True),     # shares its first two pages with the next row
+    (8, 29, True),
+    (0, 40, True),     # all five blocks: two items when an item is four pages
+]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("KV,G", [(2, 6), (16, 1)], ids=["gqa2x6", "mha16"])
+@pytest.mark.parametrize("item_pages,tile_rows,items", [
+    (1, 4, [3, 3, 1, 0, 0, 0, 4, 3, 5]), (4, 9, [1, 1, 1, 0, 0, 0, 1, 1, 2])],
+    ids=["1page-3tiles", "4pages-1tile"])
+def test_paged_decode_kernel_matches_oracle(rng, monkeypatch, item_pages,
+                                            tile_rows, items, KV, G, layer,
+                                            dtype, tol):
+    """The in-place read (whole stacks in, the layer a scalar, the step's
+    work list from `paged_decode_plan`) against the gathered-view oracle on
+    the layer's slab: Qwen's and OLMoE's head geometry, each layer of a
+    stack, scattered and shared pages, a sentinel past a row's last block,
+    and rows without work (done, released, empty) among live ones, which
+    read zero; with a work item of one page (what OLMoE's 512 KB pages get
+    on the chip) and of four (Qwen2.5's), the last item of a row short; with
+    the nine rows in one tile and in three (the last one padded)."""
+    from nanorlhf_tpu.ops import decode_attention
     from nanorlhf_tpu.ops.decode_attention import (
-        paged_decode_attention, reference_paged_decode_attention,
+        paged_decode_attention, paged_decode_plan, paged_pages_per_item,
+        reference_paged_decode_attention,
     )
 
-    B, KV, G, hd, P, nb = 3, 2, 4, 16, 8, 5
-    table, k_pool, v_pool, N, T = _scattered_pool(rng, B, KV, hd, P, nb)
-    q = jnp.asarray(rng.standard_normal((B, KV * G, hd)).astype(np.float32))
-    start = jnp.asarray([0, 3, 9], jnp.int32)
-    filled = jnp.asarray([17, 30, 25], jnp.int32)  # row0 below its sentinel
-    want = reference_paged_decode_attention(q, k_pool, v_pool, table, start,
-                                            filled)
-    got = paged_decode_attention(q, k_pool, v_pool, table, start, filled,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    monkeypatch.setattr(decode_attention, "_PAGED_ITEM_PAGES", item_pages)
+    sub = 32 // jnp.dtype(dtype).itemsize
+    row_bytes = KV * sub * -(-G // sub) * 16 * jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(decode_attention, "_PAGED_TILE_BYTES",
+                        tile_rows * row_bytes)
+
+    L, hd, P, nb = 3, 16, 8, 5
+    B = len(_PAGED_ROWS)
+    N = B * nb + 2
+    table = rng.permutation(N)[: B * nb].reshape(B, nb).astype(np.int32)
+    table[0, -1] = N                      # unallocated tail of a live row
+    table[4, :] = N                       # a released row
+    table[7, :2] = table[6, :2]           # a shared prefix
+    table = jnp.asarray(table)
+    k_pool, v_pool = (
+        jnp.asarray(rng.standard_normal((L, N, KV, P, hd)), dtype)
+        for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, KV * G, hd)), dtype)
+    start, filled, live = (jnp.asarray(c) for c in zip(*_PAGED_ROWS))
+    start, filled = start.astype(jnp.int32), filled.astype(jnp.int32)
+
+    assert paged_pages_per_item(k_pool) == item_pages
+    plan = paged_decode_plan(table, start, filled, page_size=P, num_pages=N,
+                             pages_per_item=item_pages, live=live)
+    np.testing.assert_array_equal(np.diff(np.asarray(plan.row_off)), items)
+    got = np.asarray(paged_decode_attention(
+        q, k_pool, v_pool, jnp.int32(layer), plan, interpret=True),
+        np.float32)
+    want = np.asarray(reference_paged_decode_attention(
+        q, k_pool[layer], v_pool[layer], table, start, filled), np.float32)
+    works = np.asarray([0, 1, 2, 6, 7, 8])
+    np.testing.assert_allclose(got[works], want[works], rtol=tol, atol=tol)
+    assert not got[[3, 4, 5]].any()
 
 
 def test_paged_decode_q8_kernel_matches_oracle(rng):

@@ -299,6 +299,58 @@ def test_engine_spec_greedy_stream_identical(tiny):
     assert serve(0) == serve(3)
 
 
+def _serve_by_hand(tiny, impl):
+    """A serving-mode session driven beat by beat: two rows at different
+    depths, a third admitted mid-run, one released when its budget ends.
+    Returns (tokens of each row, the session)."""
+    import dataclasses
+
+    from nanorlhf_tpu.sampler.paged.session import DecodeSession
+
+    config, params = tiny
+    config = dataclasses.replace(config, attention_impl=impl)
+    sess = DecodeSession(
+        params, config, rows=3, prompt_len=TP, max_tokens=MT, page_size=4,
+        eos_token_id=config.vocab_size + 1, pad_token_id=PAD,
+        key=jax.random.PRNGKey(0), greedy=True, per_row=True,
+        prefix_cache=RadixCache(True, headroom=1.0), sync_every=2)
+
+    def admit(r, real, budget):
+        ids, mask = _left_pad([list(range(5, 5 + real))], TP)
+        sess.admit(r, np.asarray(ids[0]), np.asarray(mask[0]), r,
+                   budget=budget, temperature=1.0, top_p=1.0, greedy=True)
+
+    admit(0, 8, 8)            # first real slot 4, seven decode steps
+    admit(1, 3, 3)            # first real slot 9, two
+    sess.step()
+    admit(2, 12, 4)           # no padding, three, from the second beat
+    done, _ = sess.step()
+    assert done[1]
+    sess.release(1)           # its table row is the sentinel from here on
+    while not sess.step()[0].all():
+        pass
+    return np.asarray(sess.state[1]), sess
+
+
+def test_in_place_read_serves_the_gathered_views_tokens(tiny):
+    """ISSUE 28: the session's greedy tokens are the same whether the decode
+    step gathers the rows' pages into a view (`"xla"`) or reads them from
+    the stacked pool in place (the Pallas kernel, interpret mode here), with
+    rows at different depths, one admitted mid-run and one released; and the
+    host's count of what the kernel touches is exact: page 4, prompt width
+    12, so row 0 reads blocks 1..3 for four steps and 1..4 for three, row 1
+    blocks 2..3 twice, row 2 blocks 0..3 three times; seven steps of 3 rows
+    x 5 blocks are what the view would have built."""
+    view, s_view = _serve_by_hand(tiny, "xla")
+    in_place, s_in_place = _serve_by_hand(tiny, "pallas")
+    np.testing.assert_array_equal(in_place, view)
+    assert (view[0] != PAD).all() and (view[1, :3] != PAD).all()
+    for sess in (s_view, s_in_place):
+        assert sess.attn_live_pages == (4 * 3 + 3 * 4) + 2 * 2 + 3 * 4 == 40
+        assert sess.attn_table_pages == 7 * 3 * 5
+    assert (s_view.attn_in_place, s_in_place.attn_in_place) == (0, 1)
+
+
 # --------------------------------------------------------------------- #
 # compose_check: the one legality matrix
 # --------------------------------------------------------------------- #

@@ -32,7 +32,11 @@ SESSION_KEYS = [f"serving/session_{p}_s" for p in SESSION_PHASES]
 TIMELINE_KEYS = ["serving/queue_wait_s_sum", "serving/queue_wait_s_count",
                  "serving/first_token_lag_s_sum",
                  "serving/first_token_lag_s_count"]
-ACCOUNT_KEYS = LOOP_KEYS + ["serving/loop_beats"] + SESSION_KEYS + TIMELINE_KEYS
+# what the paged decode read touches (ISSUE 28), counted by the session
+ATTENTION_KEYS = ["serving/attn_live_pages", "serving/attn_table_pages",
+                  "serving/attn_in_place"]
+ACCOUNT_KEYS = (LOOP_KEYS + ["serving/loop_beats"] + SESSION_KEYS
+                + TIMELINE_KEYS + ATTENTION_KEYS)
 
 
 # --------------------------------------------------------------------- #
@@ -153,6 +157,7 @@ def test_account_counts_admissions_and_beats(two_reads):
         # nobody streamed over HTTP: the engine alone reports no lag
         assert m["serving/first_token_lag_s_count"] == 0
     assert second["serving/admitted"] == len(PROMPTS) + 3
+    assert 0 < second["serving/attn_live_pages"] < second["serving/attn_table_pages"]
     assert second["serving/loop_beats"] > first["serving/loop_beats"]
     # the five phases are disjoint parts of the loop thread's life
     assert sum(second[k] for k in LOOP_KEYS) <= lifetime
