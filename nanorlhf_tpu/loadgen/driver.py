@@ -11,8 +11,8 @@ measurement honesty arxiv 2605.25645's goodput curves depend on).
 Two targets, same records:
 
 - in-process (`engine=`): `ServingEngine.submit()`/`stream()` directly —
-  the CPU-CI mode the `traffic-smoke` tier-1 step and bench
-  `detail.traffic` use (no sockets, deterministic shed reasons).
+  the CPU-CI mode the `traffic-smoke` tier-1 step uses (no sockets,
+  deterministic shed reasons).
 - HTTP (`base_url=`): `POST /generate` with `"stream": true` against a
   ServingGateway; a 429 is recorded as a shed with the gateway's JSON
   reason and its `Retry-After` header — which the driver deliberately

@@ -10,8 +10,8 @@ regression-testable — the arxiv 2605.25645 goodput-vs-offered-load
 framing) and tabulates one SweepPoint per rate.
 
 The sweep owns no engine: the caller passes `run_point(spec)` which must
-build a FRESH target per point (bench.py's `detail.traffic` does this so
-shed state and hub histograms never bleed across rates), run a
+build a FRESH target per point (so shed state and hub histograms never
+bleed across rates), run a
 TrafficDriver over it, and return the TrafficSummary. jax-free.
 """
 
@@ -62,7 +62,7 @@ def run_sweep(run_point: Callable, spec: WorkloadSpec,
 
 
 def points_as_detail(points: list[SweepPoint]) -> dict:
-    """Column-oriented dict for bench.py's `detail.traffic` JSON."""
+    """Column-oriented dict of a sweep, for a JSON report."""
     return {
         "offered_rps": [p.offered_rps for p in points],
         "goodput_rps": [p.goodput_rps for p in points],
@@ -75,7 +75,7 @@ def points_as_detail(points: list[SweepPoint]) -> dict:
 
 
 def format_table(points: list[SweepPoint]) -> str:
-    """Human-readable curve (inspect_run / bench stderr)."""
+    """Human-readable curve (tools/inspect_run.py)."""
     header = (f"{'offered':>9} {'goodput':>9} {'shed%':>7} "
               f"{'p50_ttft':>10} {'p95_ttft':>10} {'done':>6} {'shed':>6}")
     lines = [header]

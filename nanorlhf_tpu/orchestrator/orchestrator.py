@@ -345,8 +345,8 @@ class RolloutOrchestrator:
         """Next sample — waits as long as the producer is making progress.
 
         No hard deadline: a cold-cache first generation can legitimately
-        compile for many minutes (the bench's 1.5B config budgets whole
-        attempts at 2100 s), so the wait only aborts when the producer
+        compile for minutes (a cold `grpo-1.5b-r512` cell sets up in 98-131 s,
+        PERF.md section 6), so the wait only aborts when the producer
         thread is actually DEAD without having reported an error through
         `queue.fail()` (which covers every exception path in `_produce`).
         The heartbeat interval just bounds how often liveness is checked.

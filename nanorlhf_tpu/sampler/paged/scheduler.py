@@ -28,8 +28,8 @@ Scheduling shape (unchanged by the refactor):
     is admitted mid-loop. Batch shape, pool shape, and compiled code never
     change.
   * Decode iterations are counted (the carry's global counter only advances
-    while at least one row is live), which is what the long-tail test and
-    bench's `detail.paged` compare against the fixed-batch schedule.
+    while at least one row is live), which is what the long-tail test
+    (tests/test_paged_cache.py) compares against the fixed-batch schedule.
 
 Feature composition (the session's reason to exist — see
 `sampler.compose_check` for the full matrix):

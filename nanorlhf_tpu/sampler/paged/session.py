@@ -14,7 +14,8 @@ policy loops: the rollout scheduler owns queue order and output
 collection, the serving engine owns threads/SLO/shed, and both submit
 rows into the same jitted chunk functions defined here.
 
-Compositions this buys (all test-pinned, bench-gated):
+Compositions this buys (all pinned in tests/test_session.py; none
+measured on a chip but what `serve-1.5b-chat` runs):
 
   * **spec decode under the radix prefix cache** — the n-gram drafter's
     lookup window is seeded from the radix tree's cached continuation of

@@ -138,7 +138,7 @@ class SamplingParams:
     # KV-only chunk forwards interleaved with the resident rows' decode
     # chunks (sampler/paged/session.py) — a long cold prompt no longer
     # stalls every live stream for its full prefill, bounding the p95
-    # inter-token gap (bench detail.session gates it). GREEDY streams are
+    # inter-token gap (tests/test_session.py). GREEDY streams are
     # bit-identical to prefill_chunk=0 (the final chunk runs the same
     # bucketed suffix forward and samples from the same admission PRNG
     # fold, test-pinned); sampled streams are equal in distribution only
@@ -601,8 +601,8 @@ def generate(
     `spec_stats_out` (spec_k > 0 only): a caller-provided list the
     speculative path appends its per-call stats dict to (device scalars:
     verify steps, drafted/accepted/emitted token counts) — the trainer's
-    rollout/draft_acceptance metrics and bench's detail.spec_decode read
-    it without changing the return contract. `tracer` (an enabled
+    rollout/draft_acceptance metrics read it without changing the return
+    contract. `tracer` (an enabled
     telemetry.SpanTracer) switches the speculative path to its
     host-driven loop with real per-iteration "rollout.draft"/
     "rollout.verify" spans (one sync per verify step — observability

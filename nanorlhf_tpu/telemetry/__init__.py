@@ -6,8 +6,8 @@ detection over the metric stream (health.py) plus a live /metrics ·
 /healthz · /statusz HTTP exporter (exporter.py), and the per-sample
 lineage ledger — end-to-end rollout provenance with drop attribution
 (lineage.py, queried by tools/inspect_run.py). tracer/health/exporter/
-lineage are jax-free; mfu.py imports jax lazily — bench's jax-averse
-parent can load any of them by file path."""
+lineage are jax-free; mfu.py imports jax lazily, so a process that must
+not touch the backend can load any of them."""
 
 from nanorlhf_tpu.telemetry.exporter import (
     StatusExporter,

@@ -3,8 +3,9 @@
 The ROADMAP's "fast as the hardware allows" is unverifiable from
 episodes/sec alone — MFU (achieved model FLOPs / peak chip FLOPs) is the
 hardware-normalized number. The accounting is ANALYTIC, the standard
-transformer napkin model both bench.py and the trainer's per-update
-`perf/*` metrics share (one formula, two consumers — they cannot drift):
+transformer napkin model behind the trainer's per-update `perf/*` metrics
+(the benchmark's `mfu` counts operations itself, attention included:
+`benchmark/harness/ops_bytes.py`):
 
     fwd FLOPs per token ≈ 2 · n_params        (one MAC per weight)
     bwd ≈ 2 × fwd  →  train tokens cost 3 · fwd
@@ -25,8 +26,8 @@ XLA's backend-compile event: a silent retrace (a shape that escaped the
 bucket menu, a donation change) shows up as a `perf/recompiles` step
 instead of an unexplained 40 s stall.
 
-Importable without jax (bench's parent process must never touch the
-backend): jax is only imported inside `recompile_counter()` /
+Importable without jax (a parent process that must never touch the
+backend can read the tables): jax is only imported inside `recompile_counter()` /
 `flops_param_count()`.
 """
 
@@ -38,7 +39,7 @@ from nanorlhf_tpu.analysis.lockorder import make_lock
 from typing import Optional
 
 # peak dense bf16 FLOPs/s per chip by device kind (public figures;
-# substring match on jax Device.device_kind). Shared with bench.py.
+# substring match on jax Device.device_kind).
 PEAK_FLOPS_PER_CHIP = {
     "v6": 918e12,       # Trillium / v6e
     "v5p": 459e12,

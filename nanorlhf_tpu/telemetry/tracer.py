@@ -32,11 +32,11 @@ tracks (the same reason PhaseTimer uses perf_counter).
 
 Disabled (the default) the tracer is a cheap no-op: `span()` yields an
 empty dict without touching the lock, `add_complete`/`instant`/`counter`
-return immediately — the enabled/disabled bench A/B is the acceptance
-gate for keeping the instrumentation inline unconditionally.
+return immediately (tests/test_telemetry.py), which is why the
+instrumentation stays inline unconditionally; its cost enabled is not
+measured on a chip.
 
-jax-free on purpose: unit-testable (and bench-parent-importable) with
-plain Python threads.
+jax-free on purpose: unit-testable with plain Python threads.
 """
 
 from __future__ import annotations

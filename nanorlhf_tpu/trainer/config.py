@@ -118,6 +118,9 @@ class RLConfig:
     # the off-policy drift exactly as the reference's off-policy-capable
     # losses do (`REINFORCE/reinforce_trainer.py:637`). Rollout PRNG comes
     # from a dedicated stream, so update 1 is bit-identical either way.
+    # One place does it, the `rollout` phase of RLTrainer.train(), for every
+    # trainer; it is the only overlap SparseGRPOTrainer has, whose skip
+    # consumes a rollout without publishing a version (ROADMAP D2).
     rollout_ahead: bool = False
     # >0: DISAGGREGATED rollouts — reserve this many devices (a whole slice
     # on multi-slice pods, parallel/mesh.split_rollout_devices) as a
@@ -432,8 +435,8 @@ class RLConfig:
     # (`<telemetry_dir>/trace.json`) at the end of every train() call and
     # on close(). The resilience layer dumps the flight-recorder ring as
     # `blackbox_<step>.json` on sentinel trip / producer failure / SIGTERM.
-    # Off by default; the bench A/B (detail.telemetry) holds the enabled
-    # overhead under 1% of step wall.
+    # Off by default; disabled it is a no-op (tests/test_telemetry.py), its
+    # cost enabled is not measured on a chip.
     telemetry: bool = False
     telemetry_dir: Optional[str] = None     # None -> output_dir
     # bounded trace buffer: events past the cap are dropped (counted in the
@@ -456,8 +459,9 @@ class RLConfig:
     # docs/OBSERVABILITY.md §5): every metric row folds into O(1)-memory
     # streaming aggregates (fast/slow EWMA, P² quantile sketches, windowed
     # counter rates) and a declarative rule set scores the run OK/WARN/CRIT.
-    # Health is on by default (bench's detail.health A/B holds its overhead
-    # under 1%); the HTTP exporter is off by default. status_port: 0 = off,
+    # Health is on by default (every cell of `benchmark/run.py` runs with it;
+    # its share is not measured apart); the HTTP exporter is off by default.
+    # status_port: 0 = off,
     # -1 = ephemeral port (tests/CI), >0 = fixed port serving /metrics
     # (Prometheus text), /healthz (200/503 from the verdict), /statusz
     # (JSON run state incl. fleet membership + lease table).
@@ -480,8 +484,7 @@ class RLConfig:
     # drop_reason) — as size-rotated append-only JSONL under
     # <output_dir>/lineage/. Query with tools/inspect_run.py; drop-reason
     # counters + a last-N sample ring ride /statusz and /metrics. Off by
-    # default; the bench A/B (detail.lineage) holds the enabled overhead
-    # under 1% of step wall.
+    # default; its cost enabled is not measured on a chip.
     lineage: bool = False
     # fraction of rollout indices recorded (deterministic per-index hash:
     # a sampled index keeps its COMPLETE lease→...→outcome chain; others
@@ -493,8 +496,8 @@ class RLConfig:
     # gaps, queue wait, per-op RPC RTT, reward-grader wall, per-update
     # phase durations — journaled in trainer_state.json, rendered as
     # Prometheus histogram exposition on /metrics, and scored by the
-    # quantile SLO rules (health.SLO_RULES). On by default; the bench
-    # A/B (detail.latency) holds the overhead under 1% of step wall.
+    # quantile SLO rules (health.SLO_RULES). On by default (every cell of
+    # `benchmark/run.py` runs with it; its share is not measured apart).
     latency: bool = True
 
     # ---- checkpoint / eval / logging ----

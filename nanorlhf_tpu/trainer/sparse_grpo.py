@@ -22,7 +22,6 @@ menu shapes (SURVEY.md §7 hard part #2).
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Callable, Optional
 
@@ -31,44 +30,40 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from nanorlhf_tpu.algos import (
-    discounted_returns,
-    grpo_group_advantage,
-    keep_one_of_n_indices,
-    sparse_terminal_rewards,
-)
 from nanorlhf_tpu.algos.losses import grpo_loss
 from nanorlhf_tpu.ops.masking import (
     INVALID_LOGPROB,
     first_true_indices,
     logprobs_from_logits,
-    response_padding_masks,
     truncate_response,
 )
 from nanorlhf_tpu.core.model import padded_forward_logits
 from nanorlhf_tpu.ops.fused_logprob import chunked_entropy
-from nanorlhf_tpu.sampler import SamplingParams, generate
 from nanorlhf_tpu.trainer.bucketing import (
     create_batches,
+    depad_queries,
     pad_rows,
     round_up_to_menu,
     shape_menu,
 )
 from nanorlhf_tpu.trainer.trainer import (
+    NoStep,
     RLTrainer,
-    RolloutStream,
-    device_peak_bytes,
-    forward_token_budget,
+    TrainRun,
+    Update,
+    donate_argnums_on_accel,
     fused_response_logprobs,
 )
 
-# forward budget comes from forward_token_budget (activation ∧ vocab caps);
+# forward budget comes from RLTrainer._forward_budget (activation ∧ vocab caps);
 # backward keeps the reference's dedicated constant (`grpo_r1_trainer.py:700`)
 BACKWARD_BUDGET = 4 * 2316
 
 
 class SparseGRPOTrainer(RLTrainer):
-    """GRPO + sparse filtering + bucketed variable-length execution.
+    """GRPO + sparse filtering + bucketed variable-length execution: the
+    loop is `RLTrainer.train()`, of whose phases this class overrides
+    `_select`, `_score` and `_update`.
 
     `accuracy_func(trainer) -> float`, when given, runs before training and
     every `cfg.eval_steps` updates (MATH-500 greedy eval in r1,
@@ -81,19 +76,31 @@ class SparseGRPOTrainer(RLTrainer):
 
     def __init__(self, *args, accuracy_func: Optional[Callable] = None, **kwargs):
         super().__init__(*args, **kwargs)
-        if self._env_multi_turn:
-            # single-turn envs work (RLTrainer unwraps them into a plain
-            # reward callable, which _call_reward dispatches unchanged);
-            # the multi-turn episode driver is wired into the DENSE
-            # runtime's rollout phase only
-            raise ValueError(
-                "SparseGRPOTrainer does not drive multi-turn environments "
-                "(env_max_turns > 1) — use the dense RLTrainer")
+        self._refuse_unsupported()
         self.accuracy_func = accuracy_func
         self._len_menu = shape_menu(
             self.cfg.response_length + self.dataset.input_ids.shape[1], min_value=32
         )
         self._rows_menu = shape_menu(max(self.cfg.batch_size, 1), min_value=1)
+
+    def _refuse_unsupported(self):
+        """What the shared loop carries and the sparse phases cannot yet."""
+        if self._env_multi_turn:
+            # single-turn envs work (RLTrainer unwraps them into a plain
+            # reward callable, which _call_reward dispatches unchanged);
+            # the per-turn advantages and the observation loss mask ride
+            # the dense selection and update only
+            raise ValueError(
+                "SparseGRPOTrainer does not drive multi-turn environments "
+                "(env_max_turns > 1) — use the dense RLTrainer")
+        if self.cfg.rollout_orchestrator:
+            raise ValueError(
+                "rollout_orchestrator is not supported by SparseGRPOTrainer "
+                "yet: the sparse all-zero-advantage skip consumes a rollout "
+                "WITHOUT publishing a policy version, which would wedge the "
+                "bounded-staleness gate (orchestrator/sample_queue.py). Use "
+                "rollout_ahead for overlap on the sparse path."
+            )
 
     # ------------------------------------------------------------------ #
     # jitted pieces (bucket-shaped)
@@ -292,12 +299,11 @@ class SparseGRPOTrainer(RLTrainer):
             return self._apply_grads_cached
         optimizer = self.optimizer
 
-        from nanorlhf_tpu.trainer.trainer import donate_argnums_on_accel
-
         @partial(jax.jit, donate_argnums=donate_argnums_on_accel(0, 1))
         def apply_grads(trainable, opt_state, grads):
             updates, opt_state = optimizer.update(grads, opt_state, trainable)
-            return optax.apply_updates(trainable, updates), opt_state
+            return (optax.apply_updates(trainable, updates), opt_state,
+                    optax.global_norm(grads))
 
         self._apply_grads_cached = apply_grads
         return apply_grads
@@ -319,573 +325,201 @@ class SparseGRPOTrainer(RLTrainer):
             )
 
     # ------------------------------------------------------------------ #
-    # the sparse training loop
+    # the three phases of RLTrainer.train() that are the sparse runtime's
+    # own, and its evaluation hook
     # ------------------------------------------------------------------ #
 
-    def train(self, num_updates: Optional[int] = None):
-        cfg, tok = self.cfg, self.tokenizer
-        if cfg.rollout_orchestrator:
-            raise ValueError(
-                "rollout_orchestrator is not supported by SparseGRPOTrainer "
-                "yet: the sparse all-zero-advantage skip consumes a rollout "
-                "WITHOUT publishing a policy version, which would wedge the "
-                "bounded-staleness gate (orchestrator/sample_queue.py). Use "
-                "rollout_ahead for overlap on the sparse path."
-            )
+    def _evaluate(self, step: int) -> dict:
+        """`accuracy_func` before training and every `cfg.eval_steps`."""
+        if self.accuracy_func is None:
+            return {}
+        if step == 0:
+            return {"initial_accuracy": float(self.accuracy_func(self))}
+        if self.cfg.eval_steps and step % self.cfg.eval_steps == 0:
+            return {"eval_accuracy_new": float(self.accuracy_func(self))}
+        return {}
+
+    def _select(self, run: TrainRun, up: Update):
+        """The dense selection (group advantage, keep-1-of-N), then the
+        sparse filter (`grpo_r1_trainer.py:565-568`) with a lineage drop for
+        every row it excludes, then the de-padding (`:571-582`) rounded
+        onto the menu. An all-zero batch ends the update without a step."""
+        tok = self.tokenizer
         pad_id, eos_id = tok.pad_token_id, tok.eos_token_id
-        n = cfg.sample_n
-        sp_on = self._sp_on()
-        score_fn = self._sp_score_fn() if sp_on else self._bucket_score_fn()
-        grad_fn = self._sp_grad_fn() if sp_on else self._bucket_grad_fn()
-        apply_fn = self._apply_grads_fn()
+        up.extra_metrics["eval_response_length"] = float(np.asarray(
+            first_true_indices(jnp.asarray(up.responses) == pad_id)).mean())
+        super()._select(run, up)
+        scores = up.grpo_adv
+        nz = np.where(scores != 0)[0]
+        if self.lineage.enabled:
+            # the paper's silent zero-advantage skip, made loud: one
+            # drop event PER EXCLUDED ROW — the attribution the sparse
+            # filter never had (every dropped row has exactly one
+            # machine-readable drop_reason)
+            for r in np.where(scores == 0)[0]:
+                self.lineage.drop(
+                    up.rollout_index, "sparse_zero_advantage",
+                    row=int(r), step=self.state["global_step"],
+                    raw_score=round(float(up.log_scores[r]), 6),
+                )
+        if len(nz) == 0:
+            mean_raw_score = float(up.raw_scores.mean())
+            print(f"[sparse-grpo] rollout {up.rollout_index}: all "
+                  "advantages zero, skipping")
+            # skip marker in the trace: a starved streak shows up as a
+            # row of instants instead of a silent gap
+            self.tracer.instant(
+                "sparse.skip", rollout_index=self.state["rollouts"],
+                raw_score_mean=mean_raw_score,
+            )
+            # a metrics row even for the skip (the reference logs
+            # nothing here): with sparse/binary rewards, WHY training
+            # starves matters — raw_score_mean 0 = uniformly failed,
+            # high = uniformly solved; both give zero group advantage.
+            # log_event (no 'episode' stamp, rollout-indexed) keeps
+            # step-row consumers and TB x-axes intact across
+            # consecutive skips at a frozen global_step.
+            self.logger.log_event(self.state["rollouts"], {
+                "sparse_skip/raw_score_mean": mean_raw_score,
+                "sparse_skip/rollout_index": self.state["rollouts"],
+            })
+            # the rollout is consumed (state["rollouts"] stays advanced)
+            # and so is one update of train()'s budget: a starved streak
+            # must end with the budget, not wait for steps that never come
+            return NoStep("sparse skip streak", counts=True)
+        up.extra_metrics["sparse/kept_frac"] = len(nz) / max(up.batch_size, 1)
+        up.span_args["kept_rows"] = len(nz)
+        # the sample table and the lineage outcome follow the kept rows
+        up.question_strings = [up.question_strings[i] for i in nz]
+        up.decoded = [up.decoded[i] for i in nz]
+        up.log_scores, up.grpo_adv = up.log_scores[nz], scores[nz]
 
-        if self.accuracy_func is not None and self.state["global_step"] == 0:
-            acc = float(self.accuracy_func(self))
-            self.logger.log(0, 0, {"initial_accuracy": acc})
+        up.queries_rep = depad_queries(up.queries[nz], pad_id, self._len_menu)
+        up.context_length = up.queries_rep.shape[1]
+        responses = up.responses[nz]
+        post = truncate_response(eos_id, pad_id, jnp.asarray(responses))
+        resp_len = np.asarray(first_true_indices(post == pad_id))
+        max_resp = min(
+            round_up_to_menu(max(int(resp_len.max()), 1), self._len_menu),
+            responses.shape[1])
+        up.responses = responses[:, :max_resp]
+        if up.captured_lp is not None:
+            up.captured_lp = up.captured_lp[nz][:, :max_resp]
+        up.qr_len = up.context_length + resp_len  # real tokens of each row
 
+    def _bucket_len(self, up: Update, rows) -> int:
+        """Menu-rounded width of the bucket holding `rows` of `up.qr`."""
+        blen = round_up_to_menu(int(up.qr_len[rows].max()), self._len_menu)
+        blen = min(max(blen, up.context_length + 1), up.qr.shape[1])
+        return self._sp_round_len(blen, up.qr.shape[1])
+
+    def _score(self, run: TrainRun, up: Update):
+        """Bucketed logprob pass under the forward token budget."""
+        pad_id, context_length = self.tokenizer.pad_token_id, up.context_length
+        qr = up.qr = np.concatenate([up.queries_rep, up.responses], axis=1)
+        capture, ref_free = run.score_capture, self._ref_free
+        logprobs = np.full(up.responses.shape, INVALID_LOGPROB, np.float32)
+        ref_logprobs = logprobs.copy()
+        if capture:
+            # policy logprobs came from the sampler; buckets below only
+            # run the ref forward (half the scoring work)
+            logprobs = up.captured_lp.astype(np.float32)
+        score_fn = (self._sp_score_fn() if self._sp_on()
+                    else self._bucket_score_fn())
         # the single-model scorer branches to the SP variant when sp is on
         # (see RLTrainer._single_scorer_for for the ref-free/capture matrix)
-        capture = cfg.sampler_logprob_capture
-        ref_fn = self._single_scorer_for(capture)
-        sampling = SamplingParams(
-            temperature=cfg.temperature, top_p=cfg.top_p, n=n,
-            max_tokens=cfg.response_length, capture_logprobs=capture,
-            compaction_segments=cfg.rollout_compaction_segments,
-            top_k=cfg.rollout_top_k, approx_top_k=cfg.rollout_approx_top_k,
-            shared_prompt_prefill=cfg.rollout_shared_prefill,
-            spec_k=cfg.rollout_spec_k, spec_ngram=cfg.rollout_spec_ngram,
-            page_size=cfg.rollout_page_size,
-            decode_rows=cfg.rollout_decode_rows,
-        )
-        n_updates = (
-            max(0, cfg.num_total_batches - self.state["global_step"])
-            if num_updates is None else num_updates
-        )
-
-        def rollout_body(queries, gk):
-            """DISPATCH one rollout (async — nothing blocks until fetched)."""
-            q_j = jnp.asarray(queries)
-            if self.rollout_mesh is not None:
-                from nanorlhf_tpu.parallel.mesh import batch_sharding
-
-                # disaggregated rollouts: prompts land on the generation
-                # mesh; _rollout_params() re-shards the param view there
-                q_j = jax.device_put(q_j, batch_sharding(self.rollout_mesh))
-            spec_stats: list = []
-            paged_stats: list = []
-            gen_out = generate(
-                self._rollout_params(), self._rollout_mcfg, q_j, q_j != pad_id, gk,
-                sampling, eos_token_id=eos_id, pad_token_id=pad_id,
-                lora_scale=self.lora_scale,
-                spec_stats_out=spec_stats, tracer=self.tracer,
-                paged_stats_out=paged_stats, latency=self.latency,
-            )
-            return {"queries": queries, "gen_out": gen_out,
-                    "spec_stats": spec_stats[0] if spec_stats else None,
-                    "paged_stats": paged_stats[0] if paged_stats else None}
-
-        stream = RolloutStream(self, rollout_body, meter=self._rollout_meter)
-        # lineage (telemetry/lineage.py): whole-rollout drops are counted
-        # in samples — one rollout here is batch_size*n completion rows
-        self.lineage.rows_hint = cfg.batch_size * n
-        for update in range(1, n_updates + 1):
-            t_start = time.perf_counter()  # sec_per_episode is a duration
-            step_t0 = time.perf_counter()
-            # telemetry (docs/OBSERVABILITY.md): profile-window poll + the
-            # per-update span, same contract as the dense loop
-            self.profile_window.poll(self.state["global_step"] + 1)
-            span_t0 = self.tracer.now_us() if self.tracer.enabled else 0.0
-            self.state["episode"] += cfg.batch_size
-
-            # ---- rollout + reward -----------------------------------------
-            t_roll0 = time.perf_counter()
-            ro = stream.fetch_or_dispatch()
-            rollout_index = ro["_index"]
-            queries = ro["queries"]
-            batch_size = queries.shape[0]
-            if self.lineage.enabled:
-                # serial loop: generation provenance is emitted here (the
-                # stream's dispatch already logged the lease event)
-                from nanorlhf_tpu.telemetry.lineage import spec_summary
-
-                self.lineage.generation(
-                    rollout_index,
-                    policy_version=self.state["global_step"], worker_id=0,
-                    spec=spec_summary(ro),
-                )
-            pstats = ro.get("paged_stats")
-            if pstats is not None:
-                # /statusz "pages" snapshot + one lineage "lease" event per
-                # mid-loop admission — same contract as the dense loop
-                self._pages_status = {
-                    k: (None if pstats[k] is None
-                        else float(np.asarray(pstats[k])))
-                    for k in ("page_utilization", "pages_recycled",
-                              "admitted_midloop", "decode_iterations")
-                }
-                self._pages_status.update(
-                    rows=pstats["rows"], num_pages=pstats["num_pages"],
-                    page_size=pstats["page_size"],
-                )
-                if self.lineage.enabled:
-                    for adm in pstats.get("admissions") or []:
-                        self.lineage.event(
-                            "lease", rollout_index, midloop=True,
-                            row=adm["row"], queue_index=adm["queue_index"],
-                            iteration=adm["iteration"],
-                        )
-            if capture:
-                responses, captured_lp = ro["gen_out"]
-                responses = np.asarray(responses)
-                captured_lp = np.asarray(captured_lp)
-            else:
-                responses = np.asarray(ro["gen_out"])
-                captured_lp = None
-            rollout_s = time.perf_counter() - t_roll0
-            if cfg.rollout_ahead and update < n_updates:
-                # overlap the NEXT generation with this update's grading —
-                # in the r1 path the sympy/subprocess graders are the
-                # dominant host cost, so this is where the overlap pays most
-                stream.prefetch()
-            question_strings = [
-                q.replace(tok.pad_token, "") for q in tok.batch_decode(queries)
-            ]
-            question_n = [q for q in question_strings for _ in range(n)]
-            decoded = tok.batch_decode(responses)
-            t_rwd0 = time.perf_counter()
-            raw_scores = self._call_reward(
-                [q + r for q, r in zip(question_n, decoded)], responses
-            )
-            if self.latency.enabled:
-                # grader wall — same quantity the lineage reward event
-                # records as wall_s (the sympy/subprocess graders are the
-                # dominant host cost in the r1 path)
-                self.latency.record("latency/reward_s",
-                                    time.perf_counter() - t_rwd0)
-            self.lineage.reward(
-                rollout_index, step=self.state["global_step"],
-                scores=[round(float(s), 6) for s in raw_scores.tolist()],
-                attempt=1,  # _call_reward has no retry loop
-                wall_s=round(time.perf_counter() - t_rwd0, 6),
-            )
-            mean_raw_score = float(raw_scores.mean())
-            log_responses_length = float(
-                np.asarray(first_true_indices(jnp.asarray(responses) == pad_id)).mean()
-            )
-
-            # ---- group z-score + keep-1-of-N ------------------------------
-            adv_flat = np.asarray(grpo_group_advantage(jnp.asarray(raw_scores), n))
-            self.key, kk = jax.random.split(self.key)
-            keep = np.asarray(keep_one_of_n_indices(kk, batch_size, n))
-            rows = np.arange(batch_size)
-            scores = adv_flat.reshape(batch_size, n)[rows, keep]
-            responses = responses.reshape(batch_size, n, -1)[rows, keep]
-            if captured_lp is not None:
-                captured_lp = captured_lp.reshape(batch_size, n, -1)[rows, keep]
-            if n > 1:
-                # the other n−1 completions per prompt leave the batch here
-                self.lineage.drop(
-                    rollout_index, "keep_filter",
-                    count=batch_size * (n - 1),
-                    step=self.state["global_step"],
-                )
-
-            # ---- sparse filter (`grpo_r1_trainer.py:565-568`) -------------
-            nz = np.where(scores != 0)[0]
-            kept_frac = len(nz) / max(batch_size, 1)
-            if self.lineage.enabled:
-                # the paper's silent zero-advantage skip, made loud: one
-                # drop event PER EXCLUDED ROW — the attribution the sparse
-                # filter never had (every dropped row has exactly one
-                # machine-readable drop_reason)
-                for r in np.where(scores == 0)[0]:
-                    self.lineage.drop(
-                        rollout_index, "sparse_zero_advantage",
-                        row=int(r), step=self.state["global_step"],
-                        raw_score=round(
-                            float(raw_scores.reshape(batch_size, n)[r, keep[r]]),
-                            6,
-                        ),
-                    )
-            if len(nz) == 0:
-                print(f"[sparse-grpo] update {update}: all advantages zero, skipping")
-                # skip marker in the trace: a starved streak shows up as a
-                # row of instants instead of a silent gap
-                self.tracer.instant(
-                    "sparse.skip", rollout_index=self.state["rollouts"],
-                    raw_score_mean=mean_raw_score,
-                )
-                # a metrics row even for the skip (the reference logs
-                # nothing here): with sparse/binary rewards, WHY training
-                # starves matters — raw_score_mean 0 = uniformly failed,
-                # high = uniformly solved; both give zero group advantage.
-                # log_event (no 'episode' stamp, rollout-indexed) keeps
-                # step-row consumers and TB x-axes intact across
-                # consecutive skips at a frozen global_step.
-                self.logger.log_event(self.state["rollouts"], {
-                    "sparse_skip/raw_score_mean": mean_raw_score,
-                    "sparse_skip/rollout_index": self.state["rollouts"],
-                })
-                # preemption must be polled on the skip path too: a long
-                # uniformly-failed/solved streak would otherwise bypass the
-                # bottom-of-loop poll every iteration, swallow SIGTERM, and
-                # be SIGKILLed at the end of the grace window
-                if self._preemption.triggered:
-                    from nanorlhf_tpu.resilience import Preempted
-
-                    self._sparse_save({})
-                    self.ckpt.wait()
-                    self.tracer.dump_blackbox(
-                        self._telemetry_dir, self.state["global_step"],
-                        "preemption",
-                    )
-                    self._write_trace()
-                    raise Preempted(
-                        f"SIGTERM at step {self.state['global_step']} (sparse "
-                        f"skip streak): emergency checkpoint committed to "
-                        f"{cfg.output_dir}"
-                    )
-                continue
-            scores, queries_f, responses_f = scores[nz], queries[nz], responses[nz]
-            if captured_lp is not None:
-                captured_lp = captured_lp[nz]
-
-            # ---- de-pad (`:571-582`), menu-rounded ------------------------
-            from nanorlhf_tpu.trainer.bucketing import depad_queries
-
-            queries_f = depad_queries(queries_f, pad_id, self._len_menu)
-            context_length = queries_f.shape[1]
-
-            post = np.asarray(truncate_response(eos_id, pad_id, jnp.asarray(responses_f)))
-            resp_len = np.asarray(first_true_indices(jnp.asarray(post) == pad_id))
-            max_resp = round_up_to_menu(
-                max(int(resp_len.max()), 1), self._len_menu
-            )
-            max_resp = min(max_resp, responses_f.shape[1])
-            responses_f = responses_f[:, :max_resp]
-            post = post[:, :max_resp]
-
-            qr = np.concatenate([queries_f, responses_f], axis=1)
-            qr_len = context_length + resp_len
-
-            # ---- bucketed logprob pass (budget 22·2316, capped so the
-            # [tokens, vocab] logits block fits HBM — the cap lifts under
-            # fused_logprob, whose chunking bounds that block itself; NOT
-            # under sp, whose scorer still materializes per-shard logits) ---
-            rollout_budget = forward_token_budget(
-                self.mcfg.vocab_size,
-                fused_logprob=cfg.fused_logprob and not self._sp_on(),
-            )
-            backward_budget = min(BACKWARD_BUDGET, rollout_budget // 2)
-            buckets = create_batches(qr_len, rollout_budget)
-            logprobs = np.full(
-                (len(scores), max_resp), INVALID_LOGPROB, np.float32
-            )
-            ref_logprobs = logprobs.copy()
-            if captured_lp is not None:
-                # policy logprobs came from the sampler; buckets below only
-                # run the ref forward (half the scoring work)
-                logprobs = captured_lp[:, :max_resp].astype(np.float32)
-            ref_free = self._ref_free
-            for idxs in ([] if (ref_free and capture) else buckets):
-                # ref-free + capture: zero scoring forwards (sampler-captured
-                # policy logprobs, no reference model — the r1 setting)
-                blen = round_up_to_menu(int(qr_len[idxs].max()), self._len_menu)
-                blen = min(max(blen, context_length + 1), qr.shape[1])
-                blen = self._sp_round_len(blen, qr.shape[1])
-                rows_b = round_up_to_menu(len(idxs), self._rows_menu)
-                padded = pad_rows(
-                    {"qr": qr[idxs][:, :blen]}, rows_b, {"qr": pad_id}
-                )
+        one_fn = self._single_scorer_for(capture)
+        # ref-free + capture: zero scoring forwards (sampler-captured
+        # policy logprobs, no reference model — the r1 setting)
+        buckets = ([] if ref_free and capture
+                   else create_batches(up.qr_len, self._forward_budget()))
+        with self.timer.phase("logprob"):
+            for idxs in buckets:
+                blen = self._bucket_len(up, idxs)
                 width = blen - context_length
+                rows_b = round_up_to_menu(len(idxs), self._rows_menu)
+                padded = jnp.asarray(pad_rows(
+                    {"qr": qr[idxs][:, :blen]}, rows_b, {"qr": pad_id})["qr"])
                 if ref_free:
-                    lp = ref_fn(self.params, jnp.asarray(padded["qr"]),
-                                context_length)
+                    lp = one_fn(self.params, padded, context_length)
                     logprobs[idxs, :width] = np.asarray(lp)[: len(idxs)]
                 elif capture:
-                    rlp = ref_fn(self.ref_params, jnp.asarray(padded["qr"]),
-                                 context_length)
+                    rlp = one_fn(self.ref_params, padded, context_length)
                     ref_logprobs[idxs, :width] = np.asarray(rlp)[: len(idxs)]
                 else:
                     # (an expert model's chunk scorer appends its router
-                    # sums; the sparse loop logs no moe/* counters)
+                    # sums; the sparse runtime logs no moe/* counters)
                     lp, rlp = score_fn(
-                        self.params, self.ref_params, jnp.asarray(padded["qr"]),
-                        context_length,
+                        self.params, self.ref_params, padded, context_length,
                     )[:2]
                     logprobs[idxs, :width] = np.asarray(lp)[: len(idxs)]
                     ref_logprobs[idxs, :width] = np.asarray(rlp)[: len(idxs)]
-            if ref_free:
-                # ref == policy-old: every KL term and metric reads exactly 0
-                ref_logprobs = logprobs.copy()
+        # ref-free: ref == policy-old, every KL term and metric reads 0
+        up.logprobs = logprobs
+        up.ref_logprobs = logprobs.copy() if ref_free else ref_logprobs
 
-            # ---- masks + advantages ---------------------------------------
-            seq_len = np.asarray(first_true_indices(jnp.asarray(post) == pad_id) - 1)
-            padding_mask, _ = response_padding_masks(post, jnp.asarray(seq_len))
-            padding_mask = np.asarray(padding_mask)
-            logprobs = np.where(padding_mask, INVALID_LOGPROB, logprobs)
-            ref_logprobs = np.where(padding_mask, INVALID_LOGPROB, ref_logprobs)
-            rewards = np.asarray(sparse_terminal_rewards(
-                jnp.asarray(scores), jnp.asarray(seq_len), max_resp
-            ))
-            advantages = np.asarray(discounted_returns(jnp.asarray(rewards), 1.0))
-            advantages = np.where(padding_mask, 0.0, advantages)
-
-            # ---- bucketed update (budget 4·2316, loss-scaled) -------------
-            t_upd0 = time.perf_counter()
-            trainable, frozen = self._partition(
-                self._train_tree(self.params, self.value_params)
-            )
-            all_stats = []
-            local_bs = len(scores)
-            mini = min(cfg.local_mini_batch_size, local_bs)
-            lr_step = self.state.get("opt_steps", 0)
+    def _update(self, run: TrainRun, up: Update):
+        """Bucketed update under the backward budget (4·2316): each
+        bucket's gradient scaled `rows / minibatch_rows`, one optimizer
+        step a minibatch (`grpo_r1_trainer.py:786-791`)."""
+        cfg, batch, context_length = self.cfg, up.batch, up.context_length
+        pad_id = self.tokenizer.pad_token_id
+        grad_fn = self._sp_grad_fn() if self._sp_on() else self._bucket_grad_fn()
+        apply_fn = self._apply_grads_fn()
+        backward_budget = min(BACKWARD_BUDGET, self._forward_budget() // 2)
+        trainable, frozen = self._partition(
+            self._train_tree(self.params, self.value_params)
+        )
+        all_stats, norms = [], []
+        local_bs = len(up.qr)
+        mini = min(cfg.local_mini_batch_size, local_bs)
+        up.lr_step = self.state["opt_steps"]
+        # saved bytes are sized from the WIDEST backward bucket (rows
+        # bounded by the backward budget at the max bucket width)
+        up.logits_rows = max(1, backward_budget // up.qr.shape[1])
+        fill = {"query_responses": pad_id, "responses": pad_id,
+                "logprobs": INVALID_LOGPROB, "ref_logprobs": INVALID_LOGPROB,
+                "padding_mask": True}
+        with self.timer.phase("update"):
             for epoch in range(cfg.num_ppo_epochs):
                 self.key, pk = jax.random.split(self.key)
                 perm = np.asarray(jax.random.permutation(pk, local_bs))
                 for start in range(0, local_bs, mini):
                     mb_inds = perm[start : start + mini]
-                    mini_rows = len(mb_inds)
                     grads_acc = None
-                    for bidx in create_batches(qr_len[mb_inds], backward_budget):
+                    for bidx in create_batches(up.qr_len[mb_inds],
+                                               backward_budget):
                         sel = mb_inds[bidx]
-                        blen = round_up_to_menu(int(qr_len[sel].max()), self._len_menu)
-                        blen = min(max(blen, context_length + 1), qr.shape[1])
-                        blen = self._sp_round_len(blen, qr.shape[1])
+                        blen = self._bucket_len(up, sel)
                         width = blen - context_length
-                        rows_b = round_up_to_menu(len(sel), self._rows_menu)
                         mb = pad_rows(
-                            {
-                                "query_responses": qr[sel][:, :blen],
-                                "responses": responses_f[sel][:, :width],
-                                "logprobs": logprobs[sel][:, :width],
-                                "ref_logprobs": ref_logprobs[sel][:, :width],
-                                "advantages": advantages[sel][:, :width],
-                                "padding_mask": padding_mask[sel][:, :width],
-                            },
-                            rows_b,
-                            {"query_responses": pad_id, "responses": pad_id,
-                             "logprobs": INVALID_LOGPROB,
-                             "ref_logprobs": INVALID_LOGPROB,
-                             "padding_mask": True},
+                            {k: batch[k][sel][:, :(blen if k == "query_responses"
+                                                   else width)]
+                             for k in (*fill, "advantages")},
+                            round_up_to_menu(len(sel), self._rows_menu), fill,
                         )
-                        mb = {k: jnp.asarray(v) for k, v in mb.items()}
                         # scale by REAL rows (`grpo_r1_trainer.py:786-788`)
-                        loss_scale = len(sel) / mini_rows
                         grads, aux = grad_fn(
-                            trainable, frozen, mb, context_length,
-                            jnp.float32(loss_scale),
+                            trainable, frozen,
+                            {k: jnp.asarray(v) for k, v in mb.items()},
+                            context_length,
+                            jnp.float32(len(sel) / len(mb_inds)),
                         )
                         grads_acc = grads if grads_acc is None else jax.tree.map(
                             jnp.add, grads_acc, grads
                         )
                         all_stats.append(aux)
-                    trainable, self.opt_state = apply_fn(
+                    trainable, self.opt_state, gnorm = apply_fn(
                         trainable, self.opt_state, grads_acc
                     )
-                    self.state["opt_steps"] = self.state.get("opt_steps", 0) + 1
+                    norms.append(gnorm)
+                    self.state["opt_steps"] += 1
             self.params = self._combine(trainable, frozen)["policy"]
-            all_stats = jax.device_get(all_stats)
-            update_s = time.perf_counter() - t_upd0
-
-            # ---- metrics / eval / checkpoint ------------------------------
-            agg = {
-                k: float(np.mean([s[k] for s in all_stats]))
-                for k in (all_stats[0] if all_stats else {})
-            }
-            kl_rollout = float(
-                np.where(padding_mask, 0.0, logprobs - ref_logprobs).sum(1).mean()
-            )
-            metrics = {
-                # GRPO parity: update-pass refkl (see docs/METRICS.md);
-                # 0 in ref-free mode — the stand-in refkl would report
-                # KL-to-old-policy, not a reference KL
-                "objective/kl_old": (
-                    0.0 if self._ref_free
-                    else agg.get("refkl_mean", kl_rollout)
-                ),
-                "objective/kl_rollout_old": kl_rollout,
-                "objective/non_score_reward_old": 0.0,  # GRPO: KL is in-loss
-                "eval_objective/rlhf_reward_old": mean_raw_score,
-                "eval_objective/scores_old": mean_raw_score,
-                "policy/approxkl_avg_new": agg.get("approxkl", 0.0),
-                "policy/clipfrac_avg_new": agg.get("pg_clipfrac", 0.0),
-                "policy/entropy_avg_new": agg.get("entropy", 0.0),
-                "loss/policy_avg_new": agg.get("pg_loss", 0.0),
-                "val/ratio_new": agg.get("ratio_mean", 1.0),
-                "val/ratio_var_new": float(np.var(
-                    [s.get("ratio_mean", 1.0) for s in all_stats]
-                )) if all_stats else 0.0,
-                "lr": float(self._lr_schedules["policy"](lr_step)),
-                "eps": cfg.adam_eps,
-                "sparse/kept_frac": kept_frac,
-                "eval_response_length": log_responses_length,
-                **({"sampler_capture/ratio_drift_new": abs(
-                    agg.get("ratio_mean", 1.0) - 1.0
-                )} if capture else {}),
-                "sec_per_episode": (time.perf_counter() - t_start) / cfg.batch_size,
-                # memory series (docs/METRICS.md): saved bytes sized from
-                # this update's WIDEST backward bucket (rows bounded by the
-                # backward token budget at the max bucket width; resp_len /
-                # qr_len are per-row arrays here — variable-length buckets)
-                # — the buffer the fused path avoids per grad microbatch
-                "mem/peak_bytes_in_use": device_peak_bytes(),
-                # 0 on an sp mesh too: the sp grad fn runs there, not fused
-                "mem/logits_bytes_saved": float(
-                    max(1, backward_budget // (context_length + max_resp))
-                    * max_resp * self.mcfg.vocab_size
-                    * jnp.dtype(self.params["embed_tokens"].dtype).itemsize
-                    if cfg.fused_logprob and not self._sp_on() else 0.0
-                ),
-                "episode": self.state["episode"],
-            }
-            # speculative-decode acceptance rows: the dense loop's one
-            # definition (RLTrainer._spec_decode_metrics, docs/METRICS.md)
-            metrics.update(self._spec_decode_metrics(ro.get("spec_stats")))
-            metrics.update(self._paged_metrics(ro.get("paged_stats")))
-            # perf/MFU accounting (telemetry/, docs/OBSERVABILITY.md): the
-            # dense loop's napkin model with sparse-runtime token counts —
-            # scoring/update tokens count only the KEPT (post-filter) rows
-            score_forwards = (
-                0 if (ref_free and capture)
-                else 1 if (ref_free or capture) else 2
-            )
-            metrics.update(self._perf_metrics(
-                step_wall_s=time.perf_counter() - step_t0,
-                decode_tokens=batch_size * n * cfg.response_length,
-                prefill_tokens=batch_size * n * queries.shape[1],
-                score_tokens=score_forwards * len(scores)
-                * (context_length + max_resp),
-                train_tokens=cfg.num_ppo_epochs * local_bs
-                * (context_length + max_resp),
-                rollout_s=rollout_s,
-                update_s=update_s,
-            ))
-            if self.latency.enabled:
-                # per-update phase durations — the sparse loop times its two
-                # phases by hand instead of PhaseTimer, same histogram keys
-                self.latency.record("latency/phase_rollout_s", rollout_s)
-                self.latency.record("latency/phase_update_s", update_s)
-            self.state["global_step"] += 1
-            if self.accuracy_func is not None and cfg.eval_steps and \
-                    self.state["global_step"] % cfg.eval_steps == 0:
-                metrics["eval_accuracy_new"] = float(self.accuracy_func(self))
-            # run-health plane: same routing as the dense loop — every row
-            # folds into the monitor and the health/* gauges ride along
-            metrics.update(
-                self.health.observe(self.state["global_step"], metrics)
-            )
-            kept_scores = raw_scores.reshape(batch_size, n)[rows, keep]
-            if self.lineage.enabled:
-                # outcome closes the chain: kept rows survived BOTH the
-                # keep-1-of-N draw and the sparse zero-advantage filter
-                self.lineage.outcome(
-                    rollout_index, step=self.state["global_step"],
-                    policy_version=self.state["global_step"],
-                    kept=int(local_bs),
-                    advantage=round(float(scores.mean()), 6),
-                    scores=[round(float(s), 6) for s in kept_scores.tolist()],
-                    kept_frac=round(kept_frac, 4),
-                )
-                for r in nz[:8]:
-                    self.lineage.note_sample(
-                        rollout_index, step=self.state["global_step"],
-                        score=round(float(kept_scores[r]), 6),
-                        response_chars=len(decoded[r * n + keep[r]]),
-                        kept=True,
-                    )
-            if self.state["global_step"] % cfg.logging_steps == 0:
-                self.logger.log(self.state["global_step"], self.state["episode"], metrics)
-                kept_decoded = [decoded[i * n + j] for i, j in enumerate(keep)]
-                sample_limit = (
-                    cfg.log_samples_limit
-                    if cfg.log_samples_limit is not None
-                    else cfg.num_printed_samples
-                )
-                self.logger.log_samples(
-                    self.state["global_step"], question_strings, kept_decoded,
-                    kept_scores, sample_limit,
-                )
-                if self.lineage.enabled:
-                    # full-text records belong to the ledger, not
-                    # metrics.jsonl (see MetricsLogger.log_samples)
-                    for i, (q, r_txt, s) in enumerate(zip(
-                            question_strings, kept_decoded,
-                            kept_scores.tolist())):
-                        if i >= sample_limit:
-                            break
-                        self.lineage.event(
-                            "sample", rollout_index,
-                            step=self.state["global_step"], row=i,
-                            query=q, response=r_txt,
-                            score=round(float(s), 6),
-                        )
-            saved_this_step = False
-            if cfg.save_steps and self.state["global_step"] % cfg.save_steps == 0:
-                self._sparse_save(metrics)
-                saved_this_step = True
-            if self.tracer.enabled:
-                # staleness is structurally 0 here (the sparse loop rejects
-                # the orchestrator); kept_rows is the sparse-specific
-                # correlation arg
-                self.tracer.add_complete(
-                    "train.update", span_t0, self.tracer.now_us() - span_t0,
-                    step=self.state["global_step"],
-                    rollout_index=ro["_index"], staleness=0,
-                    policy_version=self.state["global_step"],
-                    kept_rows=local_bs,
-                )
-            # graceful preemption (docs/RESILIENCE.md): the guard installed
-            # by RLTrainer.__init__ swallows SIGTERM, so this loop MUST poll
-            # it — otherwise a preempted sparse run burns the whole grace
-            # window and is SIGKILLed with no emergency checkpoint
-            if self._preemption.triggered:
-                from nanorlhf_tpu.resilience import Preempted
-
-                if not saved_this_step:
-                    self._sparse_save(metrics)
-                self.ckpt.wait()
-                self.tracer.dump_blackbox(
-                    self._telemetry_dir, self.state["global_step"],
-                    "preemption",
-                )
-                self._write_trace()
-                raise Preempted(
-                    f"SIGTERM at step {self.state['global_step']}: emergency "
-                    f"checkpoint committed to {cfg.output_dir}"
-                )
-        # train() returning implies checkpoints are durable (async saver)
-        self.ckpt.wait()
-        # balance any open XLA profile window + rewrite trace.json (same
-        # end-of-train contract as the dense loop)
-        self.profile_window.stop()
-        self._write_trace()
-        if cfg.export_hf_dir and num_updates is None:
-            # handoff artifact (same contract as the dense runtime)
-            print(f"exporting HF checkpoint to {cfg.export_hf_dir}")
-            self.export_model(cfg.export_hf_dir)
-        return self.state
-
-    def _sparse_save(self, metrics: dict):
-        """Sparse-runtime checkpoint — shared by the periodic path and the
-        SIGTERM emergency path. Persists the consumed-rollout cursor (the
-        sparse filter skips updates WITHOUT stepping, so global_step alone
-        under-counts the data/PRNG streams on resume) and the resilience
-        journal, matching the dense runtime's trainer_state contract."""
-        cfg = self.cfg
-        self.ckpt.save(
-            self.state["global_step"], self.params,
-            opt_state=self.opt_state if cfg.save_optimizer_state else None,
-            rng_key=self.key,
-            metric_old=metrics.get(cfg.metric_for_best_model),
-            extra_state={"episode": self.state["episode"],
-                         "opt_steps": self.state.get("opt_steps", 0),
-                         "rollouts": self.state["rollouts"],
-                         "resilience": {
-                             "sentinel": self.sentinel.journal(),
-                             "watchdog": self.watchdog.journal(),
-                         },
-                         "health": self.health.journal(),
-                         "lineage": self.lineage.journal(),
-                         "latency": self.latency.journal()},
-        )
+            up.all_stats, norms = jax.device_get((all_stats, norms))
+        up.agg = {
+            k: float(np.mean([s[k] for s in up.all_stats]))
+            for k in (up.all_stats[0] if up.all_stats else {})
+        }
+        # the step's gradient norm (what the sentinel and
+        # policy/grad_norm_new read), a mean over its optimizer steps
+        up.agg["grad_norm"] = float(np.mean(norms))

@@ -27,9 +27,11 @@ TPU execution model (the design inversions of SURVEY.md §7):
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
-from typing import Callable, Optional
+from functools import partial
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +83,7 @@ from nanorlhf_tpu.telemetry import (DEFAULT_RULES, HealthConfig,
                                     StatusExporter, flops_param_count,
                                     peak_flops_per_chip, recompile_counter,
                                     update_flops)
+from nanorlhf_tpu.trainer.bucketing import depad_queries, shape_menu
 from nanorlhf_tpu.trainer.checkpoint import CheckpointManager
 from nanorlhf_tpu.trainer.config import AlgoName, RLConfig
 from nanorlhf_tpu.trainer.metrics import (MetricsLogger,
@@ -157,7 +160,7 @@ def fused_logprob_impl(cfg, mcfg) -> str:
 
 def device_peak_bytes() -> float:
     """Max `peak_bytes_in_use` across local devices — the `mem/peak_bytes_
-    in_use` metric and bench's `detail.peak_bytes_in_use`. 0.0 where the
+    in_use` metric. 0.0 where the
     backend reports no memory stats (the CPU test mesh).
 
     This is the allocator's PROCESS-LIFETIME high-water mark (monotone): it
@@ -207,6 +210,93 @@ def pad_chunk(rows: np.ndarray, chunk: int) -> np.ndarray:
     return np.concatenate([rows, reps], axis=0)
 
 
+@dataclasses.dataclass
+class NoStep:
+    """How an update ends without a step: why (the `train.update` span and a
+    preemption's message say it), what else the span carries, and what the
+    phase that ended it does once the loop has closed the span."""
+    why: str
+    span_args: dict = dataclasses.field(default_factory=dict)
+    then: Optional[Callable[[], None]] = None
+    # the update spends one of train()'s budget though it made no step (its
+    # rollout is consumed for good); a rollback does not, it replays
+    counts: bool = False
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What one `train()` call sets up and every update reads."""
+    n: int                       # completions sampled per prompt
+    capture: bool                # the sampler returns its logprobs
+    score_capture: bool          # ... and they stand in for the policy pass
+    target_step: int             # train() returns at this global_step
+    body: Callable               # dispatches one rollout (`_rollout_body`)
+    counted_to: int = -1         # newest rollout a no-step was charged for
+    # the rollout source's handles (`_ensure_handles`): the orchestrator
+    # or a RolloutStream, and the overlap meter of whichever it is
+    use_orch: bool = False
+    orch: Any = None
+    stream: Optional["RolloutStream"] = None
+    meter: Any = None
+
+
+@dataclasses.dataclass
+class Update:
+    """One update's record: what each phase hands the next. A phase sets
+    the fields under its name; a later phase may narrow them (selection
+    cuts `responses`, `decoded` and `log_scores` to the kept rows)."""
+    t0: float
+    span_t0: float = 0.0
+    no_step: Optional[NoStep] = None
+    span_args: dict = dataclasses.field(default_factory=dict)
+    # rollout
+    ro: Optional[dict] = None    # the rollout payload
+    rollout_index: int = -1
+    staleness: int = 0
+    queue_depth: int = 0
+    responses: Any = None        # [rows, T] tokens (numpy from `reward` on)
+    captured_lp: Any = None      # sampler logprobs, when captured
+    t_busy0: float = 0.0
+    queries: Any = None          # [B, ctx] prompts as generated
+    batch_size: int = 0
+    context_length: int = 0      # context width of the scored batch
+    # reward
+    question_strings: Optional[list] = None
+    decoded: Optional[list] = None
+    seg_ages: Any = None         # per-token policy age (in-flight swaps)
+    envp: Optional[dict] = None  # multi-turn environment payload
+    raw_scores: Any = None       # rewards of all B*n rollouts, as graded
+    scores: Any = None           # ... as the advantage reads them
+    # select
+    log_scores: Any = None       # raw scores of the rows still in the batch
+    grpo_adv: Any = None         # group advantage of the kept rows
+    env_turn: Optional[tuple] = None   # (turn advantages, turn ends)
+    env_loss_mask: Any = None
+    queries_rep: Any = None      # prompts row-aligned with `responses`
+    qr_len: Any = None           # real tokens a row (length-bucketed phases)
+    # score
+    qr: Any = None               # [rows, ctx + T] scored tokens
+    logprobs: Any = None
+    ref_logprobs: Any = None
+    router_l: list = dataclasses.field(default_factory=list)
+    # advantages
+    postprocessed: Any = None    # responses cut at the stop token
+    padding_mask: Any = None
+    contain_eos: Any = None
+    scores_sel: Any = None
+    reward_info: Optional[dict] = None
+    batch: Optional[dict] = None     # arrays of the update's minibatches
+    # update
+    lr_step: int = 0
+    logits_rows: int = 1         # rows of one update-pass logits buffer
+    all_stats: Optional[list] = None
+    agg: Optional[dict] = None
+    # report, checkpoint
+    extra_metrics: dict = dataclasses.field(default_factory=dict)
+    metrics: dict = dataclasses.field(default_factory=dict)
+    saved: bool = False
+
+
 class RolloutStream:
     """Prefetchable rollout dispatcher over a stateless generation PRNG.
 
@@ -216,8 +306,10 @@ class RolloutStream:
     rollout if one is pending and records its index in
     `trainer.state["rollouts"]` — the consumed-rollout counter that
     checkpoint/resume persists to fast-forward the data stream and re-key
-    generation exactly (Sparse-GRPO skip-updates consume a rollout without
-    advancing global_step, so global_step alone under-counts).
+    generation exactly (an update can end without a step: a sparse all-zero
+    skip consumes a rollout, a sentinel quarantine burns one, so global_step
+    alone under-counts). The one loop owns one stream a `train()` call; the
+    orchestrator takes its place when `rollout_orchestrator` is on.
 
     Generation keys are `fold_in(base, index)` rather than splits of the
     evolving trainer key: rollout_ahead dispatches rollout k+1 before update
@@ -465,7 +557,8 @@ class RLTrainer:
 
         # ONE meter for the whole trainer lifetime (stream objects are
         # recreated per train() call): the rollout/train overlap fraction
-        # accumulates across calls — how bench invokes training
+        # accumulates across calls (`benchmark/drivers/rl.py` calls
+        # train(num_updates=1) in a loop)
         self._rollout_meter = OverlapMeter()
 
         self.key = rng_key if rng_key is not None else jax.random.PRNGKey(config.seed)
@@ -593,8 +686,8 @@ class RLTrainer:
         # ---- telemetry (telemetry/, docs/OBSERVABILITY.md) ---------------
         # Span tracer + flight recorder: off by default — disabled, every
         # recording call is a cheap no-op, so the instrumentation stays
-        # inline unconditionally (bench's telemetry A/B is the overhead
-        # gate). The MFU/throughput accounting below is plain arithmetic
+        # inline unconditionally (tests/test_telemetry.py holds the no-op).
+        # The MFU/throughput accounting below is plain arithmetic
         # and is emitted regardless of the flag.
         self.tracer = SpanTracer(
             enabled=config.telemetry,
@@ -602,8 +695,7 @@ class RLTrainer:
             ring_len=config.flight_recorder_len,
         )
         self._telemetry_dir = config.telemetry_dir or config.output_dir
-        # analytic model-FLOPs inputs (telemetry/mfu.py — the same napkin
-        # model bench.py uses, so the two MFU series cannot drift)
+        # analytic model-FLOPs inputs (telemetry/mfu.py's napkin model)
         self._flops_params = flops_param_count(self.params)
         self._peak_flops, self._peak_flops_known = peak_flops_per_chip(
             jax.devices()[0].device_kind, jax.default_backend()
@@ -627,7 +719,7 @@ class RLTrainer:
         # reward, outcome, drop — as rotated JSONL under
         # <telemetry_dir>/lineage/. Off by default; disabled, every emit is
         # a cheap no-op so the instrumentation stays inline unconditionally
-        # (bench's detail.lineage A/B is the overhead gate). The key_path
+        # (tests/test_lineage.py holds the no-op). The key_path
         # string documents the generation-PRNG derivation on lease events
         # (RolloutStream.dispatch below holds the actual fold_in).
         self.lineage = LineageLedger(
@@ -640,8 +732,7 @@ class RLTrainer:
         # one mergeable log-bucketed histogram per latency/* key — TTFT,
         # inter-token gap, queue wait, RPC RTT, reward wall, phase
         # durations. Disabled, record() is a cheap no-op so every
-        # instrumentation site stays inline (bench's detail.latency A/B
-        # is the overhead gate).
+        # instrumentation site stays inline (tests/test_latency.py).
         self.latency = LatencyHub(enabled=config.latency)
         # cross-request radix prefix cache (rollout_prefix_cache, serving/
         # radix.py, docs/SERVING.md): the queued rollout path admits rows
@@ -885,7 +976,8 @@ class RLTrainer:
         share the consumer surface, so everything downstream (watchdog,
         sentinel, checkpoints) is mode-blind. The pipeline outlives train()
         calls — it stays warm across repeated train(num_updates=1)
-        invocations (how bench measures) — and is torn down by close() or
+        invocations (how `benchmark/drivers/rl.py` measures) — and is torn
+        down by close() or
         resume_from_checkpoint()."""
         if self._orchestrator is None:
             cfg = self.cfg
@@ -1030,16 +1122,15 @@ class RLTrainer:
 
     def rollout_overlap_frac(self) -> float:
         """Cumulative rollout/train overlap fraction (orchestrator metric;
-        also measured for serial / rollout_ahead runs) — bench reads this."""
+        also measured for serial / rollout_ahead runs); the row's
+        `time/rollout_overlap_frac` (tests/test_orchestrator.py)."""
         return self._rollout_meter.overlap_fraction()
 
     @staticmethod
     def _spec_decode_metrics(spec_stats) -> dict:
         """rollout/draft_acceptance + accepted_per_step + spec_verify_steps
-        rows (docs/METRICS.md) from a speculative-decode stats dict — the
-        ONE definition of these metrics, shared by the dense and sparse
-        loops so the two runtimes can never report differently-defined
-        series under the same names. {} when the lever is off."""
+        rows (docs/METRICS.md) from a speculative-decode stats dict. {} when
+        the lever is off."""
         if spec_stats is None:
             return {}
         v_steps = float(np.asarray(spec_stats["verify_steps"]))
@@ -1061,8 +1152,7 @@ class RLTrainer:
     @staticmethod
     def _paged_metrics(paged_stats) -> dict:
         """rollout/page_utilization + pages_recycled + admitted_midloop rows
-        (docs/METRICS.md) from a paged-cache stats dict — shared by the
-        dense and sparse loops like `_spec_decode_metrics`. The monolithic
+        (docs/METRICS.md) from a paged-cache stats dict. The monolithic
         paged path reports utilization with zero recycling/admissions; the
         continuous-batching scheduler reports all three. {} when
         rollout_page_size is off."""
@@ -1105,10 +1195,10 @@ class RLTrainer:
                       train_tokens: float, rollout_s: float,
                       update_s: float) -> dict:
         """Per-update throughput/MFU rows (docs/METRICS.md `perf/*`): the
-        analytic napkin FLOPs model from telemetry/mfu.py (shared with
-        bench.py — one formula, two consumers). Token counts come from the
-        caller's actual per-phase work; the dense and sparse loops both
-        feed this, so the two runtimes report comparable series.
+        analytic napkin FLOPs model from telemetry/mfu.py. Token counts come
+        from the update's record: the rows each phase actually ran, at
+        their widths (the sparse trainer's kept rows and bucket widths
+        included).
 
         `perf/tokens_per_sec_rollout` divides by the trainer-OBSERVED
         rollout phase seconds: under the orchestrator that window is just
@@ -1127,7 +1217,7 @@ class RLTrainer:
             / (self._peak_flops * self._n_devices),
             # 0.0 = the peak-FLOPs table fell back to a nominal constant
             # (e.g. CPU 1e12) and perf/mfu above is not a trustworthy
-            # utilization number — consumers (bench, /statusz) flag it
+            # utilization number — consumers (/statusz) flag it
             "perf/peak_flops_known": 1.0 if self._peak_flops_known else 0.0,
             "perf/tokens_per_sec_step": all_tokens / max(step_wall_s, 1e-9),
             "perf/tokens_per_sec_update": train_tokens / max(update_s, 1e-9),
@@ -1713,32 +1803,21 @@ class RLTrainer:
         single-model pass runs: ref-free scores the POLICY (unless capture
         already supplies it — then nothing is left to score), ref-full +
         capture scores the REF, ref-full without capture uses the two-model
-        chunk scorer instead. Shared by the dense and sparse loops; the
-        dense loop asks for an expert model's `router_stats` with it."""
+        chunk scorer instead. The dense `_score` asks for an expert model's
+        `router_stats` with it; the sparse trainer's bucketed one does not."""
         if self._ref_free:
             return None if capture else self._single_score_fn(
                 self.lora_scale, router_stats)
         return self._single_score_fn(1.0, router_stats) if capture else None
 
     # ------------------------------------------------------------------ #
-    # the training loop
+    # the training loop: set-up, one list of phases an update, the tail
     # ------------------------------------------------------------------ #
 
     def train(self, num_updates: Optional[int] = None):
         cfg = self.cfg
-        tok = self.tokenizer
-        pad_id, eos_id = tok.pad_token_id, tok.eos_token_id
-        stop_id = eos_id if cfg.stop_token == "eos" else None
-        score_fn = self._score_chunk_fn()
-
         n = cfg.sample_n if self.algo in (AlgoName.GRPO, AlgoName.RLOO, AlgoName.RAFT) else 1
         capture = cfg.sampler_logprob_capture
-        # with truncated-IS correction the captured logprobs are the STALE
-        # behavior policy's — they feed the IS weights, not the "old"
-        # logprobs the clip ratio needs, so the policy scoring pass must
-        # still run (score_capture=False) to measure π_old on the current
-        # params
-        score_capture = capture and not self._use_is
         sampling = SamplingParams(
             temperature=cfg.temperature, top_p=cfg.top_p, n=n,
             max_tokens=cfg.response_length, capture_logprobs=capture,
@@ -1763,969 +1842,62 @@ class RLTrainer:
                 page_size=cfg.rollout_page_size,
                 decode_rows=cfg.rollout_decode_rows,
             )
-
         # after a resume, the default budget is the REMAINING updates, not a
         # fresh full run
         n_updates = (
             max(0, cfg.num_total_batches - self.state["global_step"])
             if num_updates is None else num_updates
         )
-        from nanorlhf_tpu.trainer.bucketing import depad_queries, shape_menu
-
+        # rollout context widths: r1's de-padding menu (None: as loaded)
         ctx_menu = shape_menu(self.dataset.input_ids.shape[1], min_value=16) \
             if hasattr(self.dataset, "input_ids") else None
-
-        def rollout_body(queries, gen_key, gen_tree=None, gen_mesh=None,
-                         weight_refresh=None):
-            """DISPATCH one rollout (async — nothing blocks until fetched).
-            `gen_tree` (orchestrated mode) is a published weight-store
-            snapshot; None samples from the live params. `gen_mesh` (fleet
-            × disaggregation) is the calling worker's own device group;
-            None generates on the shared rollout/train mesh.
-            `weight_refresh` (rollout_inflight_swaps) is the store/transport
-            poll callback; raw host snapshots it yields are converted to
-            rollout-ready params here before the decode driver installs
-            them (docs/ORCHESTRATOR.md §in-flight swaps)."""
-            if ctx_menu is not None:
-                # r1's de-padding applied to every algorithm: batches of short
-                # prompts roll out / score at a menu-rounded context (warm jit
-                # cache) instead of the dataset-wide pad width
-                queries = depad_queries(queries, pad_id, ctx_menu)
-            if self._sp_on():
-                self._sp_check_widths(queries.shape[1])
-            bs = batch_sharding(
-                gen_mesh if gen_mesh is not None
-                else self.mesh if self.rollout_mesh is None
-                else self.rollout_mesh
-            )
-            queries_j = jax.device_put(jnp.asarray(queries), bs)
-            prompt_mask = queries_j != pad_id
-            gen_params = self._rollout_params(gen_tree, mesh=gen_mesh)
-            gen_refresh = None
-            if weight_refresh is not None:
-                def gen_refresh():
-                    # device-place a fresh snapshot exactly like the
-                    # dispatch tree so a swap cannot change sharding; a
-                    # (version, None) poll result passes through untouched
-                    version, tree = weight_refresh()
-                    if tree is None:
-                        return version, None
-                    return version, self._rollout_params(tree, mesh=gen_mesh)
-            # speculative decode (rollout_spec_k > 0) appends its acceptance
-            # counters here — device scalars fetched at metrics time, after
-            # the tokens already forced a sync. The tracer hands the spec
-            # path its instrumented driver (draft/verify spans on the
-            # "rollout" track) when telemetry is on; a disabled tracer is
-            # ignored.
-            spec_stats: list = []
-            paged_stats: list = []
-            if self._env_multi_turn:
-                from nanorlhf_tpu.envs.rollout import run_env_episodes
-
-                payload = run_env_episodes(
-                    gen_params, self._rollout_mcfg, queries_j, prompt_mask,
-                    gen_key, sampling, self.env,
-                    eos_token_id=eos_id, pad_token_id=pad_id, tokenizer=tok,
-                    max_turns=cfg.env_max_turns,
-                    turn_tokens=sampling.max_tokens,
-                    obs_budget=cfg.env_obs_budget,
-                    response_length=cfg.response_length,
-                    page_size=cfg.rollout_page_size,
-                    decode_rows=(cfg.env_decode_rows
-                                 or cfg.rollout_decode_rows),
-                    lora_scale=self.lora_scale, faults=self.faults,
-                )
-                return {"queries": queries, "gen_out": payload["tokens"],
-                        "greedy": None, "spec_stats": None,
-                        "paged_stats": None, "env": payload}
-            gen_out = generate(
-                gen_params, self._rollout_mcfg, queries_j, prompt_mask, gen_key,
-                sampling, eos_token_id=eos_id, pad_token_id=pad_id,
-                lora_scale=self.lora_scale, batch_sharding=bs,
-                spec_stats_out=spec_stats, tracer=self.tracer,
-                paged_stats_out=paged_stats, latency=self.latency,
-                prefix_cache=self.prefix_cache,
-                weight_refresh=gen_refresh,
-            )                                               # [B*n, T]
-            greedy = None
-            if self.algo == AlgoName.REMAX:
-                # extra greedy rollout as baseline (`ReMax/remax_trainer.py:166-185`)
-                greedy = generate(
-                    gen_params, self._rollout_mcfg, queries_j, prompt_mask, gen_key,
-                    SamplingParams(greedy=True, max_tokens=cfg.response_length),
-                    eos_token_id=eos_id, pad_token_id=pad_id,
-                    lora_scale=self.lora_scale,
-                )
-            out = {"queries": queries, "gen_out": gen_out, "greedy": greedy,
-                   "spec_stats": spec_stats[0] if spec_stats else None,
-                   "paged_stats": paged_stats[0] if paged_stats else None}
-            if weight_refresh is not None and paged_stats:
-                # hoist swap provenance to the payload top level: the
-                # lineage ledger (telemetry.segments_summary) and the
-                # per-segment IS batch assembly read it from here
-                ps = paged_stats[0]
-                for k in ("segments", "swap_installs", "swap_wait_s"):
-                    if k in ps:
-                        out[k] = ps[k]
-            return out
-
-        from nanorlhf_tpu.orchestrator import ProducerFailed
-        from nanorlhf_tpu.resilience import Preempted, ProducerWatchdog
-
-        use_orch = False
-        orch, stream, meter = None, None, None
-
-        def ensure_handles():
-            """(Re)build the rollout source after construction, a sentinel
-            rollback (which tears the orchestrator down), or a watchdog
-            degradation (which turns the orchestrated run synchronous)."""
-            nonlocal use_orch, orch, stream, meter
-            use_orch = cfg.rollout_orchestrator and not self.watchdog.degraded
-            if use_orch:
-                orch = self._ensure_orchestrator(rollout_body)
-                stream, meter = None, orch.meter
-            else:
-                orch = None
-                if stream is None:
-                    stream = RolloutStream(
-                        self, rollout_body, meter=self._rollout_meter
-                    )
-                meter = stream.meter
-
-        def degrade_to_sync():
-            """Watchdog budget exhausted: log the mode transition, tear the
-            pipeline down, and fall back to synchronous rollouts (staleness
-            0) from the consumed cursor instead of killing the run."""
-            nonlocal stream
-            print(
-                "[resilience] producer restart budget "
-                f"({cfg.producer_restart_budget}) exhausted — degrading to "
-                "synchronous rollouts (staleness 0)"
-            )
-            if self._orchestrator is not None:
-                # keep the queue's cumulative dropped/staleness counters:
-                # _save_checkpoint journals them from _orch_restore_state in
-                # degraded mode so the metric series stays continuous across
-                # a later resume (the same continuity _restart_producer has)
-                self._orch_restore_state = self._orchestrator.journal()
-                self._orchestrator.close(join_timeout=5.0)
-                self._orchestrator = None
-            self._reset_data_iterator()
-            stream = None  # force a fresh stream at the restored cursor
-            ensure_handles()
-
-        def fetch_sample():
-            """One device-ready rollout, supervised: a dead producer is
-            restarted with backoff up to the watchdog budget (then the run
-            degrades to sync), and sentinel-quarantined batches are consumed
-            and discarded so a post-rollback replay skips the offending
-            data instead of re-deriving the same divergence."""
-            nonlocal orch, sample_staleness, queue_depth
-            while True:
-                if use_orch:
-                    try:
-                        sample = orch.get()
-                    except ProducerFailed as e:
-                        # flight recorder first: the blackbox must capture
-                        # what every thread was doing when the producer
-                        # died, before the restart machinery mutates state
-                        extra = {"error": repr(e.__cause__ or e)}
-                        if hasattr(orch, "fleet_stats"):
-                            # fleet post-mortem: membership/lease/quarantine
-                            # counters at the moment of exhaustion
-                            extra["fleet"] = orch.fleet_stats()
-                        self.tracer.dump_blackbox(
-                            self._telemetry_dir, self.state["global_step"],
-                            "producer_failure", extra=extra,
-                        )
-                        decision, delay = self.watchdog.on_failure()
-                        if decision == ProducerWatchdog.RESTART:
-                            cause = e.__cause__ or e
-                            print(
-                                "[resilience] rollout producer died "
-                                f"({type(cause).__name__}: {cause}) — restart "
-                                f"{self.watchdog.restarts_total} in {delay:.1f}s"
-                            )
-                            time.sleep(delay)
-                            orch = self._restart_producer(rollout_body)
-                            continue
-                        if decision == ProducerWatchdog.DEGRADE:
-                            degrade_to_sync()
-                            continue
-                        raise
-                    self.watchdog.on_success()
-                    ro = sample.payload
-                    ro["_index"] = sample.index
-                    self.state["rollouts"] = sample.index + 1
-                    sample_staleness = orch.version - sample.version
-                    queue_depth = orch.queue.depth()
-                else:
-                    # quarantined indices are skipped BEFORE dispatch (zero
-                    # rollout cost) — unless a prefetch already paid for one,
-                    # which the post-fetch discard below handles
-                    while (stream._pending is None
-                           and stream.next_index in self.sentinel.quarantined):
-                        idx = stream.skip()
-                        print(
-                            f"[resilience] skipping quarantined rollout "
-                            f"{idx} (sentinel rollback; not dispatched)"
-                        )
-                    ro = stream.fetch_or_dispatch()
-                if ro["_index"] in self.sentinel.quarantined:
-                    # already-generated sample (orchestrated pipeline or a
-                    # serial prefetch): discard it; the producer gate gets a
-                    # skip credit (no version publish)
-                    print(
-                        f"[resilience] skipping quarantined rollout "
-                        f"{ro['_index']} (sentinel rollback)"
-                    )
-                    self.lineage.drop(
-                        ro["_index"], "sentinel_quarantine",
-                        step=self.state["global_step"], dispatched=True,
-                    )
-                    if use_orch:
-                        orch.consumed_without_update()
-                    continue
-                return ro
-
-        ensure_handles()
+        run = TrainRun(
+            n=n, capture=capture,
+            # with truncated-IS correction the captured logprobs are the
+            # STALE behavior policy's — they feed the IS weights, not the
+            # "old" logprobs the clip ratio needs, so the policy scoring
+            # pass must still run (score_capture=False) to measure π_old on
+            # the current params
+            score_capture=capture and not self._use_is,
+            target_step=self.state["global_step"] + n_updates,
+            # the body holds what it reads and no more: an orchestrator
+            # keeps it across train() calls
+            body=partial(self._rollout_body, sampling, ctx_menu),
+        )
+        self._ensure_handles(run)
         # whole-rollout drops (queue stale_drop, fleet late-duplicate) are
         # denominated in samples via this hint — one rollout = batch_size*n
         # completion rows
         self.lineage.rows_hint = cfg.batch_size * n
-        sample_staleness, queue_depth = 0, 0
-        target_step = self.state["global_step"] + n_updates
-        while self.state["global_step"] < target_step:
-            t_start = time.perf_counter()  # sec_per_episode is a duration
-            step_t0 = time.perf_counter()
+        if self.state["global_step"] == 0:
+            first = self._evaluate(0)
+            if first:
+                self.logger.log(0, 0, first)
+        phases = (self._rollout, self._reward, self._select, self._score,
+                  self._advantages, self._update, self._guard, self._publish,
+                  self._report, self._checkpoint)
+        while self.state["global_step"] < run.target_step:
+            up = Update(t0=time.perf_counter())  # sec_per_episode is a duration
             # windowed XLA profiling: open/close the jax.profiler window
             # for the update about to run (cfg.profile_at_step or the
             # on-demand trigger file)
             self.profile_window.poll(self.state["global_step"] + 1)
-            # per-update trace span: recorded via add_complete at the end
-            # of the iteration (a with-block could not survive the sentinel
-            # rollback's `continue`)
-            span_t0 = self.tracer.now_us() if self.tracer.enabled else 0.0
-
-            # ---- ROLLOUT -------------------------------------------------
-            with self.timer.phase("rollout"):
-                ro = fetch_sample()
-                rollout_index = ro["_index"]
-                if capture:
-                    responses, captured_lp = ro["gen_out"]
-                    captured_lp = np.asarray(captured_lp)
-                else:
-                    responses, captured_lp = ro["gen_out"], None
-                jax.block_until_ready(responses)
-                greedy_responses = ro["greedy"]
-                if greedy_responses is not None:
-                    greedy_responses.block_until_ready()
-            # overlap meter: consumer busy from here (perf_counter — must
-            # share the producers' gen-window clock or intersections die)
-            t_busy0 = time.perf_counter()
-            if not use_orch and self.lineage.enabled:
-                # serial / rollout_ahead path has no producer thread to emit
-                # this: generation provenance lands here, once the arrays
-                # are device-ready (policy version == global_step — the same
-                # convention the trace spans use without an orchestrator)
-                from nanorlhf_tpu.telemetry.lineage import (
-                    segments_summary,
-                    spec_summary,
-                )
-
-                self.lineage.generation(
-                    rollout_index,
-                    policy_version=self.state["global_step"], worker_id=0,
-                    spec=spec_summary(ro),
-                    segments=segments_summary(ro),
-                    swap_wait_s=ro.get("swap_wait_s"),
-                )
-            pstats = ro.get("paged_stats")
-            if pstats is not None:
-                # /statusz "pages" panel reads the latest snapshot; lineage
-                # gets one "lease" event per mid-loop admission so a queued
-                # sample's provenance records WHICH recycled row produced it
-                # and at which decode iteration (runs in every rollout mode)
-                self._pages_status = {
-                    k: (None if pstats[k] is None
-                        else float(np.asarray(pstats[k])))
-                    for k in ("page_utilization", "pages_recycled",
-                              "admitted_midloop", "decode_iterations")
-                }
-                self._pages_status.update(
-                    rows=pstats["rows"], num_pages=pstats["num_pages"],
-                    page_size=pstats["page_size"],
-                )
-                # the continuous-batching scheduler also ships its decode
-                # session's end-of-call status for /statusz "session";
-                # the monolithic paged paths have no session
-                self._session_status = pstats.get("session")
-                if self.lineage.enabled:
-                    for adm in pstats.get("admissions") or []:
-                        self.lineage.event(
-                            "lease", rollout_index, midloop=True,
-                            row=adm["row"], queue_index=adm["queue_index"],
-                            iteration=adm["iteration"],
-                        )
-            self.state["episode"] += cfg.batch_size
-            queries = ro["queries"]
-            batch_size, context_length = queries.shape
-            if (not use_orch and cfg.rollout_ahead
-                    and self.state["global_step"] + 1 < target_step):
-                # dispatch rollout k+1 NOW (from the pre-update-k params, one
-                # update stale): the device generates while the host below
-                # decodes/grades update k's batch
-                stream.prefetch()
-
-            # ---- REWARD (host-side, user callable) -------------------------
-            question_strings = [
-                q.replace(tok.pad_token, "") for q in tok.batch_decode(queries)
-            ]
-            question_n = [q for q in question_strings for _ in range(n)]
-            responses_np = np.asarray(responses)
-            seg_ages = None
-            if self._use_seg and ro.get("segments") is not None:
-                # per-token policy AGE (newest version that produced any
-                # token of the row, minus the token's own segment version)
-                # in response coordinates — the same [0, total) space the
-                # scheduler's segment tok_ranges tile. Rows untouched by a
-                # swap are all-zero, and zero ages make segment_is_weights
-                # reduce bit-exactly to the whole-sequence weight.
-                seg_ages = np.zeros(responses_np.shape, np.int32)
-                for r, segs in enumerate(ro["segments"]):
-                    newest = max(s["policy_version"] for s in segs)
-                    for s in segs:
-                        lo, hi = s["tok_range"]
-                        if newest > s["policy_version"]:
-                            seg_ages[r, lo:hi] = newest - s["policy_version"]
-            responses_decoded = tok.batch_decode(responses_np)
-            envp = ro.get("env")
-            with self.timer.phase("reward"):
-                if envp is not None:
-                    # multi-turn env: rewards accrued turn-by-turn inside
-                    # the episode driver (the terminal grader already ran
-                    # per episode) — no separate dispatch. Lineage gets the
-                    # usual reward event plus one `turn` event per
-                    # (episode row, turn), joinable to this rollout's
-                    # generation event on rollout_index.
-                    scores = np.asarray(envp["scores"], np.float32)
-                    if self.lineage.enabled:
-                        self.lineage.reward(
-                            rollout_index, step=self.state["global_step"],
-                            scores=[round(float(s), 6) for s in scores],
-                            attempt=1,
-                            wall_s=envp["stats"]["env/tool_wall_s"],
-                        )
-                        for rec in envp["turns"]:
-                            self.lineage.turn(
-                                rollout_index,
-                                step=self.state["global_step"], **rec,
-                            )
-                else:
-                    scores = self._dispatch_reward(
-                        [q + r for q, r in zip(question_n, responses_decoded)],
-                        tok.eos_token,
-                        rollout_index=rollout_index,
-                        step=self.state["global_step"],
-                    )
-            log_scores_all = scores.copy()  # raw sampled-rollout scores for logging
-            if greedy_responses is not None:
-                greedy_decoded = tok.batch_decode(np.asarray(greedy_responses))
-                greedy_scores = self._dispatch_reward(
-                    [q + r for q, r in zip(question_strings, greedy_decoded)],
-                    tok.eos_token,
-                )
-                # score − score_greedy is the ReMax advantage seed
-                # (`ReMax/remax_trainer.py:506-513`); raw scores still logged
-                scores = np.asarray(
-                    remax_advantage(jnp.asarray(scores), jnp.asarray(greedy_scores))
-                )
-
-            # ---- GRPO: group advantage + keep-1-of-N BEFORE scoring --------
-            grpo_adv = None
-            env_turn_adv = env_turn_ends = env_loss_mask = None
-            if self.algo == AlgoName.GRPO:
-                adv_flat = np.asarray(grpo_group_advantage(jnp.asarray(scores), n))
-                self.key, k = jax.random.split(self.key)
-                keep = np.asarray(keep_one_of_n_indices(k, batch_size, n))
-                rows = np.arange(batch_size)
-                grpo_adv = adv_flat.reshape(batch_size, n)[rows, keep]
-                if envp is not None:
-                    # per-turn advantages z-score each turn column against
-                    # the FULL group (all N siblings) before the keep
-                    # filter drops N−1 of them, mirroring the episode-level
-                    # baseline above; the turn-end positions and the
-                    # observation loss_mask ride the same selection
-                    t_adv = np.asarray(grpo_turn_advantage(
-                        jnp.asarray(envp["turn_rewards"]), n))
-                    env_turn_adv = t_adv.reshape(
-                        batch_size, n, -1)[rows, keep]
-                    env_turn_ends = np.asarray(envp["turn_ends"]).reshape(
-                        batch_size, n, -1)[rows, keep]
-                    env_loss_mask = np.asarray(envp["loss_mask"]).reshape(
-                        batch_size, n, -1)[rows, keep]
-                responses_np = responses_np.reshape(batch_size, n, -1)[rows, keep]
-                if captured_lp is not None:
-                    captured_lp = captured_lp.reshape(batch_size, n, -1)[rows, keep]
-                if seg_ages is not None:
-                    seg_ages = seg_ages.reshape(batch_size, n, -1)[rows, keep]
-                log_scores = log_scores_all.reshape(batch_size, n)[rows, keep]
-                responses_decoded = [
-                    responses_decoded[i * n + j] for i, j in enumerate(keep)
-                ]
-                if n > 1:
-                    # the other n−1 completions per prompt leave the batch
-                    # here: attribute them like any other exclusion
-                    self.lineage.drop(
-                        rollout_index, "keep_filter",
-                        count=batch_size * (n - 1),
-                        step=self.state["global_step"],
-                    )
-                queries_rep = queries
-            else:
-                queries_rep = np.repeat(queries, n, axis=0) if n > 1 else queries
-                log_scores = log_scores_all
-
-            # ---- LOGPROB PASS (chunked, jitted) ----------------------------
-            qr = np.concatenate([queries_rep, responses_np], axis=1)
-            total = qr.shape[0]
-            # the vocab-cap lift only applies when the fused scorer actually
-            # runs — an sp mesh routes scoring through sp_score_logprobs,
-            # which still materializes per-shard [chunk, T/sp, V] logits
-            chunk = cfg.local_rollout_forward_batch_size or max(
-                1,
-                forward_token_budget(
-                    self.mcfg.vocab_size,
-                    fused_logprob=cfg.fused_logprob and not self._sp_on(),
-                )
-                // (context_length + cfg.response_length),
-            )
-            chunk = max(1, min(total, chunk))
-            logprobs_l, ref_logprobs_l, router_l = [], [], []
-            ref_free = self._ref_free
-            one_fn = self._single_scorer_for(score_capture, router_stats=True)
-
-            def scored(out, n_real):
-                """Logprob arrays of one chunk, cut to its real rows; an
-                expert model's scorer appends its router sums (ops/moe.py),
-                which are kept for the row's `moe/*` counters."""
-                out = out if isinstance(out, tuple) else (out,)
-                if isinstance(out[-1], dict):
-                    router_l.append(jax.tree.map(
-                        lambda a: np.asarray(a)[:n_real] if a.ndim
-                        else np.asarray(a), out[-1]))
-                    out = out[:-1]
-                return [np.asarray(a)[:n_real] for a in out]
-
-            with self.timer.phase("logprob"):
-                if ref_free and score_capture:
-                    # zero scoring forwards: policy logprobs came from the
-                    # sampler, and there is no reference model (kl_coef 0 —
-                    # the reference's r1 path, `grpo_r1.py:138`)
-                    pass
-                else:
-                    for i in range(0, total, chunk):
-                        n_real = min(chunk, total - i)
-                        rows_c = jnp.asarray(pad_chunk(qr[i : i + chunk], chunk))
-                        if ref_free:
-                            # policy-only forward (adapters applied)
-                            lp, = scored(one_fn(
-                                self.params, rows_c, context_length), n_real)
-                            logprobs_l.append(lp)
-                        elif score_capture:
-                            # policy logprobs came from the sampler; only the
-                            # ref pass runs — half the scoring forwards
-                            rlp, = scored(one_fn(
-                                self.ref_params, rows_c, context_length),
-                                n_real)
-                            ref_logprobs_l.append(rlp)
-                        else:
-                            lp, rlp = scored(score_fn(
-                                self.params, self.ref_params, rows_c,
-                                context_length,
-                            ), n_real)
-                            logprobs_l.append(lp)
-                            ref_logprobs_l.append(rlp)
-            logprobs = (
-                captured_lp if score_capture else np.concatenate(logprobs_l)
-            ).astype(np.float32)
-            # ref == policy-old in ref-free mode: every KL term and metric
-            # reads exactly 0, matching "no reference model"
-            ref_logprobs = (
-                logprobs.copy() if ref_free else np.concatenate(ref_logprobs_l)
-            )
-
-            # ---- response post-processing ---------------------------------
-            responses_j = jnp.asarray(responses_np)
-            postprocessed = responses_j
-            if stop_id is not None and envp is None:
-                # multi-turn episodes carry INTERIOR per-turn EOS tokens the
-                # stop-token truncation would cut at; the driver already
-                # packed real tokens left-justified with pads only at the
-                # tail, so the first-pad seq_lengths below stay correct
-                postprocessed = truncate_response(stop_id, pad_id, responses_j)
-            seq_lengths = np.asarray(first_true_indices(postprocessed == pad_id) - 1)
-            padding_mask, padding_mask_p1 = response_padding_masks(
-                np.asarray(postprocessed), jnp.asarray(seq_lengths)
-            )
-            padding_mask = np.asarray(padding_mask)
-            padding_mask_p1 = np.asarray(padding_mask_p1)
-            logprobs = np.where(padding_mask, INVALID_LOGPROB, logprobs)
-            ref_logprobs = np.where(padding_mask, INVALID_LOGPROB, ref_logprobs)
-            behavior_lp = None
-            if self._use_is:
-                # the STALE sampling policy's logprobs, masked exactly like
-                # `logprobs` so the IS weight is 1 at padded positions
-                behavior_lp = np.where(
-                    padding_mask, INVALID_LOGPROB, captured_lp
-                ).astype(np.float32)
-
-            contain_eos = (np.asarray(postprocessed) == eos_id).any(axis=1)
-            scores_sel = grpo_adv if self.algo == AlgoName.GRPO else scores
-            if cfg.missing_eos_penalty is not None:
-                scores_sel = scores_sel.copy()
-                scores_sel[~contain_eos] -= cfg.missing_eos_penalty
-
-            # ---- per-algo advantage assembly ------------------------------
-            batch, keep_inds, reward_info = self._assemble_batch(
-                scores_sel, logprobs, ref_logprobs, padding_mask, padding_mask_p1,
-                seq_lengths, qr, responses_np, context_length, batch_size, n,
-                behavior_lp=behavior_lp,
-                turn_info=((env_turn_adv, env_turn_ends)
-                           if env_turn_adv is not None else None),
-            )
-            if env_loss_mask is not None:
-                # observation/tool tokens: conditioned on, never scored.
-                # The key is only present in env multi-turn runs, so every
-                # other mode compiles the identical jitted update.
-                batch["loss_mask"] = env_loss_mask
-            if seg_ages is not None:
-                # key present only under rollout_inflight_swaps (same
-                # conditional-key pattern as loss_mask above): swaps off
-                # compiles the identical jitted update
-                if keep_inds is not None:
-                    # RLOO/RAFT keep-1-of-N happens below, AFTER batch
-                    # assembly — realign the ages the same way
-                    seg_ages = seg_ages.reshape(batch_size, n, -1)[
-                        np.arange(batch_size), keep_inds
-                    ]
-                batch["segment_ages"] = seg_ages
-
-            if keep_inds is not None:
-                # RLOO/RAFT selected 1-of-N *after* the logprob pass; realign
-                # the decoded strings/scores used for the sample table
-                responses_decoded = [
-                    responses_decoded[i * n + j] for i, j in enumerate(keep_inds)
-                ]
-                log_scores = log_scores.reshape(batch_size, n)[
-                    np.arange(batch_size), keep_inds
-                ]
-                self.lineage.drop(
-                    rollout_index, "keep_filter",
-                    count=batch_size * (n - 1),
-                    step=self.state["global_step"],
-                )
-
-            # ---- PPO-epoch / minibatch / microbatch update ----------------
-            trainable, frozen = self._partition(
-                self._train_tree(self.params, self.value_params)
-            )
-            all_stats = []
-            local_bs = batch["responses"].shape[0]
-            mini = max(1, local_bs // cfg.num_mini_batches)
-            # lr reported for THIS update = schedule at the step count its
-            # first optimizer.update saw (the reference's get_last_lr-before-
-            # scheduler.step semantics, `grpo_trainer.py:744-750`)
-            lr_step = self.state["opt_steps"]
-            with self.timer.phase("update"):
-                for epoch in range(cfg.num_ppo_epochs):
-                    self.key, pk = jax.random.split(self.key)
-                    perm = np.asarray(jax.random.permutation(pk, local_bs))
-                    for start in range(0, local_bs - mini + 1, mini):
-                        inds = perm[start : start + mini]
-                        mb = {
-                            k: jax.device_put(
-                                jnp.asarray(v[inds]),
-                                batch_sharding(self.mesh, np.asarray(v).ndim),
-                            )
-                            for k, v in batch.items()
-                        }
-                        trainable, self.opt_state, stats = self._update_fn(
-                            trainable, frozen, self.opt_state, mb, context_length
-                        )
-                        self.state["opt_steps"] += 1
-                        # keep stats on device; syncing per minibatch would
-                        # serialize update dispatch
-                        all_stats.append(stats)
-                train_tree = self._combine(trainable, frozen)
-                self.params = train_tree["policy"]
-                self.value_params = train_tree.get("value")
-                all_stats = jax.device_get(all_stats)
-            agg = {
-                k: float(np.mean([s[k] for s in all_stats]))
-                for k in (all_stats[0] if all_stats else {})
-            }
-
-            # ---- SENTINEL (resilience/, docs/RESILIENCE.md) ----------------
-            # checked BEFORE the weight-store publish so a tripped step never
-            # feeds poisoned weights to the producer. The update.step fault
-            # poisons the OBSERVED stats (action=nan) — same code path a real
-            # NaN loss/grad takes, without hand-corrupting device arrays.
-            if self.faults.fire("update.step") == "nan":
-                agg["pg_loss"] = float("nan")
-                agg["grad_norm"] = float("nan")
-            verdict = self.sentinel.observe(
-                agg.get("pg_loss", 0.0), agg.get("grad_norm")
-            )
-            if verdict is not None:
-                if self.tracer.enabled:
-                    # close the tripped update's span BEFORE the rollback
-                    # dumps the flight recorder, so the blackbox ring holds
-                    # it — tagged with the quarantined rollout index
-                    self.tracer.add_complete(
-                        "train.update", span_t0,
-                        self.tracer.now_us() - span_t0,
-                        step=self.state["global_step"] + 1,
-                        rollout_index=rollout_index,
-                        staleness=sample_staleness,
-                        policy_version=(orch.version if use_orch
-                                        else self.state["global_step"]),
-                        sentinel_verdict=verdict, quarantined=True,
-                    )
-                self._sentinel_rollback(verdict, rollout_index)
-                # discard the tripped update's phase splits: the continue
-                # skips this iteration's summary() reset, and the replayed
-                # update's time/*_s rows — and the perf/tokens_per_sec_*
-                # divisors that read timer.totals — would otherwise fold in
-                # two updates' worth of wall time
-                self.timer.summary()
-                # the rollback tore the pipeline down and rewound the
-                # data/PRNG cursors — rebuild handles and replay
-                stream = None
-                ensure_handles()
-                continue
-            if use_orch:
-                # one version per optimizer update: snapshot the trainable
-                # leaves (donation hazard) and open the producer's gate
-                with self.timer.phase("publish"):
-                    orch.publish(self._policy_snapshot())
-
-            # ---- METRICS (names + semantics per docs/METRICS.md) -----------
-            sec_per_episode = (time.perf_counter() - t_start) / cfg.batch_size
-            # entropy proxy: summed response negative logprob (the reference's
-            # `(-logprobs).sum(1).mean()`, `GRPO/grpo_trainer.py:710`, with
-            # pad positions masked to 0 instead of contributing the INVALID
-            # sentinel); the true entropy is policy/entropy_avg_new below
-            mean_entropy = float(
-                (-np.where(padding_mask, 0.0, logprobs)).sum(1).mean()
-            )
-            kl_rollout = float(
-                np.where(padding_mask, 0.0, logprobs - ref_logprobs).sum(1).mean()
-            )
-            # GRPO parity: the reference fills kl_old from the UPDATE-pass
-            # new-vs-ref KL stats (`GRPO/grpo_trainer.py:668-670,689,728`);
-            # every KL-in-reward trainer uses the rollout token-sum KL
-            # (`RLOO/rloo_trainer.py:704-706`). kl_rollout_old is always the
-            # honest pre-update measurement.
-            kl_old = (
-                agg.get("refkl_mean", kl_rollout)
-                if self.algo == AlgoName.GRPO else kl_rollout
-            )
-            if self._ref_free:
-                # no reference model exists: GRPO's update-pass refkl stat
-                # would otherwise report KL-to-OLD-POLICY here (ref stands
-                # in as the old logprobs), which is not the metric's meaning
-                kl_old = 0.0
-            metrics = {
-                "objective/kl_old": kl_old,
-                "objective/kl_rollout_old": kl_rollout,
-                "objective/entropy_old": mean_entropy,
-                "objective/non_score_reward_old": reward_info.get(
-                    "non_score_reward_old", 0.0
-                ),
-                "eval_objective/rlhf_reward_old": reward_info.get(
-                    "rlhf_reward_old", float(np.mean(log_scores_all))
-                ),
-                "eval_objective/scores_old": float(np.mean(log_scores_all)),
-                "policy/approxkl_avg_new": agg.get("approxkl", 0.0),
-                "policy/clipfrac_avg_new": agg.get("pg_clipfrac", 0.0),
-                "policy/entropy_avg_new": agg.get("entropy", 0.0),
-                "loss/policy_avg_new": agg.get("pg_loss", 0.0),
-                "val/ratio_new": agg.get("ratio_mean", 1.0),
-                "val/ratio_var_new": float(np.var(
-                    [s.get("ratio_mean", 1.0) for s in all_stats]
-                )) if all_stats else 0.0,
-                "val/num_eos_tokens_old": float(
-                    (np.asarray(postprocessed) == eos_id).sum()
-                ),
-                "lr": float(self._lr_schedules["policy"](lr_step)),
-                "eps": cfg.adam_eps,
-                "sec_per_episode": sec_per_episode,
-                "episode": self.state["episode"],
-            }
-            if "vf_loss" in agg:
-                metrics["loss/value_avg_new"] = agg["vf_loss"]
-                metrics["val/clipfrac_avg_new"] = agg.get("vf_clipfrac", 0.0)
-            if score_capture:
-                # with exact scoring the epoch-1 ratio is identically 1; any
-                # deviation here is decode-vs-scoring numerics — the guard
-                # for the captured-logprob shortcut
-                metrics["sampler_capture/ratio_drift_new"] = abs(
-                    agg.get("ratio_mean", 1.0) - 1.0
-                )
-            # rollout/train overlap fraction: measured for EVERY mode
-            # (serial ≈ 0, rollout_ahead partial, orchestrator highest) —
-            # the bench payload's pipelining signal
-            metrics["time/rollout_overlap_frac"] = meter.overlap_fraction()
-            if router_l:
-                from nanorlhf_tpu.ops.moe import moe_counters
-
-                metrics.update(moe_counters(router_l))
-            metrics.update(self._spec_decode_metrics(ro.get("spec_stats")))
-            metrics.update(self._paged_metrics(ro.get("paged_stats")))
-            if envp is not None:
-                metrics.update(envp["stats"])
-            if use_orch:
-                ostats = orch.stats()
-                metrics.update({
-                    "orchestrator/queue_depth": float(queue_depth),
-                    "orchestrator/staleness": float(sample_staleness),
-                    "orchestrator/dropped_total": float(ostats["dropped"]),
-                    # who-waits-on-whom (cumulative s): trainer starved vs
-                    # producer gated — which side is the bottleneck
-                    "orchestrator/consumer_wait_s": ostats["consumer_wait_s"],
-                    "orchestrator/producer_gate_wait_s": ostats[
-                        "producer_gate_wait_s"
-                    ],
-                })
-                metrics.update(staleness_histogram_metrics(
-                    ostats["staleness_counts"]
-                ))
-                if hasattr(orch, "fleet_stats"):
-                    # fleet/* series (docs/METRICS.md): membership gauges +
-                    # cumulative lease/reassignment/quarantine counters
-                    # (counters survive restart/degrade/resume via the
-                    # coordinator journal, like the queue's)
-                    metrics.update({
-                        f"fleet/{k}": v
-                        for k, v in orch.fleet_stats().items()
-                    })
-            if self._use_is:
-                metrics["offpolicy/is_weight_mean_new"] = agg.get(
-                    "is_weight_mean", 1.0
-                )
-                metrics["offpolicy/is_trunc_frac_new"] = agg.get(
-                    "is_trunc_frac", 0.0
-                )
-            if cfg.rollout_inflight_swaps:
-                # in-flight swap provenance (docs/ORCHESTRATOR.md
-                # §in-flight swaps): installs + the mean number of policy
-                # segments per completion row THIS update consumed (1.0 =
-                # no mid-rollout publish landed), plus the cumulative
-                # install stall this rollout paid (device-put of the fresh
-                # tree at a chunk boundary — the cost drain-and-wait pays
-                # as idle time instead)
-                segs = ro.get("segments")
-                metrics.update({
-                    "rollout/swap_installs": float(
-                        ro.get("swap_installs", 0) or 0),
-                    "rollout/segments_per_sample": (
-                        float(np.mean([len(s) for s in segs]))
-                        if segs else 1.0
-                    ),
-                    "orchestrator/swap_wait_s": float(
-                        ro.get("swap_wait_s", 0.0) or 0.0),
-                })
-            # resilience series (docs/RESILIENCE.md): cumulative counters so
-            # dashboards diff them into rates; degraded_mode is the sticky
-            # sync-fallback flag (0 in healthy pipelined runs)
-            metrics.update({
-                "policy/grad_norm_new": agg.get("grad_norm", 0.0),
-                "resilience/producer_restarts": float(
-                    self.watchdog.restarts_total
-                ),
-                "resilience/rollbacks": float(self.sentinel.rollbacks),
-                "resilience/degraded_mode": float(self.watchdog.degraded),
-                "resilience/ckpt_retries": float(self.ckpt.retry_count),
-                "resilience/ckpt_fallbacks": float(self.ckpt.fallback_count),
-            })
-            # memory series (docs/METRICS.md, docs/FUSED_LOGPROB.md):
-            # peak_bytes_in_use from the backend (0 on CPU), plus the
-            # analytic size of the update-pass full-logits buffer the fused
-            # hidden→logprob path avoids per microbatch (param-dtype logits;
-            # the naive path's old f32 entropy copy is NOT counted — it is
-            # gone in both modes now that the fallback entropy is chunked)
-            n_micro_rows = max(1, mini // cfg.gradient_accumulation_steps)
-            logits_bytes = (
-                n_micro_rows * batch["responses"].shape[1]
-                * self.mcfg.vocab_size
-                * jnp.dtype(self.params["embed_tokens"].dtype).itemsize
-            )
-            metrics.update({
-                "mem/peak_bytes_in_use": device_peak_bytes(),
-                # 0 on an sp mesh too: microbatch_loss takes the sp branch
-                # there and the fused op never runs
-                "mem/logits_bytes_saved": float(
-                    logits_bytes
-                    if cfg.fused_logprob and not self._sp_on() else 0.0
-                ),
-            })
-            # ---- perf/MFU accounting (telemetry/, docs/OBSERVABILITY.md):
-            # token counts from THIS update's actual work — decode at the
-            # configured response_length (the napkin model's convention),
-            # scoring forwards as actually run (0 in ref-free+capture, 1
-            # with capture or ref-free, 2 otherwise)
-            n_rollout_rows = batch_size * n
-            t_resp = batch["responses"].shape[1]
-            score_forwards = (
-                0 if (ref_free and score_capture)
-                else 1 if (ref_free or score_capture) else 2
-            )
-            metrics.update(self._perf_metrics(
-                step_wall_s=time.perf_counter() - step_t0,
-                decode_tokens=n_rollout_rows * cfg.response_length,
-                prefill_tokens=n_rollout_rows * context_length,
-                score_tokens=score_forwards * total
-                * (context_length + cfg.response_length),
-                train_tokens=cfg.num_ppo_epochs * local_bs
-                * (context_length + t_resp),
-                rollout_s=self.timer.totals.get("rollout", 0.0),
-                update_s=self.timer.totals.get("update", 0.0),
-            ))
-            phase_rows = self.timer.summary()
-            metrics.update(phase_rows)
-            if self.latency.enabled:
-                # per-update phase durations into the latency surface: the
-                # time/{phase}_s gauges above are the LAST update's splits,
-                # the latency/phase_{phase}_s histograms hold every update's
-                for k, v in phase_rows.items():
-                    if k.startswith("time/") and k.endswith("_s"):
-                        # "time/rollout_s" -> "latency/phase_rollout_s"
-                        self.latency.record(
-                            f"latency/phase_{k[5:-2]}_s", float(v))
-            self.state["global_step"] += 1
-            # run-health plane: fold this row into the streaming aggregates,
-            # evaluate the anomaly rules, and ride the health/* gauges on
-            # the same record (CRIT side effects happen inside observe)
-            metrics.update(self.health.observe(self.state["global_step"], metrics))
-            if self.lineage.enabled:
-                # training-outcome event: closes this index's provenance
-                # chain with what the update actually consumed
-                adv_arr = np.asarray(
-                    batch.get("advantages", scores_sel), dtype=np.float32
-                )
-                if adv_arr.ndim > 1:
-                    # per-token advantages (PPO/GAE): reduce to per-row means
-                    adv_arr = adv_arr.mean(axis=tuple(range(1, adv_arr.ndim)))
-                self.lineage.outcome(
-                    rollout_index, step=self.state["global_step"],
-                    policy_version=(orch.version if use_orch
-                                    else self.state["global_step"]),
-                    kept=int(local_bs),
-                    advantage=round(float(adv_arr.mean()), 6),
-                    scores=[round(float(s), 6)
-                            for s in np.asarray(log_scores).tolist()],
-                    eos_frac=round(float(contain_eos.mean()), 4),
-                    staleness=sample_staleness,
-                )
-                if self._use_is and agg.get("is_trunc_frac", 0.0) > 0:
-                    # truncated-IS rows stay IN the update with capped
-                    # weight — partial influence loss, attributed but not
-                    # excluded (`partial` marks it for the histogram reader)
-                    n_trunc = int(round(agg["is_trunc_frac"] * local_bs))
-                    if n_trunc:
-                        self.lineage.drop(
-                            rollout_index, "is_truncated_weight",
-                            count=n_trunc, step=self.state["global_step"],
-                            partial=True,
-                        )
-                for i, s in enumerate(
-                        np.asarray(log_scores).tolist()[:8]):
-                    self.lineage.note_sample(
-                        rollout_index, step=self.state["global_step"],
-                        score=round(float(s), 6),
-                        response_chars=len(responses_decoded[i])
-                        if i < len(responses_decoded) else None,
-                        kept=True,
-                    )
-            # the whole iteration on the phases' clock, up to the row being
-            # logged: what it exceeds the sum of time/*_s by is host work
-            # between the phases (not a time/*_s key: those are summed as
-            # the phase split and folded into latency/phase_*)
-            metrics["trainer/iteration_s"] = time.perf_counter() - step_t0
-            if self.state["global_step"] % cfg.logging_steps == 0:
-                self.logger.log(self.state["global_step"], self.state["episode"], metrics)
-                sample_limit = (
-                    cfg.log_samples_limit
-                    if cfg.log_samples_limit is not None
-                    else cfg.num_printed_samples
-                )
-                self.logger.log_samples(
-                    self.state["global_step"], question_strings, responses_decoded,
-                    log_scores, sample_limit,
-                )
-                if self.lineage.enabled:
-                    # full-text sample records live here now, not in
-                    # metrics.jsonl (satellite: metrics stays numeric rows)
-                    for i, (q, r, s) in enumerate(zip(
-                            question_strings, responses_decoded,
-                            np.asarray(log_scores).tolist())):
-                        if i >= sample_limit:
-                            break
-                        self.lineage.event(
-                            "sample", rollout_index,
-                            step=self.state["global_step"], row=i,
-                            query=q, response=r, score=round(float(s), 6),
-                        )
-
-            # ---- CHECKPOINT ------------------------------------------------
-            saved_this_step = False
-            if cfg.save_steps and self.state["global_step"] % cfg.save_steps == 0:
-                self._save_checkpoint(orch if use_orch else None, metrics)
-                saved_this_step = True
-            # overlap meter: consumer busy window = everything since the
-            # sample was fetched (reward, scoring, update, logging, save)
-            meter.note_busy(t_busy0, time.perf_counter())
-            if self.tracer.enabled:
-                # the completed update's span on the trainer thread's track,
-                # with the correlation args that make trace.json queryable
-                self.tracer.add_complete(
-                    "train.update", span_t0, self.tracer.now_us() - span_t0,
-                    step=self.state["global_step"],
-                    rollout_index=rollout_index,
-                    staleness=sample_staleness,
-                    policy_version=(orch.version if use_orch
-                                    else self.state["global_step"]),
-                )
-                self.tracer.counter("staleness", sample_staleness)
-
-            # ---- PREEMPTION (SIGTERM, docs/RESILIENCE.md) ------------------
-            # polled at the update boundary where state is consistent: flush
-            # the in-flight async save, commit an emergency checkpoint, and
-            # unwind through the launcher's normal close() path
-            if self._preemption.triggered:
-                if not saved_this_step:
-                    self._save_checkpoint(orch if use_orch else None, metrics)
-                self.ckpt.wait()
-                # blackbox + trace alongside the emergency checkpoint: the
-                # post-mortem gets "what was every thread doing at SIGTERM"
-                self.tracer.dump_blackbox(
-                    self._telemetry_dir, self.state["global_step"],
-                    "preemption",
-                )
-                self._write_trace()
-                raise Preempted(
-                    f"SIGTERM at step {self.state['global_step']}: emergency "
-                    f"checkpoint committed to {self.cfg.output_dir}"
-                )
+            # the update's trace span is recorded by _close_update (a
+            # with-block could not carry a no-step's arguments)
+            up.span_t0 = self.tracer.now_us() if self.tracer.enabled else 0.0
+            for phase in phases:
+                up.no_step = phase(run, up)
+                if up.no_step is not None:
+                    break
+            self._close_update(run, up)
 
         # train() returning implies every checkpoint is DURABLE: flush the
         # in-flight async save (saves mid-run overlap training; only this
         # final one blocks)
         self.ckpt.wait()
         # balance any still-open XLA profile window, and rewrite trace.json
-        # after EVERY train() call (bench's train(num_updates=1) pattern
-        # would otherwise only get a trace at close())
+        # after EVERY train() call (a train(num_updates=1) driver would
+        # otherwise only get a trace at close())
         self.profile_window.stop()
         self._write_trace()
         # load_best_model_at_end parity (`GRPO/grpo.py:149`, resolved via the
@@ -2743,6 +1915,998 @@ class RLTrainer:
             print(f"exporting HF checkpoint to {cfg.export_hf_dir}")
             self.export_model(cfg.export_hf_dir)
         return self.state
+
+    def _evaluate(self, step: int) -> dict:
+        """Rows of a held-out evaluation at `step`: asked once before the
+        first update (step 0, logged as its own row) and after every update
+        (merged into the update's row). The dense runtime has none."""
+        return {}
+
+    def _close_update(self, run: TrainRun, up: Update):
+        """The one way out of an update, with a step or without: the
+        `train.update` span, the phase splits of an update that made no
+        step, what the no-step's owner does next, and the preemption poll."""
+        step = self.state["global_step"]
+        no_step = up.no_step
+        if self.tracer.enabled:
+            # the update's span on the trainer thread's track, with the
+            # correlation args that make trace.json queryable; a no-step
+            # carries the step it attempted and why it ended
+            self.tracer.add_complete(
+                "train.update", up.span_t0, self.tracer.now_us() - up.span_t0,
+                step=step + (no_step is not None),
+                rollout_index=up.rollout_index, staleness=up.staleness,
+                policy_version=run.orch.version if run.use_orch else step,
+                **up.span_args, **(no_step.span_args if no_step else {}),
+            )
+            self.tracer.counter("staleness", up.staleness)
+        if no_step is not None:
+            # discard the update's phase splits: no row took them, and the
+            # next update's time/*_s — and the perf/tokens_per_sec_*
+            # divisors that read timer.totals — would otherwise fold in two
+            # updates' worth of wall time
+            self.timer.summary()
+            if no_step.counts and up.rollout_index > run.counted_to:
+                # charged once a rollout: a rollback may replay the no-step
+                run.target_step, run.counted_to = (run.target_step - 1,
+                                                   up.rollout_index)
+            if no_step.then is not None:
+                no_step.then()
+        # ---- PREEMPTION (SIGTERM, docs/RESILIENCE.md) ----------------------
+        # polled at the update boundary where state is consistent: flush
+        # the in-flight async save, commit an emergency checkpoint, and
+        # unwind through the launcher's normal close() path
+        if self._preemption.triggered:
+            from nanorlhf_tpu.resilience import Preempted
+
+            if not up.saved:
+                self._save_checkpoint(run.orch if run.use_orch else None,
+                                      up.metrics)
+            self.ckpt.wait()
+            # blackbox + trace alongside the emergency checkpoint: the
+            # post-mortem gets "what was every thread doing at SIGTERM"
+            self.tracer.dump_blackbox(
+                self._telemetry_dir, self.state["global_step"], "preemption",
+            )
+            self._write_trace()
+            raise Preempted(
+                f"SIGTERM at step {self.state['global_step']}"
+                f"{f' ({no_step.why})' if no_step else ''}: emergency "
+                f"checkpoint committed to {self.cfg.output_dir}"
+            )
+
+    # ---- the rollout source -------------------------------------------- #
+
+    def _rollout_body(self, sampling: SamplingParams, ctx_menu, queries,
+                      gen_key, gen_tree=None, gen_mesh=None,
+                      weight_refresh=None):
+        """DISPATCH one rollout (async — nothing blocks until fetched).
+        `gen_tree` (orchestrated mode) is a published weight-store
+        snapshot; None samples from the live params. `gen_mesh` (fleet
+        × disaggregation) is the calling worker's own device group;
+        None generates on the shared rollout/train mesh.
+        `weight_refresh` (rollout_inflight_swaps) is the store/transport
+        poll callback; raw host snapshots it yields are converted to
+        rollout-ready params here before the decode driver installs
+        them (docs/ORCHESTRATOR.md §in-flight swaps)."""
+        cfg, tok = self.cfg, self.tokenizer
+        pad_id, eos_id = tok.pad_token_id, tok.eos_token_id
+        if ctx_menu is not None:
+            # r1's de-padding applied to every algorithm: batches of short
+            # prompts roll out / score at a menu-rounded context (warm jit
+            # cache) instead of the dataset-wide pad width
+            queries = depad_queries(queries, pad_id, ctx_menu)
+        if self._sp_on():
+            self._sp_check_widths(queries.shape[1])
+        bs = batch_sharding(
+            gen_mesh if gen_mesh is not None
+            else self.mesh if self.rollout_mesh is None
+            else self.rollout_mesh
+        )
+        queries_j = jax.device_put(jnp.asarray(queries), bs)
+        prompt_mask = queries_j != pad_id
+        gen_params = self._rollout_params(gen_tree, mesh=gen_mesh)
+        gen_refresh = None
+        if weight_refresh is not None:
+            def gen_refresh():
+                # device-place a fresh snapshot exactly like the
+                # dispatch tree so a swap cannot change sharding; a
+                # (version, None) poll result passes through untouched
+                version, tree = weight_refresh()
+                if tree is None:
+                    return version, None
+                return version, self._rollout_params(tree, mesh=gen_mesh)
+        # speculative decode (rollout_spec_k > 0) appends its acceptance
+        # counters here — device scalars fetched at metrics time, after
+        # the tokens already forced a sync. The tracer hands the spec
+        # path its instrumented driver (draft/verify spans on the
+        # "rollout" track) when telemetry is on; a disabled tracer is
+        # ignored.
+        spec_stats: list = []
+        paged_stats: list = []
+        if self._env_multi_turn:
+            from nanorlhf_tpu.envs.rollout import run_env_episodes
+
+            payload = run_env_episodes(
+                gen_params, self._rollout_mcfg, queries_j, prompt_mask,
+                gen_key, sampling, self.env,
+                eos_token_id=eos_id, pad_token_id=pad_id, tokenizer=tok,
+                max_turns=cfg.env_max_turns,
+                turn_tokens=sampling.max_tokens,
+                obs_budget=cfg.env_obs_budget,
+                response_length=cfg.response_length,
+                page_size=cfg.rollout_page_size,
+                decode_rows=(cfg.env_decode_rows
+                             or cfg.rollout_decode_rows),
+                lora_scale=self.lora_scale, faults=self.faults,
+            )
+            return {"queries": queries, "gen_out": payload["tokens"],
+                    "greedy": None, "spec_stats": None,
+                    "paged_stats": None, "env": payload}
+        gen_out = generate(
+            gen_params, self._rollout_mcfg, queries_j, prompt_mask, gen_key,
+            sampling, eos_token_id=eos_id, pad_token_id=pad_id,
+            lora_scale=self.lora_scale, batch_sharding=bs,
+            spec_stats_out=spec_stats, tracer=self.tracer,
+            paged_stats_out=paged_stats, latency=self.latency,
+            prefix_cache=self.prefix_cache,
+            weight_refresh=gen_refresh,
+        )                                               # [B*n, T]
+        greedy = None
+        if self.algo == AlgoName.REMAX:
+            # extra greedy rollout as baseline (`ReMax/remax_trainer.py:166-185`)
+            greedy = generate(
+                gen_params, self._rollout_mcfg, queries_j, prompt_mask, gen_key,
+                SamplingParams(greedy=True, max_tokens=cfg.response_length),
+                eos_token_id=eos_id, pad_token_id=pad_id,
+                lora_scale=self.lora_scale,
+            )
+        out = {"queries": queries, "gen_out": gen_out, "greedy": greedy,
+               "spec_stats": spec_stats[0] if spec_stats else None,
+               "paged_stats": paged_stats[0] if paged_stats else None}
+        if weight_refresh is not None and paged_stats:
+            # hoist swap provenance to the payload top level: the
+            # lineage ledger (telemetry.segments_summary) and the
+            # per-segment IS batch assembly read it from here
+            ps = paged_stats[0]
+            for k in ("segments", "swap_installs", "swap_wait_s"):
+                if k in ps:
+                    out[k] = ps[k]
+        return out
+
+    def _ensure_handles(self, run: TrainRun):
+        """(Re)build the rollout source after construction, a sentinel
+        rollback (which tears the orchestrator down), or a watchdog
+        degradation (which turns the orchestrated run synchronous)."""
+        run.use_orch = (self.cfg.rollout_orchestrator
+                        and not self.watchdog.degraded)
+        if run.use_orch:
+            run.orch = self._ensure_orchestrator(run.body)
+            run.stream, run.meter = None, run.orch.meter
+        else:
+            run.orch = None
+            if run.stream is None:
+                run.stream = RolloutStream(
+                    self, run.body, meter=self._rollout_meter
+                )
+            run.meter = run.stream.meter
+
+    def _degrade_to_sync(self, run: TrainRun):
+        """Watchdog budget exhausted: log the mode transition, tear the
+        pipeline down, and fall back to synchronous rollouts (staleness
+        0) from the consumed cursor instead of killing the run."""
+        print(
+            "[resilience] producer restart budget "
+            f"({self.cfg.producer_restart_budget}) exhausted — degrading to "
+            "synchronous rollouts (staleness 0)"
+        )
+        if self._orchestrator is not None:
+            # keep the queue's cumulative dropped/staleness counters:
+            # _save_checkpoint journals them from _orch_restore_state in
+            # degraded mode so the metric series stays continuous across
+            # a later resume (the same continuity _restart_producer has)
+            self._orch_restore_state = self._orchestrator.journal()
+            self._orchestrator.close(join_timeout=5.0)
+            self._orchestrator = None
+        self._reset_data_iterator()
+        run.stream = None  # force a fresh stream at the restored cursor
+        self._ensure_handles(run)
+
+    def _fetch_sample(self, run: TrainRun, up: Update) -> dict:
+        """One device-ready rollout, supervised: a dead producer is
+        restarted with backoff up to the watchdog budget (then the run
+        degrades to sync), and sentinel-quarantined batches are consumed
+        and discarded so a post-rollback replay skips the offending
+        data instead of re-deriving the same divergence."""
+        from nanorlhf_tpu.orchestrator import ProducerFailed
+        from nanorlhf_tpu.resilience import ProducerWatchdog
+
+        while True:
+            if run.use_orch:
+                orch = run.orch
+                try:
+                    sample = orch.get()
+                except ProducerFailed as e:
+                    # flight recorder first: the blackbox must capture
+                    # what every thread was doing when the producer
+                    # died, before the restart machinery mutates state
+                    extra = {"error": repr(e.__cause__ or e)}
+                    if hasattr(orch, "fleet_stats"):
+                        # fleet post-mortem: membership/lease/quarantine
+                        # counters at the moment of exhaustion
+                        extra["fleet"] = orch.fleet_stats()
+                    self.tracer.dump_blackbox(
+                        self._telemetry_dir, self.state["global_step"],
+                        "producer_failure", extra=extra,
+                    )
+                    decision, delay = self.watchdog.on_failure()
+                    if decision == ProducerWatchdog.RESTART:
+                        cause = e.__cause__ or e
+                        print(
+                            "[resilience] rollout producer died "
+                            f"({type(cause).__name__}: {cause}) — restart "
+                            f"{self.watchdog.restarts_total} in {delay:.1f}s"
+                        )
+                        time.sleep(delay)
+                        run.orch = self._restart_producer(run.body)
+                        continue
+                    if decision == ProducerWatchdog.DEGRADE:
+                        self._degrade_to_sync(run)
+                        continue
+                    raise
+                self.watchdog.on_success()
+                ro = sample.payload
+                ro["_index"] = sample.index
+                self.state["rollouts"] = sample.index + 1
+                up.staleness = orch.version - sample.version
+                up.queue_depth = orch.queue.depth()
+            else:
+                stream = run.stream
+                # quarantined indices are skipped BEFORE dispatch (zero
+                # rollout cost) — unless a prefetch already paid for one,
+                # which the post-fetch discard below handles
+                while (stream._pending is None
+                       and stream.next_index in self.sentinel.quarantined):
+                    idx = stream.skip()
+                    print(
+                        f"[resilience] skipping quarantined rollout "
+                        f"{idx} (sentinel rollback; not dispatched)"
+                    )
+                ro = stream.fetch_or_dispatch()
+            if ro["_index"] in self.sentinel.quarantined:
+                # already-generated sample (orchestrated pipeline or a
+                # serial prefetch): discard it; the producer gate gets a
+                # skip credit (no version publish)
+                print(
+                    f"[resilience] skipping quarantined rollout "
+                    f"{ro['_index']} (sentinel rollback)"
+                )
+                self.lineage.drop(
+                    ro["_index"], "sentinel_quarantine",
+                    step=self.state["global_step"], dispatched=True,
+                )
+                if run.use_orch:
+                    run.orch.consumed_without_update()
+                continue
+            return ro
+
+    # ---- the phases of one update --------------------------------------- #
+    # Each takes the run's set-up and the update's record, leaves what the
+    # next phase reads on the record, and returns None or the NoStep that
+    # ends the update.
+
+    def _rollout(self, run: TrainRun, up: Update):
+        """One device-ready rollout (fetched, or dispatched now), the
+        serial path's generation provenance, and the rollout_ahead
+        prefetch."""
+        cfg = self.cfg
+        with self.timer.phase("rollout"):
+            ro = up.ro = self._fetch_sample(run, up)
+            up.rollout_index = ro["_index"]
+            if run.capture:
+                up.responses, captured_lp = ro["gen_out"]
+                up.captured_lp = np.asarray(captured_lp)
+            else:
+                up.responses = ro["gen_out"]
+            jax.block_until_ready(up.responses)
+            if ro["greedy"] is not None:
+                ro["greedy"].block_until_ready()
+        # overlap meter: consumer busy from here (perf_counter — must
+        # share the producers' gen-window clock or intersections die)
+        up.t_busy0 = time.perf_counter()
+        if not run.use_orch and self.lineage.enabled:
+            # serial / rollout_ahead path has no producer thread to emit
+            # this: generation provenance lands here, once the arrays
+            # are device-ready (policy version == global_step — the same
+            # convention the trace spans use without an orchestrator)
+            from nanorlhf_tpu.telemetry.lineage import (
+                segments_summary,
+                spec_summary,
+            )
+
+            self.lineage.generation(
+                up.rollout_index,
+                policy_version=self.state["global_step"], worker_id=0,
+                spec=spec_summary(ro),
+                segments=segments_summary(ro),
+                swap_wait_s=ro.get("swap_wait_s"),
+            )
+        pstats = ro.get("paged_stats")
+        if pstats is not None:
+            # /statusz "pages" panel reads the latest snapshot; lineage
+            # gets one "lease" event per mid-loop admission so a queued
+            # sample's provenance records WHICH recycled row produced it
+            # and at which decode iteration (runs in every rollout mode)
+            self._pages_status = {
+                k: (None if pstats[k] is None
+                    else float(np.asarray(pstats[k])))
+                for k in ("page_utilization", "pages_recycled",
+                          "admitted_midloop", "decode_iterations")
+            }
+            self._pages_status.update(
+                rows=pstats["rows"], num_pages=pstats["num_pages"],
+                page_size=pstats["page_size"],
+            )
+            # the continuous-batching scheduler also ships its decode
+            # session's end-of-call status for /statusz "session";
+            # the monolithic paged paths have no session
+            self._session_status = pstats.get("session")
+            if self.lineage.enabled:
+                for adm in pstats.get("admissions") or []:
+                    self.lineage.event(
+                        "lease", up.rollout_index, midloop=True,
+                        row=adm["row"], queue_index=adm["queue_index"],
+                        iteration=adm["iteration"],
+                    )
+        self.state["episode"] += cfg.batch_size
+        up.queries = ro["queries"]
+        up.batch_size, up.context_length = up.queries.shape
+        if (not run.use_orch and cfg.rollout_ahead
+                and self.state["global_step"] + 1 < run.target_step):
+            # dispatch rollout k+1 NOW (from the pre-update-k params, one
+            # update stale): the device generates while the host below
+            # decodes/grades update k's batch
+            run.stream.prefetch()
+
+    def _reward(self, run: TrainRun, up: Update):
+        """Decode, then the user's reward callable on the host (or the
+        scores a multi-turn environment already accrued)."""
+        tok, n, ro = self.tokenizer, run.n, up.ro
+        up.question_strings = [
+            q.replace(tok.pad_token, "") for q in tok.batch_decode(up.queries)
+        ]
+        question_n = [q for q in up.question_strings for _ in range(n)]
+        up.responses = np.asarray(up.responses)
+        if self._use_seg and ro.get("segments") is not None:
+            # per-token policy AGE (newest version that produced any
+            # token of the row, minus the token's own segment version)
+            # in response coordinates — the same [0, total) space the
+            # scheduler's segment tok_ranges tile. Rows untouched by a
+            # swap are all-zero, and zero ages make segment_is_weights
+            # reduce bit-exactly to the whole-sequence weight.
+            up.seg_ages = np.zeros(up.responses.shape, np.int32)
+            for r, segs in enumerate(ro["segments"]):
+                newest = max(s["policy_version"] for s in segs)
+                for s in segs:
+                    lo, hi = s["tok_range"]
+                    if newest > s["policy_version"]:
+                        up.seg_ages[r, lo:hi] = newest - s["policy_version"]
+        up.decoded = tok.batch_decode(up.responses)
+        envp = up.envp = ro.get("env")
+        with self.timer.phase("reward"):
+            if envp is not None:
+                # multi-turn env: rewards accrued turn-by-turn inside
+                # the episode driver (the terminal grader already ran
+                # per episode) — no separate dispatch. Lineage gets the
+                # usual reward event plus one `turn` event per
+                # (episode row, turn), joinable to this rollout's
+                # generation event on rollout_index.
+                scores = np.asarray(envp["scores"], np.float32)
+                if self.lineage.enabled:
+                    self.lineage.reward(
+                        up.rollout_index, step=self.state["global_step"],
+                        scores=[round(float(s), 6) for s in scores],
+                        attempt=1,
+                        wall_s=envp["stats"]["env/tool_wall_s"],
+                    )
+                    for rec in envp["turns"]:
+                        self.lineage.turn(
+                            up.rollout_index,
+                            step=self.state["global_step"], **rec,
+                        )
+            else:
+                scores = self._dispatch_reward(
+                    [q + r for q, r in zip(question_n, up.decoded)],
+                    up.responses,
+                    rollout_index=up.rollout_index,
+                    step=self.state["global_step"],
+                )
+        up.raw_scores = scores.copy()  # raw sampled-rollout scores for logging
+        if ro["greedy"] is not None:
+            greedy = np.asarray(ro["greedy"])
+            greedy_scores = self._dispatch_reward(
+                [q + r for q, r in zip(up.question_strings,
+                                       tok.batch_decode(greedy))],
+                greedy,
+            )
+            # score − score_greedy is the ReMax advantage seed
+            # (`ReMax/remax_trainer.py:506-513`); raw scores still logged
+            scores = np.asarray(
+                remax_advantage(jnp.asarray(scores), jnp.asarray(greedy_scores))
+            )
+        up.scores = scores
+
+    def _select(self, run: TrainRun, up: Update):
+        """GRPO: group advantage + keep-1-of-N BEFORE scoring (RLOO/RAFT
+        select after the logprob pass, in `_advantages`)."""
+        n, batch_size = run.n, up.batch_size
+        up.log_scores = up.raw_scores
+        if self.algo != AlgoName.GRPO:
+            up.queries_rep = (np.repeat(up.queries, n, axis=0) if n > 1
+                              else up.queries)
+            return
+        adv_flat = np.asarray(grpo_group_advantage(jnp.asarray(up.scores), n))
+        self.key, k = jax.random.split(self.key)
+        keep = up.keep = np.asarray(keep_one_of_n_indices(k, batch_size, n))
+        rows = np.arange(batch_size)
+
+        def kept(x):
+            return np.asarray(x).reshape(batch_size, n, -1)[rows, keep]
+
+        up.grpo_adv = adv_flat.reshape(batch_size, n)[rows, keep]
+        if up.envp is not None:
+            # per-turn advantages z-score each turn column against
+            # the FULL group (all N siblings) before the keep
+            # filter drops N−1 of them, mirroring the episode-level
+            # baseline above; the turn-end positions and the
+            # observation loss_mask ride the same selection
+            t_adv = np.asarray(grpo_turn_advantage(
+                jnp.asarray(up.envp["turn_rewards"]), n))
+            up.env_turn = (kept(t_adv), kept(up.envp["turn_ends"]))
+            up.env_loss_mask = kept(up.envp["loss_mask"])
+        up.responses = kept(up.responses)
+        if up.captured_lp is not None:
+            up.captured_lp = kept(up.captured_lp)
+        if up.seg_ages is not None:
+            up.seg_ages = kept(up.seg_ages)
+        up.log_scores = up.raw_scores.reshape(batch_size, n)[rows, keep]
+        up.decoded = [up.decoded[i * n + j] for i, j in enumerate(keep)]
+        if n > 1:
+            # the other n−1 completions per prompt leave the batch
+            # here: attribute them like any other exclusion
+            self.lineage.drop(
+                up.rollout_index, "keep_filter",
+                count=batch_size * (n - 1),
+                step=self.state["global_step"],
+            )
+        up.queries_rep = up.queries
+
+    def _forward_budget(self) -> int:
+        """Tokens one scoring forward may hold. The vocab-cap lift only
+        applies when the fused scorer actually runs — an sp mesh routes
+        scoring through sp_score_logprobs, which still materializes
+        per-shard [chunk, T/sp, V] logits."""
+        return forward_token_budget(
+            self.mcfg.vocab_size,
+            fused_logprob=self.cfg.fused_logprob and not self._sp_on(),
+        )
+
+    def _score(self, run: TrainRun, up: Update):
+        """LOGPROB PASS (chunked, jitted): policy and reference logprobs of
+        the responses, less whatever the sampler captured."""
+        cfg, context_length = self.cfg, up.context_length
+        qr = up.qr = np.concatenate([up.queries_rep, up.responses], axis=1)
+        total = qr.shape[0]
+        chunk = cfg.local_rollout_forward_batch_size or max(
+            1,
+            self._forward_budget() // (context_length + cfg.response_length),
+        )
+        chunk = max(1, min(total, chunk))
+        logprobs_l, ref_logprobs_l = [], []
+        ref_free, score_capture = self._ref_free, run.score_capture
+        score_fn = self._score_chunk_fn()
+        one_fn = self._single_scorer_for(score_capture, router_stats=True)
+
+        def scored(out, n_real):
+            """Logprob arrays of one chunk, cut to its real rows; an
+            expert model's scorer appends its router sums (ops/moe.py),
+            which are kept for the row's `moe/*` counters."""
+            out = out if isinstance(out, tuple) else (out,)
+            if isinstance(out[-1], dict):
+                up.router_l.append(jax.tree.map(
+                    lambda a: np.asarray(a)[:n_real] if a.ndim
+                    else np.asarray(a), out[-1]))
+                out = out[:-1]
+            return [np.asarray(a)[:n_real] for a in out]
+
+        with self.timer.phase("logprob"):
+            if ref_free and score_capture:
+                # zero scoring forwards: policy logprobs came from the
+                # sampler, and there is no reference model (kl_coef 0 —
+                # the reference's r1 path, `grpo_r1.py:138`)
+                pass
+            else:
+                for i in range(0, total, chunk):
+                    n_real = min(chunk, total - i)
+                    rows_c = jnp.asarray(pad_chunk(qr[i : i + chunk], chunk))
+                    if ref_free:
+                        # policy-only forward (adapters applied)
+                        lp, = scored(one_fn(
+                            self.params, rows_c, context_length), n_real)
+                        logprobs_l.append(lp)
+                    elif score_capture:
+                        # policy logprobs came from the sampler; only the
+                        # ref pass runs — half the scoring forwards
+                        rlp, = scored(one_fn(
+                            self.ref_params, rows_c, context_length),
+                            n_real)
+                        ref_logprobs_l.append(rlp)
+                    else:
+                        lp, rlp = scored(score_fn(
+                            self.params, self.ref_params, rows_c,
+                            context_length,
+                        ), n_real)
+                        logprobs_l.append(lp)
+                        ref_logprobs_l.append(rlp)
+        up.logprobs = (
+            up.captured_lp if score_capture else np.concatenate(logprobs_l)
+        ).astype(np.float32)
+        # ref == policy-old in ref-free mode: every KL term and metric
+        # reads exactly 0, matching "no reference model"
+        up.ref_logprobs = (
+            up.logprobs.copy() if ref_free else np.concatenate(ref_logprobs_l)
+        )
+
+    def _advantages(self, run: TrainRun, up: Update):
+        """Response masks, then the per-algorithm advantage assembly into
+        the update's batch (RLOO/RAFT keep 1 of N here)."""
+        cfg, tok = self.cfg, self.tokenizer
+        pad_id, eos_id = tok.pad_token_id, tok.eos_token_id
+        n, batch_size = run.n, up.batch_size
+        responses_j = jnp.asarray(up.responses)
+        postprocessed = responses_j
+        if cfg.stop_token == "eos" and up.envp is None:
+            # multi-turn episodes carry INTERIOR per-turn EOS tokens the
+            # stop-token truncation would cut at; the driver already
+            # packed real tokens left-justified with pads only at the
+            # tail, so the first-pad seq_lengths below stay correct
+            postprocessed = truncate_response(eos_id, pad_id, responses_j)
+        up.postprocessed = np.asarray(postprocessed)
+        seq_lengths = np.asarray(first_true_indices(postprocessed == pad_id) - 1)
+        padding_mask, padding_mask_p1 = response_padding_masks(
+            up.postprocessed, jnp.asarray(seq_lengths)
+        )
+        padding_mask = up.padding_mask = np.asarray(padding_mask)
+        padding_mask_p1 = np.asarray(padding_mask_p1)
+        up.logprobs = np.where(padding_mask, INVALID_LOGPROB, up.logprobs)
+        up.ref_logprobs = np.where(padding_mask, INVALID_LOGPROB,
+                                   up.ref_logprobs)
+        behavior_lp = None
+        if self._use_is:
+            # the STALE sampling policy's logprobs, masked exactly like
+            # `logprobs` so the IS weight is 1 at padded positions
+            behavior_lp = np.where(
+                padding_mask, INVALID_LOGPROB, up.captured_lp
+            ).astype(np.float32)
+
+        up.contain_eos = (up.postprocessed == eos_id).any(axis=1)
+        scores_sel = up.grpo_adv if self.algo == AlgoName.GRPO else up.scores
+        if cfg.missing_eos_penalty is not None:
+            scores_sel = scores_sel.copy()
+            scores_sel[~up.contain_eos] -= cfg.missing_eos_penalty
+        up.scores_sel = scores_sel
+
+        # ---- per-algo advantage assembly ----------------------------------
+        batch, keep_inds, up.reward_info = self._assemble_batch(
+            scores_sel, up.logprobs, up.ref_logprobs, padding_mask,
+            padding_mask_p1, seq_lengths, up.qr, up.responses,
+            up.context_length, batch_size, n,
+            behavior_lp=behavior_lp, turn_info=up.env_turn,
+        )
+        up.batch = batch
+        if up.env_loss_mask is not None:
+            # observation/tool tokens: conditioned on, never scored.
+            # The key is only present in env multi-turn runs, so every
+            # other mode compiles the identical jitted update.
+            batch["loss_mask"] = up.env_loss_mask
+        if up.seg_ages is not None:
+            # key present only under rollout_inflight_swaps (same
+            # conditional-key pattern as loss_mask above): swaps off
+            # compiles the identical jitted update
+            if keep_inds is not None:
+                # RLOO/RAFT keep-1-of-N happens below, AFTER batch
+                # assembly — realign the ages the same way
+                up.seg_ages = up.seg_ages.reshape(batch_size, n, -1)[
+                    np.arange(batch_size), keep_inds
+                ]
+            batch["segment_ages"] = up.seg_ages
+
+        if keep_inds is not None:
+            # RLOO/RAFT selected 1-of-N *after* the logprob pass; realign
+            # the decoded strings/scores used for the sample table
+            up.decoded = [
+                up.decoded[i * n + j] for i, j in enumerate(keep_inds)
+            ]
+            up.log_scores = up.log_scores.reshape(batch_size, n)[
+                np.arange(batch_size), keep_inds
+            ]
+            self.lineage.drop(
+                up.rollout_index, "keep_filter",
+                count=batch_size * (n - 1),
+                step=self.state["global_step"],
+            )
+
+    def _update(self, run: TrainRun, up: Update):
+        """PPO-epoch / minibatch / microbatch update: one jitted optimizer
+        step a minibatch."""
+        cfg, batch = self.cfg, up.batch
+        trainable, frozen = self._partition(
+            self._train_tree(self.params, self.value_params)
+        )
+        all_stats = []
+        local_bs = batch["responses"].shape[0]
+        mini = max(1, local_bs // cfg.num_mini_batches)
+        # rows of the update-pass logits buffer (mem/logits_bytes_saved)
+        up.logits_rows = max(1, mini // cfg.gradient_accumulation_steps)
+        # lr reported for THIS update = schedule at the step count its
+        # first optimizer.update saw (the reference's get_last_lr-before-
+        # scheduler.step semantics, `grpo_trainer.py:744-750`)
+        up.lr_step = self.state["opt_steps"]
+        with self.timer.phase("update"):
+            for epoch in range(cfg.num_ppo_epochs):
+                self.key, pk = jax.random.split(self.key)
+                perm = np.asarray(jax.random.permutation(pk, local_bs))
+                for start in range(0, local_bs - mini + 1, mini):
+                    inds = perm[start : start + mini]
+                    mb = {
+                        k: jax.device_put(
+                            jnp.asarray(v[inds]),
+                            batch_sharding(self.mesh, np.asarray(v).ndim),
+                        )
+                        for k, v in batch.items()
+                    }
+                    trainable, self.opt_state, stats = self._update_fn(
+                        trainable, frozen, self.opt_state, mb,
+                        up.context_length
+                    )
+                    self.state["opt_steps"] += 1
+                    # keep stats on device; syncing per minibatch would
+                    # serialize update dispatch
+                    all_stats.append(stats)
+            train_tree = self._combine(trainable, frozen)
+            self.params = train_tree["policy"]
+            self.value_params = train_tree.get("value")
+            up.all_stats = jax.device_get(all_stats)
+        up.agg = {
+            k: float(np.mean([s[k] for s in up.all_stats]))
+            for k in (up.all_stats[0] if up.all_stats else {})
+        }
+
+    def _guard(self, run: TrainRun, up: Update):
+        """SENTINEL (resilience/, docs/RESILIENCE.md): checked BEFORE the
+        weight-store publish so a tripped step never feeds poisoned weights
+        to the producer. The update.step fault poisons the OBSERVED stats
+        (action=nan) — same code path a real NaN loss/grad takes, without
+        hand-corrupting device arrays."""
+        agg = up.agg
+        if self.faults.fire("update.step") == "nan":
+            agg["pg_loss"] = float("nan")
+            agg["grad_norm"] = float("nan")
+        verdict = self.sentinel.observe(
+            agg.get("pg_loss", 0.0), agg.get("grad_norm")
+        )
+        if verdict is None:
+            return None
+
+        def rollback():
+            # the span is closed by now, so the flight recorder the
+            # rollback dumps holds the tripped update, tagged with the
+            # quarantined rollout index; the rollback then tears the
+            # pipeline down and rewinds the data/PRNG cursors — rebuild
+            # the handles and replay
+            self._sentinel_rollback(verdict, up.rollout_index)
+            run.stream = None
+            self._ensure_handles(run)
+
+        return NoStep("sentinel rollback", then=rollback, span_args={
+            "sentinel_verdict": verdict, "quarantined": True})
+
+    def _publish(self, run: TrainRun, up: Update):
+        if run.use_orch:
+            # one version per optimizer update: snapshot the trainable
+            # leaves (donation hazard) and open the producer's gate
+            with self.timer.phase("publish"):
+                run.orch.publish(self._policy_snapshot())
+
+    def _metrics_row(self, run: TrainRun, up: Update) -> dict:
+        """The update's row before the step is counted: names + semantics
+        per docs/METRICS.md, then perf/MFU and the phase splits."""
+        cfg, agg, ro, orch = self.cfg, up.agg, up.ro, run.orch
+        n, batch_size = run.n, up.batch_size
+        padding_mask, logprobs = up.padding_mask, up.logprobs
+        sec_per_episode = (time.perf_counter() - up.t0) / cfg.batch_size
+        # entropy proxy: summed response negative logprob (the reference's
+        # `(-logprobs).sum(1).mean()`, `GRPO/grpo_trainer.py:710`, with
+        # pad positions masked to 0 instead of contributing the INVALID
+        # sentinel); the true entropy is policy/entropy_avg_new below
+        mean_entropy = float(
+            (-np.where(padding_mask, 0.0, logprobs)).sum(1).mean()
+        )
+        kl_rollout = float(
+            np.where(padding_mask, 0.0, logprobs - up.ref_logprobs).sum(1).mean()
+        )
+        # GRPO parity: the reference fills kl_old from the UPDATE-pass
+        # new-vs-ref KL stats (`GRPO/grpo_trainer.py:668-670,689,728`);
+        # every KL-in-reward trainer uses the rollout token-sum KL
+        # (`RLOO/rloo_trainer.py:704-706`). kl_rollout_old is always the
+        # honest pre-update measurement.
+        kl_old = (
+            agg.get("refkl_mean", kl_rollout)
+            if self.algo == AlgoName.GRPO else kl_rollout
+        )
+        if self._ref_free:
+            # no reference model exists: GRPO's update-pass refkl stat
+            # would otherwise report KL-to-OLD-POLICY here (ref stands
+            # in as the old logprobs), which is not the metric's meaning
+            kl_old = 0.0
+        mean_score = float(np.mean(up.raw_scores))
+        metrics = {
+            "objective/kl_old": kl_old,
+            "objective/kl_rollout_old": kl_rollout,
+            "objective/entropy_old": mean_entropy,
+            "objective/non_score_reward_old": up.reward_info.get(
+                "non_score_reward_old", 0.0
+            ),
+            "eval_objective/rlhf_reward_old": up.reward_info.get(
+                "rlhf_reward_old", mean_score
+            ),
+            "eval_objective/scores_old": mean_score,
+            "policy/approxkl_avg_new": agg.get("approxkl", 0.0),
+            "policy/clipfrac_avg_new": agg.get("pg_clipfrac", 0.0),
+            "policy/entropy_avg_new": agg.get("entropy", 0.0),
+            "loss/policy_avg_new": agg.get("pg_loss", 0.0),
+            "val/ratio_new": agg.get("ratio_mean", 1.0),
+            "val/ratio_var_new": float(np.var(
+                [s.get("ratio_mean", 1.0) for s in up.all_stats]
+            )) if up.all_stats else 0.0,
+            "val/num_eos_tokens_old": float(
+                (up.postprocessed == self.tokenizer.eos_token_id).sum()
+            ),
+            "lr": float(self._lr_schedules["policy"](up.lr_step)),
+            "eps": cfg.adam_eps,
+            "sec_per_episode": sec_per_episode,
+            "episode": self.state["episode"],
+        }
+        if "vf_loss" in agg:
+            metrics["loss/value_avg_new"] = agg["vf_loss"]
+            metrics["val/clipfrac_avg_new"] = agg.get("vf_clipfrac", 0.0)
+        if run.score_capture:
+            # with exact scoring the epoch-1 ratio is identically 1; any
+            # deviation here is decode-vs-scoring numerics — the guard
+            # for the captured-logprob shortcut
+            metrics["sampler_capture/ratio_drift_new"] = abs(
+                agg.get("ratio_mean", 1.0) - 1.0
+            )
+        # rollout/train overlap fraction: measured for EVERY mode
+        # (serial ≈ 0, rollout_ahead partial, orchestrator highest) —
+        # the pipelining signal
+        metrics["time/rollout_overlap_frac"] = run.meter.overlap_fraction()
+        if up.router_l:
+            from nanorlhf_tpu.ops.moe import moe_counters
+
+            metrics.update(moe_counters(up.router_l))
+        metrics.update(self._spec_decode_metrics(ro.get("spec_stats")))
+        metrics.update(self._paged_metrics(ro.get("paged_stats")))
+        if up.envp is not None:
+            metrics.update(up.envp["stats"])
+        if run.use_orch:
+            ostats = orch.stats()
+            metrics.update({
+                "orchestrator/queue_depth": float(up.queue_depth),
+                "orchestrator/staleness": float(up.staleness),
+                "orchestrator/dropped_total": float(ostats["dropped"]),
+                # who-waits-on-whom (cumulative s): trainer starved vs
+                # producer gated — which side is the bottleneck
+                "orchestrator/consumer_wait_s": ostats["consumer_wait_s"],
+                "orchestrator/producer_gate_wait_s": ostats[
+                    "producer_gate_wait_s"
+                ],
+            })
+            metrics.update(staleness_histogram_metrics(
+                ostats["staleness_counts"]
+            ))
+            if hasattr(orch, "fleet_stats"):
+                # fleet/* series (docs/METRICS.md): membership gauges +
+                # cumulative lease/reassignment/quarantine counters
+                # (counters survive restart/degrade/resume via the
+                # coordinator journal, like the queue's)
+                metrics.update({
+                    f"fleet/{k}": v
+                    for k, v in orch.fleet_stats().items()
+                })
+        if self._use_is:
+            metrics["offpolicy/is_weight_mean_new"] = agg.get(
+                "is_weight_mean", 1.0
+            )
+            metrics["offpolicy/is_trunc_frac_new"] = agg.get(
+                "is_trunc_frac", 0.0
+            )
+        if cfg.rollout_inflight_swaps:
+            # in-flight swap provenance (docs/ORCHESTRATOR.md
+            # §in-flight swaps): installs + the mean number of policy
+            # segments per completion row THIS update consumed (1.0 =
+            # no mid-rollout publish landed), plus the cumulative
+            # install stall this rollout paid (device-put of the fresh
+            # tree at a chunk boundary — the cost drain-and-wait pays
+            # as idle time instead)
+            segs = ro.get("segments")
+            metrics.update({
+                "rollout/swap_installs": float(
+                    ro.get("swap_installs", 0) or 0),
+                "rollout/segments_per_sample": (
+                    float(np.mean([len(s) for s in segs]))
+                    if segs else 1.0
+                ),
+                "orchestrator/swap_wait_s": float(
+                    ro.get("swap_wait_s", 0.0) or 0.0),
+            })
+        # resilience series (docs/RESILIENCE.md): cumulative counters so
+        # dashboards diff them into rates; degraded_mode is the sticky
+        # sync-fallback flag (0 in healthy pipelined runs)
+        metrics.update({
+            "policy/grad_norm_new": agg.get("grad_norm", 0.0),
+            "resilience/producer_restarts": float(
+                self.watchdog.restarts_total
+            ),
+            "resilience/rollbacks": float(self.sentinel.rollbacks),
+            "resilience/degraded_mode": float(self.watchdog.degraded),
+            "resilience/ckpt_retries": float(self.ckpt.retry_count),
+            "resilience/ckpt_fallbacks": float(self.ckpt.fallback_count),
+        })
+        # memory series (docs/METRICS.md, docs/FUSED_LOGPROB.md):
+        # peak_bytes_in_use from the backend (0 on CPU), plus the
+        # analytic size of the update-pass full-logits buffer the fused
+        # hidden→logprob path avoids per microbatch (param-dtype logits;
+        # the naive path's old f32 entropy copy is NOT counted — it is
+        # gone in both modes now that the fallback entropy is chunked)
+        t_resp = up.batch["responses"].shape[1]
+        logits_bytes = (
+            up.logits_rows * t_resp * self.mcfg.vocab_size
+            * jnp.dtype(self.params["embed_tokens"].dtype).itemsize
+        )
+        metrics.update({
+            "mem/peak_bytes_in_use": device_peak_bytes(),
+            # 0 on an sp mesh too: microbatch_loss takes the sp branch
+            # there and the fused op never runs
+            "mem/logits_bytes_saved": float(
+                logits_bytes
+                if cfg.fused_logprob and not self._sp_on() else 0.0
+            ),
+        })
+        # what only a subclass's phases measure (their own keys)
+        metrics.update(up.extra_metrics)
+        # ---- perf/MFU accounting (telemetry/, docs/OBSERVABILITY.md):
+        # token counts from THIS update's actual work — decode at the
+        # configured response_length (the napkin model's convention),
+        # scoring forwards as actually run (0 in ref-free+capture, 1
+        # with capture or ref-free, 2 otherwise) over the scored rows at
+        # their width, the update over its rows at theirs
+        n_rollout_rows = batch_size * n
+        local_bs = up.batch["responses"].shape[0]
+        score_forwards = (
+            0 if (self._ref_free and run.score_capture)
+            else 1 if (self._ref_free or run.score_capture) else 2
+        )
+        metrics.update(self._perf_metrics(
+            step_wall_s=time.perf_counter() - up.t0,
+            decode_tokens=n_rollout_rows * cfg.response_length,
+            prefill_tokens=n_rollout_rows * up.queries.shape[1],
+            score_tokens=score_forwards * up.qr.size,
+            train_tokens=cfg.num_ppo_epochs * local_bs
+            * (up.context_length + t_resp),
+            rollout_s=self.timer.totals.get("rollout", 0.0),
+            update_s=self.timer.totals.get("update", 0.0),
+        ))
+        phase_rows = self.timer.summary()
+        metrics.update(phase_rows)
+        if self.latency.enabled:
+            # per-update phase durations into the latency surface: the
+            # time/{phase}_s gauges above are the LAST update's splits,
+            # the latency/phase_{phase}_s histograms hold every update's
+            for k, v in phase_rows.items():
+                if k.startswith("time/") and k.endswith("_s"):
+                    # "time/rollout_s" -> "latency/phase_rollout_s"
+                    self.latency.record(
+                        f"latency/phase_{k[5:-2]}_s", float(v))
+        return metrics
+
+    def _report(self, run: TrainRun, up: Update):
+        """Count the step and log its row, with the evaluation hook's and
+        the health plane's keys; the lineage outcome and the sample table."""
+        cfg, agg = self.cfg, up.agg
+        metrics = up.metrics = self._metrics_row(run, up)
+        local_bs = up.batch["responses"].shape[0]
+        self.state["global_step"] += 1
+        step = self.state["global_step"]
+        metrics.update(self._evaluate(step))
+        # run-health plane: fold this row into the streaming aggregates,
+        # evaluate the anomaly rules, and ride the health/* gauges on
+        # the same record (CRIT side effects happen inside observe)
+        metrics.update(self.health.observe(step, metrics))
+        log_scores = np.asarray(up.log_scores).tolist()
+        if self.lineage.enabled:
+            # training-outcome event: closes this index's provenance
+            # chain with what the update actually consumed
+            adv_arr = np.asarray(
+                up.batch.get("advantages", up.scores_sel), dtype=np.float32
+            )
+            if adv_arr.ndim > 1:
+                # per-token advantages (PPO/GAE): reduce to per-row means
+                adv_arr = adv_arr.mean(axis=tuple(range(1, adv_arr.ndim)))
+            self.lineage.outcome(
+                up.rollout_index, step=step,
+                policy_version=run.orch.version if run.use_orch else step,
+                kept=int(local_bs),
+                advantage=round(float(adv_arr.mean()), 6),
+                scores=[round(float(s), 6) for s in log_scores],
+                eos_frac=round(float(up.contain_eos.mean()), 4),
+                staleness=up.staleness,
+            )
+            if self._use_is and agg.get("is_trunc_frac", 0.0) > 0:
+                # truncated-IS rows stay IN the update with capped
+                # weight — partial influence loss, attributed but not
+                # excluded (`partial` marks it for the histogram reader)
+                n_trunc = int(round(agg["is_trunc_frac"] * local_bs))
+                if n_trunc:
+                    self.lineage.drop(
+                        up.rollout_index, "is_truncated_weight",
+                        count=n_trunc, step=step, partial=True,
+                    )
+            for i, s in enumerate(log_scores[:8]):
+                self.lineage.note_sample(
+                    up.rollout_index, step=step,
+                    score=round(float(s), 6),
+                    response_chars=len(up.decoded[i])
+                    if i < len(up.decoded) else None,
+                    kept=True,
+                )
+        # the whole iteration on the phases' clock, up to the row being
+        # logged: what it exceeds the sum of time/*_s by is host work
+        # between the phases (not a time/*_s key: those are summed as
+        # the phase split and folded into latency/phase_*)
+        metrics["trainer/iteration_s"] = time.perf_counter() - up.t0
+        if step % cfg.logging_steps == 0:
+            self.logger.log(step, self.state["episode"], metrics)
+            sample_limit = (
+                cfg.log_samples_limit
+                if cfg.log_samples_limit is not None
+                else cfg.num_printed_samples
+            )
+            self.logger.log_samples(
+                step, up.question_strings, up.decoded, up.log_scores,
+                sample_limit,
+            )
+            if self.lineage.enabled:
+                # full-text sample records live here now, not in
+                # metrics.jsonl (satellite: metrics stays numeric rows)
+                for i, (q, r, s) in enumerate(zip(
+                        up.question_strings, up.decoded, log_scores)):
+                    if i >= sample_limit:
+                        break
+                    self.lineage.event(
+                        "sample", up.rollout_index, step=step, row=i,
+                        query=q, response=r, score=round(float(s), 6),
+                    )
+
+    def _checkpoint(self, run: TrainRun, up: Update):
+        cfg = self.cfg
+        if cfg.save_steps and self.state["global_step"] % cfg.save_steps == 0:
+            self._save_checkpoint(run.orch if run.use_orch else None,
+                                  up.metrics)
+            up.saved = True
+        # overlap meter: consumer busy window = everything since the
+        # sample was fetched (reward, scoring, update, logging, save)
+        run.meter.note_busy(up.t_busy0, time.perf_counter())
 
     def _write_trace(self):
         """Rewrite `<telemetry_dir>/trace.json` from the full buffered span
@@ -2815,7 +2979,13 @@ class RLTrainer:
             value_params=self.value_params if cfg.save_value_model else None,
         )
 
-    def _dispatch_reward(self, prompts_and_responses, eos_token,
+    def _call_reward(self, prompts_and_responses, responses_ids):
+        return np.asarray(
+            self.reward_func(prompts_and_responses, self.tokenizer.eos_token),
+            dtype=np.float32,
+        )
+
+    def _dispatch_reward(self, prompts_and_responses, responses_ids,
                          rollout_index=None, step=None) -> np.ndarray:
         """Reward dispatch with the `reward.exec` injection point and a
         bounded retry: the reward callable is host-side (subprocess graders,
@@ -2831,10 +3001,7 @@ class RLTrainer:
         def attempt():
             attempts_used[0] += 1
             self.faults.fire("reward.exec")
-            return np.asarray(
-                self.reward_func(prompts_and_responses, eos_token),
-                dtype=np.float32,
-            )
+            return self._call_reward(prompts_and_responses, responses_ids)
 
         # a dedicated "reward" trace track: the host-side graders
         # (subprocess sympy, RM inference) are a classic hidden step-time

@@ -1,5 +1,5 @@
-"""Persistent XLA compilation cache shared by launchers, chip_smoke, bench,
-tools and tests.
+"""Persistent XLA compilation cache shared by launchers, chip_smoke,
+benchmark/run.py, tools and tests.
 
 A cold process compiles every jitted program it runs (the r1 bucket menu is
 several context × response shapes plus sp variants); jax's persistent cache
@@ -30,8 +30,8 @@ DEFAULT_CACHE_DIR = os.path.join(
 )
 
 # set by the first successful enable_compilation_cache(): later calls return
-# it without touching jax.config (conftest, launchers, bench and tools all
-# call enable; re-pointing a live jax cache mid-process is not supported)
+# it without touching jax.config (conftest, launchers, benchmark/run.py and tools
+# all call enable; re-pointing a live jax cache mid-process is not supported)
 _enabled_dir: str | None = None
 
 

@@ -52,8 +52,8 @@ class PhaseTimer:
                  names: tuple = ()):
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
-        # never reset: whole-run phase split (bench MFU accounting reads this
-        # across updates while the per-update summary() resets each step)
+        # never reset: whole-run phase split (a reader across updates, e.g.
+        # tests/test_one_loop.py, while the per-update summary() resets)
         self.cumulative: dict[str, float] = {n: 0.0 for n in names}
         self.cumulative_counts: dict[str, int] = {n: 0 for n in names}
         # optional telemetry.SpanTracer: phases double as trace spans

@@ -35,9 +35,36 @@ from nanorlhf_tpu.resilience import (
     parse_fault_spec,
     retry_with_backoff,
 )
-from nanorlhf_tpu.trainer import AlgoName
+from nanorlhf_tpu.trainer import AlgoName, RLTrainer
+from nanorlhf_tpu.trainer.sparse_grpo import SparseGRPOTrainer
 
 from test_trainer_smoke import make_trainer
+
+# the sentinel sits in the one loop's `guard` phase, so every trainer that
+# runs the loop is held to it: (class, an algorithm it trains, the stream
+# keys that do not depend on a keep-1-of-N draw — GRPO's rides the trainer
+# PRNG key, which a rollback rewinds with the checkpoint)
+BOTH_TRAINERS = pytest.mark.parametrize("cls,algo,replay_keys", [
+    pytest.param(RLTrainer, AlgoName.REINFORCE, None, id="dense"),
+    pytest.param(SparseGRPOTrainer, AlgoName.GRPO,
+                 ("eval_objective/scores_old",), id="sparse"),
+])
+
+
+def _varied_reward(pmt_and_responses, *_):
+    import zlib
+
+    return np.asarray([(zlib.crc32(s.encode()) % 17) / 17.0
+                       for s in pmt_and_responses], np.float32)
+
+
+def _make(cls, algo, path, **kw):
+    tr = make_trainer(algo, path, trainer_cls=cls, **kw)
+    if cls is SparseGRPOTrainer:
+        # scores that vary inside every group: no all-zero-advantage skip
+        # takes an update out of the count the assertions below share
+        tr.reward_func = _varied_reward
+    return tr
 
 
 def _metric_rows(outdir):
@@ -329,53 +356,60 @@ def test_producer_degrade_disabled_reraises(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_nan_step_rollback_replays_bit_identical_streams(tmp_path):
+@BOTH_TRAINERS
+def test_nan_step_rollback_replays_bit_identical_streams(tmp_path, cls, algo,
+                                                         replay_keys):
     """update 2 observes an injected NaN: the sentinel restores checkpoint 1,
     quarantines update 2's rollout index, and replays. With lr=0 (REINFORCE:
     no selection PRNG) the post-rollback rows must be bit-identical to the
     CLEAN run's rows for the same rollout indices — the replayed data/PRNG
     streams are exactly the journal's."""
     kw = dict(total_episodes=64, learning_rate=0.0, save_steps=1)
-    clean = make_trainer(AlgoName.REINFORCE, tmp_path / "clean", **kw)
+    keys = replay_keys or STREAM_KEYS
+    clean = _make(cls, algo, tmp_path / "clean", **kw)
     clean.train()  # 4 updates of 16 episodes
     clean.close()
 
-    faulted = make_trainer(AlgoName.REINFORCE, tmp_path / "faulted",
-                           fault_spec="update.step:at=2,action=nan", **kw)
+    faulted = _make(cls, algo, tmp_path / "faulted",
+                    fault_spec="update.step:at=2,action=nan", **kw)
     state = faulted.train()
     assert state["global_step"] == 4
     assert faulted.sentinel.rollbacks == 1
     assert faulted.sentinel.quarantined == {1}  # update 2's rollout index
     faulted.close()
 
-    a = _metric_rows(tmp_path / "clean" / "reinforce")
-    b = _metric_rows(tmp_path / "faulted" / "reinforce")
+    a = _metric_rows(tmp_path / "clean" / algo.value)
+    b = _metric_rows(tmp_path / "faulted" / algo.value)
     assert len(a) == len(b) == 4
     # clean step k consumed rollout k-1; the faulted run quarantined rollout
     # 1, so its steps 2..4 consumed rollouts 2..4 — compare rollout-aligned
     # rows: faulted step s (s >= 2) vs clean step s+1
     for s in (1,):
-        for key in STREAM_KEYS:
+        for key in keys:
             np.testing.assert_allclose(a[s - 1][key], b[s - 1][key],
                                        rtol=1e-6, err_msg=key)
     for s in (2, 3):
-        for key in STREAM_KEYS:
+        for key in keys:
             np.testing.assert_allclose(a[s][key], b[s - 1][key], rtol=1e-6,
                                        err_msg=f"replayed {key} @ step {s}")
     assert b[-1]["resilience/rollbacks"] == 1.0
+    # the tripped update's phase splits were discarded with it: the row of
+    # the replayed step holds one update's wall time, not two
+    assert all(r["time/update_s"] <= r["trainer/iteration_s"] for r in b)
     # sentinel journal rode into the checkpoint: a fresh trainer resumes
     # the rollback spend and quarantine set
-    res = make_trainer(AlgoName.REINFORCE, tmp_path / "faulted", **kw)
+    res = _make(cls, algo, tmp_path / "faulted", **kw)
     res.resume_from_checkpoint()
     assert res.sentinel.rollbacks == 1
     assert res.sentinel.quarantined == {1}
     res.close()
 
 
-def test_nan_step_budget_exhausted_raises(tmp_path):
-    tr = make_trainer(AlgoName.REINFORCE, tmp_path, total_episodes=64,
-                      save_steps=1, rollback_budget=0,
-                      fault_spec="update.step:at=2,action=nan")
+@BOTH_TRAINERS
+def test_nan_step_budget_exhausted_raises(tmp_path, cls, algo, replay_keys):
+    tr = _make(cls, algo, tmp_path, total_episodes=64,
+               save_steps=1, rollback_budget=0,
+               fault_spec="update.step:at=2,action=nan")
     with pytest.raises(SentinelBudgetExceeded):
         tr.train()
     tr.close()
@@ -390,15 +424,17 @@ def test_nan_step_without_checkpoint_raises(tmp_path):
     tr.close()
 
 
-def test_sentinel_enabled_is_numerically_inert(tmp_path):
+@pytest.mark.parametrize("cls", [RLTrainer, SparseGRPOTrainer],
+                         ids=["dense", "sparse"])
+def test_sentinel_enabled_is_numerically_inert(tmp_path, cls):
     """Acceptance: a no-fault run with the sentinel on is numerically
     identical to one with it off — the guard only observes."""
     on = make_trainer(AlgoName.GRPO, tmp_path / "on", total_episodes=48,
-                      save_steps=0, sentinel=True)
+                      save_steps=0, sentinel=True, trainer_cls=cls)
     on.train()
     on.close()
     off = make_trainer(AlgoName.GRPO, tmp_path / "off", total_episodes=48,
-                       save_steps=0, sentinel=False)
+                       save_steps=0, sentinel=False, trainer_cls=cls)
     off.train()
     off.close()
     a = _metric_rows(tmp_path / "on" / "grpo")
@@ -409,6 +445,7 @@ def test_sentinel_enabled_is_numerically_inert(tmp_path):
                                   "policy/grad_norm_new"):
             np.testing.assert_allclose(ra[key], rb[key], rtol=0, atol=0,
                                        err_msg=key)
+        assert ra["policy/grad_norm_new"] > 0.0  # the guard had a norm to read
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,7 @@ def rule_reward(pmt_and_responses, eos_token):
     return np.asarray(out, dtype=np.float32)
 
 
-def make_trainer(algo: AlgoName, tmp_path, **overrides):
+def make_trainer(algo: AlgoName, tmp_path, trainer_cls=RLTrainer, **overrides):
     tok = ToyTokenizer(vocab_size=256)
     mcfg = ModelConfig.qwen2_tiny(vocab_size=256)
     key = jax.random.PRNGKey(0)
@@ -60,7 +60,7 @@ def make_trainer(algo: AlgoName, tmp_path, **overrides):
         value_params = init_params(mcfg, jax.random.PRNGKey(2), jnp.float32)
         value_params.pop("lm_head", None)
         value_params["score"] = init_score_head(mcfg, jax.random.PRNGKey(3), dtype=jnp.float32)
-    return RLTrainer(
+    return trainer_cls(
         cfg, mcfg, tok, params, dataset, rule_reward, value_params=value_params
     )
 
@@ -257,9 +257,9 @@ def test_rollout_top_k_reaches_sampler(tmp_path, monkeypatch):
     assert seen and seen[0].top_k == 0 and seen[0].approx_top_k is False
 
     # the SPARSE trainer (the r1-zero path the top_k=0 default targets)
-    # builds its own SamplingParams — it must thread the knobs too
-    # (code-review r4: it silently fell back to the k=64 pre-trim)
-    import nanorlhf_tpu.trainer.sparse_grpo as sparse_mod
+    # rolls out through the same body of the one loop, so the same knobs
+    # reach its sampler (code-review r4: its own SamplingParams once fell
+    # back to the k=64 pre-trim)
     from nanorlhf_tpu.trainer.sparse_grpo import SparseGRPOTrainer
 
     seen_sparse = []
@@ -268,7 +268,7 @@ def test_rollout_top_k_reaches_sampler(tmp_path, monkeypatch):
         seen_sparse.append(sampling)
         return real_generate(params, config, ids, mask, key, sampling, **kw)
 
-    monkeypatch.setattr(sparse_mod, "generate", spy_sparse)
+    monkeypatch.setattr(trainer_mod, "generate", spy_sparse)
     tok = ToyTokenizer(vocab_size=256)
     mcfg = ModelConfig.qwen2_tiny(vocab_size=256)
     cfg = RLConfig(
