@@ -605,6 +605,7 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
     gateway = ServingGateway(engine, port=-1)
     base = f"http://127.0.0.1:{gateway.port}"
     paged_kernel = use_paged_decode_kernel(mcfg)
+    pool_donated = engine.session.pool_donated
 
     def get(path):
         return urllib.request.urlopen(base + path, timeout=60).read().decode()
@@ -661,6 +662,10 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
         phase.expect(not problems and "nanorlhf_serving_completed" in metrics,
                      f"/metrics does not parse: {problems[:3]}")
         phase.expect(get("/healthz") == "ok\n", "/healthz is not ok")
+        # on a chip every session program consumes the page pool it is given
+        # (a CPU rehearsal copies: utils/donation.py)
+        phase.expect(pool_donated == int(jax.default_backend() != "cpu"),
+                     f"serving/pool_donated is {pool_donated}")
     finally:
         gateway.close()
         engine.close()
@@ -706,7 +711,8 @@ def phase_serve(sz: Sizes, trainer, bf16_error: float, meter: Meter) -> None:
         radix={k: radix[k] for k in ("hit_tokens", "cow_splits", "nodes",
                                      "shared_pages_acquired")},
         auto={"decode_attention": "pallas-paged-in-place" if paged_kernel
-              else "xla-gathered-view"},
+              else "xla-gathered-view",
+              "page_pool": "donated" if pool_donated else "copied"},
         greedy_vs_generate=agreement)
 
 

@@ -60,6 +60,8 @@ from nanorlhf_tpu.sampler.paged.session import (
     _decode_chunk,
     _install_row,
     _release_jit,
+    _sans_pool,
+    _with_pool,
 )
 
 
@@ -260,7 +262,9 @@ def run_env_episodes(
                  jnp.zeros((R,), jnp.int32),
                  jnp.ones((R,), jnp.int32),
                  jnp.zeros((R,), jnp.int32),
-                 key)
+                 # the chunk donates the carry whole, and `key` is folded
+                 # for every admission: the carry gets its own
+                 jnp.copy(key))
         pstate = init_page_state(N, R, nb)
 
     def harvest(fut):
@@ -342,12 +346,14 @@ def run_env_episodes(
                 greedy=sampling.greedy, top_k=sampling.top_k,
                 approx_top_k=sampling.approx_top_k, lora_scale=lora_scale,
             )
-            carry = _install_row(
-                carry, caches, r, t0, l0, jnp.asarray(mask), pl,
+            # _admit_one consumed carry[3] (session.py: the pool is
+            # donated); the carry goes on with the pool it returned
+            carry = _with_pool(_install_row(
+                _sans_pool(carry), r, t0, l0, jnp.asarray(mask), pl,
                 Tp=Tp_ep, max_tokens=turn_tokens,
                 eos_token_id=eos_token_id, pad_token_id=pad_token_id,
                 spec=False,
-            )
+            ), caches)
             owner[r] = ep
             admissions += 1
         if any(o >= 0 for o in owner):

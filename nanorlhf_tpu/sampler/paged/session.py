@@ -81,6 +81,7 @@ from nanorlhf_tpu.sampler.sampler import (
     _sample_token,
     _token_logprob,
 )
+from nanorlhf_tpu.utils.donation import jit_donating
 from nanorlhf_tpu.utils.profiling import PhaseTimer
 
 # the host phases of a beat and of an admission, each a `PhaseTimer.phase`
@@ -207,14 +208,21 @@ def _chunk_loop(params, config, state, table, row_params, statics):
     return state
 
 
-@partial(jax.jit, static_argnames=_CHUNK_STATIC)
+# Every program below that takes the page pool and returns it DONATES it
+# (utils/donation.py: on an accelerator), so the pool is written where it
+# lies and no call copies it. The chunk programs carry it in slot 3 of
+# `state` and donate the carry whole; the admission programs donate `caches`.
+# A donated argument is consumed: the caller puts the result in its place in
+# the same statement and holds no other reference (`DecodeSession` docstring).
+
+@partial(jit_donating, donate=2, static_argnames=_CHUNK_STATIC)
 def _decode_chunk(params, config, state, table, **statics):
     """Rollout-mode chunk: static sampling params (the pre-session
     scheduler's `_decode_chunk`, bit-identical program)."""
     return _chunk_loop(params, config, state, table, None, statics)
 
 
-@partial(jax.jit, static_argnames=_CHUNK_STATIC)
+@partial(jit_donating, donate=2, static_argnames=_CHUNK_STATIC)
 def _serving_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
                    r_budget, **statics):
     """Serving-mode chunk: per-request sampling params and token budgets
@@ -263,7 +271,7 @@ def _spec_loop(params, config, state, table, prompt_rep, seed_rep, seed_len,
     return state
 
 
-@partial(jax.jit, static_argnames=_SPEC_CHUNK_STATIC)
+@partial(jit_donating, donate=2, static_argnames=_SPEC_CHUNK_STATIC)
 def _spec_chunk(params, config, state, table, prompt_rep, **statics):
     """Spec chunk, own-buffer drafting only (spec without the radix
     cache — the pre-session scheduler's `_spec_chunk`)."""
@@ -271,7 +279,7 @@ def _spec_chunk(params, config, state, table, prompt_rep, **statics):
                       statics)
 
 
-@partial(jax.jit, static_argnames=_SPEC_CHUNK_STATIC)
+@partial(jit_donating, donate=2, static_argnames=_SPEC_CHUNK_STATIC)
 def _spec_chunk_seeded(params, config, state, table, prompt_rep, seed_rep,
                        seed_len, **statics):
     """Spec chunk with the radix-seeded lookup window (spec × prefix
@@ -281,9 +289,10 @@ def _spec_chunk_seeded(params, config, state, table, prompt_rep, seed_rep,
                       seed_len, statics)
 
 
-@partial(jax.jit, static_argnames=("config", "page_size", "T_max",
-                                   "temperature", "top_p", "greedy", "top_k",
-                                   "approx_top_k", "lora_scale"))
+@partial(jit_donating, donate=4,
+         static_argnames=("config", "page_size", "T_max", "temperature",
+                          "top_p", "greedy", "top_k", "approx_top_k",
+                          "lora_scale"))
 def _admit_one(params, config, pids, pmask, caches, row_table, key, *,
                page_size, T_max, temperature, top_p, greedy, top_k,
                approx_top_k, lora_scale):
@@ -305,7 +314,7 @@ def _admit_one(params, config, pids, pmask, caches, row_table, key, *,
 
 @partial(jax.jit, static_argnames=("Tp", "max_tokens", "eos_token_id",
                                    "pad_token_id", "spec", "per_row"))
-def _install_row(state, caches, r, tok0, lp0, pmask_row, plen, budget=None,
+def _install_row(state, r, tok0, lp0, pmask_row, plen, budget=None,
                  *, Tp, max_tokens, eos_token_id, pad_token_id, spec,
                  per_row=False):
     """Re-initialize resident row `r` of the carry for a freshly admitted
@@ -314,10 +323,10 @@ def _install_row(state, caches, r, tok0, lp0, pmask_row, plen, budget=None,
     ten slots of the spec carry line up, and `spec` additionally resets the
     per-row accepted-draft counter. `per_row` (serving) folds the traced
     token `budget` into the initial done flag (a budget-1 request is done
-    at its first token)."""
+    at its first token). `state` comes `_sans_pool` and goes back so: the
+    admission forward has the pool, this program never sees it."""
     s = list(state)
     T_mask = s[4].shape[1]
-    s[3] = caches
     s[1] = s[1].at[r].set(
         jnp.full((max_tokens,), pad_token_id, jnp.int32).at[0].set(tok0))
     s[2] = s[2].at[r].set(jnp.zeros((max_tokens,), jnp.float32).at[0].set(lp0))
@@ -333,6 +342,15 @@ def _install_row(state, caches, r, tok0, lp0, pmask_row, plen, budget=None,
     if spec:
         s[14] = s[14].at[r].set(jnp.int32(0))
     return tuple(s)
+
+
+def _sans_pool(state):
+    """The carry with nothing in the pool's slot (None is an empty pytree)."""
+    return state[:3] + (None,) + state[4:]
+
+
+def _with_pool(state, caches):
+    return state[:3] + (caches,) + state[4:]
 
 
 _release_jit = jax.jit(release_row)
@@ -351,7 +369,8 @@ def _admit_sample(logits, key, *, temperature, top_p, greedy, top_k,
     return tok0[0], _token_logprob(logits[None, :], tok0, temperature)[0]
 
 
-@partial(jax.jit, static_argnames=("config", "page_size", "lora_scale"))
+@partial(jit_donating, donate=6,
+         static_argnames=("config", "page_size", "lora_scale"))
 def _prefill_chunk_fwd(params, config, chunk_ids, positions, fill, key_mask,
                        caches, row_table, *, page_size, lora_scale):
     """One KV-only prefill chunk: a `decode_verify` forward over a
@@ -407,6 +426,19 @@ class DecodeSession:
         greedy / budget; `capture_logprobs` is illegal (the logprob
         write needs a static temperature) — `sampler.compose_check`
         documents the matrix.
+
+    Who owns the pool: `self.state` holds the ONLY reference to the page
+    pool (slot 3), and every program that takes it donates it, so a call
+    consumes the pool it is given and the session puts the returned one in
+    its place at once. The chunk programs consume the whole carry, so
+    nothing outside `self.state` may alias one of its arrays (the carry has
+    a PRNG key of its own: `_key` / `_admit_key` are folded for as long as
+    the session lives), and only the thread that drives the session may
+    read `self.state`. Other threads read `status()` / `iterations()`,
+    which answer from the host's record of the last sync. A donating call
+    that raises leaves a consumed pool in the carry: the next use raises
+    "Array has been deleted", the session is finished and nothing decodes
+    on freed pages (docs/SERVING.md "Who owns the page pool").
 
     The session NEVER resets an attached `prefix_cache` implicitly at
     step time — it resets it exactly once at construction (the rollout
@@ -489,21 +521,20 @@ class DecodeSession:
         R = self.rows
         # empty carry: every row starts done; admit() installs rows
         # through the same path mid-loop admissions use
-        base = (jnp.int32(1),
-                jnp.full((R, self.max_tokens), self.pad_token_id, jnp.int32),
-                jnp.zeros((R, self.max_tokens), jnp.float32),
-                caches0,
-                jnp.zeros((R, self.T_max), bool),
-                jnp.ones((R,), bool),
-                jnp.zeros((R,), jnp.int32),
-                jnp.ones((R,), jnp.int32),
-                jnp.zeros((R,), jnp.int32),
-                key)
-        if self.spec:
-            zero = jnp.int32(0)
-            base = base + (zero, zero, zero, zero,
-                           jnp.zeros((R,), jnp.int32))
-        self.state = base
+        self.state = self._carry(
+            jnp.full((R, self.max_tokens), self.pad_token_id, jnp.int32),
+            jnp.zeros((R, self.max_tokens), jnp.float32),
+            caches0,
+            jnp.zeros((R, self.T_max), bool),
+            jnp.ones((R,), bool),
+            jnp.zeros((R,), jnp.int32),
+            jnp.zeros((R,), jnp.int32))
+        # 1 when the session's programs consume the pool they are given
+        # (`serving/pool_donated`); one rule for all of them
+        self.pool_donated = int(_decode_chunk.donates(caches0))
+        # the host's record of the carry, as of the last sync and the
+        # admissions and cancels since: what other threads may read
+        self._done_np = np.ones((R,), bool)
 
         self._sample_kw = dict(temperature=temperature, top_p=top_p,
                                greedy=greedy, top_k=top_k,
@@ -565,6 +596,24 @@ class DecodeSession:
         self.backlog_peak = 0
         self._it_prev = 0
 
+    def _carry(self, out, lp_out, caches, key_mask, done, cur_tok,
+               prompt_len):
+        """The carry at iteration 1 with one token a row. Every slot is an
+        array of the carry's own, none shared with another slot or with
+        the session: the chunk programs donate all of them."""
+        R = self.rows
+        state = (jnp.int32(1), out, lp_out, caches, key_mask, done, cur_tok,
+                 jnp.ones((R,), jnp.int32), prompt_len, jnp.copy(self._key))
+        if self.spec:
+            # n_drafted · n_accepted · n_emitted · n_rowsteps · row_acc
+            # (speculative._spec_state)
+            state += tuple(jnp.int32(0) for _ in range(4)) + (
+                jnp.zeros((R,), jnp.int32),)
+        return state
+
+    def _set_pool(self, caches):
+        self.state = _with_pool(self.state, caches)
+
     # ------------------------------------------------------------- #
     # admission
     # ------------------------------------------------------------- #
@@ -596,15 +645,12 @@ class DecodeSession:
             ttft0 = time.perf_counter() - t0
             for _ in range(R):
                 self._hub.record("latency/ttft_s", ttft0)
-        if self.spec:
-            from nanorlhf_tpu.sampler.speculative import _spec_state
-            self.state = _spec_state(base)
-        else:
-            self.state = (jnp.int32(1), out0, lp0, caches, key_mask0, done0,
-                          tok0, jnp.ones((R,), jnp.int32), plen0, self._key)
+        self.state = self._carry(out0, lp0, caches, key_mask0, done0, tok0,
+                                 plen0)
         self._prompt_res_np[:] = np.asarray(prompt_ids[:R])
         self._prompt_rep = jnp.asarray(self._prompt_res_np)
-        self._it_prev = int(self.state[0]) - 1
+        self._it_prev = 0
+        self._done_np[:] = False    # exact at the first sync
         self._row_start_np[:] = self.Tp - np.asarray(prompt_mask[:R]).sum(1)
         self._row_gen_np[:] = 1
         self._row_live_np[:] = True
@@ -648,9 +694,8 @@ class DecodeSession:
                         kelems, self.seed_window)
                 self.table_np[r] = plan.row_pages
                 if plan.cow_src is not None:
-                    s = list(self.state)
-                    s[3] = copy_page(s[3], plan.cow_src, plan.cow_dst)
-                    self.state = tuple(s)
+                    self._set_pool(copy_page(self.state[3], plan.cow_src,
+                                             plan.cow_dst))
                 # per-row mode runs the unified suffix forward even on a
                 # cold miss (start = pad_count, pad KV never written);
                 # rollout mode keeps the cold full-row prefill so its
@@ -708,7 +753,6 @@ class DecodeSession:
         """Unchunked (or final-chunk-only) admission forward + install."""
         from nanorlhf_tpu.serving.radix import bucket_len, suffix_logits
         p = pend
-        caches = self.state[3]
         row_table = (jnp.asarray(self.table_np[p.row])
                      if self._radix is not None else p.row_table)
         if full_cold and not self.per_row and self.prefill_chunk == 0:
@@ -716,9 +760,10 @@ class DecodeSession:
             # included) — kept verbatim so rollout parity pins hold
             caches, t0, l0, plen = _admit_one(
                 self.params, self.config, jnp.asarray(p.toks[None, :]),
-                jnp.asarray(p.mask[None, :]), caches, row_table,
+                jnp.asarray(p.mask[None, :]), self.state[3], row_table,
                 p.admit_key, page_size=self.page_size, T_max=self.T_max,
                 lora_scale=self.lora_scale, **self._sample_kw)
+            self._set_pool(caches)
             self.dispatch_tokens += self.Tp
         else:
             s_real = self.Tp - start_abs
@@ -732,9 +777,10 @@ class DecodeSession:
             logits, caches = suffix_logits(
                 self.params, self.config, jnp.asarray(suffix),
                 jnp.asarray(pos), jnp.asarray([start_abs], jnp.int32),
-                jnp.int32(s_real - 1), jnp.asarray(km), caches,
+                jnp.int32(s_real - 1), jnp.asarray(km), self.state[3],
                 row_table, page_size=self.page_size,
                 lora_scale=self.lora_scale)
+            self._set_pool(caches)
             self.dispatch_tokens += Sb
             self.hit_tokens += p.plan_hit
             if self.per_row:
@@ -749,9 +795,9 @@ class DecodeSession:
                                        **self._sample_kw)
             plen = jnp.int32(int(p.mask.sum()))
         self.launches += 1
-        return self._install(p, caches, t0, l0, plen)
+        return self._install(p, t0, l0, plen)
 
-    def _install(self, p: _PendingPrefill, caches, t0, l0, plen):
+    def _install(self, p: _PendingPrefill, t0, l0, plen):
         r = p.row
         if self._radix is not None:
             self._radix.insert(p.kelems, self.table_np[r], self.Tp)
@@ -784,13 +830,18 @@ class DecodeSession:
         if self._hub is not None:
             self._hub.record("latency/ttft_s",
                              time.perf_counter() - p.t_start)
-        self.state = _install_row(
-            self.state, caches, r, t0, l0, jnp.asarray(p.mask), plen,
+        self.state = _with_pool(_install_row(
+            _sans_pool(self.state), r, t0, l0, jnp.asarray(p.mask), plen,
             (jnp.int32(int(p.budget)) if self.per_row else None),
             Tp=self.Tp, max_tokens=self.max_tokens,
             eos_token_id=self.eos_token_id, pad_token_id=self.pad_token_id,
-            spec=self.spec, per_row=self.per_row)
-        return int(t0) if self.per_row else None
+            spec=self.spec, per_row=self.per_row), self.state[3])
+        tok0 = int(t0) if self.per_row else None
+        # rollout mode does not wait for its first token: live until the
+        # next sync says otherwise
+        self._done_np[r] = self.per_row and (
+            tok0 == self.eos_token_id or int(p.budget) <= 1)
+        return tok0
 
     # ------------------------------------------------------------- #
     # stepping
@@ -819,12 +870,11 @@ class DecodeSession:
         km[0, p.pad_count:p.next_slot] = True
         row_table = (jnp.asarray(self.table_np[p.row])
                      if self._radix is not None else p.row_table)
-        s = list(self.state)
-        s[3] = _prefill_chunk_fwd(
+        self._set_pool(_prefill_chunk_fwd(
             self.params, self.config, jnp.asarray(chunk), jnp.asarray(pos),
-            jnp.asarray([p.next_slot], jnp.int32), jnp.asarray(km), s[3],
-            row_table, page_size=self.page_size, lora_scale=self.lora_scale)
-        self.state = tuple(s)
+            jnp.asarray([p.next_slot], jnp.int32), jnp.asarray(km),
+            self.state[3], row_table, page_size=self.page_size,
+            lora_scale=self.lora_scale))
         p.next_slot += C
         self.launches += 1
         self.dispatch_tokens += C
@@ -883,6 +933,7 @@ class DecodeSession:
                                  / (it_now - self._it_prev))
         self._count_attention(it_now - self._it_prev, done_h)
         self._it_prev = it_now
+        np.copyto(self._done_np, done_h)
         return done_h, installed
 
     def _count_attention(self, its: int, done_h) -> None:
@@ -914,8 +965,9 @@ class DecodeSession:
     # ------------------------------------------------------------- #
 
     def iterations(self) -> int:
-        """Decode/verify iterations so far (the carry's own counter)."""
-        return int(self.state[0]) - 1
+        """Decode/verify iterations so far: the carry's own counter as
+        `step()` last brought it to the host (only chunks advance it)."""
+        return self._it_prev
 
     def dispatch_events(self) -> int:
         """Total model-forward launches: admission/chunk forwards plus
@@ -962,6 +1014,7 @@ class DecodeSession:
         disconnect can never leak what a completion would have freed."""
         self._pending = [p for p in self._pending if p.row != r]
         self._row_live_np[r] = False
+        self._done_np[r] = True
         s = list(self.state)
         s[5] = s[5].at[r].set(True)
         self.state = tuple(s)
@@ -979,8 +1032,9 @@ class DecodeSession:
 
     def status(self) -> dict:
         """JSON-able /statusz `session` section: resident rows, the
-        chunked-prefill backlog, and per-row feature flags."""
-        done_h = np.asarray(self.state[5])
+        chunked-prefill backlog, and per-row feature flags. Safe from any
+        thread: it reads the host's record, never the carry."""
+        done_h = self._done_np.copy()
         pend = self.pending_rows()
         return {
             "rows": self.rows,

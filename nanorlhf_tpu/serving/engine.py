@@ -434,6 +434,7 @@ class ServingEngine:
             "serving/attn_live_pages": self._sess.attn_live_pages,
             "serving/attn_table_pages": self._sess.attn_table_pages,
             "serving/attn_in_place": self._sess.attn_in_place,
+            "serving/pool_donated": self._sess.pool_donated,
             "pages/shared": snap["shared_pages"],
         }
         for reason, n in sorted(reasons.items()):

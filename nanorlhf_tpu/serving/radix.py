@@ -63,6 +63,7 @@ import numpy as np
 
 from nanorlhf_tpu.analysis.lockorder import make_lock
 from nanorlhf_tpu.core.model import decode_verify
+from nanorlhf_tpu.utils.donation import jit_donating
 
 
 class RefPagePool:
@@ -505,14 +506,19 @@ class RadixCache:
 # device helpers (shared by the rollout scheduler and the engine)
 # ----------------------------------------------------------------- #
 
-@jax.jit
+# Both programs take the session's page pool as `caches` and return it, and
+# both DONATE it (utils/donation.py: on an accelerator): the pool the caller
+# passed is consumed, the returned one takes its place.
+
+@partial(jit_donating, donate=0)
 def copy_page(caches, src, dst):
     """COW split: duplicate physical page `src` into `dst` across every
     layer of the pool pytree ([L, num_pages, ...] leaves)."""
     return jax.tree.map(lambda c: c.at[:, dst].set(c[:, src]), caches)
 
 
-@partial(jax.jit, static_argnames=("config", "page_size", "lora_scale"))
+@partial(jit_donating, donate=7,
+         static_argnames=("config", "page_size", "lora_scale"))
 def suffix_logits(params, config, suffix_ids, positions, fill, last,
                   key_mask, caches, row_table, *, page_size, lora_scale):
     """Single-row suffix prefill: a `decode_verify` forward over the

@@ -51,9 +51,9 @@ from nanorlhf_tpu.trainer.trainer import (
     RLTrainer,
     TrainRun,
     Update,
-    donate_argnums_on_accel,
     fused_response_logprobs,
 )
+from nanorlhf_tpu.utils.donation import donate_argnums_on_accel
 
 # forward budget comes from RLTrainer._forward_budget (activation ∧ vocab caps);
 # backward keeps the reference's dedicated constant (`grpo_r1_trainer.py:700`)

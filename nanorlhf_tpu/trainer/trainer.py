@@ -88,6 +88,7 @@ from nanorlhf_tpu.trainer.checkpoint import CheckpointManager
 from nanorlhf_tpu.trainer.config import AlgoName, RLConfig
 from nanorlhf_tpu.trainer.metrics import (MetricsLogger,
                                           staleness_histogram_metrics)
+from nanorlhf_tpu.utils.donation import donate_argnums_on_accel
 
 # Rollout-phase forward chunking. Two independent memory models bound the
 # chunk: (1) the reference's empirical activation budget `22*2316` tokens
@@ -178,21 +179,6 @@ def device_peak_bytes() -> float:
             stats = {}
         peak = max(peak, float(stats.get("peak_bytes_in_use", 0.0)))
     return peak
-
-
-def donate_argnums_on_accel(*nums: int) -> tuple:
-    """Buffer donation argnums, gated off on the CPU backend.
-
-    On accelerators donation lets XLA reuse the params/opt-state HBM across
-    the update — essential at scale. On the CPU backend it buys nothing
-    (host RAM, test-sized models) and is LETHAL in combination with the
-    persistent compilation cache on current jaxlib: an executable
-    deserialized from the cache with donated buffers segfaults/aborts the
-    process a few optimizer steps in (deterministically reproduced via
-    repeated train/resume cycles — fresh or warm cache alike; with donation
-    off, the same sequence passes). Launchers enable the cache for every
-    backend, so this protects CPU demo runs as well as the test suite."""
-    return nums if jax.default_backend() != "cpu" else ()
 
 
 def pad_chunk(rows: np.ndarray, chunk: int) -> np.ndarray:

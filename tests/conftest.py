@@ -27,7 +27,7 @@ jax.config.update("jax_enable_x64", False)
 # Same directory rule as launchers, bench and tools (utils/compile_cache.py).
 # NOTE: cache-deserialized CPU executables with DONATED buffers abort the
 # process on this jaxlib — which is why the trainer gates buffer donation
-# off on the CPU backend (trainer.donate_argnums_on_accel); without that
+# off on the CPU backend (utils/donation.donate_argnums_on_accel); without that
 # gate this cache would have to stay off for the whole suite.
 from nanorlhf_tpu.utils.compile_cache import (  # noqa: E402
     enable_compilation_cache,

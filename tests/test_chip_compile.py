@@ -221,12 +221,14 @@ def _shapes_on(tree, sharding):
         tree)
 
 
-def _decode_loop(case, one_chip):
+def _decode_loop(case, one_chip, layers=2):
     """(lowered program, stacked cache shapes) of a decode loop at
     Qwen2.5-1.5B widths, two layers deep: the benchmark's rollout (16 prompts
     x 4 samples, 256 + 512 slots), the same over an int8 cache, and the
     serving session's chunk (64 rows, 807 pages of 128); `rollout_olmoe` is
-    the rollout at OLMoE-1B-7B's widths (64 experts, 8 a token)."""
+    the rollout at OLMoE-1B-7B's widths (64 experts, 8 a token).
+    `serving_admission` is the session's largest suffix prefill (1024
+    tokens) over the same pool."""
     import dataclasses
 
     from nanorlhf_tpu.core import ModelConfig, init_params
@@ -236,7 +238,8 @@ def _decode_loop(case, one_chip):
 
     cfg = ModelConfig(
         vocab_size=V, hidden_size=D, intermediate_size=8960,
-        num_hidden_layers=2, num_attention_heads=H, num_key_value_heads=KV,
+        num_hidden_layers=layers, num_attention_heads=H,
+        num_key_value_heads=KV,
         kv_cache_quant="int8" if case == "rollout_int8" else "none")
     if case == "rollout_olmoe":
         cfg = dataclasses.replace(ModelConfig.olmoe_1b_7b(), num_hidden_layers=2)
@@ -248,10 +251,21 @@ def _decode_loop(case, one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
-    if case == "serving_chunk":
+    if case in ("serving_chunk", "serving_admission"):
         R, Tp, new, pages = 64, 1024, 512, 807
         cache = jax.eval_shape(
             lambda: M.init_paged_kv_cache(cfg, pages, PAGE, jnp.bfloat16))
+    if case == "serving_admission":
+        from nanorlhf_tpu.serving.radix import suffix_logits
+
+        lowered = suffix_logits.lower(
+            params, cfg, spec((1, Tp), jnp.int32), spec((1, Tp), jnp.int32),
+            spec((1,), jnp.int32), spec((), jnp.int32),
+            spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip),
+            spec(((Tp + new) // PAGE,), jnp.int32), page_size=PAGE,
+            lora_scale=1.0)
+        return lowered, cache
+    if case == "serving_chunk":
         state = (spec((), jnp.int32), spec((R, new), jnp.int32),
                  spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
                  spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
@@ -313,6 +327,44 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
                 if not result.startswith("(")
                 and _shapes(result) and _shapes(result)[0][1] in view]
         assert not made, "\n".join(made)
+
+
+@pytest.mark.parametrize("case", ["serving_chunk", "serving_admission"])
+def test_session_program_writes_the_page_pool_where_it_lies_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 30, asked of the chip's compiler at the `serve-1.5b-chat` cell's
+    shapes (Qwen2.5-1.5B whole, 64 rows, 807 pages of 128: each pool leaf
+    `bf16[28,807,2,128,128]`, 1.48 GB): the session's chunk and its largest
+    admission forward DONATE the pool (`utils/donation.jit_donating`, decided
+    from the described devices the arguments name), so the compiled module
+    aliases both pool leaves from its parameters to its results and no `copy`
+    of a leaf is left anywhere in it. Without the donation each call kept its
+    argument alive and built the result beside it: one copy of each leaf per
+    call, 21 % of the device's busy time in that cell (ledger, PR 29)."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, cache = _decode_loop(case, SingleDeviceSharding(v5e[0]),
+                                  layers=28)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    (pool,) = hlo_stacks(cache)
+    assert pool == ("bf16", (28, 807, 2, 128, 128))
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo, re.M).group(1)
+    leaves = {int(rest.split(")")[0]) for _, result, op, rest in comps[entry]
+              if op == "parameter" and _shapes(result)[:1] == [pool]}
+    assert len(leaves) == 2 and leaves <= aliased, (leaves, aliased)
+    pool_bytes = 2 * 2 * int(np.prod(pool[1]))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and pool in _shapes(result)]
+    assert not copies, "\n".join(copies)
 
 
 def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(
