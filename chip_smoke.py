@@ -3,6 +3,7 @@
     python chip_smoke.py              # one chip:   kernels, train, serve
     python chip_smoke.py --multichip  # four chips: sharded GRPO vs one device
     python chip_smoke.py --olmoe      # one chip:   the sparse-expert layer
+    python chip_smoke.py --axk1       # one chip:   latent attention + a chip's share
 
 One process. It pins no platform: the first thing it does after `import jax`
 is read `jax.devices()`, print what it found, and exit non-zero unless that is
@@ -41,6 +42,15 @@ adapter, against `benchmark/harness/reference_olmoe.py` in float32 at
 through the contiguous cache (teacher-forced) and through the paged
 `DecodeSession` (greedy), each held to what the plain bf16 forward itself
 loses against float32, measured in the run (`phase_olmoe`).
+
+`--axk1` likewise runs A.X-K1 alone, as one chip of sixteen holds it
+(`benchmark/configs/axk1-ep16.json`: published widths, 1 + 6 layers, 12 of
+192 experts, an eighth of the vocabulary; 9.7 GB of bf16 weights), against
+`benchmark/harness/reference_axk1.py`: the scoring forward, then a prefill
+of a thousand tokens (the expanded form) and teacher-forced single-token
+steps through the contiguous latent cache (the absorbed form), each held to
+what the plain bf16 forward loses against float32 (`phase_axk1`). The paged
+session at these widths is the benchmark cell `serve-axk1-docqa` itself.
 
 Sizes live in `Sizes`; a rehearsal on the CPU imports this module and passes
 smaller ones (tests and scratch scripts steer, the program grows no option).
@@ -105,6 +115,12 @@ class Sizes:
     olmoe_decode: int = 32
     olmoe_context: int = 256       # scoring: responses start here
     olmoe_last: int = 128          # logits compared on the last positions
+    # axk1: the benchmark's configuration file, or a tiny one to rehearse
+    axk1_config: str = "benchmark/configs/axk1-ep16.json"
+    axk1_rows: int = 2
+    axk1_prompt: int = 1000        # prefill: past one token block of the share
+    axk1_decode: int = 24          # absorbed single-token steps
+    axk1_last: int = 64            # logits compared on the last positions
 
 
 def emit(phase: str, **fields) -> None:
@@ -975,7 +991,94 @@ def phase_olmoe(sz: Sizes, meter: Meter) -> None:
                  contiguous=contiguous, paged=paged, greedy=greedy)
 
 
-def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False) -> None:
+# --------------------------------------------------------------------------- #
+# latent attention, a chip's share of the experts
+# --------------------------------------------------------------------------- #
+
+
+def phase_axk1(sz: Sizes, meter: Meter) -> None:
+    """A.X-K1 through the normal path against the plain float32 reference,
+    under `bf16_agreement`'s rule on logits: the path under test may be
+    BF16_SLACK times further from float32 than the plain bf16 forward (XLA
+    `ragged_dot`, no cache, expanded attention), both measured here on the
+    same positions. The scoring forward takes the grouped-matmul kernel; the
+    contiguous cache takes the expanded form at prefill and the absorbed
+    form at every step after it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from harness import reference_axk1
+
+    from nanorlhf_tpu.core import (ModelConfig, decode_step, init_kv_cache,
+                                   init_params, padded_forward_logits, prefill)
+
+    phase = Phase("axk1", meter)
+    with open(os.path.join(ROOT, sz.axk1_config)) as f:
+        file = json.load(f)
+    mcfg = ModelConfig.from_hf_config(file)
+    plain_mcfg = dataclasses.replace(mcfg, attention_impl="xla")
+    check(mcfg.kv_lora_rank == file["kv_lora_rank"]
+          and mcfg.num_experts == file["n_routed_experts"]
+          and mcfg.num_dense_layers == file["first_k_dense_replace"],
+          "from_hf_config dropped the latent or the expert keys")
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    key = jax.random.PRNGKey(sz.seed)
+    params = jax.jit(lambda k: init_params(mcfg, k, dtype))(key)
+    pad = 0
+    P, n_new = sz.axk1_prompt, sz.axk1_decode
+    T, last = P + n_new, sz.axk1_last
+    ids = np.array(jax.random.randint(jax.random.fold_in(key, 2),
+                                      (sz.axk1_rows, T), 3, mcfg.vocab_size))
+    ids[0, : P // 8] = pad                                 # one left-padded row
+    ids = jnp.asarray(ids, jnp.int32)
+    real = ids != pad
+
+    def within(tested, plain, ref, what):
+        return bf16_agreement(phase, tested, plain, ref,
+                              np.ones(np.shape(ref), bool), (what, "plain"))
+
+    with jax.default_matmul_precision("highest"):
+        ref_last = np.asarray(jax.jit(lambda p, x, m: reference_axk1.logits(
+            p, file, x, pad, last=last, mask=m))(params, ids, real))
+    forward = lambda m: np.asarray(jax.jit(lambda p, x: padded_forward_logits(  # noqa: E731
+        p, m, x, pad)[:, -last:])(params, ids), np.float32)
+    plain_last = forward(plain_mcfg)
+    scoring = within(forward(mcfg), plain_last, ref_last, "auto_scoring")
+
+    # ---- prefill + teacher-forced decode through the contiguous cache ----
+    steps = slice(last - n_new - 1, last)    # positions P-1 .. T-1: n_new + 1
+    caches = init_kv_cache(mcfg, sz.axk1_rows, T, dtype)
+    latent_bytes = sum(c.nbytes for c in caches) // (sz.axk1_rows * T)
+    phase.expect(latent_bytes == mcfg.num_hidden_layers * mcfg.latent_width
+                 * jnp.dtype(dtype).itemsize,
+                 f"the cache holds {latent_bytes} B a token, not the latent")
+    lg, caches = jax.jit(lambda p, x, m, c: prefill(p, mcfg, x, m, c))(
+        params, ids[:, :P], real[:, :P], caches)
+    step = jax.jit(lambda p, tok, pos, t, km, c: decode_step(
+        p, mcfg, tok, pos, t, km, c))
+    got, n_real = [np.asarray(lg, np.float32)], real[:, :P].sum(axis=1)
+    key_mask = jnp.zeros((sz.axk1_rows, T), bool).at[:, :P].set(real[:, :P])
+    for t in range(P, T):
+        key_mask = key_mask.at[:, t].set(True)
+        lg, caches = step(params, ids[:, t], n_real + (t - P), jnp.int32(t),
+                          key_mask, caches)
+        got.append(np.asarray(lg, np.float32))
+    del caches
+    contiguous = within(np.stack(got, axis=1), plain_last[:, steps],
+                        ref_last[:, steps], "contiguous_cache")
+    phase.finish(config=sz.axk1_config, layers=mcfg.num_hidden_layers,
+                 hidden=mcfg.hidden_size, experts_held=mcfg.num_held_experts,
+                 router_width=mcfg.num_experts, vocab=mcfg.vocab_size,
+                 latent_bytes_per_token=int(latent_bytes),
+                 dtype=str(jnp.dtype(dtype)), rows=sz.axk1_rows, tokens=T,
+                 prefill=P, decode_steps=n_new, scoring=scoring,
+                 contiguous=contiguous)
+
+
+def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False,
+               axk1: bool = False) -> None:
     """Everything after the device gate. Raises at the first failed phase."""
     from nanorlhf_tpu import native
     from nanorlhf_tpu.core import ModelConfig
@@ -993,6 +1096,9 @@ def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False) -> None:
     if olmoe:
         phase_olmoe(sz, meter)
         return
+    if axk1:
+        phase_axk1(sz, meter)
+        return
     tiny = "tiny" in sz.model.lower()  # entrypoints.common.resolve_model's rule
     phase_kernels(sz, ModelConfig.qwen2_tiny(vocab_size=4096) if tiny
                   else ModelConfig.qwen2_1_5b(), meter)
@@ -1006,6 +1112,8 @@ def main(argv=None) -> int:
                         help="four chips: sharded GRPO vs one device, only")
     parser.add_argument("--olmoe", action="store_true",
                         help="one chip: OLMoE against its float32 reference, only")
+    parser.add_argument("--axk1", action="store_true",
+                        help="one chip: A.X-K1's share against its float32 reference, only")
     args = parser.parse_args(argv)
 
     import jax
@@ -1023,7 +1131,7 @@ def main(argv=None) -> int:
               f"{device['count']}", file=sys.stderr)
         return 1
 
-    run_phases(Sizes(), args.multichip, args.olmoe)
+    run_phases(Sizes(), args.multichip, args.olmoe, args.axk1)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -1,11 +1,12 @@
-"""Model architecture config: one decoder layer kind per model, either the
-dense Qwen2/Llama block (GQA + SwiGLU) or OLMoE's sparse-expert block.
+"""Model architecture config: the dense Qwen2/Llama block (GQA + SwiGLU),
+OLMoE's sparse-expert block, or A.X-K1's (DeepSeek-V3's) latent attention
+with leading dense layers before shared-plus-routed sigmoid experts.
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
 `/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
 architecture hyperparameters our JAX decoder needs. Presets mirror the HF
 configs of the model sizes the reference trains (0.5B/1.5B/7B), the Llama
-side of the same block, and OLMoE-1B-7B (docs/MOE.md).
+side of the same block, OLMoE-1B-7B (docs/MOE.md) and A.X-K1 (docs/MLA.md).
 """
 
 from __future__ import annotations
@@ -52,6 +53,41 @@ class ModelConfig:
     # RMSNorm of q and k over their WHOLE projection width, before the head
     # split and RoPE (OLMoE; `from_hf_config` sets it from the model type).
     qk_norm: bool = False
+    # Latent attention (MLA, docs/MLA.md; `kv_lora_rank > 0` says the model
+    # has it): queries through a `q_lora_rank` bottleneck, keys and values
+    # through one `kv_lora_rank` latent a token plus a `qk_rope_head_dim`
+    # rotary key all heads share, which is all the cache holds
+    # (`latent_width`). Heads are `qk_nope_head_dim + qk_rope_head_dim` wide
+    # for q and k and `v_head_dim` for v; `head_dim` is not used.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN, DeepSeek-V3's formula (core/mla.py): (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim), or None for plain RoPE. A tuple: the config is a
+    # static jit argument.
+    yarn: Optional[tuple] = None
+    # The A.X-K1 / DeepSeek-V3 expert layer (ops/moe.py): the first
+    # `first_k_dense_replace` layers keep the dense SwiGLU of width
+    # `intermediate_size`; every later layer routes over `num_experts`
+    # (`n_routed_experts`: the router's width) experts of width
+    # `moe_intermediate_size` with `scoring_func` scores, scales the
+    # renormalised top-k weights by `routed_scaling_factor`, and adds
+    # `n_shared_experts` shared experts (one SwiGLU of their summed width)
+    # to every token.
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"  # softmax | sigmoid
+    routed_scaling_factor: float = 1.0
+    # The chip's share of an expert-parallel deployment: this program holds
+    # experts [experts_offset, experts_offset + experts_held) of every expert
+    # layer, routes over all `num_experts`, and adds its own experts' part
+    # only (docs/MLA.md "the chip's share"). 0 held = all of them.
+    experts_held: int = 0
+    experts_offset: int = 0
     # "int8": the sampler's KV cache stores int8 values + per-token-per-head
     # bf16 scales (absmax over head_dim). At long responses the cache read is
     # the dominant decode HBM stream (≈7.5 GB/step at 8k tokens, batch 32);
@@ -103,6 +139,26 @@ class ModelConfig:
     @property
     def num_kv_groups(self) -> int:
         return self.num_attention_heads // self.num_key_value_heads
+
+    @property
+    def latent_width(self) -> int:
+        """What an MLA cache holds a token a layer: `[c_kv | k_rope]`."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def num_dense_layers(self) -> int:
+        """Leading layers with a dense MLP in a model whose other layers
+        have experts (0 in every single-kind model)."""
+        return min(self.first_k_dense_replace, self.num_hidden_layers) \
+            if self.num_experts else 0
+
+    @property
+    def num_held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
 
     @classmethod
     def qwen2_tiny(cls, vocab_size: int = 512) -> "ModelConfig":
@@ -178,6 +234,55 @@ class ModelConfig:
             num_experts=8, num_experts_per_tok=2)
 
     @classmethod
+    def axk1(cls) -> "ModelConfig":
+        """skt/A.X-K1: 61 MLA layers, the first dense (width 18,432), then
+        192 sigmoid-routed experts of width 2,048, 8 per token, renormalised
+        and scaled by 2.5, plus one shared expert; YaRN x32; untied head."""
+        return cls(
+            vocab_size=163840,
+            hidden_size=7168,
+            intermediate_size=18432,
+            num_hidden_layers=61,
+            num_attention_heads=64,
+            num_key_value_heads=64,
+            rope_theta=10_000.0,
+            rms_norm_eps=1e-6,
+            tie_word_embeddings=False,
+            max_position_embeddings=131072,
+            attention_bias=False,
+            model_type="axk1",
+            num_experts=192,
+            num_experts_per_tok=8,
+            norm_topk_prob=True,
+            q_lora_rank=1536,
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            yarn=(32.0, 4096, 32.0, 1.0, 1.0, 1.0),
+            first_k_dense_replace=1,
+            moe_intermediate_size=2048,
+            n_shared_experts=1,
+            scoring_func="sigmoid",
+            routed_scaling_factor=2.5,
+        )
+
+    @classmethod
+    def axk1_tiny(cls, vocab_size: int = 512, experts_held: int = 0,
+                  experts_offset: int = 0) -> "ModelConfig":
+        """Test-size A.X-K1: one dense layer and two expert layers, 16
+        routed experts, 4 per token, one shared; optionally a chip's share."""
+        return dataclasses.replace(
+            cls.axk1(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=4, max_position_embeddings=1024,
+            num_experts=16, num_experts_per_tok=4, q_lora_rank=24,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, yarn=(4.0, 64, 32.0, 1.0, 1.0, 1.0),
+            moe_intermediate_size=32, experts_held=experts_held,
+            experts_offset=experts_offset)
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -217,10 +322,10 @@ class ModelConfig:
     @classmethod
     def from_hf_config(cls, hf_config) -> "ModelConfig":
         """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
-        (or dict). A config the decoder does not implement raises: expert keys
-        under any model type but `olmoe` (shared experts, dense leading
-        layers and the rest are other layers than ops/moe.py's), and a
-        non-null `clip_qkv`."""
+        (or dict), or from A.X-K1's `config.json` (`model_type: axk1`,
+        DeepSeek-V3's key set; `_axk1_from_hf`). A config the decoder does not
+        implement raises: expert keys under any other model type (each family
+        routes, scales and shares in its own way), and a non-null `clip_qkv`."""
         get = (lambda k, d=None: getattr(hf_config, k, d)) if not isinstance(
             hf_config, dict
         ) else (lambda k, d=None: hf_config.get(k, d))
@@ -229,13 +334,16 @@ class ModelConfig:
         model_type = str(get("model_type", "qwen2")).lower()
         attn_bias = get("attention_bias", "qwen" in model_type)
         olmoe = model_type == "olmoe"
+        if model_type == "axk1":
+            return cls._axk1_from_hf(get)
         expert_keys = [k for k in _EXPERT_KEYS if get(k)]
         if expert_keys and not olmoe:
             raise ValueError(
                 f"model_type={model_type!r} with expert keys {expert_keys}: "
-                "the decoder implements OLMoE's sparse-expert layer only "
-                "(docs/MOE.md); building a dense model from this config "
-                "would be another model under its name")
+                "the decoder implements OLMoE's sparse-expert layer "
+                "(docs/MOE.md) and A.X-K1's (docs/MLA.md) only; building a "
+                "dense model from this config would be another model under "
+                "its name")
         if get("clip_qkv") is not None:
             raise ValueError(
                 f"clip_qkv={get('clip_qkv')!r}: the decoder does not clip "
@@ -258,4 +366,74 @@ class ModelConfig:
             num_experts_per_tok=get("num_experts_per_tok") or 0,
             norm_topk_prob=bool(get("norm_topk_prob", False)),
             qk_norm=olmoe,
+        )
+
+    @classmethod
+    def _axk1_from_hf(cls, get) -> "ModelConfig":
+        """A.X-K1's keys, and the chip's share where the file states one
+        (`n_routed_experts_held` / `n_routed_experts_offset`; a published
+        config.json has neither: all experts are held). Values the layer of
+        docs/MLA.md does not compute raise."""
+        refuse = {
+            "topk_method": ("none", None),      # no groups, no correction bias
+            "moe_layer_freq": (1, None),        # every later layer has experts
+            "hidden_act": ("silu", None),
+            "attention_bias": (False, None),
+        }
+        for key, allowed in refuse.items():
+            if get(key) not in allowed:
+                raise ValueError(
+                    f"axk1: {key}={get(key)!r} is not implemented (only "
+                    f"{allowed[0]!r}: docs/MLA.md)")
+        scaling = get("rope_scaling")
+        yarn = None
+        if scaling:
+            kind = scaling.get("type", scaling.get("rope_type"))
+            if kind != "yarn":
+                raise ValueError(f"axk1: rope_scaling type {kind!r} is not "
+                                 "implemented (only 'yarn')")
+            yarn = (float(scaling["factor"]),
+                    int(scaling["original_max_position_embeddings"]),
+                    float(scaling.get("beta_fast", 32)),
+                    float(scaling.get("beta_slow", 1)),
+                    float(scaling.get("mscale", 1)),
+                    float(scaling.get("mscale_all_dim", 0)))
+        scoring = str(get("scoring_func", "softmax"))
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"axk1: scoring_func={scoring!r}")
+        E = int(get("n_routed_experts"))
+        held, offset = (int(get("n_routed_experts_held") or 0),
+                        int(get("n_routed_experts_offset") or 0))
+        if held and not 0 <= offset <= E - held:
+            raise ValueError(f"axk1: experts [{offset}, {offset + held}) "
+                             f"are not among the router's {E}")
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=get("num_hidden_layers"),
+            num_attention_heads=get("num_attention_heads"),
+            num_key_value_heads=get("num_key_value_heads"),
+            rope_theta=get("rope_theta", 10_000.0),
+            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 131072),
+            attention_bias=False,
+            model_type="axk1",
+            num_experts=E,
+            num_experts_per_tok=get("num_experts_per_tok"),
+            norm_topk_prob=bool(get("norm_topk_prob", False)),
+            q_lora_rank=get("q_lora_rank"),
+            kv_lora_rank=get("kv_lora_rank"),
+            qk_nope_head_dim=get("qk_nope_head_dim"),
+            qk_rope_head_dim=get("qk_rope_head_dim"),
+            v_head_dim=get("v_head_dim"),
+            yarn=yarn,
+            first_k_dense_replace=get("first_k_dense_replace", 0),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            n_shared_experts=get("n_shared_experts") or 0,
+            scoring_func=scoring,
+            routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+            experts_held=held,
+            experts_offset=offset,
         )

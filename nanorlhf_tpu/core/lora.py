@@ -20,6 +20,12 @@ expert would be 64 x the parameters for an eighth of the tokens each), and
 the model has no `gate_proj` / `up_proj` / `down_proj` leaf to adapt. The
 model decides, not a user option; `merge_lora`, `trainable_mask` and the
 export therefore need no expert case.
+
+On a latent-attention model (A.X-K1, core/mla.py) the adapter sits on MLA's
+five projections (`MLA_TARGETS`) in both of the model's stacks
+(`lora["dense_layers"]`, `lora["layers"]`); the leading dense MLP, the
+router, the routed and the shared experts are frozen. Again the model
+decides.
 """
 
 from __future__ import annotations
@@ -33,6 +39,7 @@ from nanorlhf_tpu.core.config import ModelConfig
 
 ATTENTION_TARGETS = ("q_proj", "k_proj", "v_proj", "o_proj")
 ALL_TARGETS = ATTENTION_TARGETS + ("gate_proj", "up_proj", "down_proj")
+MLA_TARGETS = ("q_a_proj", "q_b_proj", "kv_a_proj", "kv_b_proj", "o_proj")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,9 +58,20 @@ class LoraConfig:
 
 
 def _proj_dims(config: ModelConfig, name: str) -> tuple[int, int]:
-    hd = config.actual_head_dim
     D, F = config.hidden_size, config.intermediate_size
     H, KV = config.num_attention_heads, config.num_key_value_heads
+    if config.kv_lora_rank:
+        dq, r = config.q_lora_rank, config.kv_lora_rank
+        dn, dr, dv = (config.qk_nope_head_dim, config.qk_rope_head_dim,
+                      config.v_head_dim)
+        return {
+            "q_a_proj": (D, dq),
+            "q_b_proj": (dq, H * (dn + dr)),
+            "kv_a_proj": (D, r + dr),
+            "kv_b_proj": (r, H * (dn + dv)),
+            "o_proj": (H * dv, D),
+        }[name]
+    hd = config.actual_head_dim
     return {
         "q_proj": (D, H * hd),
         "k_proj": (D, KV * hd),
@@ -67,7 +85,10 @@ def _proj_dims(config: ModelConfig, name: str) -> tuple[int, int]:
 
 def lora_targets(config: ModelConfig, lora: LoraConfig) -> tuple[str, ...]:
     """The projections of `lora.targets` this model adapts: all of them on a
-    dense model, the attention ones on an expert model."""
+    dense model, the attention ones on an expert model, MLA's five on a
+    latent-attention model (which has none of the dense model's names)."""
+    if config.kv_lora_rank:
+        return MLA_TARGETS
     if config.num_experts:
         return tuple(t for t in lora.targets if t in ATTENTION_TARGETS)
     return tuple(lora.targets)
@@ -77,6 +98,23 @@ def init_lora_params(
     config: ModelConfig, lora: LoraConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> dict:
     """A ~ N(0, 1/r) (kaiming-ish), B = 0 → adapter starts as identity."""
+    if config.kv_lora_rank:
+        Ld = config.num_dense_layers
+        stacks = {"layers": config.num_hidden_layers - Ld}
+        if Ld:
+            stacks["dense_layers"] = Ld
+        out = {}
+        for i, (stack, L) in enumerate(sorted(stacks.items())):
+            keys = jax.random.split(jax.random.fold_in(key, i), len(MLA_TARGETS))
+            out[stack] = {}
+            for k, name in zip(keys, MLA_TARGETS):
+                d_in, d_out = _proj_dims(config, name)
+                out[stack][name] = {
+                    "a": (jax.random.normal(k, (L, d_in, lora.r), jnp.float32)
+                          / jnp.sqrt(lora.r)).astype(dtype),
+                    "b": jnp.zeros((L, lora.r, d_out), dtype),
+                }
+        return out
     L = config.num_hidden_layers
     keys = jax.random.split(key, len(lora.targets))
     targets = lora_targets(config, lora)
@@ -98,16 +136,16 @@ def merge_lora(params: dict, lora_scale: float) -> dict:
     if "lora" not in params:
         return params
     merged = dict(params)
-    lora_layers = params["lora"]["layers"]
-    new_layers = dict(params["layers"])
-    for name, ab in lora_layers.items():
-        delta = jnp.einsum("lir,lro->lio", ab["a"].astype(jnp.float32), ab["b"].astype(jnp.float32))
-        entry = dict(new_layers[name])
-        entry["kernel"] = (
-            entry["kernel"].astype(jnp.float32) + lora_scale * delta
-        ).astype(entry["kernel"].dtype)
-        new_layers[name] = entry
-    merged["layers"] = new_layers
+    for stack, lora_layers in params["lora"].items():   # "layers" (+ A.X-K1's)
+        new_layers = dict(params[stack])
+        for name, ab in lora_layers.items():
+            delta = jnp.einsum("lir,lro->lio", ab["a"].astype(jnp.float32), ab["b"].astype(jnp.float32))
+            entry = dict(new_layers[name])
+            entry["kernel"] = (
+                entry["kernel"].astype(jnp.float32) + lora_scale * delta
+            ).astype(entry["kernel"].dtype)
+            new_layers[name] = entry
+        merged[stack] = new_layers
     del merged["lora"]
     return merged
 

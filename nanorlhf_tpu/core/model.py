@@ -1,7 +1,9 @@
-"""One decoder, two layer kinds, as pure functions over a stacked-layer
-pytree: the Qwen2/Llama block (GQA + SwiGLU) and OLMoE's (QK-norm + a
-sparse-expert MLP, ops/moe.py), chosen at trace time from the `ModelConfig`
-(`_mlp`); every layer of one model is the same kind.
+"""One decoder as pure functions over stacked-layer pytrees: the Qwen2/Llama
+block (GQA + SwiGLU), OLMoE's (QK-norm + a sparse-expert MLP, ops/moe.py) and
+A.X-K1's (latent attention, core/mla.py; a leading dense stack, then shared
+plus routed experts), chosen at trace time from the `ModelConfig` and the
+tree (`_layer_body`, `_mlp`, `_layer_stacks`). Every layer of a stack is the
+same kind; only A.X-K1 has two stacks.
 
 TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
 `/root/reference/GRPO/grpo.py:218-224`):
@@ -51,6 +53,10 @@ NEG_INF = -2.0**30  # large-but-finite mask value; -inf breaks softmax rows that
 
 def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random-init a full parameter tree (tests / from-scratch training)."""
+    if config.kv_lora_rank:
+        from nanorlhf_tpu.core import mla
+
+        return mla.init_params(config, key, dtype)
     hd = config.actual_head_dim
     D, F, V = config.hidden_size, config.intermediate_size, config.vocab_size
     H, KV, L = config.num_attention_heads, config.num_key_value_heads, config.num_hidden_layers
@@ -121,6 +127,16 @@ def rope_tables(positions: jnp.ndarray, head_dim: int, theta: float):
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, hd/2]
     angles = jnp.concatenate([angles, angles], axis=-1)  # HF rotate_half layout
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def _rope(config: ModelConfig, positions: jnp.ndarray):
+    """The model's cos/sin tables: plain RoPE over the head, or MLA's YaRN
+    tables over the rotated part of it (core/mla.py)."""
+    if config.kv_lora_rank:
+        from nanorlhf_tpu.core import mla
+
+        return mla.rope_tables(config, positions)
+    return rope_tables(positions, config.actual_head_dim, config.rope_theta)
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -196,7 +212,14 @@ def use_paged_decode_kernel(config: ModelConfig) -> bool:
     live (36 % of the serving cell's device time, PERF.md PR 28), so there
     is no width at which it wins. `"xla"`, off the TPU and under a mesh the
     gathered view stays: the plain form, and the kernel's oracle. The int8
-    cache has its own kernel (`use_q8_decode_kernel`)."""
+    cache has its own kernel (`use_q8_decode_kernel`). An MLA model reads
+    its latent pools through XLA on the gathered view: the kernel is a GQA
+    read of equal-width K and V pages and does not compute the absorbed
+    form (handed the one-array pool as K and as V it refused the 576-wide
+    page: "Slice shape along dimension 4 must be aligned to tiling (128)",
+    compiled for a v5e, PR 31)."""
+    if config.kv_lora_rank:
+        return False
     return config.kv_cache_quant != "int8" and use_expert_kernel(config)
 
 
@@ -459,40 +482,97 @@ def _paged_scale_view(pool, layer, table, width):
     return g.transpose(0, 2, 3, 1, 4).reshape(B, KV, e, nb * P)[..., :width]
 
 
-def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
-         expert_stack=None, layer=None):
-    """The layer's MLP on normed hidden states, chosen at trace time from
-    the config: the dense SwiGLU, or the sparse-expert MLP of ops/moe.py
-    (`config.num_experts > 0`; its router and experts carry no adapter,
-    core/lora.py). Per token, so every caller (uncached, contiguous, paged,
-    int8-KV, verify, ring attention, shared prefill) goes through it
-    untouched. `expert_stack` is the experts' subtree of EVERY layer,
-    addressed in place at `layer` instead of sliced (`_expert_xs`; ops/moe.py
-    says why). Returns `(out, aux)`; `aux` is the router's per-token record
-    (`moe_mlp`), None for the dense layer."""
-    if config.num_experts:
-        from nanorlhf_tpu.ops.moe import moe_mlp
+# A chip's share dispatches N x k assignment rows of which it computes its
+# experts' part (held / E of them): past this many tokens the layer goes in
+# blocks, so a 4k-token scoring row at A.X-K1's widths holds 0.12 GB of
+# dispatched rows at a time instead of 0.5 GB in, 0.5 GB out and a float32
+# [N, k, D] beside a served model's weights and pool
+_SHARE_TOKEN_BLOCK = 1024
 
-        experts = expert_stack or layer_params["experts"]
-        return moe_mlp(
-            h, layer_params["router"]["kernel"],
-            experts["gate_proj"]["kernel"], experts["up_proj"]["kernel"],
-            experts["down_proj"]["kernel"], config.num_experts_per_tok,
-            config.norm_topk_prob,
-            layer=layer if expert_stack is not None else None,
-            kernel=use_expert_kernel(config))
+
+def _in_token_blocks(fn, h, block: int):
+    """`fn(x [n, D]) -> (y [n, D], moe aux)` over `h`'s tokens in equal
+    blocks of at most `block` (the layer is per token, so this is the same
+    layer); zero rows pad the last block and are cut off again."""
+    lead, D = h.shape[:-1], h.shape[-1]
+    x = h.reshape(-1, D)
+    N = x.shape[0]
+    n = -(-N // block)
+    size = -(-N // n)
+    x = jnp.pad(x, ((0, n * size - N), (0, 0))).reshape(n, size, D)
+    out, aux = jax.lax.map(fn, x)
+    cut = lambda a: a.reshape((n * size,) + a.shape[2:])[:N]  # noqa: E731
+    experts = cut(aux["experts"])
+    return cut(out).reshape(h.shape), {
+        "experts": experts.reshape(lead + experts.shape[1:]),
+        "entropy": cut(aux["entropy"]).reshape(lead),
+        "dropped": jnp.sum(aux["dropped"]),
+        # of the real tokens: the padding rows' assignments do not count
+        **({"absent": jnp.sum(cut(aux["absent_by_token"]))}
+           if "absent" in aux else {}),
+    }
+
+
+def _swiglu(h, layer_params, lora_layer, lora_scale):
     gate = _proj(h, layer_params, lora_layer, "gate_proj", lora_scale)
     up = _proj(h, layer_params, lora_layer, "up_proj", lora_scale)
     return _proj(
         jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up,
         layer_params, lora_layer, "down_proj", lora_scale,
-    ), None
+    )
+
+
+def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
+         expert_stack=None, layer=None, live=None):
+    """The layer's MLP on normed hidden states, chosen at trace time from
+    the layer's own tree: the sparse-expert MLP of ops/moe.py where the layer
+    has a router (its router and experts carry no adapter, core/lora.py),
+    else the dense SwiGLU (every layer of a dense model; A.X-K1's leading
+    stack). Per token, so every caller (uncached, contiguous, paged,
+    int8-KV, verify, ring attention, shared prefill) goes through it
+    untouched. `expert_stack` is the experts' subtree of EVERY layer of the
+    stack, addressed in place at `layer` instead of sliced (`_expert_xs`;
+    ops/moe.py says why). `live` [B] marks the decode step's rows someone
+    listens to (a chip's share dispatches those only: `moe_mlp`). Returns
+    `(out, aux)`; `aux` is the router's per-token record (`moe_mlp`), None
+    for the dense layer."""
+    if "router" in layer_params:
+        from nanorlhf_tpu.ops.moe import moe_mlp
+
+        experts = expert_stack or layer_params["experts"]
+        routing = {}    # OLMoE passes none of these: its program as it was
+        if config.experts_held:     # the chip's share of the routed experts
+            routing["held"] = (config.experts_held, config.experts_offset)
+            if live is not None and h.shape[1] == 1:
+                routing["live"] = live[:, None]
+        if config.scoring_func != "softmax" or config.routed_scaling_factor != 1.0:
+            routing.update(scoring=config.scoring_func,
+                           routed_scale=config.routed_scaling_factor)
+        def routed(x):
+            return moe_mlp(
+                x, layer_params["router"]["kernel"],
+                experts["gate_proj"]["kernel"], experts["up_proj"]["kernel"],
+                experts["down_proj"]["kernel"], config.num_experts_per_tok,
+                config.norm_topk_prob,
+                layer=layer if expert_stack is not None else None,
+                kernel=use_expert_kernel(config), **routing)
+
+        if config.experts_held and h.size // h.shape[-1] > _SHARE_TOKEN_BLOCK:
+            out, aux = _in_token_blocks(routed, h, _SHARE_TOKEN_BLOCK)
+        else:
+            out, aux = routed(h)
+        if "shared_expert" in layer_params:
+            with jax.named_scope("moe.shared"):
+                out = out + _swiglu(h, layer_params["shared_expert"], None,
+                                    lora_scale)
+        return out, aux
+    return _swiglu(h, layer_params, lora_layer, lora_scale), None
 
 
 def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
                 decode_bounds=None, verify_bounds=None, paged=None, layer=0,
-                expert_stack=None):
+                expert_stack=None, stack_start=0, live=None):
     """One decoder layer. If kv_cache is not None, operate incrementally.
 
     Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
@@ -525,7 +605,31 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     The int8 and verify paged kernels take the layer's slab and skip the
     shard_map wrap (`_spmd_call` shards arg dim 0, which for pools is pages,
     not batch); GSPMD partitions them instead.
+    `layer` indexes the cache, over every layer of the model; the layer's
+    place in its own stack (`_layer_stacks`), which is where its experts
+    lie in `expert_stack`, is `layer - stack_start`.
+    An MLA model (`config.kv_lora_rank`) takes core/mla.py's attention: the
+    cache is one latent array and the forms are its own.
     """
+    # (a stack that starts the model keeps `layer` as it is: the same program)
+    expert_layer = layer - stack_start if stack_start else layer
+    if config.kv_lora_rank:
+        if attn_fn is not None:
+            raise NotImplementedError(
+                "latent attention has no sequence-parallel form: ring "
+                "attention exchanges per-head K and V (docs/MLA.md)")
+        from nanorlhf_tpu.core.mla import mla_attention
+
+        h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
+        out, new_cache = mla_attention(
+            config, h, layer_params, lora_layer, lora_scale, cos, sin, mask,
+            kv_cache, cache_index, decode_bounds, verify_bounds, paged, layer)
+        x = x + out
+        h = rms_norm(x, layer_params["post_attention_layernorm"],
+                     config.rms_norm_eps)
+        ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
+                       expert_stack, expert_layer, live)
+        return x + ff, new_cache, aux
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
@@ -703,7 +807,7 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
 
     h = rms_norm(x, layer_params["post_attention_layernorm"], config.rms_norm_eps)
     ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
-                   expert_stack, layer)
+                   expert_stack, expert_layer)
     x = x + ff
     return x, new_cache, aux
 
@@ -720,10 +824,28 @@ def _expert_xs(layers: dict, in_place: bool):
     return layer_xs, layer_xs.pop("experts")
 
 
+def _layer_stacks(params: dict) -> list:
+    """`[(stacked layer tree, its adapter tree | None, index of its first
+    layer, layers)]` in model order: `layers` alone for every model of one
+    layer kind; `dense_layers` before it for A.X-K1, whose leading layers
+    have another MLP than the rest, so another tree to scan. The least of a
+    per-layer pattern this model needs, not a general one."""
+    lora = params.get("lora", {})
+    stacks, start = [], 0
+    for name in ("dense_layers", "layers"):
+        if name in params:
+            n = params[name]["input_layernorm"].shape[0]
+            stacks.append((params[name], lora.get(name), start, n))
+            start += n
+    return stacks
+
+
 def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0,
                 lora_scale=1.0, remat=False, attn_fn=None, layer_transform=None,
-                decode_bounds=None, verify_bounds=None, paged=None):
-    """Scan the stacked layer params over the layer body.
+                decode_bounds=None, verify_bounds=None, paged=None, live=None,
+                cached_aux=False):
+    """Scan each stack of stacked layer params over the layer body
+    (`_layer_stacks`: one scan for every model but A.X-K1, which has two).
 
     `remat=True` wraps the body in jax.checkpoint — the training path's
     activation rematerialization (capability parity with the reference's
@@ -733,72 +855,87 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
     `layer_transform(layer_params, lora_layer) -> (layer_params, lora_layer)`
     runs inside the scan body before the layer math — the FSDP hook: scanned
     param slices enter as shards and are all-gathered one layer at a time.
+
+    The third result is the expert stack's stacked `aux` (`_mlp`), from the
+    uncached forward always and from a cached one where `cached_aux` asks
+    for it (else None: the cached programs as they were).
     """
-    lora_layers = params.get("lora", {}).get("layers") if isinstance(params, dict) else None
-
     if kv_caches is None:
-        # (with `ragged_dot` the differentiated forward scans the experts
-        # like every other weight: `_expert_xs`)
-        layer_xs, expert_stack = _expert_xs(
-            params["layers"], in_place=use_expert_kernel(config))
+        def uncached_body(expert_stack):
+            def body(carry, inp):
+                layer_params, lora_layer, *layer = inp
+                if layer_transform is not None:
+                    layer_params, lora_layer = layer_transform(layer_params, lora_layer)
+                y, _, aux = _layer_body(config, carry, layer_params, cos, sin, mask,
+                                        None, 0, lora_layer, lora_scale,
+                                        attn_fn=attn_fn, layer=layer[0] if layer else 0,
+                                        expert_stack=expert_stack)
+                return y, aux
 
-        def body(carry, inp):
-            layer_params, lora_layer, *layer = inp
-            if layer_transform is not None:
-                layer_params, lora_layer = layer_transform(layer_params, lora_layer)
-            y, _, aux = _layer_body(config, carry, layer_params, cos, sin, mask,
-                                    None, 0, lora_layer, lora_scale,
-                                    attn_fn=attn_fn, layer=layer[0] if layer else 0,
-                                    expert_stack=expert_stack)
-            return y, aux
-
-        if remat:
+            if not remat:
+                return body
             if config.remat_policy == "dots":
                 # keep MXU matmul outputs (no batch dims = the weight
                 # projections, not attention scores) for the backward
-                body = jax.checkpoint(
+                return jax.checkpoint(
                     body,
                     policy=jax.checkpoint_policies
                     .dots_with_no_batch_dims_saveable,
                 )
-            elif config.remat_policy == "full":
-                body = jax.checkpoint(body)
-            else:
-                raise ValueError(
-                    f"remat_policy={config.remat_policy!r}: must be "
-                    "'full' or 'dots'"
-                )
-        xs = (layer_xs, lora_layers)
-        if expert_stack is not None:    # the layer's index into the stack
-            xs += (jnp.arange(config.num_hidden_layers, dtype=jnp.int32),)
-        x, aux = jax.lax.scan(body, x, xs)
+            if config.remat_policy == "full":
+                return jax.checkpoint(body)
+            raise ValueError(
+                f"remat_policy={config.remat_policy!r}: must be "
+                "'full' or 'dots'"
+            )
+
+        aux = None
+        for tree, lora_layers, _, n in _layer_stacks(params):
+            # (with `ragged_dot` the differentiated forward scans the experts
+            # like every other weight: `_expert_xs`)
+            layer_xs, expert_stack = _expert_xs(
+                tree, in_place=use_expert_kernel(config))
+            xs = (layer_xs, lora_layers)
+            if expert_stack is not None:    # the layer's index into the stack
+                xs += (jnp.arange(n, dtype=jnp.int32),)
+            x, stack_aux = jax.lax.scan(uncached_body(expert_stack), x, xs)
+            aux = aux if stack_aux is None else stack_aux   # the expert stack's
         return x, None, aux
     else:
-        # cache is a tuple of stacked arrays: (k, v) exact, or
-        # (k_q, k_s, v_q, v_s) int8. It is the scan's CARRY, not its xs/ys:
+        # cache is a tuple of stacked arrays: (k, v) exact,
+        # (k_q, k_s, v_q, v_s) int8, or MLA's one latent array. It is the
+        # scans' CARRY, not their xs/ys:
         # every layer writes its new tokens into the one stacked buffer at
         # its own index and reads its slab from it, so the cache that leaves
         # the scan is the buffer that entered it and a decode loop that
         # carries the cache needs no copy of it. `paged` (block table + page
         # size) is closure-captured: one table serves every layer
-        def body(carry, inp):
-            y, caches = carry
-            layer_params, lora_layer, layer = inp
-            y, caches, _ = _layer_body(
-                config, y, layer_params, cos, sin, mask, caches,
-                cache_index, lora_layer, lora_scale,
-                decode_bounds=decode_bounds, verify_bounds=verify_bounds,
-                paged=paged, layer=layer, expert_stack=expert_stack,
-            )
-            return (y, caches), None
+        def cached_body(expert_stack, start):
+            def body(carry, inp):
+                y, caches = carry
+                layer_params, lora_layer, layer = inp
+                y, caches, aux = _layer_body(
+                    config, y, layer_params, cos, sin, mask, caches,
+                    cache_index, lora_layer, lora_scale,
+                    decode_bounds=decode_bounds, verify_bounds=verify_bounds,
+                    paged=paged, layer=layer, expert_stack=expert_stack,
+                    stack_start=start, live=live,
+                )
+                return (y, caches), aux if cached_aux else None
+            return body
 
-        layer_xs, expert_stack = _expert_xs(params["layers"], in_place=True)
-        layers = jnp.arange(kv_caches[0].shape[0], dtype=jnp.int32)
-        (x, new_caches), _ = jax.lax.scan(
-            body, (x, tuple(kv_caches)),
-            (layer_xs, lora_layers, layers),
-        )
-        return x, new_caches, None
+        new_caches, aux = tuple(kv_caches), None
+        for tree, lora_layers, start, n in _layer_stacks(params):
+            layer_xs, expert_stack = _expert_xs(tree, in_place=True)
+            layers = jnp.arange(n, dtype=jnp.int32)
+            if start:
+                layers = layers + start
+            (x, new_caches), stack_aux = jax.lax.scan(
+                cached_body(expert_stack, start), (x, new_caches),
+                (layer_xs, lora_layers, layers),
+            )
+            aux = aux if stack_aux is None else stack_aux
+        return x, new_caches, aux
 
 
 def unembedding(config: ModelConfig, params: dict):
@@ -870,7 +1007,7 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     attention_mask = attention_mask.astype(bool)
     x = params["embed_tokens"][input_ids].astype(params["embed_tokens"].dtype)
     T = input_ids.shape[1]
-    cos, sin = rope_tables(position_ids, config.actual_head_dim, config.rope_theta)
+    cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
     x, _, aux = _run_layers(config, params, x, cos, sin, mask,
@@ -1012,12 +1149,29 @@ def score_forward(
     return (x.astype(jnp.float32) @ params["score"].astype(jnp.float32))
 
 
+def _latent_cache_shape(config: ModelConfig, rows: int, slots: int) -> tuple:
+    """An MLA model's contiguous cache (core/mla.py): ONE array, `[c_kv |
+    k_rope]` a token a layer under a single "head", `[L, B, 1, T_max, W]`,
+    addressed by layer, row and slot like the K/V arrays (the paged cache is
+    two lane-aligned leaves of the same bytes: `mla.paged_cache_shapes`).
+    It has no int8 form: the latent is already 1/36 of A.X-K1's per-head K
+    and V, and absmax-per-token scales over a 576-wide mix of a normed
+    latent and a rotary key are not the per-head scheme's numerics."""
+    if config.kv_cache_quant == "int8":
+        raise ValueError(
+            "kv_cache_quant='int8' on a latent (MLA) cache is not "
+            "implemented: the cache is one latent a token, not per-head K "
+            "and V (docs/MLA.md)")
+    return (config.num_hidden_layers, rows, 1, slots, config.latent_width)
+
+
 def init_kv_cache(
     config: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 ) -> tuple[jnp.ndarray, ...]:
     """Stacked KV cache.
 
-    Exact: (k, v), each [L, B, KV, max_len, hd].
+    Exact: (k, v), each [L, B, KV, max_len, hd]; an MLA model: (latent,)
+    (`_latent_cache_shape`).
     kv_cache_quant="int8": (k_q, k_s, v_q, v_s) — int8 values plus bf16
     per-token-per-head scales carried SUBLANE-EXPANDED as [L, B, KV, 8,
     max_len]: the decode kernel's (1, 1, 8, block_k) scale blocks are
@@ -1027,6 +1181,8 @@ def init_kv_cache(
     the lane axis — same recipe as the flash kernel's mask
     (ops/attention.py).
     """
+    if config.kv_lora_rank:
+        return (jnp.zeros(_latent_cache_shape(config, batch, max_len), dtype),)
     shape = (
         config.num_hidden_layers,
         batch,
@@ -1049,7 +1205,8 @@ def init_paged_kv_cache(
     """Paged KV cache: a global page pool shared by every row, addressed
     through a per-row block table (sampler/paged/pages.py).
 
-    Exact: (k, v), each [L, num_pages, KV, page_size, hd].
+    Exact: (k, v), each [L, num_pages, KV, page_size, hd]; an MLA model:
+    (c_kv, k_rope) pools (`mla.paged_cache_shapes`).
     kv_cache_quant="int8": (k_q, k_s, v_q, v_s) with scale pools
     [L, num_pages, KV, 8, page_size] — the sublane-expanded layout of
     `init_kv_cache`, per page instead of per row.
@@ -1061,6 +1218,12 @@ def init_paged_kv_cache(
     block table is NOT part of the cache tuple (it is shared across layers
     and rides as a separate argument).
     """
+    if config.kv_lora_rank:
+        from nanorlhf_tpu.core import mla
+
+        _latent_cache_shape(config, num_pages, page_size)    # what raises
+        return tuple(jnp.zeros(shape, dtype) for shape in
+                     mla.paged_cache_shapes(config, num_pages, page_size))
     shape = (
         config.num_hidden_layers,
         num_pages,
@@ -1136,7 +1299,7 @@ def prefill(
     x = params["embed_tokens"][jnp.where(attention_mask, input_ids, 0)].astype(
         params["embed_tokens"].dtype
     )
-    cos, sin = rope_tables(position_ids, config.actual_head_dim, config.rope_theta)
+    cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     # queries attend over cache positions [0, T); the rest of T_max is masked
     mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
@@ -1165,12 +1328,17 @@ def decode_step(
     live=None,                    # [B] bool: rows whose logits the caller
                                   # uses (None: all). The paged in-place read
                                   # skips the others; nothing else looks
+    count_experts: bool = False,  # a chip's share of an expert layer: also
+                                  # return the held experts its rows reach
+                                  # (the live ones), summed over the layers
 ):
-    """One autoregressive decode step. Returns (logits [B, V], new caches)."""
+    """One autoregressive decode step. Returns (logits [B, V], new caches),
+    and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
+    over the layers."""
     B = token.shape[0]
     paged = (page_table, page_size) if page_table is not None else None
     x = params["embed_tokens"][token][:, None, :].astype(params["embed_tokens"].dtype)
-    cos, sin = rope_tables(position[:, None], config.actual_head_dim, config.rope_theta)
+    cos, sin = _rope(config, position[:, None])
     mask = key_mask[:, None, None, :]  # [B, 1, 1, T_max]
     # valid cache slots form the contiguous range [start, cache_index+1):
     # left-pad offset up to the slot just written (sampler sets it True before
@@ -1179,6 +1347,11 @@ def decode_step(
     filled = jnp.broadcast_to(
         jnp.asarray(cache_index, jnp.int32) + 1, (B,))
     bounds = (start, filled)
+    if config.kv_lora_rank and live is not None:
+        # an MLA model's paged read walks the key blocks these bounds span
+        # (core/mla.py): a row nobody listens to asks for none
+        bounds = (jnp.where(live, start, key_mask.shape[1]),
+                  jnp.where(live, filled, 0))
     if paged is not None and use_paged_decode_kernel(config):
         # the step's work list for the in-place read, once for every layer
         from nanorlhf_tpu.ops.decode_attention import (
@@ -1189,11 +1362,17 @@ def decode_step(
             page_table, start, filled, page_size=page_size,
             num_pages=kv_caches[0].shape[1],
             pages_per_item=paged_pages_per_item(kv_caches[0]), live=live)
-    x, new_caches, _ = _run_layers(
+    x, new_caches, aux = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
         lora_scale=lora_scale, decode_bounds=bounds, paged=paged,
+        # (an MLA model's chip's share dispatches the live rows only, and
+        # counts the held experts they reach where the caller asks)
+        **({"live": live} if config.kv_lora_rank else {}),
+        **({"cached_aux": True} if count_experts else {}),
     )
     logits = _logits(config, params, x)[:, 0, :]
+    if count_experts:
+        return logits, new_caches, jnp.sum(aux["reached"])
     return logits, new_caches
 
 
@@ -1242,7 +1421,7 @@ def decode_verify(
     paged = (page_table, page_size) if page_table is not None else None
     key_mask = key_mask.astype(bool)
     x = params["embed_tokens"][tokens].astype(params["embed_tokens"].dtype)
-    cos, sin = rope_tables(positions, config.actual_head_dim, config.rope_theta)
+    cos, sin = _rope(config, positions)
     slot = jnp.arange(T_max)[None, None, :]                  # [1, 1, T_max]
     qi = jnp.arange(Tq)[None, :, None]                       # [1, Tq, 1]
     cand = (slot >= fill[:, None, None]) & (slot <= fill[:, None, None] + qi)

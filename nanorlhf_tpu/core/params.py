@@ -11,6 +11,16 @@ OLMoE adds (docs/MOE.md): `mlp.experts.{e}.{gate,up,down}_proj.weight` ↔
 `mlp.gate.weight` ↔ `layers.router.kernel [L, D, E]`, and
 `self_attn.{q,k}_norm.weight` ↔ `layers.{q,k}_norm`.
 
+A.X-K1 (`model_type: axk1`, DeepSeek-V3's names; docs/MLA.md): the leading
+`first_k_dense_replace` layers go to `dense_layers`, the rest to `layers`;
+`self_attn.{q_a_proj,q_b_proj,kv_a_proj_with_mqa,kv_b_proj,o_proj}` ↔
+`{q_a,q_b,kv_a,kv_b,o}_proj.kernel`, `self_attn.{q_a,kv_a}_layernorm`,
+`mlp.gate.weight` ↔ `router.kernel`, `mlp.experts.{offset + e}.*` ↔ the
+HELD experts' `experts.*.kernel [L, held, in, out]`, `mlp.shared_experts.*` ↔
+`shared_expert.*`. The checkpoint stores rotary pairs interleaved; this tree
+rotates halves, so the rotary columns of `q_b_proj` and `kv_a_proj` are
+de-interleaved on load and re-interleaved on export (`_ROPE` below).
+
 Weight fidelity (GQA head layout, tied embeddings, RoPE) is pinned by
 tests/test_model_parity.py against the torch Qwen2 AND Llama
 implementations.
@@ -51,6 +61,99 @@ def _layer_keys(config: ModelConfig):
     return linear, norm
 
 
+_MLA_LINEAR_KEYS = (
+    ("q_a_proj", "self_attn.q_a_proj"),
+    ("q_b_proj", "self_attn.q_b_proj"),
+    ("kv_a_proj", "self_attn.kv_a_proj_with_mqa"),
+    ("kv_b_proj", "self_attn.kv_b_proj"),
+    ("o_proj", "self_attn.o_proj"),
+)
+_MLA_NORM_KEYS = _NORM_KEYS + (
+    ("q_a_layernorm", "self_attn.q_a_layernorm"),
+    ("kv_a_layernorm", "self_attn.kv_a_layernorm"),
+)
+
+
+def _rope_columns(config: ModelConfig, name: str, kernel, to_ours: bool):
+    """`kernel` [in, out] of `q_b_proj` / `kv_a_proj` with its rotary output
+    columns moved between the checkpoint's interleaved pairs
+    (x0 y0 x1 y1 ..) and this tree's halves (x0 x1 .. y0 y1 ..); any other
+    kernel comes back as it is."""
+    dr = config.qk_rope_head_dim
+    perm = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+    if not to_ours:
+        perm = np.argsort(perm)
+    if name == "kv_a_proj":
+        r = config.kv_lora_rank
+        return np.concatenate([kernel[:, :r], kernel[:, r:][:, perm]], axis=1)
+    if name == "q_b_proj":
+        H, dn = config.num_attention_heads, config.qk_nope_head_dim
+        k = kernel.reshape(kernel.shape[0], H, dn + dr)
+        k = np.concatenate([k[..., :dn], k[..., dn:][..., perm]], axis=-1)
+        return k.reshape(kernel.shape)
+    return kernel
+
+
+def _mla_stacks(config: ModelConfig):
+    """(tree name, HF layer indices) of an A.X-K1 tree's two stacks."""
+    Ld = config.num_dense_layers
+    stacks = [("layers", range(Ld, config.num_hidden_layers))]
+    return ([("dense_layers", range(Ld))] if Ld else []) + stacks
+
+
+def _mla_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    held = range(config.experts_offset,
+                 config.experts_offset + config.num_held_experts)
+    params = {}
+    for stack, idx in _mla_stacks(config):
+        at = lambda i, name: sd[f"model.layers.{i}.{name}.weight"]  # noqa: E731
+        tree = {ours: cast(np.stack([at(i, theirs) for i in idx]))
+                for ours, theirs in _MLA_NORM_KEYS}
+        for ours, theirs in _MLA_LINEAR_KEYS:
+            tree[ours] = {"kernel": cast(np.stack([
+                _rope_columns(config, ours, at(i, theirs).T, True)
+                for i in idx]))}
+        mlp = lambda prefix: {name: {"kernel": cast(np.stack(  # noqa: E731
+            [at(i, f"{prefix}.{name}").T for i in idx]))} for name in _MLP_KEYS}
+        if stack == "dense_layers" or not config.num_experts:
+            tree.update(mlp("mlp"))
+        else:
+            tree["router"] = {"kernel": cast(np.stack(
+                [at(i, "mlp.gate").T for i in idx]))}
+            tree["experts"] = {name: {"kernel": cast(np.stack([
+                np.stack([at(i, f"mlp.experts.{e}.{name}").T for e in held])
+                for i in idx]))} for name in _MLP_KEYS}
+            if config.n_shared_experts:
+                tree["shared_expert"] = mlp("mlp.shared_experts")
+        params[stack] = tree
+    return params
+
+
+def _mla_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    for stack, idx in _mla_stacks(config):
+        tree = params[stack]
+        for j, i in enumerate(idx):
+            pre = f"model.layers.{i}."
+            for ours, theirs in _MLA_NORM_KEYS:
+                put(f"{pre}{theirs}.weight", tree[ours][j])
+            for ours, theirs in _MLA_LINEAR_KEYS:
+                put(f"{pre}{theirs}.weight", _rope_columns(
+                    config, ours, np.asarray(tree[ours]["kernel"][j]), False).T)
+            if "router" not in tree:
+                for name in _MLP_KEYS:
+                    put(f"{pre}mlp.{name}.weight", tree[name]["kernel"][j].T)
+                continue
+            put(f"{pre}mlp.gate.weight", tree["router"]["kernel"][j].T)
+            for name in _MLP_KEYS:
+                kernel = tree["experts"][name]["kernel"][j]
+                for e in range(kernel.shape[0]):
+                    put(f"{pre}mlp.experts.{config.experts_offset + e}."
+                        f"{name}.weight", kernel[e].T)
+                if "shared_expert" in tree:
+                    put(f"{pre}mlp.shared_experts.{name}.weight",
+                        tree["shared_expert"][name]["kernel"][j].T)
+
+
 def _to_np(t) -> np.ndarray:
     """torch tensor / np array → np array (bf16-safe via float32 round-trip)."""
     if hasattr(t, "detach"):
@@ -71,6 +174,13 @@ def params_from_hf_state_dict(
     def cast(x):
         return jnp.asarray(x, dtype)
 
+    if config.kv_lora_rank:
+        params = _mla_params_from_sd(config, sd, cast)
+        params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
+                      norm=cast(sd["model.norm.weight"]))
+        if not config.tie_word_embeddings:
+            params["lm_head"] = cast(sd["lm_head.weight"].T)
+        return params
     linear_keys, norm_keys = _layer_keys(config)
     layers: dict = {
         ours: cast(np.stack(
@@ -125,6 +235,9 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
 
     layers = params["layers"]
     linear_keys, norm_keys = _layer_keys(config)
+    if config.kv_lora_rank:
+        _mla_sd_from_params(config, params, put)
+        L = 0       # the two stacks are written; the rest is shared
     for i in range(L):
         for ours, theirs in norm_keys:
             put(f"model.layers.{i}.{theirs}.weight", layers[ours][i])
@@ -201,10 +314,10 @@ def export_hf_checkpoint(
     # (sliding_window, ...) to keys we never write. Anything else falls
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
-        "qwen2", "llama", "olmoe") else (
+        "qwen2", "llama", "olmoe", "axk1") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
-            "olmoe": "OlmoeForCausalLM"}[family]
+            "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -223,7 +336,29 @@ def export_hf_checkpoint(
         "hidden_act": "silu",
         "torch_dtype": dtype,
     }
-    if config.num_experts:
+    if family == "axk1":
+        del hf_config["head_dim"]
+        hf_config.update(
+            q_lora_rank=config.q_lora_rank, kv_lora_rank=config.kv_lora_rank,
+            qk_nope_head_dim=config.qk_nope_head_dim,
+            qk_rope_head_dim=config.qk_rope_head_dim,
+            v_head_dim=config.v_head_dim,
+            first_k_dense_replace=config.first_k_dense_replace,
+            moe_intermediate_size=config.moe_intermediate_size,
+            moe_layer_freq=1, n_routed_experts=config.num_experts,
+            n_shared_experts=config.n_shared_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            norm_topk_prob=config.norm_topk_prob,
+            scoring_func=config.scoring_func, topk_method="none",
+            routed_scaling_factor=config.routed_scaling_factor,
+            rope_scaling=None if config.yarn is None else dict(
+                zip(("factor", "original_max_position_embeddings",
+                     "beta_fast", "beta_slow", "mscale", "mscale_all_dim"),
+                    config.yarn), type="yarn"))
+        if config.experts_held:     # a chip's share is no whole checkpoint
+            hf_config.update(n_routed_experts_held=config.experts_held,
+                             n_routed_experts_offset=config.experts_offset)
+    elif config.num_experts:
         hf_config.update(num_experts=config.num_experts,
                          num_experts_per_tok=config.num_experts_per_tok,
                          norm_topk_prob=config.norm_topk_prob,

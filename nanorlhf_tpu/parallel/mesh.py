@@ -119,6 +119,18 @@ _RULES = {
     ("layers", "experts", "up_proj", "kernel"): P(None, "tensor", "fsdp", None),
     ("layers", "experts", "down_proj", "kernel"): P(None, "tensor", "fsdp", None),
     ("layers", "router", "kernel"): P(None, None, None),
+    # A.X-K1 (core/mla.py): the shared expert like a dense MLP; MLA's
+    # bottlenecks keep their small latent widths whole (q_lora_rank,
+    # kv_lora_rank + rope: every head reads all of them) and shard the
+    # per-head side by tensor, as q/k/v and o do. The latent CACHE is one
+    # head wide and stays unsharded on its head axis.
+    ("shared_expert", "gate_proj", "kernel"): P(None, "fsdp", "tensor"),
+    ("shared_expert", "up_proj", "kernel"): P(None, "fsdp", "tensor"),
+    ("shared_expert", "down_proj", "kernel"): P(None, "tensor", "fsdp"),
+    ("layers", "q_a_proj", "kernel"): P(None, "fsdp", None),
+    ("layers", "q_b_proj", "kernel"): P(None, None, "tensor"),
+    ("layers", "kv_a_proj", "kernel"): P(None, "fsdp", None),
+    ("layers", "kv_b_proj", "kernel"): P(None, None, "tensor"),
     ("layers", "input_layernorm"): P(None, None),
     ("layers", "post_attention_layernorm"): P(None, None),
     # LoRA: A shards like the input dim, B like the output dim
@@ -155,6 +167,9 @@ def param_sharding_rules(params) -> dict:
         # strip a leading "lora" namespace so LoRA trees reuse layer rules
         if keys and keys[0] == "lora":
             keys = keys[1:]
+        # A.X-K1's leading dense stack holds the same leaves as `layers`
+        if keys and keys[0] == "dense_layers":
+            keys = ("layers",) + keys[1:]
         return _spec_for_path(keys)
 
     return jax.tree_util.tree_map_with_path(spec, params)

@@ -144,14 +144,16 @@ def _first_token(logits, key, temperature, top_p, greedy, *, top_k,
 def _session_decode_body(params, config, s, table, row_params, *, Tp,
                          max_tokens, page_size, eos_token_id, pad_token_id,
                          temperature, top_p, greedy, lora_scale, top_k,
-                         capture_logprobs, approx_top_k):
+                         capture_logprobs, approx_top_k, count_experts=False):
     """One decode step over the session carry — `sampler._decode_body`
     generalized to PER-ROW generation counts (resident rows sit at
     different depths) and table-routed cache writes. `row_params` is None
     for the rollout mode (static sampling params, row budget =
     `max_tokens`) or the serving mode's traced `[R]`
     (temperature, top_p, greedy, budget) tuple — a trace-time branch, so
-    each mode compiles to exactly the program its pre-session driver ran."""
+    each mode compiles to exactly the program its pre-session driver ran.
+    With `count_experts` the result is `(carry, held experts the live rows
+    reached)` (`_chunk_loop`)."""
     (it, out, lp_out, caches, key_mask, done, cur_tok, n_gen, prompt_len,
      key) = s
     R = cur_tok.shape[0]
@@ -160,10 +162,10 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
     key_mask = key_mask.at[rows, slot].set(True)
     position = prompt_len + n_gen - 1
     live = ~done
-    logits, caches = decode_step(
+    logits, caches, *reached = decode_step(
         params, config, cur_tok, position, slot, key_mask, caches,
         lora_scale=lora_scale, page_table=table, page_size=page_size,
-        live=live,
+        live=live, **({"count_experts": True} if count_experts else {}),
     )
     if row_params is None:
         tok = _sample_token(jax.random.fold_in(key, it), logits, temperature,
@@ -184,16 +186,35 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
     cur_tok = jnp.where(live, tok, cur_tok)
     n_gen = n_gen + live.astype(jnp.int32)
     done = done | (tok == eos_token_id) | (n_gen >= limit)
-    return (it + 1, out, lp_out, caches, key_mask, done, cur_tok, n_gen,
-            prompt_len, key)
+    carry = (it + 1, out, lp_out, caches, key_mask, done, cur_tok, n_gen,
+             prompt_len, key)
+    return (carry, reached[0]) if count_experts else carry
 
 
 def _chunk_loop(params, config, state, table, row_params, statics):
     """Up to `sync_every` decode iterations; exits early once every
     resident row is done (the iteration counter then stops, so it counts
-    true decode dispatches)."""
+    true decode dispatches).
+
+    The program of one chip of an expert-parallel group
+    (`config.experts_held`) also returns, beside the carry, the held experts
+    its LIVE rows reached (a step dispatches no other row: `moe_mlp`), summed
+    over the chunk's steps and the layers (`DecodeSession.held_experts_hit`):
+    the routed kernels the steps had to read."""
     statics = dict(statics)
     sync_every = statics.pop("sync_every")
+    if config.experts_held:
+        def counted(cs):
+            c, s, hit = cs
+            s, reached = _session_decode_body(
+                params, config, s, table, row_params, count_experts=True,
+                **statics)
+            return c + 1, s, hit + reached
+
+        _, state, hit = jax.lax.while_loop(
+            lambda cs: (cs[0] < sync_every) & ~jnp.all(cs[1][5]), counted,
+            (jnp.int32(0), state, jnp.int32(0)))
+        return state, hit
 
     def cond(cs):
         c, s = cs
@@ -532,6 +553,18 @@ class DecodeSession:
         # 1 when the session's programs consume the pool they are given
         # (`serving/pool_donated`); one rule for all of them
         self.pool_donated = int(_decode_chunk.donates(caches0))
+        # what the pool holds a token slot, over every layer and array
+        # (`serving/kv_bytes_per_token`: read off the pool the model's cache
+        # spec gave), and whether it is MLA's latent pool
+        # (`serving/latent_cache`)
+        self.kv_bytes_per_token = sum(
+            c.nbytes for c in caches0) // (self.num_pages * self.page_size)
+        self.latent_cache = int(bool(config.kv_lora_rank))
+        # a chip's share of an expert layer: the held experts the live rows
+        # reached, summed over every decode step and layer so far
+        # (`serving/held_experts_hit`; `_chunk_loop`)
+        self.held_experts_hit = 0
+        self._hit_dev = None
         # the host's record of the carry, as of the last sync and the
         # admissions and cancels since: what other threads may read
         self._done_np = np.ones((R,), bool)
@@ -906,18 +939,21 @@ class DecodeSession:
                         self.params, self.config, self.state, table_dev,
                         self._prompt_rep, **self._statics)
             elif self.per_row:
-                self.state = _serving_chunk(
+                self.state = self._carry_of(_serving_chunk(
                     self.params, self.config, self.state, table_dev,
                     jnp.asarray(self._temp_np), jnp.asarray(self._topp_np),
                     jnp.asarray(self._greedy_np),
-                    jnp.asarray(self._budget_np), **self._statics)
+                    jnp.asarray(self._budget_np), **self._statics))
             else:
-                self.state = _decode_chunk(
+                self.state = self._carry_of(_decode_chunk(
                     self.params, self.config, self.state, table_dev,
-                    **self._statics)
+                    **self._statics))
         with phase("sync"):     # the host waits for the device here
             done_h = np.asarray(self.state[5])
             it_now = int(self.state[0]) - 1
+            if self._hit_dev is not None:
+                self.held_experts_hit += int(self._hit_dev)
+                self._hit_dev = None
         if self._hub is not None:
             # done_h forced the device sync, so the chunk's wall time is
             # fully realised here; one mean inter-token gap per sync
@@ -935,6 +971,14 @@ class DecodeSession:
         self._it_prev = it_now
         np.copyto(self._done_np, done_h)
         return done_h, installed
+
+    def _carry_of(self, result):
+        """A decode chunk's result, for the carry's place: a chip's share of
+        an expert layer hands its count back beside it (`_chunk_loop`), and
+        the count stays on the device until the beat's sync."""
+        if self.config.experts_held:
+            result, self._hit_dev = result
+        return result
 
     def _count_attention(self, its: int, done_h) -> None:
         """Never-reset `attn_live_pages` / `attn_table_pages`, summed over

@@ -435,6 +435,10 @@ class ServingEngine:
             "serving/attn_table_pages": self._sess.attn_table_pages,
             "serving/attn_in_place": self._sess.attn_in_place,
             "serving/pool_donated": self._sess.pool_donated,
+            "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
+            "serving/latent_cache": self._sess.latent_cache,
+            "serving/decode_steps": self._sess.iterations(),
+            "serving/held_experts_hit": self._sess.held_experts_hit,
             "pages/shared": snap["shared_pages"],
         }
         for reason, n in sorted(reasons.items()):

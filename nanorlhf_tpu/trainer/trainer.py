@@ -861,6 +861,11 @@ class RLTrainer:
         # paths keep the exact config (they never build a cache)
         if config.kv_cache_quant not in ("none", "int8"):
             raise ValueError(f"kv_cache_quant={config.kv_cache_quant!r}")
+        if config.kv_cache_quant == "int8" and self.mcfg.kv_lora_rank:
+            raise ValueError(
+                "kv_cache_quant='int8' with a latent-attention model: the "
+                "cache is one latent a token, which has no int8 form "
+                "(core/model._latent_cache_shape, docs/MLA.md)")
         import dataclasses as _dc
 
         self._rollout_mcfg = (
@@ -2680,7 +2685,10 @@ class RLTrainer:
         if up.router_l:
             from nanorlhf_tpu.ops.moe import moe_counters
 
-            metrics.update(moe_counters(up.router_l))
+            metrics.update(moe_counters(
+                up.router_l,
+                held=(self.mcfg.experts_held, self.mcfg.experts_offset)
+                if self.mcfg.experts_held else None))
         metrics.update(self._spec_decode_metrics(ro.get("spec_stats")))
         metrics.update(self._paged_metrics(ro.get("paged_stats")))
         if up.envp is not None:

@@ -405,6 +405,89 @@ def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(
     assert routed < flops < 2.5 * routed, (flops, routed)
 
 
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_chunk"])
+def test_axk1_session_programs_keep_the_latent_pool_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 31, asked of the chip's compiler at the `serve-axk1-docqa`
+    cell's shapes (A.X-K1's published widths as one chip of sixteen holds
+    them, the dense layer + two expert layers; 32 rows, 2,720 pages of 128):
+    the session's decode chunk and its 1,024-token KV-only prefill chunk
+    alias BOTH leaves of the latent pool (`c_kv` `bf16[3,2720,1,128,512]`,
+    `k_rope` `bf16[3,2720,1,64,128]`, two rotary keys a row) from their
+    parameters to their results, and no `copy` of a leaf is left in the
+    module. As ONE 576-wide array the pool was relaid whole on the way in
+    and on the way out of every program (core/mla.py). The expert matmuls
+    are the grouped-matmul kernel over the 12 held experts' stack."""
+    import dataclasses
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(ModelConfig.axk1(), num_hidden_layers=3,
+                              vocab_size=20480, experts_held=12)
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+    assert params["layers"]["experts"]["up_proj"]["kernel"].shape == (
+        2, 12, 7168, 2048)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, pages, chunk = 32, 8192, 512, 2720, 1024
+    nb = (Tp + new) // PAGE
+    cache = jax.eval_shape(
+        lambda: M.init_paged_kv_cache(cfg, pages, PAGE, jnp.bfloat16))
+    pools = hlo_stacks(cache)
+    assert set(pools) == {("bf16", (3, pages, 1, PAGE, 512)),
+                          ("bf16", (3, pages, 1, PAGE // 2, 128))}
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, spec((R, nb), jnp.int32),
+            spec((R,), jnp.float32), spec((R,), jnp.float32),
+            spec((R,), jnp.bool_), spec((R,), jnp.int32), Tp=Tp,
+            max_tokens=new, page_size=PAGE, sync_every=4, eos_token_id=1,
+            pad_token_id=0, temperature=1.0, top_p=1.0, greedy=False,
+            lora_scale=1.0, top_k=64, capture_logprobs=False,
+            approx_top_k=True)
+    else:
+        lowered = session._prefill_chunk_fwd.lower(
+            params, cfg, spec((1, chunk), jnp.int32), spec((1, chunk), jnp.int32),
+            spec((1,), jnp.int32), spec((1, Tp + new), jnp.bool_),
+            _shapes_on(cache, one_chip), spec((nb,), jnp.int32),
+            page_size=PAGE, lora_scale=1.0)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo, re.M).group(1)
+    leaves = {int(rest.split(")")[0]) for _, result, op, rest in comps[entry]
+              if op == "parameter" and _shapes(result)[:1]
+              and _shapes(result)[0] in pools}
+    assert len(leaves) == 2 and leaves <= aliased, (leaves, aliased)
+    pool_bytes = 2 * sum(int(np.prod(shape)) for _, shape in pools)
+    assert pool_bytes == 3 * pages * PAGE * 576 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(pools)]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_chip_smoke_refuses_a_cpu_backend():
     """No accelerator → non-zero exit before any phase, and no result line."""
     out = subprocess.run(
