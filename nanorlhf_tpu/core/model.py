@@ -157,10 +157,24 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
 # in no ledger line and no PERF.md entry (ROADMAP S5). What the records do
 # back (PERF.md sections 5 and 6): under 2,048 slots XLA's decode attention
 # fuses both cache reads into the QK and PV matmuls and streams the slab at
-# ~725 GB/s (PR 26, T_max 768), over all T_max slots whatever is filled.
-# The PAGED single-token read has no threshold (`use_paged_decode_kernel`).
+# ~725 GB/s (PR 26, T_max 768). It masks, it does not bound: it reads every
+# slot of the extent it is handed, filled or not. `decode_step` hands it the
+# whole cache unless its caller names a static `extent`; the one-jit rollout
+# does (`decode_read_extents`, PR 33: a multiple of 128 slots that no row's
+# write has passed yet), so the crossover S5 is to measure is against a read
+# of the filled slots rounded up to a block plus the rows' left pads, not of
+# all T_max slots. The PAGED single-token read has no threshold
+# (`use_paged_decode_kernel`).
 _FLASH_AUTO_MIN_T = 512
 _DECODE_AUTO_MIN_T = 2048
+# The XLA decode read's extents: whole blocks of this many slots, and at most
+# this many extents a loop: each is one more traced and compiled decode step
+# (~0.5-1 s of a warm set-up each at Qwen2.5-1.5B and OLMoE widths), and a
+# fourth bought nothing on the v5e (PERF.md section 6, PR 33: at 256 + 512
+# slots, 384/512/640/768 against 512/640/768 moved a rollout by 0.1-0.3 %,
+# because the softmax over 384 slots takes as long as over 768)
+_DECODE_READ_BLOCK = 128
+_DECODE_READ_EXTENTS = 3
 
 
 def use_flash(impl: str, seq_len: int) -> bool:
@@ -177,6 +191,28 @@ def use_decode_kernel(impl: str, cache_len: int) -> bool:
         return True
     return (impl == "auto" and cache_len >= _DECODE_AUTO_MIN_T
             and jax.default_backend() == "tpu")
+
+
+def decode_read_extents(config: ModelConfig, first_slot: int, last_slot: int,
+                        cache_len: int) -> tuple:
+    """Static extents for `decode_step` over a decode loop whose write slot
+    runs from `first_slot` up to `last_slot`, every row's at once, in a
+    contiguous cache of `cache_len` slots: ascending, the last one
+    `cache_len`, the others multiples of `_DECODE_READ_BLOCK` that the write
+    slot reaches, thinned evenly to `_DECODE_READ_EXTENTS` in all. A step
+    may read the first extent that lies above its write slot: no row holds
+    a key at or beyond it. `(cache_len,)`, today's one program, wherever
+    the read does not go by the mask's width: the Pallas read bounds itself
+    by `[start, filled)`, the int8 cache and the latent cache have reads of
+    their own."""
+    if (config.kv_lora_rank or config.kv_cache_quant == "int8"
+            or use_decode_kernel(config.attention_impl, cache_len)):
+        return (cache_len,)
+    block, most = _DECODE_READ_BLOCK, _DECODE_READ_EXTENTS
+    cuts = [*range((first_slot // block + 1) * block, last_slot + 1, block),
+            cache_len]
+    return tuple(sorted({cuts[-(-len(cuts) * i // most) - 1]
+                         for i in range(1, most + 1)}))
 
 
 def use_q8_decode_kernel(impl: str) -> bool:
@@ -450,10 +486,17 @@ def _cache_write(stacks, news, layer, cache_index, paged):
     return tuple(out)
 
 
-def _layer_slab(stack, layer):
+def _layer_slab(stack, layer, width=None):
     """Layer `layer`'s slab of a stacked cache array: the one read a layer
-    makes of the stack, after its write."""
-    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    makes of the stack, after its write. `width`: its first `width` slots
+    only, as ONE dynamic slice of the stack (a static slice of the whole
+    slab is not folded into it: the v5e compiler then sets the slab down
+    in memory first, compiled for a described v5e, PR 33)."""
+    if width is None:
+        return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    _, B, KV, _, hd = stack.shape
+    return jax.lax.dynamic_slice(
+        stack, (layer, 0, 0, 0, 0), (1, B, KV, width, hd))[0]
 
 
 def _paged_view(pool, layer, table, width):
@@ -734,6 +777,8 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             if paged is not None:
                 return (_paged_view(new_cache[0], layer, paged[0], width),
                         _paged_view(new_cache[1], layer, paged[0], width))
+            if width < cache_len:     # `decode_step(extent=)`
+                return tuple(_layer_slab(c, layer, width) for c in new_cache)
             return k_cache, v_cache
 
         if verify_bounds is not None:
@@ -1331,12 +1376,20 @@ def decode_step(
     count_experts: bool = False,  # a chip's share of an expert layer: also
                                   # return the held experts its rows reach
                                   # (the live ones), summed over the layers
+    extent: int | None = None,    # static: no row has a valid slot at or
+                                  # beyond it, so the XLA read of a
+                                  # contiguous cache stops there (None: the
+                                  # whole cache; `decode_read_extents`)
 ):
     """One autoregressive decode step. Returns (logits [B, V], new caches),
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
     over the layers."""
     B = token.shape[0]
     paged = (page_table, page_size) if page_table is not None else None
+    if extent is not None and extent < key_mask.shape[1]:
+        # the mask's width is what the XLA read goes by (`_kv_views`); the
+        # cache write below addresses the full stack as ever
+        key_mask = key_mask[:, :extent]
     x = params["embed_tokens"][token][:, None, :].astype(params["embed_tokens"].dtype)
     cos, sin = _rope(config, position[:, None])
     mask = key_mask[:, None, None, :]  # [B, 1, 1, T_max]

@@ -18,6 +18,9 @@ becomes a per-call PRNG key. Greedy mode covers the ReMax baseline rollout
 Decode is a `lax.while_loop` over single-token steps with a shared KV cache;
 it exits early once every sequence has emitted EOS (rollouts are offline-batch,
 so big batches keep the MXU busy; early exit claws back the static-shape tax).
+Past one 128-slot block of cache it is a few such loops in a row inside the
+one jit, each reading the cache up to a static extent that no row's write has
+passed (`_read_loops`): XLA's attention masks, it does not bound.
 """
 
 from __future__ import annotations
@@ -27,10 +30,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from nanorlhf_tpu.core.config import ModelConfig
 from nanorlhf_tpu.core.model import (
-    decode_step, init_kv_cache, init_paged_kv_cache, prefill,
+    decode_read_extents, decode_step, init_kv_cache, init_paged_kv_cache,
+    prefill,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import full_table
@@ -427,21 +432,71 @@ def generate_tokens(
         prompt_fanout=prompt_fanout, page_size=page_size,
     )
 
-    def cond(state):
-        return (state[0] < max_tokens) & ~jnp.all(state[5])
+    # One loop an extent of the XLA cache read (one in all for a paged or a
+    # one-block cache: the program as it was). Step `s` writes slot
+    # Tp + s - 1, every row's at once, so a loop may keep the steps whose
+    # slot lies under its extent; the condition is otherwise the same, and
+    # a loop that ends on `done` leaves the later ones no step to take.
+    for extent, stop in _read_loops(config, Tp, max_tokens, page_size):
+        def cond(state, stop=stop):
+            return (state[0] < stop) & ~jnp.all(state[5])
 
-    def body(state):
-        return _decode_body(
-            params, config, state, Tp=Tp, max_tokens=max_tokens,
-            eos_token_id=eos_token_id, pad_token_id=pad_token_id,
-            temperature=temperature, top_p=top_p, greedy=greedy,
-            lora_scale=lora_scale, top_k=top_k,
-            capture_logprobs=capture_logprobs, approx_top_k=approx_top_k,
-            page_size=page_size,
-        )
+        def body(state, extent=extent):
+            return _decode_body(
+                params, config, state, Tp=Tp, max_tokens=max_tokens,
+                eos_token_id=eos_token_id, pad_token_id=pad_token_id,
+                temperature=temperature, top_p=top_p, greedy=greedy,
+                lora_scale=lora_scale, top_k=top_k,
+                capture_logprobs=capture_logprobs, approx_top_k=approx_top_k,
+                page_size=page_size, extent=extent,
+            )
 
-    _, out, lp_out, _, _, _, _, _, _ = jax.lax.while_loop(cond, body, state)
+        state = jax.lax.while_loop(cond, body, state)
+    _, out, lp_out, _, _, _, _, _, _ = state
     return (out, lp_out) if capture_logprobs else out
+
+
+def _read_loops(config, Tp, max_tokens, page_size=0):
+    """`[(extent, stop)]` of the monolithic loop: the extents of
+    `decode_read_extents` over its contiguous cache, each with the first
+    step it leaves to the next, the one whose write slot `Tp + step - 1` is
+    the first outside it. The last step, `max_tokens - 1`, writes slot
+    `T_max - 2` (the last sampled token is never fed back). One loop over
+    the whole cache (to `decode_step` the same as no extent) where there is
+    one extent only."""
+    T_max = Tp + max_tokens
+    extents = (T_max,) if page_size > 0 else decode_read_extents(
+        config, Tp, T_max - 2, T_max)
+    loops = [(e, e - Tp + 1) for e in extents[:-1]] + [(T_max, max_tokens)]
+    # no slot that holds a key is skipped: each loop's last write slot is
+    # inside its extent
+    assert all(Tp + stop - 2 < e for e, stop in loops)
+    return loops
+
+
+def attn_read_frac(config, sampling, prompt_width: int, responses,
+                   eos_token_id: int) -> float:
+    """Share of the cache's slots the decode attention of one `generate`
+    call read (the trainer's `rollout/attn_read_frac`): the sum over its
+    decode steps of the step's extent, over steps x T_max. 1.0 wherever the
+    loop names no extent (a cache of one block, the compacting, paged and
+    speculative loops, the Pallas read), and for a call that took no step.
+    `responses` is the call's [rows, max_tokens] result on the HOST: the
+    loop ran until its longest row ended, one step a token after the
+    prefill's, so the static extents and that length say it all."""
+    ends = responses == eos_token_id
+    steps = int(np.where(ends.any(axis=1), ends.argmax(axis=1) + 1,
+                         responses.shape[1]).max()) - 1
+    if (sampling.page_size > 0 or sampling.spec_k > 0
+            or sampling.compaction_segments > 0 or steps <= 0):
+        return 1.0
+    T_max = prompt_width + sampling.max_tokens
+    read, start = 0, 1
+    for extent, stop in _read_loops(config, prompt_width,
+                                    sampling.max_tokens):
+        read += max(0, min(stop, steps + 1) - start) * extent
+        start = stop
+    return read / (steps * T_max)
 
 
 def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
@@ -540,18 +595,19 @@ def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
 
 def _decode_body(params, config, state, *, Tp, max_tokens, eos_token_id,
                  pad_token_id, temperature, top_p, greedy, lora_scale, top_k,
-                 capture_logprobs, approx_top_k, page_size=0):
+                 capture_logprobs, approx_top_k, page_size=0, extent=None):
     """One decode step over the carry state (shared by the monolithic
     while_loop above and the segmented/compacting loop). `page_size` > 0:
     the caches in the carry are paged pools; the dense identity table is a
     shape-derived constant (pool pages // batch rows), so the carry layout
-    is unchanged."""
+    is unchanged. `extent` (the monolithic loop only): the static bound
+    `decode_step` reads a contiguous cache up to."""
     step, out, lp_out, caches, key_mask, done, cur_tok, prompt_len, key = state
-    paged_kw = {}
+    read_kw = {} if extent is None else dict(extent=extent)
     if page_size > 0:
         B = key_mask.shape[0]
-        paged_kw = dict(page_table=full_table(B, caches[0].shape[1] // B),
-                        page_size=page_size)
+        read_kw = dict(page_table=full_table(B, caches[0].shape[1] // B),
+                       page_size=page_size)
     # token t was sampled from logits at position prompt_len + step - 1;
     # its KV lands in cache slot Tp + step - 1
     cache_slot = Tp + step - 1
@@ -559,7 +615,7 @@ def _decode_body(params, config, state, *, Tp, max_tokens, eos_token_id,
     position = prompt_len + step - 1
     logits, caches = decode_step(
         params, config, cur_tok, position, cache_slot, key_mask, caches,
-        lora_scale=lora_scale, **paged_kw,
+        lora_scale=lora_scale, **read_kw,
     )
     tok = _sample_token(jax.random.fold_in(key, step), logits, temperature,
                         top_p, greedy, top_k, approx_top_k)

@@ -77,6 +77,7 @@ from nanorlhf_tpu.ops.masking import (
 from nanorlhf_tpu.parallel.mesh import (MeshConfig, batch_sharding, make_mesh,
                                         shard_params)
 from nanorlhf_tpu.sampler import SamplingParams, compose_check, generate
+from nanorlhf_tpu.sampler.sampler import attn_read_frac
 from nanorlhf_tpu.telemetry import (DEFAULT_RULES, HealthConfig,
                                     HealthMonitor, LatencyHub,
                                     LineageLedger, SLO_RULES, SpanTracer,
@@ -217,6 +218,7 @@ class TrainRun:
     score_capture: bool          # ... and they stand in for the policy pass
     target_step: int             # train() returns at this global_step
     body: Callable               # dispatches one rollout (`_rollout_body`)
+    sampling: SamplingParams     # ... with these
     counted_to: int = -1         # newest rollout a no-step was charged for
     # the rollout source's handles (`_ensure_handles`): the orchestrator
     # or a RolloutStream, and the overlap meter of whichever it is
@@ -1854,6 +1856,7 @@ class RLTrainer:
             # the body holds what it reads and no more: an orchestrator
             # keeps it across train() calls
             body=partial(self._rollout_body, sampling, ctx_menu),
+            sampling=sampling,
         )
         self._ensure_handles(run)
         # whole-rollout drops (queue stale_drop, fleet late-duplicate) are
@@ -2284,6 +2287,12 @@ class RLTrainer:
                         up.seg_ages[r, lo:hi] = newest - s["policy_version"]
         up.decoded = tok.batch_decode(up.responses)
         envp = up.envp = ro.get("env")
+        if envp is None:
+            # how far the rollout's decode read was bounded: every row is
+            # on the host here, before a selection cuts any
+            up.extra_metrics["rollout/attn_read_frac"] = attn_read_frac(
+                self._rollout_mcfg, run.sampling, up.context_length,
+                up.responses, tok.eos_token_id)
         with self.timer.phase("reward"):
             if envp is not None:
                 # multi-turn env: rewards accrued turn-by-turn inside

@@ -307,7 +307,7 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     (the rows' 64 x 12 = 768 pages gathered, their transpose into row
     order) or of a layer's slab of the pool."""
     from test_cache_carry import (
-        _shapes, decode_loop_offences, decode_loop_reach, hlo_stacks,
+        _CALLEE, _shapes, decode_loop_offences, decode_loop_reach, hlo_stacks,
     )
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -316,6 +316,34 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     offences, _ = decode_loop_offences(
         hlo, hlo_stacks(cache), slabs_too=case != "rollout_int8")
     assert not offences, "\n".join(offences)
+    if case == "rollout":
+        # ISSUE 33: three decode loops, one an extent of the cache read; each
+        # loop's QK fusion reads its own extent of the stack in place (no
+        # slab of any extent set down on the way: as a static slice BEHIND
+        # the layer's dynamic one the whole slab was), and no copy of the
+        # stack anywhere in the program, between the loops included
+        comps, reached = decode_loop_reach(hlo)
+        fused = {callee for instrs in comps.values()
+                 for _, _, op, rest in instrs if op == "fusion"
+                 for callee in _CALLEE.findall(rest)}
+        scores, slabs = set(), []
+        for name in reached:
+            for _, result, op, _ in comps[name]:
+                if result.startswith("(") or name in fused:
+                    continue
+                shape = _shapes(result)[0][1] if _shapes(result) else ()
+                if op == "fusion" and shape[:3] == (64, KV, H // KV):
+                    scores.add(shape[3])
+                if (shape[:2] == (64, KV) and shape[3:] == (HD,)
+                        and shape[2] >= 128):
+                    slabs.append(f"{name}: {result} {op}")
+        # (PV's result has the same leading axes, HD wide)
+        assert scores - {HD} == {512, 640, 768}, scores
+        assert not slabs, "\n".join(slabs)
+        copies = [f"{name}: {result}" for name, instrs in comps.items()
+                  for _, result, op, _ in instrs if op == "copy"
+                  and _shapes(result)[0] in hlo_stacks(cache)]
+        assert not copies, "\n".join(copies)
     if case == "serving_chunk":
         comps, reached = decode_loop_reach(hlo)
         assert any(op == "custom-call" and "tpu_custom_call" in rest

@@ -185,6 +185,9 @@ def test_golden_rows_and_parameters_reproduce(name, tmp_path_factory):
     assert len(got["rows"]) == len(want["rows"])
     for i, (g, w) in enumerate(zip(got["rows"], want["rows"])):
         have = {k: v for k, v in g.items() if not _is_clock(k)}
+        # a counter the golden's parent did not write (ISSUE 33): these
+        # caches are one block, so the rollout read all of it
+        assert have.pop("rollout/attn_read_frac", 1.0) == 1.0
         if name.startswith("sparse"):
             # every key the parent wrote, with its value; the keys the
             # shared phases add are counted in the test below

@@ -255,3 +255,146 @@ def test_top_p_bisect_matches_sort_oracle(rng):
             probs = np.asarray(jax.nn.softmax(logits, axis=-1))
             for b in range(logits.shape[0]):
                 assert probs[b][got[b]].sum() >= p - 1e-5
+
+
+# --------------------------------------------------------------------- #
+# the decode read's static extents (core/model.py::decode_read_extents)
+# --------------------------------------------------------------------- #
+
+def _whole_read_generate(params, config, ids, mask, key, sampling, eos):
+    """`generate_tokens` as it was before the loop named extents: the same
+    prefill, then ONE while_loop whose steps read the whole cache."""
+    from functools import partial
+
+    from nanorlhf_tpu.sampler import sampler as S
+
+    kw = dict(max_tokens=sampling.max_tokens, eos_token_id=eos,
+              pad_token_id=PAD, temperature=sampling.temperature,
+              top_p=sampling.top_p, greedy=sampling.greedy, lora_scale=1.0,
+              top_k=sampling.top_k, capture_logprobs=True,
+              approx_top_k=sampling.approx_top_k)
+
+    @jax.jit
+    def run(params, ids, mask, key):
+        state = S._prefill_state(params, config, ids, mask, key,
+                                 prompt_fanout=sampling.n, **kw)
+        body = partial(S._decode_body, params, config, Tp=ids.shape[1], **kw)
+        state = jax.lax.while_loop(
+            lambda s: (s[0] < sampling.max_tokens) & ~jnp.all(s[5]),
+            body, state)
+        return state[1], state[2]
+
+    return run(params, ids, mask, key)
+
+
+def _first_seen(row, lo, hi):
+    """Indices in [lo, hi) at which `row` holds a token for the first time."""
+    return [k for k in range(lo, hi) if row[k] not in row[:k]]
+
+
+def test_read_loops_take_every_step_once_under_its_extent(tiny):
+    """Whatever the prompt's width and the number of new tokens (a cache one
+    slot past a block among them: its last slot is never written, so it
+    earns no loop), the loops' stops rise to `max_tokens`, each step's
+    write slot lies under its loop's extent, every extent but the last is
+    whole blocks, and there are never more than three."""
+    from nanorlhf_tpu.sampler.sampler import _read_loops
+
+    config, _ = tiny
+    for Tp in (1, 5, 64, 113, 127, 128, 129, 200, 256, 300):
+        for new in range(1, 700):
+            loops = _read_loops(config, Tp, new)
+            extents, stops = zip(*loops)
+            assert len(loops) <= 3 and loops[-1] == (Tp + new, new)
+            assert all(a < b for a, b in zip(stops, stops[1:])), (Tp, new)
+            assert all(e % 128 == 0 for e in extents[:-1])
+            assert all(Tp + stop - 2 < e <= Tp + stop - 1 or e == Tp + new
+                       for e, stop in loops), (Tp, new)
+            if Tp + new <= 128:
+                assert len(loops) == 1
+    assert _read_loops(config, 256, 512) == [(512, 257), (640, 385),
+                                             (768, 512)]
+
+
+@pytest.mark.parametrize("case", ["dense", "olmoe", "eos_before_a_boundary",
+                                  "eos_at_a_boundary", "one_past_a_block",
+                                  "thinned_one_past_a_block"])
+def test_bounded_decode_read_matches_the_whole_read(tiny, case):
+    """Past one 128-slot block the monolithic loop runs as one loop an
+    extent, each reading the cache up to a static bound no row's write has
+    passed. The slots it leaves out are masked ones (exp(-inf) = 0 in the
+    softmax), so greedy tokens equal the whole read's and the captured
+    logprobs agree to float32 roundoff; rows that all end early leave the
+    later loops no step and the output padded as ever; and the counter
+    says what the extents say. A cache one slot past a block (its last slot
+    is never written) runs too, with its natural extents and with more
+    natural extents than a loop may have."""
+    from nanorlhf_tpu.sampler.sampler import _read_loops, attn_read_frac
+
+    config, params = tiny
+    if case == "olmoe":
+        config = ModelConfig.olmoe_tiny(vocab_size=128)
+        params = init_params(config, jax.random.PRNGKey(7), jnp.float32)
+    Tp, new = {"one_past_a_block": (200, 313),
+               "thinned_one_past_a_block": (64, 449)}.get(case, (64, 200))
+    key = jax.random.PRNGKey(5)
+    sampling = SamplingParams(greedy=True, n=2, max_tokens=new,
+                              capture_logprobs=True)
+    if "eos" not in case:
+        # rows of unlike pad widths, and nothing ends them
+        ids, mask = _left_pad([list(range(5, 69)), list(range(9, 40)),
+                               [70, 71, 72]], Tp)
+        eos, ends_at = -1, new - 1
+    else:
+        # identical rows end together: greedy, and EOS is whichever token
+        # the row holds first at the step wanted
+        ids, mask = _left_pad([[5, 6, 7, 8, 9]] * 2, Tp)
+        ref, _ = _whole_read_generate(params, config, ids, mask, key,
+                                      sampling, -1)
+        ref = np.asarray(ref)
+        assert (ref == ref[0]).all()
+        if case == "eos_before_a_boundary":
+            ends_at = _first_seen(ref[0], 20, 40)[0]
+        else:
+            # the first loop's last step writes slot 127: move the prompt's
+            # width so that the row's end falls on it
+            ends_at = _first_seen(ref[0], 50, 100)[0]
+            Tp = 128 - ends_at
+            ids, mask = _left_pad([[5, 6, 7, 8, 9]] * 2, Tp)
+        eos = int(ref[0, ends_at])
+    loops = _read_loops(config, Tp, new)
+    first, second = {"one_past_a_block": (256, 384),
+                     "thinned_one_past_a_block": (256, 384)}.get(
+                         case, (128, 256))
+    assert loops == [(first, first + 1 - Tp), (second, second + 1 - Tp),
+                     (Tp + new, new)]
+
+    want, want_lp = _whole_read_generate(params, config, ids, mask, key,
+                                         sampling, eos)
+    got, got_lp = generate(params, config, ids, mask, key, sampling,
+                           eos_token_id=eos, pad_token_id=PAD)
+    want, got = np.asarray(want), np.asarray(got)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(np.asarray(got_lp), np.asarray(want_lp),
+                               rtol=0, atol=1e-5)
+    assert got.shape == (2 * ids.shape[0], new)
+    if eos >= 0:
+        assert (got[:, ends_at] == eos).all() and (got[:, :ends_at] != eos).all()
+        assert (got[:, ends_at + 1:] == PAD).all()
+        assert (np.asarray(got_lp)[:, ends_at + 1:] == 0).all()
+
+    # steps 1 .. ends_at, each under the first extent its write slot is in
+    read = sum(min(e for e, _ in loops if Tp + s - 1 < e)
+               for s in range(1, ends_at + 1))
+    frac = attn_read_frac(config, sampling, Tp, got, eos)
+    assert frac == pytest.approx(read / (ends_at * (Tp + new)))
+    if eos >= 0:
+        assert frac == pytest.approx(128 / (Tp + new))   # one loop ran
+    # ... and 1.0 where the loop names no extent
+    if Tp < 128:
+        one_block = SamplingParams(greedy=True, max_tokens=128 - Tp)
+        assert _read_loops(config, Tp, 128 - Tp) == [(128, 128 - Tp)]
+        assert attn_read_frac(config, one_block, Tp, got[:, :128 - Tp],
+                              eos) == 1.0
+    paged = SamplingParams(greedy=True, max_tokens=new, page_size=16)
+    assert attn_read_frac(config, paged, Tp, got, eos) == 1.0

@@ -5,6 +5,8 @@ CPU-runnable) plus one pass of every other algorithm — the integration net
 the reference never had (SURVEY.md §4).
 """
 
+import json
+
 import numpy as np
 import pytest
 
@@ -71,6 +73,11 @@ def test_reinforce_smoke(tmp_path):
     state = tr.train()
     assert state["global_step"] == 2
     assert (tmp_path / "reinforce" / "metrics.jsonl").exists()
+    # a cache of one block: the rollout's one loop reads all of it
+    rows = [json.loads(line)
+            for line in open(tmp_path / "reinforce" / "metrics.jsonl")]
+    assert [r["rollout/attn_read_frac"] for r in rows if "episode" in r] == [
+        1.0, 1.0]
     assert (tmp_path / "reinforce" / "checkpoint-2").exists()
 
 
