@@ -1,0 +1,43 @@
+"""kernels, pattern model: the bytes one decode step must move
+(harness/ops_bytes_smallthinker.decode_step_bytes: every layer's attention
+and router, the expert kernels some live row reached, the K and V slots
+inside the bounds of each kind of layer, head and f32 logits) over the HBM
+bandwidth of peaks.json, divided by the step's time (`st_decode_step_ms`), in
+%. All three counts are the window's own, a step: experts reached
+(`serving/held_experts_hit`, counted on the device), slots read a kind
+(`serving/global_slots_read`, `serving/window_slots_read`, counted on the
+host), each over `serving/decode_steps`; live rows are the mean of the
+window's snapshots. The step's time holds its share of the beat's prefill
+chunk, so under long prompts the share reads low: a step-level share of the
+memory roofline, not the kernel's."""
+
+from harness import ops_bytes_smallthinker as ob
+from layer_metrics import st_decode_step_ms
+from layer_metrics.queue_wait_ms import ratio
+
+
+def live_rows(run):
+    snaps = run.get("snapshots") or []
+    return sum(s["active"] for s in snaps) / len(snaps) if snaps else None
+
+
+def floor_ms(run):
+    rows = live_rows(run)
+    hit = ratio(run, "serving/held_experts_hit", "serving/decode_steps", 1.0)
+    g = ratio(run, "serving/global_slots_read", "serving/decode_steps", 1.0)
+    w = ratio(run, "serving/window_slots_read", "serving/decode_steps", 1.0)
+    if not rows or None in (hit, g, w):
+        return None
+    cfg = run["config"]
+    b = ob.decode_step_bytes(cfg, rows=rows,
+                             experts_hit=hit / cfg["num_hidden_layers"],
+                             global_slots=g, window_slots=w)
+    return 1e3 * b["total"] / (run["chips"] * run["peaks"]["hbm_bytes_per_s"])
+
+
+def read(run):
+    step_ms = st_decode_step_ms.read(run)
+    if not step_ms or "sliding_window_layout" not in run.get("config", {}):
+        return None
+    floor = floor_ms(run)
+    return None if floor is None else 100.0 * floor / step_ms

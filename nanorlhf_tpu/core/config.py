@@ -1,6 +1,8 @@
 """Model architecture config: the dense Qwen2/Llama block (GQA + SwiGLU),
-OLMoE's sparse-expert block, or A.X-K1's (DeepSeek-V3's) latent attention
-with leading dense layers before shared-plus-routed sigmoid experts.
+OLMoE's sparse-expert block, A.X-K1's (DeepSeek-V3's) latent attention
+with leading dense layers before shared-plus-routed sigmoid experts, or
+SmallThinker's pattern of window layers with rotary embedding beside global
+layers without it over ReLU-gated experts (docs/SWA.md).
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
 `/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
@@ -88,6 +90,35 @@ class ModelConfig:
     # only (docs/MLA.md "the chip's share"). 0 held = all of them.
     experts_held: int = 0
     experts_offset: int = 0
+    # Tokens an expert layer takes at a time; 0: all at once, but a chip's
+    # share 1,024 (`core/model.py::_mlp`). Past it the layer goes in blocks
+    # of at most this many, so a long row scored or prefilled beside a
+    # served model's weights and page pool holds one block's dispatched rows
+    # (tokens x experts a token x hidden, in, out and their float32
+    # combine) and not the row's: about 0.12 GB a block at SmallThinker's
+    # widths, where a 9,765-token row held 3.3 GB (compiled for a described
+    # v5e, PR 34), as at A.X-K1's share. A configuration served on one chip
+    # sets it; OLMoE, whose cell is a trainer with the chip to itself, does
+    # not, and a mesh keeps its batch sharding (a block flattens the rows).
+    expert_token_block: int = 0
+    # The attention pattern (docs/SWA.md; empty layouts = every layer global
+    # and rotated, every model but SmallThinker). Layer `l` is a WINDOW layer
+    # where `sliding_window_layout[l]`: query i sees key j iff
+    # i - sliding_window < j <= i; it applies rotary embedding where
+    # `rope_layout[l]` (a layer without it carries no positional signal).
+    # Tuples: the config is a static jit argument. The layouts repeat with a
+    # period that divides the depth (`attention_pattern`), which is what the
+    # layer scan goes over.
+    sliding_window: int = 0
+    sliding_window_layout: tuple = ()
+    rope_layout: tuple = ()
+    # The experts' gate: silu(gate) * up (SwiGLU) or relu(gate) * up
+    # (SmallThinker's "sparse ReGLU").
+    expert_activation: str = "silu"  # silu | relu
+    # What the router reads: the MLP's own input (the post-attention normed
+    # state), or the layer's PRE-attention normed state (SmallThinker:
+    # "router placed before attention").
+    router_input: str = "mlp"  # mlp | pre_attention
     # "int8": the sampler's KV cache stores int8 values + per-token-per-head
     # bf16 scales (absmax over head_dim). At long responses the cache read is
     # the dominant decode HBM stream (≈7.5 GB/step at 8k tokens, batch 32);
@@ -159,6 +190,47 @@ class ModelConfig:
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def attention_pattern(self):
+        """One period of the layers' attention kinds, `((window, rotary),
+        ...)`, the shortest that tiles the depth; None for a model without a
+        pattern (every layer global and rotated), whose programs are the
+        ones it always had."""
+        L = self.num_hidden_layers
+        win = tuple(bool(w) for w in self.sliding_window_layout) or (False,) * L
+        rot = tuple(bool(r) for r in self.rope_layout) or (True,) * L
+        if self.sliding_window <= 0:
+            win = (False,) * L
+        kinds = tuple(zip(win, rot))
+        if len(kinds) != L:
+            raise ValueError(
+                f"attention layouts of {len(kinds)} entries for "
+                f"{L} layers")
+        if all(k == (False, True) for k in kinds):
+            return None
+        for p in range(1, L + 1):
+            if L % p == 0 and kinds == kinds[:p] * (L // p):
+                return kinds[:p]
+
+    @property
+    def window_layers(self) -> int:
+        """Layers with a sliding window (0 without a pattern)."""
+        pattern = self.attention_pattern
+        if pattern is None:
+            return 0
+        return (sum(w for w, _ in pattern)
+                * (self.num_hidden_layers // len(pattern)))
+
+    @property
+    def live_rows_dispatch(self) -> bool:
+        """A session's decode step dispatches to the experts only the rows
+        someone listens to (those not done), and counts the experts they
+        reach (`ops/moe.moe_mlp` `live=`): every model with expert layers,
+        whether it holds a share of them or all. A row nobody listens to
+        otherwise reaches experts of its own, whose kernels the step then
+        reads for nothing and whose count swings `tpot_p95_ms` (PR 31)."""
+        return bool(self.num_experts)
 
     @classmethod
     def qwen2_tiny(cls, vocab_size: int = 512) -> "ModelConfig":
@@ -283,6 +355,51 @@ class ModelConfig:
             experts_offset=experts_offset)
 
     @classmethod
+    def smallthinker_21b(cls) -> "ModelConfig":
+        """PowerInfer/SmallThinker-21BA3B-Instruct: 52 GQA layers in periods
+        of [global without rotary, window 4,096 with rotary x 3], every MLP
+        64 ReLU-gated experts of width 768, 6 a token, routed from the
+        pre-attention state; untied head (docs/SWA.md)."""
+        return cls(
+            vocab_size=151936,
+            hidden_size=2560,
+            intermediate_size=768,
+            num_hidden_layers=52,
+            num_attention_heads=28,
+            num_key_value_heads=4,
+            head_dim=128,
+            rope_theta=1_500_000.0,
+            rms_norm_eps=1e-6,
+            tie_word_embeddings=False,
+            max_position_embeddings=16384,
+            attention_bias=False,
+            model_type="smallthinker",
+            num_experts=64,
+            num_experts_per_tok=6,
+            norm_topk_prob=True,
+            sliding_window=4096,
+            sliding_window_layout=(0, 1, 1, 1) * 13,
+            rope_layout=(0, 1, 1, 1) * 13,
+            expert_activation="relu",
+            router_input="pre_attention",
+            expert_token_block=4096,
+        )
+
+    @classmethod
+    def smallthinker_tiny(cls, vocab_size: int = 512, window: int = 8,
+                          layers: int = 4) -> "ModelConfig":
+        """Test-size SmallThinker: the same period at 8 experts, 2 a token,
+        and a window every test path crosses."""
+        return dataclasses.replace(
+            cls.smallthinker_21b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=32, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            max_position_embeddings=1024, num_experts=8,
+            num_experts_per_tok=2, sliding_window=window,
+            sliding_window_layout=((0, 1, 1, 1) * layers)[:layers],
+            rope_layout=((0, 1, 1, 1) * layers)[:layers])
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -336,6 +453,24 @@ class ModelConfig:
         olmoe = model_type == "olmoe"
         if model_type == "axk1":
             return cls._axk1_from_hf(get)
+        if model_type == "smallthinker":
+            return cls._smallthinker_from_hf(get)
+        # a window on a family this decoder builds with full attention only:
+        # Qwen2's `use_sliding_window` (with `sliding_window`, `layer_types`
+        # or `max_window_layers`), Mistral's bare `sliding_window`
+        windowed = (get("use_sliding_window")
+                    or "sliding_attention" in (get("layer_types") or ())
+                    or (get("sliding_window") is not None
+                        and get("use_sliding_window") is None))
+        if windowed:
+            raise ValueError(
+                f"model_type={model_type!r} with a sliding window "
+                f"(use_sliding_window={get('use_sliding_window')!r}, "
+                f"sliding_window={get('sliding_window')!r}, layer_types="
+                f"{'given' if get('layer_types') else None}): the window "
+                "layers of docs/SWA.md are built for model_type "
+                "'smallthinker' only; full attention under this config "
+                "would be another model under its name")
         expert_keys = [k for k in _EXPERT_KEYS if get(k)]
         if expert_keys and not olmoe:
             raise ValueError(
@@ -366,6 +501,77 @@ class ModelConfig:
             num_experts_per_tok=get("num_experts_per_tok") or 0,
             norm_topk_prob=bool(get("norm_topk_prob", False)),
             qk_norm=olmoe,
+        )
+
+    @classmethod
+    def _smallthinker_from_hf(cls, get) -> "ModelConfig":
+        """SmallThinker's published keys (docs/SWA.md). What the layer of
+        docs/SWA.md does not compute raises: a sigmoid primary router,
+        secondary experts, a `moe_layer_layout` with dense layers (the 4B
+        sibling), rope scaling, attention biases, and layouts that give the
+        model no global or no window layer."""
+        L = int(get("num_hidden_layers"))
+        if get("moe_primary_router_apply_softmax") is False:
+            raise ValueError(
+                "smallthinker: moe_primary_router_apply_softmax=False (a "
+                "sigmoid primary router) is not implemented: docs/SWA.md")
+        for key in ("moe_num_secondary_experts", "moe_secondary_expert_size",
+                    "moe_num_active_secondary_experts"):
+            if get(key):
+                raise ValueError(
+                    f"smallthinker: {key}={get(key)!r}: secondary experts "
+                    "are not implemented (the 21B config.json has primary "
+                    "keys only: docs/SWA.md)")
+        layout = get("moe_layer_layout")
+        if layout is not None and not all(layout):
+            raise ValueError(
+                "smallthinker: a moe_layer_layout with dense layers (the 4B "
+                "sibling's) is not implemented: every layer has experts")
+        if get("rope_scaling"):
+            raise ValueError("smallthinker: rope_scaling="
+                             f"{get('rope_scaling')!r} is not implemented")
+        if get("attention_bias"):
+            raise ValueError("smallthinker: attention biases are not "
+                             "implemented (assumed none: docs/SWA.md)")
+        window = int(get("sliding_window_size") or 0)
+        win = tuple(int(bool(w)) for w in (get("sliding_window_layout")
+                                           or (0,) * L))
+        rot = tuple(int(bool(r)) for r in (get("rope_layout") or (1,) * L))
+        if len(win) != L or len(rot) != L:
+            raise ValueError(
+                f"smallthinker: layouts of {len(win)} and {len(rot)} entries "
+                f"for {L} layers")
+        if any(win) and window <= 0:
+            raise ValueError("smallthinker: window layers without a "
+                             "sliding_window_size")
+        if window and any(win) and all(win):
+            raise ValueError(
+                "smallthinker: a model of window layers only is not "
+                "implemented: the page pool of two kinds keeps a global "
+                "kind (docs/SWA.md)")
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=get("hidden_size"),
+            intermediate_size=get("moe_ffn_hidden_size"),
+            num_hidden_layers=L,
+            num_attention_heads=get("num_attention_heads"),
+            num_key_value_heads=get("num_key_value_heads"),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1_500_000.0)),
+            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 16384),
+            attention_bias=False,
+            model_type="smallthinker",
+            num_experts=get("moe_num_primary_experts"),
+            num_experts_per_tok=get("moe_num_active_primary_experts"),
+            norm_topk_prob=bool(get("norm_topk_prob", True)),
+            sliding_window=window if any(win) else 0,
+            sliding_window_layout=win if any(win) else (),
+            rope_layout=() if all(rot) else rot,
+            expert_activation="relu",
+            router_input="pre_attention",
+            expert_token_block=4096,
         )
 
     @classmethod

@@ -3,7 +3,11 @@ block (GQA + SwiGLU), OLMoE's (QK-norm + a sparse-expert MLP, ops/moe.py) and
 A.X-K1's (latent attention, core/mla.py; a leading dense stack, then shared
 plus routed experts), chosen at trace time from the `ModelConfig` and the
 tree (`_layer_body`, `_mlp`, `_layer_stacks`). Every layer of a stack is the
-same kind; only A.X-K1 has two stacks.
+same kind; only A.X-K1 has two stacks. SmallThinker's stack has an attention
+PATTERN (window layers with rotary embedding beside global layers without,
+`ModelConfig.attention_pattern`): its scan goes over periods, each layer of a
+period of its own static kind, and its cache is two groups of stacks, the
+global layers' and the window layers' (`_run_pattern_layers`, docs/SWA.md).
 
 TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
 `/root/reference/GRPO/grpo.py:218-224`):
@@ -529,21 +533,29 @@ def _paged_scale_view(pool, layer, table, width):
 # experts' part (held / E of them): past this many tokens the layer goes in
 # blocks, so a 4k-token scoring row at A.X-K1's widths holds 0.12 GB of
 # dispatched rows at a time instead of 0.5 GB in, 0.5 GB out and a float32
-# [N, k, D] beside a served model's weights and pool
+# [N, k, D] beside a served model's weights and pool. A model that holds
+# every expert says for itself where its blocks end
+# (`ModelConfig.expert_token_block`).
 _SHARE_TOKEN_BLOCK = 1024
 
 
-def _in_token_blocks(fn, h, block: int):
+def _in_token_blocks(fn, h, block: int, *more):
     """`fn(x [n, D]) -> (y [n, D], moe aux)` over `h`'s tokens in equal
     blocks of at most `block` (the layer is per token, so this is the same
-    layer); zero rows pad the last block and are cut off again."""
+    layer); zero rows pad the last block and are cut off again. `more`:
+    further per-token inputs `fn` takes after `x`, blocked alike."""
     lead, D = h.shape[:-1], h.shape[-1]
-    x = h.reshape(-1, D)
-    N = x.shape[0]
+    N = h.size // D
     n = -(-N // block)
     size = -(-N // n)
-    x = jnp.pad(x, ((0, n * size - N), (0, 0))).reshape(n, size, D)
-    out, aux = jax.lax.map(fn, x)
+    blocks = lambda a: jnp.pad(     # noqa: E731
+        a.reshape(N, -1), ((0, n * size - N), (0, 0))).reshape(n, size, -1)
+    x = blocks(h)
+    if more:
+        out, aux = jax.lax.map(lambda xs: fn(*xs),
+                               (x,) + tuple(blocks(a) for a in more))
+    else:
+        out, aux = jax.lax.map(fn, x)
     cut = lambda a: a.reshape((n * size,) + a.shape[2:])[:N]  # noqa: E731
     experts = cut(aux["experts"])
     return cut(out).reshape(h.shape), {
@@ -566,7 +578,7 @@ def _swiglu(h, layer_params, lora_layer, lora_scale):
 
 
 def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
-         expert_stack=None, layer=None, live=None):
+         expert_stack=None, layer=None, live=None, router_h=None):
     """The layer's MLP on normed hidden states, chosen at trace time from
     the layer's own tree: the sparse-expert MLP of ops/moe.py where the layer
     has a router (its router and experts carry no adapter, core/lora.py),
@@ -576,34 +588,43 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
     untouched. `expert_stack` is the experts' subtree of EVERY layer of the
     stack, addressed in place at `layer` instead of sliced (`_expert_xs`;
     ops/moe.py says why). `live` [B] marks the decode step's rows someone
-    listens to (a chip's share dispatches those only: `moe_mlp`). Returns
+    listens to (an expert layer dispatches those only: `moe_mlp`). Returns
     `(out, aux)`; `aux` is the router's per-token record (`moe_mlp`), None
-    for the dense layer."""
+    for the dense layer. `router_h`: what the router reads where that is not
+    `h` (SmallThinker: the pre-attention normed state)."""
     if "router" in layer_params:
         from nanorlhf_tpu.ops.moe import moe_mlp
 
         experts = expert_stack or layer_params["experts"]
-        routing = {}    # OLMoE passes none of these: its program as it was
-        if config.experts_held:     # the chip's share of the routed experts
-            routing["held"] = (config.experts_held, config.experts_offset)
-            if live is not None and h.shape[1] == 1:
-                routing["live"] = live[:, None]
+        routing = {}    # OLMoE's uncached and prefill programs pass none
+        listening = live is not None and h.shape[1] == 1
+        if config.experts_held or listening:
+            # a chip's share of the routed experts; a decode step that
+            # knows its listeners holds "all of them" the same way
+            routing["held"] = (config.num_held_experts, config.experts_offset)
+        if listening:   # the rows without a request are not dispatched
+            routing["live"] = live[:, None]
+        if config.expert_activation != "silu":
+            routing["activation"] = config.expert_activation
         if config.scoring_func != "softmax" or config.routed_scaling_factor != 1.0:
             routing.update(scoring=config.scoring_func,
                            routed_scale=config.routed_scaling_factor)
-        def routed(x):
+        def routed(x, router_h=None):
             return moe_mlp(
                 x, layer_params["router"]["kernel"],
                 experts["gate_proj"]["kernel"], experts["up_proj"]["kernel"],
                 experts["down_proj"]["kernel"], config.num_experts_per_tok,
                 config.norm_topk_prob,
                 layer=layer if expert_stack is not None else None,
-                kernel=use_expert_kernel(config), **routing)
+                kernel=use_expert_kernel(config), router_h=router_h, **routing)
 
-        if config.experts_held and h.size // h.shape[-1] > _SHARE_TOKEN_BLOCK:
-            out, aux = _in_token_blocks(routed, h, _SHARE_TOKEN_BLOCK)
+        more = () if router_h is None else (router_h,)
+        block = config.expert_token_block or (
+            _SHARE_TOKEN_BLOCK if config.experts_held else 0)
+        if block and h.size // h.shape[-1] > block:
+            out, aux = _in_token_blocks(routed, h, block, *more)
         else:
-            out, aux = routed(h)
+            out, aux = routed(h, *more)
         if "shared_expert" in layer_params:
             with jax.named_scope("moe.shared"):
                 out = out + _swiglu(h, layer_params["shared_expert"], None,
@@ -615,8 +636,14 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
 def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
                 decode_bounds=None, verify_bounds=None, paged=None, layer=0,
-                expert_stack=None, stack_start=0, live=None):
+                expert_stack=None, stack_start=0, live=None, kind=None,
+                expert_layer=None):
     """One decoder layer. If kv_cache is not None, operate incrementally.
+
+    `kind=(window, rotary)` marks a layer of a pattern model
+    (`_run_pattern_layers`): `mask`, `kv_cache`, `decode_bounds`,
+    `verify_bounds` and `paged` are then its KIND's, `layer` its index into
+    its kind's cache stacks and `expert_layer` its place in the model.
 
     Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
     kv_cache: the STACKED cache of every layer (init_kv_cache /
@@ -655,7 +682,8 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     cache is one latent array and the forms are its own.
     """
     # (a stack that starts the model keeps `layer` as it is: the same program)
-    expert_layer = layer - stack_start if stack_start else layer
+    if expert_layer is None:
+        expert_layer = layer - stack_start if stack_start else layer
     if config.kv_lora_rank:
         if attn_fn is not None:
             raise NotImplementedError(
@@ -690,10 +718,21 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     k = k.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
 
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if kind is None or kind[1]:     # a NoPE layer carries no position
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if attn_fn is not None:
+    if kind is not None:
+        if attn_fn is not None:
+            raise NotImplementedError(
+                "window layers have no sequence-parallel form: ring "
+                "attention passes whole K and V blocks round (docs/SWA.md)")
+        window = config.sliding_window if kind[0] else 0
+        with jax.named_scope("attn.window" if window else "attn.global"):
+            out, new_cache = _pattern_attention(
+                config, q, k, v, mask, kv_cache, cache_index, decode_bounds,
+                verify_bounds, paged, layer, window, spmd)
+    elif attn_fn is not None:
         new_cache = None
         out = attn_fn(q, k, v)
     elif kv_cache is not None and len(kv_cache) == 4:
@@ -850,11 +889,150 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
     x = x + out
 
+    router_h = h if config.router_input == "pre_attention" else None
     h = rms_norm(x, layer_params["post_attention_layernorm"], config.rms_norm_eps)
     ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
-                   expert_stack, expert_layer)
+                   expert_stack, expert_layer, live, router_h)
     x = x + ff
     return x, new_cache, aux
+
+
+# pages a key block of the pattern model's T > 1 paged read holds (1,024 keys
+# at pages of 128, as core/mla.py's)
+_PAGED_BLOCK_PAGES = 8
+
+
+def _attend_paged_blocks(pools, layer, table, page_size, mask, first, last, q):
+    """GQA attention of T > 1 queries over a paged cache a block of
+    `_PAGED_BLOCK_PAGES` pages at a time, over the blocks that hold slots
+    `[min(first), max(last)]` only, with a float32 online softmax across
+    them (`mla._attend_paged`'s walk, for per-head K and V). A chunked
+    prefill's queries need the slots from the chunk's first key (a window
+    layer's: `window` before it) to its last, not the row's whole table: at
+    16,384 slots the gathered view's scores alone are 1.9 GB a layer, a
+    block's 0.12. `mask` [B, 1, T, width] is the kind's own (the window per
+    query); q [B, H, T, hd]; first/last [B] int32. Returns [B, H, T, hd]."""
+    B, H, T, hd = q.shape
+    KV = pools[0].shape[2]
+    nb, width = table.shape[1], mask.shape[-1]
+    bp = _PAGED_BLOCK_PAGES
+    K = bp * page_size
+    n_blocks = -(-nb // bp)
+    table = jnp.pad(table, ((0, 0), (0, n_blocks * bp - nb)),
+                    constant_values=pools[0].shape[1])
+    mask = jnp.pad(mask, ((0, 0),) * 3 + ((0, max(n_blocks * K - width, 0)),))
+    lo = jnp.clip(jnp.min(first) // K, 0, n_blocks)
+    hi = jnp.clip(jnp.max(last) // K + 1, 0, n_blocks)
+    qg = q.reshape(B, KV, H // KV, T, hd)
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+
+    def block(kb, carry):
+        m_i, l_i, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, kb * bp, bp, axis=1)
+        kd = _paged_view(pools[0], layer, pages, K)
+        vd = _paged_view(pools[1], layer, pages, K)
+        s = jnp.einsum("bkgqh,bkth->bkgqt", qg, kd,
+                       preferred_element_type=jnp.float32) * scale
+        valid = jax.lax.dynamic_slice_in_dim(mask, kb * K, K, axis=3)
+        s = jnp.where(valid[:, :, None], s, NEG_INF)
+        m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_i - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_i + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("bkgqt,bkth->bkgqh", p.astype(vd.dtype), vd,
+                        preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    lead = (B, KV, H // KV, T)
+    init = (jnp.full(lead + (1,), NEG_INF, jnp.float32),
+            jnp.zeros(lead + (1,), jnp.float32),
+            jnp.zeros(lead + (hd,), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(lo, hi, block, init)
+    return (acc / jnp.maximum(l, 1e-30)).reshape(B, H, T, hd).astype(q.dtype)
+
+
+# the most an XLA score array of the pattern model's uncached read may hold
+_PATTERN_SCORE_BYTES = 512 << 20
+
+
+def _gqa_attention_in_query_blocks(q, k, v, mask):
+    """`gqa_attention` (XLA) over blocks of queries once the float32 scores
+    of all of them pass `_PATTERN_SCORE_BYTES`: a window layer past its
+    window, and every layer under `"xla"`, at the thousands of tokens a
+    served row has (at 9,728 tokens one layer's scores are 10.6 GB). Each
+    block sees every key under its own rows of the mask: the same sums."""
+    B, H, T, hd = q.shape
+    S = k.shape[2]
+    if B * H * T * S * 4 <= _PATTERN_SCORE_BYTES:
+        return gqa_attention(q, k, v, mask)
+    fit = max(_PATTERN_SCORE_BYTES // (B * H * S * 4), 8)
+    bq = 1 << (fit.bit_length() - 1)
+    n = -(-T // bq)
+    pad = ((0, 0), (0, 0), (0, n * bq - T), (0, 0))   # see nothing, cut off
+    qs = jnp.moveaxis(jnp.pad(q, pad).reshape(B, H, n, bq, hd), 2, 0)
+    ms = jnp.moveaxis(jnp.pad(mask, pad).reshape(B, 1, n, bq, S), 2, 0)
+    out = jax.lax.map(lambda a: gqa_attention(a[0], k, v, a[1]), (qs, ms))
+    return jnp.moveaxis(out, 0, 2).reshape(B, H, n * bq, hd)[:, :, :T]
+
+
+def _pattern_attention(config, q, k, v, mask, kv_cache, cache_index,
+                       decode_bounds, verify_bounds, paged, layer, window,
+                       spmd):
+    """A pattern model's attention read, for one layer of one kind: the
+    kind's `mask` carries the window per query on every path; the reads that
+    go by bounds (the two decode kernels, the paged T > 1 walk) get the
+    kind's own lower bound from their caller. `(out [B, H, T, hd], the
+    kind's updated cache stacks | None)`. What a pattern model does not have
+    raises where it is asked for (`_pattern_caches`, `sampler.compose_check`):
+    an int8 cache, speculative decode."""
+    T = q.shape[2]
+    # inside the window a window layer is a causal one: the flash kernel
+    # rebuilds causal x key-valid and has no block bound for a window
+    causal = not (window and T > window)
+    if kv_cache is None:
+        if causal and use_flash(config.attention_impl, T):
+            return gqa_attention(q, k, v, mask, impl="pallas",
+                                 mask_is_causal_x_keyvalid=True, spmd=spmd), None
+        return _gqa_attention_in_query_blocks(q, k, v, mask), None
+    new_cache = _cache_write(kv_cache, (k, v), layer, cache_index, paged)
+    width = mask.shape[-1]
+    cache_len = width if paged is not None else new_cache[0].shape[3]
+
+    def views():
+        if paged is not None:
+            return tuple(_paged_view(c, layer, paged[0], width)
+                         for c in new_cache)
+        return tuple(_layer_slab(c, layer, width if width < cache_len else None)
+                     for c in new_cache)
+
+    if verify_bounds is not None and paged is not None:
+        first, fill = verify_bounds
+        out = _attend_paged_blocks(new_cache, layer, paged[0], paged[1], mask,
+                                   first, fill + (T - 1), q)
+    elif verify_bounds is not None:
+        out = gqa_attention(q, *views(), mask)
+    elif T > 1:
+        # prefill from slot 0: the tokens at hand are all there is
+        flash = causal and use_flash(config.attention_impl, T)
+        out = gqa_attention(q, k, v, mask[..., :T],
+                            impl="pallas" if flash else "xla",
+                            mask_is_causal_x_keyvalid=causal, spmd=spmd)
+    elif (decode_bounds is not None and paged is not None
+          and use_paged_decode_kernel(config)):
+        from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
+
+        out = paged_decode_attention(
+            q[:, :, 0, :], *new_cache, layer, decode_bounds)[:, :, None, :]
+    elif (decode_bounds is not None and paged is None and spmd is None
+          and use_decode_kernel(config.attention_impl, cache_len)):
+        from nanorlhf_tpu.ops.decode_attention import decode_attention
+
+        out = decode_attention(
+            q[:, :, 0, :], *(_layer_slab(c, layer) for c in new_cache),
+            *decode_bounds)[:, :, None, :]
+    else:
+        out = gqa_attention(q, *views(), mask)
+    return out, new_cache
 
 
 def _expert_xs(layers: dict, in_place: bool):
@@ -885,6 +1063,20 @@ def _layer_stacks(params: dict) -> list:
     return stacks
 
 
+def _rematerialized(config: ModelConfig, body):
+    """The scanned layer body under `jax.checkpoint`, by `remat_policy`."""
+    if config.remat_policy == "dots":
+        # keep MXU matmul outputs (no batch dims = the weight projections,
+        # not attention scores) for the backward
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    if config.remat_policy == "full":
+        return jax.checkpoint(body)
+    raise ValueError(
+        f"remat_policy={config.remat_policy!r}: must be 'full' or 'dots'")
+
+
 def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0,
                 lora_scale=1.0, remat=False, attn_fn=None, layer_transform=None,
                 decode_bounds=None, verify_bounds=None, paged=None, live=None,
@@ -904,7 +1096,16 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
     The third result is the expert stack's stacked `aux` (`_mlp`), from the
     uncached forward always and from a cached one where `cached_aux` asks
     for it (else None: the cached programs as they were).
+
+    A pattern model goes through `_run_pattern_layers`; `mask`,
+    `decode_bounds`, `verify_bounds` and `paged` are then `(global, window)`
+    pairs (`_kind_masks` and the entrypoints make them).
     """
+    if config.attention_pattern is not None:
+        return _run_pattern_layers(
+            config, params, x, cos, sin, mask, kv_caches, cache_index,
+            lora_scale, remat, attn_fn, layer_transform, decode_bounds,
+            verify_bounds, paged, live, cached_aux)
     if kv_caches is None:
         def uncached_body(expert_stack):
             def body(carry, inp):
@@ -917,22 +1118,7 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
                                         expert_stack=expert_stack)
                 return y, aux
 
-            if not remat:
-                return body
-            if config.remat_policy == "dots":
-                # keep MXU matmul outputs (no batch dims = the weight
-                # projections, not attention scores) for the backward
-                return jax.checkpoint(
-                    body,
-                    policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable,
-                )
-            if config.remat_policy == "full":
-                return jax.checkpoint(body)
-            raise ValueError(
-                f"remat_policy={config.remat_policy!r}: must be "
-                "'full' or 'dots'"
-            )
+            return _rematerialized(config, body) if remat else body
 
         aux = None
         for tree, lora_layers, _, n in _layer_stacks(params):
@@ -981,6 +1167,134 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
             )
             aux = aux if stack_aux is None else stack_aux
         return x, new_caches, aux
+
+
+def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
+                        cache_index, lora_scale, remat, attn_fn,
+                        layer_transform, decode_bounds, verify_bounds, paged,
+                        live, cached_aux):
+    """`_run_layers` for a model with an attention pattern: ONE scan over the
+    periods of `config.attention_pattern`, its body the period's layers in
+    order, each of its own static kind `(window, rotary)`. The stacked tree
+    `[L, ...]` is scanned as `[L / p, p, ...]` (a reshape of the leading
+    axis; the expert kernels stay out of the xs and are addressed in place
+    at the layer's index, `_expert_xs`). Whatever differs by kind comes as a
+    `(global, window)` pair and a layer takes its kind's: the mask (built
+    once a call, `_kind_masks`), the decode and verify bounds, the block
+    table, and the CACHE, two groups of stacks `((k, v) of the global
+    layers, (k, v) of the window layers)`, both in the carry, a layer's index
+    into its group the count of that kind's layers before it."""
+    pattern = config.attention_pattern
+    p = len(pattern)
+    n = config.num_hidden_layers // p
+    per_period = [sum(1 for w, _ in pattern if w == kind) for kind in (False, True)]
+    rank = [sum(1 for w, _ in pattern[:j] if w == pattern[j][0])
+            for j in range(p)]
+    lora = params.get("lora", {}).get("layers")
+    cached = kv_caches is not None
+    # the expert kernels always stay out of the xs: a period's slice of them
+    # is p layers' experts copied a scan step (2.3 GB at SmallThinker's
+    # widths, compiled for a described v5e, PR 34: the plain scoring path
+    # beside a served model). `ragged_dot` addresses the stack in place as
+    # the kernel does; its backward then transposes the whole stack a
+    # kernel, which full fine-tuning of such a model at real widths would
+    # have to repair (under LoRA the experts are frozen)
+    layer_xs, expert_stack = _expert_xs(params["layers"], in_place=True)
+    periods = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: a.reshape((n, p) + a.shape[1:]), tree)
+    pick = lambda pair, w: None if pair is None else pair[int(w)]  # noqa: E731
+
+    def body(carry, inp):
+        y, caches = carry
+        period_params, period_lora, i = inp
+        auxes = []
+        for j, (window, rotary) in enumerate(pattern):
+            layer_params, lora_layer = jax.tree.map(
+                lambda a: a[j], (period_params, period_lora))
+            if layer_transform is not None:
+                layer_params, lora_layer = layer_transform(layer_params,
+                                                           lora_layer)
+            w = int(window)
+            y, cache, aux = _layer_body(
+                config, y, layer_params, cos, sin, masks[w],
+                caches[w] if cached else None, cache_index, lora_layer,
+                lora_scale, attn_fn=attn_fn,
+                decode_bounds=pick(decode_bounds, w),
+                verify_bounds=pick(verify_bounds, w), paged=pick(paged, w),
+                layer=i * per_period[w] + rank[j], expert_stack=expert_stack,
+                live=live, kind=(window, rotary), expert_layer=i * p + j)
+            if cached:
+                caches = tuple(cache if g == w else c
+                               for g, c in enumerate(caches))
+            auxes.append(aux)
+        keep = not cached or cached_aux
+        return (y, caches), (jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+                             if keep else None)
+
+    if remat and not cached:
+        body = _rematerialized(config, body)
+    (x, caches), aux = jax.lax.scan(
+        body, (x, tuple(kv_caches) if cached else None),
+        (periods(layer_xs), periods(lora), jnp.arange(n, dtype=jnp.int32)))
+    if aux is not None:     # [L / p, p, ...] -> [L, ...]
+        aux = jax.tree.map(
+            lambda a: a.reshape((n * p,) + a.shape[2:]), aux)
+    return x, caches if cached else None, aux
+
+
+def _kind_masks(config: ModelConfig, mask, q_slot):
+    """`mask` [B, 1, Tq, Tk] as the layers take it: itself for a model
+    without a pattern, else `(global, window)`, the window layers' also
+    holding key slot j from query slot i unless i - window < j. `q_slot()`
+    gives [B | 1, Tq], the cache slot (or sequence index) of each query (a
+    function, so that a model without a pattern stages no op for it: its
+    programs stay the ones they were); keys count from 0 along the mask's
+    last axis. Built once a call."""
+    if config.attention_pattern is None:
+        return mask
+    if not config.sliding_window:
+        return mask, mask
+    k_slot = jnp.arange(mask.shape[-1], dtype=jnp.int32)
+    near = k_slot[None, None, None, :] > (
+        q_slot().astype(jnp.int32)[:, None, :, None] - config.sliding_window)
+    return mask, mask & near
+
+
+def _kind_bounds(config: ModelConfig, first, bound, first_query_past: int):
+    """`(first, bound)` bounds of a read by slots as the layers take them:
+    the pair itself without a pattern, else `(global, window)`, the window
+    layers' starting no earlier than `window` - 1 slots before the FIRST
+    query's slot, which is `bound + first_query_past - 1` (a decode step's
+    `bound` is one past its query: 0; a verify's is its first query: 1)."""
+    if config.attention_pattern is None:
+        return first, bound
+    if not config.sliding_window:
+        return (first, bound), (first, bound)
+    near = jnp.maximum(first, bound + first_query_past - config.sliding_window)
+    return (first, bound), (near.astype(first.dtype), bound)
+
+
+def _cache_leaf(kv_caches):
+    """The first array of a cache: `kv_caches[0]`, or the global group's K
+    stack of a pattern model's two groups."""
+    return jax.tree.leaves(kv_caches)[0]
+
+
+def _kind_paged(config: ModelConfig, page_table, page_size):
+    """`paged` as the layers take it: `(table, page_size)`, or for a pattern
+    model one such pair a kind from `page_table = (global, window)`. The
+    window layers' table is [B, nb] like the other: the ring of the row's
+    window pages laid out over its logical blocks (sampler/paged/pages.py
+    `RingPages.table`), so every read and write addresses it as any table."""
+    if page_table is None:
+        return None
+    if config.attention_pattern is None:
+        return page_table, page_size
+    if not isinstance(page_table, (tuple, list)) or len(page_table) != 2:
+        raise ValueError(
+            "a model with window layers takes page_table=(global table, "
+            "window table), one a kind of page pool (docs/SWA.md)")
+    return tuple((t, page_size) for t in page_table)
 
 
 def unembedding(config: ModelConfig, params: dict):
@@ -1055,6 +1369,7 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
+    mask = _kind_masks(config, mask, lambda: jnp.arange(T)[None, :])
     x, _, aux = _run_layers(config, params, x, cos, sin, mask,
                             lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
                             layer_transform=layer_transform)
@@ -1210,6 +1525,18 @@ def _latent_cache_shape(config: ModelConfig, rows: int, slots: int) -> tuple:
     return (config.num_hidden_layers, rows, 1, slots, config.latent_width)
 
 
+def _pattern_caches(config: ModelConfig) -> tuple:
+    """(global layers, window layers) of a pattern model: the depths of its
+    two groups of cache stacks. It has no int8 form."""
+    if config.kv_cache_quant == "int8":
+        raise NotImplementedError(
+            "kv_cache_quant='int8' on a model with window layers is not "
+            "implemented: the int8 reads take one table and have no lower "
+            "bound (docs/SWA.md)")
+    return (config.num_hidden_layers - config.window_layers,
+            config.window_layers)
+
+
 def init_kv_cache(
     config: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 ) -> tuple[jnp.ndarray, ...]:
@@ -1228,6 +1555,13 @@ def init_kv_cache(
     """
     if config.kv_lora_rank:
         return (jnp.zeros(_latent_cache_shape(config, batch, max_len), dtype),)
+    if config.attention_pattern is not None:
+        # both groups whole: correct by mask, no slot saved (the paged pool
+        # is where a window layer keeps a window's pages only)
+        return tuple(
+            tuple(jnp.zeros((n, batch, config.num_key_value_heads, max_len,
+                             config.actual_head_dim), dtype) for _ in "kv")
+            for n in _pattern_caches(config))
     shape = (
         config.num_hidden_layers,
         batch,
@@ -1269,6 +1603,20 @@ def init_paged_kv_cache(
         _latent_cache_shape(config, num_pages, page_size)    # what raises
         return tuple(jnp.zeros(shape, dtype) for shape in
                      mla.paged_cache_shapes(config, num_pages, page_size))
+    if config.attention_pattern is not None:
+        # a pool a kind, `num_pages = (global pages, window pages)`: the
+        # window layers' holds a window's pages a row, not a row's budget
+        if isinstance(num_pages, int):
+            raise NotImplementedError(
+                "a model with window layers has a page pool of two kinds "
+                "(num_pages=(global, window)), which the decode session "
+                "builds; the monolithic paged rollout (page_size > 0 with "
+                "one identity table) is not built for it: use the "
+                "contiguous cache (docs/SWA.md)")
+        return tuple(
+            tuple(jnp.zeros((n, pages, config.num_key_value_heads, page_size,
+                             config.actual_head_dim), dtype) for _ in "kv")
+            for n, pages in zip(_pattern_caches(config), num_pages))
     shape = (
         config.num_hidden_layers,
         num_pages,
@@ -1333,12 +1681,12 @@ def prefill(
     the last position is the last prompt token for every row.
     """
     B, T = input_ids.shape
-    paged = None
+    paged = _kind_paged(config, page_table, page_size)
     if page_table is not None:
-        T_max = logical_len if logical_len else page_table.shape[1] * page_size
-        paged = (page_table, page_size)
+        T_max = logical_len if logical_len else (
+            jax.tree.leaves(page_table)[0].shape[1] * page_size)
     else:
-        T_max = kv_caches[0].shape[3]
+        T_max = _cache_leaf(kv_caches).shape[3]
     attention_mask = attention_mask.astype(bool)
     position_ids = jnp.cumsum(attention_mask, axis=1) - attention_mask.astype(jnp.int32)
     x = params["embed_tokens"][jnp.where(attention_mask, input_ids, 0)].astype(
@@ -1349,6 +1697,7 @@ def prefill(
     # queries attend over cache positions [0, T); the rest of T_max is masked
     mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
     mask_full = jnp.zeros((B, 1, T, T_max), bool).at[:, :, :, :T].set(mask)
+    mask_full = _kind_masks(config, mask_full, lambda: jnp.arange(T)[None, :])
     x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask_full, kv_caches=kv_caches, cache_index=0,
         lora_scale=lora_scale, paged=paged,
@@ -1372,8 +1721,9 @@ def decode_step(
     page_size: int = 0,
     live=None,                    # [B] bool: rows whose logits the caller
                                   # uses (None: all). The paged in-place read
-                                  # skips the others; nothing else looks
-    count_experts: bool = False,  # a chip's share of an expert layer: also
+                                  # skips the others and an expert layer
+                                  # dispatches them to no expert
+    count_experts: bool = False,  # a model with expert layers: also
                                   # return the held experts its rows reach
                                   # (the live ones), summed over the layers
     extent: int | None = None,    # static: no row has a valid slot at or
@@ -1385,7 +1735,8 @@ def decode_step(
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
     over the layers."""
     B = token.shape[0]
-    paged = (page_table, page_size) if page_table is not None else None
+    paged = _kind_paged(config, page_table, page_size)
+    pattern = config.attention_pattern is not None
     if extent is not None and extent < key_mask.shape[1]:
         # the mask's width is what the XLA read goes by (`_kv_views`); the
         # cache write below addresses the full stack as ever
@@ -1399,7 +1750,8 @@ def decode_step(
     start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
     filled = jnp.broadcast_to(
         jnp.asarray(cache_index, jnp.int32) + 1, (B,))
-    bounds = (start, filled)
+    bounds = _kind_bounds(config, start, filled, 0)
+    mask = _kind_masks(config, mask, lambda: (filled - 1)[:, None])
     if config.kv_lora_rank and live is not None:
         # an MLA model's paged read walks the key blocks these bounds span
         # (core/mla.py): a row nobody listens to asks for none
@@ -1411,16 +1763,22 @@ def decode_step(
             paged_decode_plan, paged_pages_per_item,
         )
 
-        bounds = paged_decode_plan(
-            page_table, start, filled, page_size=page_size,
-            num_pages=kv_caches[0].shape[1],
-            pages_per_item=paged_pages_per_item(kv_caches[0]), live=live)
+        plan = lambda table, pool, first: paged_decode_plan(  # noqa: E731
+            table, first, filled, page_size=page_size,
+            num_pages=pool.shape[1],
+            pages_per_item=paged_pages_per_item(pool), live=live)
+        if pattern:     # one plan a kind: its table, its pool, its bound
+            bounds = tuple(plan(t, group[0], first) for t, group, (first, _)
+                           in zip(page_table, kv_caches, bounds))
+        else:
+            bounds = plan(page_table, kv_caches[0], start)
     x, new_caches, aux = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
         lora_scale=lora_scale, decode_bounds=bounds, paged=paged,
-        # (an MLA model's chip's share dispatches the live rows only, and
-        # counts the held experts they reach where the caller asks)
-        **({"live": live} if config.kv_lora_rank else {}),
+        # (an expert layer dispatches the live rows only, and counts the
+        # experts they reach where the caller asks; a dense model's layers
+        # are not told)
+        **({"live": live} if config.num_experts else {}),
         **({"cached_aux": True} if count_experts else {}),
     )
     logits = _logits(config, params, x)[:, 0, :]
@@ -1471,7 +1829,7 @@ def decode_verify(
     # the logical width is the key_mask width — equal to the slab's T_max on
     # the contiguous layout, and the only meaningful width on the paged one
     T_max = key_mask.shape[1]
-    paged = (page_table, page_size) if page_table is not None else None
+    paged = _kind_paged(config, page_table, page_size)
     key_mask = key_mask.astype(bool)
     x = params["embed_tokens"][tokens].astype(params["embed_tokens"].dtype)
     cos, sin = _rope(config, positions)
@@ -1483,10 +1841,13 @@ def decode_verify(
     # starts at its first real token) begins at its own candidates
     start = jnp.where(key_mask.any(axis=1), jnp.argmax(key_mask, axis=1),
                       fill).astype(jnp.int32)
+    fill = fill.astype(jnp.int32)
+    mask = _kind_masks(config, mask,
+                       lambda: fill[:, None] + jnp.arange(Tq)[None, :])
     x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches,
-        cache_index=fill.astype(jnp.int32), lora_scale=lora_scale,
-        verify_bounds=(start, fill.astype(jnp.int32)), paged=paged,
+        cache_index=fill, lora_scale=lora_scale,
+        verify_bounds=_kind_bounds(config, start, fill, 1), paged=paged,
     )
     if not want_logits:
         return None, new_caches

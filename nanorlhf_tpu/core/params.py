@@ -6,6 +6,8 @@ state-dict layout (both families share it — Llama just drops the q/k/v
 biases) onto our scan-friendly stacked tree (core/model.py): per-layer
 tensors are stacked along a leading [L, ...] axis and torch `nn.Linear`
 weights ([out, in]) are transposed to the x @ W layout ([in, out]).
+SmallThinker (docs/SWA.md) names its router `block_sparse_moe.primary_router`
+and its experts `block_sparse_moe.experts.{e}.{gate,up,down}` (`_expert_names`).
 OLMoE adds (docs/MOE.md): `mlp.experts.{e}.{gate,up,down}_proj.weight` ↔
 `layers.experts.{gate,up,down}_proj.kernel [L, E, in, out]`,
 `mlp.gate.weight` ↔ `layers.router.kernel [L, D, E]`, and
@@ -51,6 +53,18 @@ _NORM_KEYS = (
     ("post_attention_layernorm", "post_attention_layernorm"),
 )
 _QK_NORM_KEYS = (("q_norm", "self_attn.q_norm"), ("k_norm", "self_attn.k_norm"))
+
+
+def _expert_names(config: ModelConfig):
+    """(router, expert format) under `model.layers.{i}.`: OLMoE's
+    `mlp.gate` / `mlp.experts.{e}.{gate,up,down}_proj`, SmallThinker's
+    `block_sparse_moe.primary_router` / `block_sparse_moe.experts.{e}.
+    {gate,up,down}`."""
+    if config.model_type == "smallthinker":
+        return ("block_sparse_moe.primary_router",
+                lambda e, name: f"block_sparse_moe.experts.{e}."
+                                f"{name.removesuffix('_proj')}")
+    return "mlp.gate", lambda e, name: f"mlp.experts.{e}.{name}"
 
 
 def _layer_keys(config: ModelConfig):
@@ -188,11 +202,12 @@ def params_from_hf_state_dict(
         for ours, theirs in norm_keys
     }
     if config.num_experts:
+        router, expert = _expert_names(config)
         layers["router"] = {"kernel": cast(np.stack(
-            [sd[f"model.layers.{i}.mlp.gate.weight"].T for i in range(L)]))}
+            [sd[f"model.layers.{i}.{router}.weight"].T for i in range(L)]))}
         layers["experts"] = {
             name: {"kernel": cast(np.stack([
-                np.stack([sd[f"model.layers.{i}.mlp.experts.{e}.{name}.weight"].T
+                np.stack([sd[f"model.layers.{i}.{expert(e, name)}.weight"].T
                           for e in range(config.num_experts)])
                 for i in range(L)]))}
             for name in _MLP_KEYS
@@ -242,12 +257,13 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
         for ours, theirs in norm_keys:
             put(f"model.layers.{i}.{theirs}.weight", layers[ours][i])
         if config.num_experts:
-            put(f"model.layers.{i}.mlp.gate.weight",
+            router, expert = _expert_names(config)
+            put(f"model.layers.{i}.{router}.weight",
                 layers["router"]["kernel"][i].T)
             for name in _MLP_KEYS:
                 kernel = layers["experts"][name]["kernel"][i]
                 for e in range(config.num_experts):
-                    put(f"model.layers.{i}.mlp.experts.{e}.{name}.weight",
+                    put(f"model.layers.{i}.{expert(e, name)}.weight",
                         kernel[e].T)
         for ours, theirs in linear_keys:
             put(f"model.layers.{i}.{theirs}.weight", layers[ours]["kernel"][i].T)
@@ -314,10 +330,11 @@ def export_hf_checkpoint(
     # (sliding_window, ...) to keys we never write. Anything else falls
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
-        "qwen2", "llama", "olmoe", "axk1") else (
+        "qwen2", "llama", "olmoe", "axk1", "smallthinker") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
-            "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM"}[family]
+            "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
+            "smallthinker": "SmallThinkerForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -358,6 +375,20 @@ def export_hf_checkpoint(
         if config.experts_held:     # a chip's share is no whole checkpoint
             hf_config.update(n_routed_experts_held=config.experts_held,
                              n_routed_experts_offset=config.experts_offset)
+    elif family == "smallthinker":
+        L = config.num_hidden_layers
+        for key in ("intermediate_size", "attention_bias", "hidden_act"):
+            del hf_config[key]
+        hf_config.update(
+            moe_ffn_hidden_size=config.intermediate_size,
+            moe_num_primary_experts=config.num_experts,
+            moe_num_active_primary_experts=config.num_experts_per_tok,
+            moe_primary_router_apply_softmax=True,
+            norm_topk_prob=config.norm_topk_prob, rope_scaling=None,
+            sliding_window_size=config.sliding_window,
+            sliding_window_layout=list(config.sliding_window_layout
+                                       or (0,) * L),
+            rope_layout=list(config.rope_layout or (1,) * L))
     elif config.num_experts:
         hf_config.update(num_experts=config.num_experts,
                          num_experts_per_tok=config.num_experts_per_tok,

@@ -6,6 +6,10 @@ expert matmul, weighted combine. docs/MOE.md has the equations and the shapes.
                                          w *= routed_scale  (A.X-K1: 2.5)
     y = Σ_j w_j · W_down[e_j]( silu(h W_gate[e_j]) ⊙ (h W_up[e_j]) )
 
+SmallThinker (docs/SWA.md) differs in two places: the router reads another
+state than the experts do (`router_h`: the layer's pre-attention normed
+state), and the gate is `relu`, not `silu` (`activation`).
+
 A.X-K1's shared expert, added to every token, is core/model.py's (`_mlp`,
 span `moe.shared`): a dense SwiGLU, nothing of this module's.
 
@@ -102,7 +106,8 @@ def _grouped_matmul(rows, w, group_sizes, kernel: bool, experts: int):
 
 def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
             layer=None, kernel: bool = False, scoring: str = "softmax",
-            routed_scale: float = 1.0, held=None, live=None):
+            routed_scale: float = 1.0, held=None, live=None,
+            router_h=None, activation: str = "silu"):
     """h [..., D]; router [D, E]; gate, up [G, D, F]; down [G, F, D], G = E
     or, with `held=(G, offset)`, the chip's share of the E. With
     `layer` (a traced index) the three expert kernels are the stacks of
@@ -127,8 +132,11 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
     four times and 42.1 and 43.7 (the empty rows all fed the pad token), and
     3.6 % of spread with each repeating its last token, against 1.4 % with
     none of them dispatched (my chip runs, PR 31); a cell is admitted under
-    1.75 %.
-    Unused, XLA removes them."""
+    1.75 %. A model that holds every expert passes `held=(E, 0)` with `live`
+    for the same reason.
+    Unused, XLA removes them.
+    `router_h` [..., D], where given, is what the router reads instead of
+    `h`; `activation` is the experts' gate, "silu" or "relu"."""
     lead, D = h.shape[:-1], h.shape[-1]
     E = router.shape[-1]
     x = h.reshape(-1, D)
@@ -136,7 +144,8 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
 
     with jax.named_scope("moe.router"):
         # bf16 operands, float32 products and sums: the float32 router
-        logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+        routed = x if router_h is None else router_h.reshape(-1, D)
+        logits = jnp.dot(routed, router, preferred_element_type=jnp.float32)
         if scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits.astype(jnp.float32))
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
@@ -182,7 +191,9 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
         # a held expert gets its share of all E, not of the G held
         g = _grouped_matmul(rows, gate, group_sizes, kernel, E)
         u = _grouped_matmul(rows, up, group_sizes, kernel, E)
-        act = jax.nn.silu(g.astype(jnp.float32)).astype(rows.dtype) * u
+        gated = (jax.nn.relu(g) if activation == "relu" else
+                 jax.nn.silu(g.astype(jnp.float32)).astype(rows.dtype))
+        act = gated * u
         out = _grouped_matmul(act, down, group_sizes, kernel, E)  # [N*k, D]
         if here is not None:    # rows no group computed hold anything
             out = jnp.where(here[:, None], out, 0)

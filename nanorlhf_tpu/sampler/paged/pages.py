@@ -32,6 +32,7 @@ the trade.
 from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 class PageState(NamedTuple):
@@ -119,3 +120,78 @@ def release_row(state: PageState, row) -> Tuple[PageState, jnp.ndarray]:
         table=state.table.at[row].set(
             jnp.full((nb,), num_pages, jnp.int32)),
     ), m
+
+
+# --------------------------------------------------------------------------- #
+# the window layers' pages (docs/SWA.md "a page pool of two kinds")
+# --------------------------------------------------------------------------- #
+
+def ring_blocks(window: int, page_size: int, longest_write: int) -> int:
+    """Pages a row's RING needs so that no forward ever writes a slot one of
+    its own queries (or any later one) still sees: a forward of
+    `longest_write` tokens from slot f writes up to f + longest_write - 1
+    while its first query sees from f - window + 1; block `lb` lives in ring
+    entry `lb % n`, so the block being written must not be the home of a
+    block that far back. `window + longest_write` slots, one page for each
+    end that starts or stops inside a page."""
+    return blocks_per_row(int(window) + int(longest_write), page_size) + 2
+
+
+class RingPages:
+    """Host-side pool and table of the WINDOW layers' pages. A row claims a
+    ring of at most `ring` pages at admission and keeps it while it lives;
+    its logical block `lb` (from its first real block on) lives in page
+    `ring_pages[(lb - first) % n]`: a page behind the window is written
+    again `n` blocks later, so a window layer keeps a window's pages and
+    not a row's budget. `table` [rows, nb] int32 is that ring laid out over
+    the row's logical blocks (sentinel `num_pages` before the first, past
+    the last and where nothing is claimed), which is all the device ever
+    sees: every read and write addresses it like the global layers' table.
+    Past the row's last block the entries are the sentinel so that an
+    admission forward's trailing pad tokens (a power-of-two bucket of the
+    suffix) are dropped as the global table drops what lies past a row's
+    budget, and do not wrap onto the row's own first pages. Allocation
+    is a free list on the host (the radix tree has no part in it: it holds
+    no window state, so such a model takes no prefix hit)."""
+
+    def __init__(self, num_pages: int, rows: int, n_blocks: int, ring: int):
+        self.num_pages, self.nb, self.ring = int(num_pages), int(n_blocks), int(ring)
+        self._free = list(range(self.num_pages - 1, -1, -1))
+        self._held: list = [()] * int(rows)
+        self._first = np.zeros((rows,), np.int64)
+        self.table = np.full((rows, self.nb), self.num_pages, np.int32)
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    def claim(self, row: int, first_block: int, last_block: int) -> int:
+        """A ring for blocks `[first_block, last_block]` of `row`:
+        min(ring, that many) pages off the free list. Raises RuntimeError
+        when the pool is short, before anything changed. Returns the ring's
+        size."""
+        self.release(row)
+        n = min(self.ring, int(last_block) - int(first_block) + 1)
+        if n > len(self._free):
+            raise RuntimeError(
+                f"window page pool exhausted: {n} wanted, "
+                f"{len(self._free)} free")
+        pages = np.asarray([self._free.pop() for _ in range(n)], np.int32)
+        self._held[row], self._first[row] = tuple(int(p) for p in pages), first_block
+        blocks = np.arange(first_block, int(last_block) + 1)
+        self.table[row] = self.num_pages
+        self.table[row, first_block:int(last_block) + 1] = \
+            pages[(blocks - first_block) % n]
+        return n
+
+    def release(self, row: int) -> int:
+        held, self._held[row] = self._held[row], ()
+        self._free.extend(p for p in held if p < self.num_pages)
+        self.table[row] = self.num_pages
+        return len(held)
+
+    def reused(self, row: int, last_block: int) -> int:
+        """Times a page of `row`'s ring was written again behind the window,
+        once the row has written up to `last_block`."""
+        n = len(self._held[row])
+        return max(0, int(last_block) - int(self._first[row]) + 1 - n) if n else 0

@@ -73,7 +73,8 @@ from nanorlhf_tpu.core.model import (
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
-    PageState, alloc_row, blocks_per_row, full_table, release_row,
+    PageState, RingPages, alloc_row, blocks_per_row, full_table, release_row,
+    ring_blocks,
 )
 from nanorlhf_tpu.sampler.sampler import (
     _nucleus_candidates,
@@ -196,14 +197,14 @@ def _chunk_loop(params, config, state, table, row_params, statics):
     resident row is done (the iteration counter then stops, so it counts
     true decode dispatches).
 
-    The program of one chip of an expert-parallel group
-    (`config.experts_held`) also returns, beside the carry, the held experts
-    its LIVE rows reached (a step dispatches no other row: `moe_mlp`), summed
+    The program of a model with expert layers (`config.live_rows_dispatch`:
+    a chip's share of them or all) also returns, beside the carry, the held
+    experts its LIVE rows reached (a step dispatches no other row: `moe_mlp`), summed
     over the chunk's steps and the layers (`DecodeSession.held_experts_hit`):
     the routed kernels the steps had to read."""
     statics = dict(statics)
     sync_every = statics.pop("sync_every")
-    if config.experts_held:
+    if config.live_rows_dispatch:
         def counted(cs):
             c, s, hit = cs
             s, reached = _session_decode_body(
@@ -323,7 +324,8 @@ def _admit_one(params, config, pids, pmask, caches, row_table, key, *,
     (caches, tok0, lp0, prompt_len) with row-0 scalars."""
     logits, caches = prefill(
         params, config, pids, pmask.astype(bool), caches,
-        lora_scale=lora_scale, page_table=row_table[None, :],
+        lora_scale=lora_scale,
+        page_table=jax.tree.map(lambda t: t[None, :], row_table),
         page_size=page_size, logical_len=T_max,
     )
     tok0 = _sample_token(key, logits, temperature, top_p, greedy, top_k,
@@ -401,7 +403,8 @@ def _prefill_chunk_fwd(params, config, chunk_ids, positions, fill, key_mask,
     runs through `suffix_logits` instead."""
     _, caches = decode_verify(
         params, config, chunk_ids, positions, fill, key_mask, caches,
-        lora_scale=lora_scale, page_table=row_table[None, :],
+        lora_scale=lora_scale,
+        page_table=jax.tree.map(lambda t: t[None, :], row_table),
         page_size=page_size, want_logits=False,
     )
     return caches
@@ -512,6 +515,19 @@ class DecodeSession:
 
         self.T_max = self.Tp + self.max_tokens
         self.nb = blocks_per_row(self.T_max, self.page_size)
+        # a model with window layers (docs/SWA.md): a second pool and table
+        # for them, a ring of pages a row
+        self.window_layers = config.window_layers
+        patterned = config.attention_pattern is not None
+        if patterned and (self.spec or not self.per_row
+                          or prefix_cache is None
+                          or not getattr(prefix_cache, "enabled", False)):
+            raise NotImplementedError(
+                "a model with window layers decodes in a serving session "
+                "only (per_row=True, pages handed out by the engine's "
+                "RadixCache): speculative decode and the rollout "
+                "scheduler's device free list are not built for a page "
+                "pool of two kinds (docs/SWA.md)")
 
         self._radix = prefix_cache if (
             prefix_cache is not None
@@ -536,8 +552,23 @@ class DecodeSession:
                 table=full_table(self.rows, self.nb))
 
         from nanorlhf_tpu.core.model import init_paged_kv_cache
+        self._ring = None
+        self.num_pages_window = 0
+        if patterned:
+            # the longest forward a row's admission makes: a prefill chunk,
+            # or without chunking the whole prompt, in the power-of-two
+            # bucket `_admit_now` pads it to
+            from nanorlhf_tpu.serving.radix import bucket_len
+            self.nbw = min(self.nb, ring_blocks(
+                config.sliding_window, self.page_size,
+                bucket_len(self.prefill_chunk or self.Tp, self.T_max))
+            ) if self.window_layers else 1
+            self.num_pages_window = self.rows * self.nbw
+            self._ring = RingPages(self.num_pages_window, self.rows, self.nb,
+                                   self.nbw)
         caches0 = init_paged_kv_cache(
-            config, self.num_pages, self.page_size,
+            config, (self.num_pages, self.num_pages_window) if patterned
+            else self.num_pages, self.page_size,
             params["embed_tokens"].dtype)
         R = self.rows
         # empty carry: every row starts done; admit() installs rows
@@ -557,10 +588,22 @@ class DecodeSession:
         # (`serving/kv_bytes_per_token`: read off the pool the model's cache
         # spec gave), and whether it is MLA's latent pool
         # (`serving/latent_cache`)
-        self.kv_bytes_per_token = sum(
-            c.nbytes for c in caches0) // (self.num_pages * self.page_size)
+        pool_bytes = lambda pool, pages: sum(   # noqa: E731
+            c.nbytes for c in jax.tree.leaves(pool)) // max(
+                pages * self.page_size, 1)
+        if patterned:   # a slot inside the window is held once a kind
+            self.kv_bytes_per_token_global = pool_bytes(caches0[0],
+                                                        self.num_pages)
+            self.kv_bytes_per_token_window = pool_bytes(
+                caches0[1], self.num_pages_window)
+        else:
+            self.kv_bytes_per_token_global = pool_bytes(caches0,
+                                                        self.num_pages)
+            self.kv_bytes_per_token_window = 0
+        self.kv_bytes_per_token = (self.kv_bytes_per_token_global
+                                   + self.kv_bytes_per_token_window)
         self.latent_cache = int(bool(config.kv_lora_rank))
-        # a chip's share of an expert layer: the held experts the live rows
+        # a model with expert layers: the held experts the live rows
         # reached, summed over every decode step and layer so far
         # (`serving/held_experts_hit`; `_chunk_loop`)
         self.held_experts_hit = 0
@@ -616,6 +659,13 @@ class DecodeSession:
         self._row_live_np = np.zeros((R,), bool)
         self.attn_live_pages = 0
         self.attn_table_pages = 0
+        # slots a decode step's read touched in a layer of each kind
+        # (`serving/global_slots_read`, `serving/window_slots_read`), and
+        # window pages written again behind the window
+        self.global_slots_read = 0
+        self.window_slots_read = 0
+        self.window_pages_reused = 0
+        self._row_reused_np = np.zeros((R,), np.int64)
         self.attn_in_place = int(not self.spec
                                  and use_paged_decode_kernel(config))
 
@@ -726,6 +776,8 @@ class DecodeSession:
                     seed = self._radix.matched_continuation(
                         kelems, self.seed_window)
                 self.table_np[r] = plan.row_pages
+                if self._ring is not None:
+                    self._claim_ring(r, plan, pad_count, budget)
                 if plan.cow_src is not None:
                     self._set_pool(copy_page(self.state[3], plan.cow_src,
                                              plan.cow_dst))
@@ -781,12 +833,40 @@ class DecodeSession:
             return self._admit_now(pend, full_cold=full_cold,
                                    start_abs=start_abs)
 
+    def _claim_ring(self, r, plan, pad_count, budget):
+        """The window layers' pages of row `r`, for its real blocks from the
+        first prompt token to its budget's last slot. The radix tree holds no
+        window state, so a prefix hit would hand the row global pages whose
+        window twins nobody wrote: none can occur (`_install` inserts
+        nothing for such a model), and one that did raises here."""
+        if plan.m > 0 or plan.cow_src is not None:
+            raise NotImplementedError(
+                "a radix prefix hit on a model with window layers: the tree "
+                "holds no window pages (docs/SWA.md)")
+        last = self.Tp + (self.max_tokens if budget is None else int(budget)) - 1
+        try:
+            self._ring.claim(r, pad_count // self.page_size,
+                             min(last // self.page_size, self.nb - 1))
+        except RuntimeError:
+            self._radix.release(self.table_np[r])
+            self.table_np[r] = self.num_pages
+            raise
+        self._row_reused_np[r] = 0
+
+    def _row_table(self, r: int):
+        """Row `r`'s block table for an admission forward: the global pages,
+        with the window ring's beside them for a pattern model."""
+        if self._ring is not None:
+            return (jnp.asarray(self.table_np[r]),
+                    jnp.asarray(self._ring.table[r]))
+        return jnp.asarray(self.table_np[r])
+
     def _admit_now(self, pend: _PendingPrefill, *, full_cold: bool,
                    start_abs: int):
         """Unchunked (or final-chunk-only) admission forward + install."""
         from nanorlhf_tpu.serving.radix import bucket_len, suffix_logits
         p = pend
-        row_table = (jnp.asarray(self.table_np[p.row])
+        row_table = (self._row_table(p.row)
                      if self._radix is not None else p.row_table)
         if full_cold and not self.per_row and self.prefill_chunk == 0:
             # the pre-session cold path: one full-row prefill (pads
@@ -832,7 +912,7 @@ class DecodeSession:
 
     def _install(self, p: _PendingPrefill, t0, l0, plen):
         r = p.row
-        if self._radix is not None:
+        if self._radix is not None and self._ring is None:
             self._radix.insert(p.kelems, self.table_np[r], self.Tp)
             self._kelems[r] = p.kelems
         if self.per_row:
@@ -901,7 +981,7 @@ class DecodeSession:
                + np.arange(C, dtype=np.int32)[None])
         km = np.zeros((1, self.T_max), bool)
         km[0, p.pad_count:p.next_slot] = True
-        row_table = (jnp.asarray(self.table_np[p.row])
+        row_table = (self._row_table(p.row)
                      if self._radix is not None else p.row_table)
         self._set_pool(_prefill_chunk_fwd(
             self.params, self.config, jnp.asarray(chunk), jnp.asarray(pos),
@@ -928,6 +1008,8 @@ class DecodeSession:
         with phase("dispatch"):
             table_dev = (jnp.asarray(self.table_np)
                          if self._radix is not None else self._pstate.table)
+            if self._ring is not None:
+                table_dev = (table_dev, jnp.asarray(self._ring.table))
             if self.spec:
                 if self.seed_window:
                     self.state = _spec_chunk_seeded(
@@ -973,10 +1055,10 @@ class DecodeSession:
         return done_h, installed
 
     def _carry_of(self, result):
-        """A decode chunk's result, for the carry's place: a chip's share of
-        an expert layer hands its count back beside it (`_chunk_loop`), and
+        """A decode chunk's result, for the carry's place: a model with
+        expert layers hands its count back beside it (`_chunk_loop`), and
         the count stays on the device until the beat's sync."""
-        if self.config.experts_held:
+        if self.config.live_rows_dispatch:
             result, self._hit_dev = result
         return result
 
@@ -996,12 +1078,24 @@ class DecodeSession:
         steps = np.where(self._row_live_np,
                          np.minimum(its, limit - self._row_gen_np), 0)
         first = self._row_start_np // self.page_size
+        window = self.config.sliding_window if self.window_layers else 0
         for s in range(its):
-            last = (self.Tp + self._row_gen_np + s - 1) // self.page_size
+            slot = self.Tp + self._row_gen_np + s - 1
+            last = slot // self.page_size
             self.attn_live_pages += int(
                 np.sum(np.where(steps > s, last - first + 1, 0)))
+            span = np.where(steps > s, slot - self._row_start_np + 1, 0)
+            self.global_slots_read += int(span.sum())
+            if window:
+                self.window_slots_read += int(np.minimum(span, window).sum())
         self.attn_table_pages += its * self.rows * self.nb
         self._row_gen_np += steps
+        if self._ring is not None:
+            for r in np.flatnonzero(self._row_live_np):
+                reused = self._ring.reused(
+                    r, (self.Tp + self._row_gen_np[r] - 2) // self.page_size)
+                self.window_pages_reused += reused - self._row_reused_np[r]
+                self._row_reused_np[r] = reused
         self._row_live_np &= ~done_h
 
     # ------------------------------------------------------------- #
@@ -1047,6 +1141,8 @@ class DecodeSession:
             freed = self._radix.release(self.table_np[r])
             self.table_np[r] = self.num_pages
             self._kelems[r] = None
+            if self._ring is not None:
+                self._ring.release(r)
             return freed
         self._pstate, m = _release_jit(self._pstate, r)
         return int(m)

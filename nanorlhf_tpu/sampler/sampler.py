@@ -167,7 +167,7 @@ class SamplingParams:
 
 
 def compose_check(sampling: SamplingParams, *,
-                  prefix_cache: bool = False) -> None:
+                  prefix_cache: bool = False, config=None) -> None:
     """THE decode-feature composition gate: raises ValueError on every
     remaining-illegal combination, with the reason. Every entry point that
     assembles decode features (generate() below, the trainer's config
@@ -197,9 +197,22 @@ def compose_check(sampling: SamplingParams, *,
         long admission; the monolithic paths have neither residents nor
         admissions.
 
+      * a model with window layers (`config.attention_pattern`,
+        docs/SWA.md) with spec_k > 0 or page_size > 0 — its page pool of
+        two kinds is built by the serving session only, and the verify
+        kernels have no window; rollouts take the contiguous cache, where
+        the window is a mask.
+
     Per-row serving constraints (spec requires static greedy, no logprob
     capture) are enforced by DecodeSession's constructor — they depend on
     the per_row flag the engine sets, not on SamplingParams."""
+    if config is not None and config.attention_pattern is not None and (
+            sampling.spec_k > 0 or sampling.page_size > 0):
+        raise NotImplementedError(
+            "a model with window layers (docs/SWA.md) rolls out on the "
+            "contiguous cache only: speculative decode (spec_k > 0) and the "
+            "paged rollout paths (page_size > 0) are not built for a page "
+            "pool of two kinds; the serving session is")
     if sampling.page_size > 0 and sampling.compaction_segments > 0:
         raise ValueError(
             "page_size > 0 is incompatible with compaction_segments > 0: "
@@ -695,7 +708,7 @@ def generate(
     path)."""
     compose_check(sampling, prefix_cache=(
         prefix_cache is not None
-        and getattr(prefix_cache, "enabled", False)))
+        and getattr(prefix_cache, "enabled", False)), config=config)
     total_rows = prompt_ids.shape[0] * sampling.n
     queued = (sampling.page_size > 0 and sampling.decode_rows > 0
               and sampling.decode_rows < total_rows)

@@ -437,6 +437,18 @@ class ServingEngine:
             "serving/pool_donated": self._sess.pool_donated,
             "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
             "serving/latent_cache": self._sess.latent_cache,
+            # a page pool of two kinds (docs/SWA.md): 0 window layers and an
+            # empty window pool for every model without them
+            "serving/window_layers": self._sess.window_layers,
+            "serving/kv_bytes_per_token_global":
+                self._sess.kv_bytes_per_token_global,
+            "serving/kv_bytes_per_token_window":
+                self._sess.kv_bytes_per_token_window,
+            "serving/pool_pages_global": self._sess.num_pages,
+            "serving/pool_pages_window": self._sess.num_pages_window,
+            "serving/window_pages_reused": self._sess.window_pages_reused,
+            "serving/global_slots_read": self._sess.global_slots_read,
+            "serving/window_slots_read": self._sess.window_slots_read,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
             "pages/shared": snap["shared_pages"],

@@ -530,7 +530,8 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
     suffix lengths (`bucket_len`) so retraces stay logarithmic."""
     logits, caches = decode_verify(
         params, config, suffix_ids, positions, fill, key_mask, caches,
-        lora_scale=lora_scale, page_table=row_table[None, :],
+        lora_scale=lora_scale,
+        page_table=jax.tree.map(lambda t: t[None, :], row_table),
         page_size=page_size,
     )
     return jnp.take(logits[0], last, axis=0), caches

@@ -739,7 +739,8 @@ class RLTrainer:
                 decode_rows=config.rollout_decode_rows,
                 spec_k=config.rollout_spec_k,
                 prefill_chunk=config.rollout_prefill_chunk),
-            prefix_cache=config.rollout_prefix_cache)
+            prefix_cache=config.rollout_prefix_cache,
+            config=self.mcfg)
         self.prefix_cache = None
         if config.rollout_prefix_cache:
             from nanorlhf_tpu.serving.radix import RadixCache
@@ -868,6 +869,12 @@ class RLTrainer:
                 "kv_cache_quant='int8' with a latent-attention model: the "
                 "cache is one latent a token, which has no int8 form "
                 "(core/model._latent_cache_shape, docs/MLA.md)")
+        if (config.kv_cache_quant == "int8"
+                and self.mcfg.attention_pattern is not None):
+            raise NotImplementedError(
+                "kv_cache_quant='int8' with a model with window layers: the "
+                "int8 reads have no window bound (core/model._pattern_caches, "
+                "docs/SWA.md)")
         import dataclasses as _dc
 
         self._rollout_mcfg = (

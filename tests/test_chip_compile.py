@@ -516,6 +516,94 @@ def test_axk1_session_programs_keep_the_latent_pool_in_place_on_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_chunk"])
+def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 34, asked of the chip's compiler at the
+    `serve-smallthinker-longshort` cell's shapes (SmallThinker's published
+    widths, one period of four layers; 32 rows of 16,384 slots, pages of
+    128, a ring of 42 window pages a row): the session's decode chunk and
+    its 1,024-token KV-only prefill chunk alias all FOUR leaves of the page
+    pool of two kinds (global `bf16[1,4224,4,128,128]` x (k, v), window
+    `bf16[3,1344,4,128,128]` x (k, v)) from their parameters to their
+    results and leave no `copy` of a leaf in the module. The decode chunk
+    reads them through the in-place kernel once a layer, named by its
+    kind's scope (`%attn.global*`, `%attn.window*`: what
+    benchmark/harness/attn_trace.py finds in the device trace); the expert
+    matmuls are the grouped-matmul kernel."""
+    import dataclasses
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.sampler.paged.pages import ring_blocks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(
+        ModelConfig.smallthinker_21b(), num_hidden_layers=4,
+        sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1))
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = 32, 14336, 2048, 1024
+    nb = (Tp + new) // PAGE
+    ring = ring_blocks(cfg.sliding_window, PAGE, chunk)
+    pages = (R * nb + nb, R * ring)
+    assert (nb, ring, pages) == (128, 42, (4224, 1344))
+    cache = jax.eval_shape(
+        lambda: M.init_paged_kv_cache(cfg, pages, PAGE, jnp.bfloat16))
+    pools = hlo_stacks(jax.tree.leaves(cache))
+    assert set(pools) == {("bf16", (1, 4224, 4, PAGE, 128)),
+                          ("bf16", (3, 1344, 4, PAGE, 128))}
+    tables = (spec((R, nb), jnp.int32),) * 2
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, tables, spec((R,), jnp.float32),
+            spec((R,), jnp.float32), spec((R,), jnp.bool_),
+            spec((R,), jnp.int32), Tp=Tp, max_tokens=new, page_size=PAGE,
+            sync_every=4, eos_token_id=1, pad_token_id=0, temperature=1.0,
+            top_p=1.0, greedy=False, lora_scale=1.0, top_k=64,
+            capture_logprobs=False, approx_top_k=True)
+    else:
+        lowered = session._prefill_chunk_fwd.lower(
+            params, cfg, spec((1, chunk), jnp.int32), spec((1, chunk), jnp.int32),
+            spec((1,), jnp.int32), spec((1, Tp + new), jnp.bool_),
+            _shapes_on(cache, one_chip), (spec((nb,), jnp.int32),) * 2,
+            page_size=PAGE, lora_scale=1.0)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo, re.M).group(1)
+    leaves = {int(rest.split(")")[0]) for _, result, op, rest in comps[entry]
+              if op == "parameter" and _shapes(result)[:1]
+              and _shapes(result)[0] in pools}
+    assert len(leaves) == 4 and leaves <= aliased, (leaves, aliased)
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(pools)]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    if case == "decode_chunk":
+        kinds = re.findall(r"%attn\.(global|window)[\w.]* = \S+ custom-call\(", hlo)
+        assert kinds.count("global") == 1 and kinds.count("window") == 3, kinds
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_chip_smoke_refuses_a_cpu_backend():
     """No accelerator → non-zero exit before any phase, and no result line."""
     out = subprocess.run(
