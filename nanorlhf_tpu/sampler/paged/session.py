@@ -60,6 +60,7 @@ baseline — docs/DECODE_ANALYSIS.md walks the arithmetic.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Optional
@@ -255,6 +256,22 @@ def _serving_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
                        (r_temp, r_topp, r_greedy, r_budget), statics)
 
 
+@partial(jax.jit, static_argnames=("width",))
+def _beat_report(it, out, done, n_gen, hit, *, width):
+    """What the host reads of a serving beat, as two small arrays of their
+    own: `[it, hit]` and, a row, `done · n_gen · the row's last `width`
+    tokens` (right-aligned at `n_gen`: a chunk writes at most `width` a row,
+    so the new ones are among them). Enqueued behind the chunk over its
+    carry and donating nothing, so the NEXT chunk may consume that carry
+    while the host still waits for these (`DecodeSession.dispatch`)."""
+    cols = n_gen[:, None] - width + jnp.arange(width, dtype=jnp.int32)[None]
+    toks = jnp.take_along_axis(
+        out, jnp.clip(cols, 0, out.shape[1] - 1), axis=1)
+    rows = jnp.concatenate(
+        [done[:, None].astype(jnp.int32), n_gen[:, None], toks], axis=1)
+    return jnp.stack([it, hit]), rows
+
+
 _SPEC_CHUNK_STATIC = _CHUNK_STATIC + ("spec_k", "spec_ngram")
 
 
@@ -380,6 +397,12 @@ _release_jit = jax.jit(release_row)
 _alloc_jit = jax.jit(alloc_row)
 
 
+@jax.jit
+def _end_row(done, r):
+    """Row `r`'s done flag forced (`DecodeSession.cancel_row`)."""
+    return done.at[r].set(True)
+
+
 @partial(jax.jit, static_argnames=("temperature", "top_p", "greedy", "top_k",
                                    "approx_top_k"))
 def _admit_sample(logits, key, *, temperature, top_p, greedy, top_k,
@@ -416,6 +439,7 @@ class _PendingPrefill:
     carry row is parked done=True; `next_slot` advances one chunk per
     session step until the final chunk installs the row."""
     row: int
+    index: int                    # the admission's `admit_index`
     toks: np.ndarray              # [Tp] left-padded
     mask: np.ndarray              # [Tp] bool
     pad_count: int
@@ -433,14 +457,63 @@ class _PendingPrefill:
     meta: dict = field(default_factory=dict)
 
 
+@dataclass
+class FirstToken:
+    """An admission's first token once the host has it (`DecodeSession.read`):
+    the row, the admission's `admit_index`, the token."""
+    row: int
+    index: int
+    token: int
+
+
+@dataclass
+class BeatReport:
+    """One decode chunk as the host read it (`DecodeSession.read`). `done`
+    is the rows' flags after the chunk and `its` the iterations it ran. A
+    serving-mode session also gives `n_gen` (tokens a row has so far),
+    `tokens` (each row's last `width`, right-aligned at `n_gen`) and
+    `current`: the rows that still hold the request the chunk ran for. A
+    report read one beat late (`dispatch`) says nothing of a row that was
+    released, cancelled or admitted into since its chunk was dispatched."""
+    its: int
+    done: np.ndarray
+    n_gen: Optional[np.ndarray] = None
+    tokens: Optional[np.ndarray] = None
+    current: Optional[np.ndarray] = None
+
+    def new_tokens(self, r: int, since: int) -> np.ndarray:
+        """Row `r`'s tokens from its `since`-th on."""
+        new = int(self.n_gen[r]) - int(since)
+        return self.tokens[r, self.tokens.shape[1] - new:]
+
+
+@dataclass
+class _First:
+    """An installed admission whose first token is still on the device."""
+    pend: _PendingPrefill
+    tok: jax.Array
+
+
+@dataclass
+class _Flight:
+    """A dispatched chunk the host has not read: when it was dispatched, who
+    held each row then, and (serving mode) its `_beat_report` on the device."""
+    t0: float
+    occupants: np.ndarray
+    hit: Optional[jax.Array] = None
+    meta: Optional[jax.Array] = None
+    rows: Optional[jax.Array] = None
+
+
 class DecodeSession:
     """One resident decode batch with uniform per-row state.
 
     Owns the carry, the page table (radix-refcounted or device
     free-stack), the speculative draft seeds, the chunked-prefill
     backlog, and the latency-hub recording; exposes
-    `admit` / `bootstrap` / `step` / `release` / `cancel_row` to the two
-    drivers (rollout scheduler, serving engine). Modes:
+    `admit` / `bootstrap` / `step` (or its halves `dispatch` / `read`) /
+    `release` / `cancel_row` to the two drivers (rollout scheduler, serving
+    engine). Modes:
 
       * `per_row=False` (rollout): static sampling params, every row
         shares `max_tokens`; spec decode composes (`spec_k > 0`), with
@@ -463,6 +536,36 @@ class DecodeSession:
     that raises leaves a consumed pool in the carry: the next use raises
     "Array has been deleted", the session is finished and nothing decodes
     on freed pages (docs/SERVING.md "Who owns the page pool").
+
+    A beat has two halves. `dispatch()` enqueues at most one pending
+    prefill piece and one decode chunk and returns; `read()` waits for the
+    oldest thing the device still owes the host, in the device's own order:
+    an admission's first token (`FirstToken`; a serving-mode `_install`
+    leaves it on the device) or a chunk's `BeatReport`. `step()` is the
+    serial beat, a dispatch and then every read, and is what the rollout
+    scheduler and a speculative engine run: they need `done` to refill or
+    verify before their next chunk. A serving-mode session without
+    speculation `looks_ahead`: its driver dispatches chunk k+1 BEFORE it
+    reads chunk k, so the device goes from one chunk into the next while
+    the host waits, streams, releases and admits (docs/SERVING.md "the
+    beat"). What makes that sound:
+
+      * what the host reads of a chunk is `_beat_report`'s two small arrays,
+        not the carry, which chunk k+1 consumes; the tables and the per-row
+        parameters a chunk is given are copies taken at its dispatch
+        (`jnp.array`: `jnp.asarray` may alias the host's arrays, which the
+        host goes on changing under the flight);
+      * everything enqueued after the chunk in flight runs after it. A row
+        the host releases on report k is DONE in chunk k+1: a done row only
+        writes the K/V of its last token again, the same values into its own
+        slot, and drops its `out` write (`_session_decode_body`), so the
+        pages may be handed on under the flight, and the admission forward
+        that writes them next queues behind the chunk. A row cancelled while
+        live decodes to the flight's end into pages nobody reads before
+        their next owner's forward, which queues behind it as well;
+      * a report read late is held to the rows whose occupant has not
+        changed since its dispatch (`BeatReport.current`), and a first token
+        is always read before the report of the first chunk its row ran in.
 
     The session NEVER resets an attached `prefix_cache` implicitly at
     step time — it resets it exactly once at construction (the rollout
@@ -581,6 +684,10 @@ class DecodeSession:
             jnp.ones((R,), bool),
             jnp.zeros((R,), jnp.int32),
             jnp.zeros((R,), jnp.int32))
+        if self.per_row:
+            # a cancel's one program, compiled now: no warm-up can make a
+            # client vanish, and the first that does must compile nothing
+            _end_row(self.state[5], 0)
         # 1 when the session's programs consume the pool they are given
         # (`serving/pool_donated`); one rule for all of them
         self.pool_donated = int(_decode_chunk.donates(caches0))
@@ -607,10 +714,28 @@ class DecodeSession:
         # reached, summed over every decode step and layer so far
         # (`serving/held_experts_hit`; `_chunk_loop`)
         self.held_experts_hit = 0
-        self._hit_dev = None
         # the host's record of the carry, as of the last sync and the
         # admissions and cancels since: what other threads may read
         self._done_np = np.ones((R,), bool)
+        # what the device owes the host, in the device's own order: first
+        # tokens (`_First`) and chunks (`_Flight`); `read()` takes the oldest
+        self._unread: deque = deque()
+        # counts a row's changes of occupant (install, release, cancel): a
+        # flight keeps the counts of its dispatch, and its report speaks for
+        # the rows where they still stand
+        self._occupant_np = np.zeros((R,), np.int64)
+        # a serving-mode report carries the tokens a chunk can have written a
+        # row: one an iteration, or a whole row's where an iteration emits
+        # several (speculation; its release wants the whole stream too)
+        self._report_width = (self.max_tokens if self.spec
+                              else min(int(sync_every), self.max_tokens))
+        self._no_hit = jnp.int32(0)
+        self._t_report = 0.0
+        # beats dispatched while an earlier chunk's report was unread, and
+        # first tokens the host read after their admission had returned
+        # (`serving/beats_overlapped`, `serving/first_tokens_deferred`)
+        self.beats_overlapped = 0
+        self.first_tokens_deferred = 0
 
         self._sample_kw = dict(temperature=temperature, top_p=top_p,
                                greedy=greedy, top_k=top_k,
@@ -752,11 +877,13 @@ class DecodeSession:
         Radix mode may raise RuntimeError (pool exhausted even after
         eviction) BEFORE any row state changes — the engine sheds on it.
 
-        Returns the first token as a host int in per-row mode (the
-        engine streams it immediately), None in rollout mode (no forced
-        device sync), and None for a chunked admission in either mode
-        (the first token lands when the final chunk installs the row —
-        drivers must treat `is_pending(r)` rows as not-yet-done)."""
+        Per-row mode waits for nothing: it returns the first token as it
+        stands ON THE DEVICE, and `read()` hands it to the driver as a host
+        int (`FirstToken`) in its turn. Rollout mode returns None (it waits
+        for the token only to time it, with a latency hub attached), and so
+        does a chunked admission in either mode (the
+        first token lands when the final chunk installs the row — drivers
+        must treat `is_pending(r)` rows as not-yet-done)."""
         toks_np = np.asarray(toks_np, np.int32)
         mask_np = np.asarray(mask_np, bool)
         t0 = time.perf_counter() if t_start is None else t_start
@@ -802,7 +929,8 @@ class DecodeSession:
             row_table_np = self._pstate.table[r]
 
         pend = _PendingPrefill(
-            row=r, toks=toks_np, mask=mask_np, pad_count=pad_count,
+            row=r, index=int(admit_index), toks=toks_np, mask=mask_np,
+            pad_count=pad_count,
             next_slot=0, admit_key=a_key, t_start=t0, kelems=kelems,
             plan_hit=(plan.hit_tokens if plan is not None else 0),
             seed=seed, budget=budget,
@@ -857,9 +985,9 @@ class DecodeSession:
         """Row `r`'s block table for an admission forward: the global pages,
         with the window ring's beside them for a pattern model."""
         if self._ring is not None:
-            return (jnp.asarray(self.table_np[r]),
-                    jnp.asarray(self._ring.table[r]))
-        return jnp.asarray(self.table_np[r])
+            return (jnp.array(self.table_np[r]),
+                    jnp.array(self._ring.table[r]))
+        return jnp.array(self.table_np[r])
 
     def _admit_now(self, pend: _PendingPrefill, *, full_cold: bool,
                    start_abs: int):
@@ -935,12 +1063,10 @@ class DecodeSession:
                 self._seed_len_np[r] = n
                 self._seed_rep = jnp.asarray(self._seed_np)
                 self._seed_len = jnp.asarray(self._seed_len_np)
-        if self._hub is not None or self.per_row:
+        if self._hub is not None and not self.per_row:
             # t0 is the admission forward's sampled first token: blocking
-            # on it gives this request's true TTFT (and the engine needs
-            # the host int to stream it)
+            # on it gives this request's true TTFT
             jax.block_until_ready(t0)
-        if self._hub is not None:
             self._hub.record("latency/ttft_s",
                              time.perf_counter() - p.t_start)
         self.state = _with_pool(_install_row(
@@ -949,12 +1075,16 @@ class DecodeSession:
             Tp=self.Tp, max_tokens=self.max_tokens,
             eos_token_id=self.eos_token_id, pad_token_id=self.pad_token_id,
             spec=self.spec, per_row=self.per_row), self.state[3])
-        tok0 = int(t0) if self.per_row else None
-        # rollout mode does not wait for its first token: live until the
-        # next sync says otherwise
-        self._done_np[r] = self.per_row and (
-            tok0 == self.eos_token_id or int(p.budget) <= 1)
-        return tok0
+        self._occupant_np[r] += 1
+        # live until a read says otherwise (a budget of one token is spent
+        # already)
+        self._done_np[r] = self.per_row and int(p.budget) <= 1
+        if not self.per_row:
+            return None
+        # serving: the token stays on the device, queued for `read()` behind
+        # whatever was dispatched before this admission
+        self._unread.append(_First(p, t0))
+        return t0
 
     # ------------------------------------------------------------- #
     # stepping
@@ -972,10 +1102,9 @@ class DecodeSession:
         C = self.prefill_chunk
         if remaining <= C:
             self._pending.pop(0)
-            tok0 = self._admit_now(p, full_cold=p.meta.get("full_cold",
-                                                           False),
-                                   start_abs=p.next_slot)
-            return (p.row, tok0)
+            self._admit_now(p, full_cold=p.meta.get("full_cold", False),
+                            start_abs=p.next_slot)
+            return (p.row, None)
         chunk = p.toks[p.next_slot:p.next_slot + C][None, :]
         pos = ((p.next_slot - p.pad_count)
                + np.arange(C, dtype=np.int32)[None])
@@ -993,90 +1122,156 @@ class DecodeSession:
         self.dispatch_tokens += C
         return None
 
+    @property
+    def looks_ahead(self) -> bool:
+        """Whether the driver may dispatch the next chunk before it has read
+        this one: what the session is, not an option. A serving-mode
+        session reads only reports; the rollout scheduler reads `done` off
+        the carry to refill, and speculation verifies against it, before
+        their next chunk."""
+        return self.per_row and not self.spec
+
+    def unread(self) -> int:
+        """First tokens and chunks the device still owes the host."""
+        return len(self._unread)
+
     def step(self):
-        """One scheduler beat: at most one pending-prefill chunk, then
-        one decode (or draft+verify) chunk of up to `sync_every`
-        iterations. Returns (done_h, installed) — the host done flags
-        and the (row, first_token_or_None) of an admission whose final
-        chunk landed this beat, if any."""
+        """One serial beat: `dispatch()`, then every `read()`. Returns
+        (done_h, installed) — the host done flags and the (row, None) of an
+        admission whose final chunk landed this beat, if any."""
+        installed = self.dispatch()
+        while self._unread:
+            got = self.read()       # first tokens, then the chunk's report
+        return got.done, installed
+
+    def dispatch(self):
+        """The device's half of a beat, enqueued and not waited for: at most
+        one pending-prefill chunk, then one decode (or draft+verify) chunk
+        of up to `sync_every` iterations and, in serving mode, the report
+        the host will read of it. Returns the (row, None) of an admission
+        whose final chunk this beat installed, if any."""
         installed = None
         phase = self.timer.phase
         if self._pending:
             with phase("prefill_tick"):
                 installed = self._prefill_tick()
-        t0 = time.perf_counter()
+        flight = _Flight(time.perf_counter(), self._occupant_np.copy())
         with phase("dispatch"):
-            table_dev = (jnp.asarray(self.table_np)
+            if any(isinstance(u, _Flight) for u in self._unread):
+                self.beats_overlapped += 1
+            # copies (class docstring): the host changes these under a
+            # flight
+            table_dev = (jnp.array(self.table_np)
                          if self._radix is not None else self._pstate.table)
             if self._ring is not None:
-                table_dev = (table_dev, jnp.asarray(self._ring.table))
+                table_dev = (table_dev, jnp.array(self._ring.table))
             if self.spec:
                 if self.seed_window:
-                    self.state = _spec_chunk_seeded(
+                    result = _spec_chunk_seeded(
                         self.params, self.config, self.state, table_dev,
                         self._prompt_rep, self._seed_rep, self._seed_len,
                         **self._statics)
                 else:
-                    self.state = _spec_chunk(
+                    result = _spec_chunk(
                         self.params, self.config, self.state, table_dev,
                         self._prompt_rep, **self._statics)
             elif self.per_row:
-                self.state = self._carry_of(_serving_chunk(
+                result = _serving_chunk(
                     self.params, self.config, self.state, table_dev,
-                    jnp.asarray(self._temp_np), jnp.asarray(self._topp_np),
-                    jnp.asarray(self._greedy_np),
-                    jnp.asarray(self._budget_np), **self._statics))
+                    jnp.array(self._temp_np), jnp.array(self._topp_np),
+                    jnp.array(self._greedy_np), jnp.array(self._budget_np),
+                    **self._statics)
             else:
-                self.state = self._carry_of(_decode_chunk(
+                result = _decode_chunk(
                     self.params, self.config, self.state, table_dev,
-                    **self._statics))
-        with phase("sync"):     # the host waits for the device here
-            done_h = np.asarray(self.state[5])
-            it_now = int(self.state[0]) - 1
-            if self._hit_dev is not None:
-                self.held_experts_hit += int(self._hit_dev)
-                self._hit_dev = None
-        if self._hub is not None:
-            # done_h forced the device sync, so the chunk's wall time is
-            # fully realised here; one mean inter-token gap per sync
-            # chunk. The serving driver only records when the counter
-            # advanced (its loop also spins on admission-only beats).
-            if not self.per_row:
-                self._hub.record("latency/intertoken_s",
-                                 (time.perf_counter() - t0)
-                                 / max(1, it_now - self._it_prev))
-            elif it_now > self._it_prev:
-                self._hub.record("latency/intertoken_s",
-                                 (time.perf_counter() - t0)
-                                 / (it_now - self._it_prev))
-        self._count_attention(it_now - self._it_prev, done_h)
+                    **self._statics)
+            # a model with expert layers hands its count back beside the
+            # carry (`_chunk_loop`); it stays on the device until the read
+            if self.config.live_rows_dispatch and not self.spec:
+                result, flight.hit = result
+            self.state = s = result
+            if self.per_row:
+                flight.meta, flight.rows = _beat_report(
+                    s[0], s[1], s[5], s[7],
+                    self._no_hit if flight.hit is None else flight.hit,
+                    width=self._report_width)
+                flight.meta.copy_to_host_async()
+                flight.rows.copy_to_host_async()
+            self._unread.append(flight)
+        return installed
+
+    def read(self):
+        """Wait for the oldest thing the device owes the host and bring the
+        host's records up to it: a `FirstToken`, or a chunk's `BeatReport`
+        (the counters of a beat all advance here, together)."""
+        item = self._unread.popleft()
+        sync = self.timer.phase("sync")     # the host waits for the device
+        if isinstance(item, _First):
+            with sync:
+                tok = int(item.tok)
+            p = item.pend
+            self.first_tokens_deferred += 1
+            if self._hub is not None:       # TTFT: the host has the token
+                self._hub.record("latency/ttft_s",
+                                 time.perf_counter() - p.t_start)
+            return FirstToken(p.row, p.index, tok)
+        with sync:
+            if item.rows is None:       # rollout mode reads the carry itself
+                done_h = np.asarray(self.state[5])
+                it_now = int(self.state[0])
+                hit = 0 if item.hit is None else int(item.hit)
+            else:
+                (it_now, hit), rows = np.asarray(item.meta), np.asarray(
+                    item.rows)
+        now = time.perf_counter()
+        it_now = int(it_now) - 1
+        its = it_now - self._it_prev
+        # one mean inter-token gap a chunk: from the report before this one
+        # (or this chunk's dispatch, if that came later) to this report.
+        # The serving driver only records when the counter advanced (its
+        # loop also spins on admission-only beats).
+        since = max(item.t0, self._t_report)
+        self._t_report = now
+        if self._hub is not None and (its > 0 or not self.per_row):
+            self._hub.record("latency/intertoken_s",
+                             (now - since) / max(1, its))
+        self.held_experts_hit += int(hit)
+        if item.rows is None:
+            self._count_attention(its, done_h)
+            report = BeatReport(its, done_h)
+            np.copyto(self._done_np, done_h)
+        else:
+            current = item.occupants == self._occupant_np
+            report = BeatReport(its, rows[:, 0].astype(bool), rows[:, 1],
+                                rows[:, 2:], current)
+            self._count_attention(its, report.done, report.n_gen, current)
+            self._done_np[current] = report.done[current]
         self._it_prev = it_now
-        np.copyto(self._done_np, done_h)
-        return done_h, installed
+        return report
 
-    def _carry_of(self, result):
-        """A decode chunk's result, for the carry's place: a model with
-        expert layers hands its count back beside it (`_chunk_loop`), and
-        the count stays on the device until the beat's sync."""
-        if self.config.live_rows_dispatch:
-            result, self._hit_dev = result
-        return result
-
-    def _count_attention(self, its: int, done_h) -> None:
+    def _count_attention(self, its: int, done_h, n_gen=None,
+                         current=None) -> None:
         """Never-reset `attn_live_pages` / `attn_table_pages`, summed over
-        the `its` decode steps of the chunk just synced: the pages the
+        the `its` decode steps of the chunk just read: the pages the
         in-place read touches (a live row's blocks from its first real slot
         to the slot it writes) against the `rows x blocks` the gathered view
         builds whatever is live; `live / table` is the share of the view the
-        kernel has to read. From the host's own record of each row (pad
-        count, tokens so far, budget); a row that ends on EOS inside a chunk
+        kernel has to read. A serving-mode report says how far each row got
+        (`n_gen`), and the count is held to the rows it still speaks for
+        (`current`: a row cancelled under the flight is not counted for it).
+        Rollout mode counts from the host's own record of each row (pad
+        count, tokens so far, budget): a row that ends on EOS inside a chunk
         is counted to its budget or the chunk's end. Speculative sessions
         verify, they take no single-token step: not counted."""
         if self.spec or its <= 0:
             return
-        limit = self._budget_np if self.per_row else self.max_tokens
-        steps = np.where(self._row_live_np,
-                         np.minimum(its, limit - self._row_gen_np), 0)
+        if n_gen is None:
+            live = self._row_live_np
+            steps = np.where(live, np.minimum(
+                its, self.max_tokens - self._row_gen_np), 0)
+        else:
+            live = self._row_live_np & current
+            steps = np.where(live, n_gen - self._row_gen_np, 0)
         first = self._row_start_np // self.page_size
         window = self.config.sliding_window if self.window_layers else 0
         for s in range(its):
@@ -1091,12 +1286,12 @@ class DecodeSession:
         self.attn_table_pages += its * self.rows * self.nb
         self._row_gen_np += steps
         if self._ring is not None:
-            for r in np.flatnonzero(self._row_live_np):
+            for r in np.flatnonzero(live):
                 reused = self._ring.reused(
                     r, (self.Tp + self._row_gen_np[r] - 2) // self.page_size)
                 self.window_pages_reused += reused - self._row_reused_np[r]
                 self._row_reused_np[r] = reused
-        self._row_live_np &= ~done_h
+        self._row_live_np[live] &= ~done_h[live]
 
     # ------------------------------------------------------------- #
     # release / introspection
@@ -1104,7 +1299,7 @@ class DecodeSession:
 
     def iterations(self) -> int:
         """Decode/verify iterations so far: the carry's own counter as
-        `step()` last brought it to the host (only chunks advance it)."""
+        `read()` last brought it to the host (only chunks advance it)."""
         return self._it_prev
 
     def dispatch_events(self) -> int:
@@ -1132,6 +1327,7 @@ class DecodeSession:
         continuation is appended to the radix tree as TEXT-ONLY nodes
         (`RadixCache.extend_text`) so the next overlapping admission can
         seed its n-gram window from it. Returns pages freed."""
+        self._occupant_np[r] += 1
         if self._radix is not None:
             if (self.seed_window and gen_tokens is not None
                     and self._kelems[r] is not None):
@@ -1149,14 +1345,15 @@ class DecodeSession:
 
     def cancel_row(self, r: int) -> None:
         """Serving-side reap: drop any pending chunked admission for the
-        row, force its done flag (the jitted chunk then skips it), and
-        free its pages — mirrors the completion path exactly so a
-        disconnect can never leak what a completion would have freed."""
+        row, force its done flag (the next chunk then skips it; one in
+        flight decodes the row to its end, class docstring), and free its
+        pages — mirrors the completion path exactly so a disconnect can
+        never leak what a completion would have freed."""
         self._pending = [p for p in self._pending if p.row != r]
         self._row_live_np[r] = False
         self._done_np[r] = True
         s = list(self.state)
-        s[5] = s[5].at[r].set(True)
+        s[5] = _end_row(s[5], r)
         self.state = tuple(s)
         self.release(r)
 

@@ -34,6 +34,16 @@ and only the suffix runs through `suffix_logits`. Cold admissions take
 the same path with an empty match — the suffix forward starts at the
 first real token, so pad KV is never written (and never read).
 
+The beat is pipelined one deep (docs/SERVING.md "The beat"): the loop
+dispatches decode chunk k+1 BEFORE it waits for chunk k's report, so the
+device runs one chunk into the next while the host streams tokens, releases
+ended rows and admits requests; those decisions lag one beat and reach the
+device behind the chunk in flight. No admission waits for its first token:
+the token stays on the device and is streamed when the host, reading in the
+device's own order, comes to it. Which session takes this is the session's
+own `looks_ahead` (serving mode without speculation); a speculative engine
+runs the same loop with nothing left in flight.
+
 The loop thread's life is accounted for (`LOOP_PHASES`, one
 `PhaseTimer.phase` each, and the session's own inside them): `metrics()`
 exports the cumulative seconds with a request timeline as sums — queue
@@ -64,7 +74,9 @@ import jax
 import numpy as np
 
 from nanorlhf_tpu.analysis.lockorder import make_condition
-from nanorlhf_tpu.sampler.paged.session import SESSION_PHASES, DecodeSession
+from nanorlhf_tpu.sampler.paged.session import (
+    SESSION_PHASES, DecodeSession, FirstToken,
+)
 from nanorlhf_tpu.serving.radix import RadixCache, prompt_key
 from nanorlhf_tpu.telemetry.health import SLO_RULES
 from nanorlhf_tpu.utils.profiling import PhaseTimer
@@ -281,10 +293,18 @@ class ServingEngine:
 
     def _idle_locked(self) -> bool:
         return (self._running and not self._pending
-                and self._n_active == 0)
+                and self._n_active == 0 and not self._sess.unread())
 
     def _loop(self):
+        """A beat: admit into free rows, reap cancels, dispatch the next
+        chunk, then read and stream, in the device's own order, what came
+        before it: the last chunk's report, then the first tokens of this
+        beat's admissions (enqueued between the two chunks). A session that
+        `looks_ahead` leaves the chunk just dispatched unread, so the device
+        has it while the host is busy; nothing is dispatched once no row
+        holds a request, and what is still in flight then is read out."""
         phase = self._timer.phase
+        sess = self._sess
         while True:
             with self._cond:
                 if self._idle_locked():
@@ -292,7 +312,7 @@ class ServingEngine:
                         while self._idle_locked():
                             self._cond.wait(0.05)
                 if (not self._running and self._n_active == 0
-                        and not self._pending):
+                        and not self._pending and not sess.unread()):
                     break
                 admits = []
                 free_rows = [r for r in range(self.rows)
@@ -306,14 +326,21 @@ class ServingEngine:
                     self._admit(r, req)
             with phase("reap"):
                 self._reap_cancelled()
-            if all(o is None for o in self._owner):
-                continue
-            with phase("step"):
-                self._sess.step()
-            with phase("deliver"):
-                self._deliver()
+            ahead = 0
+            if any(o is not None for o in self._owner):
+                with phase("step"):
+                    sess.dispatch()
+                ahead = int(sess.looks_ahead)
+            while sess.unread() > ahead:
+                with phase("step"):
+                    got = sess.read()
+                with phase("deliver"):
+                    self._deliver(got)
 
     def _admit(self, r: int, req: ServingRequest):
+        """The host half of an admission: the plan, and the forward
+        enqueued. The first token comes through `_deliver` once the device
+        has made it."""
         queue_wait = time.perf_counter() - req.t_submit
         Tp = self.prompt_len
         n = int(req.tokens.size)
@@ -324,7 +351,7 @@ class ServingEngine:
         mask[pad_count:] = True
         req.kelems = prompt_key(toks_p, mask)
         try:
-            tok0 = self._sess.admit(
+            self._sess.admit(
                 r, toks_p, mask, req.request_id, budget=req.max_tokens,
                 temperature=req.temperature, top_p=req.top_p,
                 greedy=req.greedy, t_start=req.t_submit)
@@ -345,13 +372,6 @@ class ServingEngine:
             self._timeline["serving/queue_wait_s_count"] += 1
         if self._hub is not None:
             self._hub.record("latency/queue_wait_s", queue_wait)
-        if tok0 is None:
-            # chunked admission: the first token lands when the final
-            # chunk installs the row; _deliver streams it from the carry
-            return
-        req.t_first_token = time.perf_counter()
-        req.out_q.put(int(tok0))
-        req.n_emitted = 1
 
     def _reap_cancelled(self):
         """Loop-thread only: free rows whose owner was cancelled. The
@@ -370,26 +390,35 @@ class ServingEngine:
                 self._n_active -= 1
                 self._cond.notify_all()
 
-    def _deliver(self):
-        state = self._sess.state
-        done_h = np.asarray(state[5])
-        out_h = np.asarray(state[1])
-        n_gen_h = np.asarray(state[7])
-        pending = self._sess.pending_rows()
-        for r in range(self.rows):
-            req = self._owner[r]
-            if req is None or r in pending:
-                continue
-            n = int(n_gen_h[r])
-            if req.n_emitted == 0 and n > 0:    # a chunked admission's first
+    def _deliver(self, got):
+        """Stream what the session read: an admission's first token, or a
+        chunk's report. A report speaks only for the rows that still hold
+        the request its chunk ran for (`BeatReport.current`), and a request
+        takes nothing from a report before its first token: the device made
+        that before the first chunk the row ran in, and the reads keep the
+        device's order. A cancelled request takes nothing more, whatever
+        its row went on to in a chunk already dispatched: the next beat's
+        reap ends it, as `cancel` promises."""
+        if isinstance(got, FirstToken):
+            req = self._owner[got.row]
+            if (req is not None and req.request_id == got.index
+                    and not req.cancelled):
                 req.t_first_token = time.perf_counter()
-            for tok in out_h[r, req.n_emitted:n]:
-                req.out_q.put(int(tok))
+                req.out_q.put(got.token)
+                req.n_emitted = 1
+            return
+        for r in np.flatnonzero(got.current):
+            req = self._owner[r]
+            if req is None or req.n_emitted == 0 or req.cancelled:
+                continue
+            n = int(got.n_gen[r])
+            for tok in got.new_tokens(r, req.n_emitted).tolist():
+                req.out_q.put(tok)
             req.n_emitted = n
-            if done_h[r]:
+            if got.done[r]:
                 req.out_q.put(None)
                 self._sess.release(
-                    r, gen_tokens=(out_h[r, :n] if self.spec_k > 0
+                    r, gen_tokens=(got.new_tokens(r, 0) if self.spec_k > 0
                                    else None))
                 self._owner[r] = None
                 with self._cond:
@@ -460,7 +489,13 @@ class ServingEngine:
         # goes under this thread)
         for name in LOOP_PHASES:
             rows[f"serving/loop_{name}_s"] = self._timer.cumulative[name]
-        rows["serving/loop_beats"] = self._timer.cumulative_counts["step"]
+        # a beat is a decode chunk dispatched (the loop's step span is also
+        # entered to read: once a report, once a first token)
+        rows["serving/loop_beats"] = (
+            self._sess.timer.cumulative_counts["dispatch"])
+        rows["serving/beats_overlapped"] = self._sess.beats_overlapped
+        rows["serving/first_tokens_deferred"] = (
+            self._sess.first_tokens_deferred)
         for name in SESSION_PHASES:
             rows[f"serving/session_{name}_s"] = (
                 self._sess.timer.cumulative[name])

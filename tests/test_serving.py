@@ -410,6 +410,9 @@ def test_gateway_generate_and_prefix_reuse(served):
 def test_gateway_metrics_prometheus_valid(served):
     from nanorlhf_tpu.telemetry.exporter import validate_prometheus_text
     _, _, base = served
+    # a request of its own: under xdist this test may be the first to use
+    # the module's engine, and a histogram without observations is absent
+    _post(base, {"tokens": [5, 6, 7, 8, 9, 10], "greedy": True}).read()
     text = urllib.request.urlopen(base + "/metrics",
                                   timeout=30).read().decode()
     assert validate_prometheus_text(text) == []
@@ -695,4 +698,136 @@ def test_gateway_disconnect_fault_mid_stream(tiny):
         assert inj.stats()["gw.disconnect"]["fires"] == inj_left == 4
     finally:
         gw.close()
+        eng.close()
+
+
+# --------------------------------------------------------------------- #
+# the engine's beat pipelined one deep (ISSUE 35, docs/SERVING.md "The
+# beat"): chunk k+1 dispatched before chunk k is read, no admission waits
+# for its first token
+# --------------------------------------------------------------------- #
+
+BEAT_REQUESTS = [([5, 6, 7, 8, 9, 10, 11, 12], 8), ([11, 12, 13], 3),
+                 ([20, 21, 22, 23], 6), ([30, 31], 5),
+                 ([5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15], 7),
+                 ([40, 41, 42], 1), ([50, 51, 52, 53, 54], 4)]
+
+
+def _serial_streams(tiny, prefill_chunk, sync_every):
+    """The same requests through a serving-mode session driven serially by
+    hand (tests/test_session.py `_drive`): what the streams have to be."""
+    from tests.test_session import _drive
+    want, _, _ = _drive(tiny, BEAT_REQUESTS, ahead=False, rows=2,
+                        prefill_chunk=prefill_chunk, sync_every=sync_every)
+    return [want[q] for q in range(len(BEAT_REQUESTS))]
+
+
+@pytest.fixture(scope="module", params=[0, 4], ids=["whole", "chunked"])
+def beat_run(tiny, request):
+    """Seven requests, more than rows, all submitted at once: streams, the
+    engine's metrics after it has drained, and the loop's lifetime."""
+    eng = _chaos_engine(tiny, sync_every=2, prefill_chunk=request.param,
+                        seed=0)
+    t0 = time.perf_counter()            # the loop thread has just started
+    try:
+        reqs = [eng.submit(toks, greedy=True, max_tokens=budget)[0]
+                for toks, budget in BEAT_REQUESTS]
+        streams = [list(eng.stream(r)) for r in reqs]
+        _quiesce(eng)
+        deadline = time.monotonic() + 30
+        while eng.session.unread() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.2)                 # the read's own spans close
+        drained = eng.metrics()
+        time.sleep(0.3)                 # idle: nothing may move now
+        idle = eng.metrics()
+        unread = eng.session.unread()
+    finally:
+        eng.close()
+    life = time.perf_counter() - t0
+    stamps = [(r.t_submit, r.t_first_token) for r in reqs]
+    return dict(chunk=request.param, streams=streams, drained=drained,
+                idle=idle, unread=unread, life=life, stamps=stamps,
+                after=eng.metrics())
+
+
+def test_engine_streams_equal_the_serial_session(tiny, beat_run):
+    """Token for token what a serial session gives, across admissions,
+    releases, a chunked admission and budgets that end inside a chunk."""
+    want = _serial_streams(tiny, beat_run["chunk"], 2)
+    assert beat_run["streams"] == want
+    m = beat_run["drained"]
+    assert m["serving/completed"] == m["serving/admitted"] == len(want)
+
+
+def test_engine_overlaps_its_beats_and_defers_first_tokens(beat_run):
+    """`serving/beats_overlapped` beside `serving/loop_beats`, and every
+    admission's first token read after its dispatch returned, stamped when
+    the host had it."""
+    m = beat_run["drained"]
+    assert 0 < m["serving/beats_overlapped"] <= m["serving/loop_beats"]
+    assert m["serving/first_tokens_deferred"] == m["serving/admitted"]
+    assert all(sub <= first for sub, first in beat_run["stamps"])
+    # the counters three roofline readers divide by one another moved at
+    # the same reads: steps counted, and slots read in every one of them
+    assert m["serving/decode_steps"] > 0
+    assert m["serving/global_slots_read"] >= m["serving/decode_steps"]
+
+
+def test_engine_drains_to_idle_and_dispatches_nothing_into_an_empty_session(
+        beat_run):
+    """Once no row holds a request the chunk still in flight is read out and
+    no further one is dispatched: every counter stands still."""
+    assert beat_run["unread"] == 0
+    for key in ("serving/loop_beats", "serving/decode_steps",
+                "serving/beats_overlapped", "serving/loop_step_s",
+                "serving/loop_deliver_s"):
+        assert beat_run["idle"][key] == beat_run["drained"][key], key
+    # (the idle third of a second is in `loop_wait_s` once the wait ends)
+    assert beat_run["after"]["serving/loop_wait_s"] >= 0.3
+
+
+def test_engine_loop_spans_still_tile_the_loops_life(beat_run):
+    """wait + admit + reap + step + deliver cover the loop thread's life to
+    within a few percent (what is left is the lock and the list of free
+    rows), and the session's beat spans lie inside the loop's step."""
+    m = beat_run["after"]
+    total = sum(m[f"serving/loop_{p}_s"]
+                for p in ("wait", "admit", "reap", "step", "deliver"))
+    # ~2 % is outside the spans on a quiet machine; the fifth leaves room
+    # for a loop thread that five other test workers keep off the cores
+    assert 0.8 * beat_run["life"] <= total <= beat_run["life"]
+    assert (m["serving/session_dispatch_s"] + m["serving/session_sync_s"]
+            + m["serving/session_prefill_tick_s"]
+            <= m["serving/loop_step_s"])
+
+
+def test_engine_compiles_nothing_after_warm_up(tiny):
+    """Every program of the look-ahead beat (the chunk, its report, the
+    admission forwards by bucket) is compiled by the first pass over the
+    shapes: a second pass with other tokens compiles nothing, and neither
+    does the first client that vanishes mid-stream, which no warm-up can
+    rehearse (the cancel's one program is compiled with the session)."""
+    from nanorlhf_tpu.telemetry.mfu import recompile_counter
+    counter = recompile_counter()
+    eng = _chaos_engine(tiny, sync_every=2, prefill_chunk=4, seed=0)
+    try:
+        def serve(shift):
+            reqs = [eng.submit([t + shift for t in toks], greedy=True,
+                               max_tokens=budget)[0]
+                    for toks, budget in BEAT_REQUESTS]
+            return [list(eng.stream(r)) for r in reqs]
+        assert all(serve(0))
+        _quiesce(eng)
+        before = counter.count
+        assert all(serve(37))
+        victim, _ = eng.submit([5, 6, 7, 8, 9, 10], greedy=True)
+        stream = eng.stream(victim)
+        next(stream)
+        eng.cancel(victim)
+        list(stream)
+        _quiesce(eng)
+        assert eng.metrics()["serving/cancelled"] <= 1     # or it had ended
+        assert counter.count == before
+    finally:
         eng.close()

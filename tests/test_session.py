@@ -382,3 +382,220 @@ def test_compose_check_illegal(kw, pc, match):
 def test_compose_check_legal(kw):
     compose_check(SamplingParams(**kw), prefix_cache=(
         kw.get("page_size", 0) > 0 and kw.get("decode_rows", 0) > 0))
+
+
+# --------------------------------------------------------------------- #
+# the beat pipelined one deep (ISSUE 35): chunk k+1 is dispatched before
+# chunk k is read, and nothing the host then does changes a greedy stream
+# --------------------------------------------------------------------- #
+
+def _drive(tiny, requests, *, ahead, rows=2, prefill_chunk=0, sync_every=2,
+           headroom=1.0, cancel=None):
+    """The serving engine's loop by hand over a serving-mode session:
+    requests `(real tokens, budget)` go into free rows in order, every beat
+    dispatches a chunk while a row holds a request, and the host reads what
+    the device owes it in the device's order, leaving the newest chunk
+    unread when `ahead`. `cancel=(beat, request)` cancels that request's row
+    after the beat's admissions. Returns (streams by request, session, log)."""
+    from nanorlhf_tpu.sampler.paged.session import DecodeSession, FirstToken
+
+    config, params = tiny
+    sess = DecodeSession(
+        params, config, rows=rows, prompt_len=TP, max_tokens=MT, page_size=4,
+        eos_token_id=EOS, pad_token_id=PAD, key=jax.random.PRNGKey(0),
+        greedy=True, per_row=True, prefill_chunk=prefill_chunk,
+        prefix_cache=RadixCache(True, headroom=headroom),
+        sync_every=sync_every)
+    owner = [None] * rows
+    streams = {q: [] for q in range(len(requests))}
+    queue = list(range(len(requests)))
+    log = {"beats": 0, "reused_under_flight": 0, "order": []}
+    freed_under_flight = set()
+    while queue or any(o is not None for o in owner) or sess.unread():
+        for r in range(rows):
+            if owner[r] is None and queue:
+                q = queue.pop(0)
+                real, budget = requests[q]
+                ids, mask = _left_pad([real], TP)
+                sess.admit(r, np.asarray(ids[0]), np.asarray(mask[0]), q,
+                           budget=budget, temperature=1.0, top_p=1.0,
+                           greedy=True)
+                owner[r] = q
+                if sess.unread() and freed_under_flight & set(
+                        sess.table_np[r].tolist()):
+                    log["reused_under_flight"] += 1
+        if cancel is not None and cancel[0] == log["beats"]:
+            r = owner.index(cancel[1])
+            sess.cancel_row(r)
+            owner[r] = None
+        keep = 0
+        if any(o is not None for o in owner):
+            sess.dispatch()
+            keep = int(ahead)
+        if not sess.unread():
+            freed_under_flight.clear()
+        while sess.unread() > keep:
+            got = sess.read()
+            if isinstance(got, FirstToken):
+                if owner[got.row] == got.index:
+                    streams[got.index].append(got.token)
+                    log["order"].append(("first", got.index))
+                continue
+            log["order"].append(("report", got.its))
+            for r in np.flatnonzero(got.current):
+                q = owner[r]
+                if q is None or not streams[q]:
+                    continue
+                streams[q].extend(
+                    got.new_tokens(r, len(streams[q])).tolist())
+                if got.done[r]:
+                    if sess.unread():
+                        freed_under_flight |= {
+                            int(pg) for pg in sess.table_np[r]
+                            if pg < sess.num_pages}
+                    sess.release(r)
+                    owner[r] = None
+        log["beats"] += 1
+    return streams, sess, log
+
+
+_LONG = [5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15]
+LOOKAHEAD_CASES = {
+    # more requests than rows, budgets that end with a chunk and inside one
+    "admissions_and_releases": dict(requests=[
+        (FAMILY, 8), ([11, 12, 13], 3), ([20, 21, 22, 23], 6), ([30, 31], 5),
+        (FAMILY, 7), ([40, 41, 42], 1)]),
+    # a long cold prompt admitted in pieces beside resident rows
+    "chunked_admission": dict(prefill_chunk=4, requests=[
+        ([20, 21, 22], 8), (_LONG, 6), ([30, 31], 4), (_LONG[:9], 5)]),
+    "budget_ends_inside_a_chunk": dict(sync_every=4, requests=[
+        (FAMILY, 2), ([11, 12, 13], 3), ([20, 21, 22, 23], 4), ([30, 31], 6),
+        ([50, 51, 52, 53, 54], 7)]),
+    # no spare page: an admission takes what a release just let go
+    "pages_reused_under_flight": dict(headroom=0.0, rows=3, requests=[
+        ([60 + i, 70 + i, 80 + i, 90 + i, 100 + i], 3 + i % 4)
+        for i in range(9)]),
+}
+
+
+@pytest.fixture(scope="module")
+def lookahead(tiny):
+    return {name: (_drive(tiny, ahead=False, **kw),
+                   _drive(tiny, ahead=True, **kw))
+            for name, kw in LOOKAHEAD_CASES.items()}
+
+
+@pytest.mark.parametrize("case", sorted(LOOKAHEAD_CASES))
+def test_lookahead_streams_equal_the_serial_sessions(lookahead, case):
+    """Greedy streams with a chunk always in flight are the serial
+    session's, token for token; every request got its whole budget or
+    ended on EOS; and the look-ahead runs did overlap."""
+    (serial, s_serial, _), (ahead, s_ahead, log) = lookahead[case]
+    assert ahead == serial
+    for q, (_, budget) in enumerate(LOOKAHEAD_CASES[case]["requests"]):
+        toks = ahead[q]
+        assert len(toks) == budget or toks[-1] == EOS, (q, toks)
+    assert s_serial.beats_overlapped == 0
+    assert s_ahead.beats_overlapped > 0
+    assert s_ahead.first_tokens_deferred == len(ahead)
+    assert s_ahead.unread() == 0
+    # the pool is whole again: nothing stranded under a flight
+    for sess in (s_serial, s_ahead):
+        assert (sess.table_np == sess.num_pages).all()
+        snap = sess._radix.snapshot()
+        assert snap["free_pages"] + snap["cached_pages"] == sess.num_pages
+
+
+def test_lookahead_reuses_released_pages_under_the_flight(lookahead):
+    """The case without a spare page really does hand a released row's
+    pages to the next admission while a chunk that still held them in its
+    table was unread, and a chunked admission's final piece queues its first
+    token behind the chunk in flight."""
+    _, (_, _, log) = lookahead["pages_reused_under_flight"]
+    assert log["reused_under_flight"] > 0
+    (_, s_serial, _), (_, s_ahead, _) = lookahead["chunked_admission"]
+    assert s_serial.chunked_admissions == s_ahead.chunked_admissions == 2
+
+
+@pytest.mark.parametrize("case", sorted(LOOKAHEAD_CASES))
+def test_first_token_is_read_before_its_rows_first_chunk(lookahead, case):
+    """Reads keep the device's order: a first token comes out once a
+    request, in the order of admission, and always with a report between
+    one beat's first tokens and the next beat's (the chunk dispatched
+    between them). `_drive`, like the engine, takes nothing from a report
+    for a row whose first token it has not read: were one read late, its
+    row's tokens of that report would be missing from the stream."""
+    _, (ahead, _, log) = lookahead[case]
+    firsts = [e[1] for e in log["order"] if e[0] == "first"]
+    assert firsts == sorted(ahead)
+    reports = [e for e in log["order"] if e[0] == "report"]
+    assert sum(its for _, its in reports) > 0
+    assert log["order"][0] == ("first", 0)
+
+
+def test_one_request_costs_the_lag_one_empty_chunk(tiny):
+    """A lone request: the chunk dispatched before the host saw it end runs
+    no iteration (`~all(done)`), which is the whole cost of the lag; nothing
+    is dispatched once no row holds a request."""
+    (serial, s_serial, l_serial) = _drive(tiny, [(FAMILY, 7)], ahead=False)
+    (ahead, s_ahead, l_ahead) = _drive(tiny, [(FAMILY, 7)], ahead=True)
+    assert ahead == serial and ahead[0]
+    assert s_ahead.iterations() == s_serial.iterations()
+    chunks = lambda s: s.timer.cumulative_counts["dispatch"]    # noqa: E731
+    assert chunks(s_ahead) == chunks(s_serial) + 1
+    assert [e for e in l_ahead["order"] if e[0] == "report"][-1] == (
+        "report", 0)
+    assert s_ahead.unread() == 0
+
+
+@pytest.mark.parametrize("beat", [1, 2])
+def test_cancel_under_flight_frees_the_row_for_the_next(tiny, beat):
+    """A live row cancelled while a chunk that decodes it is in flight: its
+    pages go back at once, the next request decodes in the same row and
+    every other stream is what it is without the cancel."""
+    requests = [(FAMILY, 8), ([11, 12, 13], 8), ([20, 21, 22, 23], 6),
+                ([30, 31], 5)]
+    whole, _, _ = _drive(tiny, requests, ahead=False)
+    got, sess, _ = _drive(tiny, requests, ahead=True, cancel=(beat, 0))
+    assert got[0] == whole[0][:len(got[0])] and len(got[0]) < len(whole[0])
+    assert {q: got[q] for q in (1, 2, 3)} == {q: whole[q] for q in (1, 2, 3)}
+    snap = sess._radix.snapshot()
+    assert snap["free_pages"] + snap["cached_pages"] == sess.num_pages
+    assert (sess.table_np == sess.num_pages).all()
+
+
+@pytest.mark.parametrize("mode", ["rollout", "speculative"])
+def test_serial_sessions_never_overlap(tiny, mode):
+    """Which session looks ahead is what the session is: the rollout
+    scheduler reads `done` off the carry to refill and speculation verifies
+    against it, so neither may dispatch past an unread chunk, and neither
+    does in a whole `generate` or behind a speculative engine."""
+    from nanorlhf_tpu.sampler.paged.session import DecodeSession
+    from nanorlhf_tpu.serving.engine import ServingEngine
+
+    config, params = tiny
+    kw = dict(rows=2, prompt_len=TP, max_tokens=MT, page_size=4,
+              eos_token_id=EOS, pad_token_id=PAD, key=jax.random.PRNGKey(0),
+              greedy=True, sync_every=2)
+    assert DecodeSession(params, config, per_row=True,
+                         prefix_cache=RadixCache(True), **kw).looks_ahead
+    if mode == "rollout":
+        sess = DecodeSession(params, config, **kw)
+        assert not sess.looks_ahead
+        ids, mask = _left_pad([FAMILY, [11, 12, 13]], TP)
+        sess.bootstrap(ids, mask)
+        while not sess.step()[0].all():
+            assert sess.unread() == 0
+        assert sess.beats_overlapped == 0
+        return
+    eng = ServingEngine(params, config, eos_token_id=EOS, pad_token_id=PAD,
+                        page_size=4, prompt_len=TP, max_new_tokens=MT,
+                        rows=2, seed=0, spec_k=3)
+    try:
+        assert not eng.session.looks_ahead
+        reqs = [eng.submit(p, greedy=True)[0] for p in (FAMILY, [11, 12, 13])]
+        assert all(list(eng.stream(r)) for r in reqs)
+        m = eng.metrics()
+    finally:
+        eng.close()
+    assert m["serving/beats_overlapped"] == 0 < m["serving/loop_beats"]
