@@ -29,6 +29,7 @@ A single-segment generation reduces BIT-EXACTLY to the whole-sequence path.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from nanorlhf_tpu.ops.masking import masked_mean
@@ -149,6 +150,7 @@ def _ratio_and_stats(new_logprobs, old_logprobs):
     return logprobs_diff, ratio, approxkl
 
 
+@jax.named_scope("loss")
 def ppo_clip_loss_token(
     new_logprobs: jnp.ndarray,
     old_logprobs: jnp.ndarray,
@@ -203,6 +205,7 @@ def k3_kl(new_logprobs: jnp.ndarray, ref_logprobs: jnp.ndarray) -> jnp.ndarray:
     return jnp.exp(-kl) + kl - 1.0
 
 
+@jax.named_scope("loss")
 def grpo_loss(
     new_logprobs: jnp.ndarray,
     old_logprobs: jnp.ndarray,
@@ -253,6 +256,7 @@ def grpo_loss(
     return loss, aux
 
 
+@jax.named_scope("loss")
 def ppo_clip_loss_sequence(
     new_logprobs: jnp.ndarray,
     old_logprobs: jnp.ndarray,
@@ -330,6 +334,7 @@ def ppo_clip_loss_sequence(
     return loss, aux
 
 
+@jax.named_scope("loss")
 def value_loss_clipped(
     vpred: jnp.ndarray,
     values: jnp.ndarray,
@@ -354,6 +359,7 @@ def value_loss_clipped(
     return loss, aux
 
 
+@jax.named_scope("loss")
 def sft_loss(new_logprobs: jnp.ndarray, mask: jnp.ndarray):
     """RAFT: negative summed logprob of the kept sample, mean over batch.
 

@@ -226,6 +226,7 @@ def paged_cache_shapes(config: ModelConfig, num_pages: int,
             lead + (page_size // pack, pack * config.qk_rope_head_dim))
 
 
+@jax.named_scope("attn.write")     # as `core/model._cache_write` is
 def _paged_latent_write(pools, c_kv, k_r, layer, table, cache_index,
                         page_size):
     """Write `c_kv` [B, T, r] and `k_r` [B, T, dr] through the block table

@@ -477,16 +477,19 @@ def _cache_write(stacks, news, layer, cache_index, paged):
     `(layer, ...)`: `stacks`/`news` are (k, v) exact, or (k_q, k_s, v_q, v_s)
     int8, whose odd members are scale arrays (sequence on the last axis).
     `paged=(block_table, page_size)` routes the write through the table.
-    Returns the updated stacks."""
+    Returns the updated stacks. Under the scope `attn.write`."""
     out = []
-    for i, (stack, new) in enumerate(zip(stacks, news)):
-        is_scale = len(stacks) == 4 and i % 2 == 1
-        if paged is not None:
-            update = _paged_scale_update if is_scale else _paged_cache_update
-            out.append(update(stack, new, layer, paged[0], cache_index, paged[1]))
-        else:
-            update = _scale_update if is_scale else _cache_update
-            out.append(update(stack, new, layer, cache_index))
+    with jax.named_scope("attn.write"):
+        for i, (stack, new) in enumerate(zip(stacks, news)):
+            is_scale = len(stacks) == 4 and i % 2 == 1
+            if paged is not None:
+                update = (_paged_scale_update if is_scale
+                          else _paged_cache_update)
+                out.append(update(stack, new, layer, paged[0], cache_index,
+                                  paged[1]))
+            else:
+                update = _scale_update if is_scale else _cache_update
+                out.append(update(stack, new, layer, cache_index))
     return tuple(out)
 
 
@@ -684,43 +687,67 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     # (a stack that starts the model keeps `layer` as it is: the same program)
     if expert_layer is None:
         expert_layer = layer - stack_start if stack_start else layer
-    if config.kv_lora_rank:
-        if attn_fn is not None:
-            raise NotImplementedError(
-                "latent attention has no sequence-parallel form: ring "
-                "attention exchanges per-head K and V (docs/MLA.md)")
-        from nanorlhf_tpu.core.mla import mla_attention
-
+    with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
-        out, new_cache = mla_attention(
-            config, h, layer_params, lora_layer, lora_scale, cos, sin, mask,
-            kv_cache, cache_index, decode_bounds, verify_bounds, paged, layer)
-        x = x + out
+    with jax.named_scope("attn"):
+        if config.kv_lora_rank:
+            if attn_fn is not None:
+                raise NotImplementedError(
+                    "latent attention has no sequence-parallel form: ring "
+                    "attention exchanges per-head K and V (docs/MLA.md)")
+            from nanorlhf_tpu.core.mla import mla_attention
+
+            out, new_cache = mla_attention(
+                config, h, layer_params, lora_layer, lora_scale, cos, sin,
+                mask, kv_cache, cache_index, decode_bounds, verify_bounds,
+                paged, layer)
+            x = x + out
+        else:
+            x, new_cache = _attention(
+                config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
+                mask, kv_cache, cache_index, attn_fn, decode_bounds,
+                verify_bounds, paged, layer, kind)
+
+    router_h = h if config.router_input == "pre_attention" else None
+    with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["post_attention_layernorm"],
                      config.rms_norm_eps)
+    with jax.named_scope("mlp"):
         ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
-                       expert_stack, expert_layer, live)
-        return x + ff, new_cache, aux
+                       expert_stack, expert_layer, live, router_h)
+        x = x + ff
+    return x, new_cache, aux
+
+
+def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
+               mask, kv_cache, cache_index, attn_fn, decode_bounds,
+               verify_bounds, paged, layer, kind):
+    """A layer's attention on the normed state `h`, with its residual:
+    `(x + attention, the updated cache stacks | None)`. In four parts, each
+    under its scope (utils/profiling.py `DEVICE_SCOPES`): the projections
+    and rotary (`attn.qkv`), the new tokens' write into the cache
+    (`attn.write`, `_cache_write`), the contraction (`attn.read`; a pattern
+    model's is `attn.global` / `attn.window`, its write inside) and the
+    output projection (`attn.out`)."""
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
     spmd = _kernel_spmd(config, H, KV)
+    with jax.named_scope("attn.qkv"):
+        q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
+        k = _proj(h, layer_params, lora_layer, "k_proj", lora_scale)
+        v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
+        if config.qk_norm:
+            # OLMoE: over the whole projection width, before the head split
+            q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
+            k = rms_norm(k, layer_params["k_norm"], config.rms_norm_eps)
+        q = q.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
 
-    h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
-    q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
-    k = _proj(h, layer_params, lora_layer, "k_proj", lora_scale)
-    v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
-    if config.qk_norm:
-        # OLMoE: over the whole projection width, before the head split
-        q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
-        k = rms_norm(k, layer_params["k_norm"], config.rms_norm_eps)
-    q = q.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
-
-    if kind is None or kind[1]:     # a NoPE layer carries no position
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if kind is None or kind[1]:     # a NoPE layer carries no position
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
     if kind is not None:
         if attn_fn is not None:
@@ -732,13 +759,36 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             out, new_cache = _pattern_attention(
                 config, q, k, v, mask, kv_cache, cache_index, decode_bounds,
                 verify_bounds, paged, layer, window, spmd)
-    elif attn_fn is not None:
+    else:
         new_cache = None
+        if kv_cache is not None and attn_fn is None:
+            news = (k, v)
+            if len(kv_cache) == 4:      # int8 KV cache: see init_kv_cache
+                with jax.named_scope("attn.write"):
+                    news = _quantize_kv(k) + _quantize_kv(v)
+            new_cache = _cache_write(kv_cache, news, layer, cache_index, paged)
+        with jax.named_scope("attn.read"):
+            out = _attention_read(config, q, k, v, mask, new_cache,
+                                  decode_bounds, verify_bounds, paged, layer,
+                                  spmd, attn_fn)
+    with jax.named_scope("attn.out"):
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+        out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
+        return x + out, new_cache
+
+
+def _attention_read(config, q, k, v, mask, new_cache, decode_bounds,
+                    verify_bounds, paged, layer, spmd, attn_fn):
+    """The attention contraction of a layer of one kind (every model without
+    an attention pattern), `out [B, H, T, hd]`: over `new_cache`, the cache
+    stacks that already hold this call's tokens (`_layer_body` wrote them),
+    or over `k` and `v` alone where there is no cache or a prefill needs no
+    more than the tokens at hand."""
+    T = q.shape[2]
+    if attn_fn is not None:
         out = attn_fn(q, k, v)
-    elif kv_cache is not None and len(kv_cache) == 4:
+    elif new_cache is not None and len(new_cache) == 4:
         # int8 KV cache: (k_q, k_scales, v_q, v_scales) — see init_kv_cache
-        new_cache = _cache_write(kv_cache, _quantize_kv(k) + _quantize_kv(v),
-                                 layer, cache_index, paged)
         kq_c, ks_c, vq_c, vs_c = (_layer_slab(c, layer) for c in new_cache)
 
         def _q8_views(width):
@@ -804,8 +854,7 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             # exact path — no bandwidth win off-TPU, none needed
             kd, vd = _q8_views(mask.shape[-1])
             out = gqa_attention(q, kd, vd, mask)
-    elif kv_cache is not None:
-        new_cache = _cache_write(kv_cache, (k, v), layer, cache_index, paged)
+    elif new_cache is not None:
         k_cache, v_cache = (_layer_slab(c, layer) for c in new_cache)
         # logical cache length (for the kernel-eligibility threshold and the
         # gathered view): on the paged layout the mask width, not the pool
@@ -882,19 +931,9 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             kd, vd = _kv_views(mask.shape[-1])
             out = gqa_attention(q, kd, vd, mask)
     else:
-        new_cache = None
         out = gqa_attention(q, k, v, mask, impl=config.attention_impl,
                             mask_is_causal_x_keyvalid=True, spmd=spmd)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
-    out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
-    x = x + out
-
-    router_h = h if config.router_input == "pre_attention" else None
-    h = rms_norm(x, layer_params["post_attention_layernorm"], config.rms_norm_eps)
-    ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
-                   expert_stack, expert_layer, live, router_h)
-    x = x + ff
-    return x, new_cache, aux
+    return out
 
 
 # pages a key block of the pattern model's T > 1 paged read holds (1,024 keys
@@ -1325,8 +1364,14 @@ def unembedding_weight(config: ModelConfig, params: dict) -> jnp.ndarray:
 
 
 def _logits(config: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
-    x = rms_norm(x, params["norm"], config.rms_norm_eps)
-    return x @ unembedding_weight(config, params)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["norm"], config.rms_norm_eps)
+        return x @ unembedding_weight(config, params)
+
+
+def _embed(params: dict, ids: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("embed"):
+        return params["embed_tokens"][ids].astype(params["embed_tokens"].dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1364,7 +1409,7 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     no second forward).
     """
     attention_mask = attention_mask.astype(bool)
-    x = params["embed_tokens"][input_ids].astype(params["embed_tokens"].dtype)
+    x = _embed(params, input_ids)
     T = input_ids.shape[1]
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
@@ -1476,7 +1521,8 @@ def padded_forward_hidden(
         x, stats = x
     if response_context_length is not None:
         x = x[:, response_context_length - 1 : -1]
-    x = rms_norm(x, params["norm"], config.rms_norm_eps)
+    with jax.named_scope("head"):   # its matmul is `fused_logprob`'s
+        x = rms_norm(x, params["norm"], config.rms_norm_eps)
     return (x, stats) if router_stats else x
 
 
@@ -1505,8 +1551,9 @@ def score_forward(
     (`PPO/ppo_trainer.py:630-634,732`) and RM-based rewards.
     """
     x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale, remat)
-    x = rms_norm(x, params["norm"], config.rms_norm_eps)
-    return (x.astype(jnp.float32) @ params["score"].astype(jnp.float32))
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["norm"], config.rms_norm_eps)
+        return (x.astype(jnp.float32) @ params["score"].astype(jnp.float32))
 
 
 def _latent_cache_shape(config: ModelConfig, rows: int, slots: int) -> tuple:
@@ -1689,9 +1736,7 @@ def prefill(
         T_max = _cache_leaf(kv_caches).shape[3]
     attention_mask = attention_mask.astype(bool)
     position_ids = jnp.cumsum(attention_mask, axis=1) - attention_mask.astype(jnp.int32)
-    x = params["embed_tokens"][jnp.where(attention_mask, input_ids, 0)].astype(
-        params["embed_tokens"].dtype
-    )
+    x = _embed(params, jnp.where(attention_mask, input_ids, 0))
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     # queries attend over cache positions [0, T); the rest of T_max is masked
@@ -1741,7 +1786,7 @@ def decode_step(
         # the mask's width is what the XLA read goes by (`_kv_views`); the
         # cache write below addresses the full stack as ever
         key_mask = key_mask[:, :extent]
-    x = params["embed_tokens"][token][:, None, :].astype(params["embed_tokens"].dtype)
+    x = _embed(params, token)[:, None, :]
     cos, sin = _rope(config, position[:, None])
     mask = key_mask[:, None, None, :]  # [B, 1, 1, T_max]
     # valid cache slots form the contiguous range [start, cache_index+1):
@@ -1831,7 +1876,7 @@ def decode_verify(
     T_max = key_mask.shape[1]
     paged = _kind_paged(config, page_table, page_size)
     key_mask = key_mask.astype(bool)
-    x = params["embed_tokens"][tokens].astype(params["embed_tokens"].dtype)
+    x = _embed(params, tokens)
     cos, sin = _rope(config, positions)
     slot = jnp.arange(T_max)[None, None, :]                  # [1, 1, T_max]
     qi = jnp.arange(Tq)[None, :, None]                       # [1, Tq, 1]

@@ -56,6 +56,7 @@ def dequantize_kernel(q: jnp.ndarray, scale: jnp.ndarray,
 
 
 @jax.jit
+@jax.named_scope("sync")
 def quantize_layers(layers: dict) -> dict:
     """Replace each projection's `kernel` with (`kernel_q`, `kernel_scale`).
 

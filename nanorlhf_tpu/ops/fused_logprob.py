@@ -437,6 +437,7 @@ def _resolve_impl(impl: str, with_margin: bool) -> str:
     return impl
 
 
+@jax.named_scope("logprob")
 def fused_logprob(
     hidden: jnp.ndarray,     # [..., D] final-normed hidden states
     unembed: jnp.ndarray,    # [D, V] weight ([V, D] when `transposed`)

@@ -137,6 +137,7 @@ def guard_temperature(temperature):
     return jnp.maximum(temperature, MIN_TEMPERATURE)
 
 
+@jax.named_scope("logprob")
 def logprobs_from_logits(
     logits: jnp.ndarray, labels: jnp.ndarray, temperature: float = 1.0
 ) -> jnp.ndarray:

@@ -111,6 +111,7 @@ _CHUNK_STATIC = (
 )
 
 
+@jax.named_scope("sample")
 def _serving_sample(key, logits, temperature, top_p, greedy, *, top_k,
                     approx_top_k):
     """Per-ROW sampling: `sampler._sample_token` with `temperature` /
@@ -135,6 +136,7 @@ def _serving_sample(key, logits, temperature, top_p, greedy, *, top_k,
 
 
 @partial(jax.jit, static_argnames=("top_k", "approx_top_k"))
+@jax.named_scope("install")
 def _first_token(logits, key, temperature, top_p, greedy, *, top_k,
                  approx_top_k):
     """Sample one admission's first token from its suffix logits [V]."""
@@ -143,6 +145,7 @@ def _first_token(logits, key, temperature, top_p, greedy, *, top_k,
                            approx_top_k=approx_top_k)[0]
 
 
+@jax.named_scope("decode")
 def _session_decode_body(params, config, s, table, row_params, *, Tp,
                          max_tokens, page_size, eos_token_id, pad_token_id,
                          temperature, top_p, greedy, lora_scale, top_k,
@@ -257,6 +260,7 @@ def _serving_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
 
 
 @partial(jax.jit, static_argnames=("width",))
+@jax.named_scope("install")
 def _beat_report(it, out, done, n_gen, hit, *, width):
     """What the host reads of a serving beat, as two small arrays of their
     own: `[it, hit]` and, a row, `done · n_gen · the row's last `width`
@@ -332,6 +336,7 @@ def _spec_chunk_seeded(params, config, state, table, prompt_rep, seed_rep,
          static_argnames=("config", "page_size", "T_max", "temperature",
                           "top_p", "greedy", "top_k", "approx_top_k",
                           "lora_scale"))
+@jax.named_scope("prefill")
 def _admit_one(params, config, pids, pmask, caches, row_table, key, *,
                page_size, T_max, temperature, top_p, greedy, top_k,
                approx_top_k, lora_scale):
@@ -354,6 +359,7 @@ def _admit_one(params, config, pids, pmask, caches, row_table, key, *,
 
 @partial(jax.jit, static_argnames=("Tp", "max_tokens", "eos_token_id",
                                    "pad_token_id", "spec", "per_row"))
+@jax.named_scope("install")
 def _install_row(state, r, tok0, lp0, pmask_row, plen, budget=None,
                  *, Tp, max_tokens, eos_token_id, pad_token_id, spec,
                  per_row=False):
@@ -393,11 +399,12 @@ def _with_pool(state, caches):
     return state[:3] + (caches,) + state[4:]
 
 
-_release_jit = jax.jit(release_row)
-_alloc_jit = jax.jit(alloc_row)
+_release_jit = jax.jit(jax.named_scope("install")(release_row))
+_alloc_jit = jax.jit(jax.named_scope("install")(alloc_row))
 
 
 @jax.jit
+@jax.named_scope("install")
 def _end_row(done, r):
     """Row `r`'s done flag forced (`DecodeSession.cancel_row`)."""
     return done.at[r].set(True)
@@ -405,6 +412,7 @@ def _end_row(done, r):
 
 @partial(jax.jit, static_argnames=("temperature", "top_p", "greedy", "top_k",
                                    "approx_top_k"))
+@jax.named_scope("install")
 def _admit_sample(logits, key, *, temperature, top_p, greedy, top_k,
                   approx_top_k):
     """First token + logprob from a single row's admission logits [V] —
@@ -417,6 +425,7 @@ def _admit_sample(logits, key, *, temperature, top_p, greedy, top_k,
 
 @partial(jit_donating, donate=6,
          static_argnames=("config", "page_size", "lora_scale"))
+@jax.named_scope("prefill")
 def _prefill_chunk_fwd(params, config, chunk_ids, positions, fill, key_mask,
                        caches, row_table, *, page_size, lora_scale):
     """One KV-only prefill chunk: a `decode_verify` forward over a

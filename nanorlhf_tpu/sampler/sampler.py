@@ -360,6 +360,7 @@ def filtered_logits_full(logits, temperature, top_p, top_k, approx_top_k):
     return full.reshape(*lead, V)
 
 
+@jax.named_scope("sample")
 def _sample_token(key, logits, temperature, top_p, greedy, top_k=64,
                   approx_top_k=True):
     """Sample one token per row.
@@ -392,6 +393,7 @@ def _sample_token(key, logits, temperature, top_p, greedy, top_k=64,
     )[..., 0].astype(jnp.int32)
 
 
+@jax.named_scope("logprob")
 def _token_logprob(logits, tok, temperature):
     """Full-distribution logprob of `tok` at the sampling temperature — the
     same quantity the scoring pass computes (`logprobs_from_logits`), through
@@ -512,6 +514,7 @@ def attn_read_frac(config, sampling, prompt_width: int, responses,
     return read / (steps * T_max)
 
 
+@jax.named_scope("prefill")
 def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
                    max_tokens, eos_token_id, pad_token_id, temperature,
                    top_p, greedy, lora_scale, top_k, capture_logprobs,
@@ -606,6 +609,7 @@ def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
             prompt_len, key)
 
 
+@jax.named_scope("decode")
 def _decode_body(params, config, state, *, Tp, max_tokens, eos_token_id,
                  pad_token_id, temperature, top_p, greedy, lora_scale, top_k,
                  capture_logprobs, approx_top_k, page_size=0, extent=None):

@@ -180,6 +180,7 @@ def accept_candidates(logits, drafts, step_key, *, temperature, top_p, top_k,
     return emitted, acc
 
 
+@jax.named_scope("verify")
 def _draft_fn(prompt_rep, state, *, Tp, spec_k, spec_ngram, pad_token_id,
               seed_rep=None, seed_len=None):
     """Draft step over the carry: build the prompt+output buffer and
@@ -218,6 +219,7 @@ def _draft_fn(prompt_rep, state, *, Tp, spec_k, spec_ngram, pad_token_id,
     return drafts
 
 
+@jax.named_scope("verify")
 def _verify_fn(params, config, state, drafts, *, Tp, max_tokens,
                eos_token_id, pad_token_id, spec_k, temperature, top_p,
                greedy, lora_scale, top_k, capture_logprobs, approx_top_k,

@@ -511,6 +511,7 @@ class RadixCache:
 # passed is consumed, the returned one takes its place.
 
 @partial(jit_donating, donate=0)
+@jax.named_scope("install")
 def copy_page(caches, src, dst):
     """COW split: duplicate physical page `src` into `dst` across every
     layer of the pool pytree ([L, num_pages, ...] leaves)."""
@@ -519,6 +520,7 @@ def copy_page(caches, src, dst):
 
 @partial(jit_donating, donate=7,
          static_argnames=("config", "page_size", "lora_scale"))
+@jax.named_scope("prefill")
 def suffix_logits(params, config, suffix_ids, positions, fill, last,
                   key_mask, caches, row_table, *, page_size, lora_scale):
     """Single-row suffix prefill: a `decode_verify` forward over the

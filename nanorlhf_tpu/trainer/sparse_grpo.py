@@ -123,6 +123,7 @@ class SparseGRPOTrainer(RLTrainer):
             return score
 
         @partial(jax.jit, static_argnums=(3,))
+        @jax.named_scope("score")
         def score(params, ref_params, qr, context_length: int):
             resp = qr[:, context_length:]
             lp = logprobs_from_logits(
@@ -186,6 +187,7 @@ class SparseGRPOTrainer(RLTrainer):
             return loss * loss_scale, aux
 
         @partial(jax.jit, static_argnums=(3,))
+        @jax.named_scope("update")
         def bucket_grads(trainable, frozen, mb, context_length, loss_scale):
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 trainable, frozen, mb, context_length, loss_scale
@@ -213,6 +215,7 @@ class SparseGRPOTrainer(RLTrainer):
         fsdp_axis = self._fsdp_axis()
 
         @partial(jax.jit, static_argnums=(3,))
+        @jax.named_scope("score")
         def score(params, ref_params, qr, context_length: int):
             # same attn_impl as `_sp_grad_fn`'s update forward (ADVICE r3)
             lp = sp_score_logprobs(
@@ -269,6 +272,7 @@ class SparseGRPOTrainer(RLTrainer):
             return loss * loss_scale, aux
 
         @partial(jax.jit, static_argnums=(3,))
+        @jax.named_scope("update")
         def sp_grads(trainable, frozen, mb, context_length, loss_scale):
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 trainable, frozen, mb, context_length, loss_scale
@@ -300,10 +304,13 @@ class SparseGRPOTrainer(RLTrainer):
         optimizer = self.optimizer
 
         @partial(jax.jit, donate_argnums=donate_argnums_on_accel(0, 1))
+        @jax.named_scope("update")
         def apply_grads(trainable, opt_state, grads):
-            updates, opt_state = optimizer.update(grads, opt_state, trainable)
-            return (optax.apply_updates(trainable, updates), opt_state,
-                    optax.global_norm(grads))
+            with jax.named_scope("optim"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      trainable)
+                trainable = optax.apply_updates(trainable, updates)
+            return trainable, opt_state, optax.global_norm(grads)
 
         self._apply_grads_cached = apply_grads
         return apply_grads

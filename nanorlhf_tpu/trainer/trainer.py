@@ -1547,6 +1547,7 @@ class RLTrainer:
         mesh = self.mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        @jax.named_scope("update")
         def update_minibatch(trainable, frozen, opt_state, minibatch, context_length):
             """One optimizer step over `grad_accum` scanned microbatches.
 
@@ -1601,8 +1602,10 @@ class RLTrainer:
                 micro, zero, jnp.arange(grad_accum, dtype=jnp.int32)
             )
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
-            updates, opt_state = optimizer.update(grads, opt_state, trainable)
-            trainable = optax.apply_updates(trainable, updates)
+            with jax.named_scope("optim"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      trainable)
+                trainable = optax.apply_updates(trainable, updates)
             stats = jax.tree.map(jnp.mean, auxes)
             # global gradient norm: the training sentinel's finite check
             # reads it, and policy/grad_norm_new is a useful health series
@@ -1667,6 +1670,7 @@ class RLTrainer:
             mesh, fsdp_axis = self.mesh, self._fsdp_axis()
 
             @partial(jax.jit, static_argnums=(3,))
+            @jax.named_scope("score")
             def score(params, ref_params, query_responses, context_length: int):
                 # same attn_impl as the update pass (ADVICE r3: no
                 # scoring/update kernel mismatch)
@@ -1689,6 +1693,7 @@ class RLTrainer:
             # for either forward — the rollout-phase scoring chunk size is
             # no longer bounded by the vocab term of forward_token_budget
             @partial(jax.jit, static_argnums=(3,))
+            @jax.named_scope("score")
             def score(params, ref_params, query_responses, context_length: int):
                 responses = query_responses[:, context_length:]
                 logprobs = fused_response_logprobs(
@@ -1708,6 +1713,7 @@ class RLTrainer:
             return score
 
         @partial(jax.jit, static_argnums=(3,))
+        @jax.named_scope("score")
         def score(params, ref_params, query_responses, context_length: int):
             responses = query_responses[:, context_length:]
             logits = padded_forward_logits(
@@ -1755,6 +1761,7 @@ class RLTrainer:
             mesh, fsdp_axis = self.mesh, self._fsdp_axis()
 
             @partial(jax.jit, static_argnums=(2,))
+            @jax.named_scope("score")
             def score_one(tree, query_responses, context_length: int):
                 return sp_score_logprobs(
                     tree, mcfg, query_responses, pad_id, cfg.temperature,
@@ -1763,6 +1770,7 @@ class RLTrainer:
                 )[:, context_length - 1 : -1]
         elif cfg.fused_logprob:
             @partial(jax.jit, static_argnums=(2,))
+            @jax.named_scope("score")
             def score_one(tree, query_responses, context_length: int):
                 return fused_response_logprobs(
                     tree, mcfg, query_responses,
@@ -1772,6 +1780,7 @@ class RLTrainer:
                 )
         else:
             @partial(jax.jit, static_argnums=(2,))
+            @jax.named_scope("score")
             def score_one(tree, query_responses, context_length: int):
                 responses = query_responses[:, context_length:]
                 logits = padded_forward_logits(
@@ -3376,6 +3385,7 @@ class RLTrainer:
                                  lora_scale=value_lora_scale)
 
             @partial(jax.jit, static_argnums=(2,))
+            @jax.named_scope("score")
             def value_fn(vparams, qr_chunk, context_length: int):
                 v = scorer(vparams, query_responses=qr_chunk)[:, :, 0]
                 return v[:, context_length - 1 : -1]

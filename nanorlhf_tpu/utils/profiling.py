@@ -14,6 +14,9 @@ equivalents:
   Every phase is also a `jax.profiler.TraceAnnotation` (the profiler's own
   clock: `trainer.<phase>`, `serving.<phase>`, `session.<phase>`), and with a
   telemetry.SpanTracer attached a trace span on the calling thread's track.
+- `DEVICE_SCOPES`: the one vocabulary of `jax.named_scope` names the jitted
+  programs carry, so that a profiler trace says what DEVICE time was spent
+  on (the host half is `PhaseTimer`'s).
 - `trace_profile`: a `jax.profiler` trace context writing a TensorBoard-
   loadable profile (XLA op breakdown, HBM usage) to a directory; start/stop
   stay balanced on exception, so a failed step doesn't wedge the profiler
@@ -34,6 +37,42 @@ import time
 from typing import Optional
 
 import jax
+
+
+# The names of the device's time. Every jitted body opens with its program's
+# scope and the model's parts carry theirs, all through `jax.named_scope`
+# directly: metadata of the compiled program (`op_name`), no operation, no
+# option. A trace taken with `ProfileWindow` / `touch PROFILE` shows them in
+# TensorBoard's op profile and trace viewer; `benchmark/harness/
+# scope_trace.py` reduces them to seconds by scope (docs/OBSERVABILITY.md
+# section 4, which also has the warm-cache trap).
+#
+# program level, at the top of a jitted body:
+#   prefill   the one-jit rollout's prompt forward (`sampler._prefill_state`),
+#             an admission's forward (`_admit_one`, `radix.suffix_logits`),
+#             a prefill piece of a chunked admission (`_prefill_chunk_fwd`)
+#   decode    a decode step (`sampler._decode_body`, every read extent;
+#             `session._session_decode_body`)
+#   verify    a speculative step (`speculative._draft_fn` + `_verify_fn`)
+#   install   what an admission does beside its forward (`_install_row`,
+#             `_first_token`, `_admit_sample`, `_beat_report`, `_end_row`,
+#             page alloc / release, `radix.copy_page`)
+#   score     the trainer's no-gradient forwards (policy, reference, value)
+#   update    `update_minibatch` and its kin; `loss` and `optim` inside it
+#   sync      the weight hand-off to the rollout's copy
+# layer level (`core/model.py`, `core/mla.py`, the samplers, `ops/`):
+#   embed, norm, attn (attn.qkv: projections + rotary; attn.write: the new
+#   tokens' K/V into the cache; attn.read: QK^T, softmax, PV or the kernel;
+#   attn.out), mlp (the residual add inside), head (final norm + unembedding),
+#   sample, logprob.
+# The older families stay INSIDE these, innermost around their kernels, whose
+# custom calls take their names from them: `attn.global` / `attn.window`
+# (a pattern model's read, in `attn`), `mla.*` (in `attn`), `moe.*` (in `mlp`).
+DEVICE_SCOPES = (
+    "prefill", "decode", "verify", "install", "score", "update", "sync",
+    "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
+    "attn.out", "mlp", "head", "sample", "logprob", "loss", "optim",
+)
 
 
 class PhaseTimer:
