@@ -257,7 +257,10 @@ def use_paged_decode_kernel(config: ModelConfig) -> bool:
     read of equal-width K and V pages and does not compute the absorbed
     form (handed the one-array pool as K and as V it refused the 576-wide
     page: "Slice shape along dimension 4 must be aligned to tiling (128)",
-    compiled for a v5e, PR 31)."""
+    compiled for a v5e, PR 31). A pattern model's T > 1 paged read (a
+    prefill piece, a suffix forward) goes by the same rule: the flash kernel
+    over the pages in place (ops/paged_prefill_attention) where this is
+    true, `_attend_paged_blocks` where it is not (`_pattern_attention`)."""
     if config.kv_lora_rank:
         return False
     return config.kv_cache_quant != "int8" and use_expert_kernel(config)
@@ -949,8 +952,11 @@ def _attend_paged_blocks(pools, layer, table, page_size, mask, first, last, q):
     prefill's queries need the slots from the chunk's first key (a window
     layer's: `window` before it) to its last, not the row's whole table: at
     16,384 slots the gathered view's scores alone are 1.9 GB a layer, a
-    block's 0.12. `mask` [B, 1, T, width] is the kind's own (the window per
-    query); q [B, H, T, hd]; first/last [B] int32. Returns [B, H, T, hd]."""
+    block's 0.12. The plain form of that read (`"xla"`, off the TPU, under a
+    mesh) and the oracle of its kernel, ops/paged_prefill_attention, which a
+    TPU takes (`use_paged_decode_kernel`). `mask` [B, 1, T, width] is the
+    kind's own (the window per query); q [B, H, T, hd]; first/last [B]
+    int32. Returns [B, H, T, hd]."""
     B, H, T, hd = q.shape
     KV = pools[0].shape[2]
     nb, width = table.shape[1], mask.shape[-1]
@@ -1019,8 +1025,9 @@ def _pattern_attention(config, q, k, v, mask, kv_cache, cache_index,
                        spmd):
     """A pattern model's attention read, for one layer of one kind: the
     kind's `mask` carries the window per query on every path; the reads that
-    go by bounds (the two decode kernels, the paged T > 1 walk) get the
-    kind's own lower bound from their caller. `(out [B, H, T, hd], the
+    go by bounds (the two decode kernels, the paged T > 1 read: the flash
+    kernel over the pages on a TPU, XLA's walk elsewhere) get the kind's own
+    lower bound from their caller. `(out [B, H, T, hd], the
     kind's updated cache stacks | None)`. What a pattern model does not have
     raises where it is asked for (`_pattern_caches`, `sampler.compose_check`):
     an int8 cache, speculative decode."""
@@ -1046,8 +1053,21 @@ def _pattern_attention(config, q, k, v, mask, kv_cache, cache_index,
 
     if verify_bounds is not None and paged is not None:
         first, fill = verify_bounds
-        out = _attend_paged_blocks(new_cache, layer, paged[0], paged[1], mask,
-                                   first, fill + (T - 1), q)
+        if use_paged_decode_kernel(config):
+            # a prefill piece or a suffix forward on a TPU: the flash kernel
+            # over the row's pages in place, under a scope of its own
+            # (harness/attn_trace.py takes a custom call named after
+            # `attn.global` / `attn.window` for a DECODE read)
+            from nanorlhf_tpu.ops.paged_prefill_attention import (
+                paged_prefill_attention,
+            )
+
+            with jax.named_scope("attn.paged_flash"):
+                out = paged_prefill_attention(
+                    q, *new_cache, layer, paged[0], first, fill, window)
+        else:
+            out = _attend_paged_blocks(new_cache, layer, paged[0], paged[1],
+                                       mask, first, fill + (T - 1), q)
     elif verify_bounds is not None:
         out = gqa_attention(q, *views(), mask)
     elif T > 1:

@@ -802,6 +802,15 @@ class DecodeSession:
         self._row_reused_np = np.zeros((R,), np.int64)
         self.attn_in_place = int(not self.spec
                                  and use_paged_decode_kernel(config))
+        # forwards dispatched for chunked admissions (`_prefill_tick`: the
+        # pieces and each one's closing suffix forward), and whether their
+        # T > 1 paged read is the flash kernel over the pages in place
+        # (`core/model._pattern_attention`: a pattern model under the decode
+        # read's rule) or XLA's walk / gathered view
+        self.prefill_pieces = 0
+        self.prefill_read_in_place = int(
+            config.attention_pattern is not None
+            and use_paged_decode_kernel(config))
 
         # dispatch accounting (module docstring): launches = model
         # forwards outside the decode/verify loop; decode iterations come
@@ -1107,6 +1116,7 @@ class DecodeSession:
         greedy streams are bit-identical (sampled rows decode at later
         global folds, so they match in distribution only)."""
         p = self._pending[0]
+        self.prefill_pieces += 1
         remaining = self.Tp - p.next_slot
         C = self.prefill_chunk
         if remaining <= C:

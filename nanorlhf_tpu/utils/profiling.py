@@ -68,10 +68,16 @@ import jax
 # The older families stay INSIDE these, innermost around their kernels, whose
 # custom calls take their names from them: `attn.global` / `attn.window`
 # (a pattern model's read, in `attn`), `mla.*` (in `attn`), `moe.*` (in `mlp`).
+# `attn.paged_flash` sits inside those two around ONE kernel, the T > 1 read
+# of a prefill piece over the pages in place (ops/paged_prefill_attention):
+# a custom call named `attn.global*` / `attn.window*` is a DECODE read to
+# the benchmark's trace reader (harness/attn_trace.py), and this one must
+# not be, whatever names it (its jitted wrapper today).
 DEVICE_SCOPES = (
     "prefill", "decode", "verify", "install", "score", "update", "sync",
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
-    "attn.out", "mlp", "head", "sample", "logprob", "loss", "optim",
+    "attn.out", "attn.paged_flash", "mlp", "head", "sample", "logprob",
+    "loss", "optim",
 )
 
 

@@ -530,7 +530,15 @@ def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
     reads them through the in-place kernel once a layer, named by its
     kind's scope (`%attn.global*`, `%attn.window*`: what
     benchmark/harness/attn_trace.py finds in the device trace); the expert
-    matmuls are the grouped-matmul kernel."""
+    matmuls are the grouped-matmul kernel. The prefill chunk (ISSUE 37)
+    reads them through the flash kernel over the pages in place, once a
+    layer whose output something reads, under the inner scope `attn.paged_flash`, whose custom call that
+    reader's pattern does NOT take for a decode read; no float32
+    `[.., 1024, 1024]` score array is left in the module (XLA's walk wrote
+    63), and whatever still produces an array the size of a pool leaf is the
+    new tokens' in-place scatter (`attn.write`, which the compiler rewrites
+    over a `[rows, 128]` view of the leaf: the eight "whole-leaf" fusions of
+    PERF.md section 5), never a gather, a transpose or a copy of one."""
     import dataclasses
     import re
 
@@ -598,10 +606,73 @@ def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
               if op.startswith("copy") and set(_shapes(result)) & set(pools)]
     assert not copies, "\n".join(copies)
     assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    from harness.attn_trace import KERNEL
+
+    calls = [line.strip() for line in hlo.splitlines()
+             if re.match(r"\s*%(attn\.|paged_prefill)[\w.]* = \S+ custom-call\(",
+                         line)]
+    decode_reads = [KERNEL.match(line).group(1) for line in calls
+                    if KERNEL.match(line)]
     if case == "decode_chunk":
-        kinds = re.findall(r"%attn\.(global|window)[\w.]* = \S+ custom-call\(", hlo)
-        assert kinds.count("global") == 1 and kinds.count("window") == 3, kinds
+        assert decode_reads.count("global") == 1
+        assert decode_reads.count("window") == 3 and len(calls) == 4, calls
+    else:
+        assert not decode_reads, decode_reads
+        # (a KV-only piece returns the pools alone: the LAST layer's read
+        # feeds nothing and is not in the module)
+        # and a kernel call is named by its jitted wrapper (by its scope,
+        # `%attn.paged_flash*`, should the wrapper lose its jit)
+        assert len(calls) == 3 and all(
+            line.startswith("%paged_prefill_attention") for line in calls), calls
+        scopes = re.findall(
+            r'custom-call\(.*op_name="([^"]*attn\.paged_flash[^"]*/pallas_call)"', hlo)
+        assert sum("attn.global/attn.paged_flash" in s for s in scopes) == 1
+        assert sum("attn.window/attn.paged_flash" in s for s in scopes) == 2
+        assert not re.findall(r"f32\[[\d,]*1024,1024\]", hlo)
+        sizes = {int(np.prod(shape)) for _, shape in pools}
+        fused_roots = {name: instrs[-1][2] for name, instrs in comps.items()}
+        moved = []
+        for name, instrs in comps.items():
+            for instr, result, op, rest in instrs:
+                shapes = _shapes(result)
+                if len(shapes) != 1 or int(np.prod(shapes[0][1])) not in sizes:
+                    continue
+                if op == "fusion":
+                    op = fused_roots[re.search(r"calls=%?([\w.\-]+)", rest).group(1)]
+                if op not in ("parameter", "bitcast", "get-tuple-element",
+                              "scatter"):
+                    moved.append(f"{name}: {instr} = {result} {op}")
+        assert not moved, "\n".join(moved)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("T", [1, 16, 37, 512, 1024])
+@pytest.mark.parametrize("window", [0, 4096])
+def test_paged_prefill_kernel_compiles_at_every_suffix_width_on_v5e(
+        window, T, v5e, compiled_kernels):
+    """ISSUE 37: the flash read over the pages in place, alone, at the
+    `serve-smallthinker-longshort` cell's pools and at the widths an
+    admission forward can have: the piece (1,024), a power-of-two suffix
+    bucket down to one token, and a bucket cut to the slots a row has left
+    (`radix.bucket_len` returns any number then: 37). Mosaic must take the
+    query block's `[7, bq, 128] -> [7 * bq, 128]` fold at each (bq is whole
+    sublane tiles of bf16) and the scores must fit its VMEM budget."""
+    from nanorlhf_tpu.ops.paged_prefill_attention import paged_prefill_attention
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    L, N = (6, 1344) if window else (2, 4224)
+    pool = ((L, N, 4, PAGE, 128), jnp.bfloat16)
+    args = [((1, 28, T, 128), jnp.bfloat16), pool, pool, ((), jnp.int32),
+            ((1, 128), jnp.int32), ((1,), jnp.int32), ((1,), jnp.int32)]
+    compiled = jax.jit(
+        lambda *a: paged_prefill_attention(*a, window)).lower(
+        *(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+          for shape, dtype in args)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # the pools are read where they lie: nothing their size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
 def test_chip_smoke_refuses_a_cpu_backend():

@@ -159,13 +159,15 @@ def test_the_one_jit_rollout_names_its_prefill_and_its_decode_loops(kind):
     assert top == {"prefill", "decode"}, top
 
 
-def session_programs(kind):
+def session_programs(kind, **config):
     """{name: lowered} of a serving session's programs over a page pool."""
+    import dataclasses
+
     from nanorlhf_tpu.sampler.paged import session
     from nanorlhf_tpu.sampler.paged.pages import ring_blocks
     from nanorlhf_tpu.serving.radix import copy_page, suffix_logits
 
-    cfg = KINDS[kind]()
+    cfg = dataclasses.replace(KINDS[kind](), **config)
     params = params_of(cfg)
     nb = (PROMPT + NEW) // PAGE
     pages, table, row_table = ROWS * nb + nb, spec((ROWS, nb), jnp.int32), \
@@ -224,6 +226,29 @@ def test_a_serving_sessions_programs_name_their_steps(kind):
         check_matmuls_and_innermost(ops, "prefill")
     assert {scope for _, _, scope in ops_of(lowered["copy"]) if scope} == {
         "install"}
+
+
+def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
+    """ISSUE 37: under the decode read's rule (`"pallas"`; interpret mode
+    here, so the kernel's body is XLA ops that carry its scope) a piece's
+    and a suffix forward's paged read is `ops/paged_prefill_attention`,
+    under `attn.paged_flash` INSIDE its kind's scope: its time stays
+    `prefill/attn/attn.global|window` for the prefill readers, and its custom
+    call is not named `attn.global*` / `attn.window*`, which
+    harness/attn_trace.py takes for a decode read. The decode chunk has no
+    such scope, and XLA's walk (the default off the TPU) none either."""
+    assert "attn.paged_flash" in DEVICE_SCOPES
+    flash = session_programs("pattern", attention_impl="pallas")
+    for name in ("piece", "suffix"):
+        scopes = {scope for _, _, scope in ops_of(flash[name])}
+        for kind in ("attn.global", "attn.window"):
+            assert f"prefill/attn/{kind}/attn.paged_flash" in scopes, (name, kind)
+        assert not any(s.endswith("/attn.paged_flash")
+                       and s.split("/")[-2] not in ("attn.global", "attn.window")
+                       for s in scopes)
+    assert "attn.paged_flash" not in parts_of(ops_of(flash["chunk"]), "decode")
+    walk = session_programs("pattern")
+    assert "attn.paged_flash" not in parts_of(ops_of(walk["piece"]), "prefill")
 
 
 def test_what_an_admission_does_beside_its_forward_is_install():

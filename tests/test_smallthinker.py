@@ -320,12 +320,12 @@ def test_ring_table_ends_at_the_rows_last_block():
 
 # ------------------------------------------------------------- the session
 
-def session(params, shuffle=None, **kw):
+def session(params, shuffle=None, cfg=CFG, **kw):
     from nanorlhf_tpu.sampler.paged.session import DecodeSession
     from nanorlhf_tpu.serving.radix import RadixCache
 
     sess = DecodeSession(
-        params, CFG, rows=3, prompt_len=48, max_tokens=24, page_size=4,
+        params, cfg, rows=3, prompt_len=48, max_tokens=24, page_size=4,
         eos_token_id=1, pad_token_id=0, key=jax.random.PRNGKey(1), per_row=True,
         prefix_cache=RadixCache(headroom=0.0), sync_every=4,
         **{"prefill_chunk": 8, **kw})
@@ -376,6 +376,25 @@ def test_session_long_and_short_rows_follow_the_reference(params):
         np.testing.assert_array_equal(a, b)
 
 
+def test_chunked_admission_through_the_flash_kernel_serves_the_walks_tokens(
+        params):
+    """ISSUE 37: a chunked admission's pieces and suffix forwards read the
+    row's pages through `ops/paged_prefill_attention` under the decode
+    read's rule (`"pallas"`: interpret mode here) and through XLA's walk
+    otherwise; the greedy tokens are the same, for the long row (41 tokens
+    in pieces of 8, past the window of 8, the ring wrapped) and for the
+    short one (one suffix forward of a bucket of 8), and the session says
+    which read it built."""
+    _, walk, s_walk = serve_two(params)
+    _, flash, s_flash = serve_two(
+        params, cfg=dataclasses.replace(CFG, attention_impl="pallas"))
+    for a, b in zip(walk, flash):
+        np.testing.assert_array_equal(a, b)
+    assert (s_walk.prefill_read_in_place, s_flash.prefill_read_in_place) == (0, 1)
+    # 41 tokens from slot 7: five pieces of 8 and the closing forward
+    assert s_walk.prefill_pieces == s_flash.prefill_pieces == 6
+
+
 def test_a_suffix_bucket_past_a_small_budget_is_dropped(params):
     """An admission forward writes a power-of-two bucket of its suffix: 17
     real tokens go as 32, to slot 62, and a budget of 8 ends the row's ring
@@ -418,6 +437,10 @@ def test_engine_serves_and_counts(params):
     assert m["serving/kv_bytes_per_token_window"] == 3 * 2 * 2 * 16 * 4
     assert m["serving/window_pages_reused"] > 0
     assert 0 < m["serving/window_slots_read"] < m["serving/global_slots_read"]
+    # the two prompts of 30 go in pieces of 8 (three and a closing forward
+    # each), read by XLA's walk off the TPU
+    assert m["serving/prefill_pieces"] == 2 * 4
+    assert m["serving/prefill_read_in_place"] == 0
 
 
 @pytest.mark.parametrize("kw, what", [
