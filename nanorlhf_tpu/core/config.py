@@ -2,7 +2,9 @@
 OLMoE's sparse-expert block, A.X-K1's (DeepSeek-V3's) latent attention
 with leading dense layers before shared-plus-routed sigmoid experts, or
 SmallThinker's pattern of window layers with rotary embedding beside global
-layers without it over ReLU-gated experts (docs/SWA.md).
+layers without it over ReLU-gated experts (docs/SWA.md), or LFM2's pattern of
+gated short-convolution layers with a fixed-size state beside attention
+layers, over experts chosen by bias-corrected sigmoid scores (docs/STATE.md).
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
 `/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
@@ -115,6 +117,26 @@ class ModelConfig:
     # The experts' gate: silu(gate) * up (SwiGLU) or relu(gate) * up
     # (SmallThinker's "sparse ReGLU").
     expert_activation: str = "silu"  # silu | relu
+    # Layers whose operator is not attention (docs/STATE.md; empty = every
+    # layer attends). `layer_types[l]` is "conv" or "full_attention". A conv
+    # layer is LFM2's gated short convolution: `[b | c | u] = h W_in`, `g = b
+    # * u`, `y_t = c_t * sum_j w[j] g_(t-K+1+j)` over `conv_L_cache` = K
+    # causal depthwise taps, `x += y W_out`. It keeps no keys or values: its
+    # cache is the row's last K - 1 values of `g`, a STATE of fixed size
+    # beside the attention layers' pages (`conv_layers`, `stack_pattern`).
+    layer_types: tuple = ()
+    conv_L_cache: int = 0
+    # RMSNorm of q and k over each HEAD (a weight of `head_dim`), after the
+    # head split and before RoPE (LFM2's q_layernorm / k_layernorm).
+    qk_norm_per_head: bool = False
+    # The router's selection is bias-corrected: the top k are taken of
+    # `scores + expert_bias`, the weights of `scores` alone (LFM2's
+    # `use_expert_bias`; the tree then has `router.bias [L, E]`, float32).
+    use_expert_bias: bool = False
+    # KV heads the cache keeps side by side in one row of lanes: 2 packs
+    # heads of 64 into pages 128 lanes wide, which is what the paged kernels
+    # read (core/model.py `_pack_heads`; docs/STATE.md). 1: a head a row.
+    kv_head_pack: int = 1
     # What the router reads: the MLP's own input (the post-attention normed
     # state), or the layer's PRE-attention normed state (SmallThinker:
     # "router placed before attention").
@@ -192,35 +214,55 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
-    def attention_pattern(self):
-        """One period of the layers' attention kinds, `((window, rotary),
-        ...)`, the shortest that tiles the depth; None for a model without a
-        pattern (every layer global and rotated), whose programs are the
-        ones it always had."""
+    def layer_kinds(self) -> tuple:
+        """Every layer's kind, in model order: `"conv"` (`layer_types`), or
+        an attention layer's `(window, rotary)`."""
         L = self.num_hidden_layers
         win = tuple(bool(w) for w in self.sliding_window_layout) or (False,) * L
         rot = tuple(bool(r) for r in self.rope_layout) or (True,) * L
         if self.sliding_window <= 0:
             win = (False,) * L
         kinds = tuple(zip(win, rot))
-        if len(kinds) != L:
+        if len(kinds) != L or len(self.layer_types) not in (0, L):
             raise ValueError(
-                f"attention layouts of {len(kinds)} entries for "
-                f"{L} layers")
-        if all(k == (False, True) for k in kinds):
-            return None
-        for p in range(1, L + 1):
-            if L % p == 0 and kinds == kinds[:p] * (L // p):
+                f"layer layouts of {len(kinds)} and {len(self.layer_types)} "
+                f"entries for {L} layers")
+        if self.layer_types:
+            kinds = tuple("conv" if t == "conv" else k
+                          for t, k in zip(self.layer_types, kinds))
+        return kinds
+
+    def stack_pattern(self, start: int, n: int) -> tuple:
+        """One period of the kinds of layers `[start, start + n)`, the
+        shortest that tiles them: what the layer scan of that stack goes
+        over (`core/model._run_pattern_layers`)."""
+        kinds = self.layer_kinds[start:start + n]
+        for p in range(1, n + 1):
+            if n % p == 0 and kinds == kinds[:p] * (n // p):
                 return kinds[:p]
+        return kinds
+
+    @property
+    def attention_pattern(self):
+        """One period of the layers' kinds, `("conv" | (window, rotary),
+        ...)`, the shortest that tiles the stack of layers after the leading
+        dense ones (every layer, in a model of one stack); None for a model
+        without a pattern (every layer global attention with rotary), whose
+        programs are the ones it always had."""
+        if all(k == (False, True) for k in self.layer_kinds):
+            return None
+        dense = self.num_dense_layers
+        return self.stack_pattern(dense, self.num_hidden_layers - dense)
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers that keep a state and no pages (0 in a model without)."""
+        return sum(k == "conv" for k in self.layer_kinds)
 
     @property
     def window_layers(self) -> int:
         """Layers with a sliding window (0 without a pattern)."""
-        pattern = self.attention_pattern
-        if pattern is None:
-            return 0
-        return (sum(w for w, _ in pattern)
-                * (self.num_hidden_layers // len(pattern)))
+        return sum(k != "conv" and k[0] for k in self.layer_kinds)
 
     @property
     def live_rows_dispatch(self) -> bool:
@@ -400,6 +442,55 @@ class ModelConfig:
             rope_layout=((0, 1, 1, 1) * layers)[:layers])
 
     @classmethod
+    def lfm2_24b(cls) -> "ModelConfig":
+        """LiquidAI/LFM2-24B-A2B: 40 layers in periods of [conv, conv,
+        attention, conv], the first two with a dense SwiGLU of 11,776, the
+        rest 64 experts of width 1,536, 4 a token, chosen by sigmoid scores
+        plus a bias and weighted by the scores alone; 32 / 8 heads of 64
+        with a per-head q/k norm; tied head (docs/STATE.md)."""
+        return cls(
+            vocab_size=65536,
+            hidden_size=2048,
+            intermediate_size=11776,
+            num_hidden_layers=40,
+            num_attention_heads=32,
+            num_key_value_heads=8,
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-5,
+            tie_word_embeddings=True,
+            max_position_embeddings=128000,
+            attention_bias=False,
+            model_type="lfm2_moe",
+            num_experts=64,
+            num_experts_per_tok=4,
+            norm_topk_prob=True,
+            first_k_dense_replace=2,
+            moe_intermediate_size=1536,
+            scoring_func="sigmoid",
+            routed_scaling_factor=1.0,
+            layer_types=("conv", "conv", "full_attention", "conv") * 10,
+            conv_L_cache=3,
+            qk_norm_per_head=True,
+            use_expert_bias=True,
+            kv_head_pack=2,
+            expert_token_block=4096,
+        )
+
+    @classmethod
+    def lfm2_tiny(cls, vocab_size: int = 512, layers: int = 10) -> "ModelConfig":
+        """Test-size LFM2: the first `layers` of the same layout (two dense
+        conv layers, then [attention, conv, conv, conv] periods of expert
+        layers) at 8 experts, 2 a token, heads of 16 packed in pairs."""
+        return dataclasses.replace(
+            cls.lfm2_24b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=2,
+            max_position_embeddings=1024, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            layer_types=(("conv", "conv", "full_attention", "conv")
+                         * layers)[:layers])
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -455,6 +546,8 @@ class ModelConfig:
             return cls._axk1_from_hf(get)
         if model_type == "smallthinker":
             return cls._smallthinker_from_hf(get)
+        if model_type == "lfm2_moe":
+            return cls._lfm2_from_hf(get)
         # a window on a family this decoder builds with full attention only:
         # Qwen2's `use_sliding_window` (with `sliding_window`, `layer_types`
         # or `max_window_layers`), Mistral's bare `sliding_window`
@@ -571,6 +664,75 @@ class ModelConfig:
             rope_layout=() if all(rot) else rot,
             expert_activation="relu",
             router_input="pre_attention",
+            expert_token_block=4096,
+        )
+
+    @classmethod
+    def _lfm2_from_hf(cls, get) -> "ModelConfig":
+        """LFM2-MoE's published keys (docs/STATE.md). What the layers of
+        docs/STATE.md do not compute raises: a convolution bias, a layer
+        type other than `conv` and `full_attention`, a model without an
+        attention layer (the session's page pool keeps that kind) and rope
+        scaling."""
+        L = int(get("num_hidden_layers"))
+        types = tuple(str(t) for t in (get("layer_types") or ()))
+        if len(types) != L:
+            raise ValueError(
+                f"lfm2_moe: layer_types of {len(types)} entries for {L} layers")
+        other = sorted(set(types) - {"conv", "full_attention"})
+        if other:
+            raise ValueError(
+                f"lfm2_moe: layer_types {other} are not implemented (only "
+                "'conv' and 'full_attention': docs/STATE.md)")
+        if "full_attention" not in types or "conv" not in types:
+            raise ValueError(
+                "lfm2_moe: a model of one layer kind only is not "
+                "implemented: the cache spec keeps pages AND a state "
+                "(docs/STATE.md)")
+        if get("conv_bias"):
+            raise ValueError("lfm2_moe: conv_bias=True is not implemented "
+                             "(the published config has false)")
+        K = int(get("conv_L_cache") or 0)
+        if K < 2:
+            raise ValueError(f"lfm2_moe: conv_L_cache={K} (a state of "
+                             "K - 1 values needs K >= 2)")
+        rope = get("rope_parameters") or {}
+        kind = rope.get("rope_type", "default")
+        if kind not in ("default", None) or get("rope_scaling"):
+            raise ValueError(f"lfm2_moe: rope scaling ({kind!r}, "
+                             f"{get('rope_scaling')!r}) is not implemented")
+        dense = int(get("num_dense_layers") or 0)
+        H, KV = int(get("num_attention_heads")), int(get("num_key_value_heads"))
+        hd = get("head_dim") or int(get("hidden_size")) // H
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=L,
+            num_attention_heads=H,
+            num_key_value_heads=KV,
+            head_dim=get("head_dim", None),
+            rope_theta=float(rope.get("rope_theta",
+                                      get("rope_theta", 1_000_000.0))),
+            rms_norm_eps=get("norm_eps", 1e-5),
+            tie_word_embeddings=bool(get("tie_word_embeddings", True)),
+            max_position_embeddings=get("max_position_embeddings", 128000),
+            attention_bias=False,
+            model_type="lfm2_moe",
+            num_experts=int(get("num_experts")),
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            norm_topk_prob=bool(get("norm_topk_prob", True)),
+            first_k_dense_replace=dense,
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            scoring_func="sigmoid",
+            routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+            layer_types=types,
+            conv_L_cache=K,
+            qk_norm_per_head=True,
+            use_expert_bias=bool(get("use_expert_bias", False)),
+            # two heads of 64 (or narrower) a row of lanes: at 64 the pages
+            # are the 128 lanes wide that the paged kernels read
+            kv_head_pack=2 if (hd <= 64 and KV % 2 == 0) else 1,
             expert_token_block=4096,
         )
 

@@ -8,6 +8,9 @@ PATTERN (window layers with rotary embedding beside global layers without,
 `ModelConfig.attention_pattern`): its scan goes over periods, each layer of a
 period of its own static kind, and its cache is two groups of stacks, the
 global layers' and the window layers' (`_run_pattern_layers`, docs/SWA.md).
+LFM2's pattern has a kind that is no attention at all: a gated short
+convolution (`_conv_operator`) whose cache is a STATE of fixed size a row, a
+third group beside the two of pages (docs/STATE.md).
 
 TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
 `/root/reference/GRPO/grpo.py:218-224`):
@@ -61,6 +64,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
         from nanorlhf_tpu.core import mla
 
         return mla.init_params(config, key, dtype)
+    if config.conv_layers:
+        return _init_conv_model_params(config, key, dtype)
     hd = config.actual_head_dim
     D, F, V = config.hidden_size, config.intermediate_size, config.vocab_size
     H, KV, L = config.num_attention_heads, config.num_key_value_heads, config.num_hidden_layers
@@ -111,6 +116,78 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
     if config.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((L, H * hd), dtype)
         params["layers"]["k_norm"] = jnp.ones((L, KV * hd), dtype)
+    return params
+
+
+# an attention layer's own leaves; in a model with conv layers they are
+# stacked over the attention layers of their stack only, as `conv` is over
+# its conv layers (`_run_pattern_layers`)
+_ATTENTION_LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm", "k_norm")
+
+
+def _init_conv_model_params(config: ModelConfig, key, dtype) -> dict:
+    """The tree of a model with conv layers (LFM2, docs/STATE.md):
+    `dense_layers` (the leading layers with a dense SwiGLU) and `layers` (the
+    expert layers), each with the leaves every layer has stacked over its
+    layers (`input_layernorm`, the operator's norm; `post_attention_layernorm`,
+    the MLP's; the MLP), the attention layers' projections and per-head q/k
+    norms stacked over ITS attention layers, and `conv` over its conv layers:
+    `in_proj.kernel [n, D, 3D]` (`[b | c | u]`), `conv.kernel [n, K, D]` (the
+    depthwise taps, oldest first), `out_proj.kernel [n, D, D]`. The router
+    carries `bias [n, E]` (float32) where the selection is bias-corrected."""
+    hd = config.actual_head_dim
+    D, V = config.hidden_size, config.vocab_size
+    H, KV, K = (config.num_attention_heads, config.num_key_value_heads,
+                config.conv_L_cache)
+    E, dense = config.num_experts, config.num_dense_layers
+    keys = iter(jax.random.split(key, 40))
+
+    def normal(shape, scale):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def stack(start, n, width, experts):
+        kinds = config.layer_kinds[start:start + n]
+        nc = sum(k == "conv" for k in kinds)
+        na = n - nc
+        fan = lambda *shape: normal(shape, 1.0 / jnp.sqrt(shape[-2]))  # noqa: E731
+        lead = (n, experts) if experts else (n,)
+        mlp = {"gate_proj": {"kernel": fan(*lead, D, width)},
+               "up_proj": {"kernel": fan(*lead, D, width)},
+               "down_proj": {"kernel": fan(*lead, width, D)}}
+        tree = {"input_layernorm": jnp.ones((n, D), dtype),
+                "post_attention_layernorm": jnp.ones((n, D), dtype)}
+        if experts:
+            tree["experts"] = mlp
+            tree["router"] = {"kernel": fan(n, D, experts)}
+            if config.use_expert_bias:
+                tree["router"]["bias"] = jnp.zeros((n, experts), jnp.float32)
+        else:
+            tree.update(mlp)
+        if nc:
+            tree["conv"] = {
+                "in_proj": {"kernel": fan(nc, D, 3 * D)},
+                "conv": {"kernel": normal((nc, K, D), 1.0 / jnp.sqrt(K))},
+                "out_proj": {"kernel": fan(nc, D, D)}}
+        if na:
+            tree.update({
+                "q_proj": {"kernel": fan(na, D, H * hd)},
+                "k_proj": {"kernel": fan(na, D, KV * hd)},
+                "v_proj": {"kernel": fan(na, D, KV * hd)},
+                "o_proj": {"kernel": fan(na, H * hd, D)}})
+            if config.qk_norm_per_head:
+                tree["q_norm"] = jnp.ones((na, hd), dtype)
+                tree["k_norm"] = jnp.ones((na, hd), dtype)
+        return tree
+
+    params = {"embed_tokens": normal((V, D), 0.02),
+              "norm": jnp.ones((D,), dtype)}
+    L = config.num_hidden_layers
+    if dense:
+        params["dense_layers"] = stack(0, dense, config.intermediate_size, 0)
+    params["layers"] = stack(dense, L - dense, config.expert_width, E)
+    if not config.tie_word_embeddings:
+        params["lm_head"] = normal((D, V), 0.02)
     return params
 
 
@@ -571,6 +648,8 @@ def _in_token_blocks(fn, h, block: int, *more):
         # of the real tokens: the padding rows' assignments do not count
         **({"absent": jnp.sum(cut(aux["absent_by_token"]))}
            if "absent" in aux else {}),
+        **({"bias_changed": cut(aux["bias_changed"]).reshape(lead)}
+           if "bias_changed" in aux else {}),
     }
 
 
@@ -615,6 +694,9 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
         if config.scoring_func != "softmax" or config.routed_scaling_factor != 1.0:
             routing.update(scoring=config.scoring_func,
                            routed_scale=config.routed_scaling_factor)
+        if config.use_expert_bias:      # LFM2: selects with it, weighs without
+            routing.update(select_bias=layer_params["router"]["bias"],
+                           norm_eps=1e-6)
         def routed(x, router_h=None):
             return moe_mlp(
                 x, layer_params["router"]["kernel"],
@@ -643,13 +725,16 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
                 decode_bounds=None, verify_bounds=None, paged=None, layer=0,
                 expert_stack=None, stack_start=0, live=None, kind=None,
-                expert_layer=None):
+                expert_layer=None, conv_ctx=None):
     """One decoder layer. If kv_cache is not None, operate incrementally.
 
     `kind=(window, rotary)` marks a layer of a pattern model
     (`_run_pattern_layers`): `mask`, `kv_cache`, `decode_bounds`,
     `verify_bounds` and `paged` are then its KIND's, `layer` its index into
-    its kind's cache stacks and `expert_layer` its place in the model.
+    its kind's cache stacks and `expert_layer` its place in its stack.
+    `kind="conv"`: the operator is the gated short convolution
+    (`_conv_operator`), `kv_cache` the state group, `paged` the rows' place
+    in it and `conv_ctx` what the call knows of its tokens.
 
     Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
     kv_cache: the STACKED cache of every layer (init_kv_cache /
@@ -693,7 +778,11 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
     with jax.named_scope("attn"):
-        if config.kv_lora_rank:
+        if kind == "conv":
+            x, new_cache = _conv_operator(
+                config, x, h, layer_params["conv"], kv_cache, layer, paged,
+                conv_ctx)
+        elif config.kv_lora_rank:
             if attn_fn is not None:
                 raise NotImplementedError(
                     "latent attention has no sequence-parallel form: ring "
@@ -747,10 +836,15 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
         q = q.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
         k = k.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
         v = v.reshape(B, T, KV, hd).transpose(0, 2, 1, 3)
+        if config.qk_norm_per_head:     # LFM2: over each head, before RoPE
+            q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
+            k = rms_norm(k, layer_params["k_norm"], config.rms_norm_eps)
 
         if kind is None or kind[1]:     # a NoPE layer carries no position
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
+        if config.kv_head_pack > 1:
+            q, k, v = _pack_heads(q, k, v, config.kv_head_pack)
 
     if kind is not None:
         if attn_fn is not None:
@@ -775,9 +869,110 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
                                   decode_bounds, verify_bounds, paged, layer,
                                   spmd, attn_fn)
     with jax.named_scope("attn.out"):
+        if config.kv_head_pack > 1:
+            out = _unpack_heads(out, KV, config.kv_head_pack)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
         out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
         return x + out, new_cache
+
+
+def _pack_heads(q, k, v, pack: int):
+    """Heads narrower than a row of lanes, `pack` KV heads side by side:
+    k, v [B, KV, T, hd] -> [B, KV / pack, T, pack * hd], which is what the
+    cache then holds (pages 128 lanes wide for heads of 64: the layout every
+    paged kernel reads, and no lane of a page is padding); q [B, H, T, hd] ->
+    [B, H, T, pack * hd], each query head's values in ITS KV head's lanes and
+    zeros in the others', so `q' . k'` is `q . k` of its own head and the
+    read is a GQA read of KV / pack heads with `pack` times the group. The
+    values come back in the same lanes (`_unpack_heads`). q is scaled by
+    sqrt(pack): every read divides by the square root of the width it sees."""
+    B, KV, T, hd = k.shape
+    H = q.shape[1]
+    G = H // KV
+    lanes = lambda a: a.reshape(B, KV // pack, pack, T, hd).transpose(  # noqa: E731
+        0, 1, 3, 2, 4).reshape(B, KV // pack, T, pack * hd)
+    own = (jnp.arange(H) // G) % pack                           # [H]
+    sel = own[:, None] == jnp.arange(pack)[None, :]             # [H, pack]
+    q = q * jnp.asarray(pack ** 0.5, q.dtype)
+    q = jnp.where(sel[None, :, None, :, None], q[:, :, :, None, :], 0)
+    return q.reshape(B, H, -1, pack * hd), lanes(k), lanes(v)
+
+
+def _unpack_heads(out, KV: int, pack: int):
+    """A packed read's output [B, H, T, pack * hd] -> [B, H, T, hd]: each
+    query head keeps the lanes of its own KV head (`_pack_heads`)."""
+    B, H, T, W = out.shape
+    hd = W // pack
+    own = (jnp.arange(H) // (H // KV)) % pack
+    out = out.reshape(B, H, T, pack, hd)
+    return jnp.take_along_axis(
+        out, own[None, :, None, None, None], axis=3)[:, :, :, 0, :]
+
+
+def _conv_operator(config, x, h, conv, state_group, layer, state_rows, ctx):
+    """A conv layer's operator on the normed state `h`, with its residual
+    (docs/STATE.md): `[b | c | u] = h W_in`, `g = b * u`, `y_t = c_t *
+    sum_j w[j] * g_(t-K+1+j)`, `x + y W_out`. `(x, the updated state group |
+    None)`.
+
+    The layer's cache is `state_group = (state,)`, `[conv layers, K - 1,
+    rows, D]`: a row's last K - 1 values of `g`, oldest first, which stand
+    before this call's `g` in the convolution (rows before D, so that a
+    decode step's `[rows, D]` operands are the state's own tiles: with the
+    rows outside the K - 1 values the chip's compiler relaid the whole state
+    at both ends of every decode chunk, compiled for a described v5e, PR
+    38). The call's B rows are rows `[r, r + B)` of it, `r =
+    state_rows[0, 0]` (`state_rows` [B, 1] int32 is the state kind's
+    "table": an admission's one row, a decode chunk's every row; None: rows
+    `[0, B)`, the contiguous cache). `ctx = (valid, fresh)`:
+    `valid` [B, T] bool marks the real tokens (a pad's `g` is 0, and the
+    state that leaves is the one after the row's LAST real token: left pads
+    before a prompt, a bucket's pads after a suffix, a decode step's rows
+    nobody listens to, which leave it as it was); `fresh` [B] bool marks the
+    rows that start here, whose incoming state counts as zeros whatever the
+    row's last occupant left. Without a cache the row starts from zeros.
+    Float32 products and sums over the taps, as the attention's softmax."""
+    B, T, D = x.shape
+    K = config.conv_L_cache
+    valid, fresh = ctx if ctx is not None else (None, None)
+    with jax.named_scope("attn.conv"):
+        with jax.named_scope("attn.conv.in"):
+            b, c, u = jnp.split(h @ conv["in_proj"]["kernel"], 3, axis=-1)
+            g = b * u
+            if valid is not None:
+                g = jnp.where(valid[..., None], g, 0)
+        with jax.named_scope("attn.conv.mix"):
+            if state_group is None:
+                past = jnp.zeros((B, K - 1, D), g.dtype)
+            else:
+                (state,) = state_group
+                row = 0 if state_rows is None else state_rows[0, 0]
+                past = jax.lax.dynamic_slice(
+                    state, (layer, 0, row, 0), (1, K - 1, B, D))[0]
+                past = past.transpose(1, 0, 2)              # [B, K - 1, D]
+                if fresh is not None:
+                    past = jnp.where(fresh[:, None, None], 0, past)
+            seq = jnp.concatenate([past.astype(g.dtype), g], axis=1)
+            taps = conv["conv"]["kernel"].astype(jnp.float32)       # [K, D]
+            mixed = sum(taps[j] * seq[:, j:j + T].astype(jnp.float32)
+                        for j in range(K))
+            y = (c.astype(jnp.float32) * mixed).astype(x.dtype)
+        new_group = None
+        if state_group is not None:
+            with jax.named_scope("attn.write"):
+                if valid is None:
+                    tail = seq[:, T:]
+                else:   # the K - 1 values up to the row's last real token
+                    last = jnp.where(
+                        valid.any(axis=1),
+                        T - 1 - jnp.argmax(valid[:, ::-1], axis=1), -1)
+                    at = last[:, None] + 1 + jnp.arange(K - 1)[None, :]
+                    tail = jnp.take_along_axis(seq, at[:, :, None], axis=1)
+                new_group = (jax.lax.dynamic_update_slice(
+                    state, tail.transpose(1, 0, 2)[None].astype(state.dtype),
+                    (layer, 0, row, 0)),)
+        with jax.named_scope("attn.conv.out"):
+            return x + y @ conv["out_proj"]["kernel"], new_group
 
 
 def _attention_read(config, q, k, v, mask, new_cache, decode_bounds,
@@ -1139,7 +1334,7 @@ def _rematerialized(config: ModelConfig, body):
 def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0,
                 lora_scale=1.0, remat=False, attn_fn=None, layer_transform=None,
                 decode_bounds=None, verify_bounds=None, paged=None, live=None,
-                cached_aux=False):
+                cached_aux=False, conv_ctx=None):
     """Scan each stack of stacked layer params over the layer body
     (`_layer_stacks`: one scan for every model but A.X-K1, which has two).
 
@@ -1158,13 +1353,16 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
 
     A pattern model goes through `_run_pattern_layers`; `mask`,
     `decode_bounds`, `verify_bounds` and `paged` are then `(global, window)`
-    pairs (`_kind_masks` and the entrypoints make them).
+    pairs (`_kind_masks` and the entrypoints make them), `paged` with the
+    state rows third in a model with conv layers, whose `conv_ctx = (valid
+    [B, T] | None, fresh [B] | None)` says which tokens are real and which
+    rows start here (`_conv_operator`).
     """
     if config.attention_pattern is not None:
         return _run_pattern_layers(
             config, params, x, cos, sin, mask, kv_caches, cache_index,
             lora_scale, remat, attn_fn, layer_transform, decode_bounds,
-            verify_bounds, paged, live, cached_aux)
+            verify_bounds, paged, live, cached_aux, conv_ctx)
     if kv_caches is None:
         def uncached_body(expert_stack):
             def body(carry, inp):
@@ -1228,76 +1426,117 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
         return x, new_caches, aux
 
 
+def _kind_group(kind) -> int:
+    """The cache group of a layer kind: 0 the global attention layers' pages,
+    1 the window layers', 2 the conv layers' state."""
+    return 2 if kind == "conv" else int(kind[0])
+
+
 def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
                         cache_index, lora_scale, remat, attn_fn,
                         layer_transform, decode_bounds, verify_bounds, paged,
-                        live, cached_aux):
-    """`_run_layers` for a model with an attention pattern: ONE scan over the
-    periods of `config.attention_pattern`, its body the period's layers in
-    order, each of its own static kind `(window, rotary)`. The stacked tree
-    `[L, ...]` is scanned as `[L / p, p, ...]` (a reshape of the leading
+                        live, cached_aux, conv_ctx=None):
+    """`_run_layers` for a model with a layer pattern: for each stack of
+    layers (`_layer_stacks`) ONE scan over the periods of its pattern
+    (`config.stack_pattern`), its body the period's layers in order, each of
+    its own static kind, `(window, rotary)` or `"conv"`. The stacked tree
+    `[n, ...]` is scanned as `[n / p, p, ...]` (a reshape of the leading
     axis; the expert kernels stay out of the xs and are addressed in place
-    at the layer's index, `_expert_xs`). Whatever differs by kind comes as a
-    `(global, window)` pair and a layer takes its kind's: the mask (built
-    once a call, `_kind_masks`), the decode and verify bounds, the block
-    table, and the CACHE, two groups of stacks `((k, v) of the global
-    layers, (k, v) of the window layers)`, both in the carry, a layer's index
-    into its group the count of that kind's layers before it."""
-    pattern = config.attention_pattern
-    p = len(pattern)
-    n = config.num_hidden_layers // p
-    per_period = [sum(1 for w, _ in pattern if w == kind) for kind in (False, True)]
-    rank = [sum(1 for w, _ in pattern[:j] if w == pattern[j][0])
-            for j in range(p)]
-    lora = params.get("lora", {}).get("layers")
+    at the layer's index, `_expert_xs`); in a model with conv layers the
+    leaves only one kind has (`_ATTENTION_LEAVES`, `conv`) are stacked over
+    that kind's layers and scanned by its count a period. Whatever differs
+    by kind comes as a tuple by cache group (`_kind_group`) and a layer
+    takes its kind's: the mask (built once a call, `_kind_masks`), the
+    decode and verify bounds, the block table, and the CACHE, groups of
+    stacks `((k, v) of the global layers, (k, v) of the window layers[,
+    (state,) of the conv layers])`, all in the carry, a layer's index into
+    its group the count of that kind's layers before it."""
     cached = kv_caches is not None
-    # the expert kernels always stay out of the xs: a period's slice of them
-    # is p layers' experts copied a scan step (2.3 GB at SmallThinker's
-    # widths, compiled for a described v5e, PR 34: the plain scoring path
-    # beside a served model). `ragged_dot` addresses the stack in place as
-    # the kernel does; its backward then transposes the whole stack a
-    # kernel, which full fine-tuning of such a model at real widths would
-    # have to repair (under LoRA the experts are frozen)
-    layer_xs, expert_stack = _expert_xs(params["layers"], in_place=True)
-    periods = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: a.reshape((n, p) + a.shape[1:]), tree)
-    pick = lambda pair, w: None if pair is None else pair[int(w)]  # noqa: E731
+    caches = tuple(kv_caches) if cached else None
+    pick = lambda pair, g: (None if pair is None or g >= len(pair)  # noqa: E731
+                            else pair[g])
+    split = config.conv_layers > 0
+    before = [0, 0, 0]      # layers of each group in the stacks so far
+    aux = None
+    for tree, lora, start, count in _layer_stacks(params):
+        pattern = config.stack_pattern(start, count)
+        p = len(pattern)
+        n = count // p
+        groups = [_kind_group(kind) for kind in pattern]
+        per_period = [groups.count(g) for g in range(3)]
+        rank = [groups[:j].count(groups[j]) for j in range(p)]
+        # the expert kernels always stay out of the xs: a period's slice of
+        # them is p layers' experts copied a scan step (2.3 GB at
+        # SmallThinker's widths, compiled for a described v5e, PR 34: the
+        # plain scoring path beside a served model). `ragged_dot` addresses
+        # the stack in place as the kernel does; its backward then
+        # transposes the whole stack a kernel, which full fine-tuning of
+        # such a model at real widths would have to repair (under LoRA the
+        # experts are frozen)
+        layer_xs, expert_stack = _expert_xs(tree, in_place=True)
+        periods = lambda t, per=p: jax.tree.map(  # noqa: E731
+            lambda a: a.reshape((n, per) + a.shape[1:]), t)
+        own_xs = None       # the leaves only one kind's layers have
+        if split:           # (attention's, conv's), each by its own count
+            layer_xs = dict(layer_xs)
+            conv_own = {"conv": layer_xs.pop("conv", None)}
+            attn_own = {name: layer_xs.pop(name) for name in _ATTENTION_LEAVES
+                        if name in layer_xs}
+            own_xs = (periods(attn_own, per_period[0] + per_period[1]),
+                      periods(conv_own, per_period[2]))
+        first = tuple(before)
 
-    def body(carry, inp):
-        y, caches = carry
-        period_params, period_lora, i = inp
-        auxes = []
-        for j, (window, rotary) in enumerate(pattern):
-            layer_params, lora_layer = jax.tree.map(
-                lambda a: a[j], (period_params, period_lora))
-            if layer_transform is not None:
-                layer_params, lora_layer = layer_transform(layer_params,
-                                                           lora_layer)
-            w = int(window)
-            y, cache, aux = _layer_body(
-                config, y, layer_params, cos, sin, masks[w],
-                caches[w] if cached else None, cache_index, lora_layer,
-                lora_scale, attn_fn=attn_fn,
-                decode_bounds=pick(decode_bounds, w),
-                verify_bounds=pick(verify_bounds, w), paged=pick(paged, w),
-                layer=i * per_period[w] + rank[j], expert_stack=expert_stack,
-                live=live, kind=(window, rotary), expert_layer=i * p + j)
-            if cached:
-                caches = tuple(cache if g == w else c
-                               for g, c in enumerate(caches))
-            auxes.append(aux)
-        keep = not cached or cached_aux
-        return (y, caches), (jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-                             if keep else None)
+        def body(carry, inp, pattern=pattern, groups=groups, rank=rank,
+                 per_period=per_period, expert_stack=expert_stack, p=p,
+                 first=first):
+            y, caches = carry
+            period_params, period_lora, i, period_own = inp
+            auxes = []
+            for j, kind in enumerate(pattern):
+                layer_params, lora_layer = jax.tree.map(
+                    lambda a: a[j], (period_params, period_lora))
+                g = groups[j]
+                if period_own is not None:
+                    # its place among the period's layers of its own leaves
+                    at = rank[j] if g == 2 else sum(
+                        q != 2 for q in groups[:j])
+                    layer_params = {**layer_params, **jax.tree.map(
+                        lambda a: a[at], period_own[int(g == 2)])}
+                if layer_transform is not None:
+                    layer_params, lora_layer = layer_transform(layer_params,
+                                                               lora_layer)
+                layer = i * per_period[g] + rank[j]
+                if first[g]:    # (no `+ 0` in a one-stack model's program)
+                    layer = layer + first[g]
+                y, cache, aux = _layer_body(
+                    config, y, layer_params, cos, sin, pick(masks, g),
+                    caches[g] if cached else None, cache_index, lora_layer,
+                    lora_scale, attn_fn=attn_fn,
+                    decode_bounds=pick(decode_bounds, g),
+                    verify_bounds=pick(verify_bounds, g),
+                    paged=pick(paged, g), layer=layer,
+                    expert_stack=expert_stack, live=live, kind=kind,
+                    expert_layer=i * p + j,
+                    **({"conv_ctx": conv_ctx} if kind == "conv" else {}))
+                if cached:
+                    caches = tuple(cache if k == g else c
+                                   for k, c in enumerate(caches))
+                auxes.append(aux)
+            keep = not cached or cached_aux
+            return (y, caches), (jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+                                 if keep else None)
 
-    if remat and not cached:
-        body = _rematerialized(config, body)
-    (x, caches), aux = jax.lax.scan(
-        body, (x, tuple(kv_caches) if cached else None),
-        (periods(layer_xs), periods(lora), jnp.arange(n, dtype=jnp.int32)))
-    if aux is not None:     # [L / p, p, ...] -> [L, ...]
-        aux = jax.tree.map(
-            lambda a: a.reshape((n * p,) + a.shape[2:]), aux)
+        if remat and not cached:
+            body = _rematerialized(config, body)
+        (x, caches), stack_aux = jax.lax.scan(
+            body, (x, caches),
+            (periods(layer_xs), periods(lora),
+             jnp.arange(n, dtype=jnp.int32), own_xs))
+        if stack_aux is not None:   # [n / p, p, ...] -> [n, ...]
+            aux = jax.tree.map(
+                lambda a: a.reshape((n * p,) + a.shape[2:]), stack_aux)
+        for g in range(3):
+            before[g] += n * per_period[g]
     return x, caches if cached else None, aux
 
 
@@ -1349,11 +1588,14 @@ def _kind_paged(config: ModelConfig, page_table, page_size):
         return None
     if config.attention_pattern is None:
         return page_table, page_size
-    if not isinstance(page_table, (tuple, list)) or len(page_table) != 2:
+    kinds = 3 if config.conv_layers else 2
+    if not isinstance(page_table, (tuple, list)) or len(page_table) != kinds:
         raise ValueError(
-            "a model with window layers takes page_table=(global table, "
-            "window table), one a kind of page pool (docs/SWA.md)")
-    return tuple((t, page_size) for t in page_table)
+            "a model with a layer pattern takes page_table=(global table, "
+            "window table[, state rows]), one a kind of cache (docs/SWA.md, "
+            "docs/STATE.md)")
+    # (the state kind's entry is the rows themselves: `_conv_operator`)
+    return tuple((t, page_size) for t in page_table[:2]) + tuple(page_table[2:])
 
 
 def unembedding(config: ModelConfig, params: dict):
@@ -1437,12 +1679,24 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     mask = _kind_masks(config, mask, lambda: jnp.arange(T)[None, :])
     x, _, aux = _run_layers(config, params, x, cos, sin, mask,
                             lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
-                            layer_transform=layer_transform)
+                            layer_transform=layer_transform,
+                            **_conv_ctx(config, attention_mask))
     if router_stats:
         from nanorlhf_tpu.ops.moe import router_stats as reduce_stats
 
         return x, reduce_stats(aux, attention_mask, config.num_experts)
     return x
+
+
+def _conv_ctx(config: ModelConfig, valid=None, fresh=None) -> dict:
+    """`_run_layers`' `conv_ctx` keyword for a model with conv layers, and
+    nothing for every other model. What a call has to COMPUTE comes as a
+    thunk (`fresh` always does) and is computed for a model with conv layers
+    only: an operation nobody reads still stands in the program of a loop's
+    body, and every other model's programs stay the ones they were."""
+    if not config.conv_layers:
+        return {}
+    return {"conv_ctx": tuple(v() if callable(v) else v for v in (valid, fresh))}
 
 
 def _padded_hidden(
@@ -1597,11 +1851,30 @@ def _pattern_caches(config: ModelConfig) -> tuple:
     two groups of cache stacks. It has no int8 form."""
     if config.kv_cache_quant == "int8":
         raise NotImplementedError(
-            "kv_cache_quant='int8' on a model with window layers is not "
-            "implemented: the int8 reads take one table and have no lower "
-            "bound (docs/SWA.md)")
-    return (config.num_hidden_layers - config.window_layers,
-            config.window_layers)
+            "kv_cache_quant='int8' on a model with window layers or conv "
+            f"layers ({config.model_type}) is not implemented: the int8 "
+            "reads take one table of one kind of cache and have no lower "
+            "bound (docs/SWA.md, docs/STATE.md)")
+    return (config.num_hidden_layers - config.window_layers
+            - config.conv_layers, config.window_layers)
+
+
+def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
+    """The conv layers' group of a cache, `((state,),)`: `[conv layers,
+    K - 1, rows, D]`, a row's last K - 1 values of `g` a layer, oldest first
+    (`_conv_operator`); nothing for a model without conv layers. Not a page:
+    its size does not grow with the row, no table addresses it, and a row's
+    is at the row's own index."""
+    if not config.conv_layers:
+        return ()
+    return ((jnp.zeros((config.conv_layers, config.conv_L_cache - 1, rows,
+                        config.hidden_size), dtype),),)
+
+
+def _cache_heads(config: ModelConfig) -> tuple:
+    """(KV heads, head width) as the cache holds them (`_pack_heads`)."""
+    pack = config.kv_head_pack
+    return config.num_key_value_heads // pack, config.actual_head_dim * pack
 
 
 def init_kv_cache(
@@ -1625,10 +1898,11 @@ def init_kv_cache(
     if config.attention_pattern is not None:
         # both groups whole: correct by mask, no slot saved (the paged pool
         # is where a window layer keeps a window's pages only)
+        KV, hd = _cache_heads(config)
         return tuple(
-            tuple(jnp.zeros((n, batch, config.num_key_value_heads, max_len,
-                             config.actual_head_dim), dtype) for _ in "kv")
-            for n in _pattern_caches(config))
+            tuple(jnp.zeros((n, batch, KV, max_len, hd), dtype) for _ in "kv")
+            for n in _pattern_caches(config)) + _state_group(config, batch,
+                                                             dtype)
     shape = (
         config.num_hidden_layers,
         batch,
@@ -1646,7 +1920,8 @@ def init_kv_cache(
 
 
 def init_paged_kv_cache(
-    config: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16
+    config: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16,
+    state_rows: int = 0,
 ) -> tuple[jnp.ndarray, ...]:
     """Paged KV cache: a global page pool shared by every row, addressed
     through a per-row block table (sampler/paged/pages.py).
@@ -1663,6 +1938,10 @@ def init_paged_kv_cache(
     `(layer, page, head, offset)` and gathers its rows' pages back out); the
     block table is NOT part of the cache tuple (it is shared across layers
     and rides as a separate argument).
+
+    A pattern model's is groups of such stacks, `num_pages = (global,
+    window)`, and with conv layers a third group that is no pool: the state
+    of `state_rows` rows (`_state_group`).
     """
     if config.kv_lora_rank:
         from nanorlhf_tpu.core import mla
@@ -1680,10 +1959,17 @@ def init_paged_kv_cache(
                 "builds; the monolithic paged rollout (page_size > 0 with "
                 "one identity table) is not built for it: use the "
                 "contiguous cache (docs/SWA.md)")
+        if config.conv_layers and state_rows <= 0:
+            raise ValueError(
+                "a model with conv layers keeps a state a row beside its "
+                "pages: init_paged_kv_cache(..., state_rows=rows) "
+                "(docs/STATE.md)")
+        KV, hd = _cache_heads(config)
         return tuple(
-            tuple(jnp.zeros((n, pages, config.num_key_value_heads, page_size,
-                             config.actual_head_dim), dtype) for _ in "kv")
-            for n, pages in zip(_pattern_caches(config), num_pages))
+            tuple(jnp.zeros((n, pages, KV, page_size, hd), dtype)
+                  for _ in "kv")
+            for n, pages in zip(_pattern_caches(config), num_pages)
+        ) + _state_group(config, state_rows, dtype)
     shape = (
         config.num_hidden_layers,
         num_pages,
@@ -1766,6 +2052,8 @@ def prefill(
     x, new_caches, _ = _run_layers(
         config, params, x, cos, sin, mask_full, kv_caches=kv_caches, cache_index=0,
         lora_scale=lora_scale, paged=paged,
+        # (a prompt starts its rows: whatever state they held is not theirs)
+        **_conv_ctx(config, attention_mask, lambda: jnp.ones((B,), bool)),
     )
     logits = _logits(config, params, x[:, -1:, :])[:, 0, :]
     return logits, new_caches
@@ -1845,6 +2133,9 @@ def decode_step(
         # are not told)
         **({"live": live} if config.num_experts else {}),
         **({"cached_aux": True} if count_experts else {}),
+        # (a row nobody listens to leaves its state as it was: it may be a
+        # chunked admission between two of its pieces)
+        **_conv_ctx(config, lambda: None if live is None else live[:, None]),
     )
     logits = _logits(config, params, x)[:, 0, :]
     if count_experts:
@@ -1865,6 +2156,10 @@ def decode_verify(
     page_table=None,              # [B, nb] int32 (paged layout)
     page_size: int = 0,
     want_logits: bool = True,
+    token_valid=None,             # [B, Tq] bool: the real candidates (None:
+                                  # all). Only a model with conv layers
+                                  # reads it: its state stops at a row's
+                                  # last real token (`_conv_operator`)
 ):
     """Batched k-token verification for speculative decode
     (sampler/speculative.py): one small-T causal forward over Tq = k+1
@@ -1913,6 +2208,8 @@ def decode_verify(
         config, params, x, cos, sin, mask, kv_caches=kv_caches,
         cache_index=fill, lora_scale=lora_scale,
         verify_bounds=_kind_bounds(config, start, fill, 1), paged=paged,
+        # a row with no valid slot before its candidates starts here
+        **_conv_ctx(config, token_valid, lambda: ~key_mask.any(axis=1)),
     )
     if not want_logits:
         return None, new_caches

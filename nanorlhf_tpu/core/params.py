@@ -23,6 +23,18 @@ HELD experts' `experts.*.kernel [L, held, in, out]`, `mlp.shared_experts.*` ↔
 rotates halves, so the rotary columns of `q_b_proj` and `kv_a_proj` are
 de-interleaved on load and re-interleaved on export (`_ROPE` below).
 
+LFM2-MoE (`model_type: lfm2_moe`; docs/STATE.md; names assumed from the
+family's modelling code): `operator_norm` / `ffn_norm` ↔ `input_layernorm` /
+`post_attention_layernorm`, `self_attn.{q,k,v}_proj`, `self_attn.out_proj` ↔
+`o_proj`, `self_attn.{q,k}_layernorm` ↔ `{q,k}_norm [hd]`, `conv.in_proj` ↔
+`conv.in_proj.kernel [D, 3D]`, `conv.conv.weight [D, 1, K]` ↔
+`conv.conv.kernel [K, D]`, `conv.out_proj`; `feed_forward.{w1,w3,w2}` ↔
+`{gate,up,down}_proj` (dense layers, in `dense_layers`) and
+`feed_forward.experts.{e}.{w1,w3,w2}`, `feed_forward.gate.weight` ↔
+`router.kernel`, `feed_forward.expert_bias` ↔ `router.bias`;
+`model.embedding_norm` ↔ `norm`. Each stack holds an attention layer's leaves
+over ITS attention layers and `conv` over its conv layers (`_lfm2_*`).
+
 Weight fidelity (GQA head layout, tied embeddings, RoPE) is pinned by
 tests/test_model_parity.py against the torch Qwen2 AND Llama
 implementations.
@@ -168,6 +180,103 @@ def _mla_sd_from_params(config: ModelConfig, params: dict, put) -> None:
                         tree["shared_expert"][name]["kernel"][j].T)
 
 
+_LFM2_MLP = (("gate_proj", "w1"), ("up_proj", "w3"), ("down_proj", "w2"))
+_LFM2_ATTENTION = (("q_proj", "self_attn.q_proj"), ("k_proj", "self_attn.k_proj"),
+                   ("v_proj", "self_attn.v_proj"), ("o_proj", "self_attn.out_proj"))
+_LFM2_NORMS = (("input_layernorm", "operator_norm"),
+               ("post_attention_layernorm", "ffn_norm"))
+_LFM2_QK = (("q_norm", "self_attn.q_layernorm"), ("k_norm", "self_attn.k_layernorm"))
+
+
+def _lfm2_stacks(config: ModelConfig):
+    """[(tree name, first layer, layers)] of an LFM2-MoE model."""
+    dense = config.num_dense_layers
+    return ([("dense_layers", 0, dense)] if dense else []) + [
+        ("layers", dense, config.num_hidden_layers - dense)]
+
+
+def _lfm2_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    kinds = config.layer_kinds
+    params = {}
+    for tree_name, start, n in _lfm2_stacks(config):
+        ids = range(start, start + n)
+        conv_ids = [i for i in ids if kinds[i] == "conv"]
+        attn_ids = [i for i in ids if kinds[i] != "conv"]
+        w = lambda i, name: sd[f"model.layers.{i}.{name}.weight"]   # noqa: E731
+        tree = {ours: cast(np.stack([w(i, theirs) for i in ids]))
+                for ours, theirs in _LFM2_NORMS}
+        if tree_name == "layers":
+            tree["router"] = {"kernel": cast(np.stack(
+                [w(i, "feed_forward.gate").T for i in ids]))}
+            if config.use_expert_bias:
+                tree["router"]["bias"] = jnp.asarray(np.stack(
+                    [sd[f"model.layers.{i}.feed_forward.expert_bias"]
+                     for i in ids]), jnp.float32)
+            tree["experts"] = {ours: {"kernel": cast(np.stack([np.stack(
+                [w(i, f"feed_forward.experts.{e}.{theirs}").T
+                 for e in range(config.num_experts)]) for i in ids]))}
+                for ours, theirs in _LFM2_MLP}
+        else:
+            for ours, theirs in _LFM2_MLP:
+                tree[ours] = {"kernel": cast(np.stack(
+                    [w(i, f"feed_forward.{theirs}").T for i in ids]))}
+        if conv_ids:
+            tree["conv"] = {
+                "in_proj": {"kernel": cast(np.stack(
+                    [w(i, "conv.in_proj").T for i in conv_ids]))},
+                # torch's depthwise Conv1d weight [D, 1, K] -> [K, D]
+                "conv": {"kernel": cast(np.stack(
+                    [w(i, "conv.conv")[:, 0, :].T for i in conv_ids]))},
+                "out_proj": {"kernel": cast(np.stack(
+                    [w(i, "conv.out_proj").T for i in conv_ids]))}}
+        if attn_ids:
+            for ours, theirs in _LFM2_ATTENTION:
+                tree[ours] = {"kernel": cast(np.stack(
+                    [w(i, theirs).T for i in attn_ids]))}
+            for ours, theirs in _LFM2_QK:
+                tree[ours] = cast(np.stack([w(i, theirs) for i in attn_ids]))
+        params[tree_name] = tree
+    return params
+
+
+def _lfm2_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    kinds = config.layer_kinds
+    for tree_name, start, n in _lfm2_stacks(config):
+        tree = params[tree_name]
+        at = {"conv": 0, "attn": 0}
+        for j in range(n):
+            pre = f"model.layers.{start + j}."
+            for ours, theirs in _LFM2_NORMS:
+                put(f"{pre}{theirs}.weight", tree[ours][j])
+            if tree_name == "layers":
+                put(f"{pre}feed_forward.gate.weight", tree["router"]["kernel"][j].T)
+                if "bias" in tree["router"]:
+                    put(f"{pre}feed_forward.expert_bias", tree["router"]["bias"][j])
+                for ours, theirs in _LFM2_MLP:
+                    kernel = tree["experts"][ours]["kernel"][j]
+                    for e in range(kernel.shape[0]):
+                        put(f"{pre}feed_forward.experts.{e}.{theirs}.weight",
+                            kernel[e].T)
+            else:
+                for ours, theirs in _LFM2_MLP:
+                    put(f"{pre}feed_forward.{theirs}.weight",
+                        tree[ours]["kernel"][j].T)
+            if kinds[start + j] == "conv":
+                c, conv = at["conv"], tree["conv"]
+                put(f"{pre}conv.in_proj.weight", conv["in_proj"]["kernel"][c].T)
+                put(f"{pre}conv.conv.weight",
+                    conv["conv"]["kernel"][c].T[:, None, :])
+                put(f"{pre}conv.out_proj.weight", conv["out_proj"]["kernel"][c].T)
+                at["conv"] += 1
+            else:
+                a = at["attn"]
+                for ours, theirs in _LFM2_ATTENTION:
+                    put(f"{pre}{theirs}.weight", tree[ours]["kernel"][a].T)
+                for ours, theirs in _LFM2_QK:
+                    put(f"{pre}{theirs}.weight", tree[ours][a])
+                at["attn"] += 1
+
+
 def _to_np(t) -> np.ndarray:
     """torch tensor / np array → np array (bf16-safe via float32 round-trip)."""
     if hasattr(t, "detach"):
@@ -192,6 +301,13 @@ def params_from_hf_state_dict(
         params = _mla_params_from_sd(config, sd, cast)
         params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
                       norm=cast(sd["model.norm.weight"]))
+        if not config.tie_word_embeddings:
+            params["lm_head"] = cast(sd["lm_head.weight"].T)
+        return params
+    if config.conv_layers:
+        params = _lfm2_params_from_sd(config, sd, cast)
+        params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
+                      norm=cast(sd["model.embedding_norm.weight"]))
         if not config.tie_word_embeddings:
             params["lm_head"] = cast(sd["lm_head.weight"].T)
         return params
@@ -250,6 +366,13 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
 
     layers = params["layers"]
     linear_keys, norm_keys = _layer_keys(config)
+    if config.conv_layers:
+        _lfm2_sd_from_params(config, params, put)
+        put("model.embed_tokens.weight", params["embed_tokens"])
+        put("model.embedding_norm.weight", params["norm"])
+        if not config.tie_word_embeddings:
+            put("lm_head.weight", params["lm_head"].T)
+        return sd
     if config.kv_lora_rank:
         _mla_sd_from_params(config, params, put)
         L = 0       # the two stacks are written; the rest is shared
@@ -330,11 +453,12 @@ def export_hf_checkpoint(
     # (sliding_window, ...) to keys we never write. Anything else falls
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
-        "qwen2", "llama", "olmoe", "axk1", "smallthinker") else (
+        "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
-            "smallthinker": "SmallThinkerForCausalLM"}[family]
+            "smallthinker": "SmallThinkerForCausalLM",
+            "lfm2_moe": "Lfm2MoeForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -375,6 +499,23 @@ def export_hf_checkpoint(
         if config.experts_held:     # a chip's share is no whole checkpoint
             hf_config.update(n_routed_experts_held=config.experts_held,
                              n_routed_experts_offset=config.experts_offset)
+    elif family == "lfm2_moe":
+        for key in ("head_dim", "rope_theta", "rms_norm_eps", "attention_bias",
+                    "hidden_act"):
+            del hf_config[key]
+        hf_config.update(
+            layer_types=list(config.layer_types),
+            num_dense_layers=config.num_dense_layers,
+            conv_L_cache=config.conv_L_cache, conv_bias=False,
+            use_expert_bias=config.use_expert_bias,
+            num_experts=config.num_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            norm_topk_prob=config.norm_topk_prob,
+            routed_scaling_factor=config.routed_scaling_factor,
+            norm_eps=config.rms_norm_eps,
+            rope_parameters={"rope_theta": config.rope_theta,
+                             "rope_type": "default"})
     elif family == "smallthinker":
         L = config.num_hidden_layers
         for key in ("intermediate_size", "attention_bias", "hidden_act"):

@@ -6,6 +6,10 @@ expert matmul, weighted combine. docs/MOE.md has the equations and the shapes.
                                          w *= routed_scale  (A.X-K1: 2.5)
     y = Σ_j w_j · W_down[e_j]( silu(h W_gate[e_j]) ⊙ (h W_up[e_j]) )
 
+LFM2 (docs/STATE.md) selects with a bias and weighs without it
+(`select_bias`): `e = top_k(p + b)`, `w = p[e]`, renormalised with an epsilon
+of 1e-6 under the sum (`norm_eps`).
+
 SmallThinker (docs/SWA.md) differs in two places: the router reads another
 state than the experts do (`router_h`: the layer's pre-attention normed
 state), and the gate is `relu`, not `silu` (`activation`).
@@ -107,7 +111,8 @@ def _grouped_matmul(rows, w, group_sizes, kernel: bool, experts: int):
 def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
             layer=None, kernel: bool = False, scoring: str = "softmax",
             routed_scale: float = 1.0, held=None, live=None,
-            router_h=None, activation: str = "silu"):
+            router_h=None, activation: str = "silu", select_bias=None,
+            norm_eps: float = 0.0):
     """h [..., D]; router [D, E]; gate, up [G, D, F]; down [G, F, D], G = E
     or, with `held=(G, offset)`, the chip's share of the E. With
     `layer` (a traced index) the three expert kernels are the stacks of
@@ -136,7 +141,12 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
     for the same reason.
     Unused, XLA removes them.
     `router_h` [..., D], where given, is what the router reads instead of
-    `h`; `activation` is the experts' gate, "silu" or "relu"."""
+    `h`; `activation` is the experts' gate, "silu" or "relu".
+    `select_bias` [E] float32, where given, is added to the scores for the
+    SELECTION only: the weights are the chosen experts' own scores
+    (renormalised over `sum + norm_eps`), and `aux["bias_changed"]` [...]
+    bool marks the tokens whose chosen set is not the top k of the scores
+    alone (`moe/bias_changed_choice`)."""
     lead, D = h.shape[:-1], h.shape[-1]
     E = router.shape[-1]
     x = h.reshape(-1, D)
@@ -151,9 +161,19 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
         else:
             scores = probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        weights, experts = jax.lax.top_k(scores, top_k)         # [N, k]
+        bias_changed = None
+        if select_bias is None:
+            weights, experts = jax.lax.top_k(scores, top_k)     # [N, k]
+        else:
+            _, experts = jax.lax.top_k(
+                scores + select_bias.astype(jnp.float32), top_k)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+            # the unbiased top k, as a set: the k-th largest score's rank
+            kth = jax.lax.top_k(scores, top_k)[0][:, -1:]
+            bias_changed = jnp.any(weights < kth, axis=-1)
         if norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            weights = weights / (total + norm_eps if norm_eps else total)
         if routed_scale != 1.0:
             weights = weights * routed_scale
         entropy = -jnp.sum(probs * jnp.log(jnp.maximum(probs, 1e-30)), axis=-1)
@@ -211,6 +231,8 @@ def moe_mlp(h, router, gate, up, down, top_k: int, norm_topk_prob: bool,
         "entropy": entropy.reshape(lead),
         "dropped": jnp.int32(N * top_k) - computed,
     }
+    if bias_changed is not None:
+        aux["bias_changed"] = bias_changed.reshape(lead)
     if absent is not None:
         aux["dropped"] = aux["dropped"] - absent
         aux["absent"] = absent
@@ -239,6 +261,9 @@ def router_stats(aux, token_mask, num_experts: int):
              "dropped": jnp.sum(aux["dropped"])}
     if "absent" in aux:
         stats["absent"] = jnp.sum(aux["absent"])
+    if "bias_changed" in aux:       # [B]: token-layers the bias re-chose
+        stats["bias_changed"] = jnp.einsum(
+            "lbt,bt->b", aux["bias_changed"].astype(jnp.float32), m)
     return stats
 
 
@@ -261,6 +286,12 @@ def moe_counters(stats: list, held=None) -> dict:
         "moe/router_entropy": entropy / max(tokens * load.shape[0], 1.0),
         "moe/dropped_tokens": float(sum(int(s["dropped"]) for s in stats)),
     }
+    if all("bias_changed" in s for s in stats):
+        # tokens (a layer) whose chosen experts are not the top k of the
+        # scores alone, and their share of all token-layers
+        changed = sum(float(np.sum(s["bias_changed"])) for s in stats)
+        out["moe/bias_changed_choice"] = changed
+        out["moe/bias_changed_frac"] = changed / max(tokens * load.shape[0], 1.0)
     if held is not None:
         count, offset = held
         out["moe/held_experts"] = float(count)

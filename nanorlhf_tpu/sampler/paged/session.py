@@ -631,6 +631,33 @@ class DecodeSession:
         # for them, a ring of pages a row
         self.window_layers = config.window_layers
         patterned = config.attention_pattern is not None
+        # a model with conv layers (docs/STATE.md): a state a row beside
+        # the pages, which only this serving session keeps right
+        self.state_layers = config.conv_layers
+        if self.state_layers:
+            what = f"a model with conv layers ({config.model_type})"
+            if self.spec:
+                raise NotImplementedError(
+                    f"speculative decode (spec_k={spec_k}) on {what}: a "
+                    "verify forward advances the conv state past every "
+                    "candidate, and a rejected draft needs it rolled back to "
+                    "the last accepted token; no such rollback is built "
+                    "(docs/STATE.md)")
+            if not self.per_row:
+                raise NotImplementedError(
+                    f"the paged rollout scheduler on {what}: its batched "
+                    "bootstrap and device free list hand rows on without "
+                    "resetting or carrying a state that is not a page; the "
+                    "serving session does (per_row=True; docs/STATE.md)")
+            if config.kv_cache_quant == "int8":
+                raise NotImplementedError(
+                    f"kv_cache_quant='int8' on {what}: the int8 reads take "
+                    "one table of one kind of cache (docs/STATE.md)")
+            if config.spmd_mesh is not None:
+                raise NotImplementedError(
+                    f"a mesh under a serving session of {what}: the state's "
+                    "rows have no sharding rule and its updates no "
+                    "partitioned form (docs/STATE.md)")
         if patterned and (self.spec or not self.per_row
                           or prefix_cache is None
                           or not getattr(prefix_cache, "enabled", False)):
@@ -678,11 +705,25 @@ class DecodeSession:
             self.num_pages_window = self.rows * self.nbw
             self._ring = RingPages(self.num_pages_window, self.rows, self.nb,
                                    self.nbw)
+        R = self.rows
         caches0 = init_paged_kv_cache(
             config, (self.num_pages, self.num_pages_window) if patterned
             else self.num_pages, self.page_size,
-            params["embed_tokens"].dtype)
-        R = self.rows
+            params["embed_tokens"].dtype,
+            **({"state_rows": R} if self.state_layers else {}))
+        # what the state holds a row, over every conv layer
+        # (`serving/state_bytes_per_row`), and the rows a decode chunk's
+        # state "table" names: all of them, in order (`_conv_operator`)
+        self.state_bytes_per_row = 0
+        self._state_rows = None
+        if self.state_layers:
+            self.state_bytes_per_row = caches0[2][0].nbytes // R
+            self._state_rows = jnp.arange(R, dtype=jnp.int32)[:, None]
+        # admissions that started a row from a zero state, and forwards of a
+        # chunked admission that took the state its last piece left
+        # (`serving/state_resets`, `serving/state_piece_carries`)
+        self.state_resets = 0
+        self.state_piece_carries = 0
         # empty carry: every row starts done; admit() installs rows
         # through the same path mid-loop admissions use
         self.state = self._carry(
@@ -987,8 +1028,11 @@ class DecodeSession:
         nothing for such a model), and one that did raises here."""
         if plan.m > 0 or plan.cow_src is not None:
             raise NotImplementedError(
-                "a radix prefix hit on a model with window layers: the tree "
-                "holds no window pages (docs/SWA.md)")
+                "a radix prefix hit on a model with window or conv layers "
+                f"({self.config.model_type}): the tree holds pages of the "
+                "global kind only, no window pages (docs/SWA.md) and no "
+                "snapshot of the conv state at the prefix's end "
+                "(docs/STATE.md)")
         last = self.Tp + (self.max_tokens if budget is None else int(budget)) - 1
         try:
             self._ring.claim(r, pad_count // self.page_size,
@@ -1003,8 +1047,11 @@ class DecodeSession:
         """Row `r`'s block table for an admission forward: the global pages,
         with the window ring's beside them for a pattern model."""
         if self._ring is not None:
-            return (jnp.array(self.table_np[r]),
-                    jnp.array(self._ring.table[r]))
+            kinds = (jnp.array(self.table_np[r]),
+                     jnp.array(self._ring.table[r]))
+            if self.state_layers:   # the row's place in the state
+                kinds += (jnp.array([r], jnp.int32),)
+            return kinds
         return jnp.array(self.table_np[r])
 
     def _admit_now(self, pend: _PendingPrefill, *, full_cold: bool,
@@ -1033,6 +1080,7 @@ class DecodeSession:
                    + np.arange(Sb, dtype=np.int32)[None])
             km = np.zeros((1, self.T_max), bool)
             km[0, p.pad_count:start_abs] = True
+            self._count_state(p, start_abs)
             logits, caches = suffix_logits(
                 self.params, self.config, jnp.asarray(suffix),
                 jnp.asarray(pos), jnp.asarray([start_abs], jnp.int32),
@@ -1131,6 +1179,7 @@ class DecodeSession:
         km[0, p.pad_count:p.next_slot] = True
         row_table = (self._row_table(p.row)
                      if self._radix is not None else p.row_table)
+        self._count_state(p, p.next_slot)
         self._set_pool(_prefill_chunk_fwd(
             self.params, self.config, jnp.asarray(chunk), jnp.asarray(pos),
             jnp.asarray([p.next_slot], jnp.int32), jnp.asarray(km),
@@ -1140,6 +1189,17 @@ class DecodeSession:
         self.launches += 1
         self.dispatch_tokens += C
         return None
+
+    def _count_state(self, p: _PendingPrefill, start_abs: int) -> None:
+        """An admission forward from slot `start_abs`, as the conv state
+        sees it: with no slot of the row before it the forward starts from
+        zeros (`decode_verify`'s `fresh`), else it takes what the piece
+        before it left."""
+        if self.state_layers:
+            if start_abs > p.pad_count:
+                self.state_piece_carries += 1
+            else:
+                self.state_resets += 1
 
     @property
     def looks_ahead(self) -> bool:
@@ -1184,6 +1244,8 @@ class DecodeSession:
                          if self._radix is not None else self._pstate.table)
             if self._ring is not None:
                 table_dev = (table_dev, jnp.array(self._ring.table))
+                if self.state_layers:
+                    table_dev += (self._state_rows,)
             if self.spec:
                 if self.seed_window:
                     result = _spec_chunk_seeded(

@@ -480,6 +480,12 @@ class ServingEngine:
             "serving/window_pages_reused": self._sess.window_pages_reused,
             "serving/global_slots_read": self._sess.global_slots_read,
             "serving/window_slots_read": self._sess.window_slots_read,
+            # a state that is not a page (docs/STATE.md): 0 layers and bytes
+            # for every model without conv layers
+            "serving/state_layers": self._sess.state_layers,
+            "serving/state_bytes_per_row": self._sess.state_bytes_per_row,
+            "serving/state_resets": self._sess.state_resets,
+            "serving/state_piece_carries": self._sess.state_piece_carries,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
             "pages/shared": snap["shared_pages"],

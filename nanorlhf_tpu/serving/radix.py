@@ -535,6 +535,9 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
         lora_scale=lora_scale,
         page_table=jax.tree.map(lambda t: t[None, :], row_table),
         page_size=page_size,
+        # (a conv state stops at the last REAL token, not the bucket's end)
+        **({"token_valid": jnp.arange(suffix_ids.shape[1])[None, :] <= last}
+           if config.conv_layers else {}),
     )
     return jnp.take(logits[0], last, axis=0), caches
 

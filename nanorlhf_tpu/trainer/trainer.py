@@ -397,6 +397,13 @@ class RLTrainer:
     ):
         self.cfg = config
         self.mcfg = model_config
+        if self.mcfg.conv_layers:
+            raise NotImplementedError(
+                f"training a model with conv layers ({self.mcfg.model_type}) "
+                "is not built: the update's packed rows have no boundary "
+                "the convolution stops at, its backward under the sparse "
+                "trainer's packing is not written, and a mesh has no rule "
+                "for the state (docs/STATE.md); the model is served")
         self.tokenizer = tokenizer
         self.reward_func = reward_func
         self.algo = config.algo

@@ -73,10 +73,16 @@ import jax
 # a custom call named `attn.global*` / `attn.window*` is a DECODE read to
 # the benchmark's trace reader (harness/attn_trace.py), and this one must
 # not be, whatever names it (its jitted wrapper today).
+# A conv layer's operator (docs/STATE.md) stands in the attention's slot and
+# takes names of its family, so that a reader that keeps `attn.*` keeps it:
+# `attn.conv` around `attn.conv.in` (the input projection and the gate),
+# `attn.conv.mix` (the state's read and the taps), `attn.write` (the state's
+# write) and `attn.conv.out`.
 DEVICE_SCOPES = (
     "prefill", "decode", "verify", "install", "score", "update", "sync",
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
-    "attn.out", "attn.paged_flash", "mlp", "head", "sample", "logprob",
+    "attn.out", "attn.paged_flash", "attn.conv", "attn.conv.in",
+    "attn.conv.mix", "attn.conv.out", "mlp", "head", "sample", "logprob",
     "loss", "optim",
 )
 

@@ -684,3 +684,119 @@ def test_chip_smoke_refuses_a_cpu_backend():
     assert out.returncode != 0
     assert '"ok": true' not in out.stdout
     assert '"platform": "cpu"' in out.stdout  # it said what it found
+
+
+def _lfm2_session_program(case, v5e, layers=6):
+    """The `serve-lfm2-chat` cell's decode chunk, KV-only prefill piece or
+    closing suffix forward, lowered for a described v5e at LFM2-24B-A2B's
+    published widths and the first `layers` layers: `(compiled, cache
+    shapes, config)`."""
+    import dataclasses
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.serving import radix
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    full = ModelConfig.lfm2_24b()
+    cfg = dataclasses.replace(full, num_hidden_layers=layers,
+                              layer_types=full.layer_types[:layers])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = 64, 4096, 1024, 1024
+    nb = (Tp + new) // PAGE
+    pages = (R * nb, R)     # no window layer: the ring's one page a row
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, pages, PAGE, jnp.bfloat16, state_rows=R))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        tables = (spec((R, nb), jnp.int32),) * 2 + (spec((R, 1), jnp.int32),)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, tables, spec((R,), jnp.float32),
+            spec((R,), jnp.float32), spec((R,), jnp.bool_),
+            spec((R,), jnp.int32), Tp=Tp, max_tokens=new, page_size=PAGE,
+            sync_every=4, eos_token_id=1, pad_token_id=0, temperature=1.0,
+            top_p=1.0, greedy=False, lora_scale=1.0, top_k=64,
+            capture_logprobs=False, approx_top_k=True)
+    else:
+        row = (spec((nb,), jnp.int32),) * 2 + (spec((1,), jnp.int32),)
+        args = (params, cfg, spec((1, chunk), jnp.int32),
+                spec((1, chunk), jnp.int32), spec((1,), jnp.int32))
+        tail = (spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip), row)
+        if case == "prefill_piece":
+            lowered = session._prefill_chunk_fwd.lower(
+                *args, *tail, page_size=PAGE, lora_scale=1.0)
+        else:
+            lowered = radix.suffix_logits.lower(
+                *args, spec((), jnp.int32), *tail, page_size=PAGE,
+                lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_piece", "suffix"])
+def test_lfm2_session_programs_keep_pages_and_state_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 38, asked of the chip's compiler at the `serve-lfm2-chat` cell's
+    shapes (LFM2-24B-A2B's published widths, the two dense conv layers and
+    one period [a, c, c, c]; 64 rows of 5,120 slots, pages of 128): the
+    session's decode chunk, its 1,024-token KV-only prefill piece and its
+    closing suffix forward alias the attention layers' pool (heads of 64 in
+    pairs: `bf16[1,2560,4,128,128]` x (k, v)) AND the conv state
+    (`bf16[5,2,64,2048]`) from their parameters to their results; no module
+    holds a `copy` of a pool leaf and the decode chunk none of the state;
+    the T = 1 read is the in-place kernel (`%attn.global*`), the T > 1 read
+    the flash kernel over the pages
+    (`%paged_prefill_attention*`), neither `_paged_view`'s gather; the
+    expert matmuls are the grouped-matmul kernel."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _lfm2_session_program(case, v5e)
+    hlo = compiled.as_text()
+    kept = hlo_stacks([leaf for leaf in jax.tree.leaves(cache) if leaf.size])
+    assert set(kept) == {("bf16", (1, 2560, 4, PAGE, 128)),
+                         ("bf16", (5, 2, 64, 2048))}
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo, re.M).group(1)
+    leaves = {int(rest.split(")")[0]) for _, result, op, rest in comps[entry]
+              if op == "parameter" and _shapes(result)[:1]
+              and _shapes(result)[0] in kept}
+    assert len(leaves) == 3 and leaves <= aliased, (leaves, aliased)
+    # the decode chunk copies neither; an admission forward (one row) may
+    # relay the STATE at its ends (the compiler wants the row's K - 1 values
+    # in one tile there: 2.6 MB each way, ~10 us beside a forward that reads
+    # every expert), never the pool
+    held = kept if case == "decode_chunk" else {
+        k for k in kept if k[1][-1] == 128}
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(held)]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    calls = [line.strip() for line in hlo.splitlines()
+             if re.match(r"\s*%(attn\.|paged_prefill)[\w.]* = \S+ custom-call\(",
+                         line)]
+    if case == "decode_chunk":
+        assert len(calls) == 1 and calls[0].startswith("%attn.global"), calls
+    elif case == "suffix":
+        assert len(calls) == 1 and calls[0].startswith(
+            "%paged_prefill_attention"), calls
+    else:   # a KV-only piece: its one attention layer is not the last layer
+        assert len(calls) == 1, calls
+    # no gathered view of the row's pages: [.., 5120, 128] by slot
+    assert not re.findall(r"bf16\[\d+,4,5120,128\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

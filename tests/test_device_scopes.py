@@ -43,6 +43,7 @@ KINDS = {
     "latent": lambda: ModelConfig.axk1_tiny(vocab_size=256),
     "pattern": lambda: ModelConfig.smallthinker_tiny(
         vocab_size=256, window=16, layers=4),
+    "conv": lambda: ModelConfig.lfm2_tiny(vocab_size=256),
 }
 # what a layer's attention is made of, by kind (the older families are the
 # latent model's `mla.*` and the pattern model's `attn.global` / `.window`)
@@ -52,6 +53,8 @@ ATTENTION = {
     "latent": {"attn.write", "mla.q", "mla.latent", "mla.attend", "mla.out"},
     "pattern": {"attn.qkv", "attn.write", "attn.global", "attn.window",
                 "attn.out"},
+    "conv": {"attn.qkv", "attn.write", "attn.global", "attn.out", "attn.conv",
+             "attn.conv.in", "attn.conv.mix", "attn.conv.out"},
 }
 MODEL = {"embed", "norm", "attn", "mlp", "head"}
 MATMUL_HOMES = ("attn", "mlp", "head")
@@ -175,8 +178,13 @@ def session_programs(kind, **config):
     if kind == "pattern":
         pages = (pages, ROWS * ring_blocks(cfg.sliding_window, PAGE, PAGE))
         table, row_table = (table,) * 2, (row_table,) * 2
-    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(cfg, pages, PAGE,
-                                                    jnp.float32))
+    state_rows = {}
+    if kind == "conv":      # no window layer; the state's rows ride third
+        pages, state_rows = (pages, ROWS), {"state_rows": ROWS}
+        table = (table,) * 2 + (spec((ROWS, 1), jnp.int32),)
+        row_table = (row_table,) * 2 + (spec((1,), jnp.int32),)
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, pages, PAGE, jnp.float32, **state_rows))
     T = PROMPT + NEW
     state = (spec((), jnp.int32), spec((ROWS, NEW), jnp.int32),
              spec((ROWS, NEW), jnp.float32), cache,
