@@ -528,15 +528,106 @@ def _paged_pages(pool, table, slots, page_size):
     return page, slots % page_size
 
 
-def _paged_cache_update(pool, new, layer, table, cache_index, page_size):
-    """Write `new` [B, KV, T, hd] through the block table into layer `layer`
-    of the stacked page pool [L, num_pages, KV, page_size, hd], in place."""
+def _paged_row_scatter(pool, new, layer, table, cache_index, page_size):
+    """The plain form of the paged write, and the oracle of the other two:
+    one scatter of `B x T x KV` rows of `[hd]`, each to its `(page, head,
+    offset)`. The TPU's compiler runs it over a `[rows, hd]` view of the
+    whole leaf, a row at a time (~100-240 ns each whatever a row holds:
+    PERF.md section 6, PR 41)."""
     B, KV, T, hd = new.shape
     page, off = _paged_pages(pool, table, _paged_slots(cache_index, B, T),
                              page_size)
     heads = jnp.arange(KV, dtype=jnp.int32)[None, None, :]
     return pool.at[layer, page[:, :, None], heads, off[:, :, None], :].set(
         new.transpose(0, 2, 1, 3), mode="drop")
+
+
+# Rows of `hd` (tokens x KV heads) from which a write SHORTER than a page
+# goes by page all the same: two pages read and written back for those rows
+# spared. One row's write into a 1.5 GB leaf on a v5e, a K and V pair a
+# layer, row scatter / by page in microseconds (my chip run, PR 41,
+# `tools/bench_paged_write.py`, `chiprun_out/w1`): KV 2: T 2 6.9 / 10.7, T 8
+# 8.4 / 10.5, T 16 11.8 / 10.8, T 32 18.3 / 10.1, T 64 34.3 / 10.2, T 128
+# 54.8 / 10.7; KV 4: T 16 17.4 / 11.6, T 64 50.7 / 12.0; one head of 512: T
+# 64 20.6 / 12.2. The page write is flat at 10-12 us under a page of tokens
+# and the scatter ~0.25 us a row over ~6: they cross at about 32 rows.
+_PAGE_WRITE_MIN_ROWS = 32
+
+
+def _touched_blocks(T: int, page_size: int) -> int:
+    """Logical blocks that T consecutive slots can span, from any start."""
+    return (T + page_size - 2) // page_size + 1
+
+
+def _paged_page_write(pool, new, layer, table, cache_index, page_size):
+    """The paged write by PAGE: a row's T slots are consecutive from its
+    `cache_index`, so they lie in `n = _touched_blocks(T, P)` logical blocks,
+    the first and the last in part (a served prompt is left-padded: its first
+    piece starts at slot `Tp - len`, not at a page's first). Those n pages of
+    the layer are read `[B, n, KV, P, hd]`, `new` is laid over them shifted by
+    `cache_index % P` (ONE dynamic slice of `new` padded at both ends: the
+    slots are consecutive), slots that are not among the T keep what the page
+    held, and the pages go back in one scatter of `B x n` windows `[KV, P,
+    hd]`, which the pool stores contiguously. What the row scatter dropped is
+    dropped: a block past the table or a sentinel entry resolves to page
+    `num_pages` (`_paged_pages`), block by block as ever.
+
+    PRECONDITION: the n pages a row touches in one call are distinct, and
+    its own. The global table's are by allocation; a window ring's because
+    `pages.ring_blocks` sizes it `window + longest write` slots and two
+    pages more (tests/test_paged_cache_write.py pins that); a page shared
+    through the radix tree is never written (`radix.copy_page` gives a
+    straddling row its own)."""
+    B, KV, T, hd = new.shape
+    P, n = page_size, _touched_blocks(T, page_size)
+    first = jnp.asarray(cache_index, jnp.int32)
+    # the first slot of each touched block, [B, n], resolved as any slot is
+    page, _ = _paged_pages(
+        pool, table, _paged_slots(first // P * P, B, 1)
+        + P * jnp.arange(n, dtype=jnp.int32)[None], P)
+    old = pool[layer, jnp.minimum(page, pool.shape[1] - 1)]  # [B, n, KV, P, hd]
+    # slot j of the n pages is token j - shift: `new` with P slots before it
+    # and n P - T after, read from P - shift on
+    padded = jnp.pad(new, ((0, 0), (0, 0), (P, n * P - T), (0, 0)))
+    window = lambda a, s: jax.lax.dynamic_slice_in_dim(      # noqa: E731
+        a, P - s, n * P, axis=-2)
+    shift = first % P
+    laid = (window(padded, shift) if first.ndim == 0
+            else jax.vmap(window)(padded, shift))           # [B, KV, n P, hd]
+    j = jnp.arange(n * P, dtype=jnp.int32)[None] - jnp.broadcast_to(
+        shift, (B,))[:, None]
+    mine = ((j >= 0) & (j < T)).reshape(B, n, 1, P, 1)
+    laid = laid.reshape(B, KV, n, P, hd).transpose(0, 2, 1, 3, 4)
+    return pool.at[layer, page].set(jnp.where(mine, laid, old), mode="drop")
+
+
+def _page_write_takes(pool, T: int, page_size: int, table_blocks: int) -> bool:
+    """Whether a write of T tokens into `pool` goes by page
+    (`_paged_cache_update`)."""
+    return ((T >= page_size or T * pool.shape[2] >= _PAGE_WRITE_MIN_ROWS)
+            and pool.dtype != jnp.int8
+            and _touched_blocks(T, page_size) <= table_blocks)
+
+
+def _paged_cache_update(pool, new, layer, table, cache_index, page_size):
+    """Write `new` [B, KV, T, hd] through the block table into layer `layer`
+    of the stacked page pool [L, num_pages, KV, page_size, hd], in place, in
+    the unit the pool stores contiguously where that is cheaper than a row
+    of `hd` at a time, chosen by what this call can see (docs/PAGED_CACHE.md
+    "The write"):
+
+    - a page of tokens or more (a prefill piece, a long suffix bucket), or
+      `_PAGE_WRITE_MIN_ROWS` rows of `hd` (a short bucket): by page
+      (`_paged_page_write`), where the table is wide enough to hold the
+      pages of one write apart;
+    - everything else, and the int8 pool (whose scales go row by row beside
+      it, `_paged_scale_update`): `_paged_row_scatter`.
+
+    Every form leaves the pool bit-identical to the row scatter's."""
+    if _page_write_takes(pool, new.shape[2], page_size, table.shape[1]):
+        return _paged_page_write(pool, new, layer, table, cache_index,
+                                 page_size)
+    return _paged_row_scatter(pool, new, layer, table, cache_index, page_size)
 
 
 def _paged_scale_update(pool, new, layer, table, cache_index, page_size):
@@ -556,10 +647,18 @@ def _cache_write(stacks, news, layer, cache_index, paged):
     """Write one layer's new tokens into the stacked cache arrays, each at
     `(layer, ...)`: `stacks`/`news` are (k, v) exact, or (k_q, k_s, v_q, v_s)
     int8, whose odd members are scale arrays (sequence on the last axis).
-    `paged=(block_table, page_size)` routes the write through the table.
-    Returns the updated stacks. Under the scope `attn.write`."""
+    `paged=(block_table, page_size)` routes the write through the table;
+    `paged=(block_table, page_size, PagedWritePlan)` is a decode step's on a
+    TPU (`_with_write_plan`): its one slot a row goes through
+    ops/paged_cache_write, K and V in one call. Returns the updated stacks.
+    Under the scope `attn.write`."""
     out = []
     with jax.named_scope("attn.write"):
+        if paged is not None and len(paged) == 3:
+            from nanorlhf_tpu.ops.paged_cache_write import paged_row_write
+
+            return tuple(paged_row_write(
+                *stacks, news[0][:, :, 0], news[1][:, :, 0], layer, paged[2]))
         for i, (stack, new) in enumerate(zip(stacks, news)):
             is_scale = len(stacks) == 4 and i % 2 == 1
             if paged is not None:
@@ -571,6 +670,56 @@ def _cache_write(stacks, news, layer, cache_index, paged):
                 update = _scale_update if is_scale else _cache_update
                 out.append(update(stack, new, layer, cache_index))
     return tuple(out)
+
+
+def _paged_row_kernel_takes(stacks, page_size: int) -> bool:
+    """Whether ops/paged_cache_write takes a decode step's write into these
+    stacks: a (k, v) pool of whole 128-lane rows and whole tiles a page (not
+    the int8 pool's four arrays, not a page of 4 or 8 slots)."""
+    from nanorlhf_tpu.ops.paged_cache_write import sublanes
+
+    return (len(stacks) == 2 and stacks[0].dtype == stacks[1].dtype
+            and stacks[0].dtype != jnp.int8
+            and stacks[0].shape[-1] % 128 == 0
+            and page_size % sublanes(stacks[0].dtype) == 0)
+
+
+def _with_write_plan(config: ModelConfig, paged, kv_caches, cache_index, live):
+    """A decode step's `paged` with the step's `PagedWritePlan` as the third
+    member of each (table, page_size) whose pool the live-row kernel takes,
+    under `use_paged_decode_kernel`'s rule: made once here, for every layer
+    (`_cache_write`). Elsewhere `paged` as it was: the row scatter."""
+    if paged is None or not use_paged_decode_kernel(config):
+        return paged
+    from nanorlhf_tpu.ops.paged_cache_write import paged_write_plan
+
+    def one(pair, group):
+        table, page_size = pair
+        if not _paged_row_kernel_takes(group, page_size):
+            return pair
+        return table, page_size, paged_write_plan(
+            table, cache_index, page_size=page_size,
+            num_pages=group[0].shape[1], live=live)
+
+    if config.attention_pattern is None:
+        return one(paged, kv_caches)
+    return tuple(one(p, g) for p, g in zip(paged[:2], kv_caches[:2])) + tuple(
+        paged[2:])
+
+
+def paged_write_forms(config: ModelConfig, caches, page_size: int,
+                      longest_write: int, table_blocks: int) -> tuple:
+    """`(by page, live rows)`, 0 or 1 each: whether a forward of
+    `longest_write` tokens (a prefill piece, a whole prompt) writes the paged
+    cache `caches` by page, and whether a decode step's write is the
+    live-row kernel: what `_paged_cache_update` and `_cache_write` decide
+    from the same shapes when the programs are traced
+    (`DecodeSession.kv_write_by_page`, `kv_write_live_rows`)."""
+    group = caches[0] if config.attention_pattern is not None else caches
+    return (int(_page_write_takes(group[0], longest_write, page_size,
+                                  table_blocks)),
+            int(use_paged_decode_kernel(config)
+                and _paged_row_kernel_takes(group, page_size)))
 
 
 def _layer_slab(stack, layer, width=None):
@@ -2125,6 +2274,7 @@ def decode_step(
                            in zip(page_table, kv_caches, bounds))
         else:
             bounds = plan(page_table, kv_caches[0], start)
+    paged = _with_write_plan(config, paged, kv_caches, cache_index, live)
     x, new_caches, aux = _run_layers(
         config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
         lora_scale=lora_scale, decode_bounds=bounds, paged=paged,

@@ -70,7 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from nanorlhf_tpu.core.model import (
-    decode_step, decode_verify, prefill, use_paged_decode_kernel,
+    decode_step, decode_verify, paged_write_forms, prefill,
+    use_paged_decode_kernel,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
@@ -852,6 +853,13 @@ class DecodeSession:
         self.prefill_read_in_place = int(
             config.attention_pattern is not None
             and use_paged_decode_kernel(config))
+        # which write the programs were built with (`core/model.
+        # _paged_cache_update`, `_cache_write`): a prefill piece's by page,
+        # and a decode step's live rows through ops/paged_cache_write
+        self.kv_write_by_page, live_rows = paged_write_forms(
+            config, caches0, self.page_size, self.prefill_chunk or self.Tp,
+            self.nb)
+        self.kv_write_live_rows = int(live_rows and not self.spec)
 
         # dispatch accounting (module docstring): launches = model
         # forwards outside the decode/verify loop; decode iterations come

@@ -465,6 +465,8 @@ class ServingEngine:
             "serving/attn_in_place": self._sess.attn_in_place,
             "serving/prefill_pieces": self._sess.prefill_pieces,
             "serving/prefill_read_in_place": self._sess.prefill_read_in_place,
+            "serving/kv_write_by_page": self._sess.kv_write_by_page,
+            "serving/kv_write_live_rows": self._sess.kv_write_live_rows,
             "serving/pool_donated": self._sess.pool_donated,
             "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
             "serving/latent_cache": self._sess.latent_cache,

@@ -49,6 +49,15 @@ _LOOPBACK = ("127.0.0.1", "localhost", "::1")
 _RETRY_AFTER = {"queue_full": 1, "slo_ttft_p95": 5, "closed": 30}
 
 
+class _Server(ThreadingHTTPServer):
+    """socketserver listens with a backlog of 5: of as many connections at
+    once as an engine has rows (64), the kernel reset one in ~60 before the
+    accept loop reached it (`ConnectionResetError` at the client, 19 and 25
+    of 1,280 on this container's loopback; none at 128; PERF.md PR 41)."""
+    daemon_threads = True
+    request_queue_size = 128
+
+
 class ServingGateway:
     """HTTP front for one ServingEngine. `close()` stops the listener
     only — the engine has its own lifecycle (the caller that built it
@@ -184,8 +193,7 @@ class ServingGateway:
                 pass
 
         bind_port = port if port > 0 else 0  # -1 → ephemeral
-        self._server = ThreadingHTTPServer((host, bind_port), _Handler)
-        self._server.daemon_threads = True
+        self._server = _Server((host, bind_port), _Handler)
         self.port = self._server.server_address[1]
         self._thread = threading.Thread(
             target=self._server.serve_forever, name="serving-gateway",

@@ -259,6 +259,38 @@ def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
     assert "attn.paged_flash" not in parts_of(ops_of(walk["piece"]), "prefill")
 
 
+@pytest.mark.parametrize("kind", ["dense", "latent", "pattern", "conv"])
+def test_a_pieces_write_by_page_carries_attn_write(kind):
+    """ISSUE 41: a piece of a page or more writes its K and V by PAGE
+    (`core/model._paged_page_write`: the touched pages gathered, patched and
+    scattered back whole), all of it under `attn.write`, inside its kind's
+    scope in a pattern model: the gather of the old pages, the select and
+    the scatter of `[KV, P, hd]` windows. The row scatter the compiler
+    rewrote over a `[rows, 128]` view of the leaf lost its `op_name`, and
+    sixteen of them a piece read as no scope at all (PERF.md PR 37)."""
+    piece = session_programs(kind)["piece"]
+    ops = ops_of(piece)
+    homes = {"pattern": {"prefill/attn/attn.global/attn.write",
+                         "prefill/attn/attn.window/attn.write"},
+             "conv": {"prefill/attn/attn.global/attn.write"}}.get(
+        kind, {"prefill/attn/attn.write"})
+    for part in ("gather", "select_n", "scatter"):
+        found = {scope for _, name, scope in ops
+                 if name.endswith("/attn.write/" + part)}
+        # (the conv layers' state write is a dynamic-update-slice, also
+        # under `attn.write`, in `attn.conv`)
+        assert homes <= found, (part, found)
+        assert all(s.endswith("attn.write") for s in found), (part, found)
+    # and the scatters into the pool move pages: windows over (KV, P, hd),
+    # where the row scatter's were one row of hd
+    windows = [len(m.group(1).split(",")) for line in
+               piece.compile().as_text().splitlines()
+               if "/attn.write/scatter" in line
+               for m in [re.search(r"update_window_dims=\{([\d,]*)\}", line)]
+               if m]
+    assert windows and min(windows) >= 3, windows
+
+
 def test_what_an_admission_does_beside_its_forward_is_install():
     from nanorlhf_tpu.sampler.paged import session
 

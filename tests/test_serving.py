@@ -462,6 +462,35 @@ def test_gateway_rejects_bad_request_and_nonloopback():
         ServingGateway(object(), port=-1, host="0.0.0.0")
 
 
+def test_gateway_takes_as_many_connections_at_once_as_an_engine_has_rows():
+    """socketserver's listen backlog of 5 reset one connection in ~60 of a
+    burst of 64 (benchmark/drivers/serve_state_ref.py asks a row's worth at
+    once; a run of `serve-lfm2-chat` died of it, PERF.md PR 41)."""
+    from concurrent.futures import ThreadPoolExecutor
+    from types import SimpleNamespace
+
+    from nanorlhf_tpu.serving.gateway import ServingGateway
+
+    def stream(req):
+        time.sleep(0.05)
+        yield from req.tokens
+
+    engine = SimpleNamespace(
+        submit=lambda tokens, **kw: (SimpleNamespace(
+            request_id=0, tokens=tokens), None),
+        stream=stream)
+    gw = ServingGateway(engine, port=-1)
+    try:
+        base = f"http://127.0.0.1:{gw.port}"
+        ask = lambda i: json.loads(_post(base, {"tokens": [i]}).read())  # noqa: E731
+        with ThreadPoolExecutor(64) as pool:
+            for _ in range(3):
+                answers = list(pool.map(ask, range(64)))
+                assert [a["tokens"] for a in answers] == [[i] for i in range(64)]
+    finally:
+        gw.close()
+
+
 def test_engine_prompt_length_validation(served):
     eng, _, _ = served
     with pytest.raises(ValueError, match="prompt length"):
@@ -567,7 +596,9 @@ def test_engine_cancel_active_releases_pages(tiny):
     abandoned KV page returns to free/radix-cached (no leak, nothing
     left shared). Pins the precondition the chaos kv_page_leak auditor
     relies on."""
-    eng = _chaos_engine(tiny)
+    # 64 tokens: a budget of 8 is all out 2 ms after the first token on this
+    # CPU, and the cancel below then found the stream complete (2 runs of 12)
+    eng = _chaos_engine(tiny, max_new_tokens=64)
     try:
         victim = _full_budget_prompt(eng)
         base = eng.snapshot()["counters"]

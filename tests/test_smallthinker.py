@@ -5,6 +5,7 @@ of benchmark/harness/reference_smallthinker.py on seeded weights. Tiny
 widths, a window of 8 so that every path crosses it; logits, not tokens."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -246,24 +247,30 @@ def paged_logits(params, ids, P, chunk, order=None, impl="auto"):
     mask = np.asarray(ids != 0)
     pos = np.cumsum(mask, 1) - 1
     out = {}
+    # jitted: an eager call lowers its layer scans anew each time (a scan's
+    # jaxpr is a new object a call) and every such executable stays mapped,
+    # ~10,000 memory maps a run of this function; a test worker that had run
+    # a suite's worth before it reached vm.max_map_count (65,530) here and
+    # died in `deserialize_executable` (three whole runs of three, PR 41)
+    verify, step = (jax.jit(
+        functools.partial(f, page_table=tabs, page_size=P),
+        static_argnums=1) for f in (decode_verify, decode_step))
     with jax.default_matmul_precision("highest"):
         for f in range(0, Tp, chunk):
             km = np.zeros((2, T_max), bool)
             km[:, :f] = mask[:, :f]
-            lg, caches = decode_verify(
+            lg, caches = verify(
                 params, cfg, ids[:, f:f + chunk], jnp.asarray(pos[:, f:f + chunk]),
-                jnp.full((2,), f, jnp.int32), jnp.asarray(km), caches,
-                page_table=tabs, page_size=P)
+                jnp.full((2,), f, jnp.int32), jnp.asarray(km), caches)
             for i in range(chunk):
                 out[f + i] = np.asarray(lg[:, i])
         km = jnp.zeros((2, T_max), bool).at[:, :Tp].set(ids[:, :Tp] != 0)
         for t in range(Tp, T_max):
             km = km.at[:, t].set(True)
             live = jnp.asarray([True, t < 32])      # then nobody hears row 1
-            lg, caches = decode_step(
+            lg, caches = step(
                 params, cfg, ids[:, t], jnp.asarray(pos[:, t]),
-                jnp.full((2,), t, jnp.int32), km, caches, page_table=tabs,
-                page_size=P, live=live)
+                jnp.full((2,), t, jnp.int32), km, caches, live=live)
             out[t] = np.where(np.asarray(live)[:, None], np.asarray(lg), np.nan)
     return np.stack([out[t] for t in range(T_max)], axis=1), pages
 
