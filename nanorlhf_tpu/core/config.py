@@ -4,7 +4,10 @@ with leading dense layers before shared-plus-routed sigmoid experts, or
 SmallThinker's pattern of window layers with rotary embedding beside global
 layers without it over ReLU-gated experts (docs/SWA.md), or LFM2's pattern of
 gated short-convolution layers with a fixed-size state beside attention
-layers, over experts chosen by bias-corrected sigmoid scores (docs/STATE.md).
+layers, over experts chosen by bias-corrected sigmoid scores (docs/STATE.md),
+or Trinity's (`afmoe`) gated attention over the same window pattern with four
+norms a layer, a leading dense stack inside the pattern and a chip's share of
+bias-selected sigmoid experts beside a shared one (docs/AFMOE.md).
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
 `/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
@@ -104,8 +107,8 @@ class ModelConfig:
     # not, and a mesh keeps its batch sharding (a block flattens the rows).
     expert_token_block: int = 0
     # The attention pattern (docs/SWA.md; empty layouts = every layer global
-    # and rotated, every model but SmallThinker). Layer `l` is a WINDOW layer
-    # where `sliding_window_layout[l]`: query i sees key j iff
+    # and rotated, every model but SmallThinker and afmoe). Layer `l` is a
+    # WINDOW layer where `sliding_window_layout[l]`: query i sees key j iff
     # i - sliding_window < j <= i; it applies rotary embedding where
     # `rope_layout[l]` (a layer without it carries no positional signal).
     # Tuples: the config is a static jit argument. The layouts repeat with a
@@ -133,6 +136,20 @@ class ModelConfig:
     # `scores + expert_bias`, the weights of `scores` alone (LFM2's
     # `use_expert_bias`; the tree then has `router.bias [L, E]`, float32).
     use_expert_bias: bool = False
+    # The epsilon under the sum when a bias-selected router renormalises its
+    # chosen scores (LFM2: 1e-6; afmoe: 1e-20). Read under `use_expert_bias`.
+    route_norm_eps: float = 1e-6
+    # afmoe (docs/AFMOE.md). `attention_gate`: a fourth projection of the
+    # normed state, `g_proj` [D, H * hd] beside `q_proj`, whose sigmoid
+    # multiplies the attention's output element by element before `o_proj`.
+    # `branch_norms`: each branch is normed AGAIN before it joins the stream
+    # (`x + RMSNorm(attention)`, `x + RMSNorm(mlp)`: the tree's
+    # `attn_branch_norm` / `mlp_branch_norm`, four norms a layer).
+    # `embed_scale`: what the embedding's rows are multiplied by (muP:
+    # sqrt(hidden_size)); 1.0 stages no operation.
+    attention_gate: bool = False
+    branch_norms: bool = False
+    embed_scale: float = 1.0
     # KV heads the cache keeps side by side in one row of lanes: 2 packs
     # heads of 64 into pages 128 lanes wide, which is what the paged kernels
     # read (core/model.py `_pack_heads`; docs/STATE.md). 1: a head a row.
@@ -491,6 +508,67 @@ class ModelConfig:
                          * layers)[:layers])
 
     @classmethod
+    def trinity_large(cls) -> "ModelConfig":
+        """arcee-ai/Trinity-Large-Preview (`afmoe`): 60 GQA layers of 48 / 8
+        heads of 128 in periods of [window 4,096 with rotary x 3, global
+        without rotary], a gate on the attention's output, four norms a
+        layer, the first 6 layers a dense SwiGLU of 12,288, the rest one
+        shared expert plus 256 routed experts of 3,072, 4 a token, chosen by
+        sigmoid scores plus a bias, renormalised and scaled by 2.448; the
+        embedding scaled by sqrt(3,072); untied head (docs/AFMOE.md)."""
+        return cls(
+            vocab_size=200192,
+            hidden_size=3072,
+            intermediate_size=12288,
+            num_hidden_layers=60,
+            num_attention_heads=48,
+            num_key_value_heads=8,
+            head_dim=128,
+            rope_theta=10_000.0,
+            rms_norm_eps=1e-5,
+            tie_word_embeddings=False,
+            max_position_embeddings=262144,
+            attention_bias=False,
+            model_type="afmoe",
+            num_experts=256,
+            num_experts_per_tok=4,
+            norm_topk_prob=True,
+            first_k_dense_replace=6,
+            moe_intermediate_size=3072,
+            n_shared_experts=1,
+            scoring_func="sigmoid",
+            routed_scaling_factor=2.448,
+            sliding_window=4096,
+            sliding_window_layout=(1, 1, 1, 0) * 15,
+            rope_layout=(1, 1, 1, 0) * 15,
+            qk_norm_per_head=True,
+            use_expert_bias=True,
+            route_norm_eps=1e-20,
+            attention_gate=True,
+            branch_norms=True,
+            embed_scale=3072 ** 0.5,
+            expert_token_block=1024,
+        )
+
+    @classmethod
+    def trinity_tiny(cls, vocab_size: int = 512, window: int = 8,
+                     experts_held: int = 0,
+                     experts_offset: int = 0) -> "ModelConfig":
+        """Test-size Trinity: one dense layer (a window layer) and one period
+        of expert layers [window x 3, global], 16 experts, 2 a token, one
+        shared, heads of 16 and a window every test path crosses; optionally
+        a chip's share of the experts."""
+        return dataclasses.replace(
+            cls.trinity_large(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=5, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, max_position_embeddings=1024,
+            num_experts=16, num_experts_per_tok=2, first_k_dense_replace=1,
+            moe_intermediate_size=32, sliding_window=window,
+            sliding_window_layout=(1, 1, 1, 1, 0), rope_layout=(1, 1, 1, 1, 0),
+            embed_scale=8.0, experts_held=experts_held,
+            experts_offset=experts_offset)
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -531,9 +609,11 @@ class ModelConfig:
     def from_hf_config(cls, hf_config) -> "ModelConfig":
         """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
         (or dict), or from A.X-K1's `config.json` (`model_type: axk1`,
-        DeepSeek-V3's key set; `_axk1_from_hf`). A config the decoder does not
-        implement raises: expert keys under any other model type (each family
-        routes, scales and shares in its own way), and a non-null `clip_qkv`."""
+        DeepSeek-V3's key set; `_axk1_from_hf`), SmallThinker's, LFM2-MoE's
+        or Trinity's (`afmoe`), each by a parser of its own. A config the
+        decoder does not implement raises: expert keys under any other model
+        type (each family routes, scales and shares in its own way), and a
+        non-null `clip_qkv`."""
         get = (lambda k, d=None: getattr(hf_config, k, d)) if not isinstance(
             hf_config, dict
         ) else (lambda k, d=None: hf_config.get(k, d))
@@ -548,6 +628,8 @@ class ModelConfig:
             return cls._smallthinker_from_hf(get)
         if model_type == "lfm2_moe":
             return cls._lfm2_from_hf(get)
+        if model_type == "afmoe":
+            return cls._afmoe_from_hf(get)
         # a window on a family this decoder builds with full attention only:
         # Qwen2's `use_sliding_window` (with `sliding_window`, `layer_types`
         # or `max_window_layers`), Mistral's bare `sliding_window`
@@ -562,8 +644,8 @@ class ModelConfig:
                 f"sliding_window={get('sliding_window')!r}, layer_types="
                 f"{'given' if get('layer_types') else None}): the window "
                 "layers of docs/SWA.md are built for model_type "
-                "'smallthinker' only; full attention under this config "
-                "would be another model under its name")
+                "'smallthinker' and 'afmoe' only; full attention under this "
+                "config would be another model under its name")
         expert_keys = [k for k in _EXPERT_KEYS if get(k)]
         if expert_keys and not olmoe:
             raise ValueError(
@@ -736,6 +818,106 @@ class ModelConfig:
             expert_token_block=4096,
         )
 
+    @staticmethod
+    def _chip_share(get, who: str, experts: int, key: str) -> tuple:
+        """`(held, offset)`: the chip's share of the routed experts where the
+        file states one (`<key>_held` / `<key>_offset`; a published
+        config.json has neither: all are held)."""
+        held, offset = (int(get(key + "_held") or 0),
+                        int(get(key + "_offset") or 0))
+        if held and not 0 <= offset <= experts - held:
+            raise ValueError(f"{who}: experts [{offset}, {offset + held}) "
+                             f"are not among the router's {experts}")
+        return held, offset
+
+    @classmethod
+    def _afmoe_from_hf(cls, get) -> "ModelConfig":
+        """Trinity's published keys (`model_type: afmoe`, docs/AFMOE.md), and
+        the chip's share where the file states one (`num_experts_held` /
+        `num_experts_offset`). What the layers of docs/AFMOE.md do not
+        compute raises, by name."""
+        L = int(get("num_hidden_layers"))
+        for key in ("n_group", "topk_group", "num_expert_groups",
+                    "num_limited_groups"):
+            if get(key) not in (1, None):
+                raise ValueError(
+                    f"afmoe: {key}={get(key)!r}: group-limited selection is "
+                    "not implemented (the top k are taken over all experts: "
+                    "docs/AFMOE.md)")
+        refuse = {
+            "score_func": ("sigmoid",),
+            "hidden_act": ("silu", None),
+            "rope_scaling": (None,),
+            "attention_bias": (False, None),
+            # without muP the embedding's scale, and whatever else the
+            # family's code then changes, is not stated anywhere we can read
+            "mup_enabled": (True,),
+        }
+        for key, allowed in refuse.items():
+            if get(key) not in allowed:
+                raise ValueError(
+                    f"afmoe: {key}={get(key)!r} is not implemented (only "
+                    f"{allowed[0]!r}: docs/AFMOE.md)")
+        types = tuple(str(t) for t in (get("layer_types") or ()))
+        if len(types) != L:
+            raise ValueError(
+                f"afmoe: layer_types of {len(types)} entries for {L} layers")
+        other = sorted(set(types) - {"sliding_attention", "full_attention"})
+        if other:
+            raise ValueError(
+                f"afmoe: layer_types {other} are not implemented (only "
+                "'sliding_attention' and 'full_attention': docs/AFMOE.md)")
+        win = tuple(int(t == "sliding_attention") for t in types)
+        window = int(get("sliding_window") or 0)
+        if any(win) and window <= 0:
+            raise ValueError("afmoe: sliding_attention layers without a "
+                             "sliding_window")
+        if all(win):
+            raise ValueError(
+                "afmoe: a model of window layers only is not implemented: "
+                "the page pool of two kinds keeps a global kind "
+                "(docs/SWA.md)")
+        E = int(get("num_experts"))
+        held, offset = cls._chip_share(get, "afmoe", E, "num_experts")
+        D = int(get("hidden_size"))
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=D,
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=L,
+            num_attention_heads=get("num_attention_heads"),
+            num_key_value_heads=get("num_key_value_heads"),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 10_000.0)),
+            rms_norm_eps=get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 262144),
+            attention_bias=False,
+            model_type="afmoe",
+            num_experts=E,
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            norm_topk_prob=bool(get("route_norm", True)),
+            first_k_dense_replace=int(get("num_dense_layers") or 0),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            n_shared_experts=int(get("num_shared_experts") or 0),
+            scoring_func="sigmoid",
+            routed_scaling_factor=float(get("route_scale", 1.0)),
+            experts_held=held,
+            experts_offset=offset,
+            sliding_window=window if any(win) else 0,
+            sliding_window_layout=win if any(win) else (),
+            # rotary on the window layers only: a global layer carries no
+            # positional signal (assumed: docs/AFMOE.md)
+            rope_layout=win,
+            qk_norm_per_head=True,
+            use_expert_bias=True,
+            route_norm_eps=1e-20,
+            attention_gate=True,
+            branch_norms=True,
+            embed_scale=float(D) ** 0.5,
+            expert_token_block=1024,
+        )
+
     @classmethod
     def _axk1_from_hf(cls, get) -> "ModelConfig":
         """A.X-K1's keys, and the chip's share where the file states one
@@ -770,11 +952,7 @@ class ModelConfig:
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"axk1: scoring_func={scoring!r}")
         E = int(get("n_routed_experts"))
-        held, offset = (int(get("n_routed_experts_held") or 0),
-                        int(get("n_routed_experts_offset") or 0))
-        if held and not 0 <= offset <= E - held:
-            raise ValueError(f"axk1: experts [{offset}, {offset + held}) "
-                             f"are not among the router's {E}")
+        held, offset = cls._chip_share(get, "axk1", E, "n_routed_experts")
         return cls(
             vocab_size=get("vocab_size"),
             hidden_size=get("hidden_size"),

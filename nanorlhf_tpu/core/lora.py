@@ -26,6 +26,10 @@ five projections (`MLA_TARGETS`) in both of the model's stacks
 (`lora["dense_layers"]`, `lora["layers"]`); the leading dense MLP, the
 router, the routed and the shared experts are frozen. Again the model
 decides.
+
+On Trinity (`afmoe`, docs/AFMOE.md) the adapter sits on the attention
+projections and the gate's (`g_proj`) in both stacks; the leading dense MLP,
+the router, the routed and the shared experts are frozen.
 """
 
 from __future__ import annotations
@@ -77,6 +81,7 @@ def _proj_dims(config: ModelConfig, name: str) -> tuple[int, int]:
         "k_proj": (D, KV * hd),
         "v_proj": (D, KV * hd),
         "o_proj": (H * hd, D),
+        "g_proj": (D, H * hd),
         "gate_proj": (D, F),
         "up_proj": (D, F),
         "down_proj": (F, D),
@@ -90,7 +95,9 @@ def lora_targets(config: ModelConfig, lora: LoraConfig) -> tuple[str, ...]:
     if config.kv_lora_rank:
         return MLA_TARGETS
     if config.num_experts:
-        return tuple(t for t in lora.targets if t in ATTENTION_TARGETS)
+        kept = tuple(t for t in lora.targets if t in ATTENTION_TARGETS)
+        # the attention gate's projection goes with the attention's
+        return kept + (("g_proj",) if kept and config.attention_gate else ())
     return tuple(lora.targets)
 
 
@@ -98,16 +105,19 @@ def init_lora_params(
     config: ModelConfig, lora: LoraConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> dict:
     """A ~ N(0, 1/r) (kaiming-ish), B = 0 → adapter starts as identity."""
-    if config.kv_lora_rank:
+    if config.kv_lora_rank or (config.num_dense_layers
+                               and not config.conv_layers):
+        # a model of two stacks whose attention leaves lie over every layer
+        names = lora_targets(config, lora)
         Ld = config.num_dense_layers
         stacks = {"layers": config.num_hidden_layers - Ld}
         if Ld:
             stacks["dense_layers"] = Ld
         out = {}
         for i, (stack, L) in enumerate(sorted(stacks.items())):
-            keys = jax.random.split(jax.random.fold_in(key, i), len(MLA_TARGETS))
+            keys = jax.random.split(jax.random.fold_in(key, i), len(names))
             out[stack] = {}
-            for k, name in zip(keys, MLA_TARGETS):
+            for k, name in zip(keys, names):
                 d_in, d_out = _proj_dims(config, name)
                 out[stack][name] = {
                     "a": (jax.random.normal(k, (L, d_in, lora.r), jnp.float32)

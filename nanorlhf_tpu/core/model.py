@@ -64,8 +64,9 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
         from nanorlhf_tpu.core import mla
 
         return mla.init_params(config, key, dtype)
-    if config.conv_layers:
-        return _init_conv_model_params(config, key, dtype)
+    if (config.conv_layers or config.num_dense_layers
+            or config.n_shared_experts or config.experts_held):
+        return _init_stacked_model_params(config, key, dtype)
     hd = config.actual_head_dim
     D, F, V = config.hidden_size, config.intermediate_size, config.vocab_size
     H, KV, L = config.num_attention_heads, config.num_key_value_heads, config.num_hidden_layers
@@ -122,19 +123,27 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
 # an attention layer's own leaves; in a model with conv layers they are
 # stacked over the attention layers of their stack only, as `conv` is over
 # its conv layers (`_run_pattern_layers`)
-_ATTENTION_LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm", "k_norm")
+_ATTENTION_LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm",
+                     "k_norm", "g_proj")
 
 
-def _init_conv_model_params(config: ModelConfig, key, dtype) -> dict:
-    """The tree of a model with conv layers (LFM2, docs/STATE.md):
-    `dense_layers` (the leading layers with a dense SwiGLU) and `layers` (the
-    expert layers), each with the leaves every layer has stacked over its
-    layers (`input_layernorm`, the operator's norm; `post_attention_layernorm`,
-    the MLP's; the MLP), the attention layers' projections and per-head q/k
-    norms stacked over ITS attention layers, and `conv` over its conv layers:
-    `in_proj.kernel [n, D, 3D]` (`[b | c | u]`), `conv.kernel [n, K, D]` (the
-    depthwise taps, oldest first), `out_proj.kernel [n, D, D]`. The router
-    carries `bias [n, E]` (float32) where the selection is bias-corrected."""
+def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
+    """The tree of a GQA model with a dense stack before its expert stack, a
+    shared expert or a chip's share of the experts (LFM2, docs/STATE.md;
+    Trinity, docs/AFMOE.md): `dense_layers` (the leading layers with a dense
+    SwiGLU) and `layers` (the expert layers), each with the leaves every
+    layer has stacked over its layers (`input_layernorm`, the operator's
+    norm; `post_attention_layernorm`, the MLP's; the MLP), the attention
+    layers' projections and per-head q/k norms stacked over ITS attention
+    layers, and `conv` over its conv layers: `in_proj.kernel [n, D, 3D]`
+    (`[b | c | u]`), `conv.kernel [n, K, D]` (the depthwise taps, oldest
+    first), `out_proj.kernel [n, D, D]`. The router carries `bias [n, E]`
+    (float32) where the selection is bias-corrected. The experts are the
+    HELD ones, `[n, held, ...]`, under a router of every expert's width;
+    `shared_expert` is one SwiGLU of the shared experts' summed width;
+    `g_proj` the attention gate's projection (`config.attention_gate`);
+    `attn_branch_norm` / `mlp_branch_norm` the norms on the two branches
+    (`config.branch_norms`)."""
     hd = config.actual_head_dim
     D, V = config.hidden_size, config.vocab_size
     H, KV, K = (config.num_attention_heads, config.num_key_value_heads,
@@ -151,19 +160,25 @@ def _init_conv_model_params(config: ModelConfig, key, dtype) -> dict:
         nc = sum(k == "conv" for k in kinds)
         na = n - nc
         fan = lambda *shape: normal(shape, 1.0 / jnp.sqrt(shape[-2]))  # noqa: E731
-        lead = (n, experts) if experts else (n,)
-        mlp = {"gate_proj": {"kernel": fan(*lead, D, width)},
-               "up_proj": {"kernel": fan(*lead, D, width)},
-               "down_proj": {"kernel": fan(*lead, width, D)}}
+        swiglu = lambda lead, width: {  # noqa: E731
+            "gate_proj": {"kernel": fan(*lead, D, width)},
+            "up_proj": {"kernel": fan(*lead, D, width)},
+            "down_proj": {"kernel": fan(*lead, width, D)}}
         tree = {"input_layernorm": jnp.ones((n, D), dtype),
                 "post_attention_layernorm": jnp.ones((n, D), dtype)}
+        if config.branch_norms:
+            tree["attn_branch_norm"] = jnp.ones((n, D), dtype)
+            tree["mlp_branch_norm"] = jnp.ones((n, D), dtype)
         if experts:
-            tree["experts"] = mlp
+            tree["experts"] = swiglu((n, config.num_held_experts), width)
             tree["router"] = {"kernel": fan(n, D, experts)}
             if config.use_expert_bias:
                 tree["router"]["bias"] = jnp.zeros((n, experts), jnp.float32)
+            if config.n_shared_experts:
+                tree["shared_expert"] = swiglu(
+                    (n,), config.n_shared_experts * width)
         else:
-            tree.update(mlp)
+            tree.update(swiglu((n,), width))
         if nc:
             tree["conv"] = {
                 "in_proj": {"kernel": fan(nc, D, 3 * D)},
@@ -178,6 +193,8 @@ def _init_conv_model_params(config: ModelConfig, key, dtype) -> dict:
             if config.qk_norm_per_head:
                 tree["q_norm"] = jnp.ones((na, hd), dtype)
                 tree["k_norm"] = jnp.ones((na, hd), dtype)
+            if config.attention_gate:
+                tree["g_proj"] = {"kernel": fan(na, D, H * hd)}
         return tree
 
     params = {"embed_tokens": normal((V, D), 0.02),
@@ -843,9 +860,9 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
         if config.scoring_func != "softmax" or config.routed_scaling_factor != 1.0:
             routing.update(scoring=config.scoring_func,
                            routed_scale=config.routed_scaling_factor)
-        if config.use_expert_bias:      # LFM2: selects with it, weighs without
+        if config.use_expert_bias:      # selects with it, weighs without
             routing.update(select_bias=layer_params["router"]["bias"],
-                           norm_eps=1e-6)
+                           norm_eps=config.route_norm_eps)
         def routed(x, router_h=None):
             return moe_mlp(
                 x, layer_params["router"]["kernel"],
@@ -956,6 +973,10 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     with jax.named_scope("mlp"):
         ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
                        expert_stack, expert_layer, live, router_h)
+        if config.branch_norms:     # afmoe: the branch is normed again
+            with jax.named_scope("norm"):
+                ff = rms_norm(ff, layer_params["mlp_branch_norm"],
+                              config.rms_norm_eps)
         x = x + ff
     return x, new_cache, aux
 
@@ -966,10 +987,13 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
     """A layer's attention on the normed state `h`, with its residual:
     `(x + attention, the updated cache stacks | None)`. In four parts, each
     under its scope (utils/profiling.py `DEVICE_SCOPES`): the projections
-    and rotary (`attn.qkv`), the new tokens' write into the cache
-    (`attn.write`, `_cache_write`), the contraction (`attn.read`; a pattern
-    model's is `attn.global` / `attn.window`, its write inside) and the
-    output projection (`attn.out`)."""
+    and rotary (`attn.qkv`; the gate's projection too, where the model has
+    one), the new tokens' write into the cache (`attn.write`,
+    `_cache_write`), the contraction (`attn.read`; a pattern model's is
+    `attn.global` / `attn.window`, its write inside) and the output
+    projection (`attn.out`); afmoe's gate, `out * sigmoid(g)`, is a fifth
+    between the last two (`attn.gate`), and its branch norm closes
+    `attn.out`."""
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
@@ -978,6 +1002,8 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
         q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
         k = _proj(h, layer_params, lora_layer, "k_proj", lora_scale)
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
+        gate = (_proj(h, layer_params, lora_layer, "g_proj", lora_scale)
+                if config.attention_gate else None)
         if config.qk_norm:
             # OLMoE: over the whole projection width, before the head split
             q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
@@ -1017,11 +1043,23 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
             out = _attention_read(config, q, k, v, mask, new_cache,
                                   decode_bounds, verify_bounds, paged, layer,
                                   spmd, attn_fn)
-    with jax.named_scope("attn.out"):
+    def merged(out):    # [B, H, T, hd] as the cache's heads -> [B, T, H hd]
         if config.kv_head_pack > 1:
             out = _unpack_heads(out, KV, config.kv_head_pack)
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+        return out.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+
+    if gate is not None:    # afmoe: per element, before the output projection
+        with jax.named_scope("attn.gate"):
+            out = merged(out) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype)
+    with jax.named_scope("attn.out"):
+        if gate is None:
+            out = merged(out)
         out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
+        if config.branch_norms:     # afmoe: the branch is normed again
+            with jax.named_scope("norm"):
+                out = rms_norm(out, layer_params["attn_branch_norm"],
+                               config.rms_norm_eps)
         return x + out, new_cache
 
 
@@ -1780,9 +1818,12 @@ def _logits(config: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
         return x @ unembedding_weight(config, params)
 
 
-def _embed(params: dict, ids: jnp.ndarray) -> jnp.ndarray:
+def _embed(config: ModelConfig, params: dict, ids: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("embed"):
-        return params["embed_tokens"][ids].astype(params["embed_tokens"].dtype)
+        x = params["embed_tokens"][ids].astype(params["embed_tokens"].dtype)
+        if config.embed_scale != 1.0:   # afmoe (muP): x sqrt(hidden_size)
+            x = x * jnp.asarray(config.embed_scale, x.dtype)
+        return x
 
 
 # ---------------------------------------------------------------------------
@@ -1820,7 +1861,7 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     no second forward).
     """
     attention_mask = attention_mask.astype(bool)
-    x = _embed(params, input_ids)
+    x = _embed(config, params, input_ids)
     T = input_ids.shape[1]
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
@@ -2191,7 +2232,7 @@ def prefill(
         T_max = _cache_leaf(kv_caches).shape[3]
     attention_mask = attention_mask.astype(bool)
     position_ids = jnp.cumsum(attention_mask, axis=1) - attention_mask.astype(jnp.int32)
-    x = _embed(params, jnp.where(attention_mask, input_ids, 0))
+    x = _embed(config, params, jnp.where(attention_mask, input_ids, 0))
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     # queries attend over cache positions [0, T); the rest of T_max is masked
@@ -2243,7 +2284,7 @@ def decode_step(
         # the mask's width is what the XLA read goes by (`_kv_views`); the
         # cache write below addresses the full stack as ever
         key_mask = key_mask[:, :extent]
-    x = _embed(params, token)[:, None, :]
+    x = _embed(config, params, token)[:, None, :]
     cos, sin = _rope(config, position[:, None])
     mask = key_mask[:, None, None, :]  # [B, 1, 1, T_max]
     # valid cache slots form the contiguous range [start, cache_index+1):
@@ -2341,7 +2382,7 @@ def decode_verify(
     T_max = key_mask.shape[1]
     paged = _kind_paged(config, page_table, page_size)
     key_mask = key_mask.astype(bool)
-    x = _embed(params, tokens)
+    x = _embed(config, params, tokens)
     cos, sin = _rope(config, positions)
     slot = jnp.arange(T_max)[None, None, :]                  # [1, 1, T_max]
     qi = jnp.arange(Tq)[None, :, None]                       # [1, Tq, 1]

@@ -35,6 +35,18 @@ family's modelling code): `operator_norm` / `ffn_norm` ↔ `input_layernorm` /
 `model.embedding_norm` ↔ `norm`. Each stack holds an attention layer's leaves
 over ITS attention layers and `conv` over its conv layers (`_lfm2_*`).
 
+Trinity (`model_type: afmoe`; docs/AFMOE.md; names assumed from the family's
+modelling code): `self_attn.{q,k,v,o}_proj`, `self_attn.gate_proj` ↔ `g_proj`,
+`self_attn.{q,k}_norm` ↔ `{q,k}_norm [hd]`; the four norms `input_layernorm`,
+`post_attention_layernorm` ↔ `attn_branch_norm` (it norms the BRANCH here),
+`pre_mlp_layernorm` ↔ `post_attention_layernorm` (this tree's name for the
+MLP's input norm) and `post_mlp_layernorm` ↔ `mlp_branch_norm`;
+`mlp.{gate,up,down}_proj` (the leading dense layers, in `dense_layers`),
+`mlp.router.gate.weight` ↔ `router.kernel`, `mlp.expert_bias` ↔
+`router.bias`, `mlp.shared_experts.*` ↔ `shared_expert.*` and
+`mlp.experts.{offset + e}.*` ↔ the HELD experts (`_two_stack_*`, A.X-K1's
+loader with this family's names).
+
 Weight fidelity (GQA head layout, tied embeddings, RoPE) is pinned by
 tests/test_model_parity.py against the torch Qwen2 AND Llama
 implementations.
@@ -120,22 +132,33 @@ def _rope_columns(config: ModelConfig, name: str, kernel, to_ours: bool):
     return kernel
 
 
-def _mla_stacks(config: ModelConfig):
-    """(tree name, HF layer indices) of an A.X-K1 tree's two stacks."""
+def _two_stacks(config: ModelConfig):
+    """(tree name, HF layer indices) of the two stacks of a model whose
+    leading layers have a dense MLP and whose attention leaves lie over
+    every layer (A.X-K1, Trinity)."""
     Ld = config.num_dense_layers
     stacks = [("layers", range(Ld, config.num_hidden_layers))]
     return ([("dense_layers", range(Ld))] if Ld else []) + stacks
 
 
-def _mla_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+def _two_stack_names(config: ModelConfig):
+    """(norm pairs, linear pairs, the router's HF name, its bias's | None)
+    of such a model's family."""
+    if config.kv_lora_rank:
+        return _MLA_NORM_KEYS, _MLA_LINEAR_KEYS, "mlp.gate", None
+    return _AFMOE_NORMS, _AFMOE_LINEAR, "mlp.router.gate", "mlp.expert_bias"
+
+
+def _two_stack_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    norms, linears, router, bias = _two_stack_names(config)
     held = range(config.experts_offset,
                  config.experts_offset + config.num_held_experts)
     params = {}
-    for stack, idx in _mla_stacks(config):
+    for stack, idx in _two_stacks(config):
         at = lambda i, name: sd[f"model.layers.{i}.{name}.weight"]  # noqa: E731
         tree = {ours: cast(np.stack([at(i, theirs) for i in idx]))
-                for ours, theirs in _MLA_NORM_KEYS}
-        for ours, theirs in _MLA_LINEAR_KEYS:
+                for ours, theirs in norms}
+        for ours, theirs in linears:
             tree[ours] = {"kernel": cast(np.stack([
                 _rope_columns(config, ours, at(i, theirs).T, True)
                 for i in idx]))}
@@ -145,7 +168,10 @@ def _mla_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
             tree.update(mlp("mlp"))
         else:
             tree["router"] = {"kernel": cast(np.stack(
-                [at(i, "mlp.gate").T for i in idx]))}
+                [at(i, router).T for i in idx]))}
+            if bias:
+                tree["router"]["bias"] = jnp.asarray(np.stack(
+                    [sd[f"model.layers.{i}.{bias}"] for i in idx]), jnp.float32)
             tree["experts"] = {name: {"kernel": cast(np.stack([
                 np.stack([at(i, f"mlp.experts.{e}.{name}").T for e in held])
                 for i in idx]))} for name in _MLP_KEYS}
@@ -155,21 +181,24 @@ def _mla_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
     return params
 
 
-def _mla_sd_from_params(config: ModelConfig, params: dict, put) -> None:
-    for stack, idx in _mla_stacks(config):
+def _two_stack_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    norms, linears, router, bias = _two_stack_names(config)
+    for stack, idx in _two_stacks(config):
         tree = params[stack]
         for j, i in enumerate(idx):
             pre = f"model.layers.{i}."
-            for ours, theirs in _MLA_NORM_KEYS:
+            for ours, theirs in norms:
                 put(f"{pre}{theirs}.weight", tree[ours][j])
-            for ours, theirs in _MLA_LINEAR_KEYS:
+            for ours, theirs in linears:
                 put(f"{pre}{theirs}.weight", _rope_columns(
                     config, ours, np.asarray(tree[ours]["kernel"][j]), False).T)
             if "router" not in tree:
                 for name in _MLP_KEYS:
                     put(f"{pre}mlp.{name}.weight", tree[name]["kernel"][j].T)
                 continue
-            put(f"{pre}mlp.gate.weight", tree["router"]["kernel"][j].T)
+            put(f"{pre}{router}.weight", tree["router"]["kernel"][j].T)
+            if bias:
+                put(f"{pre}{bias}", tree["router"]["bias"][j])
             for name in _MLP_KEYS:
                 kernel = tree["experts"][name]["kernel"][j]
                 for e in range(kernel.shape[0]):
@@ -178,6 +207,13 @@ def _mla_sd_from_params(config: ModelConfig, params: dict, put) -> None:
                 if "shared_expert" in tree:
                     put(f"{pre}mlp.shared_experts.{name}.weight",
                         tree["shared_expert"][name]["kernel"][j].T)
+
+
+_AFMOE_LINEAR = _ATTENTION_KEYS + (("g_proj", "self_attn.gate_proj"),)
+_AFMOE_NORMS = (("input_layernorm", "input_layernorm"),
+                ("attn_branch_norm", "post_attention_layernorm"),
+                ("post_attention_layernorm", "pre_mlp_layernorm"),
+                ("mlp_branch_norm", "post_mlp_layernorm")) + _QK_NORM_KEYS
 
 
 _LFM2_MLP = (("gate_proj", "w1"), ("up_proj", "w3"), ("down_proj", "w2"))
@@ -297,8 +333,8 @@ def params_from_hf_state_dict(
     def cast(x):
         return jnp.asarray(x, dtype)
 
-    if config.kv_lora_rank:
-        params = _mla_params_from_sd(config, sd, cast)
+    if config.kv_lora_rank or config.model_type == "afmoe":
+        params = _two_stack_params_from_sd(config, sd, cast)
         params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
                       norm=cast(sd["model.norm.weight"]))
         if not config.tie_word_embeddings:
@@ -373,8 +409,8 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
         if not config.tie_word_embeddings:
             put("lm_head.weight", params["lm_head"].T)
         return sd
-    if config.kv_lora_rank:
-        _mla_sd_from_params(config, params, put)
+    if config.kv_lora_rank or config.model_type == "afmoe":
+        _two_stack_sd_from_params(config, params, put)
         L = 0       # the two stacks are written; the rest is shared
     for i in range(L):
         for ours, theirs in norm_keys:
@@ -453,12 +489,14 @@ def export_hf_checkpoint(
     # (sliding_window, ...) to keys we never write. Anything else falls
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
-        "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe") else (
+        "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe",
+        "afmoe") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
             "smallthinker": "SmallThinkerForCausalLM",
-            "lfm2_moe": "Lfm2MoeForCausalLM"}[family]
+            "lfm2_moe": "Lfm2MoeForCausalLM",
+            "afmoe": "AfmoeForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -516,6 +554,25 @@ def export_hf_checkpoint(
             norm_eps=config.rms_norm_eps,
             rope_parameters={"rope_theta": config.rope_theta,
                              "rope_type": "default"})
+    elif family == "afmoe":
+        L = config.num_hidden_layers
+        del hf_config["attention_bias"]
+        hf_config.update(
+            layer_types=["sliding_attention" if w else "full_attention"
+                         for w in (config.sliding_window_layout or (0,) * L)],
+            sliding_window=config.sliding_window,
+            num_dense_layers=config.num_dense_layers,
+            num_experts=config.num_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            num_shared_experts=config.n_shared_experts,
+            score_func=config.scoring_func, route_norm=config.norm_topk_prob,
+            route_scale=config.routed_scaling_factor, mup_enabled=True,
+            n_group=1, topk_group=1, num_expert_groups=1,
+            num_limited_groups=1, rope_scaling=None)
+        if config.experts_held:     # a chip's share is no whole checkpoint
+            hf_config.update(num_experts_held=config.experts_held,
+                             num_experts_offset=config.experts_offset)
     elif family == "smallthinker":
         L = config.num_hidden_layers
         for key in ("intermediate_size", "attention_bias", "hidden_act"):

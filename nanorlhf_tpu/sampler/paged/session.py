@@ -663,11 +663,11 @@ class DecodeSession:
                           or prefix_cache is None
                           or not getattr(prefix_cache, "enabled", False)):
             raise NotImplementedError(
-                "a model with window layers decodes in a serving session "
-                "only (per_row=True, pages handed out by the engine's "
-                "RadixCache): speculative decode and the rollout "
-                "scheduler's device free list are not built for a page "
-                "pool of two kinds (docs/SWA.md)")
+                f"a model with window layers ({config.model_type}) decodes "
+                "in a serving session only (per_row=True, pages handed out "
+                "by the engine's RadixCache): speculative decode and the "
+                "rollout scheduler's device free list are not built for a "
+                "page pool of two kinds (docs/SWA.md)")
 
         self._radix = prefix_cache if (
             prefix_cache is not None
@@ -837,10 +837,17 @@ class DecodeSession:
         self.attn_table_pages = 0
         # slots a decode step's read touched in a layer of each kind
         # (`serving/global_slots_read`, `serving/window_slots_read`), and
-        # window pages written again behind the window
+        # window pages written again behind the window, of which by a row
+        # that was DECODING (its ring wrapped after its prompt:
+        # `serving/window_pages_reused_in_decode`), and the live rows whose
+        # context had passed the window, summed a step
+        # (`serving/rows_past_window`, beside `serving/decode_steps`)
         self.global_slots_read = 0
         self.window_slots_read = 0
         self.window_pages_reused = 0
+        self.window_pages_reused_in_decode = 0
+        self.rows_past_window = 0
+        self.live_row_steps = 0     # live rows, summed a step
         self._row_reused_np = np.zeros((R,), np.int64)
         self.attn_in_place = int(not self.spec
                                  and use_paged_decode_kernel(config))
@@ -1251,7 +1258,21 @@ class DecodeSession:
             table_dev = (jnp.array(self.table_np)
                          if self._radix is not None else self._pstate.table)
             if self._ring is not None:
-                table_dev = (table_dev, jnp.array(self._ring.table))
+                ring_table = self._ring.table
+                if self._pending:
+                    # a row between two of its prefill pieces is resident
+                    # and not live, and a decode step still writes such a
+                    # row's token at slot Tp - 1 wherever the write is the
+                    # row scatter (every backend but a TPU under the
+                    # live-row kernel). Its global page is the last piece's
+                    # to overwrite; in the RING that block shares a page
+                    # with a block `ring` blocks earlier, which the next
+                    # piece's window may still read. The chunk sees no ring
+                    # of a pending row (the pieces take `_row_table`).
+                    ring_table = ring_table.copy()
+                    ring_table[[p.row for p in self._pending]] = \
+                        self._ring.num_pages
+                table_dev = (table_dev, jnp.array(ring_table))
                 if self.state_layers:
                     table_dev += (self._state_rows,)
             if self.spec:
@@ -1369,9 +1390,11 @@ class DecodeSession:
             self.attn_live_pages += int(
                 np.sum(np.where(steps > s, last - first + 1, 0)))
             span = np.where(steps > s, slot - self._row_start_np + 1, 0)
+            self.live_row_steps += int((steps > s).sum())
             self.global_slots_read += int(span.sum())
             if window:
                 self.window_slots_read += int(np.minimum(span, window).sum())
+                self.rows_past_window += int((span > window).sum())
         self.attn_table_pages += its * self.rows * self.nb
         self._row_gen_np += steps
         if self._ring is not None:
@@ -1379,6 +1402,10 @@ class DecodeSession:
                 reused = self._ring.reused(
                     r, (self.Tp + self._row_gen_np[r] - 2) // self.page_size)
                 self.window_pages_reused += reused - self._row_reused_np[r]
+                # what the prompt's own blocks had wrapped is not decode's
+                self.window_pages_reused_in_decode += max(0, reused - max(
+                    int(self._row_reused_np[r]), self._ring.reused(
+                        r, (self.Tp - 1) // self.page_size)))
                 self._row_reused_np[r] = reused
         self._row_live_np[live] &= ~done_h[live]
 

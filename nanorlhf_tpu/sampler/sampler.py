@@ -225,10 +225,11 @@ def compose_check(sampling: SamplingParams, *,
     if config is not None and config.attention_pattern is not None and (
             sampling.spec_k > 0 or sampling.page_size > 0):
         raise NotImplementedError(
-            "a model with window layers (docs/SWA.md) rolls out on the "
-            "contiguous cache only: speculative decode (spec_k > 0) and the "
-            "paged rollout paths (page_size > 0) are not built for a page "
-            "pool of two kinds; the serving session is")
+            f"a model with window layers ({config.model_type}; docs/SWA.md, "
+            "docs/AFMOE.md) rolls out on the contiguous cache only: "
+            "speculative decode (spec_k > 0) and the paged rollout paths "
+            "(page_size > 0) are not built for a page pool of two kinds; "
+            "the serving session is")
     if sampling.page_size > 0 and sampling.compaction_segments > 0:
         raise ValueError(
             "page_size > 0 is incompatible with compaction_segments > 0: "

@@ -480,6 +480,10 @@ class ServingEngine:
             "serving/pool_pages_global": self._sess.num_pages,
             "serving/pool_pages_window": self._sess.num_pages_window,
             "serving/window_pages_reused": self._sess.window_pages_reused,
+            "serving/window_pages_reused_in_decode":
+                self._sess.window_pages_reused_in_decode,
+            "serving/rows_past_window": self._sess.rows_past_window,
+            "serving/live_row_steps": self._sess.live_row_steps,
             "serving/global_slots_read": self._sess.global_slots_read,
             "serving/window_slots_read": self._sess.window_slots_read,
             # a state that is not a page (docs/STATE.md): 0 layers and bytes

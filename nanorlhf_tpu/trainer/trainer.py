@@ -879,7 +879,8 @@ class RLTrainer:
         if (config.kv_cache_quant == "int8"
                 and self.mcfg.attention_pattern is not None):
             raise NotImplementedError(
-                "kv_cache_quant='int8' with a model with window layers: the "
+                "kv_cache_quant='int8' with a model with window layers "
+                f"({self.mcfg.model_type}): the "
                 "int8 reads have no window bound (core/model._pattern_caches, "
                 "docs/SWA.md)")
         import dataclasses as _dc

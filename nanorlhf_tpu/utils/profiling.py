@@ -73,6 +73,10 @@ import jax
 # a custom call named `attn.global*` / `attn.window*` is a DECODE read to
 # the benchmark's trace reader (harness/attn_trace.py), and this one must
 # not be, whatever names it (its jitted wrapper today).
+# `attn.gate` (docs/AFMOE.md) is the product of the attention's output with
+# the sigmoid of the gate's projection, between the read and `attn.out`; the
+# projection itself is one of `attn.qkv`'s, and the two branch norms of such
+# a model run under `norm` inside `attn.out` and `mlp`.
 # A conv layer's operator (docs/STATE.md) stands in the attention's slot and
 # takes names of its family, so that a reader that keeps `attn.*` keeps it:
 # `attn.conv` around `attn.conv.in` (the input projection and the gate),
@@ -81,7 +85,7 @@ import jax
 DEVICE_SCOPES = (
     "prefill", "decode", "verify", "install", "score", "update", "sync",
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
-    "attn.out", "attn.paged_flash", "attn.conv", "attn.conv.in",
+    "attn.out", "attn.gate", "attn.paged_flash", "attn.conv", "attn.conv.in",
     "attn.conv.mix", "attn.conv.out", "mlp", "head", "sample", "logprob",
     "loss", "optim",
 )

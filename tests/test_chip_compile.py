@@ -867,3 +867,118 @@ def test_lfm2_session_programs_keep_pages_and_state_in_place_on_v5e(
     # no gathered view of the row's pages: [.., 5120, 128] by slot
     assert not re.findall(r"bf16\[\d+,4,5120,128\]", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _trinity_session_program(case, v5e):
+    """The `serve-trinity-reason` cell's decode chunk, KV-only prefill piece
+    or closing admission forward, lowered for a described v5e at the
+    configuration file's own cut (Trinity-Large-Preview's published widths,
+    one dense window layer and one period of expert layers, 32 of 256
+    experts held, an eighth of the vocabulary) and the cell's engine sizes:
+    `(compiled, cache shapes, config)`."""
+    import json
+    import os
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.sampler.paged.pages import ring_blocks
+    from nanorlhf_tpu.serving import radix
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "trinity-large-ep8-l5.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "reason-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = (eng["rows"], eng["prompt_len"], eng["max_new_tokens"],
+                         eng["prefill_chunk"])
+    nb = (Tp + new) // PAGE
+    ring = ring_blocks(cfg.sliding_window, PAGE, chunk)
+    pages = (R * nb + nb, R * ring)     # one row's spare pages (headroom 0)
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, pages, PAGE, jnp.bfloat16))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, (spec((R, nb), jnp.int32),) * 2,
+            spec((R,), jnp.float32), spec((R,), jnp.float32),
+            spec((R,), jnp.bool_), spec((R,), jnp.int32), Tp=Tp,
+            max_tokens=new, page_size=PAGE, sync_every=eng["sync_every"],
+            eos_token_id=1, pad_token_id=0, temperature=1.0, top_p=1.0,
+            greedy=False, lora_scale=1.0, top_k=64, capture_logprobs=False,
+            approx_top_k=True)
+    else:
+        args = (params, cfg, spec((1, chunk), jnp.int32),
+                spec((1, chunk), jnp.int32), spec((1,), jnp.int32))
+        tail = (spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip),
+                (spec((nb,), jnp.int32),) * 2)
+        if case == "prefill_piece":
+            lowered = session._prefill_chunk_fwd.lower(
+                *args, *tail, page_size=PAGE, lora_scale=1.0)
+        else:
+            lowered = radix.suffix_logits.lower(
+                *args, spec((), jnp.int32), *tail, page_size=PAGE,
+                lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_piece", "admission"])
+def test_trinity_session_programs_fit_the_chip_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 42, asked of the chip's compiler at the `serve-trinity-reason`
+    cell's own shapes (the configuration file's cut: 8.64 GB of bf16 weights,
+    32 rows of 9,216 slots, pages of 128, a global pool of 2,376 pages x 1
+    layer and a window pool of 1,344 x 4): the session's decode chunk, its
+    1,024-token KV-only prefill piece and its closing admission forward each
+    FIT 16 GB (arguments + temporaries + results less what they alias), alias
+    both pools from their parameters to their results, hold no `copy` of a
+    pool leaf, read the pages through the two in-place kernels
+    (`%attn.global*` / `%attn.window*` at T = 1, `%paged_prefill_attention*`
+    at T > 1) and run the experts through the grouped-matmul kernel. A later
+    change that grows a buffer fails here, on the CPU."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _trinity_session_program(case, v5e)
+    hlo = compiled.as_text()
+    kept = hlo_stacks(jax.tree.leaves(cache))
+    assert set(kept) == {("bf16", (1, 2376, 8, PAGE, 128)),
+                         ("bf16", (4, 1344, 8, PAGE, 128))}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.5e9 < m.argument_size_in_bytes < 13.0e9     # weights + pools
+    assert m.alias_size_in_bytes > 4.0e9                  # both pools donated
+    assert peak < 15.5e9, (case, peak, m.temp_size_in_bytes)
+    assert m.temp_size_in_bytes < 1.5e9, (case, m.temp_size_in_bytes)
+    comps = _computations(hlo)
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(kept)]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    calls = [line.strip().split(" ")[0] for line in hlo.splitlines()
+             if re.match(r"\s*%(attn\.|paged_prefill)[\w.]* = \S+ custom-call\(",
+                         line)]
+    if case == "decode_chunk":
+        assert {c.split(".")[1] for c in calls} == {"global", "window"}, calls
+    else:
+        assert calls and all(c.startswith("%paged_prefill_attention")
+                             for c in calls), calls
+    # no gathered view of a row's pages: [.., 9216, 128] by slot
+    assert not re.findall(r"bf16\[\d+,8,9216,128\]", hlo)

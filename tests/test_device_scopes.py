@@ -44,6 +44,7 @@ KINDS = {
     "pattern": lambda: ModelConfig.smallthinker_tiny(
         vocab_size=256, window=16, layers=4),
     "conv": lambda: ModelConfig.lfm2_tiny(vocab_size=256),
+    "gated": lambda: ModelConfig.trinity_tiny(vocab_size=256, window=16),
 }
 # what a layer's attention is made of, by kind (the older families are the
 # latent model's `mla.*` and the pattern model's `attn.global` / `.window`)
@@ -55,6 +56,9 @@ ATTENTION = {
                 "attn.out"},
     "conv": {"attn.qkv", "attn.write", "attn.global", "attn.out", "attn.conv",
              "attn.conv.in", "attn.conv.mix", "attn.conv.out"},
+    # afmoe: the pattern model's parts and the gate between read and out
+    "gated": {"attn.qkv", "attn.write", "attn.global", "attn.window",
+              "attn.gate", "attn.out"},
 }
 MODEL = {"embed", "norm", "attn", "mlp", "head"}
 MATMUL_HOMES = ("attn", "mlp", "head")
@@ -175,7 +179,7 @@ def session_programs(kind, **config):
     nb = (PROMPT + NEW) // PAGE
     pages, table, row_table = ROWS * nb + nb, spec((ROWS, nb), jnp.int32), \
         spec((nb,), jnp.int32)
-    if kind == "pattern":
+    if kind in ("pattern", "gated"):
         pages = (pages, ROWS * ring_blocks(cfg.sliding_window, PAGE, PAGE))
         table, row_table = (table,) * 2, (row_table,) * 2
     state_rows = {}
@@ -259,7 +263,8 @@ def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
     assert "attn.paged_flash" not in parts_of(ops_of(walk["piece"]), "prefill")
 
 
-@pytest.mark.parametrize("kind", ["dense", "latent", "pattern", "conv"])
+@pytest.mark.parametrize("kind", ["dense", "latent", "pattern", "conv",
+                                  "gated"])
 def test_a_pieces_write_by_page_carries_attn_write(kind):
     """ISSUE 41: a piece of a page or more writes its K and V by PAGE
     (`core/model._paged_page_write`: the touched pages gathered, patched and
@@ -270,8 +275,9 @@ def test_a_pieces_write_by_page_carries_attn_write(kind):
     sixteen of them a piece read as no scope at all (PERF.md PR 37)."""
     piece = session_programs(kind)["piece"]
     ops = ops_of(piece)
-    homes = {"pattern": {"prefill/attn/attn.global/attn.write",
-                         "prefill/attn/attn.window/attn.write"},
+    both = {"prefill/attn/attn.global/attn.write",
+            "prefill/attn/attn.window/attn.write"}
+    homes = {"pattern": both, "gated": both,
              "conv": {"prefill/attn/attn.global/attn.write"}}.get(
         kind, {"prefill/attn/attn.write"})
     for part in ("gather", "select_n", "scatter"):
