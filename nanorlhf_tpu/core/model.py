@@ -1613,6 +1613,14 @@ def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0
         return x, new_caches, aux
 
 
+def _at(stacks, layer):
+    """Every leaf of stacked trees at one (traced) index of its leading
+    axis: a size-one dynamic slice of the stack where it lies."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stacks)
+
+
 def _kind_group(kind) -> int:
     """The cache group of a layer kind: 0 the global attention layers' pages,
     1 the window layers', 2 the conv layers' state."""
@@ -1626,12 +1634,32 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
     """`_run_layers` for a model with a layer pattern: for each stack of
     layers (`_layer_stacks`) ONE scan over the periods of its pattern
     (`config.stack_pattern`), its body the period's layers in order, each of
-    its own static kind, `(window, rotary)` or `"conv"`. The stacked tree
-    `[n, ...]` is scanned as `[n / p, p, ...]` (a reshape of the leading
-    axis; the expert kernels stay out of the xs and are addressed in place
-    at the layer's index, `_expert_xs`); in a model with conv layers the
-    leaves only one kind has (`_ATTENTION_LEAVES`, `conv`) are stacked over
-    that kind's layers and scanned by its count a period. Whatever differs
+    its own static kind, `(window, rotary)` or `"conv"`. In a model with
+    conv layers the leaves only one kind has (`_ATTENTION_LEAVES`, `conv`)
+    are stacked over that kind's layers, and a layer's place among them is
+    that kind's count a period times the period plus its rank. The expert
+    kernels always stay out of the xs and are addressed in place at the
+    layer's index (`_expert_xs`). How a layer gets its OTHER leaves goes by
+    what the call can see:
+
+    - the CACHED forward (`kv_caches`: a session's decode chunk, prefill
+      piece, suffix and admission forwards, `generate()`) scans the period's
+      index alone and every layer takes each leaf from the WHOLE stack at
+      its own index (`_at`: a size-one dynamic slice), as the expert kernels
+      are. Each such slice has one user, the layer's matmul, and the
+      compiler reads the stack there. A period's slice `[p, ...]` of scanned
+      xs has `p` users, fuses into none of them and is set down: at
+      SmallThinker's widths the q and o kernels of four layers, 73 MB each,
+      copied every period of every decode step (0.585 of a 5.65 ms step,
+      PERF.md PR 43);
+    - the UNCACHED forward (scoring, training, `remat`) scans the stacked
+      tree `[n, ...]` as `[n / p, p, ...]` (a reshape of the leading axis):
+      the backward of an index into a closed-over stack carries a gradient
+      the size of the stack through the scan, where a scanned slice's is a
+      slice. A call with a `layer_transform` (the FSDP hook: a scanned
+      slice enters as a shard) scans too.
+
+    Whatever differs
     by kind comes as a tuple by cache group (`_kind_group`) and a layer
     takes its kind's: the mask (built once a call, `_kind_masks`), the
     decode and verify bounds, the block table, and the CACHE, groups of
@@ -1661,34 +1689,50 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
         # such a model at real widths would have to repair (under LoRA the
         # experts are frozen)
         layer_xs, expert_stack = _expert_xs(tree, in_place=True)
-        periods = lambda t, per=p: jax.tree.map(  # noqa: E731
-            lambda a: a.reshape((n, per) + a.shape[1:]), t)
-        own_xs = None       # the leaves only one kind's layers have
-        if split:           # (attention's, conv's), each by its own count
-            layer_xs = dict(layer_xs)
-            conv_own = {"conv": layer_xs.pop("conv", None)}
-            attn_own = {name: layer_xs.pop(name) for name in _ATTENTION_LEAVES
-                        if name in layer_xs}
-            own_xs = (periods(attn_own, per_period[0] + per_period[1]),
-                      periods(conv_own, per_period[2]))
+        # a layer's leaves lie in up to four stacked trees: what every layer
+        # has, its adapters, and in a model with conv layers what only the
+        # attention layers and only the conv layers have, each stacked over
+        # ITS layers, `per` of them a period
+        trees = [layer_xs, lora, None, None]
+        per = (p, p, per_period[0] + per_period[1], per_period[2])
+        if split:
+            shared = dict(layer_xs)
+            trees[3] = {"conv": shared.pop("conv", None)}
+            trees[2] = {name: shared.pop(name) for name in _ATTENTION_LEAVES
+                        if name in shared}
+            trees[0] = shared
+        in_place = cached and layer_transform is None
+        xs = [None if in_place else jax.tree.map(
+            lambda a, k=k: a.reshape((n, k) + a.shape[1:]), t)
+            for t, k in zip(trees, per)]
         first = tuple(before)
 
         def body(carry, inp, pattern=pattern, groups=groups, rank=rank,
                  per_period=per_period, expert_stack=expert_stack, p=p,
-                 first=first):
+                 first=first, trees=trees, per=per, in_place=in_place):
             y, caches = carry
-            period_params, period_lora, i, period_own = inp
+            # (the index third, as it always was: the uncached programs
+            # stay the ones they were)
+            shared_xs, lora_xs, i, attn_xs, conv_xs = inp
+            period = (shared_xs, lora_xs, attn_xs, conv_xs)
+
+            def leaves(which, k):
+                """Tree `which` at the period's layer `k` of `per[which]`:
+                out of the whole stacks, or of the scanned period."""
+                if in_place:
+                    return _at(trees[which], i * per[which] + k)
+                return jax.tree.map(lambda a: a[k], period[which])
+
             auxes = []
             for j, kind in enumerate(pattern):
-                layer_params, lora_layer = jax.tree.map(
-                    lambda a: a[j], (period_params, period_lora))
                 g = groups[j]
-                if period_own is not None:
+                layer_params, lora_layer = leaves(0, j), leaves(1, j)
+                if split:
                     # its place among the period's layers of its own leaves
                     at = rank[j] if g == 2 else sum(
                         q != 2 for q in groups[:j])
-                    layer_params = {**layer_params, **jax.tree.map(
-                        lambda a: a[at], period_own[int(g == 2)])}
+                    layer_params = {**layer_params,
+                                    **leaves(3 if g == 2 else 2, at)}
                 if layer_transform is not None:
                     layer_params, lora_layer = layer_transform(layer_params,
                                                                lora_layer)
@@ -1717,8 +1761,7 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
             body = _rematerialized(config, body)
         (x, caches), stack_aux = jax.lax.scan(
             body, (x, caches),
-            (periods(layer_xs), periods(lora),
-             jnp.arange(n, dtype=jnp.int32), own_xs))
+            (*xs[:2], jnp.arange(n, dtype=jnp.int32), *xs[2:]))
         if stack_aux is not None:   # [n / p, p, ...] -> [n, ...]
             aux = jax.tree.map(
                 lambda a: a.reshape((n * p,) + a.shape[2:]), stack_aux)

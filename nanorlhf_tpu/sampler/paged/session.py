@@ -867,6 +867,11 @@ class DecodeSession:
             config, caches0, self.page_size, self.prefill_chunk or self.Tp,
             self.nb)
         self.kv_write_live_rows = int(live_rows and not self.spec)
+        # whether a layer takes its kernels by index into the whole stacks
+        # (`core/model._run_pattern_layers`: every cached forward of a
+        # pattern model; a model without a pattern scans one layer a trip
+        # and never copied a period)
+        self.layer_kernels_in_place = int(config.attention_pattern is not None)
 
         # dispatch accounting (module docstring): launches = model
         # forwards outside the decode/verify loop; decode iterations come

@@ -467,6 +467,8 @@ class ServingEngine:
             "serving/prefill_read_in_place": self._sess.prefill_read_in_place,
             "serving/kv_write_by_page": self._sess.kv_write_by_page,
             "serving/kv_write_live_rows": self._sess.kv_write_live_rows,
+            "serving/layer_kernels_in_place":
+                self._sess.layer_kernels_in_place,
             "serving/pool_donated": self._sess.pool_donated,
             "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
             "serving/latent_cache": self._sess.latent_cache,

@@ -459,6 +459,24 @@ def _live_row_write_calls(hlo: str) -> int:
         r"custom-call\(.*output_to_operand_aliasing=", hlo))
 
 
+def _unfused_results(comps):
+    """`(computation, name, result type, opcode, rest)` of every instruction
+    of a compiled module that stands OUTSIDE the fused computations and makes
+    something: parameters, bitcasts, tuple reads and control flow apart."""
+    import re
+
+    fused = {re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+             for instrs in comps.values() for _, _, op, rest in instrs
+             if op == "fusion"}
+    for name, instrs in comps.items():
+        if name in fused:
+            continue
+        for instr, result, op, rest in instrs:
+            if op not in ("parameter", "bitcast", "get-tuple-element", "while",
+                          "tuple", "conditional", "call"):
+                yield name, instr, result, op, rest
+
+
 def _leaf_sized_results(comps, pools) -> list:
     """The opcode (a fusion's: its root's) of every instruction of a compiled
     module that produces ONE array with as many elements as a pool leaf,
@@ -471,23 +489,13 @@ def _leaf_sized_results(comps, pools) -> list:
 
     sizes = {int(np.prod(shape)) for _, shape in pools}
     roots = {name: instrs[-1][2] for name, instrs in comps.items()}
-    fused = {re.search(r"calls=%?([\w.\-]+)", rest).group(1)
-             for instrs in comps.values() for _, _, op, rest in instrs
-             if op == "fusion"}
     made = []
-    for name, instrs in comps.items():
-        if name in fused:
+    for _, _, result, op, rest in _unfused_results(comps):
+        if not any(int(np.prod(s[1])) in sizes for s in _shapes(result)):
             continue
-        for _, result, op, rest in instrs:
-            shapes = [s for s in _shapes(result)
-                      if int(np.prod(s[1])) in sizes]
-            if not shapes or op in ("parameter", "bitcast",
-                                    "get-tuple-element", "while", "tuple",
-                                    "conditional", "call"):
-                continue
-            if op == "fusion":
-                op = roots[re.search(r"calls=%?([\w.\-]+)", rest).group(1)]
-            made.append(op)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%?([\w.\-]+)", rest).group(1)]
+        made.append(op)
     return made
 
 
@@ -574,49 +582,23 @@ def test_axk1_session_programs_keep_the_latent_pool_in_place_on_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-@pytest.mark.parametrize("case", ["decode_chunk", "prefill_chunk", "suffix"])
-def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
-        case, v5e, compiled_kernels, monkeypatch):
-    """ISSUE 34, asked of the chip's compiler at the
-    `serve-smallthinker-longshort` cell's shapes (SmallThinker's published
-    widths, one period of four layers; 32 rows of 16,384 slots, pages of
-    128, a ring of 42 window pages a row): the session's decode chunk and
-    its 1,024-token KV-only prefill chunk alias all FOUR leaves of the page
-    pool of two kinds (global `bf16[1,4224,4,128,128]` x (k, v), window
-    `bf16[3,1344,4,128,128]` x (k, v)) from their parameters to their
-    results and leave no `copy` of a leaf in the module. The decode chunk
-    reads them through the in-place kernel once a layer, named by its
-    kind's scope (`%attn.global*`, `%attn.window*`: what
-    benchmark/harness/attn_trace.py finds in the device trace); the expert
-    matmuls are the grouped-matmul kernel. The prefill chunk (ISSUE 37)
-    reads them through the flash kernel over the pages in place, once a
-    layer whose output something reads, under the inner scope `attn.paged_flash`, whose custom call that
-    reader's pattern does NOT take for a decode read; no float32
-    `[.., 1024, 1024]` score array is left in the module (XLA's walk wrote
-    63). ISSUE 41: whatever produces an array the size of a pool leaf is the
-    new tokens' write and nothing else, never a gather, a transpose or a
-    copy of one: in the piece and in the 1,024-token suffix forward the
-    scatter of whole pages into the leaf ITSELF, under `attn.write` (as a
-    scatter of 4,096 rows the compiler ran it over a `[rows, 128]` view of
-    the leaf and dropped its `op_name`: sixteen a piece at ~0.24 ms, PERF.md
-    PR 37), and in the decode chunk the live-row kernel's call
-    (ops/paged_cache_write, `%attn.write*`), whose outputs alias its pool
-    operands."""
+def _smallthinker_session_program(case, v5e, layers=4):
+    """The `serve-smallthinker-longshort` cell's decode chunk, KV-only
+    prefill piece or suffix forward, lowered for a described v5e at
+    SmallThinker's published widths and `layers` layers (periods of four):
+    `(compiled, cache shapes)`."""
     import dataclasses
-    import re
-
-    from test_cache_carry import _computations, _shapes, hlo_stacks
 
     from nanorlhf_tpu.core import ModelConfig, init_params
     from nanorlhf_tpu.core import model as M
     from nanorlhf_tpu.sampler.paged import session
     from nanorlhf_tpu.sampler.paged.pages import ring_blocks
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one_chip = SingleDeviceSharding(v5e[0])
+    layout = (0, 1, 1, 1) * (layers // 4)
     cfg = dataclasses.replace(
-        ModelConfig.smallthinker_21b(), num_hidden_layers=4,
-        sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1))
+        ModelConfig.smallthinker_21b(), num_hidden_layers=layers,
+        sliding_window_layout=layout, rope_layout=layout)
     params = _shapes_on(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
 
@@ -630,9 +612,6 @@ def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
     assert (nb, ring, pages) == (128, 42, (4224, 1344))
     cache = jax.eval_shape(
         lambda: M.init_paged_kv_cache(cfg, pages, PAGE, jnp.bfloat16))
-    pools = hlo_stacks(jax.tree.leaves(cache))
-    assert set(pools) == {("bf16", (1, 4224, 4, PAGE, 128)),
-                          ("bf16", (3, 1344, 4, PAGE, 128))}
     tables = (spec((R, nb), jnp.int32),) * 2
     if case == "decode_chunk":
         key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
@@ -662,7 +641,46 @@ def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
             spec((1,), jnp.int32), spec((1, Tp + new), jnp.bool_),
             _shapes_on(cache, one_chip), (spec((nb,), jnp.int32),) * 2,
             page_size=PAGE, lora_scale=1.0)
-    compiled = lowered.compile()
+    return lowered.compile(), cache
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_chunk", "suffix"])
+def test_smallthinker_session_programs_keep_both_pools_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 34, asked of the chip's compiler at the
+    `serve-smallthinker-longshort` cell's shapes (SmallThinker's published
+    widths, one period of four layers; 32 rows of 16,384 slots, pages of
+    128, a ring of 42 window pages a row): the session's decode chunk and
+    its 1,024-token KV-only prefill chunk alias all FOUR leaves of the page
+    pool of two kinds (global `bf16[1,4224,4,128,128]` x (k, v), window
+    `bf16[3,1344,4,128,128]` x (k, v)) from their parameters to their
+    results and leave no `copy` of a leaf in the module. The decode chunk
+    reads them through the in-place kernel once a layer, named by its
+    kind's scope (`%attn.global*`, `%attn.window*`: what
+    benchmark/harness/attn_trace.py finds in the device trace); the expert
+    matmuls are the grouped-matmul kernel. The prefill chunk (ISSUE 37)
+    reads them through the flash kernel over the pages in place, once a
+    layer whose output something reads, under the inner scope `attn.paged_flash`, whose custom call that
+    reader's pattern does NOT take for a decode read; no float32
+    `[.., 1024, 1024]` score array is left in the module (XLA's walk wrote
+    63). ISSUE 41: whatever produces an array the size of a pool leaf is the
+    new tokens' write and nothing else, never a gather, a transpose or a
+    copy of one: in the piece and in the 1,024-token suffix forward the
+    scatter of whole pages into the leaf ITSELF, under `attn.write` (as a
+    scatter of 4,096 rows the compiler ran it over a `[rows, 128]` view of
+    the leaf and dropped its `op_name`: sixteen a piece at ~0.24 ms, PERF.md
+    PR 37), and in the decode chunk the live-row kernel's call
+    (ops/paged_cache_write, `%attn.write*`), whose outputs alias its pool
+    operands."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache = _smallthinker_session_program(case, v5e)
+    pools = hlo_stacks(jax.tree.leaves(cache))
+    assert set(pools) == {("bf16", (1, 4224, 4, PAGE, 128)),
+                          ("bf16", (3, 1344, 4, PAGE, 128))}
     hlo = compiled.as_text()
     aliased = {int(n) for n in re.findall(
         r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
@@ -866,6 +884,68 @@ def test_lfm2_session_programs_keep_pages_and_state_in_place_on_v5e(
         assert len(calls) == 1, calls
     # no gathered view of the row's pages: [.., 5120, 128] by slot
     assert not re.findall(r"bf16\[\d+,4,5120,128\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _set_down(comps, shapes, layer_elements) -> list:
+    """What a compiled module sets down of its stacked kernels: every
+    instruction outside the fused computations whose result holds a bf16
+    array of one of `shapes` (with or without a leading 1 or 2), and every
+    one named `*.remat*` whose result holds an array of more than
+    `layer_elements` elements."""
+    from test_cache_carry import _shapes
+
+    made = []
+    for name, instr, result, op, _ in _unfused_results(comps):
+        for dtype, dims in _shapes(result):
+            while dims[:1] in ((1,), (2,)) and dims not in shapes:
+                dims = dims[1:]
+            if (dtype == "bf16" and dims in shapes) or (
+                    ".remat" in instr and int(np.prod(dims)) > layer_elements):
+                made.append(f"{name}: {instr} {op} {result}")
+                break
+    return made
+
+
+@pytest.mark.parametrize("model, case", [
+    ("smallthinker", "decode_chunk"), ("smallthinker", "prefill_chunk"),
+    ("lfm2", "decode_chunk"), ("lfm2", "prefill_piece")])
+def test_pattern_session_programs_set_down_no_period_of_kernels_on_v5e(
+        model, case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 43, asked of the chip's compiler at the cells' DEPTH, two
+    periods of the pattern (`serve-smallthinker-longshort`: 8 layers;
+    `serve-lfm2-chat`: the two dense conv layers and two periods [a, c, c,
+    c]), where the layer scan has two trips and a period's slice is dynamic
+    (at one period, as the cases above lower it, the scan has one trip and
+    the compiler reads the stacks with static slices): outside its fused
+    computations the decode chunk and the prefill piece produce NO array
+    shaped like a period of a projection stack, and nothing named `*.remat`
+    larger than one layer's kernel. The cached scan hands a layer its
+    kernels by index into the whole stacks (`core/model._run_pattern_layers`,
+    `_at`), so each slice has one user, the layer's matmul. As scanned xs a
+    period's slice had `p` users, fused into none and was set down: at
+    SmallThinker's widths `%dynamic-slice_bitcast_fusion.24` and its twin
+    `.24.remat` (`bf16[4,3584,2560]`), `.20` (`bf16[4,2560,3584]`), `.22`,
+    `.23` (`bf16[4,2560,512]`) and `%copy-done.1 bf16[4,3584,2560]`, 0.585 ms
+    of a 5.65 ms decode step on the chip (PERF.md PR 43); at LFM2's the conv
+    layers' `bf16[3,2048,6144]` and `bf16[3,2048,2048]`, each twice, and the
+    dense stack whole, `bf16[2,1,11776,2048]`, a trip of ITS scan. What is
+    still set down is a layer's worth: the q kernel of each layer on its way
+    into fast memory (`bf16[1,2560,3584]`), as in a model without a pattern."""
+    from test_cache_carry import _computations
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if model == "smallthinker":
+        compiled, _ = _smallthinker_session_program(case, v5e, layers=8)
+        shapes = {(4, 3584, 2560), (4, 2560, 3584), (4, 2560, 512)}
+        layer_elements = 2560 * 3584
+    else:
+        compiled, _, cfg = _lfm2_session_program(case, v5e, layers=10)
+        assert cfg.stack_pattern(2, 8) == ((False, True),) + ("conv",) * 3
+        shapes = {(3, 2048, 6144), (3, 2048, 2048), (2, 1, 11776, 2048)}
+        layer_elements = 11776 * 2048
+    made = _set_down(_computations(compiled.as_text()), shapes, layer_elements)
+    assert not made, "\n".join(made)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
