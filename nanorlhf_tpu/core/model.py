@@ -891,7 +891,7 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
                 cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
                 decode_bounds=None, verify_bounds=None, paged=None, layer=0,
                 expert_stack=None, stack_start=0, live=None, kind=None,
-                expert_layer=None, conv_ctx=None):
+                expert_layer=None, conv_ctx=None, kernels_in_place=False):
     """One decoder layer. If kv_cache is not None, operate incrementally.
 
     `kind=(window, rotary)` marks a layer of a pattern model
@@ -901,6 +901,9 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     `kind="conv"`: the operator is the gated short convolution
     (`_conv_operator`), `kv_cache` the state group, `paged` the rows' place
     in it and `conv_ctx` what the call knows of its tokens.
+    `kernels_in_place`: `layer_params`' leaves are size-one slices of the
+    whole stacks at a traced index (`_run_pattern_layers`' cached forward),
+    which `_attention` keeps its head split away from.
 
     Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
     kv_cache: the STACKED cache of every layer (init_kv_cache /
@@ -964,7 +967,7 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             x, new_cache = _attention(
                 config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
                 mask, kv_cache, cache_index, attn_fn, decode_bounds,
-                verify_bounds, paged, layer, kind)
+                verify_bounds, paged, layer, kind, kernels_in_place)
 
     router_h = h if config.router_input == "pre_attention" else None
     with jax.named_scope("norm"):
@@ -983,7 +986,7 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
 
 def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
                mask, kv_cache, cache_index, attn_fn, decode_bounds,
-               verify_bounds, paged, layer, kind):
+               verify_bounds, paged, layer, kind, kernels_in_place=False):
     """A layer's attention on the normed state `h`, with its residual:
     `(x + attention, the updated cache stacks | None)`. In four parts, each
     under its scope (utils/profiling.py `DEVICE_SCOPES`): the projections
@@ -993,7 +996,19 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
     `attn.global` / `attn.window`, its write inside) and the output
     projection (`attn.out`); afmoe's gate, `out * sigmoid(g)`, is a fifth
     between the last two (`attn.gate`), and its branch norm closes
-    `attn.out`."""
+    `attn.out`.
+
+    `kernels_in_place`: the layer took its kernels at a traced index of the
+    whole stacks (`_run_pattern_layers`' cached forward). The projections'
+    results are then fenced from the head split: the chip's compiler carries
+    a reshape `[B, T, H, hd]` and its transpose back INTO the q, k and v
+    kernels' layout (it wants them contraction-minor, `[H, hd, D]`), so it
+    relaid the whole stacks once a call and had to make each layer's relaid
+    slice before it could prefetch it, made, dropped and made again
+    (`.remat`): ~0.5 ms of a 5.3 ms decode step at SmallThinker's widths
+    (PERF.md PR 44; docs/SWA.md). Behind the fence a projection is a plain
+    matmul over the stack where it lies, as `o_proj`'s always was. A scanned
+    slice of xs is a layer's own buffer already and is not fenced."""
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
@@ -1004,6 +1019,8 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
         gate = (_proj(h, layer_params, lora_layer, "g_proj", lora_scale)
                 if config.attention_gate else None)
+        if kernels_in_place:    # a value is what it was: only where it lies
+            q, k, v, gate = jax.lax.optimization_barrier((q, k, v, gate))
         if config.qk_norm:
             # OLMoE: over the whole projection width, before the head split
             q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
@@ -1651,7 +1668,8 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
       xs has `p` users, fuses into none of them and is set down: at
       SmallThinker's widths the q and o kernels of four layers, 73 MB each,
       copied every period of every decode step (0.585 of a 5.65 ms step,
-      PERF.md PR 43);
+      PERF.md PR 43). Such a layer's attention is told so and keeps its
+      head split off the kernels (`_attention`'s `kernels_in_place`);
     - the UNCACHED forward (scoring, training, `remat`) scans the stacked
       tree `[n, ...]` as `[n / p, p, ...]` (a reshape of the leading axis):
       the backward of an index into a closed-over stack carries a gradient
@@ -1747,7 +1765,7 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
                     verify_bounds=pick(verify_bounds, g),
                     paged=pick(paged, g), layer=layer,
                     expert_stack=expert_stack, live=live, kind=kind,
-                    expert_layer=i * p + j,
+                    expert_layer=i * p + j, kernels_in_place=in_place,
                     **({"conv_ctx": conv_ctx} if kind == "conv" else {}))
                 if cached:
                     caches = tuple(cache if k == g else c

@@ -872,6 +872,12 @@ class DecodeSession:
         # pattern model; a model without a pattern scans one layer a trip
         # and never copied a period)
         self.layer_kernels_in_place = int(config.attention_pattern is not None)
+        # and whether its attention then fences the q, k and v projections'
+        # results from the head split, so that each is a matmul over the
+        # stack where it lies (`core/model._attention`; MLA's projections
+        # are core/mla.py's own)
+        self.qkv_kernels_in_place = int(
+            self.layer_kernels_in_place and not config.kv_lora_rank)
 
         # dispatch accounting (module docstring): launches = model
         # forwards outside the decode/verify loop; decode iterations come

@@ -469,6 +469,7 @@ class ServingEngine:
             "serving/kv_write_live_rows": self._sess.kv_write_live_rows,
             "serving/layer_kernels_in_place":
                 self._sess.layer_kernels_in_place,
+            "serving/qkv_kernels_in_place": self._sess.qkv_kernels_in_place,
             "serving/pool_donated": self._sess.pool_donated,
             "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
             "serving/latent_cache": self._sess.latent_cache,

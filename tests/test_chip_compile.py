@@ -929,9 +929,9 @@ def test_pattern_session_programs_set_down_no_period_of_kernels_on_v5e(
     `.23` (`bf16[4,2560,512]`) and `%copy-done.1 bf16[4,3584,2560]`, 0.585 ms
     of a 5.65 ms decode step on the chip (PERF.md PR 43); at LFM2's the conv
     layers' `bf16[3,2048,6144]` and `bf16[3,2048,2048]`, each twice, and the
-    dense stack whole, `bf16[2,1,11776,2048]`, a trip of ITS scan. What is
-    still set down is a layer's worth: the q kernel of each layer on its way
-    into fast memory (`bf16[1,2560,3584]`), as in a model without a pattern."""
+    dense stack whole, `bf16[2,1,11776,2048]`, a trip of ITS scan. What was
+    still set down after that, a LAYER's q / k / v kernel relaid on its way
+    into fast memory and the relaid stacks, is the next test's."""
     from test_cache_carry import _computations
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -947,6 +947,80 @@ def test_pattern_session_programs_set_down_no_period_of_kernels_on_v5e(
     made = _set_down(_computations(compiled.as_text()), shapes, layer_elements)
     assert not made, "\n".join(made)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def _kernels_moved(comps, widths) -> list:
+    """What a compiled module does to its q / k / v projection kernels
+    besides reading them: every instruction outside the fused computations
+    whose result holds a bf16 array `[D, width]` or `[layers, D, width]` of
+    `widths` (`(D, width)` pairs: one layer's kernel, a period's, a whole
+    stack), unless it is a PREFETCH, the array as it is stored (row-major)
+    brought into fast memory (`S(1)`), which is the kernel's one read; and
+    whatever of those shapes is named `*.remat*` (made a second time). A
+    `-start`'s result names its source beside its target: its `-done` is
+    judged."""
+    import re
+
+    array = re.compile(r"bf16\[([\d,]+)\]\{([\d,]*)(?::([^}]*))?\}")
+    moved = []
+    for name, instr, result, op, _ in _unfused_results(comps):
+        if op.endswith("-start"):
+            continue
+        for dims, order, tiling in array.findall(result):
+            dims = tuple(int(n) for n in dims.split(","))
+            if len(dims) not in (2, 3) or dims[-2:] not in widths:
+                continue
+            stored = order == ",".join(
+                str(n) for n in reversed(range(len(dims))))
+            if ".remat" in instr or not (stored and "S(1)" in tiling):
+                moved.append(f"{name}: {instr} {op} {result}")
+                break
+    return moved
+
+
+@pytest.mark.parametrize("model, case", [
+    ("smallthinker", "decode_chunk"), ("smallthinker", "prefill_chunk"),
+    ("lfm2", "decode_chunk"), ("lfm2", "prefill_piece"),
+    ("trinity", "decode_chunk")])
+def test_pattern_session_programs_read_qkv_kernels_where_they_lie_on_v5e(
+        model, case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 44, asked of the chip's compiler at the cells' depth (the
+    programs of the test above, and `serve-trinity-reason`'s decode chunk at
+    its own cut, a one-layer dense stack and one period of four): outside
+    its fused computations a program produces no bf16 array shaped like ONE
+    layer's q / k / v projection kernel or like a whole projection stack in
+    another layout than the stored one or outside fast memory, and nothing
+    of those shapes named `*.remat*`. `core/model._attention` fences the
+    projections' results from the head split where the layer took its
+    kernels at a traced index of the stacks (`optimization_barrier`), so the
+    reshape `[B, T, H, hd]` cannot reach back into a kernel's layout. Without
+    the fence the compiler wanted the kernels contraction-minor (`{1,2,0}`):
+    SmallThinker's decode chunk relaid the stacks once a call (`%copy.175
+    bf16[8,2560,3584]`, `%copy.174/.177 bf16[8,2560,512]`), set each layer's
+    relaid slice down twice a step (`%constant_dynamic-slice_fusion.15` and
+    `.15.remat bf16[1,2560,3584]`, `.16/.17` and their `.remat`s
+    `bf16[1,2560,512]`) and fetched the k / v stacks again every period
+    (`%copy-done.7/.9 bf16[8,2560,512]`); Trinity's did the same to its dense
+    layer (`%fusion.1124` and `.1124.remat bf16[1,3072,6144]`, `.1126/.1131`
+    with `.remat` and `.remat2`) and to its period (`%copy.484
+    bf16[4,3072,6144]`): temporaries 233 -> 14 MB and 349 -> 11 MB. What
+    stays is a prefetch of a kernel as it is stored (`%copy-done.9
+    bf16[1,3072,6144]{2,1,0:..S(1)}`): the kernel's read."""
+    from test_cache_carry import _computations
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if model == "smallthinker":
+        compiled, _ = _smallthinker_session_program(case, v5e, layers=8)
+        widths = {(2560, 3584), (2560, 512)}
+    elif model == "lfm2":   # (the conv layers' `out_proj` is as wide as q's)
+        compiled, _, _ = _lfm2_session_program(case, v5e, layers=10)
+        widths = {(2048, 2048), (2048, 512)}
+    else:                   # (q's kernel and the gate's have one shape)
+        compiled, _, _ = _trinity_session_program(case, v5e)
+        widths = {(3072, 6144), (3072, 1024)}
+    moved = _kernels_moved(_computations(compiled.as_text()), widths)
+    assert not moved, "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 def _trinity_session_program(case, v5e):

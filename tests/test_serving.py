@@ -549,20 +549,25 @@ def test_grpo_update_with_prefix_cache(tmp_path):
     assert sz["prefix_cache"]["lookups"] > 0
 
 @pytest.mark.parametrize("model, in_place", [
-    ("qwen2", 0), ("smallthinker", 1), ("lfm2", 1), ("trinity", 1)])
+    ("qwen2", 0), ("smallthinker", 1), ("lfm2", 1), ("trinity", 1),
+    ("axk1", 0)])
 def test_engine_says_how_its_programs_were_built(model, in_place):
     """The static gauges of `engine.metrics()` that say which form the
     session's programs took: `serving/layer_kernels_in_place` is 1 for a
     model with a layer pattern (its cached layer scan hands each layer its
     kernels by index into the stacks, `core/model._run_pattern_layers`) and 0
-    for one without (a plain scan, a layer a trip); off the TPU a piece of
+    for one without (a plain scan, a layer a trip), and
+    `serving/qkv_kernels_in_place` with it (such a layer's attention fences
+    its projections from the head split, `core/model._attention`; the latent
+    model's projections are core/mla.py's); off the TPU a piece of
     `prefill_chunk >= page_size` tokens writes by page on every model and no
     decode step writes through the live-row kernel."""
     from nanorlhf_tpu.serving.engine import ServingEngine
 
     config = {"qwen2": ModelConfig.qwen2_tiny, "lfm2": ModelConfig.lfm2_tiny,
               "smallthinker": ModelConfig.smallthinker_tiny,
-              "trinity": ModelConfig.trinity_tiny}[model](vocab_size=128)
+              "trinity": ModelConfig.trinity_tiny,
+              "axk1": ModelConfig.axk1_tiny}[model](vocab_size=128)
     params = init_params(config, jax.random.PRNGKey(7), jnp.float32)
     with ServingEngine(params, config, eos_token_id=EOS, pad_token_id=PAD,
                        page_size=4, prompt_len=16, max_new_tokens=8, rows=2,
@@ -572,6 +577,7 @@ def test_engine_says_how_its_programs_were_built(model, in_place):
         m = engine.metrics()
     assert (config.attention_pattern is not None) == bool(in_place)
     assert m["serving/layer_kernels_in_place"] == in_place
+    assert m["serving/qkv_kernels_in_place"] == in_place
     assert m["serving/kv_write_by_page"] == 1
     assert m["serving/kv_write_live_rows"] == 0
 

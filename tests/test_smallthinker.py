@@ -501,6 +501,26 @@ def _adapters(params):
     return out
 
 
+def _two_rows(cfg):
+    """A left-padded row beside a full one for the paged walks below: `(ids
+    [B, T_max], valid, positions, the kinds' tables, the pools' pages)` at
+    B = 2, T_max = 16, pages of 4; a model without a pattern gets one table
+    and one pool."""
+    B, P, T_max = 2, 4, 16
+    nb = T_max // P
+    rng = np.random.default_rng(3)
+    ids = rng.integers(3, V, (B, T_max)).astype(np.int32)
+    ids[0, :5] = 0
+    valid = ids != 0
+    pos = jnp.asarray(np.cumsum(valid, 1) - 1)
+    table = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    if cfg.attention_pattern is None:
+        return jnp.asarray(ids), valid, pos, table, B * nb
+    tabs = (table, table) + (
+        (jnp.arange(B, dtype=jnp.int32)[:, None],) if cfg.conv_layers else ())
+    return jnp.asarray(ids), valid, pos, tabs, (B * nb, B * nb)
+
+
 def _loop_layers(config, params, x, cos, sin, masks, kv_caches, cache_index,
                  lora_scale, remat, attn_fn, layer_transform, decode_bounds,
                  verify_bounds, paged, live, cached_aux, conv_ctx=None):
@@ -593,16 +613,7 @@ def test_the_cached_scan_indexes_the_stacks_and_the_uncached_scans_them(
         assert set(params["lora"]) == ({"layers"} if model != "trinity"
                                        else {"dense_layers", "layers"})
     B, T, P, T_max = 2, 12, 4, 16
-    nb = T_max // P
-    rng = np.random.default_rng(3)
-    ids = rng.integers(3, V, (B, T_max)).astype(np.int32)
-    ids[0, :5] = 0      # a left-padded row beside a full one
-    ids = jnp.asarray(ids)
-    table = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
-    tabs = (table, table) + (
-        (jnp.arange(B, dtype=jnp.int32)[:, None],) if cfg.conv_layers else ())
-    valid = np.asarray(ids != 0)
-    pos = jnp.asarray(np.cumsum(valid, 1) - 1)
+    ids, valid, pos, tabs, pages = _two_rows(cfg)
 
     fill = functools.partial(
         prefill, page_table=tabs, page_size=P, logical_len=T_max)
@@ -611,7 +622,7 @@ def test_the_cached_scan_indexes_the_stacks_and_the_uncached_scans_them(
 
     def pool():
         return init_paged_kv_cache(
-            cfg, (B * nb, B * nb), P, jnp.float32,
+            cfg, pages, P, jnp.float32,
             **({"state_rows": B} if cfg.conv_layers else {}))
 
     @jax.disable_jit()
@@ -668,6 +679,76 @@ def test_the_cached_scan_indexes_the_stacks_and_the_uncached_scans_them(
                                    rtol=2e-4, atol=1e-6)
         moved += bool(np.abs(np.asarray(b)).max() > 0)
     assert moved > 10
+
+
+@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "trinity", "qwen2"])
+def test_the_cached_attention_fences_its_projections_from_the_head_split(
+        model, monkeypatch):
+    """ISSUE 44. Where a layer took its kernels at a traced index of the
+    whole stacks (a pattern model's cached forward) `_attention` puts one
+    `optimization_barrier` over its projections' results, before the head
+    split: one a layer of the period's (and of a dense stack's) attention
+    layers in the paged decode step's lowered text, none in the uncached
+    forward's. The fence moves no value: as jitted programs the prefill's
+    and three decode steps' logits are BITWISE those of the same programs
+    traced without it, and the prefill's are bitwise the uncached forward's
+    where they were before (SmallThinker, Trinity; LFM2's differ in the last
+    bit, 2.4e-7, with the fence and without). A model without a pattern
+    never reaches the fence: its decode step lowers with
+    `optimization_barrier` made to raise."""
+    cfg = (_two_periods(model) if model != "qwen2"
+           else ModelConfig.qwen2_tiny(vocab_size=V))
+    params = init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
+    B, T, P, T_max = 2, 12, 4, 16
+    ids, valid, pos, tabs, pages = _two_rows(cfg)
+    pattern = cfg.attention_pattern is not None
+
+    def served():
+        """(the prefill's and each step's logits, the decode step's lowered
+        text), from programs traced NOW: new functions, so no trace is kept
+        from the other side."""
+        fill = jax.jit(functools.partial(
+            prefill, page_table=tabs, page_size=P, logical_len=T_max),
+            static_argnums=1)
+        step = jax.jit(functools.partial(
+            decode_step, page_table=tabs, page_size=P), static_argnums=1)
+        caches = init_paged_kv_cache(
+            cfg, pages, P, jnp.float32,
+            **({"state_rows": B} if cfg.conv_layers else {}))
+        lg, caches = fill(params, cfg, ids[:, :T], jnp.asarray(valid[:, :T]),
+                          caches)
+        out = [lg]
+        km = jnp.zeros((B, T_max), bool).at[:, :T].set(valid[:, :T])
+        for t in range(T, T + 3):
+            km = km.at[:, t].set(True)
+            args = (params, cfg, ids[:, t], pos[:, t],
+                    jnp.full((B,), t, jnp.int32), km, caches)
+            lg, caches = step(*args)
+            out.append(lg)
+        return [np.asarray(a) for a in out], step.lower(*args).as_text()
+
+    def unfenced(tree):
+        if pattern:
+            return tree
+        raise AssertionError("a model without a pattern reached the fence")
+
+    got, text = served()
+    monkeypatch.setattr(jax.lax, "optimization_barrier", unfenced)
+    want, plain = served()
+    monkeypatch.undo()
+    # in the scan's body (and a one-trip dense stack's), once a layer
+    fences = {"smallthinker": 4, "lfm2": 1, "trinity": 1 + 4, "qwen2": 0}
+    assert text.count("optimization_barrier") == fences[model]
+    assert "optimization_barrier" not in plain
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    scored = jax.jit(lambda p: padded_forward_logits(p, cfg, ids[:, :T], 0))
+    assert "optimization_barrier" not in scored.lower(params).as_text()
+    last = np.asarray(scored(params))[:, -1]
+    if model in ("smallthinker", "trinity"):
+        np.testing.assert_array_equal(got[0], last)
+    else:       # (the last bit, as ever: another order of the same sums)
+        np.testing.assert_allclose(got[0], last, rtol=0, atol=1e-6)
 
 
 # ------------------------------------------------------------- the trainer
