@@ -252,7 +252,7 @@ class ModelConfig:
     def stack_pattern(self, start: int, n: int) -> tuple:
         """One period of the kinds of layers `[start, start + n)`, the
         shortest that tiles them: what the layer scan of that stack goes
-        over (`core/model._run_pattern_layers`)."""
+        over (`core/model._run_layers`)."""
         kinds = self.layer_kinds[start:start + n]
         for p in range(1, n + 1):
             if n % p == 0 and kinds == kinds[:p] * (n // p):

@@ -333,13 +333,16 @@ def _kv_b_kernel(layer_params, lora_layer, lora_scale):
 
 
 def mla_attention(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
-                  cos, sin, mask, kv_cache, cache_index, decode_bounds,
-                  verify_bounds, paged, layer):
+                  cos, sin, view, kv_cache, layer):
     """The attention half of an MLA layer on normed hidden states `h`
     [B, T, D]: returns `(attention output through W_o [B, T, D], the updated
-    stacked cache or None)`. Arguments as `core/model._layer_body`'s;
-    `kv_cache` is the 1-tuple of `init_kv_cache` / `init_paged_kv_cache`."""
+    stacked cache or None)`. `view` is the call's `core/model.KindView` (its
+    mask, bounds, table and slot), `layer` the layer's index into
+    `kv_cache`, the 1-tuple of `init_kv_cache` / the pools of
+    `init_paged_kv_cache`."""
     B, T, _ = h.shape
+    mask, decode_bounds, verify_bounds = view.mask, view.decode, view.verify
+    paged = None if view.table is None else (view.table, view.page_size)
     H, eps = config.num_attention_heads, config.rms_norm_eps
     dn, dr, dv, r = (config.qk_nope_head_dim, config.qk_rope_head_dim,
                      config.v_head_dim, config.kv_lora_rank)
@@ -358,10 +361,10 @@ def mla_attention(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
     new_cache = None
     if kv_cache is not None and paged is not None:
         new_cache = _paged_latent_write(kv_cache, c_kv, k_r, layer, paged[0],
-                                        cache_index, paged[1])
+                                        view.index, paged[1])
     elif kv_cache is not None:
         latent = jnp.concatenate([c_kv, k_r], axis=-1)[:, None]  # one "head"
-        new_cache = _cache_write(kv_cache, (latent,), layer, cache_index, None)
+        new_cache = _cache_write(kv_cache, (latent,), layer, view)
 
     def paged_read(first, last, out_width, scores_and_values):
         return _attend_paged(new_cache, layer, paged[0], paged[1], mask, first,

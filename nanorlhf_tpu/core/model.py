@@ -1,16 +1,39 @@
 """One decoder as pure functions over stacked-layer pytrees: the Qwen2/Llama
-block (GQA + SwiGLU), OLMoE's (QK-norm + a sparse-expert MLP, ops/moe.py) and
+block (GQA + SwiGLU), OLMoE's (QK-norm + a sparse-expert MLP, ops/moe.py),
 A.X-K1's (latent attention, core/mla.py; a leading dense stack, then shared
-plus routed experts), chosen at trace time from the `ModelConfig` and the
-tree (`_layer_body`, `_mlp`, `_layer_stacks`). Every layer of a stack is the
-same kind; only A.X-K1 has two stacks. SmallThinker's stack has an attention
-PATTERN (window layers with rotary embedding beside global layers without,
-`ModelConfig.attention_pattern`): its scan goes over periods, each layer of a
-period of its own static kind, and its cache is two groups of stacks, the
-global layers' and the window layers' (`_run_pattern_layers`, docs/SWA.md).
-LFM2's pattern has a kind that is no attention at all: a gated short
-convolution (`_conv_operator`) whose cache is a STATE of fixed size a row, a
-third group beside the two of pages (docs/STATE.md).
+plus routed experts), SmallThinker's and Trinity's (an attention PATTERN:
+window layers beside global ones, docs/SWA.md, docs/AFMOE.md) and LFM2's (a
+pattern with a kind that is no attention at all: a gated short convolution
+whose cache is a STATE of fixed size a row, docs/STATE.md), chosen at trace
+time from the `ModelConfig` and the tree. Every forward is the same few boxes:
+
+    _embed
+      -> _run_layers            the ONE function that scans layers
+           for each stack       (`_layer_stacks`: `dense_layers`, `layers`)
+             lax.scan over the periods of its pattern (`stack_pattern`;
+             a model without a pattern: one kind, a layer a trip)
+               _layer_body      one layer of one static kind
+                 norm
+                 attention      `_attention`: project (`attn.qkv`)
+                                / write (`_cache_write`)
+                                / dispatch (`attention_form` names the
+                                  read's form, `_attention_read` calls it)
+                                / out (`attn.out`);
+                                or `mla.mla_attention`, `_conv_operator`
+                 norm
+                 MLP            `_mlp`: SwiGLU or the sparse experts
+      -> _logits (`head`)
+
+What differs by KIND OF CACHE (the global layers' pages, the window layers',
+the conv layers' state; one group for a model without a pattern) travels as
+one record a group, `KindView`: the kind's mask, its decode or verify bounds,
+its table with the page size and the step's write plan, the state's rows and
+context. `_kind_views` makes a call's tuple of them from what `prefill` /
+`decode_step` / `decode_verify` / `_hidden_from_inputs` know, and a layer
+takes its kind's (`_kind_group`). The cache itself is groups of stacks in the
+scans' carry. How a layer gets its weights (by index into the whole stacks,
+or as the scan's xs) is `leaves_in_place`'s rule, and `LayerLeaves` says
+which it was.
 
 TPU-first design choices (vs the reference's HF `AutoModelForCausalLM`,
 `/root/reference/GRPO/grpo.py:218-224`):
@@ -45,6 +68,10 @@ transpose of torch `nn.Linear.weight`; the HF loader transposes on load.
 """
 
 from __future__ import annotations
+
+import contextlib
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -122,7 +149,7 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
 
 # an attention layer's own leaves; in a model with conv layers they are
 # stacked over the attention layers of their stack only, as `conv` is over
-# its conv layers (`_run_pattern_layers`)
+# its conv layers (`_run_layers`)
 _ATTENTION_LEAVES = ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm",
                      "k_norm", "g_proj")
 
@@ -354,7 +381,7 @@ def use_paged_decode_kernel(config: ModelConfig) -> bool:
     compiled for a v5e, PR 31). A pattern model's T > 1 paged read (a
     prefill piece, a suffix forward) goes by the same rule: the flash kernel
     over the pages in place (ops/paged_prefill_attention) where this is
-    true, `_attend_paged_blocks` where it is not (`_pattern_attention`)."""
+    true, `_attend_paged_blocks` where it is not (`attention_form`)."""
     if config.kv_lora_rank:
         return False
     return config.kv_cache_quant != "int8" and use_expert_kernel(config)
@@ -402,34 +429,34 @@ def _spmd_call(spmd, fn, args, head_dims):
                          out_specs=out_specs, check_vma=False)(*args)
 
 
+def _flash_attention(q, k, v, mask, spmd=None):
+    """The flash kernel (ops/attention.py) over the tokens at hand, q
+    [B, H, T, hd] against k, v [B, KV, T, hd], for a `mask` [B, 1, T, T]
+    that factors as causal(T, T) & key_valid[B, T]: the kernel rebuilds the
+    causal part and keeps only the key-validity row (`attention_form` names
+    this form where that holds). `spmd` (from `_kernel_spmd`) shard_maps the
+    kernel so a sharded batch stays sharded."""
+    from nanorlhf_tpu.ops.attention import flash_attention
+
+    # key-validity = the mask's last query row (causal there is all-True)
+    key_valid = mask[:, 0, -1, :]
+    if spmd is not None:
+        return _spmd_call(
+            spmd, lambda q, k, v, kv: flash_attention(q, k, v, kv, causal=True),
+            (q, k, v, key_valid), (1, 1, 1, None),
+        )
+    return flash_attention(q, k, v, key_valid, causal=True)
+
+
 def gqa_attention(
     q: jnp.ndarray,       # [B, H, Tq, hd]
     k: jnp.ndarray,       # [B, KV, Tk, hd]
     v: jnp.ndarray,       # [B, KV, Tk, hd]
     mask: jnp.ndarray,    # [B, 1, Tq, Tk] bool, True = attend
-    impl: str = "xla",
-    mask_is_causal_x_keyvalid: bool = False,
-    spmd=None,
 ) -> jnp.ndarray:
-    """`mask_is_causal_x_keyvalid` asserts the mask factors as
-    causal(Tq,Tk) & key_valid[B,Tk] — required for the flash path, which
-    rebuilds the causal part in-kernel and keeps only the key-validity row.
-    Callers with arbitrary masks (prefix-LM etc.) must leave it False and get
-    the general XLA path. `spmd` (from `_kernel_spmd`) shard_maps the flash
-    kernel so a sharded batch stays sharded."""
+    """Masked attention in XLA, any mask, without materializing repeated
+    KV: the plain form of every read."""
     B, H, Tq, hd = q.shape
-    Tk = k.shape[2]
-    if use_flash(impl, Tq) and mask_is_causal_x_keyvalid and Tq == Tk and Tq > 1:
-        # key-validity = the mask's last query row (causal there is all-True)
-        from nanorlhf_tpu.ops.attention import flash_attention
-
-        key_valid = mask[:, 0, -1, :]
-        if spmd is not None:
-            return _spmd_call(
-                spmd, lambda q, k, v, kv: flash_attention(q, k, v, kv, causal=True),
-                (q, k, v, key_valid), (1, 1, 1, None),
-            )
-        return flash_attention(q, k, v, key_valid, causal=True)
     KV = k.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, Tq, hd)
@@ -660,68 +687,46 @@ def _paged_scale_update(pool, new, layer, table, cache_index, page_size):
         new.transpose(0, 3, 1, 2), mode="drop")
 
 
-def _cache_write(stacks, news, layer, cache_index, paged):
+def _cache_write(stacks, news, layer, view):
     """Write one layer's new tokens into the stacked cache arrays, each at
-    `(layer, ...)`: `stacks`/`news` are (k, v) exact, or (k_q, k_s, v_q, v_s)
-    int8, whose odd members are scale arrays (sequence on the last axis).
-    `paged=(block_table, page_size)` routes the write through the table;
-    `paged=(block_table, page_size, PagedWritePlan)` is a decode step's on a
-    TPU (`_with_write_plan`): its one slot a row goes through
-    ops/paged_cache_write, K and V in one call. Returns the updated stacks.
-    Under the scope `attn.write`."""
+    `(layer, ...)`, from slot `view.index` on: `stacks`/`news` are (k, v)
+    exact, or (k_q, k_s, v_q, v_s) int8, whose odd members are scale arrays
+    (sequence on the last axis). A `view` with a table routes the write
+    through it; one with a `write_plan` is a decode step's on a TPU
+    (`_kind_views`): its one slot a row goes through ops/paged_cache_write,
+    K and V in one call. Returns the updated stacks. Under the scope
+    `attn.write`."""
     out = []
     with jax.named_scope("attn.write"):
-        if paged is not None and len(paged) == 3:
+        if view.write_plan is not None:
             from nanorlhf_tpu.ops.paged_cache_write import paged_row_write
 
             return tuple(paged_row_write(
-                *stacks, news[0][:, :, 0], news[1][:, :, 0], layer, paged[2]))
+                *stacks, news[0][:, :, 0], news[1][:, :, 0], layer,
+                view.write_plan))
         for i, (stack, new) in enumerate(zip(stacks, news)):
-            is_scale = len(stacks) == 4 and i % 2 == 1
-            if paged is not None:
+            is_scale = view.cache == "int8" and i % 2 == 1
+            if view.table is not None:
                 update = (_paged_scale_update if is_scale
                           else _paged_cache_update)
-                out.append(update(stack, new, layer, paged[0], cache_index,
-                                  paged[1]))
+                out.append(update(stack, new, layer, view.table, view.index,
+                                  view.page_size))
             else:
                 update = _scale_update if is_scale else _cache_update
-                out.append(update(stack, new, layer, cache_index))
+                out.append(update(stack, new, layer, view.index))
     return tuple(out)
 
 
 def _paged_row_kernel_takes(stacks, page_size: int) -> bool:
     """Whether ops/paged_cache_write takes a decode step's write into these
     stacks: a (k, v) pool of whole 128-lane rows and whole tiles a page (not
-    the int8 pool's four arrays, not a page of 4 or 8 slots)."""
+    the int8 pool, not a page of 4 or 8 slots)."""
     from nanorlhf_tpu.ops.paged_cache_write import sublanes
 
-    return (len(stacks) == 2 and stacks[0].dtype == stacks[1].dtype
+    return (stacks[0].dtype == stacks[1].dtype
             and stacks[0].dtype != jnp.int8
             and stacks[0].shape[-1] % 128 == 0
             and page_size % sublanes(stacks[0].dtype) == 0)
-
-
-def _with_write_plan(config: ModelConfig, paged, kv_caches, cache_index, live):
-    """A decode step's `paged` with the step's `PagedWritePlan` as the third
-    member of each (table, page_size) whose pool the live-row kernel takes,
-    under `use_paged_decode_kernel`'s rule: made once here, for every layer
-    (`_cache_write`). Elsewhere `paged` as it was: the row scatter."""
-    if paged is None or not use_paged_decode_kernel(config):
-        return paged
-    from nanorlhf_tpu.ops.paged_cache_write import paged_write_plan
-
-    def one(pair, group):
-        table, page_size = pair
-        if not _paged_row_kernel_takes(group, page_size):
-            return pair
-        return table, page_size, paged_write_plan(
-            table, cache_index, page_size=page_size,
-            num_pages=group[0].shape[1], live=live)
-
-    if config.attention_pattern is None:
-        return one(paged, kv_caches)
-    return tuple(one(p, g) for p, g in zip(paged[:2], kv_caches[:2])) + tuple(
-        paged[2:])
 
 
 def paged_write_forms(config: ModelConfig, caches, page_size: int,
@@ -729,7 +734,7 @@ def paged_write_forms(config: ModelConfig, caches, page_size: int,
     """`(by page, live rows)`, 0 or 1 each: whether a forward of
     `longest_write` tokens (a prefill piece, a whole prompt) writes the paged
     cache `caches` by page, and whether a decode step's write is the
-    live-row kernel: what `_paged_cache_update` and `_cache_write` decide
+    live-row kernel: what `_paged_cache_update` and `_kind_views` decide
     from the same shapes when the programs are traced
     (`DecodeSession.kv_write_by_page`, `kv_write_live_rows`)."""
     group = caches[0] if config.attention_pattern is not None else caches
@@ -887,70 +892,71 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
     return _swiglu(h, layer_params, lora_layer, lora_scale), None
 
 
-def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
-                cache_index, lora_layer=None, lora_scale=1.0, attn_fn=None,
-                decode_bounds=None, verify_bounds=None, paged=None, layer=0,
-                expert_stack=None, stack_start=0, live=None, kind=None,
-                expert_layer=None, conv_ctx=None, kernels_in_place=False):
-    """One decoder layer. If kv_cache is not None, operate incrementally.
+class KindView(NamedTuple):
+    """What ONE cache group of ONE call hands the layers of its kind
+    (`_kind_views` makes a call's tuple of them, one a group): everything
+    that differs by kind of cache, and the call's slot and listeners beside
+    it. A model without a pattern has one group."""
+    mask: jnp.ndarray           # [B, 1, Tq, Tk] bool, True = attend: the
+                                # kind's own (a window layer's holds its window)
+    cache: str | None = None    # what the group's cache is: "exact" (k, v),
+                                # "int8" (k_q, k_s, v_q, v_s), "latent"
+                                # (core/mla.py), "state" (`_conv_operator`);
+                                # None: the call has no cache
+    index: object = 0           # the cache slot of the call's first token:
+                                # scalar, or per-row [B]
+    decode: object = None       # a decode step's `(start, filled)` [B] each,
+                                # or its `PagedDecodePlan` (the in-place read)
+    verify: tuple | None = None  # `(first, fill)` [B] each: T candidate or
+                                # chunk tokens a row from its slot `fill` on
+    table: object = None        # block table [B, nb] int32 (paged), or the
+                                # state group's rows [B, 1]
+    page_size: int = 0
+    write_plan: object = None   # a decode step's `PagedWritePlan` on a TPU
+    conv_ctx: tuple | None = None   # the state group's `(valid, fresh)`
+    live: object = None         # [B] bool: the rows someone listens to
 
-    `kind=(window, rotary)` marks a layer of a pattern model
-    (`_run_pattern_layers`): `mask`, `kv_cache`, `decode_bounds`,
-    `verify_bounds` and `paged` are then its KIND's, `layer` its index into
-    its kind's cache stacks and `expert_layer` its place in its stack.
-    `kind="conv"`: the operator is the gated short convolution
-    (`_conv_operator`), `kv_cache` the state group, `paged` the rows' place
-    in it and `conv_ctx` what the call knows of its tokens.
-    `kernels_in_place`: `layer_params`' leaves are size-one slices of the
-    whole stacks at a traced index (`_run_pattern_layers`' cached forward),
-    which `_attention` keeps its head split away from.
 
-    Returns (x_out, new_kv_cache_or_None, mlp_aux_or_None).
-    kv_cache: the STACKED cache of every layer (init_kv_cache /
-    init_paged_kv_cache: (k, v) each [L, B, KV, T_max, hd], or the four int8
-    arrays) or None; `layer` is this layer's index into it. The layer writes
-    its new tokens into the stack at `(layer, ...)` — an update the size of
-    what is new — and reads its slab back out of the UPDATED stack, so the
-    stack can ride the layer scan's carry and the decode loop's carry as one
-    buffer (`_run_layers`); the returned cache is the whole stack again.
+class LayerLeaves(NamedTuple):
+    """One layer's weights as `_run_layers` hands them to `_layer_body`."""
+    tree: dict                  # the layer's own leaves
+    lora: dict | None           # its adapters' (core/lora.py)
+    experts: dict | None        # the expert kernels of EVERY layer of its
+                                # stack, addressed in place (`_expert_xs`)
+    at: object                  # its place in its stack: its experts' index
+    in_place: bool = False      # provenance: `tree`'s leaves are size-one
+                                # slices of the whole stacks at a traced
+                                # index (`leaves_in_place`), not a scan's xs
+
+
+def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
+                view: KindView, cache, cos, sin, lora_scale=1.0, attn_fn=None):
+    """One decoder layer: norm, the operator of its `kind` with its residual
+    (attention: `_attention`; MLA: core/mla.py; `"conv"`: `_conv_operator`),
+    norm, MLP. If `cache` is not None, operate incrementally.
+
+    `kind=(window, rotary)` or `"conv"` is the layer's static kind
+    (`config.stack_pattern`); `view` its kind's record of this call and
+    `cache` its kind's group of STACKED cache arrays (init_kv_cache /
+    init_paged_kv_cache: (k, v) each [L, B, KV, T_max, hd], the four int8
+    arrays, MLA's latent, the conv layers' state) or None; `layer` is this
+    layer's index into them. The layer writes its new tokens into the stack
+    at `(layer, ...)`, an update the size of what is new, and reads its slab
+    back out of the UPDATED stack, so the stack can ride the layer scan's
+    carry and the decode loop's carry as one buffer (`_run_layers`); the
+    returned cache is the whole group again.
     `attn_fn(q, k, v)`, when given, replaces the attention contraction (used
-    by the sequence-parallel path to route through ring attention) — every
+    by the sequence-parallel path to route through ring attention): every
     other op stays this single implementation.
-    `verify_bounds=(start, fill)` ([B] each) marks the speculative-verify
-    path: T = k+1 candidate tokens per row, cache_index is per-row, and
-    attention runs the k-query prefix-bounded contraction over the cache
-    (general masked XLA attention off-TPU / for the int8 cache, which
-    dequantizes — correct, no bandwidth win; the single-token q8 kernel is
-    unaffected).
-    `paged=(block_table [B, nb] int32, page_size)` switches the cache to the
-    paged layout (init_paged_kv_cache): writes scatter through the table
-    with `mode="drop"` (sentinel/over-budget slots discard). The single-token
-    decode read is the in-place kernel on a TPU (`use_paged_decode_kernel`:
-    the whole stacks are its operands, `layer` a scalar, `decode_bounds` the
-    step's `PagedDecodePlan`), and elsewhere a gathered row-contiguous view
-    sliced to the mask width (`_paged_view`), as are the T > 1 reads under
-    the kernel threshold — the view path reuses the exact same masked
-    gqa_attention math as the contiguous cache, which is what makes paged
-    generation bit-identical to contiguous on the CPU mesh (test-pinned).
-    The int8 and verify paged kernels take the layer's slab and skip the
-    shard_map wrap (`_spmd_call` shards arg dim 0, which for pools is pages,
-    not batch); GSPMD partitions them instead.
-    `layer` indexes the cache, over every layer of the model; the layer's
-    place in its own stack (`_layer_stacks`), which is where its experts
-    lie in `expert_stack`, is `layer - stack_start`.
-    An MLA model (`config.kv_lora_rank`) takes core/mla.py's attention: the
-    cache is one latent array and the forms are its own.
-    """
-    # (a stack that starts the model keeps `layer` as it is: the same program)
-    if expert_layer is None:
-        expert_layer = layer - stack_start if stack_start else layer
+
+    Returns (x_out, new_cache_or_None, mlp_aux_or_None)."""
+    layer_params = leaves.tree
     with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
     with jax.named_scope("attn"):
         if kind == "conv":
             x, new_cache = _conv_operator(
-                config, x, h, layer_params["conv"], kv_cache, layer, paged,
-                conv_ctx)
+                config, x, h, layer_params["conv"], cache, layer, view)
         elif config.kv_lora_rank:
             if attn_fn is not None:
                 raise NotImplementedError(
@@ -959,23 +965,21 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
             from nanorlhf_tpu.core.mla import mla_attention
 
             out, new_cache = mla_attention(
-                config, h, layer_params, lora_layer, lora_scale, cos, sin,
-                mask, kv_cache, cache_index, decode_bounds, verify_bounds,
-                paged, layer)
+                config, h, layer_params, leaves.lora, lora_scale, cos, sin,
+                view, cache, layer)
             x = x + out
         else:
             x, new_cache = _attention(
-                config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
-                mask, kv_cache, cache_index, attn_fn, decode_bounds,
-                verify_bounds, paged, layer, kind, kernels_in_place)
+                config, x, h, leaves, layer, kind, view, cache, cos, sin,
+                lora_scale, attn_fn)
 
     router_h = h if config.router_input == "pre_attention" else None
     with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["post_attention_layernorm"],
                      config.rms_norm_eps)
     with jax.named_scope("mlp"):
-        ff, aux = _mlp(config, h, layer_params, lora_layer, lora_scale,
-                       expert_stack, expert_layer, live, router_h)
+        ff, aux = _mlp(config, h, layer_params, leaves.lora, lora_scale,
+                       leaves.experts, leaves.at, view.live, router_h)
         if config.branch_norms:     # afmoe: the branch is normed again
             with jax.named_scope("norm"):
                 ff = rms_norm(ff, layer_params["mlp_branch_norm"],
@@ -984,34 +988,35 @@ def _layer_body(config: ModelConfig, x, layer_params, cos, sin, mask, kv_cache,
     return x, new_cache, aux
 
 
-def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
-               mask, kv_cache, cache_index, attn_fn, decode_bounds,
-               verify_bounds, paged, layer, kind, kernels_in_place=False):
+def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
+               lora_scale, attn_fn):
     """A layer's attention on the normed state `h`, with its residual:
-    `(x + attention, the updated cache stacks | None)`. In four parts, each
-    under its scope (utils/profiling.py `DEVICE_SCOPES`): the projections
-    and rotary (`attn.qkv`; the gate's projection too, where the model has
-    one), the new tokens' write into the cache (`attn.write`,
-    `_cache_write`), the contraction (`attn.read`; a pattern model's is
-    `attn.global` / `attn.window`, its write inside) and the output
-    projection (`attn.out`); afmoe's gate, `out * sigmoid(g)`, is a fifth
-    between the last two (`attn.gate`), and its branch norm closes
-    `attn.out`.
+    `(x + attention, the updated cache stacks | None)`: project, write,
+    dispatch, out, each under its scope (utils/profiling.py `DEVICE_SCOPES`):
+    the projections and rotary (`attn.qkv`; the gate's projection too, where
+    the model has one), the new tokens' write into the cache (`attn.write`,
+    `_cache_write`), the contraction (`_attention_read`, under `attn.read`;
+    a pattern model's under `attn.global` / `attn.window`, which hold its
+    write too) and the output projection (`attn.out`); afmoe's gate, `out *
+    sigmoid(g)`, is a fifth between the last two (`attn.gate`), and its
+    branch norm closes `attn.out`.
 
-    `kernels_in_place`: the layer took its kernels at a traced index of the
-    whole stacks (`_run_pattern_layers`' cached forward). The projections'
-    results are then fenced from the head split: the chip's compiler carries
-    a reshape `[B, T, H, hd]` and its transpose back INTO the q, k and v
-    kernels' layout (it wants them contraction-minor, `[H, hd, D]`), so it
-    relaid the whole stacks once a call and had to make each layer's relaid
-    slice before it could prefetch it, made, dropped and made again
-    (`.remat`): ~0.5 ms of a 5.3 ms decode step at SmallThinker's widths
-    (PERF.md PR 44; docs/SWA.md). Behind the fence a projection is a plain
-    matmul over the stack where it lies, as `o_proj`'s always was. A scanned
-    slice of xs is a layer's own buffer already and is not fenced."""
+    Where the layer took its kernels at a traced index of the whole stacks
+    (`leaves.in_place`) the projections' results are fenced from the head
+    split: the chip's compiler carries a reshape `[B, T, H, hd]` and its
+    transpose back INTO the q, k and v kernels' layout (it wants them
+    contraction-minor, `[H, hd, D]`), so it relaid the whole stacks once a
+    call and had to make each layer's relaid slice before it could prefetch
+    it, made, dropped and made again (`.remat`): ~0.5 ms of a 5.3 ms decode
+    step at SmallThinker's widths (PERF.md PR 44; docs/SWA.md). Behind the
+    fence a projection is a plain matmul over the stack where it lies, as
+    `o_proj`'s always was. A scanned slice of xs is a layer's own buffer
+    already and is not fenced."""
     hd = config.actual_head_dim
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
+    layer_params, lora_layer = leaves.tree, leaves.lora
+    window, rotary = kind
     spmd = _kernel_spmd(config, H, KV)
     with jax.named_scope("attn.qkv"):
         q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
@@ -1019,7 +1024,7 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
         gate = (_proj(h, layer_params, lora_layer, "g_proj", lora_scale)
                 if config.attention_gate else None)
-        if kernels_in_place:    # a value is what it was: only where it lies
+        if leaves.in_place:     # a value is what it was: only where it lies
             q, k, v, gate = jax.lax.optimization_barrier((q, k, v, gate))
         if config.qk_norm:
             # OLMoE: over the whole projection width, before the head split
@@ -1032,34 +1037,43 @@ def _attention(config, x, h, layer_params, lora_layer, lora_scale, cos, sin,
             q = rms_norm(q, layer_params["q_norm"], config.rms_norm_eps)
             k = rms_norm(k, layer_params["k_norm"], config.rms_norm_eps)
 
-        if kind is None or kind[1]:     # a NoPE layer carries no position
+        if rotary:      # a NoPE layer carries no position
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         if config.kv_head_pack > 1:
             q, k, v = _pack_heads(q, k, v, config.kv_head_pack)
 
-    if kind is not None:
-        if attn_fn is not None:
-            raise NotImplementedError(
-                "window layers have no sequence-parallel form: ring "
-                "attention passes whole K and V blocks round (docs/SWA.md)")
-        window = config.sliding_window if kind[0] else 0
-        with jax.named_scope("attn.window" if window else "attn.global"):
-            out, new_cache = _pattern_attention(
-                config, q, k, v, mask, kv_cache, cache_index, decode_bounds,
-                verify_bounds, paged, layer, window, spmd)
-    else:
+    # a pattern model's scope names the layer's kind and holds its write
+    # (harness/attn_trace.py reads a decode read by it); a model of one
+    # kind writes beside `attn.read`
+    patterned = config.attention_pattern is not None
+    if patterned and attn_fn is not None:
+        raise NotImplementedError(
+            "window layers have no sequence-parallel form: ring "
+            "attention passes whole K and V blocks round (docs/SWA.md)")
+    window = config.sliding_window if window else 0
+    with contextlib.ExitStack() as scopes:
+        if patterned:
+            scopes.enter_context(jax.named_scope(
+                "attn.window" if window else "attn.global"))
         new_cache = None
-        if kv_cache is not None and attn_fn is None:
+        if cache is not None and attn_fn is None:
             news = (k, v)
-            if len(kv_cache) == 4:      # int8 KV cache: see init_kv_cache
+            if view.cache == "int8":    # see init_kv_cache
                 with jax.named_scope("attn.write"):
                     news = _quantize_kv(k) + _quantize_kv(v)
-            new_cache = _cache_write(kv_cache, news, layer, cache_index, paged)
-        with jax.named_scope("attn.read"):
-            out = _attention_read(config, q, k, v, mask, new_cache,
-                                  decode_bounds, verify_bounds, paged, layer,
-                                  spmd, attn_fn)
+            new_cache = _cache_write(cache, news, layer, view)
+        if not patterned:
+            scopes.enter_context(jax.named_scope("attn.read"))
+        if attn_fn is not None:
+            out = attn_fn(q, k, v)
+        elif view.cache == "int8":
+            out = _int8_attention_read(config, q, k, v, view, new_cache,
+                                       layer, spmd)
+        else:
+            out = _attention_read(config, q, k, v, view, new_cache, layer,
+                                  window, spmd)
+
     def merged(out):    # [B, H, T, hd] as the cache's heads -> [B, T, H hd]
         if config.kv_head_pack > 1:
             out = _unpack_heads(out, KV, config.kv_head_pack)
@@ -1113,7 +1127,7 @@ def _unpack_heads(out, KV: int, pack: int):
         out, own[None, :, None, None, None], axis=3)[:, :, :, 0, :]
 
 
-def _conv_operator(config, x, h, conv, state_group, layer, state_rows, ctx):
+def _conv_operator(config, x, h, conv, state_group, layer, view):
     """A conv layer's operator on the normed state `h`, with its residual
     (docs/STATE.md): `[b | c | u] = h W_in`, `g = b * u`, `y_t = c_t *
     sum_j w[j] * g_(t-K+1+j)`, `x + y W_out`. `(x, the updated state group |
@@ -1138,6 +1152,7 @@ def _conv_operator(config, x, h, conv, state_group, layer, state_rows, ctx):
     Float32 products and sums over the taps, as the attention's softmax."""
     B, T, D = x.shape
     K = config.conv_L_cache
+    state_rows, ctx = view.table, view.conv_ctx
     valid, fresh = ctx if ctx is not None else (None, None)
     with jax.named_scope("attn.conv"):
         with jax.named_scope("attn.conv.in"):
@@ -1179,163 +1194,225 @@ def _conv_operator(config, x, h, conv, state_group, layer, state_rows, ctx):
             return x + y @ conv["out_proj"]["kernel"], new_group
 
 
-def _attention_read(config, q, k, v, mask, new_cache, decode_bounds,
-                    verify_bounds, paged, layer, spmd, attn_fn):
-    """The attention contraction of a layer of one kind (every model without
-    an attention pattern), `out [B, H, T, hd]`: over `new_cache`, the cache
-    stacks that already hold this call's tokens (`_layer_body` wrote them),
-    or over `k` and `v` alone where there is no cache or a prefill needs no
-    more than the tokens at hand."""
+def _int8_attention_read(config, q, k, v, view, new_cache, layer, spmd):
+    """The attention contraction over the int8 cache `(k_q, k_scales, v_q,
+    v_scales)` (init_kv_cache), which already holds this call's tokens: the
+    q8 decode kernels where they apply, the tokens at hand for a prefill,
+    else the dequantized view. Only a model of one kind has this cache
+    (`_pattern_caches`, `_latent_cache_shape`)."""
     T = q.shape[2]
-    if attn_fn is not None:
-        out = attn_fn(q, k, v)
-    elif new_cache is not None and len(new_cache) == 4:
-        # int8 KV cache: (k_q, k_scales, v_q, v_scales) — see init_kv_cache
-        kq_c, ks_c, vq_c, vs_c = (_layer_slab(c, layer) for c in new_cache)
+    mask, table = view.mask, view.table
+    kq_c, ks_c, vq_c, vs_c = (_layer_slab(c, layer) for c in new_cache)
 
-        def _q8_views(width):
-            """Row-contiguous dequantized cache views (paged gathers through
-            the table; contiguous passes the slabs through)."""
-            if paged is not None:
-                kq_p, ks_p, vq_p, vs_p = new_cache
-                return (
-                    _dequantize_kv(
-                        _paged_view(kq_p, layer, paged[0], width),
-                        _paged_scale_view(ks_p, layer, paged[0], width),
-                        q.dtype),
-                    _dequantize_kv(
-                        _paged_view(vq_p, layer, paged[0], width),
-                        _paged_scale_view(vs_p, layer, paged[0], width),
-                        q.dtype),
-                )
-            return (_dequantize_kv(kq_c, ks_c, q.dtype),
-                    _dequantize_kv(vq_c, vs_c, q.dtype))
+    def _q8_views(width):
+        """Row-contiguous dequantized cache views (paged gathers through
+        the table; contiguous passes the slabs through)."""
+        if table is not None:
+            kq_p, ks_p, vq_p, vs_p = new_cache
+            return (
+                _dequantize_kv(
+                    _paged_view(kq_p, layer, table, width),
+                    _paged_scale_view(ks_p, layer, table, width),
+                    q.dtype),
+                _dequantize_kv(
+                    _paged_view(vq_p, layer, table, width),
+                    _paged_scale_view(vs_p, layer, table, width),
+                    q.dtype),
+            )
+        return (_dequantize_kv(kq_c, ks_c, q.dtype),
+                _dequantize_kv(vq_c, vs_c, q.dtype))
 
-        if verify_bounds is not None:
-            # speculative verify over the int8 cache: dequantize and run the
-            # general masked path — correct everywhere, no bandwidth win
-            # (the q8 k-query kernel is future work; single-token decode
-            # keeps the q8 kernel either way)
-            kd, vd = _q8_views(mask.shape[-1])
-            out = gqa_attention(q, kd, vd, mask)
-        elif T > 1 and use_flash(config.attention_impl, T):
-            out = gqa_attention(q, k, v, mask[..., :T], impl="pallas",
-                                mask_is_causal_x_keyvalid=True, spmd=spmd)
-        elif T > 1:
-            out = gqa_attention(q, k, v, mask[..., :T])
-        elif (decode_bounds is not None
-              and use_q8_decode_kernel(config.attention_impl)):
-            # decode reads the cache: the q8 kernel consumes int8 + scales
-            # natively — the whole point of the quantized cache.
-            # attention_impl="xla" stays a working escape hatch (dequant
-            # fallback below: correct, no bandwidth win)
-            start, filled = decode_bounds
-            if paged is not None:
-                from nanorlhf_tpu.ops.decode_attention import (
-                    paged_decode_attention_q8,
-                )
-
-                out = paged_decode_attention_q8(
-                    q[:, :, 0, :], kq_c, ks_c, vq_c, vs_c, paged[0],
-                    start, filled,
-                )[:, :, None, :]
-            else:
-                from nanorlhf_tpu.ops.decode_attention import (
-                    decode_attention_q8,
-                )
-
-                q8_args = (q[:, :, 0, :], kq_c, ks_c, vq_c, vs_c, start,
-                           filled)
-                if spmd is not None:
-                    out = _spmd_call(spmd, decode_attention_q8, q8_args,
-                                     (1, 1, 1, 1, 1, None, None))[:, :, None, :]
-                else:
-                    out = decode_attention_q8(*q8_args)[:, :, None, :]
-        else:
-            # correctness fallback (CPU tests): dequantize and reuse the
-            # exact path — no bandwidth win off-TPU, none needed
-            kd, vd = _q8_views(mask.shape[-1])
-            out = gqa_attention(q, kd, vd, mask)
-    elif new_cache is not None:
-        k_cache, v_cache = (_layer_slab(c, layer) for c in new_cache)
-        # logical cache length (for the kernel-eligibility threshold and the
-        # gathered view): on the paged layout the mask width, not the pool
-        # shape
-        cache_len = mask.shape[-1] if paged is not None else k_cache.shape[2]
-
-        def _kv_views(width):
-            if paged is not None:
-                return (_paged_view(new_cache[0], layer, paged[0], width),
-                        _paged_view(new_cache[1], layer, paged[0], width))
-            if width < cache_len:     # `decode_step(extent=)`
-                return tuple(_layer_slab(c, layer, width) for c in new_cache)
-            return k_cache, v_cache
-
-        if verify_bounds is not None:
-            # speculative verify: T = k+1 candidate queries read the cache
-            # (their KV just landed at per-row slots [fill, fill+T)). The
-            # k-query prefix-bounded kernel on TPU; the general masked XLA
-            # contraction elsewhere (mask carries prefix + causal-within-
-            # candidates, built by decode_verify).
-            if use_decode_kernel(config.attention_impl, cache_len):
-                start, vfill = verify_bounds
-                if paged is not None:
-                    from nanorlhf_tpu.ops.decode_attention import (
-                        paged_decode_verify_attention,
-                    )
-
-                    out = paged_decode_verify_attention(
-                        q, k_cache, v_cache, paged[0], start, vfill)
-                else:
-                    from nanorlhf_tpu.ops.decode_attention import (
-                        decode_verify_attention,
-                    )
-
-                    ver_args = (q, k_cache, v_cache, start, vfill)
-                    if spmd is not None:
-                        out = _spmd_call(spmd, decode_verify_attention,
-                                         ver_args, (1, 1, 1, None, None))
-                    else:
-                        out = decode_verify_attention(*ver_args)
-            else:
-                kd, vd = _kv_views(mask.shape[-1])
-                out = gqa_attention(q, kd, vd, mask)
-        elif T > 1 and use_flash(config.attention_impl, T):
-            # prefill: cache slots beyond T are masked anyway, so attend over
-            # the local-length K/V through the flash kernel instead of the
-            # T_max-padded cache
-            out = gqa_attention(q, k, v, mask[..., :T], impl="pallas",
-                                mask_is_causal_x_keyvalid=True, spmd=spmd)
-        elif (T == 1 and decode_bounds is not None and paged is not None
-              and use_paged_decode_kernel(config)):
-            # paged decode on a TPU: the rows' live pages are read from the
-            # stacks in place, the layer a scalar; `decode_bounds` is the
-            # step's work list (`decode_step` made it under the same rule)
+    if view.verify is not None:
+        # speculative verify over the int8 cache: dequantize and run the
+        # general masked path: correct everywhere, no bandwidth win (the q8
+        # k-query kernel is future work; single-token decode keeps the q8
+        # kernel either way)
+        return gqa_attention(q, *_q8_views(mask.shape[-1]), mask)
+    if attention_form(config, T, cached=False) == "flash":
+        # a prefill needs the tokens at hand only, by the uncached rule
+        return _flash_attention(q, k, v, mask[..., :T], spmd)
+    if T > 1:
+        return gqa_attention(q, k, v, mask[..., :T])
+    if (view.decode is not None
+            and use_q8_decode_kernel(config.attention_impl)):
+        # decode reads the cache: the q8 kernel consumes int8 + scales
+        # natively, the whole point of the quantized cache.
+        # attention_impl="xla" stays a working escape hatch (dequant
+        # fallback below: correct, no bandwidth win)
+        start, filled = view.decode
+        if table is not None:
             from nanorlhf_tpu.ops.decode_attention import (
-                paged_decode_attention,
+                paged_decode_attention_q8,
             )
 
-            out = paged_decode_attention(
-                q[:, :, 0, :], *new_cache, layer, decode_bounds)[:, :, None, :]
-        elif (T == 1 and decode_bounds is not None and paged is None
-              and use_decode_kernel(config.attention_impl, cache_len)):
-            # decode: prefix-bounded Pallas kernel reads only the filled
-            # cache range instead of the masked T_max square
-            from nanorlhf_tpu.ops.decode_attention import decode_attention
+            return paged_decode_attention_q8(
+                q[:, :, 0, :], kq_c, ks_c, vq_c, vs_c, table, start, filled,
+            )[:, :, None, :]
+        from nanorlhf_tpu.ops.decode_attention import decode_attention_q8
 
-            dec_args = (q[:, :, 0, :], k_cache, v_cache) + tuple(decode_bounds)
-            if spmd is not None:
-                out = _spmd_call(spmd, decode_attention, dec_args,
-                                 (1, 1, 1, None, None))[:, :, None, :]
-            else:
-                out = decode_attention(*dec_args)[:, :, None, :]
-        else:
-            kd, vd = _kv_views(mask.shape[-1])
-            out = gqa_attention(q, kd, vd, mask)
-    else:
-        out = gqa_attention(q, k, v, mask, impl=config.attention_impl,
-                            mask_is_causal_x_keyvalid=True, spmd=spmd)
-    return out
+        q8_args = (q[:, :, 0, :], kq_c, ks_c, vq_c, vs_c, start, filled)
+        if spmd is not None:
+            return _spmd_call(spmd, decode_attention_q8, q8_args,
+                              (1, 1, 1, 1, 1, None, None))[:, :, None, :]
+        return decode_attention_q8(*q8_args)[:, :, None, :]
+    # correctness fallback (CPU tests): dequantize and reuse the exact path:
+    # no bandwidth win off-TPU, none needed
+    return gqa_attention(q, *_q8_views(mask.shape[-1]), mask)
+
+
+def attention_form(config: ModelConfig, T: int, *, cached: bool,
+                   paged: bool = False, decode: bool = False,
+                   verify: bool = False, window: int = 0, mesh: bool = False,
+                   cache_len: int = 0) -> str:
+    """The form a layer's bf16 attention read takes (`_attention_read` calls
+    it): a pure function of what the call can see. `T` queries a row;
+    `cached`: there is a cache, `paged` or contiguous, of `cache_len` logical
+    slots; which bounds came (`decode`: a decode step's, `verify`: a
+    candidate or chunk forward's); `window`: the layer's window (0: none);
+    `mesh`: a multi-device hint applies (`_kernel_spmd`); and of the
+    configuration `attention_impl`, the backend behind it (`use_flash`,
+    `use_decode_kernel`, `use_paged_decode_kernel`) and whether the model
+    has a layer pattern. The rules, in order. Four of them tell a pattern
+    model from one of a single kind: nobody decided those, two functions
+    drifted (ROADMAP, model layer debts (a) and (b)), and each stays until a
+    `perf_opt` issue measures it."""
+    patterned = config.attention_pattern is not None
+    impl = config.attention_impl
+    # the flash kernel rebuilds causal x key-valid from the tokens at hand
+    # and has no block bound for a window: inside its window a window layer
+    # is a causal one, past it the kernel does not apply
+    flash = (T > 1 and not (window and T > window) and use_flash(impl, T))
+    if not cached:
+        if flash:
+            return "flash"
+        # XLA over the tokens at hand. (1) a pattern model's goes in blocks
+        # of queries once the scores pass `_PATTERN_SCORE_BYTES` (a served
+        # row's thousands of tokens); a model of one kind scores whole
+        return "query_blocks" if patterned else "local"
+    if verify:
+        # T candidate or chunk tokens against the cache they just joined
+        if patterned:
+            # (2) the T > 1 paged read over the pages in place: the flash
+            # kernel on a TPU, XLA's walk of the key blocks elsewhere; a
+            # contiguous cache is read whole under the mask
+            if not paged:
+                return "view"
+            return ("paged_flash" if use_paged_decode_kernel(config)
+                    else "paged_walk")
+        # (2) a model of one kind: the k-query prefix-bounded kernel over
+        # the layer's slab from the contiguous kernel's threshold on
+        if use_decode_kernel(impl, cache_len):
+            return "paged_verify_slab" if paged else "verify_slab"
+        return "view"
+    if T > 1:
+        # a prefill from slot 0: the tokens at hand are all there is
+        if flash:
+            return "flash"
+        # (3) XLA: a pattern model over the tokens at hand, a model of one
+        # kind over the cache it just wrote, under the mask
+        return "local" if patterned else "view"
+    if decode and paged and use_paged_decode_kernel(config):
+        # the rows' live pages read from the stacks in place
+        return "paged_decode"
+    if (decode and not paged and use_decode_kernel(impl, cache_len)
+            and not (patterned and mesh)):
+        # the prefix-bounded kernel reads the filled range, not the masked
+        # T_max square. (4) under a mesh a model of one kind wraps it in
+        # shard_map; a pattern model's refuses the mesh
+        return "decode"
+    # the plain form, and every kernel's oracle: the cache as a
+    # row-contiguous view (a paged one gathered), masked
+    return "view"
+
+
+def _attention_read(config, q, k, v, view, new_cache, layer, window, spmd):
+    """The bf16 attention contraction of one layer, `out [B, H, T, hd]`, in
+    the form `attention_form` names: over `new_cache`, the kind's cache
+    stacks that already hold this call's tokens (`_attention` wrote them),
+    or over `k` and `v` alone where there is no cache or a prefill needs no
+    more than the tokens at hand. The reads that go by bounds (the decode
+    kernels, the paged T > 1 reads) get the kind's own from `view`; its
+    mask carries the window per query on every path."""
+    T = q.shape[2]
+    mask, table = view.mask, view.table
+    cached, paged = new_cache is not None, table is not None
+    # the logical cache length (the kernels' threshold, the gathered view):
+    # on the paged layout the mask's width, not the pool's shape
+    cache_len = (mask.shape[-1] if paged or not cached
+                 else new_cache[0].shape[3])
+    form = attention_form(
+        config, T, cached=cached, paged=paged, decode=view.decode is not None,
+        verify=view.verify is not None, window=window, mesh=spmd is not None,
+        cache_len=cache_len)
+    slabs = functools.cache(
+        lambda: tuple(_layer_slab(c, layer) for c in new_cache))
+    if cached and config.attention_pattern is None:
+        # (a model of one kind has always staged its layer's slabs here,
+        # read or not: dead in a decode loop's text, which is the compile
+        # cache's key, and nothing once compiled)
+        slabs()
+
+    def views(width):
+        if paged:
+            return tuple(_paged_view(c, layer, table, width)
+                         for c in new_cache)
+        if width < cache_len:       # `decode_step(extent=)`
+            return tuple(_layer_slab(c, layer, width) for c in new_cache)
+        return slabs()
+
+    def on_mesh(kernel, args, head_dims):
+        if spmd is not None:
+            return _spmd_call(spmd, kernel, args, head_dims)
+        return kernel(*args)
+
+    def at_hand():      # the mask over the call's own tokens
+        return mask[..., :T] if cached else mask
+
+    if form == "flash":
+        return _flash_attention(q, k, v, at_hand(), spmd)
+    if form == "local":
+        return gqa_attention(q, k, v, at_hand())
+    if form == "query_blocks":
+        return _gqa_attention_in_query_blocks(q, k, v, mask)
+    if form == "paged_flash":
+        # under a scope of its own (harness/attn_trace.py takes a custom
+        # call named after `attn.global` / `attn.window` for a DECODE read)
+        from nanorlhf_tpu.ops.paged_prefill_attention import (
+            paged_prefill_attention,
+        )
+
+        with jax.named_scope("attn.paged_flash"):
+            return paged_prefill_attention(
+                q, *new_cache, layer, table, *view.verify, window)
+    if form == "paged_walk":
+        first, fill = view.verify
+        return _attend_paged_blocks(new_cache, layer, table, view.page_size,
+                                    mask, first, fill + (T - 1), q)
+    if form == "paged_verify_slab":
+        from nanorlhf_tpu.ops.decode_attention import (
+            paged_decode_verify_attention,
+        )
+
+        return paged_decode_verify_attention(q, *slabs(), table, *view.verify)
+    if form == "verify_slab":
+        from nanorlhf_tpu.ops.decode_attention import decode_verify_attention
+
+        return on_mesh(decode_verify_attention, (q, *slabs(), *view.verify),
+                       (1, 1, 1, None, None))
+    if form == "paged_decode":
+        # `view.decode` is the step's work list (`_kind_views` made it
+        # under the same rule)
+        from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
+
+        return paged_decode_attention(
+            q[:, :, 0, :], *new_cache, layer, view.decode)[:, :, None, :]
+    if form == "decode":
+        from nanorlhf_tpu.ops.decode_attention import decode_attention
+
+        return on_mesh(decode_attention,
+                       (q[:, :, 0, :], *slabs(), *view.decode),
+                       (1, 1, 1, None, None))[:, :, None, :]
+    return gqa_attention(q, *views(mask.shape[-1]), mask)
 
 
 # pages a key block of the pattern model's T > 1 paged read holds (1,024 keys
@@ -1419,80 +1496,6 @@ def _gqa_attention_in_query_blocks(q, k, v, mask):
     return jnp.moveaxis(out, 0, 2).reshape(B, H, n * bq, hd)[:, :, :T]
 
 
-def _pattern_attention(config, q, k, v, mask, kv_cache, cache_index,
-                       decode_bounds, verify_bounds, paged, layer, window,
-                       spmd):
-    """A pattern model's attention read, for one layer of one kind: the
-    kind's `mask` carries the window per query on every path; the reads that
-    go by bounds (the two decode kernels, the paged T > 1 read: the flash
-    kernel over the pages on a TPU, XLA's walk elsewhere) get the kind's own
-    lower bound from their caller. `(out [B, H, T, hd], the
-    kind's updated cache stacks | None)`. What a pattern model does not have
-    raises where it is asked for (`_pattern_caches`, `sampler.compose_check`):
-    an int8 cache, speculative decode."""
-    T = q.shape[2]
-    # inside the window a window layer is a causal one: the flash kernel
-    # rebuilds causal x key-valid and has no block bound for a window
-    causal = not (window and T > window)
-    if kv_cache is None:
-        if causal and use_flash(config.attention_impl, T):
-            return gqa_attention(q, k, v, mask, impl="pallas",
-                                 mask_is_causal_x_keyvalid=True, spmd=spmd), None
-        return _gqa_attention_in_query_blocks(q, k, v, mask), None
-    new_cache = _cache_write(kv_cache, (k, v), layer, cache_index, paged)
-    width = mask.shape[-1]
-    cache_len = width if paged is not None else new_cache[0].shape[3]
-
-    def views():
-        if paged is not None:
-            return tuple(_paged_view(c, layer, paged[0], width)
-                         for c in new_cache)
-        return tuple(_layer_slab(c, layer, width if width < cache_len else None)
-                     for c in new_cache)
-
-    if verify_bounds is not None and paged is not None:
-        first, fill = verify_bounds
-        if use_paged_decode_kernel(config):
-            # a prefill piece or a suffix forward on a TPU: the flash kernel
-            # over the row's pages in place, under a scope of its own
-            # (harness/attn_trace.py takes a custom call named after
-            # `attn.global` / `attn.window` for a DECODE read)
-            from nanorlhf_tpu.ops.paged_prefill_attention import (
-                paged_prefill_attention,
-            )
-
-            with jax.named_scope("attn.paged_flash"):
-                out = paged_prefill_attention(
-                    q, *new_cache, layer, paged[0], first, fill, window)
-        else:
-            out = _attend_paged_blocks(new_cache, layer, paged[0], paged[1],
-                                       mask, first, fill + (T - 1), q)
-    elif verify_bounds is not None:
-        out = gqa_attention(q, *views(), mask)
-    elif T > 1:
-        # prefill from slot 0: the tokens at hand are all there is
-        flash = causal and use_flash(config.attention_impl, T)
-        out = gqa_attention(q, k, v, mask[..., :T],
-                            impl="pallas" if flash else "xla",
-                            mask_is_causal_x_keyvalid=causal, spmd=spmd)
-    elif (decode_bounds is not None and paged is not None
-          and use_paged_decode_kernel(config)):
-        from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
-
-        out = paged_decode_attention(
-            q[:, :, 0, :], *new_cache, layer, decode_bounds)[:, :, None, :]
-    elif (decode_bounds is not None and paged is None and spmd is None
-          and use_decode_kernel(config.attention_impl, cache_len)):
-        from nanorlhf_tpu.ops.decode_attention import decode_attention
-
-        out = decode_attention(
-            q[:, :, 0, :], *(_layer_slab(c, layer) for c in new_cache),
-            *decode_bounds)[:, :, None, :]
-    else:
-        out = gqa_attention(q, *views(), mask)
-    return out, new_cache
-
-
 def _expert_xs(layers: dict, in_place: bool):
     """`(scanned layer tree, expert stack | None)`. In place, an expert
     model's expert kernels stay OUT of the scanned xs and every layer
@@ -1508,9 +1511,9 @@ def _expert_xs(layers: dict, in_place: bool):
 def _layer_stacks(params: dict) -> list:
     """`[(stacked layer tree, its adapter tree | None, index of its first
     layer, layers)]` in model order: `layers` alone for every model of one
-    layer kind; `dense_layers` before it for A.X-K1, whose leading layers
-    have another MLP than the rest, so another tree to scan. The least of a
-    per-layer pattern this model needs, not a general one."""
+    stack; `dense_layers` before it for a model whose leading layers have
+    another MLP than the rest (A.X-K1, LFM2, Trinity), so another tree to
+    scan."""
     lora = params.get("lora", {})
     stacks, start = [], 0
     for name in ("dense_layers", "layers"):
@@ -1535,101 +1538,6 @@ def _rematerialized(config: ModelConfig, body):
         f"remat_policy={config.remat_policy!r}: must be 'full' or 'dots'")
 
 
-def _run_layers(config, params, x, cos, sin, mask, kv_caches=None, cache_index=0,
-                lora_scale=1.0, remat=False, attn_fn=None, layer_transform=None,
-                decode_bounds=None, verify_bounds=None, paged=None, live=None,
-                cached_aux=False, conv_ctx=None):
-    """Scan each stack of stacked layer params over the layer body
-    (`_layer_stacks`: one scan for every model but A.X-K1, which has two).
-
-    `remat=True` wraps the body in jax.checkpoint — the training path's
-    activation rematerialization (capability parity with the reference's
-    `gradient_checkpointing=True`, `/root/reference/GRPO/grpo.py:134`, but
-    trading FLOPs for HBM the XLA way).
-
-    `layer_transform(layer_params, lora_layer) -> (layer_params, lora_layer)`
-    runs inside the scan body before the layer math — the FSDP hook: scanned
-    param slices enter as shards and are all-gathered one layer at a time.
-
-    The third result is the expert stack's stacked `aux` (`_mlp`), from the
-    uncached forward always and from a cached one where `cached_aux` asks
-    for it (else None: the cached programs as they were).
-
-    A pattern model goes through `_run_pattern_layers`; `mask`,
-    `decode_bounds`, `verify_bounds` and `paged` are then `(global, window)`
-    pairs (`_kind_masks` and the entrypoints make them), `paged` with the
-    state rows third in a model with conv layers, whose `conv_ctx = (valid
-    [B, T] | None, fresh [B] | None)` says which tokens are real and which
-    rows start here (`_conv_operator`).
-    """
-    if config.attention_pattern is not None:
-        return _run_pattern_layers(
-            config, params, x, cos, sin, mask, kv_caches, cache_index,
-            lora_scale, remat, attn_fn, layer_transform, decode_bounds,
-            verify_bounds, paged, live, cached_aux, conv_ctx)
-    if kv_caches is None:
-        def uncached_body(expert_stack):
-            def body(carry, inp):
-                layer_params, lora_layer, *layer = inp
-                if layer_transform is not None:
-                    layer_params, lora_layer = layer_transform(layer_params, lora_layer)
-                y, _, aux = _layer_body(config, carry, layer_params, cos, sin, mask,
-                                        None, 0, lora_layer, lora_scale,
-                                        attn_fn=attn_fn, layer=layer[0] if layer else 0,
-                                        expert_stack=expert_stack)
-                return y, aux
-
-            return _rematerialized(config, body) if remat else body
-
-        aux = None
-        for tree, lora_layers, _, n in _layer_stacks(params):
-            # (with `ragged_dot` the differentiated forward scans the experts
-            # like every other weight: `_expert_xs`)
-            layer_xs, expert_stack = _expert_xs(
-                tree, in_place=use_expert_kernel(config))
-            xs = (layer_xs, lora_layers)
-            if expert_stack is not None:    # the layer's index into the stack
-                xs += (jnp.arange(n, dtype=jnp.int32),)
-            x, stack_aux = jax.lax.scan(uncached_body(expert_stack), x, xs)
-            aux = aux if stack_aux is None else stack_aux   # the expert stack's
-        return x, None, aux
-    else:
-        # cache is a tuple of stacked arrays: (k, v) exact,
-        # (k_q, k_s, v_q, v_s) int8, or MLA's one latent array. It is the
-        # scans' CARRY, not their xs/ys:
-        # every layer writes its new tokens into the one stacked buffer at
-        # its own index and reads its slab from it, so the cache that leaves
-        # the scan is the buffer that entered it and a decode loop that
-        # carries the cache needs no copy of it. `paged` (block table + page
-        # size) is closure-captured: one table serves every layer
-        def cached_body(expert_stack, start):
-            def body(carry, inp):
-                y, caches = carry
-                layer_params, lora_layer, layer = inp
-                y, caches, aux = _layer_body(
-                    config, y, layer_params, cos, sin, mask, caches,
-                    cache_index, lora_layer, lora_scale,
-                    decode_bounds=decode_bounds, verify_bounds=verify_bounds,
-                    paged=paged, layer=layer, expert_stack=expert_stack,
-                    stack_start=start, live=live,
-                )
-                return (y, caches), aux if cached_aux else None
-            return body
-
-        new_caches, aux = tuple(kv_caches), None
-        for tree, lora_layers, start, n in _layer_stacks(params):
-            layer_xs, expert_stack = _expert_xs(tree, in_place=True)
-            layers = jnp.arange(n, dtype=jnp.int32)
-            if start:
-                layers = layers + start
-            (x, new_caches), stack_aux = jax.lax.scan(
-                cached_body(expert_stack, start), (x, new_caches),
-                (layer_xs, lora_layers, layers),
-            )
-            aux = aux if stack_aux is None else stack_aux
-        return x, new_caches, aux
-
-
 def _at(stacks, layer):
     """Every leaf of stacked trees at one (traced) index of its leading
     axis: a size-one dynamic slice of the stack where it lies."""
@@ -1639,55 +1547,97 @@ def _at(stacks, layer):
 
 
 def _kind_group(kind) -> int:
-    """The cache group of a layer kind: 0 the global attention layers' pages,
-    1 the window layers', 2 the conv layers' state."""
+    """The cache group of a layer kind: 0 the global attention layers' pages
+    (every layer of a model without a pattern), 1 the window layers', 2 the
+    conv layers' state."""
     return 2 if kind == "conv" else int(kind[0])
 
 
-def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
-                        cache_index, lora_scale, remat, attn_fn,
-                        layer_transform, decode_bounds, verify_bounds, paged,
-                        live, cached_aux, conv_ctx=None):
-    """`_run_layers` for a model with a layer pattern: for each stack of
-    layers (`_layer_stacks`) ONE scan over the periods of its pattern
-    (`config.stack_pattern`), its body the period's layers in order, each of
-    its own static kind, `(window, rotary)` or `"conv"`. In a model with
-    conv layers the leaves only one kind has (`_ATTENTION_LEAVES`, `conv`)
-    are stacked over that kind's layers, and a layer's place among them is
-    that kind's count a period times the period plus its rank. The expert
-    kernels always stay out of the xs and are addressed in place at the
-    layer's index (`_expert_xs`). How a layer gets its OTHER leaves goes by
-    what the call can see:
+def leaves_in_place(config: ModelConfig, cached: bool,
+                    layer_transform=None) -> bool:
+    """How a layer of `_run_layers` gets its leaves, from what the call can
+    see: True, by index into the WHOLE stacks (`_at`: a size-one dynamic
+    slice where the stack lies); False, as the scan's xs.
 
-    - the CACHED forward (`kv_caches`: a session's decode chunk, prefill
-      piece, suffix and admission forwards, `generate()`) scans the period's
-      index alone and every layer takes each leaf from the WHOLE stack at
-      its own index (`_at`: a size-one dynamic slice), as the expert kernels
-      are. Each such slice has one user, the layer's matmul, and the
-      compiler reads the stack there. A period's slice `[p, ...]` of scanned
-      xs has `p` users, fuses into none of them and is set down: at
-      SmallThinker's widths the q and o kernels of four layers, 73 MB each,
-      copied every period of every decode step (0.585 of a 5.65 ms step,
-      PERF.md PR 43). Such a layer's attention is told so and keeps its
-      head split off the kernels (`_attention`'s `kernels_in_place`);
+    - the CACHED forward of a pattern model (a session's decode chunk,
+      prefill piece, suffix and admission forwards, `generate()`) indexes.
+      Each such slice has one user, the layer's matmul, and the compiler
+      reads the stack there. A period's slice `[p, ...]` of scanned xs has
+      `p` users, fuses into none of them and is set down: at SmallThinker's
+      widths the q and o kernels of four layers, 73 MB each, copied every
+      period of every decode step (0.585 of a 5.65 ms step, PERF.md PR 43).
+      Such a layer's attention keeps its head split off the kernels
+      (`_attention`, from `LayerLeaves.in_place`). The rule turns on the
+      model's having a pattern, not on the period's length: a pattern
+      model's stack of period one (Trinity's leading dense stack) indexes
+      too, as it has since PR 43, and its programs are the ones measured;
     - the UNCACHED forward (scoring, training, `remat`) scans the stacked
-      tree `[n, ...]` as `[n / p, p, ...]` (a reshape of the leading axis):
-      the backward of an index into a closed-over stack carries a gradient
-      the size of the stack through the scan, where a scanned slice's is a
-      slice. A call with a `layer_transform` (the FSDP hook: a scanned
-      slice enters as a shard) scans too.
+      tree, a pattern model's `[n, ...]` as `[n / p, p, ...]`: the backward
+      of an index into a closed-over stack carries a gradient the size of
+      the stack through the scan, where a scanned slice's is a slice. A
+      call with a `layer_transform` (the FSDP hook: a scanned slice enters
+      as a shard) scans too, and so does a model of one kind, whose scanned
+      slice has one user."""
+    return (config.attention_pattern is not None and cached
+            and layer_transform is None)
 
-    Whatever differs
-    by kind comes as a tuple by cache group (`_kind_group`) and a layer
-    takes its kind's: the mask (built once a call, `_kind_masks`), the
-    decode and verify bounds, the block table, and the CACHE, groups of
-    stacks `((k, v) of the global layers, (k, v) of the window layers[,
-    (state,) of the conv layers])`, all in the carry, a layer's index into
-    its group the count of that kind's layers before it."""
+
+def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
+                lora_scale=1.0, remat=False, attn_fn=None,
+                layer_transform=None, cached_aux=False):
+    """The decoder's layers, the one function that scans them: for each
+    stack of stacked layer params (`_layer_stacks`) ONE `lax.scan` over the
+    periods of its pattern (`config.stack_pattern`), its body the period's
+    layers in order through `_layer_body`, each of its own static kind,
+    `(window, rotary)` or `"conv"`. A model without a pattern goes through
+    the same scan a layer a trip, but not yet AS a pattern of period one:
+    see the last paragraph.
+
+    `views` is the call's `KindView` a cache group (`_kind_views`) and a
+    layer takes its kind's (`_kind_group`); the CACHE is groups of stacks
+    `((k, v) of the global layers, (k, v) of the window layers[, (state,)
+    of the conv layers])` for every model (`kv_caches` of a model without a
+    pattern is its one group, wrapped here and unwrapped on the way out),
+    all in the scans' CARRY, not their xs/ys: every layer writes its new
+    tokens into the one stacked buffer at its own index, the count of its
+    kind's layers before it, and reads its slab from it, so the cache that
+    leaves the scan is the buffer that entered it and a decode loop that
+    carries the cache needs no copy of it.
+
+    In a model with conv layers the leaves only one kind has
+    (`_ATTENTION_LEAVES`, `conv`) are stacked over that kind's layers, and a
+    layer's place among them is that kind's count a period times the period
+    plus its rank. How a layer gets its leaves: `leaves_in_place`.
+
+    `remat=True` wraps the body in jax.checkpoint: the training path's
+    activation rematerialization (capability parity with the reference's
+    `gradient_checkpointing=True`, `/root/reference/GRPO/grpo.py:134`, but
+    trading FLOPs for HBM the XLA way).
+
+    `layer_transform(layer_params, lora_layer) -> (layer_params, lora_layer)`
+    runs inside the scan body before the layer math: the FSDP hook: scanned
+    param slices enter as shards and are all-gathered one layer at a time.
+
+    Returns `(x, the updated caches | None, aux)`; `aux` is the expert
+    stack's stacked router record (`_mlp`), from the uncached forward always
+    and from a cached one where `cached_aux` asks for it (else None).
+
+    A model of one kind keeps the programs it always had, and the fork
+    survives in here as `plain`, asked at TEN sites below for four things:
+    its cache is one group, wrapped and unwrapped (2 sites); its period has
+    no axis of its own, the scan's slice IS the layer and its aux needs no
+    restacking (4); its layer index is the scan's own xs, over every stack
+    (3); and the differentiated forward under `ragged_dot` scans the
+    experts like every other weight (1). Scanning it as `[n, 1, ...]` with
+    the pattern's index rule would delete all but the first; it changes
+    every such program's text, so it takes the compiled comparison and
+    paired chip runs (ROADMAP D5 (b'))."""
     cached = kv_caches is not None
-    caches = tuple(kv_caches) if cached else None
-    pick = lambda pair, g: (None if pair is None or g >= len(pair)  # noqa: E731
-                            else pair[g])
+    plain = config.attention_pattern is None
+    caches = None
+    if cached:
+        caches = (tuple(kv_caches),) if plain else tuple(kv_caches)
+    in_place = leaves_in_place(config, cached, layer_transform)
     split = config.conv_layers > 0
     before = [0, 0, 0]      # layers of each group in the stacks so far
     aux = None
@@ -1698,15 +1648,16 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
         groups = [_kind_group(kind) for kind in pattern]
         per_period = [groups.count(g) for g in range(3)]
         rank = [groups[:j].count(groups[j]) for j in range(p)]
-        # the expert kernels always stay out of the xs: a period's slice of
-        # them is p layers' experts copied a scan step (2.3 GB at
-        # SmallThinker's widths, compiled for a described v5e, PR 34: the
-        # plain scoring path beside a served model). `ragged_dot` addresses
-        # the stack in place as the kernel does; its backward then
-        # transposes the whole stack a kernel, which full fine-tuning of
-        # such a model at real widths would have to repair (under LoRA the
-        # experts are frozen)
-        layer_xs, expert_stack = _expert_xs(tree, in_place=True)
+        # the expert kernels stay out of the xs: a period's slice of them is
+        # p layers' experts copied a scan step (2.3 GB at SmallThinker's
+        # widths, compiled for a described v5e, PR 34: the plain scoring
+        # path beside a served model). `ragged_dot` addresses the stack in
+        # place as the kernel does; its backward then transposes the whole
+        # stack a kernel, which full fine-tuning of a pattern model at real
+        # widths would have to repair (under LoRA the experts are frozen),
+        # and which a model of one kind avoids by scanning them
+        layer_xs, expert_stack = _expert_xs(
+            tree, in_place=cached or not plain or use_expert_kernel(config))
         # a layer's leaves lie in up to four stacked trees: what every layer
         # has, its adapters, and in a model with conv layers what only the
         # attention layers and only the conv layers have, each stacked over
@@ -1719,18 +1670,22 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
             trees[2] = {name: shared.pop(name) for name in _ATTENTION_LEAVES
                         if name in shared}
             trees[0] = shared
-        in_place = cached and layer_transform is None
-        xs = [None if in_place else jax.tree.map(
-            lambda a, k=k: a.reshape((n, k) + a.shape[1:]), t)
-            for t, k in zip(trees, per)]
+        if in_place:
+            xs = [None] * 4
+        elif plain:
+            xs = trees
+        else:
+            xs = [jax.tree.map(lambda a, k=k: a.reshape((n, k) + a.shape[1:]),
+                               t) for t, k in zip(trees, per)]
+        index = jnp.arange(n, dtype=jnp.int32)
+        if plain and not cached and expert_stack is None:
+            index = None        # nothing of such a layer is addressed by it
+        elif plain and cached and start:
+            index = index + start       # over every layer: the cache's
         first = tuple(before)
 
-        def body(carry, inp, pattern=pattern, groups=groups, rank=rank,
-                 per_period=per_period, expert_stack=expert_stack, p=p,
-                 first=first, trees=trees, per=per, in_place=in_place):
+        def body(carry, inp):
             y, caches = carry
-            # (the index third, as it always was: the uncached programs
-            # stay the ones they were)
             shared_xs, lora_xs, i, attn_xs, conv_xs = inp
             period = (shared_xs, lora_xs, attn_xs, conv_xs)
 
@@ -1739,6 +1694,8 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
                 out of the whole stacks, or of the scanned period."""
                 if in_place:
                     return _at(trees[which], i * per[which] + k)
+                if plain:
+                    return period[which]
                 return jax.tree.map(lambda a: a[k], period[which])
 
             auxes = []
@@ -1754,96 +1711,147 @@ def _run_pattern_layers(config, params, x, cos, sin, masks, kv_caches,
                 if layer_transform is not None:
                     layer_params, lora_layer = layer_transform(layer_params,
                                                                lora_layer)
-                layer = i * per_period[g] + rank[j]
-                if first[g]:    # (no `+ 0` in a one-stack model's program)
-                    layer = layer + first[g]
-                y, cache, aux = _layer_body(
-                    config, y, layer_params, cos, sin, pick(masks, g),
-                    caches[g] if cached else None, cache_index, lora_layer,
-                    lora_scale, attn_fn=attn_fn,
-                    decode_bounds=pick(decode_bounds, g),
-                    verify_bounds=pick(verify_bounds, g),
-                    paged=pick(paged, g), layer=layer,
-                    expert_stack=expert_stack, live=live, kind=kind,
-                    expert_layer=i * p + j, kernels_in_place=in_place,
-                    **({"conv_ctx": conv_ctx} if kind == "conv" else {}))
+                # the layer's index into its group's cache stacks, and its
+                # place in its own stack, where its experts lie
+                if plain:
+                    layer = 0 if i is None else i
+                    place = layer - start if cached and start else layer
+                else:
+                    layer = i * per_period[g] + rank[j]
+                    if first[g]:    # (no `+ 0` in a one-stack model's program)
+                        layer = layer + first[g]
+                    place = i * p + j
+                y, cache, layer_aux = _layer_body(
+                    config, y, LayerLeaves(layer_params, lora_layer,
+                                           expert_stack, place, in_place),
+                    layer, kind, views[g], caches[g] if cached else None,
+                    cos, sin, lora_scale, attn_fn)
                 if cached:
                     caches = tuple(cache if k == g else c
                                    for k, c in enumerate(caches))
-                auxes.append(aux)
-            keep = not cached or cached_aux
-            return (y, caches), (jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-                                 if keep else None)
+                auxes.append(layer_aux)
+            if cached and not cached_aux:
+                return (y, caches), None
+            return (y, caches), (auxes[0] if plain else jax.tree.map(
+                lambda *a: jnp.stack(a), *auxes))
 
         if remat and not cached:
             body = _rematerialized(config, body)
         (x, caches), stack_aux = jax.lax.scan(
-            body, (x, caches),
-            (*xs[:2], jnp.arange(n, dtype=jnp.int32), *xs[2:]))
-        if stack_aux is not None:   # [n / p, p, ...] -> [n, ...]
-            aux = jax.tree.map(
+            body, (x, caches), (*xs[:2], index, *xs[2:]))
+        if stack_aux is not None:   # a pattern's [n / p, p, ...] -> [n, ...]
+            aux = stack_aux if plain else jax.tree.map(
                 lambda a: a.reshape((n * p,) + a.shape[2:]), stack_aux)
         for g in range(3):
             before[g] += n * per_period[g]
-    return x, caches if cached else None, aux
+    if cached and plain:
+        (caches,) = caches
+    return x, caches, aux
 
 
-def _kind_masks(config: ModelConfig, mask, q_slot):
-    """`mask` [B, 1, Tq, Tk] as the layers take it: itself for a model
-    without a pattern, else `(global, window)`, the window layers' also
-    holding key slot j from query slot i unless i - window < j. `q_slot()`
-    gives [B | 1, Tq], the cache slot (or sequence index) of each query (a
-    function, so that a model without a pattern stages no op for it: its
-    programs stay the ones they were); keys count from 0 along the mask's
-    last axis. Built once a call."""
-    if config.attention_pattern is None:
-        return mask
-    if not config.sliding_window:
-        return mask, mask
-    k_slot = jnp.arange(mask.shape[-1], dtype=jnp.int32)
-    near = k_slot[None, None, None, :] > (
-        q_slot().astype(jnp.int32)[:, None, :, None] - config.sliding_window)
-    return mask, mask & near
+def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
+                decode=None, verify=None, page_table=None, page_size=0,
+                live=None, conv_ctx=None) -> tuple:
+    """The call's `KindView` a cache group, from what its entrypoint knows:
+    one for a model without a pattern, else `(global, window[, state])`.
 
+    `mask` [B, 1, Tq, Tk] is the call's; the window layers' also holds key
+    slot j from query slot i unless i - window < j. `q_slot()` gives [B | 1,
+    Tq], the cache slot (or sequence index) of each query (a function, so
+    that a model without a window stages no op for it); keys count from 0
+    along the mask's last axis. `decode=(start, filled)` / `verify=(first,
+    fill)` are the bounds of a read by slots; the window layers' start no
+    earlier than `window` - 1 slots before the FIRST query's slot (a decode
+    step's `filled` is one past its query, a verify's `fill` is its first
+    query). `page_table` is a model of one kind's [B, nb] table, a pattern
+    model's `(global, window[, state rows])`, one a kind of cache: the
+    window layers' is [B, nb] like the other, the ring of the row's window
+    pages laid out over its logical blocks (sampler/paged/pages.py
+    `RingPages.table`), so every read and write addresses it as any table;
+    the state kind's entry is the rows themselves (`_conv_operator`).
+    `kv_caches` says what each group's cache is and, for a decode step over
+    pages under `use_paged_decode_kernel`, sizes the step's plans, made once
+    here for every layer: the in-place read's work list a kind (its table,
+    its pool, its bound) and the live-row write's where the pool's shapes
+    take it (`_paged_row_kernel_takes`; elsewhere the row scatter).
+    `conv_ctx`: a thunk of `_conv_ctx(...)`, called last (the operations
+    stand in the program in the order the entrypoints always staged them:
+    the decode bounds, the masks, the verify bounds, the plans, the state's
+    context)."""
+    plain = config.attention_pattern is None
+    kinds = 1 if plain else 2
+    window = 0 if plain else config.sliding_window
+    tables, rows = [None] * kinds, None
+    if page_table is not None and plain:
+        tables = [page_table]
+    elif page_table is not None:
+        if (not isinstance(page_table, (tuple, list))
+                or len(page_table) != kinds + bool(config.conv_layers)):
+            raise ValueError(
+                "a model with a layer pattern takes page_table=(global table, "
+                "window table[, state rows]), one a kind of cache (docs/SWA.md, "
+                "docs/STATE.md)")
+        tables = list(page_table[:2])
+        rows = page_table[2] if config.conv_layers else None
+    groups = [None] * kinds
+    if kv_caches is not None:
+        groups = [kv_caches] if plain else list(kv_caches[:2])
 
-def _kind_bounds(config: ModelConfig, first, bound, first_query_past: int):
-    """`(first, bound)` bounds of a read by slots as the layers take them:
-    the pair itself without a pattern, else `(global, window)`, the window
-    layers' starting no earlier than `window` - 1 slots before the FIRST
-    query's slot, which is `bound + first_query_past - 1` (a decode step's
-    `bound` is one past its query: 0; a verify's is its first query: 1)."""
-    if config.attention_pattern is None:
-        return first, bound
-    if not config.sliding_window:
-        return (first, bound), (first, bound)
-    near = jnp.maximum(first, bound + first_query_past - config.sliding_window)
-    return (first, bound), (near.astype(first.dtype), bound)
+    def near(bounds, first_query_past):
+        first, bound = bounds
+        lo = jnp.maximum(first, bound + first_query_past - window)
+        return lo.astype(first.dtype), bound
 
+    decodes, masks, verifies = [decode] * kinds, [mask] * kinds, [verify] * kinds
+    if window and decode is not None:
+        decodes[1] = near(decode, 0)
+    if window:
+        k_slot = jnp.arange(mask.shape[-1], dtype=jnp.int32)
+        masks[1] = mask & (k_slot[None, None, None, :] > (
+            q_slot().astype(jnp.int32)[:, None, :, None] - window))
+    if window and verify is not None:
+        verifies[1] = near(verify, 1)
+    if decode is not None and config.kv_lora_rank and live is not None:
+        # an MLA model's paged read walks the key blocks these bounds span
+        # (core/mla.py): a row nobody listens to asks for none
+        decodes = [(jnp.where(live, decode[0], mask.shape[-1]),
+                    jnp.where(live, decode[1], 0))]
+    plans = [None] * kinds
+    if (decode is not None and page_table is not None
+            and use_paged_decode_kernel(config)):
+        from nanorlhf_tpu.ops.decode_attention import (
+            paged_decode_plan, paged_pages_per_item,
+        )
+        from nanorlhf_tpu.ops.paged_cache_write import paged_write_plan
 
-def _cache_leaf(kv_caches):
-    """The first array of a cache: `kv_caches[0]`, or the global group's K
-    stack of a pattern model's two groups."""
-    return jax.tree.leaves(kv_caches)[0]
+        decodes = [paged_decode_plan(
+            table, first, decode[1], page_size=page_size,
+            num_pages=group[0].shape[1],
+            pages_per_item=paged_pages_per_item(group[0]), live=live)
+            for table, group, (first, _) in zip(tables, groups, decodes)]
+        plans = [paged_write_plan(
+            table, index, page_size=page_size, num_pages=group[0].shape[1],
+            live=live) if _paged_row_kernel_takes(group, page_size) else None
+            for table, group in zip(tables, groups)]
+    ctx = None if conv_ctx is None else conv_ctx().get("conv_ctx")
 
+    def form(group):
+        if group is None:
+            return None
+        if config.kv_lora_rank:
+            return "latent"
+        return "int8" if group[0].dtype == jnp.int8 else "exact"
 
-def _kind_paged(config: ModelConfig, page_table, page_size):
-    """`paged` as the layers take it: `(table, page_size)`, or for a pattern
-    model one such pair a kind from `page_table = (global, window)`. The
-    window layers' table is [B, nb] like the other: the ring of the row's
-    window pages laid out over its logical blocks (sampler/paged/pages.py
-    `RingPages.table`), so every read and write addresses it as any table."""
-    if page_table is None:
-        return None
-    if config.attention_pattern is None:
-        return page_table, page_size
-    kinds = 3 if config.conv_layers else 2
-    if not isinstance(page_table, (tuple, list)) or len(page_table) != kinds:
-        raise ValueError(
-            "a model with a layer pattern takes page_table=(global table, "
-            "window table[, state rows]), one a kind of cache (docs/SWA.md, "
-            "docs/STATE.md)")
-    # (the state kind's entry is the rows themselves: `_conv_operator`)
-    return tuple((t, page_size) for t in page_table[:2]) + tuple(page_table[2:])
+    views = tuple(
+        KindView(mask=m, cache=form(group), index=index, decode=d, verify=v,
+                 table=table, page_size=page_size, write_plan=plan, live=live)
+        for m, group, d, v, table, plan
+        in zip(masks, groups, decodes, verifies, tables, plans))
+    if config.conv_layers:
+        views += (KindView(
+            mask=None, cache=None if kv_caches is None else "state",
+            index=index, table=rows, conv_ctx=ctx, live=live),)
+    return views
 
 
 def unembedding(config: ModelConfig, params: dict):
@@ -1927,11 +1935,11 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     cos, sin = _rope(config, position_ids)
     causal = jnp.tril(jnp.ones((T, T), bool))
     mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
-    mask = _kind_masks(config, mask, lambda: jnp.arange(T)[None, :])
-    x, _, aux = _run_layers(config, params, x, cos, sin, mask,
+    views = _kind_views(config, mask, lambda: jnp.arange(T)[None, :],
+                        conv_ctx=lambda: _conv_ctx(config, attention_mask))
+    x, _, aux = _run_layers(config, params, x, cos, sin, views,
                             lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
-                            layer_transform=layer_transform,
-                            **_conv_ctx(config, attention_mask))
+                            layer_transform=layer_transform)
     if router_stats:
         from nanorlhf_tpu.ops.moe import router_stats as reduce_stats
 
@@ -1940,8 +1948,9 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
 
 
 def _conv_ctx(config: ModelConfig, valid=None, fresh=None) -> dict:
-    """`_run_layers`' `conv_ctx` keyword for a model with conv layers, and
-    nothing for every other model. What a call has to COMPUTE comes as a
+    """`{"conv_ctx": (valid, fresh)}`, what a call knows of its tokens for
+    the state group's `KindView` (`_kind_views`) of a model with conv layers,
+    and nothing for every other model. What a call has to COMPUTE comes as a
     thunk (`fresh` always does) and is computed for a model with conv layers
     only: an operation nobody reads still stands in the program of a loop's
     body, and every other model's programs stay the ones they were."""
@@ -2285,12 +2294,11 @@ def prefill(
     the last position is the last prompt token for every row.
     """
     B, T = input_ids.shape
-    paged = _kind_paged(config, page_table, page_size)
     if page_table is not None:
         T_max = logical_len if logical_len else (
             jax.tree.leaves(page_table)[0].shape[1] * page_size)
     else:
-        T_max = _cache_leaf(kv_caches).shape[3]
+        T_max = jax.tree.leaves(kv_caches)[0].shape[3]
     attention_mask = attention_mask.astype(bool)
     position_ids = jnp.cumsum(attention_mask, axis=1) - attention_mask.astype(jnp.int32)
     x = _embed(config, params, jnp.where(attention_mask, input_ids, 0))
@@ -2299,13 +2307,14 @@ def prefill(
     # queries attend over cache positions [0, T); the rest of T_max is masked
     mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
     mask_full = jnp.zeros((B, 1, T, T_max), bool).at[:, :, :, :T].set(mask)
-    mask_full = _kind_masks(config, mask_full, lambda: jnp.arange(T)[None, :])
-    x, new_caches, _ = _run_layers(
-        config, params, x, cos, sin, mask_full, kv_caches=kv_caches, cache_index=0,
-        lora_scale=lora_scale, paged=paged,
+    views = _kind_views(
+        config, mask_full, lambda: jnp.arange(T)[None, :], kv_caches=kv_caches,
+        page_table=page_table, page_size=page_size,
         # (a prompt starts its rows: whatever state they held is not theirs)
-        **_conv_ctx(config, attention_mask, lambda: jnp.ones((B,), bool)),
-    )
+        conv_ctx=lambda: _conv_ctx(config, attention_mask,
+                                   lambda: jnp.ones((B,), bool)))
+    x, new_caches, _ = _run_layers(config, params, x, cos, sin, views,
+                                   kv_caches, lora_scale)
     logits = _logits(config, params, x[:, -1:, :])[:, 0, :]
     return logits, new_caches
 
@@ -2339,10 +2348,8 @@ def decode_step(
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
     over the layers."""
     B = token.shape[0]
-    paged = _kind_paged(config, page_table, page_size)
-    pattern = config.attention_pattern is not None
     if extent is not None and extent < key_mask.shape[1]:
-        # the mask's width is what the XLA read goes by (`_kv_views`); the
+        # the mask's width is what the XLA read goes by (`_attention_read`); the
         # cache write below addresses the full stack as ever
         key_mask = key_mask[:, :extent]
     x = _embed(config, params, token)[:, None, :]
@@ -2354,41 +2361,21 @@ def decode_step(
     start = jnp.argmax(key_mask, axis=1).astype(jnp.int32)
     filled = jnp.broadcast_to(
         jnp.asarray(cache_index, jnp.int32) + 1, (B,))
-    bounds = _kind_bounds(config, start, filled, 0)
-    mask = _kind_masks(config, mask, lambda: (filled - 1)[:, None])
-    if config.kv_lora_rank and live is not None:
-        # an MLA model's paged read walks the key blocks these bounds span
-        # (core/mla.py): a row nobody listens to asks for none
-        bounds = (jnp.where(live, start, key_mask.shape[1]),
-                  jnp.where(live, filled, 0))
-    if paged is not None and use_paged_decode_kernel(config):
-        # the step's work list for the in-place read, once for every layer
-        from nanorlhf_tpu.ops.decode_attention import (
-            paged_decode_plan, paged_pages_per_item,
-        )
-
-        plan = lambda table, pool, first: paged_decode_plan(  # noqa: E731
-            table, first, filled, page_size=page_size,
-            num_pages=pool.shape[1],
-            pages_per_item=paged_pages_per_item(pool), live=live)
-        if pattern:     # one plan a kind: its table, its pool, its bound
-            bounds = tuple(plan(t, group[0], first) for t, group, (first, _)
-                           in zip(page_table, kv_caches, bounds))
-        else:
-            bounds = plan(page_table, kv_caches[0], start)
-    paged = _with_write_plan(config, paged, kv_caches, cache_index, live)
-    x, new_caches, aux = _run_layers(
-        config, params, x, cos, sin, mask, kv_caches=kv_caches, cache_index=cache_index,
-        lora_scale=lora_scale, decode_bounds=bounds, paged=paged,
-        # (an expert layer dispatches the live rows only, and counts the
-        # experts they reach where the caller asks; a dense model's layers
-        # are not told)
-        **({"live": live} if config.num_experts else {}),
-        **({"cached_aux": True} if count_experts else {}),
+    views = _kind_views(
+        config, mask, lambda: (filled - 1)[:, None], kv_caches=kv_caches,
+        index=cache_index, decode=(start, filled), page_table=page_table,
+        page_size=page_size,
+        # (an expert layer dispatches the rows someone listens to only, and
+        # the in-place paged read and write skip the others)
+        live=live,
         # (a row nobody listens to leaves its state as it was: it may be a
         # chunked admission between two of its pieces)
-        **_conv_ctx(config, lambda: None if live is None else live[:, None]),
-    )
+        conv_ctx=lambda: _conv_ctx(
+            config, lambda: None if live is None else live[:, None]))
+    x, new_caches, aux = _run_layers(
+        config, params, x, cos, sin, views, kv_caches, lora_scale,
+        # (the experts its rows reach, where the caller asks)
+        cached_aux=count_experts)
     logits = _logits(config, params, x)[:, 0, :]
     if count_experts:
         return logits, new_caches, jnp.sum(aux["reached"])
@@ -2441,7 +2428,6 @@ def decode_verify(
     # the logical width is the key_mask width — equal to the slab's T_max on
     # the contiguous layout, and the only meaningful width on the paged one
     T_max = key_mask.shape[1]
-    paged = _kind_paged(config, page_table, page_size)
     key_mask = key_mask.astype(bool)
     x = _embed(config, params, tokens)
     cos, sin = _rope(config, positions)
@@ -2454,15 +2440,15 @@ def decode_verify(
     start = jnp.where(key_mask.any(axis=1), jnp.argmax(key_mask, axis=1),
                       fill).astype(jnp.int32)
     fill = fill.astype(jnp.int32)
-    mask = _kind_masks(config, mask,
-                       lambda: fill[:, None] + jnp.arange(Tq)[None, :])
-    x, new_caches, _ = _run_layers(
-        config, params, x, cos, sin, mask, kv_caches=kv_caches,
-        cache_index=fill, lora_scale=lora_scale,
-        verify_bounds=_kind_bounds(config, start, fill, 1), paged=paged,
+    views = _kind_views(
+        config, mask, lambda: fill[:, None] + jnp.arange(Tq)[None, :],
+        kv_caches=kv_caches, index=fill, verify=(start, fill),
+        page_table=page_table, page_size=page_size,
         # a row with no valid slot before its candidates starts here
-        **_conv_ctx(config, token_valid, lambda: ~key_mask.any(axis=1)),
-    )
+        conv_ctx=lambda: _conv_ctx(config, token_valid,
+                                   lambda: ~key_mask.any(axis=1)))
+    x, new_caches, _ = _run_layers(config, params, x, cos, sin, views,
+                                   kv_caches, lora_scale)
     if not want_logits:
         return None, new_caches
     return _logits(config, params, x), new_caches

@@ -24,7 +24,7 @@ SmallThinker's widths, PERF.md PR 37). This kernel is the same read on the chip:
   twelve admission programs took ~15 s longer to trace and lower).
 - **The mask from iota.** Query i sits at slot `fill + i` and sees key j iff
   `first <= j <= fill + i` and, in a window layer, `j > fill + i - window`:
-  what `_kind_masks` hands the walk for a row whose valid keys are contiguous
+  what `core/model._kind_views` hands the walk for a row whose valid keys are contiguous
   from `first` (every serving row). Items wholly outside a query block's
   `[lowest visible key, its last query]` are SKIPPED: a block's items start
   at the page of its lowest visible key, and the grid steps past its last
@@ -184,7 +184,7 @@ def paged_prefill_attention(
     """Flash attention of T queries over a row's pages, read from the stacked
     pool in place (module docstring). Query i sees key slot j iff
     `first <= j <= fill + i` and, with a `window`, `j > fill + i - window`
-    (`first`, `fill` as `core/model._kind_bounds(config, start, fill, 1)`
+    (`first`, `fill` as the kind's `core/model.KindView.verify`
     gives them for the layer's kind). `block_q` and `pages_per_item` default
     to the chip's (`_BLOCK_Q` queries, `_ITEM_KEYS` keys); tests shrink them.
     Returns [B, H, T, hd]."""

@@ -70,8 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from nanorlhf_tpu.core.model import (
-    decode_step, decode_verify, paged_write_forms, prefill,
-    use_paged_decode_kernel,
+    attention_form, decode_step, decode_verify, leaves_in_place,
+    paged_write_forms, prefill,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
@@ -849,29 +849,33 @@ class DecodeSession:
         self.rows_past_window = 0
         self.live_row_steps = 0     # live rows, summed a step
         self._row_reused_np = np.zeros((R,), np.int64)
-        self.attn_in_place = int(not self.spec
-                                 and use_paged_decode_kernel(config))
-        # forwards dispatched for chunked admissions (`_prefill_tick`: the
-        # pieces and each one's closing suffix forward), and whether their
-        # T > 1 paged read is the flash kernel over the pages in place
-        # (`core/model._pattern_attention`: a pattern model under the decode
-        # read's rule) or XLA's walk / gathered view
+        # what the model layer will do, asked of the function that decides
+        # it (`core/model.attention_form`), not worked out again here: the
+        # decode step's paged read in place ...
+        paged_read = partial(
+            attention_form, config, cached=True, paged=True,
+            cache_len=self.T_max)
+        self.attn_in_place = int(
+            not self.spec and paged_read(1, decode=True) == "paged_decode")
+        # ... and, of the forwards dispatched for chunked admissions
+        # (`_prefill_tick`: the pieces and each one's closing suffix
+        # forward), whether their T > 1 paged read is the flash kernel over
+        # the pages in place or XLA's walk / a slab / the gathered view
         self.prefill_pieces = 0
-        self.prefill_read_in_place = int(
-            config.attention_pattern is not None
-            and use_paged_decode_kernel(config))
+        self.prefill_read_in_place = int(paged_read(
+            self.prefill_chunk or self.Tp, verify=True) == "paged_flash")
         # which write the programs were built with (`core/model.
-        # _paged_cache_update`, `_cache_write`): a prefill piece's by page,
+        # _paged_cache_update`, `_kind_views`): a prefill piece's by page,
         # and a decode step's live rows through ops/paged_cache_write
         self.kv_write_by_page, live_rows = paged_write_forms(
             config, caches0, self.page_size, self.prefill_chunk or self.Tp,
             self.nb)
         self.kv_write_live_rows = int(live_rows and not self.spec)
         # whether a layer takes its kernels by index into the whole stacks
-        # (`core/model._run_pattern_layers`: every cached forward of a
-        # pattern model; a model without a pattern scans one layer a trip
-        # and never copied a period)
-        self.layer_kernels_in_place = int(config.attention_pattern is not None)
+        # (`core/model.leaves_in_place`, the layer runner's rule: every
+        # cached forward of a pattern model; a model without a pattern scans
+        # one layer a trip and never copied a period)
+        self.layer_kernels_in_place = int(leaves_in_place(config, cached=True))
         # and whether its attention then fences the q, k and v projections'
         # results from the head split, so that each is a matmul over the
         # stack where it lies (`core/model._attention`; MLA's projections

@@ -1,15 +1,11 @@
 """The stacked KV cache is the layer scan's carry (core/model.py::_run_layers).
 
-Two things are pinned here:
-
-- the numbers: `prefill`, a chain of `decode_step` and a `decode_verify`
-  through the scanned path equal, bit for bit on CPU, an unrolled reference
-  in which neither the scan nor the carry exists: a Python loop over layers
-  that hands `_layer_body` each layer's own one-layer stack `c[l:l+1]`;
-- the mechanism: in the compiled decode loops no instruction copies a
-  stacked cache array and no `dynamic-update-slice` writes a whole layer slab
-  into one. With the cache as the scan's xs/ys (before ISSUE 26) XLA put two
-  whole-stack copies into every decode step.
+The mechanism is pinned here: in the compiled decode loops no instruction
+copies a stacked cache array and no `dynamic-update-slice` writes a whole
+layer slab into one. With the cache as the scan's xs/ys (before ISSUE 26) XLA
+put two whole-stack copies into every decode step. The numbers (the scanned
+path against the layers one by one, bit for bit) are
+tests/test_layer_runner.py's.
 """
 
 import dataclasses
@@ -36,110 +32,6 @@ def _config(quant):
 @pytest.fixture(scope="module")
 def params():
     return init_params(_config("none"), jax.random.PRNGKey(7), jnp.float32)
-
-
-# --------------------------------------------------------------------- #
-# the numbers
-# --------------------------------------------------------------------- #
-
-def _unrolled_run_layers(config, params, x, cos, sin, mask, kv_caches=None,
-                         cache_index=0, lora_scale=1.0, decode_bounds=None,
-                         verify_bounds=None, paged=None):
-    """`_run_layers`' cached branch without the scan and without the carry:
-    layer l sees only its own one-layer stack, as layer 0 of it."""
-    outs = []
-    for l in range(kv_caches[0].shape[0]):
-        x, new, _ = M._layer_body(
-            config, x, jax.tree.map(lambda p: p[l], params["layers"]),
-            cos, sin, mask, tuple(c[l:l + 1] for c in kv_caches), cache_index,
-            None, lora_scale, decode_bounds=decode_bounds,
-            verify_bounds=verify_bounds, paged=paged, layer=0,
-        )
-        outs.append(new)
-    return x, tuple(jnp.concatenate(cs, axis=0) for cs in zip(*outs)), None
-
-
-def _forward_chain(config, params, layout, per_row):
-    """prefill → three decode_steps → one decode_verify of four candidates;
-    returns every logits array and the final caches."""
-    B, Tp, steps, K1, P = 2, 4, 3, 4, 4
-    T_max = Tp + steps + K1 + 3
-    ids = jnp.asarray([[PAD, 5, 6, 7], [9, 10, 11, 12]], jnp.int32)
-    mask = ids != PAD
-    kw = {}
-    if layout == "paged":
-        nb = -(-T_max // P)
-        # rows interleave their pages, so a wrong table lookup shows
-        table = jnp.arange(B * nb, dtype=jnp.int32).reshape(nb, B).T
-        caches = M.init_paged_kv_cache(config, B * nb, P, jnp.float32)
-        kw = dict(page_table=table, page_size=P)
-        logits, caches = M.prefill(params, config, ids, mask, caches,
-                                   logical_len=T_max, **kw)
-    else:
-        caches = M.init_kv_cache(config, B, T_max, jnp.float32)
-        logits, caches = M.prefill(params, config, ids, mask, caches)
-    got = [logits]
-    plen = jnp.sum(mask, axis=1).astype(jnp.int32)
-    # per-row: row 1 sits two slots deeper than row 0 (rows of a session
-    # advance at different rates); the skipped slots stay invisible
-    ahead = jnp.asarray([0, 2] if per_row else [0, 0], jnp.int32)
-    key_mask = jnp.zeros((B, T_max), bool).at[:, :Tp].set(mask)
-    rows = jnp.arange(B)
-    toks = jnp.asarray([[20, 21, 22], [30, 31, 32]], jnp.int32)
-    for i in range(steps):
-        slot = Tp + i + ahead
-        key_mask = key_mask.at[rows, slot].set(True)
-        logits, caches = M.decode_step(
-            params, config, toks[:, i], plen + i,
-            slot if per_row else Tp + i, key_mask, caches, **kw)
-        got.append(logits)
-    cand = jnp.asarray([[40, 41, 42, 43], [50, 51, 52, 53]], jnp.int32)
-    fill = Tp + steps + ahead
-    positions = (plen + steps)[:, None] + jnp.arange(K1)[None, :]
-    logits, caches = M.decode_verify(params, config, cand, positions, fill,
-                                     key_mask, caches, **kw)
-    got.append(logits)
-    return got, caches
-
-
-@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
-@pytest.mark.parametrize("quant", ["none", "int8"], ids=["exact", "int8"])
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_scanned_carry_matches_unrolled_layers(params, monkeypatch, layout,
-                                               quant, per_row):
-    """Bit for bit with both sides run op by op (`jax.disable_jit`: the scan
-    then steps its body in Python, carry and layer index as in the compiled
-    loop), because only then do both sides run the same executables. A scan
-    body compiled as one computation differs from the same layer run op by op
-    (or jitted alone) in the last bit on XLA:CPU (1.2e-7 on these logits:
-    other multiply-adds are contracted), whatever the cache does; the
-    compiled path is held to that roundoff below, on the exact cache."""
-    config = _config(quant)
-    with jax.disable_jit():
-        got, got_caches = _forward_chain(config, params, layout, per_row)
-    compiled, compiled_caches = _forward_chain(config, params, layout, per_row)
-    monkeypatch.setattr(M, "_run_layers", _unrolled_run_layers)
-    with jax.disable_jit():
-        want, want_caches = _forward_chain(config, params, layout, per_row)
-    for i, (a, b) in enumerate(zip(got, want)):
-        assert np.isfinite(np.asarray(a)).all()
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=f"logits of forward {i}")
-    assert len(got_caches) == (4 if quant == "int8" else 2)
-    for a, b in zip(got_caches, want_caches):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # something was written, in every layer
-    k = np.asarray(got_caches[0])
-    assert all(np.abs(k[l]).sum() > 0 for l in range(k.shape[0]))
-    if quant == "none":
-        # an int8 cache turns a last-bit difference into a whole step now
-        # and then, so only the exact cache is compared across compilations
-        for a, b in zip(compiled + list(compiled_caches),
-                        want + list(want_caches)):
-            b = np.asarray(b)
-            np.testing.assert_allclose(np.asarray(a), b, rtol=0,
-                                       atol=1e-5 * np.abs(b).max())
 
 
 # --------------------------------------------------------------------- #

@@ -921,7 +921,7 @@ def test_pattern_session_programs_set_down_no_period_of_kernels_on_v5e(
     computations the decode chunk and the prefill piece produce NO array
     shaped like a period of a projection stack, and nothing named `*.remat`
     larger than one layer's kernel. The cached scan hands a layer its
-    kernels by index into the whole stacks (`core/model._run_pattern_layers`,
+    kernels by index into the whole stacks (`core/model.leaves_in_place`,
     `_at`), so each slice has one user, the layer's matmul. As scanned xs a
     period's slice had `p` users, fused into none and was set down: at
     SmallThinker's widths `%dynamic-slice_bitcast_fusion.24` and its twin

@@ -292,7 +292,7 @@ def test_a_decode_step_takes_the_kernel_only_where_it_can(pool_kind,
     """`decode_step` under `use_paged_decode_kernel`'s rule
     (`attention_impl="pallas"` here) hands `_cache_write` a plan where the
     pool is one the kernel takes: a (k, v) pool of whole 128-lane rows and
-    whole tiles a page. `_with_write_plan` decides; `_cache_write` follows
+    whole tiles a page. `_kind_views` decides; `_cache_write` follows
     the plan it is given and agrees with the scatter bit for bit."""
     import dataclasses
 
@@ -318,23 +318,31 @@ def test_a_decode_step_takes_the_kernel_only_where_it_can(pool_kind,
     cfg = dataclasses.replace(
         ModelConfig.qwen2_tiny(), attention_impl="pallas",
         kv_cache_quant="int8" if pool_kind == "int8" else "none")
-    paged = M._with_write_plan(cfg, (jnp.asarray(table), P), stacks, starts,
-                               None)
+    def view(cfg):
+        """The step's `KindView` as `decode_step` makes it."""
+        key_mask = jnp.arange(4 * P)[None, :] <= starts[:, None]
+        return M._kind_views(
+            cfg, key_mask[:, None, None, :], lambda: starts[:, None],
+            kv_caches=stacks, index=starts,
+            decode=(jnp.zeros((B,), jnp.int32), starts + 1),
+            page_table=jnp.asarray(table), page_size=P)[0]
+
+    planned = view(cfg)
     taken = pool_kind in ("bf16_pages_of_16", "wide_rows_are_taken_too",
                           "float32_pages_of_8")
-    assert (len(paged) == 3) == taken
-    assert len(M._with_write_plan(
-        dataclasses.replace(cfg, attention_impl="xla"),
-        (jnp.asarray(table), P), stacks, starts, None)) == 2
+    assert (planned.write_plan is not None) == taken
+    assert planned.cache == ("int8" if pool_kind == "int8" else "exact")
+    scattered = view(dataclasses.replace(cfg, attention_impl="xla"))
+    assert scattered.write_plan is None
     if pool_kind == "int8":
         return
     news = tuple(noise(i, (B, 2, 1, hd), dtype) for i in (2, 3))
-    write = lambda pg: lambda s, nw: M._cache_write(   # noqa: E731
-        s, nw, jnp.int32(LAYER), starts, pg)
-    assert ("pallas_call" in str(jax.make_jaxpr(write(paged))(stacks, news))
+    write = lambda view: lambda s, nw: M._cache_write(   # noqa: E731
+        s, nw, jnp.int32(LAYER), view)
+    assert ("pallas_call" in str(jax.make_jaxpr(write(planned))(stacks, news))
             ) == taken
-    for a, b in zip(jax.jit(write(paged))(stacks, news),
-                    jax.jit(write(paged[:2]))(stacks, news)):
+    for a, b in zip(jax.jit(write(planned))(stacks, news),
+                    jax.jit(write(scattered))(stacks, news)):
         np.testing.assert_array_equal(bits(a), bits(b))
 
 

@@ -1,7 +1,7 @@
 """The pattern model's T > 1 paged read as a flash kernel over the pages in
 place (ops/paged_prefill_attention.py, ISSUE 37), in interpret mode, against
 its two oracles: the XLA block walk it stands in for on a TPU
-(`core/model._attend_paged_blocks`, handed the mask `_kind_masks` builds) and
+(`core/model._attend_paged_blocks`, handed the mask `_kind_views` builds) and
 plain attention over the row as it was written, slot by slot
 (`reference_attention` for a global layer; the same sums under the window's
 mask for a window layer). Tiny widths: pages of 8, a window of 32, blocks of
@@ -98,8 +98,9 @@ def test_kernel_is_the_walk_and_the_plain_read(kind, case, dtype):
     cand = (slot >= fill[:, None, None]) & (slot <= fill[:, None, None] + qi)
     mask = (key_mask[:, None, :] | cand)[:, None]
     w = int(kind == "window")
-    mask = M._kind_masks(CFG, mask, lambda: fill[:, None] + jnp.arange(T)[None])[w]
-    first, bound = M._kind_bounds(CFG, start, fill, 1)[w]
+    view = M._kind_views(CFG, mask, lambda: fill[:, None] + jnp.arange(T)[None],
+                         verify=(start, fill))[w]
+    mask, (first, bound) = view.mask, view.verify
 
     got = paged_prefill_attention(q, *pools, LAYER, table, first, bound, window,
                                   block_q=16, pages_per_item=2, interpret=True)

@@ -555,7 +555,7 @@ def test_engine_says_how_its_programs_were_built(model, in_place):
     """The static gauges of `engine.metrics()` that say which form the
     session's programs took: `serving/layer_kernels_in_place` is 1 for a
     model with a layer pattern (its cached layer scan hands each layer its
-    kernels by index into the stacks, `core/model._run_pattern_layers`) and 0
+    kernels by index into the stacks, `core/model.leaves_in_place`) and 0
     for one without (a plain scan, a layer a trip), and
     `serving/qkv_kernels_in_place` with it (such a layer's attention fences
     its projections from the head split, `core/model._attention`; the latent

@@ -481,26 +481,6 @@ def _two_periods(model):
         sliding_window_layout=layout, rope_layout=layout)
 
 
-def _adapters(params):
-    """LoRA leaves on q and o of every stack with attention leaves, over the
-    STACK's layers (LFM2's attention leaves themselves lie over its attention
-    layers only), with a `b` that is not zero, so that they count."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(7), 8))
-    out = {}
-    for stack in ("dense_layers", "layers"):
-        tree = params.get(stack, {})
-        if "q_proj" not in tree:
-            continue
-        L = tree["input_layernorm"].shape[0]
-        out[stack] = {}
-        for name in ("q_proj", "o_proj"):
-            d_in, d_out = tree[name]["kernel"].shape[1:]
-            out[stack][name] = {
-                "a": jax.random.normal(next(keys), (L, d_in, 4)) / 2,
-                "b": jax.random.normal(next(keys), (L, 4, d_out)) * 0.05}
-    return out
-
-
 def _two_rows(cfg):
     """A left-padded row beside a full one for the paged walks below: `(ids
     [B, T_max], valid, positions, the kinds' tables, the pools' pages)` at
@@ -519,166 +499,6 @@ def _two_rows(cfg):
     tabs = (table, table) + (
         (jnp.arange(B, dtype=jnp.int32)[:, None],) if cfg.conv_layers else ())
     return jnp.asarray(ids), valid, pos, tabs, (B * nb, B * nb)
-
-
-def _loop_layers(config, params, x, cos, sin, masks, kv_caches, cache_index,
-                 lora_scale, remat, attn_fn, layer_transform, decode_bounds,
-                 verify_bounds, paged, live, cached_aux, conv_ctx=None):
-    """What `core/model._run_pattern_layers` computes, with no scan and no
-    index that is not a Python number: the layers one by one through
-    `_layer_body`, each on its leaves sliced statically out of the stacks."""
-    from nanorlhf_tpu.core import model as M
-
-    cached = kv_caches is not None
-    caches = tuple(kv_caches) if cached else None
-    pick = lambda pair, g: (None if pair is None or g >= len(pair)  # noqa: E731
-                            else pair[g])
-    seen, aux = [0, 0, 0], None     # layers of each cache group so far
-    for tree, lora, start, count in M._layer_stacks(params):
-        tree = dict(tree)
-        experts = tree.pop("experts", None)
-        own = [0, 0]    # this stack's attention layers and conv layers so far
-        auxes = []
-        for at, kind in enumerate(config.layer_kinds[start:start + count]):
-            g = M._kind_group(kind)
-            layer_params = {}
-            for name, leaf in tree.items():
-                mine = name == "conv" or name in M._ATTENTION_LEAVES
-                if config.conv_layers and mine:
-                    if (name == "conv") == (g == 2):
-                        layer_params[name] = jax.tree.map(
-                            lambda a: a[own[int(g == 2)]], leaf)
-                else:
-                    layer_params[name] = jax.tree.map(lambda a: a[at], leaf)
-            x, cache, layer_aux = M._layer_body(
-                config, x, layer_params, cos, sin, pick(masks, g),
-                caches[g] if cached else None, cache_index,
-                jax.tree.map(lambda a: a[at], lora), lora_scale,
-                attn_fn=attn_fn, decode_bounds=pick(decode_bounds, g),
-                verify_bounds=pick(verify_bounds, g), paged=pick(paged, g),
-                layer=seen[g], expert_stack=experts, live=live, kind=kind,
-                expert_layer=at,
-                **({"conv_ctx": conv_ctx} if kind == "conv" else {}))
-            if cached:
-                caches = tuple(cache if k == g else c
-                               for k, c in enumerate(caches))
-            auxes.append(layer_aux)
-            seen[g] += 1
-            own[int(g == 2)] += 1
-        if not cached or cached_aux:
-            stacked = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-            aux = aux if stacked is None else stacked
-    return x, caches, aux
-
-
-def _scans(jaxpr):
-    """Every `scan` equation of a jaxpr, nested ones too."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            found.append(eqn)
-        for v in eqn.params.values():
-            for sub in v if isinstance(v, (tuple, list)) else (v,):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found += _scans(sub)
-    return found
-
-
-def _scanned(eqn):
-    """The shapes of a scan's xs."""
-    skip = eqn.params["num_consts"] + eqn.params["num_carry"]
-    return [v.aval.shape for v in eqn.invars[skip:]]
-
-
-@pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
-@pytest.mark.parametrize("model", ["smallthinker", "lfm2", "trinity"])
-def test_the_cached_scan_indexes_the_stacks_and_the_uncached_scans_them(
-        model, lora, monkeypatch):
-    """ISSUE 43. With a cache (a prefill, then three decode steps, paged)
-    the period scan's xs are the period's index alone and every layer takes
-    its leaves, LoRA's and a kind's own too, from the whole stacks at its own
-    index: logits and caches BITWISE those of the layers run one by one on
-    statically sliced leaves (`_loop_layers`). Without a cache the stacks
-    are still the scan's xs, `[n / p, p, ...]`, and the gradient is the
-    loop's."""
-    from nanorlhf_tpu.core import model as M
-
-    cfg = _two_periods(model)
-    params = init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
-    *_, start, n = M._layer_stacks(params)[-1]
-    assert n == 2 * len(cfg.stack_pattern(start, n))    # two trips
-    if lora:
-        params = {**params, "lora": _adapters(params)}
-        assert set(params["lora"]) == ({"layers"} if model != "trinity"
-                                       else {"dense_layers", "layers"})
-    B, T, P, T_max = 2, 12, 4, 16
-    ids, valid, pos, tabs, pages = _two_rows(cfg)
-
-    fill = functools.partial(
-        prefill, page_table=tabs, page_size=P, logical_len=T_max)
-    step = functools.partial(decode_step, page_table=tabs, page_size=P)
-    prompt = (ids[:, :T], jnp.asarray(valid[:, :T]))
-
-    def pool():
-        return init_paged_kv_cache(
-            cfg, pages, P, jnp.float32,
-            **({"state_rows": B} if cfg.conv_layers else {}))
-
-    @jax.disable_jit()
-    def served():
-        """(the prefill's and each step's logits, the caches after them),
-        primitive by primitive: the scan then runs its body a trip at a
-        time, and both sides run the same primitives on the same values. (As
-        ONE program a side, XLA's CPU backend fuses the elementwise
-        operations of a loop's body and of the unrolled layers differently,
-        and Trinity's decode step then differs in the last bit, 2e-7.)"""
-        lg, caches = fill(params, cfg, *prompt, pool())
-        out = [lg]
-        km = jnp.zeros((B, T_max), bool).at[:, :T].set(valid[:, :T])
-        for t in range(T, T + 3):
-            km = km.at[:, t].set(True)
-            lg, caches = step(params, cfg, ids[:, t], pos[:, t],
-                              jnp.full((B,), t, jnp.int32), km, caches)
-            out.append(lg)
-        return out, caches
-
-    def scored():
-        """The uncached forward's loss, a NEW function a call: jit and
-        make_jaxpr keep a trace by function, and the second trace has to see
-        the loop."""
-        def loss(p):
-            logits = padded_forward_logits(p, cfg, ids, 0)
-            return jnp.mean(jax.nn.logsumexp(logits, -1) * valid)
-        return loss
-
-    cached = jax.make_jaxpr(fill, static_argnums=1)(
-        params, cfg, *prompt, jax.eval_shape(pool))
-    uncached = jax.make_jaxpr(scored())(params)
-    # the last scan is the expert stack's: two trips, the index its one xs
-    assert _scanned(_scans(cached.jaxpr)[-1]) == [(2,)]
-    xs = _scanned(_scans(uncached.jaxpr)[-1])
-    # every projection kernel is among them, by period (LFM2: its attention
-    # layer's one a period, its conv layers' three)
-    kernels = [s for s in xs if len(s) == 4 and s[0] == 2]
-    assert (2,) in xs and len(kernels) >= 4 + 4 * lora, xs
-    got, got_caches = served()
-    got_grad = jax.jit(jax.grad(scored()))(params)
-    monkeypatch.setattr(M, "_run_pattern_layers", _loop_layers)
-    assert not [e for e in _scans(jax.make_jaxpr(scored())(params).jaxpr)
-                if e.params["length"] == 2]
-    want, want_caches = served()
-    want_grad = jax.jit(jax.grad(scored()))(params)
-    for a, b in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree.leaves(got_caches), jax.tree.leaves(want_caches)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    moved = 0
-    for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=1e-6)
-        moved += bool(np.abs(np.asarray(b)).max() > 0)
-    assert moved > 10
 
 
 @pytest.mark.parametrize("model", ["smallthinker", "lfm2", "trinity", "qwen2"])
