@@ -7,7 +7,9 @@ gated short-convolution layers with a fixed-size state beside attention
 layers, over experts chosen by bias-corrected sigmoid scores (docs/STATE.md),
 or Trinity's (`afmoe`) gated attention over the same window pattern with four
 norms a layer, a leading dense stack inside the pattern and a chip's share of
-bias-selected sigmoid experts beside a shared one (docs/AFMOE.md).
+bias-selected sigmoid experts beside a shared one (docs/AFMOE.md), or SDAR's
+(`sdar_moe`): Qwen3-MoE's layer under a BLOCK-causal mask, generated by
+diffusion over blocks of `block_length` tokens (docs/BLOCKDIFF.md).
 
 The reference loads policies with `AutoModelForCausalLM` (Qwen2.5 models,
 `/root/reference/GRPO/grpo.py:218-224`); this dataclass captures the
@@ -25,6 +27,13 @@ from typing import Optional
 _EXPERT_KEYS = ("num_experts", "num_local_experts", "n_routed_experts",
                 "num_experts_per_tok", "moe_intermediate_size",
                 "n_shared_experts", "shared_expert_intermediate_size")
+
+
+# SDAR's generation defaults (docs/BLOCKDIFF.md "assumed"): the Chat releases'
+# block size, and the id of the `<|MASK|>` token the family adds to Qwen3's
+# vocabulary. No key of the published config.json.
+SDAR_BLOCK_LENGTH = 4
+SDAR_MASK_TOKEN_ID = 151669
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,6 +163,16 @@ class ModelConfig:
     # heads of 64 into pages 128 lanes wide, which is what the paged kernels
     # read (core/model.py `_pack_heads`; docs/STATE.md). 1: a head a row.
     kv_head_pack: int = 1
+    # Generation by diffusion over blocks (SDAR, docs/BLOCKDIFF.md). A
+    # `block_length` B > 0 makes every mask BLOCK-causal (the query at
+    # position i sees the key at position j iff j // B <= i // B, blocks
+    # aligned to absolute positions) and the model generate a block at a
+    # time: forwards over a block's B tokens, `mask_token_id` where one is
+    # still masked (`core/model.block_forward`,
+    # `sampler/paged/session._block_loop`). 0: a causal, autoregressive
+    # model, every other one here.
+    block_length: int = 0
+    mask_token_id: int = -1
     # What the router reads: the MLP's own input (the post-attention normed
     # state), or the layer's PRE-attention normed state (SmallThinker:
     # "router placed before attention").
@@ -280,6 +299,28 @@ class ModelConfig:
     def window_layers(self) -> int:
         """Layers with a sliding window (0 without a pattern)."""
         return sum(k != "conv" and k[0] for k in self.layer_kinds)
+
+    @property
+    def block_generation(self) -> bool:
+        """Tokens are generated a block at a time by unmasking, not one a
+        forward in order (docs/BLOCKDIFF.md): a forward yields 0 to
+        `block_length` tokens a row, and everything that assumes one token
+        a row a step (the contiguous rollout, the trainers, speculation,
+        `decode_step`) refuses the model by name (`refuse_block_generation`)."""
+        return self.block_length > 0
+
+    def refuse_block_generation(self, who: str) -> None:
+        """Raises NotImplementedError, by the model's name, where `who`
+        takes one token a row a forward in order."""
+        if self.block_generation:
+            raise NotImplementedError(
+                f"{who} on a model that generates by diffusion over blocks "
+                f"({self.model_type}, block_length={self.block_length}): a "
+                "forward there yields 0 to block_length tokens a row, each "
+                "conditioned on the tokens of its block unmasked so far, and "
+                "a rollout's log-probability is no product of next-token "
+                "terms; it is served through a serving-mode DecodeSession "
+                "only (docs/BLOCKDIFF.md)")
 
     @property
     def live_rows_dispatch(self) -> bool:
@@ -569,6 +610,49 @@ class ModelConfig:
             experts_offset=experts_offset)
 
     @classmethod
+    def sdar_30b_a3b(cls) -> "ModelConfig":
+        """JetLM/SDAR-30B-A3B-Chat (`model_type: sdar_moe`): 48 layers of
+        Qwen3-MoE's block (GQA 32 / 4 heads of 128, per-head q/k norms, 128
+        softmax experts of width 768, 8 a token, renormalised), generated by
+        diffusion over blocks of 4 (docs/BLOCKDIFF.md)."""
+        return cls(
+            vocab_size=151936,
+            hidden_size=2048,
+            intermediate_size=6144,
+            num_hidden_layers=48,
+            num_attention_heads=32,
+            num_key_value_heads=4,
+            head_dim=128,
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-6,
+            tie_word_embeddings=False,
+            max_position_embeddings=32768,
+            attention_bias=False,
+            model_type="sdar_moe",
+            num_experts=128,
+            num_experts_per_tok=8,
+            norm_topk_prob=True,
+            moe_intermediate_size=768,
+            qk_norm_per_head=True,
+            expert_token_block=4096,
+            block_length=SDAR_BLOCK_LENGTH,
+            mask_token_id=SDAR_MASK_TOKEN_ID,
+        )
+
+    @classmethod
+    def sdar_tiny(cls, vocab_size: int = 512,
+                  block_length: int = 4) -> "ModelConfig":
+        """Test-size SDAR: the same layer at 8 experts of 32, 2 a token,
+        heads of 16, the mask token the vocabulary's last id."""
+        return dataclasses.replace(
+            cls.sdar_30b_a3b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, max_position_embeddings=1024,
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+            expert_token_block=0, block_length=block_length,
+            mask_token_id=vocab_size - 1)
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -609,8 +693,9 @@ class ModelConfig:
     def from_hf_config(cls, hf_config) -> "ModelConfig":
         """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
         (or dict), or from A.X-K1's `config.json` (`model_type: axk1`,
-        DeepSeek-V3's key set; `_axk1_from_hf`), SmallThinker's, LFM2-MoE's
-        or Trinity's (`afmoe`), each by a parser of its own. A config the
+        DeepSeek-V3's key set; `_axk1_from_hf`), SmallThinker's, LFM2-MoE's,
+        Trinity's (`afmoe`) or SDAR's (`sdar_moe`: generation by diffusion
+        over blocks, docs/BLOCKDIFF.md), each by a parser of its own. A config the
         decoder does not implement raises: expert keys under any other model
         type (each family routes, scales and shares in its own way), and a
         non-null `clip_qkv`."""
@@ -630,6 +715,8 @@ class ModelConfig:
             return cls._lfm2_from_hf(get)
         if model_type == "afmoe":
             return cls._afmoe_from_hf(get)
+        if model_type == "sdar_moe":
+            return cls._sdar_from_hf(get)
         # a window on a family this decoder builds with full attention only:
         # Qwen2's `use_sliding_window` (with `sliding_window`, `layer_types`
         # or `max_window_layers`), Mistral's bare `sliding_window`
@@ -676,6 +763,76 @@ class ModelConfig:
             num_experts_per_tok=get("num_experts_per_tok") or 0,
             norm_topk_prob=bool(get("norm_topk_prob", False)),
             qk_norm=olmoe,
+        )
+
+    @classmethod
+    def _sdar_from_hf(cls, get) -> "ModelConfig":
+        """SDAR-MoE's published keys (`model_type: sdar_moe`,
+        docs/BLOCKDIFF.md): Qwen3-MoE's key set. The generation procedure's
+        `block_length` and `mask_token_id` are no keys of the published
+        config.json (the family's `generate.py` takes them as arguments):
+        read where a file states them, else the Chat releases' defaults
+        (`SDAR_BLOCK_LENGTH`, `SDAR_MASK_TOKEN_ID`). What the decoder does
+        not compute raises by name."""
+        who = "model_type='sdar_moe'"
+        if get("use_sliding_window") or get("sliding_window") is not None:
+            raise ValueError(
+                f"{who} with a sliding window (use_sliding_window="
+                f"{get('use_sliding_window')!r}, sliding_window="
+                f"{get('sliding_window')!r}): the block-causal mask is built "
+                "for full attention only")
+        if get("rope_scaling") is not None:
+            raise ValueError(
+                f"{who} with rope_scaling={get('rope_scaling')!r}: the "
+                "family publishes plain rotary embedding (theta 1e6)")
+        if get("mlp_only_layers"):
+            raise ValueError(
+                f"{who} with mlp_only_layers={get('mlp_only_layers')!r}: "
+                "every layer is an expert layer here")
+        if int(get("decoder_sparse_step", 1)) != 1:
+            raise ValueError(
+                f"{who} with decoder_sparse_step="
+                f"{get('decoder_sparse_step')!r}: every layer is an expert "
+                "layer here")
+        if get("attention_bias"):
+            raise ValueError(
+                f"{who} with attention_bias=True: the family's q/k/v carry "
+                "no bias, and per-head norms over a biased projection would "
+                "be another model under its name")
+        if str(get("hidden_act", "silu")) != "silu":
+            raise ValueError(f"{who} with hidden_act={get('hidden_act')!r}")
+        B = int(get("block_length", SDAR_BLOCK_LENGTH))
+        mask_id = int(get("mask_token_id", SDAR_MASK_TOKEN_ID))
+        V = int(get("vocab_size"))
+        if B < 1 or not 0 <= mask_id < V:
+            raise ValueError(
+                f"{who} with block_length={B}, mask_token_id={mask_id}: the "
+                f"block holds at least one token and the mask token is an "
+                f"id of the vocabulary (0..{V - 1})")
+        return cls(
+            vocab_size=V,
+            hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=get("num_hidden_layers"),
+            num_attention_heads=get("num_attention_heads"),
+            num_key_value_heads=get("num_key_value_heads"),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1_000_000.0)),
+            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 32768),
+            attention_bias=False,
+            model_type="sdar_moe",
+            num_experts=int(get("num_experts")),
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            norm_topk_prob=bool(get("norm_topk_prob", True)),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            qk_norm_per_head=True,
+            # a served row's prefill piece or scored row dispatches in
+            # blocks of tokens (a one-chip configuration: `expert_token_block`)
+            expert_token_block=4096,
+            block_length=B,
+            mask_token_id=mask_id,
         )
 
     @classmethod

@@ -4,7 +4,9 @@ A.X-K1's (latent attention, core/mla.py; a leading dense stack, then shared
 plus routed experts), SmallThinker's and Trinity's (an attention PATTERN:
 window layers beside global ones, docs/SWA.md, docs/AFMOE.md) and LFM2's (a
 pattern with a kind that is no attention at all: a gated short convolution
-whose cache is a STATE of fixed size a row, docs/STATE.md), chosen at trace
+whose cache is a STATE of fixed size a row, docs/STATE.md) and SDAR's
+(Qwen3-MoE's layer under a BLOCK-causal mask, generated a block at a time:
+`block_forward`, docs/BLOCKDIFF.md), chosen at trace
 time from the `ModelConfig` and the tree. Every forward is the same few boxes:
 
     _embed
@@ -97,6 +99,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
     hd = config.actual_head_dim
     D, F, V = config.hidden_size, config.intermediate_size, config.vocab_size
     H, KV, L = config.num_attention_heads, config.num_key_value_heads, config.num_hidden_layers
+    if config.num_experts:      # (`moe_intermediate_size`, where a model has one)
+        F = config.expert_width
 
     keys = iter(jax.random.split(key, 16))
 
@@ -144,6 +148,9 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
     if config.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((L, H * hd), dtype)
         params["layers"]["k_norm"] = jnp.ones((L, KV * hd), dtype)
+    if config.qk_norm_per_head:     # over each head's `hd` (`_attention`)
+        params["layers"]["q_norm"] = jnp.ones((L, hd), dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, hd), dtype)
     return params
 
 
@@ -693,17 +700,19 @@ def _cache_write(stacks, news, layer, view):
     exact, or (k_q, k_s, v_q, v_s) int8, whose odd members are scale arrays
     (sequence on the last axis). A `view` with a table routes the write
     through it; one with a `write_plan` is a decode step's on a TPU
-    (`_kind_views`): its one slot a row goes through ops/paged_cache_write,
-    K and V in one call. Returns the updated stacks. Under the scope
-    `attn.write`."""
+    (`_kind_views`): a plan a new slot of the row (one, or a block
+    forward's `block_length`), the live rows' slot i through
+    ops/paged_cache_write, K and V in one call, each in turn. Returns the
+    updated stacks. Under the scope `attn.write`."""
     out = []
     with jax.named_scope("attn.write"):
         if view.write_plan is not None:
             from nanorlhf_tpu.ops.paged_cache_write import paged_row_write
 
-            return tuple(paged_row_write(
-                *stacks, news[0][:, :, 0], news[1][:, :, 0], layer,
-                view.write_plan))
+            for i, plan in enumerate(view.write_plan):
+                stacks = tuple(paged_row_write(
+                    *stacks, news[0][:, :, i], news[1][:, :, i], layer, plan))
+            return stacks
         for i, (stack, new) in enumerate(zip(stacks, news)):
             is_scale = view.cache == "int8" and i % 2 == 1
             if view.table is not None:
@@ -853,13 +862,15 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
 
         experts = expert_stack or layer_params["experts"]
         routing = {}    # OLMoE's uncached and prefill programs pass none
-        listening = live is not None and h.shape[1] == 1
+        # (a decode step's one token a row, or a block forward's block)
+        listening = live is not None and (
+            h.shape[1] == 1 or config.block_generation)
         if config.experts_held or listening:
             # a chip's share of the routed experts; a decode step that
             # knows its listeners holds "all of them" the same way
             routing["held"] = (config.num_held_experts, config.experts_offset)
         if listening:   # the rows without a request are not dispatched
-            routing["live"] = live[:, None]
+            routing["live"] = jnp.broadcast_to(live[:, None], h.shape[:2])
         if config.expert_activation != "silu":
             routing["activation"] = config.expert_activation
         if config.scoring_func != "softmax" or config.routed_scaling_factor != 1.0:
@@ -912,7 +923,7 @@ class KindView(NamedTuple):
     table: object = None        # block table [B, nb] int32 (paged), or the
                                 # state group's rows [B, 1]
     page_size: int = 0
-    write_plan: object = None   # a decode step's `PagedWritePlan` on a TPU
+    write_plan: object = None   # a decode step's `PagedWritePlan`s on a TPU
     conv_ctx: tuple | None = None   # the state group's `(valid, fresh)`
     live: object = None         # [B] bool: the rows someone listens to
 
@@ -1282,6 +1293,23 @@ def attention_form(config: ModelConfig, T: int, *, cached: bool,
     # and has no block bound for a window: inside its window a window layer
     # is a causal one, past it the kernel does not apply
     flash = (T > 1 and not (window and T > window) and use_flash(impl, T))
+    if config.block_generation:
+        # a BLOCK-causal model (docs/BLOCKDIFF.md): neither flash kernel
+        # takes a block length (both rebuild causal from the tokens at
+        # hand), so every read of T > 1 tokens is XLA's, under the call's
+        # own mask: in blocks of queries uncached, the walk of the key
+        # blocks over pages. A block forward (`decode` bounds with T > 1:
+        # the block's queries all see ONE key set, the committed prefix and
+        # the block's own slots) rides the in-place paged decode kernel on
+        # a TPU, its queries on the kernel's group axis
+        if not cached:
+            return "query_blocks"
+        if decode and T > 1:
+            return ("paged_block" if paged and use_paged_decode_kernel(config)
+                    else "view")
+        if verify and paged:
+            return "paged_walk"
+        return "view"
     if not cached:
         if flash:
             return "flash"
@@ -1399,6 +1427,19 @@ def _attention_read(config, q, k, v, view, new_cache, layer, window, spmd):
 
         return on_mesh(decode_verify_attention, (q, *slabs(), *view.verify),
                        (1, 1, 1, None, None))
+    if form == "paged_block":
+        # the block's T queries see one key set: a KV head's G x T query
+        # rows go as that head's group through the decode kernel, over the
+        # rows' live pages in place (`view.decode`: the forward's work list)
+        from nanorlhf_tpu.ops.decode_attention import paged_decode_attention
+
+        B, H, _, hd = q.shape
+        KV = new_cache[0].shape[2]
+        with jax.named_scope("attn.block"):
+            out = paged_decode_attention(
+                q.reshape(B, KV, H // KV, T, hd).reshape(B, H * T, hd),
+                *new_cache, layer, view.decode)
+        return out.reshape(B, H, T, hd)
     if form == "paged_decode":
         # `view.decode` is the step's work list (`_kind_views` made it
         # under the same rule)
@@ -1412,7 +1453,11 @@ def _attention_read(config, q, k, v, view, new_cache, layer, window, spmd):
         return on_mesh(decode_attention,
                        (q[:, :, 0, :], *slabs(), *view.decode),
                        (1, 1, 1, None, None))[:, :, None, :]
-    return gqa_attention(q, *views(mask.shape[-1]), mask)
+    # (the block read's plain form carries the block read's scope)
+    block_read = config.block_generation and view.decode is not None and T > 1
+    with (jax.named_scope("attn.block") if block_read
+          else contextlib.nullcontext()):
+        return gqa_attention(q, *views(mask.shape[-1]), mask)
 
 
 # pages a key block of the pattern model's T > 1 paged read holds (1,024 keys
@@ -1751,7 +1796,7 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
 
 def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
                 decode=None, verify=None, page_table=None, page_size=0,
-                live=None, conv_ctx=None) -> tuple:
+                live=None, conv_ctx=None, write_at=None) -> tuple:
     """The call's `KindView` a cache group, from what its entrypoint knows:
     one for a model without a pattern, else `(global, window[, state])`.
 
@@ -1773,7 +1818,9 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
     pages under `use_paged_decode_kernel`, sizes the step's plans, made once
     here for every layer: the in-place read's work list a kind (its table,
     its pool, its bound) and the live-row write's where the pool's shapes
-    take it (`_paged_row_kernel_takes`; elsewhere the row scatter).
+    take it (`_paged_row_kernel_takes`; elsewhere the row scatter), a plan
+    a slot of `write_at` (`(index,)`, or a block forward's `block_length`
+    slots from `index` on).
     `conv_ctx`: a thunk of `_conv_ctx(...)`, called last (the operations
     stand in the program in the order the entrypoints always staged them:
     the decode bounds, the masks, the verify bounds, the plans, the state's
@@ -1829,9 +1876,10 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
             num_pages=group[0].shape[1],
             pages_per_item=paged_pages_per_item(group[0]), live=live)
             for table, group, (first, _) in zip(tables, groups, decodes)]
-        plans = [paged_write_plan(
-            table, index, page_size=page_size, num_pages=group[0].shape[1],
-            live=live) if _paged_row_kernel_takes(group, page_size) else None
+        plans = [tuple(paged_write_plan(
+            table, at, page_size=page_size, num_pages=group[0].shape[1],
+            live=live) for at in write_at or (index,))
+            if _paged_row_kernel_takes(group, page_size) else None
             for table, group in zip(tables, groups)]
     ctx = None if conv_ctx is None else conv_ctx().get("conv_ctx")
 
@@ -1933,8 +1981,12 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     x = _embed(config, params, input_ids)
     T = input_ids.shape[1]
     cos, sin = _rope(config, position_ids)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
+    if config.block_generation:
+        mask = (_block_causal(config, position_ids)
+                & attention_mask[:, None, None, :])
+    else:
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
     views = _kind_views(config, mask, lambda: jnp.arange(T)[None, :],
                         conv_ctx=lambda: _conv_ctx(config, attention_mask))
     x, _, aux = _run_layers(config, params, x, cos, sin, views,
@@ -1945,6 +1997,18 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
 
         return x, reduce_stats(aux, attention_mask, config.num_experts)
     return x
+
+
+def _block_causal(config: ModelConfig, positions, key_positions=None):
+    """The block-causal mask of a model that generates by blocks
+    (docs/BLOCKDIFF.md), `[B, 1, Tq, Tk]` bool: the query at position i
+    sees the key at position j iff `j // block <= i // block`; blocks are
+    aligned to ABSOLUTE positions, wherever a left-padded row's tokens lie.
+    `positions` [B, Tq] are the queries' (and, without `key_positions`
+    [B, Tk], the keys')."""
+    B_ = config.block_length
+    keys = positions if key_positions is None else key_positions
+    return ((keys // B_)[:, None, None, :] <= (positions // B_)[:, None, :, None])
 
 
 def _conv_ctx(config: ModelConfig, valid=None, fresh=None) -> dict:
@@ -2303,9 +2367,13 @@ def prefill(
     position_ids = jnp.cumsum(attention_mask, axis=1) - attention_mask.astype(jnp.int32)
     x = _embed(config, params, jnp.where(attention_mask, input_ids, 0))
     cos, sin = _rope(config, position_ids)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    # queries attend over cache positions [0, T); the rest of T_max is masked
-    mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
+    if config.block_generation:
+        mask = (_block_causal(config, position_ids)
+                & attention_mask[:, None, None, :])
+    else:
+        causal = jnp.tril(jnp.ones((T, T), bool))
+        # queries attend over cache positions [0, T); the rest of T_max is masked
+        mask = (causal[None, None, :, :] & attention_mask[:, None, None, :])
     mask_full = jnp.zeros((B, 1, T, T_max), bool).at[:, :, :, :T].set(mask)
     views = _kind_views(
         config, mask_full, lambda: jnp.arange(T)[None, :], kv_caches=kv_caches,
@@ -2347,6 +2415,7 @@ def decode_step(
     """One autoregressive decode step. Returns (logits [B, V], new caches),
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
     over the layers."""
+    config.refuse_block_generation("decode_step (one token a row a step)")
     B = token.shape[0]
     if extent is not None and extent < key_mask.shape[1]:
         # the mask's width is what the XLA read goes by (`_attention_read`); the
@@ -2433,7 +2502,18 @@ def decode_verify(
     cos, sin = _rope(config, positions)
     slot = jnp.arange(T_max)[None, None, :]                  # [1, 1, T_max]
     qi = jnp.arange(Tq)[None, :, None]                       # [1, Tq, 1]
-    cand = (slot >= fill[:, None, None]) & (slot <= fill[:, None, None] + qi)
+    if config.block_generation:
+        # block-causal inside the candidates (a prompt's piece or suffix,
+        # docs/BLOCKDIFF.md): query i sees the candidates up to the END of
+        # its own block, by absolute position
+        bl = config.block_length
+        ends = (positions // bl + 1) * bl - positions[:, :1]     # [B, Tq]
+        cand = ((slot >= fill[:, None, None])
+                & (slot < fill[:, None, None]
+                   + jnp.minimum(ends, Tq)[:, :, None]))
+    else:
+        cand = ((slot >= fill[:, None, None])
+                & (slot <= fill[:, None, None] + qi))
     mask = (key_mask[:, None, :] | cand)[:, None, :, :]      # [B, 1, Tq, T_max]
     # first valid slot; a row with no valid prefix (a cold serving admission
     # starts at its first real token) begins at its own candidates
@@ -2452,3 +2532,71 @@ def decode_verify(
     if not want_logits:
         return None, new_caches
     return _logits(config, params, x), new_caches
+
+
+def block_forward(
+    params: dict,
+    config: ModelConfig,
+    tokens: jnp.ndarray,          # [B, Tb] the block: its tokens unmasked so
+                                  # far, `mask_token_id` elsewhere
+    positions: jnp.ndarray,       # [B, Tb] their absolute position ids
+    fill: jnp.ndarray,            # [B] cache slot of tokens[:, 0] (per-row)
+    key_mask: jnp.ndarray,        # [B, T_max] the COMMITTED slots (the
+                                  # prompt's whole blocks and every block
+                                  # committed since: one range a row)
+    kv_caches: tuple[jnp.ndarray, ...],
+    lora_scale: float = 1.0,
+    page_table=None,              # [B, nb] int32 (paged layout)
+    page_size: int = 0,
+    live=None,                    # [B] bool: rows whose logits the caller
+                                  # uses (None: all)
+    count_experts: bool = False,
+):
+    """One forward of a model that generates by blocks (docs/BLOCKDIFF.md)
+    over ONE block a row, `decode_verify`'s successor for it: the block's
+    Tb = `block_length` tokens are written at slots `[fill, fill + Tb)` and
+    every query sees the committed slots (`key_mask`) plus ALL Tb slots of
+    its block, so the queries of a row share one key set. The write is
+    PROVISIONAL: the caller leaves `key_mask` alone after a denoise forward
+    (the next forward of the block overwrites the same slots with the
+    tokens as they then stand) and marks the Tb slots valid only after the
+    COMMIT forward, run on the fully unmasked block. Rows stand at different
+    steps of different blocks; a row not `live` reads nothing, reaches no
+    expert and its logits are discarded. Returns `(logits [B, Tb, V], new
+    caches)`: position i's logits predict position i's OWN token (no
+    shift); with `count_experts` also the held experts the live rows
+    reached, summed over the layers."""
+    if not config.block_generation:
+        raise ValueError("block_forward is the forward of a model that "
+                         "generates by blocks (config.block_length > 0)")
+    B, Tb = tokens.shape
+    T_max = key_mask.shape[1]
+    key_mask = key_mask.astype(bool)
+    fill = fill.astype(jnp.int32)
+    x = _embed(config, params, tokens)
+    cos, sin = _rope(config, positions)
+    slot = jnp.arange(T_max)[None, :]
+    block = (slot >= fill[:, None]) & (slot < fill[:, None] + Tb)
+    mask = jnp.broadcast_to((key_mask | block)[:, None, None, :],
+                            (B, 1, Tb, T_max))
+    # one range a row: from its first committed slot (its own block's first,
+    # where nothing is committed yet) to the block's last
+    start = jnp.where(key_mask.any(axis=1), jnp.argmax(key_mask, axis=1),
+                      fill).astype(jnp.int32)
+    views = _kind_views(
+        config, mask, lambda: fill[:, None] + jnp.arange(Tb)[None, :],
+        kv_caches=kv_caches, index=fill, decode=(start, fill + Tb),
+        page_table=page_table, page_size=page_size, live=live,
+        # the block's write on a TPU: the live rows' slot i, for each of
+        # the block's Tb slots, through the decode step's live-row kernel
+        # (the row scatter elsewhere: at 4 tokens x 4 heads a row it is
+        # what `_paged_cache_update` picks, and on the chip it ran under no
+        # scope at 0.87 ms a forward, PERF.md PR 46)
+        write_at=tuple(fill + i for i in range(Tb)))
+    x, new_caches, aux = _run_layers(
+        config, params, x, cos, sin, views, kv_caches, lora_scale,
+        cached_aux=count_experts)
+    logits = _logits(config, params, x)
+    if count_experts:
+        return logits, new_caches, jnp.sum(aux["reached"])
+    return logits, new_caches

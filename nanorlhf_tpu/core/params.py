@@ -95,7 +95,10 @@ def _layer_keys(config: ModelConfig):
     """(linear, norm) name pairs of the layer tree this config builds: an
     expert model's MLP is not among the per-layer Linears."""
     linear = _ATTENTION_KEYS if config.num_experts else _LINEAR_KEYS
-    norm = _NORM_KEYS + (_QK_NORM_KEYS if config.qk_norm else ())
+    # OLMoE's q/k norms (over the projection's width) and SDAR's (Qwen3-MoE's
+    # names, a weight of `head_dim` a layer: assumed, docs/BLOCKDIFF.md)
+    norm = _NORM_KEYS + (_QK_NORM_KEYS if config.qk_norm
+                         or config.model_type == "sdar_moe" else ())
     return linear, norm
 
 
@@ -490,13 +493,14 @@ def export_hf_checkpoint(
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
         "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe",
-        "afmoe") else (
+        "afmoe", "sdar_moe") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
             "smallthinker": "SmallThinkerForCausalLM",
             "lfm2_moe": "Lfm2MoeForCausalLM",
-            "afmoe": "AfmoeForCausalLM"}[family]
+            "afmoe": "AfmoeForCausalLM",
+            "sdar_moe": "SDARMoeForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -587,6 +591,18 @@ def export_hf_checkpoint(
             sliding_window_layout=list(config.sliding_window_layout
                                        or (0,) * L),
             rope_layout=list(config.rope_layout or (1,) * L))
+    elif family == "sdar_moe":
+        hf_config.update(
+            num_experts=config.num_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            norm_topk_prob=config.norm_topk_prob, decoder_sparse_step=1,
+            mlp_only_layers=[], rope_scaling=None, sliding_window=None,
+            use_sliding_window=False,
+            # the generation procedure's, which the published file leaves
+            # to the family's script (docs/BLOCKDIFF.md)
+            block_length=config.block_length,
+            mask_token_id=config.mask_token_id)
     elif config.num_experts:
         hf_config.update(num_experts=config.num_experts,
                          num_experts_per_tok=config.num_experts_per_tok,

@@ -186,6 +186,7 @@ def generate_tokens_queued(
     `swap_installs`, and `swap_wait_s`. With no mid-rollout publish the
     poll returns None every chunk and the token stream is bit-identical
     to `weight_refresh=None` (the PRNG stream never sees the callback)."""
+    config.refuse_block_generation("the paged rollout scheduler")
     Q, Tp = prompt_ids.shape
     R = min(int(decode_rows), Q)
     P = int(page_size)

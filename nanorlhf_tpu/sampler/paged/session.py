@@ -40,6 +40,15 @@ measured on a chip but what `serve-1.5b-chat` runs):
     arguments of the shared chunk body instead of a private carry layout;
     one compiled decode program serves rollout and gateway traffic.
 
+  * **generation by blocks** (docs/BLOCKDIFF.md) — a model whose config
+    says `block_generation` is served by a THIRD chunk program,
+    `_block_loop`: a forward runs one block of `block_length` tokens a row
+    (`core/model.block_forward`), rows standing at different denoise steps
+    of different blocks, and yields 0 to `block_length` tokens a row. Its
+    carry is the base carry (slot 2 holds, a token, the denoise step that
+    unmasked it; `n_gen` counts a row's FINAL tokens, its longest in-order
+    run of unmasked ones) and five slots more (`_BLOCK_SLOTS`).
+
 Carry layout (identical to the pre-session scheduler, which is what keeps
 every greedy stream bit-identical through the refactor):
 
@@ -70,13 +79,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from nanorlhf_tpu.core.model import (
-    attention_form, decode_step, decode_verify, leaves_in_place,
-    paged_write_forms, prefill,
+    attention_form, block_forward, decode_step, decode_verify,
+    leaves_in_place, paged_write_forms, prefill,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
     PageState, RingPages, alloc_row, blocks_per_row, full_table, release_row,
     ring_blocks,
+)
+from nanorlhf_tpu.sampler.blockdiff import (
+    REMASKING, choose_unmask, sample_positions, transfer_count,
 )
 from nanorlhf_tpu.sampler.sampler import (
     _nucleus_candidates,
@@ -277,6 +289,168 @@ def _beat_report(it, out, done, n_gen, hit, *, width):
     return jnp.stack([it, hit]), rows
 
 
+# a block session's carry past the base ten (docs/BLOCKDIFF.md "row state"):
+#   blk [R, B] int32     the row's current block: its tokens unmasked so far
+#   masked [R, B] bool   which of its positions are still masked
+#   step [R] int32       denoise forwards the row has run on this block
+#   base [R] int32       the block's first position (a multiple of B; its
+#                        cache slot is `Tp - prompt_len + base`)
+#   counts [5] int32     never reset: live row-forwards, commit forwards,
+#                        tokens unmasked, blocks fully unmasked, pages the
+#                        live rows' block reads spanned
+_BLOCK_SLOTS = 5
+_BLOCK_STATIC = ("config", "Tp", "page_size", "sync_every", "eos_token_id",
+                 "lora_scale", "top_k", "approx_top_k")
+
+
+@jax.named_scope("decode")
+def _block_body(params, config, s, table, row_params, *, Tp, page_size,
+                eos_token_id, lora_scale, top_k, approx_top_k):
+    """One forward of generation by blocks over the session carry
+    (docs/BLOCKDIFF.md): every live row runs its current block through
+    `block_forward`. A row whose block still has masked positions takes a
+    DENOISE forward: a token is sampled at every position, its confidence
+    taken, and the row's strategy unmasks some of the masked ones
+    (`sampler/blockdiff.py`); the block's K/V just written stay PROVISIONAL
+    (`key_mask` is left alone: the next forward writes the same slots
+    again). A row whose block has no mask left takes its COMMIT forward:
+    the K/V written are final, the block's slots become valid, the row ends
+    if the block holds EOS inside its budget or reaches the budget, else
+    its next block opens all masked. `row_params`: the traced `[R]`
+    (temperature, top_p, greedy, budget, denoising steps, remasking)."""
+    (it, out, rec, caches, key_mask, done, cur_tok, n_gen, prompt_len, key,
+     blk, masked, step, base, counts) = s
+    r_temp, r_topp, r_greedy, r_budget, r_steps, r_remask = row_params
+    R, B = blk.shape
+    W = out.shape[1]
+    rows = jnp.arange(R)
+    live = ~done
+    fill = Tp - prompt_len + base                       # [R] the block's slot
+    within = jnp.arange(B, dtype=jnp.int32)[None, :]
+    tokens = jnp.where(masked, config.mask_token_id, blk)
+    logits, caches, reached = block_forward(
+        params, config, tokens, base[:, None] + within, fill, key_mask,
+        caches, lora_scale=lora_scale, page_table=table, page_size=page_size,
+        live=live, count_experts=True)
+    open_ = masked.any(axis=1)
+    denoise, commit = live & open_, live & ~open_
+    tok, conf = sample_positions(
+        jax.random.fold_in(key, it), logits, r_temp, r_topp, r_greedy,
+        top_k=top_k, approx_top_k=approx_top_k)
+    # (inside `sample`: the benchmark's scope reduction keeps the families
+    # `attn.`, `moe.`, `mla.` and reads this as its parent's)
+    with jax.named_scope("sample"), jax.named_scope("sample.unmask"):
+        chosen = denoise[:, None] & choose_unmask(
+            conf, masked, transfer_count(step, r_steps, B), r_remask)
+        blk = jnp.where(chosen, tok, blk)
+        masked = masked & ~chosen
+        gen = (base - prompt_len)[:, None] + within     # index among the new
+        at = jnp.where(chosen, gen, W)                  # (a tail's is < 0:
+        out = out.at[rows[:, None], at].set(blk, mode="drop")   # never chosen)
+        rec = rec.at[rows[:, None], at].set(
+            jnp.broadcast_to(step[:, None], (R, B)), mode="drop")
+        step = step + denoise.astype(jnp.int32)
+        closed = denoise & ~masked.any(axis=1)
+        # the commit: the block's slots are valid from here on
+        slot = jnp.arange(key_mask.shape[1], dtype=jnp.int32)[None, :]
+        key_mask = key_mask | (commit[:, None] & (slot >= fill[:, None])
+                               & (slot < fill[:, None] + B))
+        ends = ((gen >= 0) & (gen < r_budget[:, None])
+                & (blk == eos_token_id)).any(axis=1)
+        base = base + B * commit.astype(jnp.int32)
+        done = done | (commit & (ends | (base - prompt_len >= r_budget)))
+        blk = jnp.where(commit[:, None], config.mask_token_id, blk)
+        masked = masked | commit[:, None]
+        step = jnp.where(commit, 0, step)
+        # a row's final tokens: everything before its block, and the
+        # block's leading run of unmasked positions
+        lead = jnp.sum(jnp.cumprod(~masked, axis=1), axis=1, dtype=jnp.int32)
+        n_gen = jnp.where(live, jnp.maximum(base - prompt_len + lead, 0),
+                          n_gen)
+        first = jnp.where(key_mask.any(axis=1), jnp.argmax(key_mask, axis=1),
+                          fill)
+        pages = jnp.where(live, (fill + B - 1) // page_size
+                          - first // page_size + 1, 0)
+        counts = counts + jnp.stack([
+            jnp.sum(live, dtype=jnp.int32), jnp.sum(commit, dtype=jnp.int32),
+            jnp.sum(chosen, dtype=jnp.int32), jnp.sum(closed, dtype=jnp.int32),
+            jnp.sum(pages, dtype=jnp.int32)])
+    carry = (it + 1, out, rec, caches, key_mask, done, cur_tok, n_gen,
+             prompt_len, key, blk, masked, step, base, counts)
+    return carry, reached
+
+
+def _block_loop(params, config, state, table, row_params, statics):
+    """Up to `sync_every` forwards of generation by blocks; exits early once
+    every resident row is done. Returns `(carry, held experts the live rows
+    reached)`, as `_chunk_loop` does for a model with expert layers."""
+    statics = dict(statics)
+    sync_every = statics.pop("sync_every")
+
+    def body(cs):
+        c, s, hit = cs
+        s, reached = _block_body(params, config, s, table, row_params,
+                                 **statics)
+        return c + 1, s, hit + reached
+
+    _, state, hit = jax.lax.while_loop(
+        lambda cs: (cs[0] < sync_every) & ~jnp.all(cs[1][5]), body,
+        (jnp.int32(0), state, jnp.int32(0)))
+    return state, hit
+
+
+@partial(jit_donating, donate=2, static_argnames=_BLOCK_STATIC)
+def _block_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
+                 r_budget, r_steps, r_remask, **statics):
+    """Serving-mode chunk of a model that generates by blocks."""
+    return _block_loop(params, config, state, table,
+                       (r_temp, r_topp, r_greedy, r_budget, r_steps,
+                        r_remask), statics)
+
+
+@partial(jax.jit, static_argnames=("width",))
+@jax.named_scope("install")
+def _block_report(it, out, rec, done, n_gen, hit, counts, *, width):
+    """`_beat_report` for a block session: `[it, hit, *counts]` and, a row,
+    `done · n_gen · the last `width` FINAL tokens · the denoise step that
+    unmasked each` (right-aligned at `n_gen`, the row's count of final
+    tokens: a chunk of `sync_every` forwards finalises at most `width`)."""
+    cols = n_gen[:, None] - width + jnp.arange(width, dtype=jnp.int32)[None]
+    cols = jnp.clip(cols, 0, out.shape[1] - 1)
+    rows = jnp.concatenate(
+        [done[:, None].astype(jnp.int32), n_gen[:, None],
+         jnp.take_along_axis(out, cols, axis=1),
+         jnp.take_along_axis(rec, cols, axis=1)], axis=1)
+    return jnp.concatenate([jnp.stack([it, hit]), counts]), rows
+
+
+@partial(jax.jit, static_argnames=("Tp", "pad_token_id", "mask_token_id"))
+@jax.named_scope("install")
+def _install_block_row(state, r, plen, whole, tail, *, Tp, pad_token_id,
+                       mask_token_id):
+    """Row `r` of a block session's carry for a freshly admitted prompt of
+    `plen` tokens whose `whole` leading tokens (its whole blocks) are
+    prefilled: those slots valid, nothing generated, and the first block
+    open with the prompt's tail `tail` [B] (its first `plen - whole`
+    entries) already unmasked. `state` comes `_sans_pool`."""
+    s = list(state)
+    W, T_mask, B = s[1].shape[1], s[4].shape[1], s[10].shape[1]
+    slot = jnp.arange(T_mask, dtype=jnp.int32)
+    within = jnp.arange(B, dtype=jnp.int32)
+    s[1] = s[1].at[r].set(jnp.full((W,), pad_token_id, jnp.int32))
+    s[2] = s[2].at[r].set(jnp.zeros((W,), s[2].dtype))
+    s[4] = s[4].at[r].set((slot >= Tp - plen) & (slot < Tp - plen + whole))
+    s[5] = s[5].at[r].set(False)
+    s[7] = s[7].at[r].set(jnp.int32(0))
+    s[8] = s[8].at[r].set(plen)
+    is_tail = within < plen - whole
+    s[10] = s[10].at[r].set(jnp.where(is_tail, tail, mask_token_id))
+    s[11] = s[11].at[r].set(~is_tail)
+    s[12] = s[12].at[r].set(jnp.int32(0))
+    s[13] = s[13].at[r].set(whole)
+    return tuple(s)
+
+
 _SPEC_CHUNK_STATIC = _CHUNK_STATIC + ("spec_k", "spec_ngram")
 
 
@@ -465,6 +639,11 @@ class _PendingPrefill:
     greedy: bool = False
     row_table: Optional[np.ndarray] = None  # non-radix: device row snapshot
     meta: dict = field(default_factory=dict)
+    end: int = 0                          # one past the last slot to prefill
+                                          # (Tp; a block session: the
+                                          # prompt's whole blocks' end)
+    denoising_steps: int = 0              # a block session's request params
+    remasking: int = 0
 
 
 @dataclass
@@ -483,6 +662,9 @@ class BeatReport:
     serving-mode session also gives `n_gen` (tokens a row has so far),
     `tokens` (each row's last `width`, right-aligned at `n_gen`) and
     `current`: the rows that still hold the request the chunk ran for. A
+    block session's `n_gen` counts a row's FINAL tokens (its longest in-order
+    run of unmasked ones: a token once unmasked never changes), so a beat
+    carries 0 to `sync_every x block_length` new tokens a row. A
     report read one beat late (`dispatch`) says nothing of a row that was
     released, cancelled or admitted into since its chunk was dispatched."""
     its: int
@@ -490,11 +672,19 @@ class BeatReport:
     n_gen: Optional[np.ndarray] = None
     tokens: Optional[np.ndarray] = None
     current: Optional[np.ndarray] = None
+    steps: Optional[np.ndarray] = None      # a block session: beside each of
+                                            # `tokens`, the denoise step of
+                                            # its block that unmasked it
 
     def new_tokens(self, r: int, since: int) -> np.ndarray:
         """Row `r`'s tokens from its `since`-th on."""
         new = int(self.n_gen[r]) - int(since)
         return self.tokens[r, self.tokens.shape[1] - new:]
+
+    def new_steps(self, r: int, since: int) -> np.ndarray:
+        """`new_tokens`' tokens' denoise steps (a block session)."""
+        new = int(self.n_gen[r]) - int(since)
+        return self.steps[r, self.steps.shape[1] - new:]
 
 
 @dataclass
@@ -513,6 +703,37 @@ class _Flight:
     hit: Optional[jax.Array] = None
     meta: Optional[jax.Array] = None
     rows: Optional[jax.Array] = None
+
+
+def _refuse_block_compositions(config, *, per_row, spec_k, prefix_cache,
+                               prefill_chunk) -> None:
+    """What a session of a model that generates by blocks is not built for,
+    by the model's name (docs/BLOCKDIFF.md "what is left")."""
+    what = (f"a model that generates by diffusion over blocks "
+            f"({config.model_type}, block_length={config.block_length})")
+    if spec_k:
+        config.refuse_block_generation(
+            f"speculative decode (spec_k={spec_k})")
+    if not per_row:
+        config.refuse_block_generation(
+            "the paged rollout scheduler (per_row=False)")
+    if prefix_cache is None or not getattr(prefix_cache, "enabled", False):
+        raise NotImplementedError(
+            f"{what} decodes in a serving session whose pages the engine's "
+            "RadixCache hands out (it takes no hit there)")
+    if config.kv_cache_quant == "int8":
+        raise NotImplementedError(
+            f"kv_cache_quant='int8' on {what}: the block read has no int8 "
+            "form (docs/BLOCKDIFF.md)")
+    if config.spmd_mesh is not None:
+        raise NotImplementedError(
+            f"a mesh under a serving session of {what}: the block read and "
+            "the rows' block state have no partitioned form")
+    if prefill_chunk % config.block_length:
+        raise ValueError(
+            f"prefill_chunk={prefill_chunk} on {what}: a prefill piece ends "
+            "on a block's end, so the chunk is a multiple of the block "
+            "length")
 
 
 class DecodeSession:
@@ -590,6 +811,13 @@ class DecodeSession:
                  per_row=False, spec_k=0, spec_ngram=3, prefix_cache=None,
                  prefill_chunk=0, sync_every=8, latency=None,
                  admit_key=None):
+        # a model that generates by blocks (docs/BLOCKDIFF.md): its block
+        # length, 0 for every autoregressive model
+        self.block = int(config.block_length)
+        if self.block:
+            _refuse_block_compositions(
+                config, per_row=per_row, spec_k=spec_k,
+                prefix_cache=prefix_cache, prefill_chunk=prefill_chunk)
         if per_row and capture_logprobs:
             raise ValueError(
                 "capture_logprobs is incompatible with per-row sampling "
@@ -626,7 +854,9 @@ class DecodeSession:
         # another thread
         self.timer = PhaseTimer(span_prefix="session.", names=SESSION_PHASES)
 
-        self.T_max = self.Tp + self.max_tokens
+        # (a block session's last block may reach past the budget: its
+        # slots are written and read like any block's, so they are real)
+        self.T_max = self.Tp + self.max_tokens + self.block
         self.nb = blocks_per_row(self.T_max, self.page_size)
         # a model with window layers (docs/SWA.md): a second pool and table
         # for them, a ring of pages a row
@@ -728,8 +958,10 @@ class DecodeSession:
         # empty carry: every row starts done; admit() installs rows
         # through the same path mid-loop admissions use
         self.state = self._carry(
-            jnp.full((R, self.max_tokens), self.pad_token_id, jnp.int32),
-            jnp.zeros((R, self.max_tokens), jnp.float32),
+            jnp.full((R, self.max_tokens + self.block), self.pad_token_id,
+                     jnp.int32),
+            jnp.zeros((R, self.max_tokens + self.block),
+                      jnp.int32 if self.block else jnp.float32),
             caches0,
             jnp.zeros((R, self.T_max), bool),
             jnp.ones((R,), bool),
@@ -778,8 +1010,9 @@ class DecodeSession:
         # a serving-mode report carries the tokens a chunk can have written a
         # row: one an iteration, or a whole row's where an iteration emits
         # several (speculation; its release wants the whole stream too)
-        self._report_width = (self.max_tokens if self.spec
-                              else min(int(sync_every), self.max_tokens))
+        self._report_width = (
+            self.max_tokens if self.spec
+            else min(int(sync_every) * max(self.block, 1), self.max_tokens))
         self._no_hit = jnp.int32(0)
         self._t_report = 0.0
         # beats dispatched while an earlier chunk's report was unread, and
@@ -802,6 +1035,20 @@ class DecodeSession:
         if self.spec:
             self._statics.update(spec_k=self.spec_k,
                                  spec_ngram=self.spec_ngram)
+        if self.block:
+            self._block_statics = dict(
+                Tp=self.Tp, page_size=self.page_size,
+                sync_every=int(sync_every), eos_token_id=self.eos_token_id,
+                lora_scale=lora_scale, top_k=top_k,
+                approx_top_k=approx_top_k)
+        # a block session's requests: denoise steps a block and the index
+        # of the strategy (`blockdiff.REMASKING`); and its counters, the
+        # carry's own as the last read brought them (`_BLOCK_SLOTS`), with
+        # the prompt tokens that opened a first block already unmasked
+        self._steps_np = np.full((R,), max(self.block, 1), np.int32)
+        self._remask_np = np.zeros((R,), np.int32)
+        self.block_counts = np.zeros((5,), np.int64)
+        self.prompt_tail_tokens = 0
 
         # per-row sampling params (serving mode): host-of-record arrays,
         # uploaded as traced chunk arguments — the values the pre-session
@@ -856,7 +1103,9 @@ class DecodeSession:
             attention_form, config, cached=True, paged=True,
             cache_len=self.T_max)
         self.attn_in_place = int(
-            not self.spec and paged_read(1, decode=True) == "paged_decode")
+            not self.spec and paged_read(
+                max(self.block, 1), decode=True) in ("paged_decode",
+                                                     "paged_block"))
         # ... and, of the forwards dispatched for chunked admissions
         # (`_prefill_tick`: the pieces and each one's closing suffix
         # forward), whether their T > 1 paged read is the flash kernel over
@@ -870,7 +1119,8 @@ class DecodeSession:
         self.kv_write_by_page, live_rows = paged_write_forms(
             config, caches0, self.page_size, self.prefill_chunk or self.Tp,
             self.nb)
-        self.kv_write_live_rows = int(live_rows and not self.spec)
+        self.kv_write_live_rows = int(live_rows and not self.spec
+                                      and not self.block)
         # whether a layer takes its kernels by index into the whole stacks
         # (`core/model.leaves_in_place`, the layer runner's rule: every
         # cached forward of a pattern model; a model without a pattern scans
@@ -906,6 +1156,12 @@ class DecodeSession:
             # (speculative._spec_state)
             state += tuple(jnp.int32(0) for _ in range(4)) + (
                 jnp.zeros((R,), jnp.int32),)
+        if self.block:      # blk · masked · step · base · counts
+            B = self.block
+            state = (state[:7] + (jnp.zeros((R,), jnp.int32),) + state[8:] + (
+                jnp.full((R, B), self.config.mask_token_id, jnp.int32),
+                jnp.ones((R, B), bool), jnp.zeros((R,), jnp.int32),
+                jnp.zeros((R,), jnp.int32), jnp.zeros((5,), jnp.int32)))
         return state
 
     def _set_pool(self, caches):
@@ -954,7 +1210,7 @@ class DecodeSession:
 
     def admit(self, r: int, toks_np, mask_np, admit_index: int, *,
               budget=None, temperature=None, top_p=None, greedy=None,
-              t_start=None):
+              t_start=None, denoising_steps=None, remasking=None):
         """Admit one prompt into resident row `r`.
 
         `admit_index` keys the admission PRNG fold
@@ -966,6 +1222,12 @@ class DecodeSession:
         Radix mode may raise RuntimeError (pool exhausted even after
         eviction) BEFORE any row state changes — the engine sheds on it.
 
+        A block session (docs/BLOCKDIFF.md) also takes the request's
+        `denoising_steps` (1..block_length; None: block_length) and
+        `remasking` (a name of `blockdiff.REMASKING`); it prefills the
+        prompt's whole blocks only and installs the row with no first token
+        (returns None: a row's tokens all come through `read()`'s reports).
+
         Per-row mode waits for nothing: it returns the first token as it
         stands ON THE DEVICE, and `read()` hands it to the driver as a host
         int (`FirstToken`) in its turn. Rollout mode returns None (it waits
@@ -976,6 +1238,13 @@ class DecodeSession:
         toks_np = np.asarray(toks_np, np.int32)
         mask_np = np.asarray(mask_np, bool)
         t0 = time.perf_counter() if t_start is None else t_start
+        if self.block:      # (before any page is claimed)
+            steps = int(denoising_steps or self.block)
+            remask = REMASKING.index(remasking or REMASKING[0])
+            if not 1 <= steps <= self.block:
+                raise ValueError(
+                    f"denoising_steps={denoising_steps} outside "
+                    f"[1, {self.block}] (the block length)")
         pad_count = int(self.Tp - mask_np.sum())
         a_key = jax.random.fold_in(self._admit_key, _ADMIT_BASE
                                    + int(admit_index))
@@ -991,6 +1260,13 @@ class DecodeSession:
                 if self.seed_window:
                     seed = self._radix.matched_continuation(
                         kelems, self.seed_window)
+                if self.block and (plan.m > 0 or plan.cow_src is not None):
+                    raise NotImplementedError(
+                        "a radix prefix hit on a model that generates by "
+                        f"blocks ({self.config.model_type}): a row's pages "
+                        "are never inserted (the prompt's tail has no K/V "
+                        "until its block commits), so none can occur "
+                        "(docs/BLOCKDIFF.md)")
                 self.table_np[r] = plan.row_pages
                 if self._ring is not None:
                     self._claim_ring(r, plan, pad_count, budget)
@@ -1025,7 +1301,13 @@ class DecodeSession:
             seed=seed, budget=budget,
             temperature=(1.0 if temperature is None else float(temperature)),
             top_p=(1.0 if top_p is None else float(top_p)),
-            greedy=bool(greedy), row_table=row_table_np)
+            greedy=bool(greedy), row_table=row_table_np, end=self.Tp)
+        if self.block:
+            # the prompt's whole blocks are prefilled; its tail opens the
+            # first generated block
+            plen = self.Tp - pad_count
+            pend.end = pad_count + plen // self.block * self.block
+            pend.denoising_steps, pend.remasking = steps, remask
 
         if start is None:
             # cold full-row prefill (rollout mode): identical to the
@@ -1036,7 +1318,7 @@ class DecodeSession:
         else:
             start_abs = start
             full_cold = False
-        s_real = self.Tp - start_abs
+        s_real = pend.end - start_abs
         C = self.prefill_chunk
         if C > 0 and s_real > C:
             pend.next_slot = start_abs
@@ -1091,6 +1373,28 @@ class DecodeSession:
         p = pend
         row_table = (self._row_table(p.row)
                      if self._radix is not None else p.row_table)
+        if self.block:
+            # KV only, in the power-of-two bucket of what is left of the
+            # prompt's whole blocks: nothing is sampled from a prompt, and
+            # the bucket's pad tokens land in slots no one marks valid,
+            # which the first block's forwards write again
+            s_real = p.end - start_abs
+            if s_real > 0:
+                Sb = bucket_len(s_real, self.T_max - start_abs)
+                suffix = np.zeros((1, Sb), np.int32)
+                suffix[0, :s_real] = p.toks[start_abs:p.end]
+                pos = ((start_abs - p.pad_count)
+                       + np.arange(Sb, dtype=np.int32)[None])
+                km = np.zeros((1, self.T_max), bool)
+                km[0, p.pad_count:start_abs] = True
+                self._set_pool(_prefill_chunk_fwd(
+                    self.params, self.config, jnp.asarray(suffix),
+                    jnp.asarray(pos), jnp.asarray([start_abs], jnp.int32),
+                    jnp.asarray(km), self.state[3], row_table,
+                    page_size=self.page_size, lora_scale=self.lora_scale))
+                self.dispatch_tokens += Sb
+                self.launches += 1
+            return self._install_block(p)
         if full_cold and not self.per_row and self.prefill_chunk == 0:
             # the pre-session cold path: one full-row prefill (pads
             # included) — kept verbatim so rollout parity pins hold
@@ -1182,6 +1486,31 @@ class DecodeSession:
         self._unread.append(_First(p, t0))
         return t0
 
+    def _install_block(self, p: _PendingPrefill):
+        """A block session's install: the request's parameters, and the
+        row's carry with its first block open (`_install_block_row`). No
+        page of the row enters the radix tree and no first token exists."""
+        r, B = p.row, self.block
+        self._temp_np[r] = p.temperature
+        self._topp_np[r] = p.top_p
+        self._greedy_np[r] = p.greedy
+        self._budget_np[r] = int(p.budget)
+        self._steps_np[r] = p.denoising_steps
+        self._remask_np[r] = p.remasking
+        self._row_start_np[r] = p.pad_count
+        self._row_live_np[r] = True
+        tail = np.zeros((B,), np.int32)
+        tail[:self.Tp - p.end] = p.toks[p.end:]
+        self.prompt_tail_tokens += self.Tp - p.end
+        self.state = _with_pool(_install_block_row(
+            _sans_pool(self.state), r, jnp.int32(self.Tp - p.pad_count),
+            jnp.int32(p.end - p.pad_count), jnp.asarray(tail), Tp=self.Tp,
+            pad_token_id=self.pad_token_id,
+            mask_token_id=self.config.mask_token_id), self.state[3])
+        self._occupant_np[r] += 1
+        self._done_np[r] = False
+        return None
+
     # ------------------------------------------------------------- #
     # stepping
     # ------------------------------------------------------------- #
@@ -1195,7 +1524,7 @@ class DecodeSession:
         global folds, so they match in distribution only)."""
         p = self._pending[0]
         self.prefill_pieces += 1
-        remaining = self.Tp - p.next_slot
+        remaining = p.end - p.next_slot
         C = self.prefill_chunk
         if remaining <= C:
             self._pending.pop(0)
@@ -1300,6 +1629,13 @@ class DecodeSession:
                     result = _spec_chunk(
                         self.params, self.config, self.state, table_dev,
                         self._prompt_rep, **self._statics)
+            elif self.block:
+                result = _block_chunk(
+                    self.params, self.config, self.state, table_dev,
+                    jnp.array(self._temp_np), jnp.array(self._topp_np),
+                    jnp.array(self._greedy_np), jnp.array(self._budget_np),
+                    jnp.array(self._steps_np), jnp.array(self._remask_np),
+                    **self._block_statics)
             elif self.per_row:
                 result = _serving_chunk(
                     self.params, self.config, self.state, table_dev,
@@ -1315,7 +1651,13 @@ class DecodeSession:
             if self.config.live_rows_dispatch and not self.spec:
                 result, flight.hit = result
             self.state = s = result
-            if self.per_row:
+            if self.block:
+                flight.meta, flight.rows = _block_report(
+                    s[0], s[1], s[2], s[5], s[7], flight.hit, s[14],
+                    width=self._report_width)
+                flight.meta.copy_to_host_async()
+                flight.rows.copy_to_host_async()
+            elif self.per_row:
                 flight.meta, flight.rows = _beat_report(
                     s[0], s[1], s[5], s[7],
                     self._no_hit if flight.hit is None else flight.hit,
@@ -1346,8 +1688,10 @@ class DecodeSession:
                 it_now = int(self.state[0])
                 hit = 0 if item.hit is None else int(item.hit)
             else:
-                (it_now, hit), rows = np.asarray(item.meta), np.asarray(
-                    item.rows)
+                meta, rows = np.asarray(item.meta), np.asarray(item.rows)
+                it_now, hit = meta[:2]
+                if self.block:
+                    self.block_counts = meta[2:].astype(np.int64)
         now = time.perf_counter()
         it_now = int(it_now) - 1
         its = it_now - self._it_prev
@@ -1365,6 +1709,18 @@ class DecodeSession:
             self._count_attention(its, done_h)
             report = BeatReport(its, done_h)
             np.copyto(self._done_np, done_h)
+        elif self.block:
+            current = item.occupants == self._occupant_np
+            w = self._report_width
+            report = BeatReport(its, rows[:, 0].astype(bool), rows[:, 1],
+                                rows[:, 2:2 + w], current, rows[:, 2 + w:])
+            # what the live rows' block reads spanned is the carry's own
+            # count (rows stand at different blocks: the host cannot know)
+            self.live_row_steps = int(self.block_counts[0])
+            self.attn_live_pages = int(self.block_counts[4])
+            self.attn_table_pages += its * self.rows * self.nb
+            self._row_live_np[current] &= ~report.done[current]
+            self._done_np[current] = report.done[current]
         else:
             current = item.occupants == self._occupant_np
             report = BeatReport(its, rows[:, 0].astype(bool), rows[:, 1],
@@ -1448,7 +1804,7 @@ class DecodeSession:
         return bool(self._pending)
 
     def _backlog_tokens(self) -> int:
-        return int(sum(self.Tp - p.next_slot for p in self._pending))
+        return int(sum(p.end - p.next_slot for p in self._pending))
 
     def release(self, r: int, gen_tokens=None) -> int:
         """Release row `r`'s pages (radix: drop the ROW's refs — tree

@@ -206,9 +206,16 @@ def compose_check(sampling: SamplingParams, *,
         with spec_k > 0 (no state rollback) or page_size > 0 (the rollout
         scheduler keeps no state that is not a page).
 
+      * a model that generates by diffusion over blocks
+        (`config.block_generation`, docs/BLOCKDIFF.md) under ANY rollout:
+        every path here takes one token a row a step in order and keeps
+        next-token logprobs; such a model is served (`serving/engine.py`).
+
     Per-row serving constraints (spec requires static greedy, no logprob
     capture) are enforced by DecodeSession's constructor — they depend on
     the per_row flag the engine sets, not on SamplingParams."""
+    if config is not None:
+        config.refuse_block_generation("the rollout sampler")
     if config is not None and config.conv_layers and sampling.spec_k > 0:
         raise NotImplementedError(
             f"speculative decode (spec_k={sampling.spec_k}) on a model with "
@@ -454,6 +461,7 @@ def generate_tokens(
     (prompt-major rows), sharing the prompt KV. `page_size` > 0 runs the
     same loop over the paged KV layout (dense identity block table — no
     recycling here; see sampler/paged/scheduler.py for that)."""
+    config.refuse_block_generation("the one-jit rollout (generate_tokens)")
     Tp = prompt_ids.shape[1]
     state = _prefill_state(
         params, config, prompt_ids, prompt_mask, key,

@@ -358,6 +358,7 @@ def generate_tokens_spec(
     per token; `row_steps` counts live (row, verify-step) pairs, so
     emitted/row_steps is mean tokens per row per dispatch (monolithic:
     identically 1)."""
+    config.refuse_block_generation(f"speculative decode (spec_k={spec_k})")
     Tp = prompt_ids.shape[1]
     base = _prefill_state(
         params, config, prompt_ids, prompt_mask, key,

@@ -26,6 +26,14 @@ engine). What remains here is open-loop POLICY:
     chunks (greedy requests with the full token budget only — the
     accept rule compiles against static sampling params; see
     `sampler.compose_check`).
+  * A model that generates by diffusion over blocks (docs/BLOCKDIFF.md)
+    goes through the same session, engine and gateway: a request also
+    carries `denoising_steps` and `remasking`, a beat delivers the tokens a
+    row has FINAL (0 to `sync_every x block_length` of them), budgets and
+    EOS hold at a block's granularity (the row ends with the block that
+    holds EOS or reaches `max_tokens`; what lies past either is CUT before
+    emission), and a request keeps, a token, the denoise step of its block
+    that unmasked it.
 
 Admission through one `RadixCache` kept alive for the engine's whole
 lifetime (params are fixed, so cached KV never goes stale): a request's
@@ -103,6 +111,18 @@ class ServingRequest:
     n_emitted: int = 0
     cancelled: bool = False       # set by cancel(); loop reaps the row
     kelems: Optional[tuple] = None
+    # generation by blocks (docs/BLOCKDIFF.md): the request's parameters,
+    # and what it keeps of its generation: a token, the denoise step of its
+    # block that unmasked it; the tokens past its EOS or budget, cut before
+    # emission, and theirs (a replay of the block needs them: they were in
+    # the block when the kept ones were unmasked)
+    denoising_steps: Optional[int] = None
+    remasking: Optional[str] = None
+    n_seen: int = 0               # final tokens read off the reports
+    ended: bool = False           # EOS or budget met: the rest is cut
+    unmask_steps: list = field(default_factory=list)
+    cut_tokens: list = field(default_factory=list)
+    cut_unmask_steps: list = field(default_factory=list)
 
 
 class ServingEngine:
@@ -134,6 +154,7 @@ class ServingEngine:
         self.max_queue = int(max_queue)
         self.prefill_chunk = int(prefill_chunk)
         self.spec_k = int(spec_k)
+        self.block_length = int(config.block_length)
 
         rule = next(r for r in SLO_RULES if r.name == "slo_ttft_p95")
         self._slo_metric = rule.metric
@@ -171,7 +192,10 @@ class ServingEngine:
         self._running = True
         self._ids = itertools.count()
         self._counters = {"requests": 0, "admitted": 0, "shed": 0,
-                          "completed": 0, "cancelled": 0}
+                          "completed": 0, "cancelled": 0,
+                          # a block engine: tokens streamed, and tokens its
+                          # rows unmasked past an EOS or a budget
+                          "tokens_streamed": 0, "tokens_cut": 0}
         # per-cause shed counters (serving/shed_total{reason=...}):
         # pre-seeded so every reason exports a 0 row from the first
         # scrape — dashboards can alert on rate() without init gaps
@@ -193,11 +217,14 @@ class ServingEngine:
     # ------------------------------------------------------------- #
 
     def submit(self, tokens, *, temperature=1.0, top_p=1.0, greedy=False,
-               max_tokens=None):
+               max_tokens=None, denoising_steps=None, remasking=None):
         """Admission-controlled enqueue. Returns `(request, None)` or
         `(None, shed_reason)` — `"queue_full"` when the pending bound is
         hit, `"slo_ttft_p95"` when the hub's p95 TTFT is over the SLO
-        warn threshold (past its warmup count)."""
+        warn threshold (past its warmup count). `denoising_steps` (1 to the
+        block length; default: the block length, one token a denoise
+        forward) and `remasking` (`sampler.blockdiff.REMASKING`) are a
+        block model's alone."""
         toks = np.asarray(tokens, np.int32).ravel()
         if toks.size < 1 or toks.size > self.prompt_len:
             raise ValueError(
@@ -205,6 +232,23 @@ class ServingEngine:
                 " — the engine's compiled prompt shape is fixed")
         mx = self.max_new_tokens if max_tokens is None else int(max_tokens)
         mx = max(1, min(mx, self.max_new_tokens))
+        if self.block_length:
+            from nanorlhf_tpu.sampler.blockdiff import REMASKING
+
+            if denoising_steps is None:
+                denoising_steps = self.block_length
+            if remasking is None:
+                remasking = REMASKING[0]
+            if (not 1 <= int(denoising_steps) <= self.block_length
+                    or remasking not in REMASKING):
+                raise ValueError(
+                    f"denoising_steps={denoising_steps!r} outside [1, "
+                    f"{self.block_length}] or remasking={remasking!r} not "
+                    f"one of {REMASKING}")
+        elif denoising_steps is not None or remasking is not None:
+            raise ValueError(
+                "denoising_steps / remasking on a model that does not "
+                f"generate by blocks ({self.config.model_type})")
         if self.spec_k > 0 and (not greedy or mx != self.max_new_tokens):
             raise ValueError(
                 "a spec-decode engine (spec_k > 0) serves greedy requests "
@@ -223,7 +267,10 @@ class ServingEngine:
                 request_id=next(self._ids), tokens=toks,
                 temperature=float(temperature), top_p=float(top_p),
                 greedy=bool(greedy), max_tokens=mx,
-                t_submit=time.perf_counter())
+                t_submit=time.perf_counter(),
+                denoising_steps=(None if denoising_steps is None
+                                 else int(denoising_steps)),
+                remasking=remasking)
             self._pending.append(req)
             self._cond.notify_all()
         return req, None
@@ -354,7 +401,9 @@ class ServingEngine:
             self._sess.admit(
                 r, toks_p, mask, req.request_id, budget=req.max_tokens,
                 temperature=req.temperature, top_p=req.top_p,
-                greedy=req.greedy, t_start=req.t_submit)
+                greedy=req.greedy, t_start=req.t_submit,
+                **({"denoising_steps": req.denoising_steps,
+                    "remasking": req.remasking} if self.block_length else {}))
         except RuntimeError:
             # pool sizing makes this unreachable (rows*nb live refs max,
             # the rest evictable) — shed rather than crash if it fires
@@ -407,6 +456,8 @@ class ServingEngine:
                 req.out_q.put(got.token)
                 req.n_emitted = 1
             return
+        if self.block_length:
+            return self._deliver_blocks(got)
         for r in np.flatnonzero(got.current):
             req = self._owner[r]
             if req is None or req.n_emitted == 0 or req.cancelled:
@@ -416,15 +467,54 @@ class ServingEngine:
                 req.out_q.put(tok)
             req.n_emitted = n
             if got.done[r]:
-                req.out_q.put(None)
-                self._sess.release(
-                    r, gen_tokens=(got.new_tokens(r, 0) if self.spec_k > 0
-                                   else None))
-                self._owner[r] = None
-                with self._cond:
-                    self._counters["completed"] += 1
-                    self._n_active -= 1
-                    self._cond.notify_all()
+                self._finish(r, req, gen_tokens=(
+                    got.new_tokens(r, 0) if self.spec_k > 0 else None))
+
+    def _finish(self, r, req, gen_tokens=None, **counted):
+        """Row `r`'s request is over: close its stream, release the row,
+        count it (`counted`: further counters and what each gains)."""
+        req.out_q.put(None)
+        self._sess.release(r, gen_tokens=gen_tokens)
+        self._owner[r] = None
+        with self._cond:
+            self._counters["completed"] += 1
+            for name, n in counted.items():
+                self._counters[name] += n
+            self._n_active -= 1
+            self._cond.notify_all()
+
+    def _deliver_blocks(self, got):
+        """`_deliver` for a model that generates by blocks: a report brings
+        the tokens a row has FINAL since the last one, with the denoise
+        step that unmasked each. They stream until the request's EOS or
+        budget; the rest of the row's last block is cut (and kept, for a
+        replay). The row ends when the session says so: at the commit of
+        the block that held the EOS or met the budget."""
+        pending = self._sess.pending_rows()
+        for r in np.flatnonzero(got.current):
+            req = self._owner[r]
+            # (a row between two pieces of its prompt is parked done; the
+            # install changes its occupant, so no earlier flight speaks for it)
+            if req is None or req.cancelled or r in pending:
+                continue
+            toks = got.new_tokens(r, req.n_seen).tolist()
+            steps = got.new_steps(r, req.n_seen).tolist()
+            req.n_seen = int(got.n_gen[r])
+            for tok, step in zip(toks, steps):
+                if req.ended:
+                    req.cut_tokens.append(tok)
+                    req.cut_unmask_steps.append(step)
+                    continue
+                if req.n_emitted == 0:
+                    req.t_first_token = time.perf_counter()
+                req.unmask_steps.append(step)
+                req.out_q.put(tok)
+                req.n_emitted += 1
+                req.ended = (tok == self.eos_token_id
+                             or req.n_emitted >= req.max_tokens)
+            if got.done[r]:
+                self._finish(r, req, tokens_streamed=req.n_emitted,
+                             tokens_cut=len(req.cut_tokens))
 
     # ------------------------------------------------------------- #
     # observability
@@ -499,6 +589,23 @@ class ServingEngine:
             "serving/held_experts_hit": self._sess.held_experts_hit,
             "pages/shared": snap["shared_pages"],
         }
+        if self.block_length:
+            # generation by blocks (docs/BLOCKDIFF.md): the carry's own
+            # counts as the last read brought them, the prompts' tails, and
+            # this loop's account of what it streamed and cut (completed
+            # requests': `tokens_unmasked` = streamed + cut once none runs)
+            live, commit, unmasked, closed, _ = (
+                int(c) for c in self._sess.block_counts)
+            rows.update({
+                "serving/block_length": self.block_length,
+                "serving/block_forwards": live,
+                "serving/commit_forwards": commit,
+                "serving/tokens_unmasked": unmasked,
+                "serving/blocks_done": closed,
+                "serving/prompt_tail_tokens": self._sess.prompt_tail_tokens,
+                "serving/tokens_streamed": c["tokens_streamed"],
+                "serving/tokens_cut": c["tokens_cut"],
+            })
         for reason, n in sorted(reasons.items()):
             rows[f'serving/shed_total{{reason="{reason}"}}'] = n
         # the loop account and the request timeline: cumulative seconds,

@@ -132,6 +132,10 @@ class ServingGateway:
                     top_p=float(spec.get("top_p", 1.0)),
                     greedy=bool(spec.get("greedy", False)),
                     max_tokens=spec.get("max_tokens"),
+                    # a model that generates by blocks (docs/BLOCKDIFF.md);
+                    # any other refuses them (400)
+                    **{k: spec[k] for k in ("denoising_steps", "remasking")
+                       if spec.get(k) is not None},
                 )
                 if req is None:
                     self._write(
@@ -169,8 +173,16 @@ class ServingGateway:
                         self.close_connection = True
                     return
                 toks = list(gw.engine.stream(req))
-                self._write(200, "application/json", json.dumps(
-                    {"request_id": req.request_id, "tokens": toks}).encode())
+                body = {"request_id": req.request_id, "tokens": toks}
+                if getattr(req, "denoising_steps", None) is not None:
+                    # per token, the denoise step of its block that
+                    # unmasked it; and what the row's last block held past
+                    # its EOS or budget, cut before emission
+                    body.update(unmask_steps=req.unmask_steps,
+                                cut_tokens=req.cut_tokens,
+                                cut_unmask_steps=req.cut_unmask_steps)
+                self._write(200, "application/json",
+                            json.dumps(body).encode())
 
             # ---- plumbing ------------------------------------------ #
 

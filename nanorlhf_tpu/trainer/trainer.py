@@ -397,6 +397,9 @@ class RLTrainer:
     ):
         self.cfg = config
         self.mcfg = model_config
+        # (no published RL objective for a policy that generates by blocks:
+        # docs/BLOCKDIFF.md "what is left")
+        self.mcfg.refuse_block_generation(f"training ({type(self).__name__})")
         if self.mcfg.conv_layers:
             raise NotImplementedError(
                 f"training a model with conv layers ({self.mcfg.model_type}) "

@@ -77,6 +77,14 @@ import jax
 # the sigmoid of the gate's projection, between the read and `attn.out`; the
 # projection itself is one of `attn.qkv`'s, and the two branch norms of such
 # a model run under `norm` inside `attn.out` and `mlp`.
+# Generation by blocks (docs/BLOCKDIFF.md; the program scope of a block forward
+# is `decode`): `attn.block` sits inside `attn.read` around the block read,
+# a block's queries over the rows' live pages in place (the paged decode
+# kernel's custom call takes its name from it) or its plain form; and
+# `sample.unmask`, inside `sample`, is what follows the per-position draw:
+# the choice of the positions to unmask, the block's and the row's state
+# (the benchmark's reduction knows no `sample.` family and reads it as
+# `sample`; the cell's own reader, layer_metrics/unmask_share.py, tells it apart).
 # A conv layer's operator (docs/STATE.md) stands in the attention's slot and
 # takes names of its family, so that a reader that keeps `attn.*` keeps it:
 # `attn.conv` around `attn.conv.in` (the input projection and the gate),
@@ -87,7 +95,7 @@ DEVICE_SCOPES = (
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
     "attn.out", "attn.gate", "attn.paged_flash", "attn.conv", "attn.conv.in",
     "attn.conv.mix", "attn.conv.out", "mlp", "head", "sample", "logprob",
-    "loss", "optim",
+    "loss", "optim", "attn.block", "sample.unmask",
 )
 
 

@@ -1136,3 +1136,102 @@ def test_trinity_session_programs_fit_the_chip_in_place_on_v5e(
                              for c in calls), calls
     # no gathered view of a row's pages: [.., 9216, 128] by slot
     assert not re.findall(r"bf16\[\d+,8,9216,128\]", hlo)
+
+
+def _sdar_session_program(case, v5e):
+    """The `serve-sdar-blockgen` cell's block chunk or its KV-only prefill
+    piece, lowered for a described v5e at the configuration file's own cut
+    (SDAR-30B-A3B-Chat's published widths, 7 layers, all 128 experts, the
+    whole vocabulary) and the cell's engine sizes: `(compiled, cache
+    shapes, config)`."""
+    import json
+    import os
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "sdar-30b-a3b-l7.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "blockgen-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = (eng["rows"], eng["prompt_len"], eng["max_new_tokens"],
+                         eng["prefill_chunk"])
+    B = cfg.block_length
+    T_max = Tp + new + B        # a row's last block may pass its budget
+    nb = -(-T_max // PAGE)
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, R * nb + nb, PAGE, jnp.bfloat16))     # one row's spare pages
+    if case == "block_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        rows = lambda dt: spec((R,), dt)    # noqa: E731
+        state = (spec((), jnp.int32), spec((R, new + B), jnp.int32),
+                 spec((R, new + B), jnp.int32), _shapes_on(cache, one_chip),
+                 spec((R, T_max), jnp.bool_), rows(jnp.bool_), rows(jnp.int32),
+                 rows(jnp.int32), rows(jnp.int32), key,
+                 spec((R, B), jnp.int32), spec((R, B), jnp.bool_),
+                 rows(jnp.int32), rows(jnp.int32), spec((5,), jnp.int32))
+        lowered = session._block_chunk.lower(
+            params, cfg, state, spec((R, nb), jnp.int32), rows(jnp.float32),
+            rows(jnp.float32), rows(jnp.bool_), rows(jnp.int32),
+            rows(jnp.int32), rows(jnp.int32), Tp=Tp, page_size=PAGE,
+            sync_every=eng["sync_every"], eos_token_id=1, lora_scale=1.0,
+            top_k=64, approx_top_k=True)
+    else:
+        lowered = session._prefill_chunk_fwd.lower(
+            params, cfg, spec((1, chunk), jnp.int32),
+            spec((1, chunk), jnp.int32), spec((1,), jnp.int32),
+            spec((1, T_max), jnp.bool_), _shapes_on(cache, one_chip),
+            spec((nb,), jnp.int32), page_size=PAGE, lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["block_chunk", "prefill_piece"])
+def test_sdar_session_programs_fit_the_chip_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 46, asked of the chip's compiler at the `serve-sdar-blockgen`
+    cell's own shapes (9.97 GB of bf16 weights, 64 rows of 3,076 slots, pages
+    of 128, a pool of 1,625 pages x 7 layers): the session's block chunk and
+    its 1,024-token KV-only prefill piece each FIT 16 GB, alias the pool from
+    their parameters to their results, hold no `copy` of a pool leaf and run
+    the experts through the grouped-matmul kernel; the block chunk reads the
+    pages through the in-place decode kernel under the name `attn.block`,
+    and neither gathers a row's pages into a view by slot."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _sdar_session_program(case, v5e)
+    hlo = compiled.as_text()
+    kept = hlo_stacks(jax.tree.leaves(cache))
+    assert set(kept) == {("bf16", (7, 1625, 4, PAGE, 128))}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.2e9 < m.argument_size_in_bytes < 13.1e9     # weights + pool
+    assert m.alias_size_in_bytes > 2.9e9                  # the pool donated
+    assert peak < 14.0e9, (case, peak, m.temp_size_in_bytes)
+    assert m.temp_size_in_bytes < 0.7e9, (case, m.temp_size_in_bytes)
+    comps = _computations(hlo)
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(kept)]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 3
+    calls = [line.strip().split(" ")[0] for line in hlo.splitlines()
+             if re.match(r"\s*%attn\.[\w.]* = \S+ custom-call\(", line)]
+    if case == "block_chunk":
+        assert calls and all(c.startswith("%attn.block") for c in calls), calls
+    else:       # the piece's read is XLA's walk of the key blocks
+        assert not calls
+    assert not re.findall(r"bf16\[\d+,4,3200,128\]", hlo)

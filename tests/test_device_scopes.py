@@ -131,6 +131,13 @@ def test_the_harness_reads_the_programs_vocabulary():
     `DEVICE_SCOPES`); the families' members are the program's."""
     assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES)
     for scope in DEVICE_SCOPES:
+        if scope == "sample.unmask":
+            # the harness (not PR 46's to edit) keeps no `sample.` family:
+            # the scope sits inside `sample` and reads as it
+            # (docs/BLOCKDIFF.md; layer_metrics/unmask_share.py reads it apart)
+            assert scope_trace.scope_of(
+                f"jit(f)/sample/{scope}/add") == "sample"
+            continue
         assert scope_trace.scope_of(f"jit(f)/{scope}/add") == scope
     assert scope_trace.SCOPES == {s for s in DEVICE_SCOPES if "." not in s}
     assert scope_trace.scope_of(
