@@ -2411,6 +2411,9 @@ def decode_step(
                                   # beyond it, so the XLA read of a
                                   # contiguous cache stops there (None: the
                                   # whole cache; `decode_read_extents`)
+    want_logits: bool = True,     # False: the final hidden state [B, D] as
+                                  # the head (`_logits`) would take it, for a
+                                  # caller that scores some of the rows
 ):
     """One autoregressive decode step. Returns (logits [B, V], new caches),
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
@@ -2445,7 +2448,7 @@ def decode_step(
         config, params, x, cos, sin, views, kv_caches, lora_scale,
         # (the experts its rows reach, where the caller asks)
         cached_aux=count_experts)
-    logits = _logits(config, params, x)[:, 0, :]
+    logits = (_logits(config, params, x) if want_logits else x)[:, 0, :]
     if count_experts:
         return logits, new_caches, jnp.sum(aux["reached"])
     return logits, new_caches
@@ -2551,6 +2554,8 @@ def block_forward(
     live=None,                    # [B] bool: rows whose logits the caller
                                   # uses (None: all)
     count_experts: bool = False,
+    want_logits: bool = True,     # False: the final hidden state
+                                  # [B, Tb, D] as the head would take it
 ):
     """One forward of a model that generates by blocks (docs/BLOCKDIFF.md)
     over ONE block a row, `decode_verify`'s successor for it: the block's
@@ -2596,7 +2601,7 @@ def block_forward(
     x, new_caches, aux = _run_layers(
         config, params, x, cos, sin, views, kv_caches, lora_scale,
         cached_aux=count_experts)
-    logits = _logits(config, params, x)
+    logits = _logits(config, params, x) if want_logits else x
     if count_experts:
         return logits, new_caches, jnp.sum(aux["reached"])
     return logits, new_caches

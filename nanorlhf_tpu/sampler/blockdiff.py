@@ -3,10 +3,10 @@
 A denoise forward gives logits at every position of a row's block; this
 module turns them into the forward's result for the row:
 
-- `sample_positions`: a token at EVERY position (greedy, or temperature /
-  top-p over the serving sampler's candidate set, per row) and its
-  CONFIDENCE, the token's probability under the distribution it was drawn
-  from (a greedy token's: under the plain softmax of its logits);
+- `sample_positions`: a token at each position it is given (greedy, or
+  temperature / top-p over the serving sampler's candidate set, per row)
+  and its CONFIDENCE, the token's probability under the distribution it was
+  drawn from (a greedy token's: under the plain softmax of its logits);
 - `transfer_count`: how many masked positions step s of S unmasks, the
   block's B spread over the S steps as evenly as integers allow, the earlier
   steps taking the remainder;
@@ -28,7 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from nanorlhf_tpu.ops.masking import guard_temperature
-from nanorlhf_tpu.sampler.sampler import _nucleus_candidates
+from nanorlhf_tpu.sampler.sampler import (
+    _categorical_rows, _nucleus_candidates,
+)
 
 # a request's `remasking`, by name; a row carries the index
 REMASKING = ("low_confidence_static", "low_confidence_dynamic", "sequential")
@@ -47,30 +49,29 @@ def transfer_count(step, steps, block_length: int):
 
 @jax.named_scope("sample")
 def sample_positions(key, logits, temperature, top_p, greedy, *, top_k,
-                     approx_top_k):
-    """logits [R, B, V]; temperature / top_p / greedy [R]. Returns `(tokens
-    [R, B] int32, confidence [R, B] float32)`: `session._serving_sample`'s
-    draw at every position of the block (one candidate set a position), and
-    the drawn token's probability under the kept candidates' renormalised
-    distribution; a greedy row takes each position's argmax and its
-    probability under the plain softmax."""
-    R, B, V = logits.shape
-    flat = logits.reshape(R * B, V).astype(jnp.float32)
-    per = lambda a: jnp.repeat(a, B)       # noqa: E731  a row's, a position
-    scaled = flat / guard_temperature(per(temperature))[:, None]
+                     approx_top_k, draw=None):
+    """logits [M, V], one row a POSITION of some row's block; temperature /
+    top_p / greedy [M], the position's row's. Returns `(tokens [M] int32,
+    confidence [M] float32)`: `session._serving_sample`'s draw at each
+    position (one candidate set a position), and the drawn token's
+    probability under the kept candidates' renormalised distribution; a
+    greedy row takes each position's argmax and its probability under the
+    plain softmax. `draw`: `sampler._categorical_rows`' (the positions given
+    are some of a forward's, and draw as they would among all of them)."""
+    flat = logits.astype(jnp.float32)
+    scaled = flat / guard_temperature(temperature)[:, None]
     top_logits, top_idx, keep = _nucleus_candidates(
-        scaled, per(top_p)[:, None], top_k, approx_top_k)
+        scaled, top_p[:, None], top_k, approx_top_k)
     kept = jnp.where(keep, top_logits, -jnp.inf)
-    choice = jax.random.categorical(key, kept, axis=-1)
+    choice = _categorical_rows(key, kept, draw)
     sampled = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
     p_sampled = jnp.take_along_axis(
         jax.nn.softmax(kept, axis=-1), choice[:, None], axis=-1)[:, 0]
     best = jnp.argmax(flat, axis=-1)
     p_best = jnp.exp(jnp.max(flat, axis=-1)
                      - jax.nn.logsumexp(flat, axis=-1))
-    g = per(greedy)
-    return (jnp.where(g, best, sampled).astype(jnp.int32).reshape(R, B),
-            jnp.where(g, p_best, p_sampled).reshape(R, B))
+    return (jnp.where(greedy, best, sampled).astype(jnp.int32),
+            jnp.where(greedy, p_best, p_sampled))
 
 
 def choose_unmask(confidence, masked, n, remasking,

@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nanorlhf_tpu.core.model import (
-    attention_form, block_forward, decode_step, decode_verify,
+    _logits, attention_form, block_forward, decode_step, decode_verify,
     leaves_in_place, paged_write_forms, prefill,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
@@ -91,6 +91,7 @@ from nanorlhf_tpu.sampler.blockdiff import (
     REMASKING, choose_unmask, sample_positions, transfer_count,
 )
 from nanorlhf_tpu.sampler.sampler import (
+    _categorical_rows,
     _nucleus_candidates,
     _prefill_state,
     _sample_token,
@@ -126,7 +127,7 @@ _CHUNK_STATIC = (
 
 @jax.named_scope("sample")
 def _serving_sample(key, logits, temperature, top_p, greedy, *, top_k,
-                    approx_top_k):
+                    approx_top_k, draw=None):
     """Per-ROW sampling: `sampler._sample_token` with `temperature` /
     `top_p` / `greedy` as traced `[R]` arrays so one compiled decode
     step serves heterogeneous requests. Both branches are computed and
@@ -135,17 +136,63 @@ def _serving_sample(key, logits, temperature, top_p, greedy, *, top_k,
     Unlike the rollout sampler there is no exact full-vocab escape for
     `top_p >= 1` — serving always samples in top-k candidate space
     (`top_p = 1` keeps every candidate), which is the usual serving
-    trade and keeps the row-mixed program shape fixed."""
+    trade and keeps the row-mixed program shape fixed. `draw`:
+    `sampler._categorical_rows`' (the rows given are some of a step's, and
+    draw as they would among all of them)."""
     scaled = (logits.astype(jnp.float32)
               / guard_temperature(temperature)[:, None])
     top_logits, top_idx, keep = _nucleus_candidates(
         scaled, top_p[:, None], top_k, approx_top_k)
     kept = jnp.where(keep, top_logits, -jnp.inf)
-    choice = jax.random.categorical(key, kept, axis=-1)
+    choice = _categorical_rows(key, kept, draw)
     sampled = jnp.take_along_axis(
         top_idx, choice[..., None], axis=-1)[..., 0]
     return jnp.where(greedy, jnp.argmax(logits, axis=-1),
                      sampled).astype(jnp.int32)
+
+
+def needed_sizes(items: int) -> tuple:
+    """The sizes a served forward's sampler runs at: an eighth, a quarter, a
+    half and all of its items, none under 8."""
+    return tuple(items // d for d in (8, 4, 2) if items // d >= 8) + (items,)
+
+
+def _over_needed(need, hidden, head, sample):
+    """A served forward's head and sampler with the items that NEED a token
+    first (docs/PAGED_CACHE.md "The rows a step scores"): `need` [N] bool,
+    `hidden` [N, D] the model's final hidden state, `head(hidden [N, D])` its
+    logits `[N, V]`, `sample(logits [s, V], idx [s])` the sampler over the
+    items `idx`, returning a tuple of `[s]` arrays. The hidden state is
+    gathered with the needed items to the front, the head runs over it once
+    (a weight stream: rows do not shrink it, and no branch takes the weight
+    as an operand, which the compiler relays where it is stored minor-axis
+    first), and ONE branch of a `lax.switch` samples, over the leading rows
+    of the logits: the smallest of `needed_sizes(N)` that holds the needed
+    ones (a branch's other items are unneeded ones, sampled as every item
+    was before). Each scatters its tokens back to `[N]`, zeros elsewhere, so
+    a needed item reads what it would have read with every item sampled.
+    Returns `(the tuple of [N] arrays, the size taken [] int32)`."""
+    N = need.shape[0]
+    sizes = needed_sizes(N)
+    with jax.named_scope("sample"):
+        order = jnp.argsort(~need, stable=True)
+        which = jnp.sum(jnp.sum(need, dtype=jnp.int32)
+                        > jnp.asarray(sizes[:-1], jnp.int32), dtype=jnp.int32)
+    with jax.named_scope("head"):
+        front = hidden[order]
+    logits = head(front)
+
+    def at(size):
+        def branch(order, logits):
+            idx = order[:size]
+            sampled = sample(logits[:size], idx)
+            with jax.named_scope("sample"):
+                return tuple(jnp.zeros((N,), a.dtype).at[idx].set(a)
+                             for a in sampled)
+        return branch
+
+    out = jax.lax.switch(which, [at(size) for size in sizes], order, logits)
+    return out, jnp.asarray(sizes, jnp.int32)[which]
 
 
 @partial(jax.jit, static_argnames=("top_k", "approx_top_k"))
@@ -168,10 +215,12 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
     different depths) and table-routed cache writes. `row_params` is None
     for the rollout mode (static sampling params, row budget =
     `max_tokens`) or the serving mode's traced `[R]`
-    (temperature, top_p, greedy, budget) tuple — a trace-time branch, so
-    each mode compiles to exactly the program its pre-session driver ran.
+    (temperature, top_p, greedy, budget) tuple — a trace-time branch. The
+    rollout mode compiles to exactly the program its pre-session driver ran;
+    the serving mode samples the LIVE rows alone (`_over_needed`).
     With `count_experts` the result is `(carry, held experts the live rows
-    reached)` (`_chunk_loop`)."""
+    reached)`, in the serving mode always `(carry, [that or 0, the rows the
+    sampler ran over])` (`_chunk_loop`)."""
     (it, out, lp_out, caches, key_mask, done, cur_tok, n_gen, prompt_len,
      key) = s
     R = cur_tok.shape[0]
@@ -184,6 +233,7 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
         params, config, cur_tok, position, slot, key_mask, caches,
         lora_scale=lora_scale, page_table=table, page_size=page_size,
         live=live, **({"count_experts": True} if count_experts else {}),
+        want_logits=row_params is None,
     )
     if row_params is None:
         tok = _sample_token(jax.random.fold_in(key, it), logits, temperature,
@@ -191,9 +241,13 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
         limit = max_tokens
     else:
         r_temp, r_topp, r_greedy, r_budget = row_params
-        tok = _serving_sample(jax.random.fold_in(key, it), logits, r_temp,
-                              r_topp, r_greedy, top_k=top_k,
-                              approx_top_k=approx_top_k)
+        hidden = logits     # (`want_logits=False`: the head runs below)
+        step_key = jax.random.fold_in(key, it)
+        (tok,), taken = _over_needed(
+            live, hidden, partial(_logits, config, params),
+            lambda logits, idx: (_serving_sample(
+                step_key, logits, r_temp[idx], r_topp[idx], r_greedy[idx],
+                top_k=top_k, approx_top_k=approx_top_k, draw=(idx, R)),))
         limit = r_budget
     tok = jnp.where(done, pad_token_id, tok)
     wpos = jnp.where(live, n_gen, max_tokens)  # done rows drop their write
@@ -206,6 +260,9 @@ def _session_decode_body(params, config, s, table, row_params, *, Tp,
     done = done | (tok == eos_token_id) | (n_gen >= limit)
     carry = (it + 1, out, lp_out, caches, key_mask, done, cur_tok, n_gen,
              prompt_len, key)
+    if row_params is not None:
+        return carry, jnp.stack([reached[0] if count_experts
+                                 else jnp.int32(0), taken])
     return (carry, reached[0]) if count_experts else carry
 
 
@@ -218,21 +275,26 @@ def _chunk_loop(params, config, state, table, row_params, statics):
     a chip's share of them or all) also returns, beside the carry, the held
     experts its LIVE rows reached (a step dispatches no other row: `moe_mlp`), summed
     over the chunk's steps and the layers (`DecodeSession.held_experts_hit`):
-    the routed kernels the steps had to read."""
+    the routed kernels the steps had to read. The serving mode's returns
+    `[that or 0, the rows its sampler ran over]` summed over the steps
+    (`DecodeSession.sample_rows`)."""
     statics = dict(statics)
     sync_every = statics.pop("sync_every")
-    if config.live_rows_dispatch:
+    experts = config.live_rows_dispatch
+    if experts or row_params is not None:
         def counted(cs):
-            c, s, hit = cs
-            s, reached = _session_decode_body(
-                params, config, s, table, row_params, count_experts=True,
+            c, s, seen = cs
+            s, more = _session_decode_body(
+                params, config, s, table, row_params, count_experts=experts,
                 **statics)
-            return c + 1, s, hit + reached
+            return c + 1, s, seen + more
 
-        _, state, hit = jax.lax.while_loop(
+        _, state, seen = jax.lax.while_loop(
             lambda cs: (cs[0] < sync_every) & ~jnp.all(cs[1][5]), counted,
-            (jnp.int32(0), state, jnp.int32(0)))
-        return state, hit
+            (jnp.int32(0), state,
+             jnp.int32(0) if row_params is None
+             else jnp.zeros((2,), jnp.int32)))
+        return state, seen
 
     def cond(cs):
         c, s = cs
@@ -274,11 +336,11 @@ def _serving_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
 
 @partial(jax.jit, static_argnames=("width",))
 @jax.named_scope("install")
-def _beat_report(it, out, done, n_gen, hit, *, width):
+def _beat_report(it, out, done, n_gen, seen, *, width):
     """What the host reads of a serving beat, as two small arrays of their
-    own: `[it, hit]` and, a row, `done · n_gen · the row's last `width`
-    tokens` (right-aligned at `n_gen`: a chunk writes at most `width` a row,
-    so the new ones are among them). Enqueued behind the chunk over its
+    own: `[it, *seen]` (`_chunk_loop`'s two counts) and, a row, `done ·
+    n_gen · the row's last `width` tokens` (right-aligned at `n_gen`: a chunk
+    writes at most `width` a row, so the new ones are among them). Enqueued behind the chunk over its
     carry and donating nothing, so the NEXT chunk may consume that carry
     while the host still waits for these (`DecodeSession.dispatch`)."""
     cols = n_gen[:, None] - width + jnp.arange(width, dtype=jnp.int32)[None]
@@ -286,7 +348,7 @@ def _beat_report(it, out, done, n_gen, hit, *, width):
         out, jnp.clip(cols, 0, out.shape[1] - 1), axis=1)
     rows = jnp.concatenate(
         [done[:, None].astype(jnp.int32), n_gen[:, None], toks], axis=1)
-    return jnp.stack([it, hit]), rows
+    return jnp.concatenate([it[None], seen]), rows
 
 
 # a block session's carry past the base ten (docs/BLOCKDIFF.md "row state"):
@@ -309,8 +371,8 @@ def _block_body(params, config, s, table, row_params, *, Tp, page_size,
     """One forward of generation by blocks over the session carry
     (docs/BLOCKDIFF.md): every live row runs its current block through
     `block_forward`. A row whose block still has masked positions takes a
-    DENOISE forward: a token is sampled at every position, its confidence
-    taken, and the row's strategy unmasks some of the masked ones
+    DENOISE forward: a token is sampled at its masked positions, its
+    confidence taken, and the row's strategy unmasks some of them
     (`sampler/blockdiff.py`); the block's K/V just written stay PROVISIONAL
     (`key_mask` is left alone: the next forward writes the same slots
     again). A row whose block has no mask left takes its COMMIT forward:
@@ -328,15 +390,24 @@ def _block_body(params, config, s, table, row_params, *, Tp, page_size,
     fill = Tp - prompt_len + base                       # [R] the block's slot
     within = jnp.arange(B, dtype=jnp.int32)[None, :]
     tokens = jnp.where(masked, config.mask_token_id, blk)
-    logits, caches, reached = block_forward(
+    hidden, caches, reached = block_forward(
         params, config, tokens, base[:, None] + within, fill, key_mask,
         caches, lora_scale=lora_scale, page_table=table, page_size=page_size,
-        live=live, count_experts=True)
+        live=live, count_experts=True, want_logits=False)
     open_ = masked.any(axis=1)
     denoise, commit = live & open_, live & ~open_
-    tok, conf = sample_positions(
-        jax.random.fold_in(key, it), logits, r_temp, r_topp, r_greedy,
-        top_k=top_k, approx_top_k=approx_top_k)
+    # a token and a confidence where one can be used: at the masked
+    # positions of the rows on a denoise forward (`chosen` is among them)
+    step_key = jax.random.fold_in(key, it)
+    (tok, conf), taken = _over_needed(
+        (live[:, None] & masked).reshape(R * B),
+        hidden.reshape(R * B, hidden.shape[-1]),
+        partial(_logits, config, params),
+        lambda logits, idx: sample_positions(
+            step_key, logits, r_temp[idx // B], r_topp[idx // B],
+            r_greedy[idx // B], top_k=top_k, approx_top_k=approx_top_k,
+            draw=(idx, R * B)))
+    tok, conf = tok.reshape(R, B), conf.reshape(R, B)
     # (inside `sample`: the benchmark's scope reduction keeps the families
     # `attn.`, `moe.`, `mla.` and reads this as its parent's)
     with jax.named_scope("sample"), jax.named_scope("sample.unmask"):
@@ -377,26 +448,26 @@ def _block_body(params, config, s, table, row_params, *, Tp, page_size,
             jnp.sum(pages, dtype=jnp.int32)])
     carry = (it + 1, out, rec, caches, key_mask, done, cur_tok, n_gen,
              prompt_len, key, blk, masked, step, base, counts)
-    return carry, reached
+    return carry, jnp.stack([reached, taken])
 
 
 def _block_loop(params, config, state, table, row_params, statics):
     """Up to `sync_every` forwards of generation by blocks; exits early once
-    every resident row is done. Returns `(carry, held experts the live rows
-    reached)`, as `_chunk_loop` does for a model with expert layers."""
+    every resident row is done. Returns `(carry, [held experts the live rows
+    reached, positions the sampler ran over])`, as `_chunk_loop` does in the
+    serving mode."""
     statics = dict(statics)
     sync_every = statics.pop("sync_every")
 
     def body(cs):
-        c, s, hit = cs
-        s, reached = _block_body(params, config, s, table, row_params,
-                                 **statics)
-        return c + 1, s, hit + reached
+        c, s, seen = cs
+        s, more = _block_body(params, config, s, table, row_params, **statics)
+        return c + 1, s, seen + more
 
-    _, state, hit = jax.lax.while_loop(
+    _, state, seen = jax.lax.while_loop(
         lambda cs: (cs[0] < sync_every) & ~jnp.all(cs[1][5]), body,
-        (jnp.int32(0), state, jnp.int32(0)))
-    return state, hit
+        (jnp.int32(0), state, jnp.zeros((2,), jnp.int32)))
+    return state, seen
 
 
 @partial(jit_donating, donate=2, static_argnames=_BLOCK_STATIC)
@@ -410,8 +481,8 @@ def _block_chunk(params, config, state, table, r_temp, r_topp, r_greedy,
 
 @partial(jax.jit, static_argnames=("width",))
 @jax.named_scope("install")
-def _block_report(it, out, rec, done, n_gen, hit, counts, *, width):
-    """`_beat_report` for a block session: `[it, hit, *counts]` and, a row,
+def _block_report(it, out, rec, done, n_gen, seen, counts, *, width):
+    """`_beat_report` for a block session: `[it, *seen, *counts]` and, a row,
     `done · n_gen · the last `width` FINAL tokens · the denoise step that
     unmasked each` (right-aligned at `n_gen`, the row's count of final
     tokens: a chunk of `sync_every` forwards finalises at most `width`)."""
@@ -421,7 +492,7 @@ def _block_report(it, out, rec, done, n_gen, hit, counts, *, width):
         [done[:, None].astype(jnp.int32), n_gen[:, None],
          jnp.take_along_axis(out, cols, axis=1),
          jnp.take_along_axis(rec, cols, axis=1)], axis=1)
-    return jnp.concatenate([jnp.stack([it, hit]), counts]), rows
+    return jnp.concatenate([it[None], seen, counts]), rows
 
 
 @partial(jax.jit, static_argnames=("Tp", "pad_token_id", "mask_token_id"))
@@ -697,7 +768,9 @@ class _First:
 @dataclass
 class _Flight:
     """A dispatched chunk the host has not read: when it was dispatched, who
-    held each row then, and (serving mode) its `_beat_report` on the device."""
+    held each row then, the counts its program handed back beside the carry
+    (`hit`: `_chunk_loop`'s) and (serving mode) its `_beat_report`, all still
+    on the device."""
     t0: float
     occupants: np.ndarray
     hit: Optional[jax.Array] = None
@@ -997,6 +1070,12 @@ class DecodeSession:
         # reached, summed over every decode step and layer so far
         # (`serving/held_experts_hit`; `_chunk_loop`)
         self.held_experts_hit = 0
+        # the serving mode: the rows (a block session: positions) the
+        # sampler ran over, summed over every step so far, and the rows
+        # there were (`serving/sample_rows`, `serving/sample_slots`;
+        # `_over_needed`)
+        self.sample_rows = 0
+        self.sample_slots = 0
         # the host's record of the carry, as of the last sync and the
         # admissions and cancels since: what other threads may read
         self._done_np = np.ones((R,), bool)
@@ -1013,7 +1092,7 @@ class DecodeSession:
         self._report_width = (
             self.max_tokens if self.spec
             else min(int(sync_every) * max(self.block, 1), self.max_tokens))
-        self._no_hit = jnp.int32(0)
+        self._nothing_seen = jnp.zeros((2,), jnp.int32)
         self._t_report = 0.0
         # beats dispatched while an earlier chunk's report was unread, and
         # first tokens the host read after their admission had returned
@@ -1646,9 +1725,11 @@ class DecodeSession:
                 result = _decode_chunk(
                     self.params, self.config, self.state, table_dev,
                     **self._statics)
-            # a model with expert layers hands its count back beside the
-            # carry (`_chunk_loop`); it stays on the device until the read
-            if self.config.live_rows_dispatch and not self.spec:
+            # a model with expert layers, and the serving mode, hand their
+            # counts back beside the carry (`_chunk_loop`); they stay on the
+            # device until the read
+            if ((self.config.live_rows_dispatch or self.per_row)
+                    and not self.spec):
                 result, flight.hit = result
             self.state = s = result
             if self.block:
@@ -1660,7 +1741,7 @@ class DecodeSession:
             elif self.per_row:
                 flight.meta, flight.rows = _beat_report(
                     s[0], s[1], s[5], s[7],
-                    self._no_hit if flight.hit is None else flight.hit,
+                    self._nothing_seen if flight.hit is None else flight.hit,
                     width=self._report_width)
                 flight.meta.copy_to_host_async()
                 flight.rows.copy_to_host_async()
@@ -1689,9 +1770,9 @@ class DecodeSession:
                 hit = 0 if item.hit is None else int(item.hit)
             else:
                 meta, rows = np.asarray(item.meta), np.asarray(item.rows)
-                it_now, hit = meta[:2]
+                it_now, hit, taken = meta[:3]
                 if self.block:
-                    self.block_counts = meta[2:].astype(np.int64)
+                    self.block_counts = meta[3:].astype(np.int64)
         now = time.perf_counter()
         it_now = int(it_now) - 1
         its = it_now - self._it_prev
@@ -1705,6 +1786,9 @@ class DecodeSession:
             self._hub.record("latency/intertoken_s",
                              (now - since) / max(1, its))
         self.held_experts_hit += int(hit)
+        if item.rows is not None and not self.spec:
+            self.sample_rows += int(taken)
+            self.sample_slots += its * self.rows * max(self.block, 1)
         if item.rows is None:
             self._count_attention(its, done_h)
             report = BeatReport(its, done_h)

@@ -359,6 +359,19 @@ def _nucleus_candidates(logits, top_p, top_k, approx_top_k):
     return top_logits, top_idx, keep
 
 
+def _categorical_rows(key, kept, draw=None):
+    """`jax.random.categorical(key, kept)` over the last axis; with
+    `draw = (idx [s], N)`, `kept` [s, K] holds the rows `idx` of an `[N, K]`
+    batch and each draws what it would have drawn there: the Gumbel noise is
+    drawn at the batch's shape from `key` and gathered, so a row's token
+    does not depend on which other rows were scored beside it."""
+    if draw is None:
+        return jax.random.categorical(key, kept, axis=-1)
+    idx, n = draw
+    noise = jax.random.gumbel(key, (n, kept.shape[-1]), kept.dtype)
+    return jnp.argmax(noise[idx] + kept, axis=-1)
+
+
 def filtered_logits_full(logits, temperature, top_p, top_k, approx_top_k):
     """Full-vocab filtered/temperature-scaled logits whose softmax is
     EXACTLY the distribution `_sample_token` draws from (same candidate

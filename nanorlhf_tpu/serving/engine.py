@@ -471,9 +471,9 @@ class ServingEngine:
                     got.new_tokens(r, 0) if self.spec_k > 0 else None))
 
     def _finish(self, r, req, gen_tokens=None, **counted):
-        """Row `r`'s request is over: close its stream, release the row,
-        count it (`counted`: further counters and what each gains)."""
-        req.out_q.put(None)
+        """Row `r`'s request is over: release the row, count it (`counted`:
+        further counters and what each gains), then close its stream, so
+        whoever reads the stream's end finds the request counted."""
         self._sess.release(r, gen_tokens=gen_tokens)
         self._owner[r] = None
         with self._cond:
@@ -482,6 +482,7 @@ class ServingEngine:
                 self._counters[name] += n
             self._n_active -= 1
             self._cond.notify_all()
+        req.out_q.put(None)
 
     def _deliver_blocks(self, got):
         """`_deliver` for a model that generates by blocks: a report brings
@@ -587,6 +588,12 @@ class ServingEngine:
             "serving/state_piece_carries": self._sess.state_piece_carries,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
+            # the rows (generation by blocks: positions) the sampler ran
+            # over, and those a step has: the needed ones are gathered
+            # into an eighth, a quarter, a half or all of them
+            # (docs/PAGED_CACHE.md "The rows a step scores")
+            "serving/sample_rows": self._sess.sample_rows,
+            "serving/sample_slots": self._sess.sample_slots,
             "pages/shared": snap["shared_pages"],
         }
         if self.block_length:

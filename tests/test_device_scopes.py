@@ -318,7 +318,7 @@ def test_what_an_admission_does_beside_its_forward_is_install():
             greedy=False, **sampling),
         "_beat_report": session._beat_report.lower(
             spec((), jnp.int32), spec((R, NEW), jnp.int32),
-            spec((R,), jnp.bool_), spec((R,), jnp.int32), spec((), jnp.int32),
+            spec((R,), jnp.bool_), spec((R,), jnp.int32), spec((2,), jnp.int32),
             width=4),
         "_end_row": session._end_row.lower(spec((R,), jnp.bool_),
                                            spec((), jnp.int32)),

@@ -350,9 +350,11 @@ def test_the_choice_follows_the_rule(strategy, n):
 def test_confidence_is_the_drawn_tokens_probability():
     logits = jax.random.normal(jax.random.PRNGKey(3), (3, B, V)) * 3.0
     greedy = jnp.asarray([True, False, False])
-    tok, conf = blockdiff.sample_positions(
-        jax.random.PRNGKey(4), logits, jnp.asarray([1.0, 0.7, 1.0]),
-        jnp.asarray([1.0, 0.9, 1.0]), greedy, top_k=V, approx_top_k=False)
+    per = lambda a: jnp.repeat(jnp.asarray(a), B)   # noqa: E731
+    tok, conf = (a.reshape(3, B) for a in blockdiff.sample_positions(
+        jax.random.PRNGKey(4), logits.reshape(3 * B, V),
+        per([1.0, 0.7, 1.0]), per([1.0, 0.9, 1.0]), per(greedy), top_k=V,
+        approx_top_k=False))
     p = np.asarray(jax.nn.softmax(logits, axis=-1))
     np.testing.assert_array_equal(np.asarray(tok[0]), p[0].argmax(-1))
     np.testing.assert_allclose(np.asarray(conf[0]), p[0].max(-1), rtol=1e-5)
