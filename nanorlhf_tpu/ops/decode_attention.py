@@ -632,17 +632,47 @@ def paged_decode_plan(table, start, filled, *, page_size: int, num_pages: int,
                            filled)
 
 
+def _paged_item_fold(q, k, v, valid, state, scale: float):
+    """Fold one work item into its row's online softmax, every KV head at
+    once: ONE batched product for the scores, one max / exp / sum over the
+    whole block, one batched product with V. Head by head (PR 28's loop) the
+    same arithmetic was `KV` dependent chains of product, reduce, exp,
+    reduce, product that the copies waited beside: 2.33 µs an item at
+    Trinity's 8 heads against 1.39 for its copies; at once it is 0.49, under
+    them (docs/PAGED_CACHE.md "The read's cost"). q: [KV, Gp, hd]; k, v:
+    [KV, S, hd], the item's S slots; valid: [Gp, S]; state: (m, l
+    [KV, Gp, 1], acc [KV, Gp, hd]) float32. Returns the new state."""
+    m_prev, l_prev, acc_prev = state
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale                                                # [KV, Gp, S]
+    s = jnp.where(valid[None], s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+    # probabilities enter the PV product in the cache's dtype, as in the
+    # plain path (`gqa_attention`); the sums stay float32
+    acc_new = acc_prev * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc_new
+
+
 def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
                          start_ref, filled_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, sem, acc_ref, m_ref, l_ref,
-                         *, scale: float, n_rows: int):
+                         kbuf, vbuf, sem, *, scale: float, n_rows: int):
     """One tile of rows: walk the tile's work items. An item is up to C
     consecutive pages of one row, each page one DMA for ALL kv heads
     ([KV, P, hd] is contiguous in the pool), fetched from the stack in HBM
-    into a two-slot buffer while the previous item is computed; online
-    softmax per (row, kv head) as in `_decode_kernel`."""
+    into a ring of buffer slots, as many items ahead as there are slots to
+    spare, while the items before it are folded (`_paged_item_fold`); the
+    online softmax's state of the row in hand rides the loop."""
     tile_rows, KV, Gp, _ = q_ref.shape
-    _, _, C, P, hd = kbuf.shape
+    slots, _, C, P, hd = kbuf.shape
+    ahead = slots - 1
     num_pages, nb = k_hbm.shape[1], table_ref.shape[1]
     r0 = pl.program_id(0) * tile_rows
     lo = off_ref[r0]
@@ -650,8 +680,8 @@ def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
     layer = layer_ref[0]
 
     def pages(i, act):
-        """Start or wait for the copies of item i's pages into slot i % 2."""
-        slot, row, blk = i % 2, row_ref[i], blk_ref[i]
+        """Start or wait for the copies of item i's pages into its slot."""
+        slot, row, blk = i % slots, row_ref[i], blk_ref[i]
         n = (filled_ref[row] - 1) // P - blk + 1
         for c in range(C):
             @pl.when(c < n)
@@ -669,59 +699,43 @@ def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
     if C > 1:
         vbuf[...] = jnp.zeros_like(vbuf)
 
-    @pl.when(lo < hi)
-    def _first_fetch():
-        pages(lo, lambda copy: copy.start())
+    for d in range(ahead):
+        @pl.when(lo + d < hi)
+        def _first_fetch():
+            pages(lo + d, lambda copy: copy.start())
 
-    def item(i, carry):
-        @pl.when(i + 1 < hi)
+    def item(i, state):
+        # before the wait for item i, into the slot item i - 1 has left
+        @pl.when(i + ahead < hi)
         def _next_fetch():
-            pages(i + 1, lambda copy: copy.start())
+            pages(i + ahead, lambda copy: copy.start())
 
-        row, blk, slot = row_ref[i], blk_ref[i], i % 2
+        row, blk, slot = row_ref[i], blk_ref[i], i % slots
         r = row - r0
         start, filled = start_ref[row], filled_ref[row]
-
-        @pl.when(blk == start // P)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        # a row's first item starts its softmax anew
+        first = blk == start // P
+        state = tuple(jnp.where(first, fresh, x) for fresh, x in
+                      zip((NEG_INF, 0.0, 0.0), state))
 
         pages(i, lambda copy: copy.wait())
         pos = blk * P + jax.lax.broadcasted_iota(jnp.int32, (Gp, C * P), 1)
-        valid = (pos >= start) & (pos < filled)
-        for h in range(KV):
-            s = jax.lax.dot_general(
-                q_ref[r, h], kbuf[slot, h].reshape(C * P, hd),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                        # [Gp, C * P]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[h, :, :1]
-            l_prev = l_ref[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            v = vbuf[slot, h].reshape(C * P, hd)
-            # probabilities enter the PV product in the cache's dtype, as in
-            # the plain path (`gqa_attention`); the sums stay float32
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        state = _paged_item_fold(
+            q_ref[r], kbuf[slot].reshape(KV, C * P, hd),
+            vbuf[slot].reshape(KV, C * P, hd),
+            (pos >= start) & (pos < filled), state, scale)
 
         @pl.when(blk + C > (filled - 1) // P)
         def _finalize():
-            l = jnp.maximum(l_ref[:, :, :1], 1e-30)
-            o_ref[r] = (acc_ref[...] / l).astype(o_ref.dtype)
+            _, l, acc = state
+            o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-        return carry
+        return state
 
-    jax.lax.fori_loop(lo, hi, item, None)
+    jax.lax.fori_loop(lo, hi, item, (
+        jnp.full((KV, Gp, 1), NEG_INF, jnp.float32),
+        jnp.zeros((KV, Gp, 1), jnp.float32),
+        jnp.zeros((KV, Gp, hd), jnp.float32)))
 
 
 # a tile of rows keeps its queries and outputs in VMEM: at most this many
@@ -767,13 +781,12 @@ def paged_decode_attention(
         in_specs=[rows_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows_spec,
+        # three buffer slots: the copies of two items run under the third's
+        # fold (at items of 0.5 MB one item ahead left the copies exposed)
         scratch_shapes=[
-            pltpu.VMEM((2, KV, C, P, hd), k_pool.dtype),
-            pltpu.VMEM((2, KV, C, P, hd), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((KV, Gp, hd), jnp.float32),
-            pltpu.VMEM((KV, Gp, 128), jnp.float32),
-            pltpu.VMEM((KV, Gp, 128), jnp.float32),
+            pltpu.VMEM((3, KV, C, P, hd), k_pool.dtype),
+            pltpu.VMEM((3, KV, C, P, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
     out = pl.pallas_call(

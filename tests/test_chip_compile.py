@@ -12,6 +12,7 @@ compile cache is off around the compiles: an entry written for a described
 device cannot be read back without one, and the next run would warn.
 """
 
+import functools
 import os
 import subprocess
 import sys
@@ -106,9 +107,12 @@ def _verify(B=32, T=2048, Tq=4):
         ((B, KV, T, HD), jnp.bfloat16), ((B,), jnp.int32), ((B,), jnp.int32)]
 
 
-def _paged_decode(B=64, nb=12, L=28, N=807):
+def _paged_decode(B=64, nb=12, L=28, N=807, H=H, KV=KV):
     """The in-place read at the serving cell's shape: 64 rows of 12 blocks
-    over the whole stack of 28 x 807 pages, the layer a scalar."""
+    over the whole stack of 28 x 807 pages, the layer a scalar. (The other
+    served geometries, whose buffer slots, query tiles and batched products
+    differ: Trinity's 8 KV heads and items of two pages, SDAR's block read
+    with 32 query rows a KV head in two tiles, OLMoE's 16 heads a page.)"""
     from nanorlhf_tpu.ops.decode_attention import (
         paged_decode_attention, paged_decode_plan, paged_pages_per_item,
     )
@@ -173,7 +177,14 @@ def _fused_bwd():
 
 CASES = {"flash_fwd": _flash, "flash_bwd": _flash_bwd, "decode": _decode,
          "decode_q8": _decode_q8, "verify": _verify,
-         "paged_decode": _paged_decode, "paged_decode_q8": _paged_decode_q8,
+         "paged_decode": _paged_decode,
+         "paged_decode_trinity": functools.partial(
+             _paged_decode, B=32, nb=72, L=4, N=1344, H=48, KV=8),
+         "paged_decode_sdar_block": functools.partial(
+             _paged_decode, B=64, nb=25, L=7, N=1625, H=128, KV=4),
+         "paged_decode_olmoe": functools.partial(
+             _paged_decode, B=64, nb=6, L=16, N=400, H=16, KV=16),
+         "paged_decode_q8": _paged_decode_q8,
          "paged_verify": _paged_verify, "fused_fwd": _fused,
          "fused_bwd": _fused_bwd}
 

@@ -153,21 +153,27 @@ _PAGED_ROWS = [
                                        (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("layer", [0, 1, 2])
-@pytest.mark.parametrize("KV,G", [(2, 6), (16, 1)], ids=["gqa2x6", "mha16"])
+@pytest.mark.parametrize("KV,G", [(2, 6), (16, 1), (4, 7), (8, 6), (4, 32)],
+                         ids=["gqa2x6", "mha16", "gqa4x7", "gqa8x6", "block4x32"])
 @pytest.mark.parametrize("item_pages,tile_rows,items", [
-    (1, 4, [3, 3, 1, 0, 0, 0, 4, 3, 5]), (4, 9, [1, 1, 1, 0, 0, 0, 1, 1, 2])],
-    ids=["1page-3tiles", "4pages-1tile"])
+    (1, 4, [3, 3, 1, 0, 0, 0, 4, 3, 5]), (4, 9, [1, 1, 1, 0, 0, 0, 1, 1, 2]),
+    (2, 4, [2, 2, 1, 0, 0, 0, 2, 2, 3])],
+    ids=["1page-3tiles", "4pages-1tile", "2pages-3tiles"])
 def test_paged_decode_kernel_matches_oracle(rng, monkeypatch, item_pages,
                                             tile_rows, items, KV, G, layer,
                                             dtype, tol):
     """The in-place read (whole stacks in, the layer a scalar, the step's
     work list from `paged_decode_plan`) against the gathered-view oracle on
-    the layer's slab: Qwen's and OLMoE's head geometry, each layer of a
-    stack, scattered and shared pages, a sentinel past a row's last block,
-    and rows without work (done, released, empty) among live ones, which
-    read zero; with a work item of one page (what OLMoE's 512 KB pages get
-    on the chip) and of four (Qwen2.5's), the last item of a row short; with
-    the nine rows in one tile and in three (the last one padded)."""
+    the layer's slab: Qwen's and OLMoE's head geometry, SmallThinker's,
+    Trinity's and SDAR's block read (a block's 4 positions x 8 heads a KV
+    head), each layer of a stack, scattered and shared pages, a sentinel
+    past a row's last block, and rows without work (done, released, empty)
+    among live ones, which read zero; with a work item of one page (what
+    OLMoE's 512 KB pages get on the chip), of two (Trinity's) and of four
+    (Qwen2.5's), the last item of a row short; with the nine rows in one
+    tile and in three (the last one padded; with items of two pages the
+    middle tile's first row has no item and its last row's last item is
+    short)."""
     from nanorlhf_tpu.ops import decode_attention
     from nanorlhf_tpu.ops.decode_attention import (
         paged_decode_attention, paged_decode_plan, paged_pages_per_item,
