@@ -4,6 +4,7 @@
     python chip_smoke.py --multichip  # four chips: sharded GRPO vs one device
     python chip_smoke.py --olmoe      # one chip:   the sparse-expert layer
     python chip_smoke.py --axk1       # one chip:   latent attention + a chip's share
+    python chip_smoke.py --falcon-h1  # one chip:   a state-space mixer beside attention
 
 One process. It pins no platform: the first thing it does after `import jax`
 is read `jax.devices()`, print what it found, and exit non-zero unless that is
@@ -51,6 +52,15 @@ of a thousand tokens (the expanded form) and teacher-forced single-token
 steps through the contiguous latent cache (the absorbed form), each held to
 what the plain bf16 forward loses against float32 (`phase_axk1`). The paged
 session at these widths is the benchmark cell `serve-axk1-docqa` itself.
+
+`--falcon-h1` runs Falcon-H1 alone, as one pipeline stage holds it
+(`benchmark/configs/falcon-h1-34b-l5.json`: published widths, 5 layers, the
+whole vocabulary; 9.65 GB of bf16 weights under the file's `assumed.init`),
+against `benchmark/harness/reference_falcon_h1.py`: the scoring forward (the
+scan in chunks over the whole row), then a prefill in TWO pieces whose second
+takes both state leaves over and teacher-forced single-token steps through
+the contiguous cache (`phase_falcon_h1`). The paged session at these widths
+is the benchmark cell `serve-falcon-h1-assist` itself.
 
 Sizes live in `Sizes`; a rehearsal on the CPU imports this module and passes
 smaller ones (tests and scratch scripts steer, the program grows no option).
@@ -121,6 +131,13 @@ class Sizes:
     axk1_prompt: int = 1000        # prefill: past one token block of the share
     axk1_decode: int = 24          # absorbed single-token steps
     axk1_last: int = 64            # logits compared on the last positions
+    # falcon-h1: the benchmark's configuration file, or a tiny one to rehearse
+    fh1_config: str = "benchmark/configs/falcon-h1-34b-l5.json"
+    fh1_rows: int = 2
+    fh1_prompt: int = 1100         # prefill: a piece of 1,024 and one of 76
+    fh1_piece: int = 1024
+    fh1_decode: int = 24           # single-token passes over the state
+    fh1_last: int = 64             # logits compared on the last positions
 
 
 def emit(phase: str, **fields) -> None:
@@ -1077,8 +1094,103 @@ def phase_axk1(sz: Sizes, meter: Meter) -> None:
                  contiguous=contiguous)
 
 
+# --------------------------------------------------------------------------- #
+# a state-space mixer beside attention in every layer
+# --------------------------------------------------------------------------- #
+
+
+def phase_falcon_h1(sz: Sizes, meter: Meter) -> None:
+    """Falcon-H1 through the normal path against the plain float32
+    reference, under `bf16_agreement`'s rule on logits: the path under test
+    may be BF16_SLACK times further from float32 than the plain bf16 forward
+    (XLA attention, no cache), both measured here on the same positions. The
+    contiguous cache takes the prompt in two pieces (`prefill`, then a
+    `decode_verify` that takes both state leaves over) and then a pass over
+    the state a step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from drivers import serve_ssm_ref
+    from harness import reference_falcon_h1
+
+    from nanorlhf_tpu.core import (ModelConfig, decode_step, init_kv_cache,
+                                   init_params, padded_forward_logits, prefill)
+    from nanorlhf_tpu.core.model import decode_verify
+
+    phase = Phase("falcon_h1", meter)
+    with open(os.path.join(ROOT, sz.fh1_config)) as f:
+        file = json.load(f)
+    mcfg = ModelConfig.from_hf_config(file)
+    plain_mcfg = dataclasses.replace(mcfg, attention_impl="xla")
+    check(mcfg.ssm_layers == file["num_hidden_layers"]
+          and mcfg.ssm_state == file["mamba_d_state"]
+          and mcfg.ssm_multipliers == tuple(file["ssm_multipliers"]),
+          "from_hf_config dropped the mixer or its multipliers")
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    key = jax.random.PRNGKey(sz.seed)
+    params = serve_ssm_ref.spread(
+        jax.jit(lambda k: init_params(mcfg, k, dtype))(key),
+        file["assumed"].get("init"), sz.seed)
+    pad = 0
+    P, piece, n_new = sz.fh1_prompt, sz.fh1_piece, sz.fh1_decode
+    T, last, rows = P + n_new, sz.fh1_last, sz.fh1_rows
+    ids = np.array(jax.random.randint(jax.random.fold_in(key, 2), (rows, T), 3,
+                                      mcfg.vocab_size))
+    ids[0, : piece // 8] = pad                             # one left-padded row
+    ids = jnp.asarray(ids, jnp.int32)
+    real = ids != pad
+
+    def within(tested, plain, ref, what):
+        return bf16_agreement(phase, tested, plain, ref,
+                              np.ones(np.shape(ref), bool), (what, "plain"))
+
+    with jax.default_matmul_precision("highest"):
+        ref_last = np.asarray(jax.jit(lambda p, x, m: reference_falcon_h1.logits(
+            p, file, x, pad, last=last, mask=m))(params, ids, real))
+    forward = lambda m: np.asarray(jax.jit(lambda p, x: padded_forward_logits(  # noqa: E731
+        p, m, x, pad)[:, -last:])(params, ids), np.float32)
+    plain_last = forward(plain_mcfg)
+    scoring = within(forward(mcfg), plain_last, ref_last, "auto_scoring")
+
+    # ---- two pieces, then teacher-forced decode, the contiguous cache ----
+    steps = slice(last - n_new - 1, last)    # positions P-1 .. T-1: n_new + 1
+    caches = init_kv_cache(mcfg, rows, T, dtype)
+    tail, state = caches[2]
+    phase.expect(state.dtype == jnp.float32 and state.shape[1:] == (
+        rows, mcfg.ssm_heads, mcfg.ssm_head_dim, mcfg.ssm_state),
+        f"the recurrent state is {state.dtype}{state.shape}")
+    _, caches = jax.jit(lambda p, x, m, c: prefill(p, mcfg, x, m, c))(
+        params, ids[:, :piece], real[:, :piece], caches)
+    n_real = real[:, :piece].sum(axis=1)
+    key_mask = jnp.zeros((rows, T), bool).at[:, :piece].set(real[:, :piece])
+    lg, caches = jax.jit(lambda p, x, pos, km, c: decode_verify(
+        p, mcfg, x, pos, jnp.full((rows,), piece, jnp.int32), km, c))(
+        params, ids[:, piece:P],
+        n_real[:, None] + jnp.arange(P - piece)[None], key_mask, caches)
+    key_mask = key_mask.at[:, piece:P].set(True)
+    step = jax.jit(lambda p, tok, pos, t, km, c: decode_step(
+        p, mcfg, tok, pos, t, km, c))
+    got = [np.asarray(lg[:, -1], np.float32)]
+    for t in range(P, T):
+        key_mask = key_mask.at[:, t].set(True)
+        lg, caches = step(params, ids[:, t], n_real + (t - piece), jnp.int32(t),
+                          key_mask, caches)
+        got.append(np.asarray(lg, np.float32))
+    del caches
+    contiguous = within(np.stack(got, axis=1), plain_last[:, steps],
+                        ref_last[:, steps], "contiguous_cache")
+    phase.finish(config=sz.fh1_config, layers=mcfg.num_hidden_layers,
+                 hidden=mcfg.hidden_size, ssm_heads=mcfg.ssm_heads,
+                 ssm_state=mcfg.ssm_state, vocab=mcfg.vocab_size,
+                 dtype=str(jnp.dtype(dtype)), rows=rows, tokens=T,
+                 pieces=(piece, P - piece), decode_steps=n_new,
+                 scoring=scoring, contiguous=contiguous)
+
+
 def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False,
-               axk1: bool = False) -> None:
+               axk1: bool = False, falcon_h1: bool = False) -> None:
     """Everything after the device gate. Raises at the first failed phase."""
     from nanorlhf_tpu import native
     from nanorlhf_tpu.core import ModelConfig
@@ -1099,6 +1211,9 @@ def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False,
     if axk1:
         phase_axk1(sz, meter)
         return
+    if falcon_h1:
+        phase_falcon_h1(sz, meter)
+        return
     tiny = "tiny" in sz.model.lower()  # entrypoints.common.resolve_model's rule
     phase_kernels(sz, ModelConfig.qwen2_tiny(vocab_size=4096) if tiny
                   else ModelConfig.qwen2_1_5b(), meter)
@@ -1114,6 +1229,8 @@ def main(argv=None) -> int:
                         help="one chip: OLMoE against its float32 reference, only")
     parser.add_argument("--axk1", action="store_true",
                         help="one chip: A.X-K1's share against its float32 reference, only")
+    parser.add_argument("--falcon-h1", action="store_true",
+                        help="one chip: Falcon-H1's stage against its float32 reference, only")
     args = parser.parse_args(argv)
 
     import jax
@@ -1131,7 +1248,7 @@ def main(argv=None) -> int:
               f"{device['count']}", file=sys.stderr)
         return 1
 
-    run_phases(Sizes(), args.multichip, args.olmoe, args.axk1)
+    run_phases(Sizes(), args.multichip, args.olmoe, args.axk1, args.falcon_h1)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -138,6 +138,34 @@ class ModelConfig:
     # beside the attention layers' pages (`conv_layers`, `stack_pattern`).
     layer_types: tuple = ()
     conv_L_cache: int = 0
+    # Falcon-H1's layer (docs/SSM.md): `layer_types[l] == "hybrid"` is a
+    # Mamba-2 state-space mixer and an attention of `num_attention_heads`
+    # SIDE BY SIDE, both reading the one normed state and both added into
+    # the one residual, so the layer keeps pages AND a state. The mixer:
+    # `ssm_heads` heads of `ssm_head_dim`, B and C in `ssm_groups` groups of
+    # `ssm_state` (a recurrent state `[ssm_head_dim, ssm_state]` a head,
+    # float32), a causal depthwise convolution of `ssm_conv` taps with a
+    # bias over `[xs | B | C]`, a scan in chunks of `ssm_chunk` at prefill.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 128
+    # Falcon-H1's muP multipliers, the published keys under the tree's
+    # names, applied where the published code applies them: on the
+    # attention's input, on k, on its output; on the mixer's input, on the
+    # five parts `[z | xs | B | C | dt]` of its input projection, on its
+    # output; on the MLP's gate (before the SiLU) and down projection; on
+    # the logits. (The embedding's is `embed_scale`.) 1.0 stages nothing.
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
     # RMSNorm of q and k over each HEAD (a weight of `head_dim`), after the
     # head split and before RoPE (LFM2's q_layernorm / k_layernorm).
     qk_norm_per_head: bool = False
@@ -251,8 +279,8 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> tuple:
-        """Every layer's kind, in model order: `"conv"` (`layer_types`), or
-        an attention layer's `(window, rotary)`."""
+        """Every layer's kind, in model order: `"conv"` or `"hybrid"`
+        (`layer_types`), or an attention layer's `(window, rotary)`."""
         L = self.num_hidden_layers
         win = tuple(bool(w) for w in self.sliding_window_layout) or (False,) * L
         rot = tuple(bool(r) for r in self.rope_layout) or (True,) * L
@@ -264,7 +292,7 @@ class ModelConfig:
                 f"layer layouts of {len(kinds)} and {len(self.layer_types)} "
                 f"entries for {L} layers")
         if self.layer_types:
-            kinds = tuple("conv" if t == "conv" else k
+            kinds = tuple(t if t in ("conv", "hybrid") else k
                           for t, k in zip(self.layer_types, kinds))
         return kinds
 
@@ -296,9 +324,38 @@ class ModelConfig:
         return sum(k == "conv" for k in self.layer_kinds)
 
     @property
+    def ssm_layers(self) -> int:
+        """Layers with a state-space mixer beside their attention, which
+        keep a state AND pages (0 in a model without)."""
+        return sum(k == "hybrid" for k in self.layer_kinds)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a state a row, of either form (docs/STATE.md):
+        what a session sizes, donates, resets and reports a state by."""
+        return self.conv_layers + self.ssm_layers
+
+    @property
+    def state_what(self) -> str:
+        """The model as a refusal names it, by what keeps the state."""
+        layers = "state-space layers" if self.ssm_layers else "conv layers"
+        return f"a model with {layers} ({self.model_type})"
+
+    @property
+    def ssm_inner(self) -> int:
+        """The mixer's width, `ssm_heads * ssm_head_dim` (`mamba_d_ssm`)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of the mixer's convolution, `[xs | B | C]`."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def window_layers(self) -> int:
         """Layers with a sliding window (0 without a pattern)."""
-        return sum(k != "conv" and k[0] for k in self.layer_kinds)
+        return sum(k not in ("conv", "hybrid") and k[0]
+                   for k in self.layer_kinds)
 
     @property
     def block_generation(self) -> bool:
@@ -549,6 +606,52 @@ class ModelConfig:
                          * layers)[:layers])
 
     @classmethod
+    def falcon_h1_34b(cls) -> "ModelConfig":
+        """tiiuae/Falcon-H1-34B-Instruct (docs/SSM.md): 72 layers, each a
+        Mamba-2 mixer (32 heads of 128, 2 groups, state 256, 4 taps) beside
+        an attention of 20 / 4 heads of 128, then a SwiGLU of 21,504; muP
+        multipliers as published; vocabulary 261,120, untied."""
+        return cls(
+            vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+            num_hidden_layers=72, num_attention_heads=20,
+            num_key_value_heads=4, head_dim=128, rope_theta=1e11,
+            rms_norm_eps=1e-5, tie_word_embeddings=False,
+            max_position_embeddings=262144, attention_bias=False,
+            model_type="falcon_h1", layer_types=("hybrid",) * 72,
+            ssm_heads=32, ssm_head_dim=128, ssm_groups=2, ssm_state=256,
+            ssm_conv=4, ssm_chunk=128,
+            embed_scale=5.656854249492381,
+            attention_in_multiplier=1.0,
+            key_multiplier=0.011048543456039804,
+            attention_out_multiplier=0.0375,
+            ssm_in_multiplier=0.25,
+            ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369,
+                             0.5, 0.3535533905932738),
+            ssm_out_multiplier=0.08838834764831845,
+            mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+            lm_head_multiplier=0.0078125,
+        )
+
+    @classmethod
+    def falcon_h1_tiny(cls, vocab_size: int = 512,
+                       layers: int = 2) -> "ModelConfig":
+        """Test-size Falcon-H1: the same layer at 4 / 2 heads of 16, a mixer
+        of 4 heads of 16 in 2 groups of state 8, chunks of 4 tokens, and
+        EVERY multiplier at a value of its own other than 1."""
+        return dataclasses.replace(
+            cls.falcon_h1_34b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            max_position_embeddings=1024, layer_types=("hybrid",) * layers,
+            ssm_heads=4, ssm_head_dim=16, ssm_groups=2, ssm_state=8,
+            ssm_chunk=4, rope_theta=1e4,
+            embed_scale=1.7, attention_in_multiplier=0.9,
+            key_multiplier=0.6, attention_out_multiplier=0.7,
+            ssm_in_multiplier=0.8, ssm_multipliers=(0.9, 0.7, 0.6, 0.8, 1.3),
+            ssm_out_multiplier=0.5, mlp_multipliers=(0.75, 0.4),
+            lm_head_multiplier=0.3)
+
+    @classmethod
     def trinity_large(cls) -> "ModelConfig":
         """arcee-ai/Trinity-Large-Preview (`afmoe`): 60 GQA layers of 48 / 8
         heads of 128 in periods of [window 4,096 with rotary x 3, global
@@ -694,7 +797,7 @@ class ModelConfig:
         """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
         (or dict), or from A.X-K1's `config.json` (`model_type: axk1`,
         DeepSeek-V3's key set; `_axk1_from_hf`), SmallThinker's, LFM2-MoE's,
-        Trinity's (`afmoe`) or SDAR's (`sdar_moe`: generation by diffusion
+        Trinity's (`afmoe`), Falcon-H1's (`falcon_h1`, docs/SSM.md) or SDAR's (`sdar_moe`: generation by diffusion
         over blocks, docs/BLOCKDIFF.md), each by a parser of its own. A config the
         decoder does not implement raises: expert keys under any other model
         type (each family routes, scales and shares in its own way), and a
@@ -715,6 +818,8 @@ class ModelConfig:
             return cls._lfm2_from_hf(get)
         if model_type == "afmoe":
             return cls._afmoe_from_hf(get)
+        if model_type == "falcon_h1":
+            return cls._falcon_h1_from_hf(get)
         if model_type == "sdar_moe":
             return cls._sdar_from_hf(get)
         # a window on a family this decoder builds with full attention only:
@@ -973,6 +1078,79 @@ class ModelConfig:
             # are the 128 lanes wide that the paged kernels read
             kv_head_pack=2 if (hd <= 64 and KV % 2 == 0) else 1,
             expert_token_block=4096,
+        )
+
+    @classmethod
+    def _falcon_h1_from_hf(cls, get) -> "ModelConfig":
+        """Falcon-H1's published keys (docs/SSM.md). What the layer of
+        docs/SSM.md does not compute raises, by the key's name."""
+        def refuse(key, why):
+            raise ValueError(f"falcon_h1: {key}={get(key)!r} is not "
+                             f"implemented ({why}; docs/SSM.md)")
+
+        if get("mamba_rms_norm") is False:
+            refuse("mamba_rms_norm", "the mixer's output goes through the "
+                   "gated group norm")
+        if get("mamba_norm_before_gate"):
+            refuse("mamba_norm_before_gate", "the gate is applied before "
+                   "the norm")
+        if get("attn_layer_indices") is not None:
+            refuse("attn_layer_indices", "every layer holds an attention "
+                   "beside its mixer")
+        for key in ("mamba_proj_bias", "projectors_bias", "mlp_bias",
+                    "attention_bias"):
+            if get(key):
+                refuse(key, "the projections carry no bias")
+        if get("mamba_conv_bias") is False:
+            refuse("mamba_conv_bias", "the mixer's convolution has a bias")
+        if get("rope_scaling"):
+            refuse("rope_scaling", "plain rotary embedding only")
+        if str(get("hidden_act", "silu")) != "silu":
+            refuse("hidden_act", "SiLU in the mixer and the MLP")
+        heads, head = int(get("mamba_n_heads")), int(get("mamba_d_head"))
+        if int(get("mamba_d_ssm") or heads * head) != heads * head:
+            refuse("mamba_d_ssm", f"mamba_n_heads * mamba_d_head = "
+                   f"{heads * head}")
+        groups = int(get("mamba_n_groups"))
+        if heads % groups:
+            refuse("mamba_n_groups", f"must divide mamba_n_heads = {heads}")
+        if int(get("mamba_d_conv")) < 2:
+            refuse("mamba_d_conv", "a tail of K - 1 values needs K >= 2")
+        mults = {key: tuple(float(m) for m in get(key) or ())
+                 for key in ("ssm_multipliers", "mlp_multipliers")}
+        for key, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            if len(mults[key]) != n:
+                refuse(key, f"{n} numbers")
+        L = int(get("num_hidden_layers"))
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=L,
+            num_attention_heads=int(get("num_attention_heads")),
+            num_key_value_heads=int(get("num_key_value_heads")),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1e11)),
+            rms_norm_eps=get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 262144),
+            attention_bias=False,
+            model_type="falcon_h1",
+            layer_types=("hybrid",) * L,
+            ssm_heads=heads, ssm_head_dim=head, ssm_groups=groups,
+            ssm_state=int(get("mamba_d_state")),
+            ssm_conv=int(get("mamba_d_conv")),
+            ssm_chunk=int(get("mamba_chunk_size") or 128),
+            embed_scale=float(get("embedding_multiplier", 1.0)),
+            attention_in_multiplier=float(get("attention_in_multiplier", 1.0)),
+            key_multiplier=float(get("key_multiplier", 1.0)),
+            attention_out_multiplier=float(
+                get("attention_out_multiplier", 1.0)),
+            ssm_in_multiplier=float(get("ssm_in_multiplier", 1.0)),
+            ssm_multipliers=mults["ssm_multipliers"],
+            ssm_out_multiplier=float(get("ssm_out_multiplier", 1.0)),
+            mlp_multipliers=mults["mlp_multipliers"],
+            lm_head_multiplier=float(get("lm_head_multiplier", 1.0)),
         )
 
     @staticmethod

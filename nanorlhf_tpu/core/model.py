@@ -93,7 +93,7 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
         from nanorlhf_tpu.core import mla
 
         return mla.init_params(config, key, dtype)
-    if (config.conv_layers or config.num_dense_layers
+    if (config.state_layers or config.num_dense_layers
             or config.n_shared_experts or config.experts_held):
         return _init_stacked_model_params(config, key, dtype)
     hd = config.actual_head_dim
@@ -193,6 +193,7 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
         kinds = config.layer_kinds[start:start + n]
         nc = sum(k == "conv" for k in kinds)
         na = n - nc
+        ns = sum(k == "hybrid" for k in kinds)  # (an attention layer too)
         fan = lambda *shape: normal(shape, 1.0 / jnp.sqrt(shape[-2]))  # noqa: E731
         swiglu = lambda lead, width: {  # noqa: E731
             "gate_proj": {"kernel": fan(*lead, D, width)},
@@ -218,6 +219,8 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
                 "in_proj": {"kernel": fan(nc, D, 3 * D)},
                 "conv": {"kernel": normal((nc, K, D), 1.0 / jnp.sqrt(K))},
                 "out_proj": {"kernel": fan(nc, D, D)}}
+        if ns:
+            tree["ssm"] = _init_ssm(config, ns, fan, normal, next(keys), dtype)
         if na:
             tree.update({
                 "q_proj": {"kernel": fan(na, D, H * hd)},
@@ -240,6 +243,39 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
     if not config.tie_word_embeddings:
         params["lm_head"] = normal((D, V), 0.02)
     return params
+
+
+def _init_ssm(config: ModelConfig, n: int, fan, normal, key, dtype) -> dict:
+    """The state-space mixers of `n` layers (docs/SSM.md): `in_proj.kernel
+    [n, D, 2 I + 2 G N]` (`[z | xs | B | C]`) and `dt_proj.kernel [n, D, H]`
+    (the published input projection's last H columns, a leaf of their own:
+    2 I + 2 G N is whole 128-lane tiles and with the H columns behind them
+    it is not, and the chip's compiler then stored the stack
+    contraction-minor and relaid all of it, 473 MB at the published widths,
+    at the start of every decode chunk; compiled for a described v5e, PR
+    49), `conv.kernel [n, K, I + 2 G N]` (depthwise taps, oldest first) and
+    `conv.bias`, `A_log`, `D`, `dt_bias` `[n, H]`, `norm [n, I]`,
+    `out_proj.kernel [n, I, D]`.
+    `A_log` and `dt_bias` are drawn as Mamba-2 draws them: `A` uniform in
+    [1, 16], `softplus(dt_bias)` log-uniform in [1e-3, 1e-1], so a head
+    forgets within one token or within a thousand."""
+    D, H, K = config.hidden_size, config.ssm_heads, config.ssm_conv
+    I, W = config.ssm_inner, config.ssm_conv_width
+    k_a, k_dt = jax.random.split(key)
+    step = jnp.exp(jax.random.uniform(
+        k_dt, (n, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "in_proj": {"kernel": fan(n, D, I + W)},
+        "dt_proj": {"kernel": fan(n, D, H)},
+        "conv": {"kernel": normal((n, K, W), 1.0 / jnp.sqrt(K)),
+                 "bias": jnp.zeros((n, W), dtype)},
+        "A_log": jnp.log(jax.random.uniform(
+            k_a, (n, H), jnp.float32, 1.0, 16.0)).astype(dtype),
+        "D": jnp.ones((n, H), dtype),
+        # (softplus's inverse)
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
+        "norm": jnp.ones((n, I), dtype),
+        "out_proj": {"kernel": fan(n, I, D)}}
 
 
 # ---------------------------------------------------------------------------
@@ -833,13 +869,22 @@ def _in_token_blocks(fn, h, block: int, *more):
     }
 
 
-def _swiglu(h, layer_params, lora_layer, lora_scale):
-    gate = _proj(h, layer_params, lora_layer, "gate_proj", lora_scale)
+def _times(x, multiplier: float):
+    """`x * multiplier` in x's type (a muP multiplier of the published
+    config, core/config.py); 1.0 stages no operation."""
+    return x if multiplier == 1.0 else x * jnp.asarray(multiplier, x.dtype)
+
+
+def _swiglu(h, layer_params, lora_layer, lora_scale, multipliers=(1.0, 1.0)):
+    """`multipliers`: on the gate before its SiLU, on the down projection's
+    result (Falcon-H1's `mlp_multipliers`)."""
+    gate = _times(_proj(h, layer_params, lora_layer, "gate_proj", lora_scale),
+                  multipliers[0])
     up = _proj(h, layer_params, lora_layer, "up_proj", lora_scale)
-    return _proj(
+    return _times(_proj(
         jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up,
         layer_params, lora_layer, "down_proj", lora_scale,
-    )
+    ), multipliers[1])
 
 
 def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
@@ -900,7 +945,8 @@ def _mlp(config: ModelConfig, h, layer_params, lora_layer, lora_scale,
                 out = out + _swiglu(h, layer_params["shared_expert"], None,
                                     lora_scale)
         return out, aux
-    return _swiglu(h, layer_params, lora_layer, lora_scale), None
+    return _swiglu(h, layer_params, lora_layer, lora_scale,
+                   config.mlp_multipliers), None
 
 
 class KindView(NamedTuple):
@@ -941,7 +987,8 @@ class LayerLeaves(NamedTuple):
 
 
 def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
-                view: KindView, cache, cos, sin, lora_scale=1.0, attn_fn=None):
+                view: KindView, cache, cos, sin, lora_scale=1.0, attn_fn=None,
+                state=None):
     """One decoder layer: norm, the operator of its `kind` with its residual
     (attention: `_attention`; MLA: core/mla.py; `"conv"`: `_conv_operator`),
     norm, MLP. If `cache` is not None, operate incrementally.
@@ -960,12 +1007,25 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
     by the sequence-parallel path to route through ring attention): every
     other op stays this single implementation.
 
+    A `"hybrid"` layer (docs/SSM.md) is an attention AND a state-space mixer
+    on the one normed state, both added into the one residual: it takes its
+    pages as `view` / `cache` like any attention layer and the state group's
+    pair beside them as `state = (view, cache | None)`, and its new cache is
+    the pair `(pages, state)`.
+
     Returns (x_out, new_cache_or_None, mlp_aux_or_None)."""
     layer_params = leaves.tree
     with jax.named_scope("norm"):
         h = rms_norm(x, layer_params["input_layernorm"], config.rms_norm_eps)
     with jax.named_scope("attn"):
-        if kind == "conv":
+        if kind == "hybrid":
+            mixed, new_state = _ssm_operator(
+                config, h, layer_params["ssm"], state[1], layer, state[0])
+            x, new_cache = _attention(
+                config, x, h, leaves, layer, (False, True), view, cache, cos,
+                sin, lora_scale, attn_fn)
+            x, new_cache = x + mixed, (new_cache, new_state)
+        elif kind == "conv":
             x, new_cache = _conv_operator(
                 config, x, h, layer_params["conv"], cache, layer, view)
         elif config.kv_lora_rank:
@@ -1030,8 +1090,10 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
     window, rotary = kind
     spmd = _kernel_spmd(config, H, KV)
     with jax.named_scope("attn.qkv"):
+        h = _times(h, config.attention_in_multiplier)
         q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
-        k = _proj(h, layer_params, lora_layer, "k_proj", lora_scale)
+        k = _times(_proj(h, layer_params, lora_layer, "k_proj", lora_scale),
+                   config.key_multiplier)
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
         gate = (_proj(h, layer_params, lora_layer, "g_proj", lora_scale)
                 if config.attention_gate else None)
@@ -1097,7 +1159,8 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
     with jax.named_scope("attn.out"):
         if gate is None:
             out = merged(out)
-        out = _proj(out, layer_params, lora_layer, "o_proj", lora_scale)
+        out = _times(_proj(out, layer_params, lora_layer, "o_proj",
+                           lora_scale), config.attention_out_multiplier)
         if config.branch_norms:     # afmoe: the branch is normed again
             with jax.named_scope("norm"):
                 out = rms_norm(out, layer_params["attn_branch_norm"],
@@ -1136,6 +1199,35 @@ def _unpack_heads(out, KV: int, pack: int):
     out = out.reshape(B, H, T, pack, hd)
     return jnp.take_along_axis(
         out, own[None, :, None, None, None], axis=3)[:, :, :, 0, :]
+
+
+def _tail_read(stack, layer, row, B, fresh):
+    """A call's B rows of a state leaf `[layers, K - 1, rows, W]` at `layer`,
+    from row `row` on, as `[B, K - 1, W]`; a `fresh` row's as zeros."""
+    _, K1, _, W = stack.shape
+    past = jax.lax.dynamic_slice(stack, (layer, 0, row, 0), (1, K1, B, W))[0]
+    past = past.transpose(1, 0, 2)
+    if fresh is not None:
+        past = jnp.where(fresh[:, None, None], 0, past)
+    return past
+
+
+def _tail_write(stack, seq, T, valid, layer, row):
+    """The leaf with the call's rows set to the last K - 1 values of `seq`
+    `[B, K - 1 + T, W]` (what stood before the call's T tokens, then they):
+    with `valid` [B, T], the K - 1 values up to the row's LAST real token."""
+    K1 = seq.shape[1] - T
+    if valid is None:
+        tail = seq[:, T:]
+    else:
+        last = jnp.where(
+            valid.any(axis=1),
+            T - 1 - jnp.argmax(valid[:, ::-1], axis=1), -1)
+        at = last[:, None] + 1 + jnp.arange(K1)[None, :]
+        tail = jnp.take_along_axis(seq, at[:, :, None], axis=1)
+    return jax.lax.dynamic_update_slice(
+        stack, tail.transpose(1, 0, 2)[None].astype(stack.dtype),
+        (layer, 0, row, 0))
 
 
 def _conv_operator(config, x, h, conv, state_group, layer, view):
@@ -1177,11 +1269,7 @@ def _conv_operator(config, x, h, conv, state_group, layer, view):
             else:
                 (state,) = state_group
                 row = 0 if state_rows is None else state_rows[0, 0]
-                past = jax.lax.dynamic_slice(
-                    state, (layer, 0, row, 0), (1, K - 1, B, D))[0]
-                past = past.transpose(1, 0, 2)              # [B, K - 1, D]
-                if fresh is not None:
-                    past = jnp.where(fresh[:, None, None], 0, past)
+                past = _tail_read(state, layer, row, B, fresh)
             seq = jnp.concatenate([past.astype(g.dtype), g], axis=1)
             taps = conv["conv"]["kernel"].astype(jnp.float32)       # [K, D]
             mixed = sum(taps[j] * seq[:, j:j + T].astype(jnp.float32)
@@ -1190,19 +1278,107 @@ def _conv_operator(config, x, h, conv, state_group, layer, view):
         new_group = None
         if state_group is not None:
             with jax.named_scope("attn.write"):
-                if valid is None:
-                    tail = seq[:, T:]
-                else:   # the K - 1 values up to the row's last real token
-                    last = jnp.where(
-                        valid.any(axis=1),
-                        T - 1 - jnp.argmax(valid[:, ::-1], axis=1), -1)
-                    at = last[:, None] + 1 + jnp.arange(K - 1)[None, :]
-                    tail = jnp.take_along_axis(seq, at[:, :, None], axis=1)
-                new_group = (jax.lax.dynamic_update_slice(
-                    state, tail.transpose(1, 0, 2)[None].astype(state.dtype),
-                    (layer, 0, row, 0)),)
+                new_group = (_tail_write(state, seq, T, valid, layer, row),)
         with jax.named_scope("attn.conv.out"):
             return x + y @ conv["out_proj"]["kernel"], new_group
+
+
+def _ssm_operator(config, h, ssm, state_group, layer, view):
+    """A hybrid layer's state-space mixer on the normed state `h`
+    (docs/SSM.md; Mamba-2 with Falcon-H1's multipliers): `(the mixer's
+    branch [B, T, D], the updated state group | None)`; the caller adds it
+    into the residual beside the attention's.
+
+    `[z | xs | B | C | dt] = ((h ssm_in) W_in) * mup` (`W_in`'s last H
+    columns are the leaf `dt_proj`: `_init_ssm`); `[xs | B | C]` through
+    a causal depthwise convolution of K taps with a bias and a SiLU; the
+    selective recurrence over `xs` (ops/ssm.py: `ssd_scan` for a piece,
+    `ssm_update` for one token a row); `+ D xs`; times `silu(z)`; RMSNorm
+    over each group's channels; `(y W_out) ssm_out`.
+
+    The layer's state is `state_group = (tail, S)`: `tail` `[layers, K - 1,
+    rows, I + 2 G N]`, a row's last K - 1 inputs of the convolution, oldest
+    first (`_conv_operator`'s layout and rules), and `S` `[layers, rows, H,
+    P, N]` FLOAT32, the recurrence's state. `view.table` names the rows and
+    `view.conv_ctx = (valid, fresh)` means for both what it means for a conv
+    layer's state: a token not `valid` (a left pad, a bucket's pad, a row
+    nobody listens to) enters the convolution as 0 and has `dt = 0`, so it
+    neither decays nor feeds `S`, and both leaves hold what they held after
+    the row's LAST real token; a `fresh` row starts from zeros. Without a
+    cache the row starts from zeros."""
+    from nanorlhf_tpu.ops import ssm as ops
+
+    B, T, _ = h.shape
+    H, P, G, N = (config.ssm_heads, config.ssm_head_dim, config.ssm_groups,
+                  config.ssm_state)
+    K, I, W = config.ssm_conv, config.ssm_inner, config.ssm_conv_width
+    f32 = jnp.float32
+    state_rows, ctx = view.table, view.conv_ctx
+    valid, fresh = ctx if ctx is not None else (None, None)
+    with jax.named_scope("attn.ssm"):
+        with jax.named_scope("attn.ssm.in"):
+            h = _times(h, config.ssm_in_multiplier)
+            p = h @ ssm["in_proj"]["kernel"]
+            m = config.ssm_multipliers
+            if any(part != 1.0 for part in m[:4]):
+                p = p * jnp.concatenate([
+                    jnp.full((width,), part, p.dtype) for width, part in
+                    zip((I, I, G * N, G * N), m)])
+            z, xbc = jnp.split(p, (I,), axis=-1)
+            dt = _times(h @ ssm["dt_proj"]["kernel"], m[4])
+            if valid is not None:
+                xbc = jnp.where(valid[..., None], xbc, 0)
+        with jax.named_scope("attn.ssm.conv"):
+            if state_group is None:
+                past = jnp.zeros((B, K - 1, W), xbc.dtype)
+                before = jnp.zeros((B, H, P, N), f32)
+            else:
+                tail_stack, s_stack = state_group
+                row = 0 if state_rows is None else state_rows[0, 0]
+                past = _tail_read(tail_stack, layer, row, B, fresh)
+                before = jax.lax.dynamic_slice(
+                    s_stack, (layer, row, 0, 0, 0), (1, B, H, P, N))[0]
+                if fresh is not None:
+                    before = jnp.where(fresh[:, None, None, None], 0, before)
+            seq = jnp.concatenate([past.astype(xbc.dtype), xbc], axis=1)
+            taps = ssm["conv"]["kernel"].astype(f32)                # [K, W]
+            mixed = jax.nn.silu(
+                sum(taps[j] * seq[:, j:j + T].astype(f32) for j in range(K))
+                + ssm["conv"]["bias"].astype(f32))
+            xs, Bm, Cm = jnp.split(mixed, (I, I + G * N), axis=-1)
+            xs = xs.reshape(B, T, H, P)
+            Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
+            dt = jax.nn.softplus(dt.astype(f32) + ssm["dt_bias"].astype(f32))
+            if valid is not None:
+                dt = jnp.where(valid[..., None], dt, 0)
+            A = -jnp.exp(ssm["A_log"].astype(f32))
+        if T == 1:
+            with jax.named_scope("attn.ssm.update"):
+                y, after = ops.ssm_update(xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                                          Cm[:, 0], before)
+                y = y[:, None]
+        else:
+            with jax.named_scope("attn.ssm.scan"):
+                y, after = ops.ssd_scan(xs, dt, A, Bm, Cm, before,
+                                        config.ssm_chunk)
+        with jax.named_scope("attn.ssm.gate"):
+            y = y + ssm["D"].astype(f32)[:, None] * xs
+            y = y.reshape(B, T, I) * jax.nn.silu(z.astype(f32))
+            y = y.reshape(B, T, G, I // G)
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                                  + config.rms_norm_eps)
+            y = (y.reshape(B, T, I) * ssm["norm"].astype(f32)).astype(h.dtype)
+        new_group = None
+        if state_group is not None:
+            with jax.named_scope("attn.write"):
+                new_group = (
+                    _tail_write(tail_stack, seq, T, valid, layer, row),
+                    jax.lax.dynamic_update_slice(
+                        s_stack, after[None].astype(s_stack.dtype),
+                        (layer, row, 0, 0, 0)))
+        with jax.named_scope("attn.ssm.out"):
+            return _times(y @ ssm["out_proj"]["kernel"],
+                          config.ssm_out_multiplier), new_group
 
 
 def _int8_attention_read(config, q, k, v, view, new_cache, layer, spmd):
@@ -1594,7 +1770,11 @@ def _at(stacks, layer):
 def _kind_group(kind) -> int:
     """The cache group of a layer kind: 0 the global attention layers' pages
     (every layer of a model without a pattern), 1 the window layers', 2 the
-    conv layers' state."""
+    conv layers' state. A hybrid layer's attention keeps pages of group 0;
+    its mixer's state lies in group 2 at the same index (every layer of such
+    a model is hybrid: core/config.py)."""
+    if kind == "hybrid":
+        return 0
     return 2 if kind == "conv" else int(kind[0])
 
 
@@ -1766,13 +1946,17 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
                     if first[g]:    # (no `+ 0` in a one-stack model's program)
                         layer = layer + first[g]
                     place = i * p + j
+                hybrid = kind == "hybrid"   # pages and a state at once
                 y, cache, layer_aux = _layer_body(
                     config, y, LayerLeaves(layer_params, lora_layer,
                                            expert_stack, place, in_place),
                     layer, kind, views[g], caches[g] if cached else None,
-                    cos, sin, lora_scale, attn_fn)
+                    cos, sin, lora_scale, attn_fn,
+                    (views[2], caches[2] if cached else None)
+                    if hybrid else None)
                 if cached:
-                    caches = tuple(cache if k == g else c
+                    new = {g: cache[0], 2: cache[1]} if hybrid else {g: cache}
+                    caches = tuple(new.get(k, c)
                                    for k, c in enumerate(caches))
                 auxes.append(layer_aux)
             if cached and not cached_aux:
@@ -1833,13 +2017,13 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
         tables = [page_table]
     elif page_table is not None:
         if (not isinstance(page_table, (tuple, list))
-                or len(page_table) != kinds + bool(config.conv_layers)):
+                or len(page_table) != kinds + bool(config.state_layers)):
             raise ValueError(
                 "a model with a layer pattern takes page_table=(global table, "
                 "window table[, state rows]), one a kind of cache (docs/SWA.md, "
                 "docs/STATE.md)")
         tables = list(page_table[:2])
-        rows = page_table[2] if config.conv_layers else None
+        rows = page_table[2] if config.state_layers else None
     groups = [None] * kinds
     if kv_caches is not None:
         groups = [kv_caches] if plain else list(kv_caches[:2])
@@ -1895,7 +2079,7 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
                  table=table, page_size=page_size, write_plan=plan, live=live)
         for m, group, d, v, table, plan
         in zip(masks, groups, decodes, verifies, tables, plans))
-    if config.conv_layers:
+    if config.state_layers:
         views += (KindView(
             mask=None, cache=None if kv_caches is None else "state",
             index=index, table=rows, conv_ctx=ctx, live=live),)
@@ -1932,7 +2116,8 @@ def unembedding_weight(config: ModelConfig, params: dict) -> jnp.ndarray:
 def _logits(config: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("head"):
         x = rms_norm(x, params["norm"], config.rms_norm_eps)
-        return x @ unembedding_weight(config, params)
+        return _times(x @ unembedding_weight(config, params),
+                      config.lm_head_multiplier)
 
 
 def _embed(config: ModelConfig, params: dict, ids: jnp.ndarray) -> jnp.ndarray:
@@ -2018,7 +2203,7 @@ def _conv_ctx(config: ModelConfig, valid=None, fresh=None) -> dict:
     thunk (`fresh` always does) and is computed for a model with conv layers
     only: an operation nobody reads still stands in the program of a loop's
     body, and every other model's programs stay the ones they were."""
-    if not config.conv_layers:
+    if not config.state_layers:
         return {}
     return {"conv_ctx": tuple(v() if callable(v) else v for v in (valid, fresh))}
 
@@ -2175,8 +2360,8 @@ def _pattern_caches(config: ModelConfig) -> tuple:
     two groups of cache stacks. It has no int8 form."""
     if config.kv_cache_quant == "int8":
         raise NotImplementedError(
-            "kv_cache_quant='int8' on a model with window layers or conv "
-            f"layers ({config.model_type}) is not implemented: the int8 "
+            "kv_cache_quant='int8' on a model with window layers or a "
+            f"state ({config.model_type}) is not implemented: the int8 "
             "reads take one table of one kind of cache and have no lower "
             "bound (docs/SWA.md, docs/STATE.md)")
     return (config.num_hidden_layers - config.window_layers
@@ -2184,11 +2369,21 @@ def _pattern_caches(config: ModelConfig) -> tuple:
 
 
 def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
-    """The conv layers' group of a cache, `((state,),)`: `[conv layers,
-    K - 1, rows, D]`, a row's last K - 1 values of `g` a layer, oldest first
-    (`_conv_operator`); nothing for a model without conv layers. Not a page:
-    its size does not grow with the row, no table addresses it, and a row's
-    is at the row's own index."""
+    """The state group of a cache, in one of its two forms (docs/STATE.md);
+    nothing for a model without a state. Conv layers: `((state,),)`, `[conv
+    layers, K - 1, rows, D]`, a row's last K - 1 values of `g` a layer,
+    oldest first (`_conv_operator`). Hybrid layers: `((tail, S),)`, the
+    convolution's tail `[layers, K - 1, rows, I + 2 G N]` likewise and the
+    recurrence's state `[layers, rows, H, P, N]` in FLOAT32 whatever the
+    cache's type (`_ssm_operator`; docs/SSM.md). Not a page: its size does
+    not grow with the row, no table addresses it, and a row's is at the
+    row's own index."""
+    if config.ssm_layers:
+        L = config.ssm_layers
+        return ((jnp.zeros((L, config.ssm_conv - 1, rows,
+                            config.ssm_conv_width), dtype),
+                 jnp.zeros((L, rows, config.ssm_heads, config.ssm_head_dim,
+                            config.ssm_state), jnp.float32)),)
     if not config.conv_layers:
         return ()
     return ((jnp.zeros((config.conv_layers, config.conv_L_cache - 1, rows,
@@ -2283,9 +2478,9 @@ def init_paged_kv_cache(
                 "builds; the monolithic paged rollout (page_size > 0 with "
                 "one identity table) is not built for it: use the "
                 "contiguous cache (docs/SWA.md)")
-        if config.conv_layers and state_rows <= 0:
+        if config.state_layers and state_rows <= 0:
             raise ValueError(
-                "a model with conv layers keeps a state a row beside its "
+                f"{config.state_what} keeps a state a row beside its "
                 "pages: init_paged_kv_cache(..., state_rows=rows) "
                 "(docs/STATE.md)")
         KV, hd = _cache_heads(config)

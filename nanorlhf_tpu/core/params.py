@@ -47,6 +47,16 @@ MLP's input norm) and `post_mlp_layernorm` ↔ `mlp_branch_norm`;
 `mlp.experts.{offset + e}.*` ↔ the HELD experts (`_two_stack_*`, A.X-K1's
 loader with this family's names).
 
+Falcon-H1 (`model_type: falcon_h1`; docs/SSM.md; names assumed from the
+family's modelling code): `input_layernorm`, `pre_ff_layernorm` ↔
+`post_attention_layernorm` (this tree's name for the MLP's input norm),
+`self_attn.{q,k,v,o}_proj`, `feed_forward.{gate,up,down}_proj`, and the mixer
+under `ssm`: `mamba.in_proj [2 I + 2 G N + H, D]` ↔ `in_proj.kernel [D, 2 I +
+2 G N]` and `dt_proj.kernel [D, H]` (its last H rows: core/model.py `_init_ssm`),
+`mamba.conv1d.weight [W, 1, K]` ↔ `conv.kernel [K, W]`, `mamba.conv1d.bias` ↔
+`conv.bias`, `mamba.{A_log, D, dt_bias}`, `mamba.norm.weight` ↔ `norm`,
+`mamba.out_proj`; `model.final_layernorm` ↔ `norm` (`_falcon_h1_*`).
+
 Weight fidelity (GQA head layout, tied embeddings, RoPE) is pinned by
 tests/test_model_parity.py against the torch Qwen2 AND Llama
 implementations.
@@ -316,6 +326,59 @@ def _lfm2_sd_from_params(config: ModelConfig, params: dict, put) -> None:
                 at["attn"] += 1
 
 
+# Falcon-H1: (ours, theirs) of a layer's kernels outside its mixer, of its
+# norms, and of the mixer's vectors
+_FH1_KERNELS = (("q_proj", "self_attn.q_proj"), ("k_proj", "self_attn.k_proj"),
+                ("v_proj", "self_attn.v_proj"), ("o_proj", "self_attn.o_proj"),
+                ("gate_proj", "feed_forward.gate_proj"),
+                ("up_proj", "feed_forward.up_proj"),
+                ("down_proj", "feed_forward.down_proj"))
+_FH1_NORMS = (("input_layernorm", "input_layernorm"),
+              ("post_attention_layernorm", "pre_ff_layernorm"))
+_FH1_VECTORS = (("A_log", "mamba.A_log"), ("D", "mamba.D"),
+                ("dt_bias", "mamba.dt_bias"), ("norm", "mamba.norm.weight"))
+
+
+def _falcon_h1_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    ids = range(config.num_hidden_layers)
+    at = lambda i, name: sd[f"model.layers.{i}.{name}"]            # noqa: E731
+    over = lambda name, f=lambda a: a: cast(np.stack(              # noqa: E731
+        [f(at(i, name)) for i in ids]))
+    T = lambda a: a.T                                               # noqa: E731
+    layers = {ours: over(theirs + ".weight") for ours, theirs in _FH1_NORMS}
+    for ours, theirs in _FH1_KERNELS:
+        layers[ours] = {"kernel": over(theirs + ".weight", T)}
+    layers["ssm"] = {
+        "in_proj": {"kernel": over("mamba.in_proj.weight",
+                                   lambda a: a[:-config.ssm_heads].T)},
+        "dt_proj": {"kernel": over("mamba.in_proj.weight",
+                                   lambda a: a[-config.ssm_heads:].T)},
+        # torch's depthwise Conv1d weight [W, 1, K] -> [K, W]
+        "conv": {"kernel": over("mamba.conv1d.weight", lambda a: a[:, 0, :].T),
+                 "bias": over("mamba.conv1d.bias")},
+        "out_proj": {"kernel": over("mamba.out_proj.weight", T)},
+        **{ours: over(theirs) for ours, theirs in _FH1_VECTORS}}
+    return {"layers": layers}
+
+
+def _falcon_h1_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    layers = params["layers"]
+    ssm = layers["ssm"]
+    for i in range(config.num_hidden_layers):
+        pre = f"model.layers.{i}."
+        for ours, theirs in _FH1_NORMS:
+            put(f"{pre}{theirs}.weight", layers[ours][i])
+        for ours, theirs in _FH1_KERNELS:
+            put(f"{pre}{theirs}.weight", layers[ours]["kernel"][i].T)
+        put(f"{pre}mamba.in_proj.weight", jnp.concatenate(
+            [ssm["in_proj"]["kernel"][i].T, ssm["dt_proj"]["kernel"][i].T]))
+        put(f"{pre}mamba.conv1d.weight", ssm["conv"]["kernel"][i].T[:, None, :])
+        put(f"{pre}mamba.conv1d.bias", ssm["conv"]["bias"][i])
+        put(f"{pre}mamba.out_proj.weight", ssm["out_proj"]["kernel"][i].T)
+        for ours, theirs in _FH1_VECTORS:
+            put(f"{pre}{theirs}", ssm[ours][i])
+
+
 def _to_np(t) -> np.ndarray:
     """torch tensor / np array → np array (bf16-safe via float32 round-trip)."""
     if hasattr(t, "detach"):
@@ -340,6 +403,13 @@ def params_from_hf_state_dict(
         params = _two_stack_params_from_sd(config, sd, cast)
         params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
                       norm=cast(sd["model.norm.weight"]))
+        if not config.tie_word_embeddings:
+            params["lm_head"] = cast(sd["lm_head.weight"].T)
+        return params
+    if config.ssm_layers:
+        params = _falcon_h1_params_from_sd(config, sd, cast)
+        params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
+                      norm=cast(sd["model.final_layernorm.weight"]))
         if not config.tie_word_embeddings:
             params["lm_head"] = cast(sd["lm_head.weight"].T)
         return params
@@ -405,6 +475,13 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
 
     layers = params["layers"]
     linear_keys, norm_keys = _layer_keys(config)
+    if config.ssm_layers:
+        _falcon_h1_sd_from_params(config, params, put)
+        put("model.embed_tokens.weight", params["embed_tokens"])
+        put("model.final_layernorm.weight", params["norm"])
+        if not config.tie_word_embeddings:
+            put("lm_head.weight", params["lm_head"].T)
+        return sd
     if config.conv_layers:
         _lfm2_sd_from_params(config, params, put)
         put("model.embed_tokens.weight", params["embed_tokens"])
@@ -493,14 +570,15 @@ def export_hf_checkpoint(
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
         "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe",
-        "afmoe", "sdar_moe") else (
+        "afmoe", "sdar_moe", "falcon_h1") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
             "smallthinker": "SmallThinkerForCausalLM",
             "lfm2_moe": "Lfm2MoeForCausalLM",
             "afmoe": "AfmoeForCausalLM",
-            "sdar_moe": "SDARMoeForCausalLM"}[family]
+            "sdar_moe": "SDARMoeForCausalLM",
+            "falcon_h1": "FalconH1ForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -558,6 +636,25 @@ def export_hf_checkpoint(
             norm_eps=config.rms_norm_eps,
             rope_parameters={"rope_theta": config.rope_theta,
                              "rope_type": "default"})
+    elif family == "falcon_h1":
+        hf_config.update(
+            attn_layer_indices=None, rope_scaling=None,
+            mamba_n_heads=config.ssm_heads, mamba_d_head=config.ssm_head_dim,
+            mamba_d_ssm=config.ssm_inner, mamba_n_groups=config.ssm_groups,
+            mamba_d_state=config.ssm_state, mamba_d_conv=config.ssm_conv,
+            mamba_chunk_size=config.ssm_chunk, mamba_conv_bias=True,
+            mamba_proj_bias=False, mamba_rms_norm=True,
+            mamba_norm_before_gate=False, mlp_bias=False,
+            projectors_bias=False,
+            embedding_multiplier=config.embed_scale,
+            attention_in_multiplier=config.attention_in_multiplier,
+            key_multiplier=config.key_multiplier,
+            attention_out_multiplier=config.attention_out_multiplier,
+            ssm_in_multiplier=config.ssm_in_multiplier,
+            ssm_multipliers=list(config.ssm_multipliers),
+            ssm_out_multiplier=config.ssm_out_multiplier,
+            mlp_multipliers=list(config.mlp_multipliers),
+            lm_head_multiplier=config.lm_head_multiplier)
     elif family == "afmoe":
         L = config.num_hidden_layers
         del hf_config["attention_bias"]

@@ -935,15 +935,16 @@ class DecodeSession:
         # for them, a ring of pages a row
         self.window_layers = config.window_layers
         patterned = config.attention_pattern is not None
-        # a model with conv layers (docs/STATE.md): a state a row beside
-        # the pages, which only this serving session keeps right
-        self.state_layers = config.conv_layers
+        # a model with conv layers or state-space layers (docs/STATE.md,
+        # docs/SSM.md): a state a row beside the pages, which only this
+        # serving session keeps right
+        self.state_layers = config.state_layers
         if self.state_layers:
-            what = f"a model with conv layers ({config.model_type})"
+            what = config.state_what
             if self.spec:
                 raise NotImplementedError(
                     f"speculative decode (spec_k={spec_k}) on {what}: a "
-                    "verify forward advances the conv state past every "
+                    "verify forward advances the state past every "
                     "candidate, and a rejected draft needs it rolled back to "
                     "the last accepted token; no such rollback is built "
                     "(docs/STATE.md)")
@@ -1015,19 +1016,24 @@ class DecodeSession:
             else self.num_pages, self.page_size,
             params["embed_tokens"].dtype,
             **({"state_rows": R} if self.state_layers else {}))
-        # what the state holds a row, over every conv layer
-        # (`serving/state_bytes_per_row`), and the rows a decode chunk's
-        # state "table" names: all of them, in order (`_conv_operator`)
+        # what the state holds a row, over every layer that keeps one and
+        # every leaf of it (`serving/state_bytes_per_row`), and the rows a
+        # decode chunk's state "table" names: all of them, in order
+        # (`_conv_operator`)
         self.state_bytes_per_row = 0
         self._state_rows = None
         if self.state_layers:
-            self.state_bytes_per_row = caches0[2][0].nbytes // R
+            self.state_bytes_per_row = sum(
+                leaf.nbytes for leaf in caches0[2]) // R
             self._state_rows = jnp.arange(R, dtype=jnp.int32)[:, None]
         # admissions that started a row from a zero state, and forwards of a
         # chunked admission that took the state its last piece left
         # (`serving/state_resets`, `serving/state_piece_carries`)
         self.state_resets = 0
         self.state_piece_carries = 0
+        # the REAL tokens of those forwards (`dispatch_tokens` holds a
+        # bucket's pads too): what a state's recurrence ran over
+        self.state_tokens = 0
         # empty carry: every row starts done; admit() installs rows
         # through the same path mid-loop admissions use
         self.state = self._carry(
@@ -1418,11 +1424,12 @@ class DecodeSession:
         window twins nobody wrote: none can occur (`_install` inserts
         nothing for such a model), and one that did raises here."""
         if plan.m > 0 or plan.cow_src is not None:
+            state = "recurrent" if self.config.ssm_layers else "conv"
             raise NotImplementedError(
-                "a radix prefix hit on a model with window or conv layers "
+                "a radix prefix hit on a model with window layers or a state "
                 f"({self.config.model_type}): the tree holds pages of the "
                 "global kind only, no window pages (docs/SWA.md) and no "
-                "snapshot of the conv state at the prefix's end "
+                f"snapshot of the {state} state at the prefix's end "
                 "(docs/STATE.md)")
         last = self.Tp + (self.max_tokens if budget is None else int(budget)) - 1
         try:
@@ -1638,6 +1645,8 @@ class DecodeSession:
                 self.state_piece_carries += 1
             else:
                 self.state_resets += 1
+            self.state_tokens += min(p.end - start_abs,
+                                     self.prefill_chunk or self.Tp)
 
     @property
     def looks_ahead(self) -> bool:

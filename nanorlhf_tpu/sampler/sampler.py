@@ -202,7 +202,8 @@ def compose_check(sampling: SamplingParams, *,
         two kinds is built by the serving session only, and the verify
         kernels have no window; rollouts take the contiguous cache, where
         the window is a mask.
-      * a model with conv layers (`config.conv_layers`, docs/STATE.md)
+      * a model that keeps a state (`config.state_layers`: conv layers,
+        docs/STATE.md; state-space layers, docs/SSM.md)
         with spec_k > 0 (no state rollback) or page_size > 0 (the rollout
         scheduler keeps no state that is not a page).
 
@@ -216,16 +217,16 @@ def compose_check(sampling: SamplingParams, *,
     the per_row flag the engine sets, not on SamplingParams."""
     if config is not None:
         config.refuse_block_generation("the rollout sampler")
-    if config is not None and config.conv_layers and sampling.spec_k > 0:
+    if config is not None and config.state_layers and sampling.spec_k > 0:
         raise NotImplementedError(
-            f"speculative decode (spec_k={sampling.spec_k}) on a model with "
-            f"conv layers ({config.model_type}): a verify forward advances "
-            "the conv state past every candidate and no rollback to the last "
+            f"speculative decode (spec_k={sampling.spec_k}) on "
+            f"{config.state_what}: a verify forward advances "
+            "the state past every candidate and no rollback to the last "
             "accepted token is built (docs/STATE.md)")
-    if config is not None and config.conv_layers and sampling.page_size > 0:
+    if config is not None and config.state_layers and sampling.page_size > 0:
         raise NotImplementedError(
-            f"the paged rollout paths (page_size={sampling.page_size}) on a "
-            f"model with conv layers ({config.model_type}): the monolithic "
+            f"the paged rollout paths (page_size={sampling.page_size}) on "
+            f"{config.state_what}: the monolithic "
             "paged rollout and the rollout scheduler keep no state that is "
             "not a page; rollouts take the contiguous cache, serving the "
             "session (docs/STATE.md)")
@@ -626,14 +627,16 @@ def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
             )
         else:
             # caches are stacked [L, B, KV, T, d] — batch on axis 1; a conv
-            # state [L, K - 1, B, D] (the cache's last group) has it on axis 2
+            # state or tail [L, K - 1, B, D] (the cache's last group) has it
+            # on axis 2, a recurrent state [L, B, H, P, N] on axis 1
             def fan(axis):
                 return lambda c: jnp.repeat(c, prompt_fanout, axis=axis)
 
-            if config.conv_layers:
+            if config.state_layers:
                 *paged_groups, state_group = caches
                 caches = (*jax.tree.map(fan(1), tuple(paged_groups)),
-                          jax.tree.map(fan(2), state_group))
+                          tuple(fan(2 if c.ndim == 4 else 1)(c)
+                                for c in state_group))
             else:
                 caches = jax.tree.map(fan(1), caches)
         prompt_mask = jnp.repeat(prompt_mask, prompt_fanout, axis=0)

@@ -586,6 +586,7 @@ class ServingEngine:
             "serving/state_bytes_per_row": self._sess.state_bytes_per_row,
             "serving/state_resets": self._sess.state_resets,
             "serving/state_piece_carries": self._sess.state_piece_carries,
+            "serving/state_tokens": self._sess.state_tokens,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
             # the rows (generation by blocks: positions) the sampler ran

@@ -537,7 +537,7 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
         page_size=page_size,
         # (a conv state stops at the last REAL token, not the bucket's end)
         **({"token_valid": jnp.arange(suffix_ids.shape[1])[None, :] <= last}
-           if config.conv_layers else {}),
+           if config.state_layers else {}),
     )
     return jnp.take(logits[0], last, axis=0), caches
 

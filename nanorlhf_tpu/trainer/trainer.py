@@ -400,12 +400,13 @@ class RLTrainer:
         # (no published RL objective for a policy that generates by blocks:
         # docs/BLOCKDIFF.md "what is left")
         self.mcfg.refuse_block_generation(f"training ({type(self).__name__})")
-        if self.mcfg.conv_layers:
+        if self.mcfg.state_layers:
             raise NotImplementedError(
-                f"training a model with conv layers ({self.mcfg.model_type}) "
+                f"training {self.mcfg.state_what} "
                 "is not built: the update's packed rows have no boundary "
                 "the convolution stops at, its backward under the sparse "
-                "trainer's packing is not written, and a mesh has no rule "
+                "trainer's packing is not written (nor the state-space "
+                "scan's at all), and a mesh has no rule "
                 "for the state (docs/STATE.md); the model is served")
         self.tokenizer = tokenizer
         self.reward_func = reward_func

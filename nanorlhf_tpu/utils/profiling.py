@@ -90,12 +90,21 @@ import jax
 # `attn.conv` around `attn.conv.in` (the input projection and the gate),
 # `attn.conv.mix` (the state's read and the taps), `attn.write` (the state's
 # write) and `attn.conv.out`.
+# A hybrid layer's state-space mixer (docs/SSM.md) runs BESIDE the attention,
+# which keeps `attn.qkv/.write/.read/.out`: `attn.ssm` around `attn.ssm.in`
+# (the input projection and its multipliers), `attn.ssm.conv` (the tail's
+# read, the taps, `dt` and `A`), `attn.ssm.scan` (a piece's recurrence in
+# chunks) or `attn.ssm.update` (a decode step's pass over the rows' state),
+# `attn.ssm.gate` (the skip, the gate and the group norm), `attn.write` (both
+# state leaves' write) and `attn.ssm.out`.
 DEVICE_SCOPES = (
     "prefill", "decode", "verify", "install", "score", "update", "sync",
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
     "attn.out", "attn.gate", "attn.paged_flash", "attn.conv", "attn.conv.in",
     "attn.conv.mix", "attn.conv.out", "mlp", "head", "sample", "logprob",
-    "loss", "optim", "attn.block", "sample.unmask",
+    "loss", "optim", "attn.block", "sample.unmask", "attn.ssm",
+    "attn.ssm.in", "attn.ssm.conv", "attn.ssm.scan", "attn.ssm.update",
+    "attn.ssm.gate", "attn.ssm.out",
 )
 
 

@@ -1246,3 +1246,112 @@ def test_sdar_session_programs_fit_the_chip_in_place_on_v5e(
     else:       # the piece's read is XLA's walk of the key blocks
         assert not calls
     assert not re.findall(r"bf16\[\d+,4,3200,128\]", hlo)
+
+
+def _falcon_h1_session_program(case, v5e):
+    """The `serve-falcon-h1-assist` cell's decode chunk, its KV-only prefill
+    piece or its closing suffix forward, lowered for a described v5e at the
+    configuration file's own cut (Falcon-H1-34B-Instruct's published widths,
+    5 layers, the whole vocabulary) and the cell's engine sizes: `(compiled,
+    cache shapes, config)`."""
+    import json
+    import os
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.serving import radix
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "falcon-h1-34b-l5.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "assist-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = (eng["rows"], eng["prompt_len"], eng["max_new_tokens"],
+                         eng["prefill_chunk"])
+    nb = (Tp + new) // PAGE
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, (R * nb, R), PAGE, jnp.bfloat16, state_rows=R))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        tables = (spec((R, nb), jnp.int32),) * 2 + (spec((R, 1), jnp.int32),)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, tables, spec((R,), jnp.float32),
+            spec((R,), jnp.float32), spec((R,), jnp.bool_),
+            spec((R,), jnp.int32), Tp=Tp, max_tokens=new, page_size=PAGE,
+            sync_every=eng["sync_every"], eos_token_id=1, pad_token_id=0,
+            temperature=1.0, top_p=1.0, greedy=False, lora_scale=1.0, top_k=64,
+            capture_logprobs=False, approx_top_k=True)
+    else:
+        row = (spec((nb,), jnp.int32),) * 2 + (spec((1,), jnp.int32),)
+        args = (params, cfg, spec((1, chunk), jnp.int32),
+                spec((1, chunk), jnp.int32), spec((1,), jnp.int32))
+        tail = (spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip), row)
+        if case == "prefill_piece":
+            lowered = session._prefill_chunk_fwd.lower(
+                *args, *tail, page_size=PAGE, lora_scale=1.0)
+        else:
+            lowered = radix.suffix_logits.lower(
+                *args, spec((), jnp.int32), *tail, page_size=PAGE,
+                lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_piece", "suffix"])
+def test_falcon_h1_session_programs_fit_the_chip_with_pages_and_state_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 49, asked of the chip's compiler at the `serve-falcon-h1-assist`
+    cell's own shapes (9.65 GB of bf16 weights, 48 rows of 5,120 slots, pages
+    of 128: a pool of 1,920 pages x 5 layers, 2.52 GB; a state of 48 rows x
+    5 layers, the tail `bf16[5,3,48,5120]` and the recurrent state
+    `f32[5,48,32,128,256]`, 1.01 GB): the session's decode chunk, its
+    1,024-token KV-only prefill piece and its closing suffix forward each
+    FIT 16 GB and alias the pool AND both state leaves from their parameters
+    to their results; no module holds a `copy` of a pool leaf or of the
+    recurrent state (a second copy of it is 1 GB); the T = 1 read is the
+    in-place kernel (`%attn.global*`)."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _falcon_h1_session_program(case, v5e)
+    hlo = compiled.as_text()
+    kept = hlo_stacks([leaf for leaf in jax.tree.leaves(cache) if leaf.size])
+    assert set(kept) == {("bf16", (5, 1920, 4, PAGE, 128)),
+                         ("bf16", (5, 3, 48, 5120)),
+                         ("f32", (5, 48, 32, 128, 256))}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    # weights, pool, state (a KV-only piece takes no head: 2.67 GB less)
+    head = 2.67e9 if case == "prefill_piece" else 0.0
+    assert 13.0e9 < m.argument_size_in_bytes + head < 13.4e9
+    assert m.alias_size_in_bytes > 3.5e9                # pool and state donated
+    assert peak < 15.0e9, (case, peak, m.temp_size_in_bytes)
+    comps = _computations(hlo)
+    held = {k for k in kept if k[1] != (5, 3, 48, 5120)}
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & held]
+    assert not copies, "\n".join(copies)
+    calls = [line.strip().split(" ")[0] for line in hlo.splitlines()
+             if re.match(r"\s*%(attn\.|paged_prefill)[\w.]* = \S+ custom-call\(",
+                         line)]
+    if case == "decode_chunk":
+        assert calls and all(c.startswith("%attn.global") for c in calls), calls
+    # no gathered view of the row's pages: [.., 5120, 128] by slot
+    assert not re.findall(r"bf16\[\d+,4,5120,128\]", hlo)

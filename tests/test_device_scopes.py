@@ -45,6 +45,7 @@ KINDS = {
         vocab_size=256, window=16, layers=4),
     "conv": lambda: ModelConfig.lfm2_tiny(vocab_size=256),
     "gated": lambda: ModelConfig.trinity_tiny(vocab_size=256, window=16),
+    "hybrid": lambda: ModelConfig.falcon_h1_tiny(vocab_size=256),
 }
 # what a layer's attention is made of, by kind (the older families are the
 # latent model's `mla.*` and the pattern model's `attn.global` / `.window`)
@@ -59,6 +60,11 @@ ATTENTION = {
     # afmoe: the pattern model's parts and the gate between read and out
     "gated": {"attn.qkv", "attn.write", "attn.global", "attn.window",
               "attn.gate", "attn.out"},
+    # a state-space mixer BESIDE the attention (docs/SSM.md): a piece's
+    # `attn.ssm.scan` and a step's `attn.ssm.update` have a test of their own
+    "hybrid": {"attn.qkv", "attn.write", "attn.global", "attn.out",
+               "attn.ssm", "attn.ssm.in", "attn.ssm.conv", "attn.ssm.gate",
+               "attn.ssm.out"},
 }
 MODEL = {"embed", "norm", "attn", "mlp", "head"}
 MATMUL_HOMES = ("attn", "mlp", "head")
@@ -164,7 +170,7 @@ def test_the_one_jit_rollout_names_its_prefill_and_its_decode_loops(kind):
         missing = want - parts_of(ops, first)
         assert not missing, (first, missing)
     check_matmuls_and_innermost(ops, "decode")
-    if kind != "dense":
+    if kind not in ("dense", "hybrid"):
         assert {"moe.router", "moe.dispatch", "moe.experts",
                 "moe.combine"} <= parts_of(ops, "decode")
     # a scope of the model is never the first of a path: some program's is
@@ -190,7 +196,7 @@ def session_programs(kind, **config):
         pages = (pages, ROWS * ring_blocks(cfg.sliding_window, PAGE, PAGE))
         table, row_table = (table,) * 2, (row_table,) * 2
     state_rows = {}
-    if kind == "conv":      # no window layer; the state's rows ride third
+    if kind in ("conv", "hybrid"):  # no window layer; the state's rows ride third
         pages, state_rows = (pages, ROWS), {"state_rows": ROWS}
         table = (table,) * 2 + (spec((ROWS, 1), jnp.int32),)
         row_table = (row_table,) * 2 + (spec((1,), jnp.int32),)
@@ -247,6 +253,23 @@ def test_a_serving_sessions_programs_name_their_steps(kind):
         "install"}
 
 
+def test_a_hybrid_layers_recurrence_is_a_scan_in_a_piece_an_update_in_a_step():
+    """docs/SSM.md: the mixer's recurrence runs under `attn.ssm.scan` in a
+    prefill piece and a suffix forward, under `attn.ssm.update` in a decode
+    chunk, both inside `attn/attn.ssm`, and both state leaves are written
+    under `attn.write` there."""
+    lowered = session_programs("hybrid")
+    chunk = {scope for _, _, scope in ops_of(lowered["chunk"])}
+    assert "decode/attn/attn.ssm/attn.ssm.update" in chunk
+    assert "decode/attn/attn.ssm/attn.write" in chunk
+    assert not any("attn.ssm.scan" in s for s in chunk)
+    for name in ("piece", "suffix"):
+        scopes = {scope for _, _, scope in ops_of(lowered[name])}
+        assert "prefill/attn/attn.ssm/attn.ssm.scan" in scopes, name
+        assert "prefill/attn/attn.ssm/attn.write" in scopes, name
+        assert not any("attn.ssm.update" in s for s in scopes), name
+
+
 def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
     """ISSUE 37: under the decode read's rule (`"pallas"`; interpret mode
     here, so the kernel's body is XLA ops that carry its scope) a piece's
@@ -271,7 +294,7 @@ def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
 
 
 @pytest.mark.parametrize("kind", ["dense", "latent", "pattern", "conv",
-                                  "gated"])
+                                  "gated", "hybrid"])
 def test_a_pieces_write_by_page_carries_attn_write(kind):
     """ISSUE 41: a piece of a page or more writes its K and V by PAGE
     (`core/model._paged_page_write`: the touched pages gathered, patched and
@@ -285,7 +308,8 @@ def test_a_pieces_write_by_page_carries_attn_write(kind):
     both = {"prefill/attn/attn.global/attn.write",
             "prefill/attn/attn.window/attn.write"}
     homes = {"pattern": both, "gated": both,
-             "conv": {"prefill/attn/attn.global/attn.write"}}.get(
+             "conv": {"prefill/attn/attn.global/attn.write"},
+             "hybrid": {"prefill/attn/attn.global/attn.write"}}.get(
         kind, {"prefill/attn/attn.write"})
     for part in ("gather", "select_n", "scatter"):
         found = {scope for _, name, scope in ops
