@@ -1293,8 +1293,10 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
     columns are the leaf `dt_proj`: `_init_ssm`); `[xs | B | C]` through
     a causal depthwise convolution of K taps with a bias and a SiLU; the
     selective recurrence over `xs` (ops/ssm.py: `ssd_scan` for a piece,
-    `ssm_update` for one token a row); `+ D xs`; times `silu(z)`; RMSNorm
-    over each group's channels; `(y W_out) ssm_out`.
+    `ssm_update` for one token a row, which with a cache is
+    `ssm_update_in_place`: one pass over the rows someone listens to, where
+    `S` lies in the stack); `+ D xs`; times `silu(z)`; RMSNorm over each
+    group's channels; `(y W_out) ssm_out`.
 
     The layer's state is `state_group = (tail, S)`: `tail` `[layers, K - 1,
     rows, I + 2 G N]`, a row's last K - 1 inputs of the convolution, oldest
@@ -1303,9 +1305,10 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
     `view.conv_ctx = (valid, fresh)` means for both what it means for a conv
     layer's state: a token not `valid` (a left pad, a bucket's pad, a row
     nobody listens to) enters the convolution as 0 and has `dt = 0`, so it
-    neither decays nor feeds `S`, and both leaves hold what they held after
-    the row's LAST real token; a `fresh` row starts from zeros. Without a
-    cache the row starts from zeros."""
+    neither decays nor feeds `S` (a step does not visit its row at all, and
+    its `y` is 0), and both leaves hold what they held after the row's LAST
+    real token; a `fresh` row starts from zeros. Without a cache the row
+    starts from zeros."""
     from nanorlhf_tpu.ops import ssm as ops
 
     B, T, _ = h.shape
@@ -1336,10 +1339,12 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
                 tail_stack, s_stack = state_group
                 row = 0 if state_rows is None else state_rows[0, 0]
                 past = _tail_read(tail_stack, layer, row, B, fresh)
-                before = jax.lax.dynamic_slice(
-                    s_stack, (layer, row, 0, 0, 0), (1, B, H, P, N))[0]
-                if fresh is not None:
-                    before = jnp.where(fresh[:, None, None, None], 0, before)
+                if T > 1:   # (a cached step passes over `S` where it lies)
+                    before = jax.lax.dynamic_slice(
+                        s_stack, (layer, row, 0, 0, 0), (1, B, H, P, N))[0]
+                    if fresh is not None:
+                        before = jnp.where(fresh[:, None, None, None], 0,
+                                           before)
             seq = jnp.concatenate([past.astype(xbc.dtype), xbc], axis=1)
             taps = ssm["conv"]["kernel"].astype(f32)                # [K, W]
             mixed = jax.nn.silu(
@@ -1354,8 +1359,13 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
             A = -jnp.exp(ssm["A_log"].astype(f32))
         if T == 1:
             with jax.named_scope("attn.ssm.update"):
-                y, after = ops.ssm_update(xs[:, 0], dt[:, 0], A, Bm[:, 0],
-                                          Cm[:, 0], before)
+                step = (xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+                if state_group is None:
+                    y, _ = ops.ssm_update(*step, before)
+                else:   # one pass over the live rows' `S`, in the stack
+                    y, s_stack = ops.ssm_update_in_place(
+                        s_stack, layer, row,
+                        None if valid is None else valid[:, 0], fresh, *step)
                 y = y[:, None]
         else:
             with jax.named_scope("attn.ssm.scan"):
@@ -1373,7 +1383,7 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
             with jax.named_scope("attn.write"):
                 new_group = (
                     _tail_write(tail_stack, seq, T, valid, layer, row),
-                    jax.lax.dynamic_update_slice(
+                    s_stack if T == 1 else jax.lax.dynamic_update_slice(
                         s_stack, after[None].astype(s_stack.dtype),
                         (layer, row, 0, 0, 0)))
         with jax.named_scope("attn.ssm.out"):

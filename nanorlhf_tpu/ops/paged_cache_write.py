@@ -64,6 +64,19 @@ class PagedWritePlan(NamedTuple):
     off: jnp.ndarray     # [B] int32, by row: the slot's offset in its page
 
 
+def rows_first(has):
+    """`(n [1] int32, row [B] int32)`: how many of `has` [B] bool are set,
+    and those rows' indices, in order, first (what a kernel's work list
+    rides in scalar prefetch as)."""
+    B = has.shape[0]
+    ends = jnp.cumsum(has, dtype=jnp.int32)     # writers among rows 0..b
+    i = jnp.arange(B, dtype=jnp.int32)
+    # the i-th writer is the first row with more than i writers up to it
+    row = jnp.minimum(
+        jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1)
+    return ends[-1:], row
+
+
 def paged_write_plan(table, cache_index, *, page_size: int, num_pages: int,
                      live=None) -> PagedWritePlan:
     """The plan of one decode step: row b writes slot `cache_index[b]` (a
@@ -81,12 +94,7 @@ def paged_write_plan(table, cache_index, *, page_size: int, num_pages: int,
     has = page < num_pages
     if live is not None:
         has = has & live
-    ends = jnp.cumsum(has, dtype=jnp.int32)     # writers among rows 0..b
-    i = jnp.arange(B, dtype=jnp.int32)
-    # the i-th writer is the first row with more than i writers up to it
-    row = jnp.minimum(
-        jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1)
-    return PagedWritePlan(ends[-1:], row, page.astype(jnp.int32),
+    return PagedWritePlan(*rows_first(has), page.astype(jnp.int32),
                           slot % page_size)
 
 

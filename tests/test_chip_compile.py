@@ -1355,3 +1355,37 @@ def test_falcon_h1_session_programs_fit_the_chip_with_pages_and_state_in_place_o
         assert calls and all(c.startswith("%attn.global") for c in calls), calls
     # no gathered view of the row's pages: [.., 5120, 128] by slot
     assert not re.findall(r"bf16\[\d+,4,5120,128\]", hlo)
+
+
+def test_falcon_h1_decode_chunk_passes_over_the_state_in_one_kernel_on_v5e(
+        v5e, compiled_kernels, monkeypatch):
+    """ISSUE 50, asked of the chip's compiler at the `serve-falcon-h1-assist`
+    cell's own shapes: the decode chunk's pass over the recurrent state is
+    `ops/ssm.ssm_update_in_place`'s call, named by its scope
+    (`%attn.ssm.update*`), which takes the whole `f32[5,48,32,128,256]` and
+    returns it; no fusion reads or writes that stack or a layer's
+    `f32[48,32,128,256]` of it (XLA's read for `y` and its in-place
+    read-and-write of all 48 rows were two such: PERF.md, PR 49), nothing
+    copies it, and the program's temporaries stay PR 49's (0.6 GB)."""
+    import re
+
+    from test_cache_carry import _CALLEE, _computations, _shapes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, _, _ = _falcon_h1_session_program("decode_chunk", v5e)
+    comps = _computations(compiled.as_text())
+    S = {("f32", (5, 48, 32, 128, 256)), ("f32", (48, 32, 128, 256))}
+    everything = [i for instrs in comps.values() for i in instrs]
+    kernels = [result for name, result, op, _ in everything
+               if op == "custom-call" and name.startswith("attn.ssm.update")]
+    assert kernels and all(set(_shapes(r)) & S for r in kernels), kernels
+    fused = {callee for _, _, op, rest in everything if op == "fusion"
+             for callee in _CALLEE.findall(rest)}
+    touching = [f"{comp}: {name} = {result} {op}" for comp in fused
+                for name, result, op, _ in comps.get(comp, ())
+                if set(_shapes(result)) & S]
+    assert fused and not touching, "\n".join(touching)
+    copies = [f"{name} = {result} {op}" for name, result, op, _ in everything
+              if op.startswith("copy") and set(_shapes(result)) & S]
+    assert not copies, "\n".join(copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

@@ -179,6 +179,62 @@ def test_the_chunked_scan_is_the_token_scan(T, chunk):
     np.testing.assert_array_equal(np.asarray(kept), np.asarray(S))
 
 
+@pytest.mark.parametrize("live, layer, row0, fresh, heads, dtype", [
+    pytest.param(None, 0, 0, None, None, "float32", id="every_row"),
+    pytest.param((1, 1, 1, 1, 1, 1), 2, 0, None, None, "float32",
+                 id="all_live"),
+    pytest.param((0, 0, 0, 0, 0, 0), 1, 2, None, None, "float32",
+                 id="none_live"),
+    pytest.param((0, 1, 0, 0, 1, 0), 2, 1, None, 2, "float32",
+                 id="a_scattered_third"),
+    pytest.param((0, 0, 0, 0, 0, 1), 1, 3, None, 1, "float32",
+                 id="the_last_row_only"),
+    pytest.param((1, 0, 1, 1, 0, 1), 2, 2, (0, 1, 1, 0, 0, 0), None,
+                 "float32", id="a_fresh_live_row"),
+    pytest.param(None, 1, 1, (0, 0, 0, 1, 0, 0), 4, "float32",
+                 id="fresh_and_every_row"),
+    # benchmark/tools/ssm_control.py's control: rounded as it is written
+    pytest.param((1, 0, 1, 0, 0, 1), 0, 4, None, None, "bfloat16",
+                 id="a_bfloat16_stack"),
+])
+def test_the_in_place_update_is_the_update_on_the_live_rows(
+        live, layer, row0, fresh, heads, dtype):
+    """`ssm_update_in_place` (interpret mode) against `ssm_update` on the
+    sliced layer: a live row's `y` and state, and EVERY other row of the
+    stack (not live, another layer's, outside the call's rows) bit for bit
+    what went in; `heads`: the block of heads a grid step takes."""
+    k = jax.random.split(jax.random.PRNGKey(layer + 7 * row0), 6)
+    L, R, B, H, P, G, N = 3, 10, 6, 4, 16, 2, 8
+    stack = jax.random.normal(k[0], (L, R, H, P, N)).astype(dtype)
+    xs = jax.random.normal(k[1], (B, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (B, H)))
+    A = -jnp.exp(jax.random.normal(k[3], (H,)))
+    Bm, Cm = (jax.random.normal(k_, (B, G, N)) for k_ in k[4:])
+    live, fresh = (None if m is None else jnp.asarray(m, bool)
+                   for m in (live, fresh))
+    y, out = jax.jit(lambda s, at, r: ops.ssm_update_in_place(
+        s, at, r, live, fresh, xs, dt, A, Bm, Cm, heads_a_block=heads))(
+            stack, jnp.int32(layer), jnp.int32(row0))
+    before = stack[layer, row0:row0 + B]
+    if fresh is not None:
+        before = jnp.where(fresh[:, None, None, None], 0, before)
+    want_y, want = jax.jit(ops.ssm_update)(xs, dt, A, Bm, Cm, before)
+    on = np.ones(B, bool) if live is None else np.asarray(live)
+    assert out.dtype == stack.dtype and out.shape == stack.shape
+    y, out, stack, want = (np.asarray(a, np.float32) for a in (
+        y, out, stack, want.astype(dtype)))
+    visited = np.zeros((L, R), bool)
+    visited[layer, row0 + np.flatnonzero(on)] = True
+    np.testing.assert_array_equal(out[~visited], stack[~visited])
+    assert not y[~on].any()
+    if on.any():
+        # elementwise: equal up to one rounding of a fused multiply-add
+        np.testing.assert_allclose(
+            out[layer, row0:row0 + B][on], want[on], atol=1e-6,
+            rtol=3e-7 if dtype == "float32" else 2 ** -7)
+        assert far(y[on], np.asarray(want_y)[on]) < 1e-5
+
+
 # ------------------------------------------------------ forwards and caches
 
 def test_uncached_forward_is_the_reference(params, ids, sound):
