@@ -720,10 +720,13 @@ class _PendingPrefill:
 @dataclass
 class FirstToken:
     """An admission's first token once the host has it (`DecodeSession.read`):
-    the row, the admission's `admit_index`, the token."""
+    the row, the admission's `admit_index`, the token, and the seconds the
+    host has stood waiting for the device since the last report, this
+    token's read included (the running beat's `BeatReport.wait_s` so far)."""
     row: int
     index: int
     token: int
+    wait_s: float = 0.0
 
 
 @dataclass
@@ -737,7 +740,18 @@ class BeatReport:
     run of unmasked ones: a token once unmasked never changes), so a beat
     carries 0 to `sync_every x block_length` new tokens a row. A
     report read one beat late (`dispatch`) says nothing of a row that was
-    released, cancelled or admitted into since its chunk was dispatched."""
+    released, cancelled or admitted into since its chunk was dispatched.
+
+    What the beat was, for whoever books it to a request: `period_s`, the
+    seconds from the report before this one (or this chunk's dispatch, where
+    that came later) to this report; `wait_s`, those of them the host stood
+    waiting for the device inside `read()`, for this report and for every
+    first token read since the report before it (an admission forward or a
+    last prefill piece had to run first); `foreign`, the forwards of more
+    than one token (admission forwards, prefill pieces) the session enqueued
+    between the chunk before this one and this one, which the device ran in
+    between and which delayed every resident row; `t`, the report's
+    instant (`time.perf_counter()`, the clock of all of these)."""
     its: int
     done: np.ndarray
     n_gen: Optional[np.ndarray] = None
@@ -746,6 +760,10 @@ class BeatReport:
     steps: Optional[np.ndarray] = None      # a block session: beside each of
                                             # `tokens`, the denoise step of
                                             # its block that unmasked it
+    period_s: float = 0.0
+    wait_s: float = 0.0
+    foreign: int = 0
+    t: float = 0.0
 
     def new_tokens(self, r: int, since: int) -> np.ndarray:
         """Row `r`'s tokens from its `since`-th on."""
@@ -768,11 +786,13 @@ class _First:
 @dataclass
 class _Flight:
     """A dispatched chunk the host has not read: when it was dispatched, who
-    held each row then, the counts its program handed back beside the carry
-    (`hit`: `_chunk_loop`'s) and (serving mode) its `_beat_report`, all still
-    on the device."""
+    held each row then, the forwards of more than one token enqueued since
+    the chunk before it (`launches`' gain), the counts its program handed
+    back beside the carry (`hit`: `_chunk_loop`'s) and (serving mode) its
+    `_beat_report`, all still on the device."""
     t0: float
     occupants: np.ndarray
+    foreign: int = 0
     hit: Optional[jax.Array] = None
     meta: Optional[jax.Array] = None
     rows: Optional[jax.Array] = None
@@ -1105,6 +1125,18 @@ class DecodeSession:
         # (`serving/beats_overlapped`, `serving/first_tokens_deferred`)
         self.beats_overlapped = 0
         self.first_tokens_deferred = 0
+        # the beats by kind, never reset, of the reports that took a decode
+        # step: clean, or loaded with a forward of more than one token that
+        # the device ran before the chunk (`serving/beats_clean`, `_loaded`,
+        # `beat_clean_s`, `beat_loaded_s`); and those forwards as the reports
+        # brought them, every report's (`serving/foreign_forwards`)
+        self.beats_clean = 0
+        self.beats_loaded = 0
+        self.beat_clean_s = 0.0
+        self.beat_loaded_s = 0.0
+        self.foreign_forwards = 0
+        self._launches_flown = 0    # `launches` as of the last flight
+        self._sync_reported = 0.0   # the `sync` seconds as of the last report
 
         self._sample_kw = dict(temperature=temperature, top_p=top_p,
                                greedy=greedy, top_k=top_k,
@@ -1337,7 +1369,7 @@ class DecodeSession:
         kelems = plan = seed = None
         if self._radix is not None:
             from nanorlhf_tpu.serving.radix import copy_page, prompt_key
-            with self.timer.phase("plan"):
+            with self.timer.phase("plan", request=int(admit_index), row=r):
                 kelems = prompt_key(toks_np, mask_np)
                 # may raise RuntimeError — before any state mutation
                 plan = self._radix.plan(kelems, pad_count=pad_count,
@@ -1413,7 +1445,8 @@ class DecodeSession:
                                     self._backlog_tokens())
             self.chunked_admissions += 1
             return None
-        with self.timer.phase("admit_forward"):
+        with self.timer.phase("admit_forward", request=int(admit_index),
+                              row=r):
             return self._admit_now(pend, full_cold=full_cold,
                                    start_abs=start_abs)
 
@@ -1679,9 +1712,12 @@ class DecodeSession:
         installed = None
         phase = self.timer.phase
         if self._pending:
-            with phase("prefill_tick"):
+            p = self._pending[0]
+            with phase("prefill_tick", request=p.index, row=p.row):
                 installed = self._prefill_tick()
-        flight = _Flight(time.perf_counter(), self._occupant_np.copy())
+        flight = _Flight(time.perf_counter(), self._occupant_np.copy(),
+                         self.launches - self._launches_flown)
+        self._launches_flown = self.launches
         with phase("dispatch"):
             if any(isinstance(u, _Flight) for u in self._unread):
                 self.beats_overlapped += 1
@@ -1762,17 +1798,17 @@ class DecodeSession:
         host's records up to it: a `FirstToken`, or a chunk's `BeatReport`
         (the counters of a beat all advance here, together)."""
         item = self._unread.popleft()
-        sync = self.timer.phase("sync")     # the host waits for the device
+        phase = self.timer.phase    # "sync": the host waits for the device
         if isinstance(item, _First):
-            with sync:
-                tok = int(item.tok)
             p = item.pend
+            with phase("sync", request=p.index, row=p.row):
+                tok = int(item.tok)
             self.first_tokens_deferred += 1
             if self._hub is not None:       # TTFT: the host has the token
                 self._hub.record("latency/ttft_s",
                                  time.perf_counter() - p.t_start)
-            return FirstToken(p.row, p.index, tok)
-        with sync:
+            return FirstToken(p.row, p.index, tok, self._waited())
+        with phase("sync", foreign=item.foreign) as span:
             if item.rows is None:       # rollout mode reads the carry itself
                 done_h = np.asarray(self.state[5])
                 it_now = int(self.state[0])
@@ -1782,18 +1818,26 @@ class DecodeSession:
                 it_now, hit, taken = meta[:3]
                 if self.block:
                     self.block_counts = meta[3:].astype(np.int64)
+            it_now = int(it_now) - 1
+            its = it_now - self._it_prev
+            span.set_metadata(its=its)
         now = time.perf_counter()
-        it_now = int(it_now) - 1
-        its = it_now - self._it_prev
         # one mean inter-token gap a chunk: from the report before this one
         # (or this chunk's dispatch, if that came later) to this report.
         # The serving driver only records when the counter advanced (its
         # loop also spins on admission-only beats).
         since = max(item.t0, self._t_report)
         self._t_report = now
+        period = now - since
         if self._hub is not None and (its > 0 or not self.per_row):
-            self._hub.record("latency/intertoken_s",
-                             (now - since) / max(1, its))
+            self._hub.record("latency/intertoken_s", period / max(1, its))
+        self.foreign_forwards += item.foreign
+        if its > 0 and item.foreign:
+            self.beats_loaded += 1
+            self.beat_loaded_s += period
+        elif its > 0:
+            self.beats_clean += 1
+            self.beat_clean_s += period
         self.held_experts_hit += int(hit)
         if item.rows is not None and not self.spec:
             self.sample_rows += int(taken)
@@ -1821,7 +1865,14 @@ class DecodeSession:
             self._count_attention(its, report.done, report.n_gen, current)
             self._done_np[current] = report.done[current]
         self._it_prev = it_now
+        report.period_s, report.wait_s, report.t = period, self._waited(), now
+        report.foreign = item.foreign
+        self._sync_reported = self.timer.cumulative["sync"]
         return report
+
+    def _waited(self) -> float:
+        """The seconds of `sync` since the last report."""
+        return self.timer.cumulative["sync"] - self._sync_reported
 
     def _count_attention(self, its: int, done_h, n_gen=None,
                          current=None) -> None:

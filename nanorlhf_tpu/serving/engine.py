@@ -56,7 +56,13 @@ The loop thread's life is accounted for (`LOOP_PHASES`, one
 `PhaseTimer.phase` each, and the session's own inside them): `metrics()`
 exports the cumulative seconds with a request timeline as sums — queue
 wait, and the lag from a first token being queued to the gateway having
-flushed it (docs/SERVING.md "Engine loop account").
+flushed it (docs/SERVING.md "Engine loop account"). The account reaches
+down to the request: every beat a request lives through after its first
+token is booked to it (`_book`) with the seconds the loop stood waiting for
+the device and the forwards of other requests the device ran before the
+chunk, and a finished request is reduced into never-reset sums, once for
+all requests and once for the slow tenth by its own seconds a token
+(docs/SERVING.md "A request's account").
 
 Threading: one background loop thread owns the session (carry, block
 table, all device dispatch). `submit()` only appends to the pending
@@ -71,7 +77,9 @@ deadlock-free by construction.
 from __future__ import annotations
 
 import itertools
+import math
 import queue
+import statistics
 import threading
 import time
 from collections import deque
@@ -92,6 +100,21 @@ from nanorlhf_tpu.utils.profiling import PhaseTimer
 # what the loop thread does, one `PhaseTimer.phase` each: between them they
 # cover the loop's lifetime (docs/SERVING.md "engine loop account")
 LOOP_PHASES = ("wait", "admit", "reap", "step", "deliver")
+
+# a finished request's account as `metrics()` sums it, for all requests and
+# for the slow tenth (`serving/all_<name>`, `serving/slow_<name>`), beside
+# `requests` (1) and `tpot_s_sum` (its seconds a token): the seconds of what
+# the request holds under these names
+REQUEST_SUMS = ("decode_s", "wait_s", "loaded_s")
+# a request is slow at or above a running estimate of this quantile of the
+# finished requests' seconds a token: the median of the first `SLOW_WARMUP`
+# (none of which is classed), then for every request up by `SLOW_STEP` x 0.9
+# (in the logarithm) if it was slow and down by `SLOW_STEP` x 0.1 if not, so
+# a tenth are slow whatever came before and however the load drifts
+SLOW_QUANTILE = 0.90
+SLOW_WARMUP = 20
+SLOW_STEP = 0.1
+RECENT_REQUESTS = 64    # finished requests `snapshot()` keeps
 
 
 @dataclass
@@ -123,6 +146,21 @@ class ServingRequest:
     unmask_steps: list = field(default_factory=list)
     cut_tokens: list = field(default_factory=list)
     cut_unmask_steps: list = field(default_factory=list)
+    # the request's account (`ServingEngine._book`), the loop thread's: the
+    # row that served it, when its admission started and when its last token
+    # was queued, and over the beats it lived through after its first token:
+    # their count and seconds, the seconds of them the loop stood waiting for
+    # the device, and the beats (with their seconds and the forwards) that
+    # carried another request's admission forward or prefill piece
+    row: Optional[int] = None
+    t_admit: Optional[float] = None
+    t_last_token: Optional[float] = None
+    beats: int = 0
+    decode_s: float = 0.0
+    wait_s: float = 0.0
+    loaded_beats: int = 0
+    loaded_s: float = 0.0
+    foreign_forwards: int = 0
 
 
 class ServingEngine:
@@ -206,7 +244,19 @@ class ServingEngine:
         self._timeline = {"serving/queue_wait_s_sum": 0.0,
                           "serving/queue_wait_s_count": 0,
                           "serving/first_token_lag_s_sum": 0.0,
-                          "serving/first_token_lag_s_count": 0}
+                          "serving/first_token_lag_s_count": 0,
+                          "serving/last_token_lag_s_sum": 0.0,
+                          "serving/last_token_lag_s_count": 0}
+        # finished requests of two tokens or more, reduced (`_reduce`)
+        for kind in ("all", "slow"):
+            self._timeline[f"serving/{kind}_requests"] = 0
+            for name in ("tpot_s_sum",) + REQUEST_SUMS:
+                self._timeline[f"serving/{kind}_{name}"] = 0.0
+        # the slow tenth's threshold (seconds a token) and, until it is set,
+        # the TPOTs it will be set from: the loop thread's
+        self._slow_at: Optional[float] = None
+        self._early_tpots: list = []
+        self._recent: deque = deque(maxlen=RECENT_REQUESTS)
         self._timer = PhaseTimer(span_prefix="serving.", names=LOOP_PHASES)
         self._thread = threading.Thread(target=self._loop,
                                         name="serving-engine", daemon=True)
@@ -329,10 +379,20 @@ class ServingEngine:
         """The gateway's streaming handler calls this once it has written
         and flushed the request's first token: the seconds since the loop
         queued that token are the way out through the handler thread."""
-        lag = time.perf_counter() - req.t_first_token
+        self._token_sent("first", req.t_first_token, time.perf_counter())
+
+    def last_token_sent(self, req: ServingRequest, t_sent: float) -> None:
+        """`first_token_sent`'s twin for the tokens that make a TPOT: called
+        once the stream has ended, with the instant (`time.perf_counter()`)
+        at which the handler had written and flushed the request's last
+        token, it counts the seconds from the loop's queueing that one."""
+        self._token_sent("last", req.t_last_token, t_sent)
+
+    def _token_sent(self, which: str, t_queued: float, t_sent: float) -> None:
+        lag = t_sent - t_queued
         with self._cond:
-            self._timeline["serving/first_token_lag_s_sum"] += lag
-            self._timeline["serving/first_token_lag_s_count"] += 1
+            self._timeline[f"serving/{which}_token_lag_s_sum"] += lag
+            self._timeline[f"serving/{which}_token_lag_s_count"] += 1
 
     # ------------------------------------------------------------- #
     # engine loop (single background thread owns the session)
@@ -369,7 +429,7 @@ class ServingEngine:
                                    self._pending.popleft()))
                 self._n_active += len(admits)
             for r, req in admits:
-                with phase("admit"):
+                with phase("admit", request=req.request_id, row=r):
                     self._admit(r, req)
             with phase("reap"):
                 self._reap_cancelled()
@@ -388,7 +448,8 @@ class ServingEngine:
         """The host half of an admission: the plan, and the forward
         enqueued. The first token comes through `_deliver` once the device
         has made it."""
-        queue_wait = time.perf_counter() - req.t_submit
+        req.row, req.t_admit = r, time.perf_counter()
+        queue_wait = req.t_admit - req.t_submit
         Tp = self.prompt_len
         n = int(req.tokens.size)
         pad_count = Tp - n
@@ -452,9 +513,12 @@ class ServingEngine:
             req = self._owner[got.row]
             if (req is not None and req.request_id == got.index
                     and not req.cancelled):
-                req.t_first_token = time.perf_counter()
+                req.t_first_token = req.t_last_token = time.perf_counter()
                 req.out_q.put(got.token)
                 req.n_emitted = 1
+                # its first beat's report brings the wait since the report
+                # before it, and this much of that came before the token
+                req.wait_s = -got.wait_s
             return
         if self.block_length:
             return self._deliver_blocks(got)
@@ -462,7 +526,10 @@ class ServingEngine:
             req = self._owner[r]
             if req is None or req.n_emitted == 0 or req.cancelled:
                 continue
+            self._book(req, got)
             n = int(got.n_gen[r])
+            if n > req.n_emitted:
+                req.t_last_token = time.perf_counter()
             for tok in got.new_tokens(r, req.n_emitted).tolist():
                 req.out_q.put(tok)
             req.n_emitted = n
@@ -470,12 +537,38 @@ class ServingEngine:
                 self._finish(r, req, gen_tokens=(
                     got.new_tokens(r, 0) if self.spec_k > 0 else None))
 
+    def _book(self, req, got):
+        """Book the beat of report `got` to a request that had its first
+        token before it: the report's period (the request's first such beat
+        counts from its first token, which the host read after the report
+        before this one), the seconds of it the loop stood waiting for the
+        device (the first beat's likewise: `_deliver` took off what came
+        before the token), and, if the device ran forwards of other
+        requests before the chunk, the same period and their count. The
+        request's own admission forward is not foreign to it: it stood
+        before the first chunk the row ran in, which is the first beat
+        booked here. (Not so where tokens come by blocks: a row has no token
+        before a report brings it, so that beat is never booked.)"""
+        first = req.beats == 0
+        period = got.t - req.t_first_token if first else got.period_s
+        own = 1 if first and not self.block_length else 0
+        foreign = got.foreign - own
+        req.beats += 1
+        req.decode_s += period
+        req.wait_s += got.wait_s
+        if foreign > 0:
+            req.loaded_beats += 1
+            req.loaded_s += period
+            req.foreign_forwards += foreign
+
     def _finish(self, r, req, gen_tokens=None, **counted):
-        """Row `r`'s request is over: release the row, count it (`counted`:
-        further counters and what each gains), then close its stream, so
-        whoever reads the stream's end finds the request counted."""
+        """Row `r`'s request is over: release the row, reduce its account
+        and count it (`counted`: further counters and what each gains), then
+        close its stream, so whoever reads the stream's end finds the request
+        counted."""
         self._sess.release(r, gen_tokens=gen_tokens)
         self._owner[r] = None
+        self._reduce(req)
         with self._cond:
             self._counters["completed"] += 1
             for name, n in counted.items():
@@ -483,6 +576,58 @@ class ServingEngine:
             self._n_active -= 1
             self._cond.notify_all()
         req.out_q.put(None)
+
+    def _reduce(self, req):
+        """A finished request into the sums of `metrics()` and the records
+        of `snapshot()`. One of two tokens or more has a TPOT on this loop's
+        clock, its seconds a token after the first: it goes to the hub
+        (`latency/tpot_s`) and, with the request's account, to the sums of
+        all requests; a request at or above the running `SLOW_QUANTILE`
+        (as it stood before this request moved it) goes to the slow tenth's
+        sums as well."""
+        record = self._record(req, time.perf_counter())
+        kinds, tokens = (), req.n_emitted - 1
+        if tokens >= 1:
+            tpot = (req.t_last_token - req.t_first_token) / tokens
+            kinds = ("all", "slow") if self._is_slow(tpot) else ("all",)
+            if self._hub is not None:
+                self._hub.record("latency/tpot_s", tpot)
+        with self._cond:
+            for kind in kinds:
+                self._timeline[f"serving/{kind}_requests"] += 1
+                self._timeline[f"serving/{kind}_tpot_s_sum"] += tpot
+                for name in REQUEST_SUMS:
+                    self._timeline[f"serving/{kind}_{name}"] += record[name]
+            self._recent.append(record)
+
+    def _is_slow(self, tpot: float) -> bool:
+        """Class one finished request's seconds a token against the running
+        quantile, and move the quantile by it (see `SLOW_STEP`)."""
+        if self._slow_at is None:
+            self._early_tpots.append(tpot)
+            if len(self._early_tpots) == SLOW_WARMUP:
+                self._slow_at = statistics.median(self._early_tpots)
+            return False
+        slow = tpot >= self._slow_at
+        self._slow_at *= math.exp(SLOW_STEP * (slow - (1 - SLOW_QUANTILE)))
+        return slow
+
+    @staticmethod
+    def _record(req, t_finish) -> dict:
+        """A finished request as `snapshot()["recent_requests"]` keeps it:
+        who and where, its instants in seconds since its `submit()`, its
+        tokens, and its account."""
+        return {
+            "request_id": req.request_id, "row": req.row,
+            **{name: None if t is None else t - req.t_submit
+               for name, t in (("t_admit", req.t_admit),
+                               ("t_first_token", req.t_first_token),
+                               ("t_last_token", req.t_last_token),
+                               ("t_finish", t_finish))},
+            "n_emitted": req.n_emitted,
+            **{name: getattr(req, name)
+               for name in ("beats", "loaded_beats", "foreign_forwards")
+               + REQUEST_SUMS}}
 
     def _deliver_blocks(self, got):
         """`_deliver` for a model that generates by blocks: a report brings
@@ -498,16 +643,20 @@ class ServingEngine:
             # install changes its occupant, so no earlier flight speaks for it)
             if req is None or req.cancelled or r in pending:
                 continue
+            if req.n_emitted and not req.ended:     # from its first token
+                self._book(req, got)                # to its last
             toks = got.new_tokens(r, req.n_seen).tolist()
             steps = got.new_steps(r, req.n_seen).tolist()
             req.n_seen = int(got.n_gen[r])
+            now = time.perf_counter()
             for tok, step in zip(toks, steps):
                 if req.ended:
                     req.cut_tokens.append(tok)
                     req.cut_unmask_steps.append(step)
                     continue
+                req.t_last_token = now
                 if req.n_emitted == 0:
-                    req.t_first_token = time.perf_counter()
+                    req.t_first_token = now
                 req.unmask_steps.append(step)
                 req.out_q.put(tok)
                 req.n_emitted += 1
@@ -628,6 +777,13 @@ class ServingEngine:
         rows["serving/beats_overlapped"] = self._sess.beats_overlapped
         rows["serving/first_tokens_deferred"] = (
             self._sess.first_tokens_deferred)
+        # the beats by kind (a loaded beat carried an admission forward or a
+        # prefill piece before its chunk), as the session read them
+        rows["serving/beats_clean"] = self._sess.beats_clean
+        rows["serving/beats_loaded"] = self._sess.beats_loaded
+        rows["serving/beat_clean_s"] = self._sess.beat_clean_s
+        rows["serving/beat_loaded_s"] = self._sess.beat_loaded_s
+        rows["serving/foreign_forwards"] = self._sess.foreign_forwards
         for name in SESSION_PHASES:
             rows[f"serving/session_{name}_s"] = (
                 self._sess.timer.cumulative[name])
@@ -637,12 +793,16 @@ class ServingEngine:
     def snapshot(self) -> dict:
         """JSON-able /statusz section: engine shape + live occupancy +
         the radix tree's own snapshot under `prefix_cache` + the decode
-        session's row/backlog/feature view under `session`."""
+        session's row/backlog/feature view under `session` + the seconds a
+        token at which a request now counts as slow under `slow_at_s` + the
+        last finished requests' records under `recent_requests`, oldest
+        first."""
         with self._cond:
             c = dict(self._counters)
             reasons = dict(self._shed_reasons)
             pending = len(self._pending)
             active = self._n_active
+            recent = list(self._recent)
         return {
             "rows": self.rows,
             "active": active,
@@ -658,6 +818,8 @@ class ServingEngine:
                     "quantile": self._slo_q, "warmup": self._slo_warmup},
             "prefix_cache": self._radix.snapshot(),
             "session": self._sess.status(),
+            "slow_at_s": self._slow_at,
+            "recent_requests": recent,
         }
 
     @property

@@ -18,7 +18,8 @@ silenced — plus one streaming endpoint:
 - `GET /healthz`    200 `ok` while the engine loop runs, 503 after
   close — the k8s-style liveness shape.
 - `GET /statusz`    one JSON blob: engine occupancy, counters, SLO
-  config, and the radix prefix cache's snapshot.
+  config, the radix prefix cache's snapshot, and the last finished
+  requests' records (`recent_requests`: row, instants, decode account).
 
 The gateway binds LOOPBACK ONLY (`127.0.0.1`): the fleet transport's
 listener auth (ROADMAP item 2) has not landed, so exposing the port
@@ -31,6 +32,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -158,9 +160,12 @@ class ServingGateway:
                                 raise ConnectionResetError(
                                     "injected client disconnect")
                             self._chunk(json.dumps({"token": tok}) + "\n")
+                            t_sent = time.perf_counter()
                             if count == 0:
                                 gw.engine.first_token_sent(req)
                             count += 1
+                        if count:
+                            gw.engine.last_token_sent(req, t_sent)
                         self._chunk(json.dumps({"done": True, "n": count})
                                     + "\n")
                         self.wfile.write(b"0\r\n\r\n")

@@ -115,6 +115,10 @@ class PhaseTimer:
     `jax.profiler.TraceAnnotation` named `span_prefix + name`, so while a
     profiler session runs the phase sits in the profiler's own trace, on the
     clock of the device events (with no session that is a flag check).
+    `meta` goes to that annotation and nowhere else: the profiler shows it
+    as the event's arguments (`request=`, `row=`: the spans of one request
+    share an identifier), and the block is handed the annotation, so what
+    is known only at its end can follow (`set_metadata`).
 
     `names` pre-seeds the never-reset totals, so that a thread other than
     the one that runs the phases can read `cumulative` / `cumulative_counts`
@@ -133,7 +137,7 @@ class PhaseTimer:
         self.span_prefix = span_prefix
 
     @contextlib.contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, **meta):
         """Callers must block on the phase's outputs inside the block (e.g.
         `jax.block_until_ready(...)`) or async dispatch shifts time into the
         next phase."""
@@ -145,8 +149,8 @@ class PhaseTimer:
         )
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation(label), span:
-                yield
+            with jax.profiler.TraceAnnotation(label, **meta) as ann, span:
+                yield ann
         finally:
             dt = time.perf_counter() - t0
             self.totals[name] = self.totals.get(name, 0.0) + dt
