@@ -430,6 +430,46 @@ def use_paged_decode_kernel(config: ModelConfig) -> bool:
     return config.kv_cache_quant != "int8" and use_expert_kernel(config)
 
 
+def decode_loop_page_size(config: ModelConfig) -> int:
+    """The page size the one-jit rollout gives its OWN KV cache
+    (`sampler.generate_tokens` where its caller names none): pages of one
+    read block (`_DECODE_READ_BLOCK` slots) under the dense identity table
+    wherever a decode step then reads each row's own slots `[start, filled)`
+    in place (`attention_form` -> `"paged_decode"`), 0 = the contiguous cache
+    and its static extents (`decode_read_extents`) wherever it does not. The
+    two layouts of that private loop carry hold the same values and emit the
+    same tokens, so the choice is the program's, from what it can observe:
+
+    - `use_paged_decode_kernel`: the backend behind `attention_impl` (a TPU
+      under `"auto"`, or `"pallas"`), no mesh, a (k, v) cache that is not
+      int8 and not latent: elsewhere a paged read is the gathered view, which
+      moves every page of every row and loses to the extents;
+    - one kind of layer: what the monolithic paged loop runs
+      (`sampler.compose_check`: no window pattern, no state, no blocks);
+    - pages of whole 128-lane rows, the kernels' own geometry
+      (`_paged_row_kernel_takes`; the read's "`hd` must be a multiple of 128
+      when compiled"): Qwen2.5-0.5B's heads of 64 stay contiguous.
+
+    On the v5e the in-place read wins at every step of a 512-token response
+    of 64 rows at both measured geometries (`tools/bench_paged_read.py
+    rollout.*`, PERF.md section 6, PR 52; µs a layer, in place against
+    XLA's masked read of the 512 / 640 / 768-slot extent that step reads):
+    Qwen2.5-1.5B's 2 KV heads 46 / 72 / 83 against 64 / 75 / 86, OLMoE's 16
+    heads 254 / 431 / 520 against 372 / 455 / 545; in their cells the read
+    fell 1.92 -> 1.67 and 1.83 -> 1.53 ms a step. So the rule has no term
+    for the pool's geometry: none was found at which the extents win."""
+    KV, hd = _cache_heads(config)
+    # (a page of 128 slots is whole tiles of any cache type's sublanes:
+    # bfloat16 stands for whichever the parameters bring)
+    leaf = jax.ShapeDtypeStruct((1, 1, KV, _DECODE_READ_BLOCK, hd),
+                                jnp.bfloat16)
+    one_kind = (config.attention_pattern is None and not config.state_layers
+                and not config.block_generation)
+    return _DECODE_READ_BLOCK if (
+        one_kind and use_paged_decode_kernel(config)
+        and _paged_row_kernel_takes((leaf, leaf), _DECODE_READ_BLOCK)) else 0
+
+
 def _kernel_spmd(config: ModelConfig, H: int, KV: int):
     """(mesh, batch_axes, head_axis|None) for wrapping a Pallas kernel in
     shard_map, or None when no multi-device hint applies (single device, or
@@ -688,6 +728,34 @@ def _paged_page_write(pool, new, layer, table, cache_index, page_size):
     return pool.at[layer, page].set(jnp.where(mine, laid, old), mode="drop")
 
 
+# `KindView.write_plan` of a decode step whose table is the dense identity
+# table and whose rows all stand at one slot (the one-jit rollout's)
+IDENTITY_SLOT = "identity_slot"
+
+
+def _identity_slot_write(pool, new, layer, slot):
+    """A decode step's write under the dense identity table
+    (`pages.full_table`: row r's block j is page `r nb + j`) with every row
+    at the one scalar `slot`, as the one-jit rollout's are: every row writes
+    the same `(block, offset)`, so the write is ONE `dynamic_update_slice`
+    through the `[L, B, nb, KV, P, hd]` view of the pool, the contiguous
+    cache's write (`_cache_update`) and its bytes, where the live-row kernel
+    moves a row's whole tile group there and back (64 live rows: PERF.md
+    section 6, PR 52). The page splits into tiles of sublanes as the
+    contiguous cache's sequence does, for `_cache_update`'s reason. `new`
+    [B, KV, 1, hd]; bit-identical to `_paged_row_scatter`."""
+    L, N, KV, P, hd = pool.shape
+    B = new.shape[0]
+    sub = 32 // pool.dtype.itemsize
+    tiles = (P // sub, sub) if P % sub == 0 else (1, P)
+    off = slot % P
+    view = jax.lax.dynamic_update_slice(
+        pool.reshape(L, B, N // B, KV, *tiles, hd),
+        new[None, :, None, :, :, None, :],
+        (layer, 0, slot // P, 0, off // tiles[1], off % tiles[1], 0))
+    return view.reshape(pool.shape)
+
+
 def _page_write_takes(pool, T: int, page_size: int, table_blocks: int) -> bool:
     """Whether a write of T tokens into `pool` goes by page
     (`_paged_cache_update`)."""
@@ -738,10 +806,16 @@ def _cache_write(stacks, news, layer, view):
     through it; one with a `write_plan` is a decode step's on a TPU
     (`_kind_views`): a plan a new slot of the row (one, or a block
     forward's `block_length`), the live rows' slot i through
-    ops/paged_cache_write, K and V in one call, each in turn. Returns the
-    updated stacks. Under the scope `attn.write`."""
+    ops/paged_cache_write, K and V in one call, each in turn; under the
+    identity table the plan is `IDENTITY_SLOT`, one slice a leaf
+    (`_identity_slot_write`). Returns the updated stacks. Under the scope
+    `attn.write`."""
     out = []
     with jax.named_scope("attn.write"):
+        if view.write_plan is IDENTITY_SLOT:
+            return tuple(
+                _identity_slot_write(stack, new, layer, view.index)
+                for stack, new in zip(stacks, news))
         if view.write_plan is not None:
             from nanorlhf_tpu.ops.paged_cache_write import paged_row_write
 
@@ -969,7 +1043,8 @@ class KindView(NamedTuple):
     table: object = None        # block table [B, nb] int32 (paged), or the
                                 # state group's rows [B, 1]
     page_size: int = 0
-    write_plan: object = None   # a decode step's `PagedWritePlan`s on a TPU
+    write_plan: object = None   # a decode step's `PagedWritePlan`s on a TPU,
+                                # or `IDENTITY_SLOT`
     conv_ctx: tuple | None = None   # the state group's `(valid, fresh)`
     live: object = None         # [B] bool: the rows someone listens to
 
@@ -1990,7 +2065,8 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
 
 def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
                 decode=None, verify=None, page_table=None, page_size=0,
-                live=None, conv_ctx=None, write_at=None) -> tuple:
+                live=None, conv_ctx=None, write_at=None,
+                identity_table=False) -> tuple:
     """The call's `KindView` a cache group, from what its entrypoint knows:
     one for a model without a pattern, else `(global, window[, state])`.
 
@@ -2014,7 +2090,9 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
     its pool, its bound) and the live-row write's where the pool's shapes
     take it (`_paged_row_kernel_takes`; elsewhere the row scatter), a plan
     a slot of `write_at` (`(index,)`, or a block forward's `block_length`
-    slots from `index` on).
+    slots from `index` on). `identity_table`: the table is the dense
+    identity table and `index` one slot for every row (the one-jit
+    rollout), where that write is `IDENTITY_SLOT` and needs no plan.
     `conv_ctx`: a thunk of `_conv_ctx(...)`, called last (the operations
     stand in the program in the order the entrypoints always staged them:
     the decode bounds, the masks, the verify bounds, the plans, the state's
@@ -2070,11 +2148,13 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
             num_pages=group[0].shape[1],
             pages_per_item=paged_pages_per_item(group[0]), live=live)
             for table, group, (first, _) in zip(tables, groups, decodes)]
-        plans = [tuple(paged_write_plan(
-            table, at, page_size=page_size, num_pages=group[0].shape[1],
-            live=live) for at in write_at or (index,))
-            if _paged_row_kernel_takes(group, page_size) else None
-            for table, group in zip(tables, groups)]
+        plans = [None if not _paged_row_kernel_takes(group, page_size)
+                 else IDENTITY_SLOT if identity_table
+                 else tuple(paged_write_plan(
+                     table, at, page_size=page_size,
+                     num_pages=group[0].shape[1], live=live)
+                     for at in write_at or (index,))
+                 for table, group in zip(tables, groups)]
     ctx = None if conv_ctx is None else conv_ctx().get("conv_ctx")
 
     def form(group):
@@ -2619,6 +2699,9 @@ def decode_step(
     want_logits: bool = True,     # False: the final hidden state [B, D] as
                                   # the head (`_logits`) would take it, for a
                                   # caller that scores some of the rows
+    identity_table: bool = False,  # static: `page_table` is the dense
+                                  # identity table (`pages.full_table`) and
+                                  # `cache_index` a scalar, every row's
 ):
     """One autoregressive decode step. Returns (logits [B, V], new caches),
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
@@ -2641,7 +2724,7 @@ def decode_step(
     views = _kind_views(
         config, mask, lambda: (filled - 1)[:, None], kv_caches=kv_caches,
         index=cache_index, decode=(start, filled), page_table=page_table,
-        page_size=page_size,
+        page_size=page_size, identity_table=identity_table,
         # (an expert layer dispatches the rows someone listens to only, and
         # the in-place paged read and write skip the others)
         live=live,

@@ -18,9 +18,13 @@ becomes a per-call PRNG key. Greedy mode covers the ReMax baseline rollout
 Decode is a `lax.while_loop` over single-token steps with a shared KV cache;
 it exits early once every sequence has emitted EOS (rollouts are offline-batch,
 so big batches keep the MXU busy; early exit claws back the static-shape tax).
-Past one 128-slot block of cache it is a few such loops in a row inside the
-one jit, each reading the cache up to a static extent that no row's write has
-passed (`_read_loops`): XLA's attention masks, it does not bound.
+The loop lays its own cache out (`_loop_page_size`): in pages of 128 slots
+under the identity table wherever a step then reads each row's own slots in
+place (a TPU without a mesh, `core/model.decode_loop_page_size`), and
+elsewhere contiguous, where past one 128-slot block it is a few such loops in
+a row inside the one jit, each reading the cache up to a static extent that
+no row's write has passed (`_read_loops`): XLA's attention masks, it does
+not bound.
 """
 
 from __future__ import annotations
@@ -34,8 +38,8 @@ import numpy as np
 
 from nanorlhf_tpu.core.config import ModelConfig
 from nanorlhf_tpu.core.model import (
-    decode_read_extents, decode_step, init_kv_cache, init_paged_kv_cache,
-    prefill,
+    decode_loop_page_size, decode_read_extents, decode_step, init_kv_cache,
+    init_paged_kv_cache, prefill, use_paged_decode_kernel,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import full_table
@@ -103,7 +107,10 @@ class SamplingParams:
     # writes) and kv_cache_quant="int8" (paged scale pools). Pick
     # page_size >= 128 on real TPUs (lane-tile alignment for the paged
     # kernels' int8 scale blocks); CPU tests run any size via interpret
-    # mode. 0 = contiguous slabs, bit-for-bit untouched.
+    # mode. 0 = the loop's own choice: the queued, speculative and
+    # compacting loops keep contiguous slabs, the monolithic one-jit loop
+    # lays its private cache out whichever way its decode read is cheaper
+    # (`_loop_page_size`: the same tokens either way).
     page_size: int = 0
     # page_size > 0 only: >0 enables CONTINUOUS BATCHING — the decode loop
     # runs `decode_rows` resident rows over a page pool sized for exactly
@@ -474,9 +481,11 @@ def generate_tokens(
     N prefills the [B] prompts once and decodes N samples per prompt
     (prompt-major rows), sharing the prompt KV. `page_size` > 0 runs the
     same loop over the paged KV layout (dense identity block table — no
-    recycling here; see sampler/paged/scheduler.py for that)."""
+    recycling here; see sampler/paged/scheduler.py for that); 0 leaves the
+    layout to the loop (`_loop_page_size`)."""
     config.refuse_block_generation("the one-jit rollout (generate_tokens)")
     Tp = prompt_ids.shape[1]
+    page_size = _loop_page_size(config, page_size)
     state = _prefill_state(
         params, config, prompt_ids, prompt_mask, key,
         max_tokens=max_tokens, eos_token_id=eos_token_id,
@@ -510,6 +519,41 @@ def generate_tokens(
     return (out, lp_out) if capture_logprobs else out
 
 
+def _loop_page_size(config, page_size: int) -> int:
+    """The page size of the monolithic one-jit loop's cache: its caller's,
+    or where the caller names none (0) the loop's own choice,
+    `core/model.decode_loop_page_size`: pages where a decode step reads them
+    in place, 0 = the contiguous cache. The one place the layout is decided:
+    the loop, its extents (`_read_loops`) and what is reported of it
+    (`attn_read_frac`, `kv_in_place`, `_monolithic_paged_stats`) all ask
+    here."""
+    return page_size if page_size > 0 else decode_loop_page_size(config)
+
+
+def _queued(sampling: SamplingParams, rows: int) -> bool:
+    """Whether `generate` hands a call of `rows` rows in all to the rollout
+    scheduler: fewer resident rows than rows, over recycled pages."""
+    return sampling.page_size > 0 and 0 < sampling.decode_rows < rows
+
+
+def _monolithic(sampling: SamplingParams, rows: int) -> bool:
+    """Whether `generate` runs a call of `rows` rows in all through the
+    monolithic loop (`generate_tokens`), not the queued, the speculative or
+    the compacting one."""
+    return not (_queued(sampling, rows) or sampling.spec_k > 0
+                or sampling.compaction_segments > 0)
+
+
+def kv_in_place(config, sampling: SamplingParams, rows: int) -> int:
+    """1 where the monolithic loop of this `generate` call keeps its cache
+    in pages and reads each row's own in place, else 0: the trainer's static
+    `rollout/kv_in_place`, as `serving/kv_write_live_rows` is the
+    session's."""
+    return int(_monolithic(sampling, rows)
+               and _loop_page_size(config, sampling.page_size) > 0
+               and use_paged_decode_kernel(config))
+
+
 def _read_loops(config, Tp, max_tokens, page_size=0):
     """`[(extent, stop)]` of the monolithic loop: the extents of
     `decode_read_extents` over its contiguous cache, each with the first
@@ -517,10 +561,11 @@ def _read_loops(config, Tp, max_tokens, page_size=0):
     the first outside it. The last step, `max_tokens - 1`, writes slot
     `T_max - 2` (the last sampled token is never fed back). One loop over
     the whole cache (to `decode_step` the same as no extent) where there is
-    one extent only."""
+    one extent only, and over a paged cache (`_loop_page_size`), whose read
+    goes by the table."""
     T_max = Tp + max_tokens
-    extents = (T_max,) if page_size > 0 else decode_read_extents(
-        config, Tp, T_max - 2, T_max)
+    extents = (T_max,) if _loop_page_size(config, page_size) > 0 else (
+        decode_read_extents(config, Tp, T_max - 2, T_max))
     loops = [(e, e - Tp + 1) for e in extents[:-1]] + [(T_max, max_tokens)]
     # no slot that holds a key is skipped: each loop's last write slot is
     # inside its extent
@@ -529,22 +574,39 @@ def _read_loops(config, Tp, max_tokens, page_size=0):
 
 
 def attn_read_frac(config, sampling, prompt_width: int, responses,
-                   eos_token_id: int) -> float:
-    """Share of the cache's slots the decode attention of one `generate`
-    call read (the trainer's `rollout/attn_read_frac`): the sum over its
-    decode steps of the step's extent, over steps x T_max. 1.0 wherever the
-    loop names no extent (a cache of one block, the compacting, paged and
-    speculative loops, the Pallas read), and for a call that took no step.
+                   eos_token_id: int, prompt_lens=None) -> float:
+    """Share of the cache the decode attention of one `generate` call read
+    (the trainer's `rollout/attn_read_frac`), summed over its decode steps.
+    Over the contiguous cache: the step's extent over the `T_max` slots.
+    Over pages read in place (`kv_in_place`): the pages the kernel copies,
+    each row's blocks `[start // P, (filled - 1) // P]` from the end of its
+    left pad, `start = prompt_width - prompt_lens[row]`, to the step's
+    slot, over the table's `rows x ceil(T_max / P)`; `prompt_lens` is a real
+    length a PROMPT (each N consecutive rows), and every row counts at every
+    step (the loop marks none dead). 1.0 wherever the loop names no bound: a
+    cache of one block, the compacting, queued and speculative loops, a
+    paged cache read as the gathered view, and a call that took no step.
     `responses` is the call's [rows, max_tokens] result on the HOST: the
     loop ran until its longest row ended, one step a token after the
-    prefill's, so the static extents and that length say it all."""
+    prefill's, so the static layout, the prompts' lengths and that length
+    say it all: no device read."""
     ends = responses == eos_token_id
     steps = int(np.where(ends.any(axis=1), ends.argmax(axis=1) + 1,
                          responses.shape[1]).max()) - 1
-    if (sampling.page_size > 0 or sampling.spec_k > 0
-            or sampling.compaction_segments > 0 or steps <= 0):
+    rows = responses.shape[0]
+    if not _monolithic(sampling, rows) or steps <= 0:
         return 1.0
     T_max = prompt_width + sampling.max_tokens
+    P = _loop_page_size(config, sampling.page_size)
+    if P > 0:
+        if not use_paged_decode_kernel(config):     # the gathered view
+            return 1.0
+        lens = np.asarray(prompt_lens)
+        first = (prompt_width - np.repeat(lens, rows // lens.shape[0])) // P
+        # step s writes slot Tp + s - 1, the last of its `filled`
+        last = (prompt_width + np.arange(steps)) // P
+        read = int((last[None, :] - first[:, None] + 1).sum())
+        return read / (steps * rows * -(-T_max // P))
     read, start = 0, 1
     for extent, stop in _read_loops(config, prompt_width,
                                     sampling.max_tokens):
@@ -672,7 +734,7 @@ def _decode_body(params, config, state, *, Tp, max_tokens, eos_token_id,
     if page_size > 0:
         B = key_mask.shape[0]
         read_kw = dict(page_table=full_table(B, caches[0].shape[1] // B),
-                       page_size=page_size)
+                       page_size=page_size, identity_table=True)
     # token t was sampled from logits at position prompt_len + step - 1;
     # its KV lands in cache slot Tp + step - 1
     cache_slot = Tp + step - 1
@@ -762,8 +824,7 @@ def generate(
         prefix_cache is not None
         and getattr(prefix_cache, "enabled", False)), config=config)
     total_rows = prompt_ids.shape[0] * sampling.n
-    queued = (sampling.page_size > 0 and sampling.decode_rows > 0
-              and sampling.decode_rows < total_rows)
+    queued = _queued(sampling, total_rows)
     fanout = 1
     if sampling.n > 1:
         if sampling.shared_prompt_prefill and not queued:
@@ -808,7 +869,8 @@ def generate(
             page_size=sampling.page_size,
         )
         _monolithic_paged_stats(result, sampling, prompt_mask, fanout,
-                                pad_token_id, paged_stats_out)
+                                pad_token_id, paged_stats_out,
+                                sampling.page_size)
         return result
     if sampling.compaction_segments > 0:
         from nanorlhf_tpu.sampler.compaction import generate_tokens_compact
@@ -844,21 +906,24 @@ def generate(
         page_size=sampling.page_size,
     )
     _monolithic_paged_stats(result, sampling, prompt_mask, fanout,
-                            pad_token_id, paged_stats_out)
+                            pad_token_id, paged_stats_out,
+                            _loop_page_size(config, sampling.page_size))
     return result
 
 
 def _monolithic_paged_stats(result, sampling, prompt_mask, fanout,
-                            pad_token_id, paged_stats_out):
-    """Fill `paged_stats_out` for the monolithic (non-queued) paged paths:
-    no recycling, no admissions — utilization is just final cache occupancy
-    over the fully-provisioned pool. Device scalars only (no sync; the
-    trainer materializes them at metrics time like spec_stats)."""
-    if paged_stats_out is None or sampling.page_size <= 0:
+                            pad_token_id, paged_stats_out, page_size):
+    """Fill `paged_stats_out` for the monolithic (non-queued) paged paths,
+    whose cache has pages of `page_size` (the caller's or the loop's own
+    choice; 0: a contiguous cache, nothing to say): no recycling, no
+    admissions — utilization is just final cache occupancy over the
+    fully-provisioned pool. Device scalars only (no sync; the trainer
+    materializes them at metrics time like spec_stats)."""
+    if paged_stats_out is None or page_size <= 0:
         return
     toks = result[0] if sampling.capture_logprobs else result
     rows, Tp = toks.shape[0], prompt_mask.shape[1]
-    P = sampling.page_size
+    P = page_size
     nb = -(-(Tp + sampling.max_tokens) // P)
     used = (jnp.sum(prompt_mask) * fanout
             + jnp.sum(toks != pad_token_id)).astype(jnp.float32)

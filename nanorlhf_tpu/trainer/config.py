@@ -328,7 +328,13 @@ class RLConfig:
     # (greedy streams bit-identical to contiguous, test-pinned); with
     # rollout_decode_rows > 0 it unlocks true continuous batching. Composes
     # with rollout_spec_k and kv_cache_quant="int8". Use >= 128 on real
-    # TPUs (lane-tile alignment for the paged kernels); 0 = contiguous.
+    # TPUs (lane-tile alignment for the paged kernels). 0 = no paged
+    # FEATURE (no queue, no recycling) and the layout left to the loop: the
+    # monolithic one-jit rollout keeps its private cache in pages of 128
+    # wherever a decode step then reads each row's own in place (one TPU
+    # device, a plain bf16 cache: core/model.decode_loop_page_size; the
+    # same tokens, `rollout/kv_in_place` says which) and contiguous
+    # elsewhere; the speculative and compacting loops stay contiguous.
     rollout_page_size: int = 0
     # rollout_page_size > 0 only. >0: continuous batching — only this many
     # rows are RESIDENT in the decode loop; when a row emits EOS its pages

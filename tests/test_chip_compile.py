@@ -235,7 +235,11 @@ def _shapes_on(tree, sharding):
 def _decode_loop(case, one_chip, layers=2):
     """(lowered program, stacked cache shapes) of a decode loop at
     Qwen2.5-1.5B widths, two layers deep: the benchmark's rollout (16 prompts
-    x 4 samples, 256 + 512 slots), the same over an int8 cache, and the
+    x 4 samples, 256 + 512 slots: since ISSUE 52 over 64 x 6 pages of 128
+    wherever `core/model.decode_loop_page_size` says so, which with
+    `default_backend` answered as `tpu` is here), the same under
+    `attention_impl="xla"` (`rollout_xla`: the contiguous cache and its
+    extents on a TPU too) and over an int8 cache, and the
     serving session's chunk (64 rows, 807 pages of 128); `rollout_olmoe` is
     the rollout at OLMoE-1B-7B's widths (64 experts, 8 a token).
     `serving_admission` is the session's largest suffix prefill (1024
@@ -251,6 +255,7 @@ def _decode_loop(case, one_chip, layers=2):
         vocab_size=V, hidden_size=D, intermediate_size=8960,
         num_hidden_layers=layers, num_attention_heads=H,
         num_key_value_heads=KV,
+        attention_impl="xla" if case == "rollout_xla" else "auto",
         kv_cache_quant="int8" if case == "rollout_int8" else "none")
     if case == "rollout_olmoe":
         cfg = dataclasses.replace(ModelConfig.olmoe_1b_7b(), num_hidden_layers=2)
@@ -296,12 +301,16 @@ def _decode_loop(case, one_chip, layers=2):
         params, cfg, spec((B, Tp), jnp.int32), spec((B, Tp), jnp.bool_), key,
         max_tokens=new, eos_token_id=3, pad_token_id=0, temperature=0.9,
         capture_logprobs=True, prompt_fanout=fanout)
+    page = M.decode_loop_page_size(cfg)
     cache = jax.eval_shape(
-        lambda: M.init_kv_cache(cfg, B * fanout, Tp + new, jnp.bfloat16))
+        lambda: M.init_paged_kv_cache(
+            cfg, B * fanout * (Tp + new) // page, page, jnp.bfloat16) if page
+        else M.init_kv_cache(cfg, B * fanout, Tp + new, jnp.bfloat16))
     return lowered, cache
 
 
-@pytest.mark.parametrize("case", ["rollout", "rollout_int8", "serving_chunk"])
+@pytest.mark.parametrize("case", ["rollout", "rollout_xla", "rollout_int8",
+                                  "serving_chunk"])
 def test_decode_loop_carries_the_cache_in_place_on_v5e(
         case, v5e, compiled_kernels, monkeypatch):
     """What tests/test_cache_carry.py holds XLA:CPU to, asked of the chip's
@@ -316,7 +325,10 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
     pages in place (ISSUE 28): the kernel is in the loop, and nothing the
     loop reaches, fused or not, has the shape of the gathered view's pieces
     (the rows' 64 x 12 = 768 pages gathered, their transpose into row
-    order) or of a layer's slab of the pool."""
+    order) or of a layer's slab of the pool. So does the rollout since
+    ISSUE 52, over its own 64 x 6 pages under the identity table (`rollout`);
+    the contiguous cache and its three extents (ISSUE 33) are what a TPU
+    runs under `"xla"` (`rollout_xla`) and under a mesh."""
     from test_cache_carry import (
         _CALLEE, _shapes, decode_loop_offences, decode_loop_reach, hlo_stacks,
     )
@@ -328,6 +340,39 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
         hlo, hlo_stacks(cache), slabs_too=case != "rollout_int8")
     assert not offences, "\n".join(offences)
     if case == "rollout":
+        # ISSUE 52: ONE decode loop over the page pool; the in-place read's
+        # kernel is in it; the write is a slice of each leaf where it lies
+        # (`_identity_slot_write`: through a view, not the live-row kernel,
+        # no scatter); no copy of a pool leaf anywhere in the program, the
+        # prefill's fan-out included; and nothing the loop reaches has the
+        # shape of a gathered view (the rows' 384 pages, their transpose
+        # into rows of 768 slots) or of a layer's slab of the pool
+        assert hlo_stacks(cache) == {("bf16", (2, 384, KV, PAGE, HD))}
+        comps, reached = decode_loop_reach(hlo)
+        assert _decode_loops(comps) == 1
+        reads = [name for name in reached
+                 for instr, _, op, rest in comps[name]
+                 if op == "custom-call" and instr.startswith("attn.read")
+                 and "tpu_custom_call" in rest]
+        assert len(reads) == 1, reads
+        offences, writes = decode_loop_offences(hlo, hlo_stacks(cache),
+                                                slabs_too=True)
+        assert not offences and writes >= 2, (offences, writes)
+        assert _live_row_write_calls(hlo) == 0
+        assert not any(op == "scatter" for name in reached
+                       for _, _, op, _ in comps[name])
+        view = {(384, KV, PAGE, HD), (64, KV, 6, PAGE, HD),
+                (64, 6, KV, PAGE, HD), (64, KV, 768, HD)}
+        made = [f"{name}: {result} {op}" for name in reached
+                for _, result, op, _ in comps[name]
+                if not result.startswith("(")
+                and _shapes(result) and _shapes(result)[0][1] in view]
+        assert not made, "\n".join(made)
+        copies = [f"{name}: {result}" for name, instrs in comps.items()
+                  for _, result, op, _ in instrs if op.startswith("copy")
+                  and _shapes(result)[:1] == list(hlo_stacks(cache))]
+        assert not copies, "\n".join(copies)
+    if case == "rollout_xla":
         # ISSUE 33: three decode loops, one an extent of the cache read; each
         # loop's QK fusion reads its own extent of the stack in place (no
         # slab of any extent set down on the way: as a static slice BEHIND
@@ -441,8 +486,23 @@ def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(
     lowered, cache = _decode_loop("rollout_olmoe", SingleDeviceSharding(v5e[0]))
     compiled = lowered.compile()
     hlo = compiled.as_text()
-    offences, _ = decode_loop_offences(hlo, hlo_stacks(cache))
-    assert not offences, "\n".join(offences)
+    offences, writes = decode_loop_offences(hlo, hlo_stacks(cache),
+                                            slabs_too=True)
+    assert not offences and writes >= 2, (offences, writes)
+    # ISSUE 52: its cache is 64 x 6 pages of 16 heads, read in place by the
+    # kernel inside ONE decode loop and written by slice; no copy of a leaf
+    from test_cache_carry import _shapes, decode_loop_reach
+
+    assert hlo_stacks(cache) == {("bf16", (2, 384, 16, PAGE, HD))}
+    comps, reached = decode_loop_reach(hlo)
+    reads = [name for name in reached for instr, _, op, _ in comps[name]
+             if op == "custom-call" and instr.startswith("attn.read")]
+    assert len(reads) == 1, reads      # one layer scan: one loop (an expert
+    assert _live_row_write_calls(hlo) == 0      # layer has loops of its own)
+    copies = [f"{name}: {result}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs if op.startswith("copy")
+              and _shapes(result)[:1] == list(hlo_stacks(cache))]
+    assert not copies, "\n".join(copies)
     # prefill + decode loop bodies, three kernels each, under one layer scan
     assert len(re.findall(r"%gmm[\w.]* = bf16\[\d+,\d+\]\S* custom-call\(", hlo)) >= 6
     expert_shapes = r"bf16\[(?:\d+,)?64,(?:2048,1024|1024,2048)\]"
@@ -457,6 +517,17 @@ def test_moe_decode_step_is_a_grouped_matmul_over_the_stack_in_place_on_v5e(
     flops = compiled.cost_analysis()["flops"]
     routed = (16 * 256 + 64) * 8 * 3 * 2 * 2048 * 1024
     assert routed < flops < 2.5 * routed, (flops, routed)
+
+
+def _decode_loops(comps) -> int:
+    """Decode loops of a compiled module: `while`s whose body holds the
+    layer scan's `while` (tests/test_cache_carry.decode_loop_reach)."""
+    import re
+
+    bodies = {re.search(r"body=%?([\w.\-]+)", rest).group(1)
+              for instrs in comps.values() for _, _, op, rest in instrs
+              if op == "while"}
+    return sum(any(op == "while" for _, _, op, _ in comps[b]) for b in bodies)
 
 
 def _live_row_write_calls(hlo: str) -> int:

@@ -482,3 +482,82 @@ def test_the_kernel_rules_that_bar_a_cache_bar_its_forms():
         sees = dict(cached=True, paged=True, cache_len=64)
         assert M.attention_form(cfg, 1, decode=True, **sees) == "view"
         assert M.attention_form(cfg, 4, verify=True, **sees) == verify
+
+
+# --------------------------------------------------------------------- #
+# the layout of the one-jit rollout's own cache, a term a case (ISSUE 52)
+# --------------------------------------------------------------------- #
+
+def _wide(config, **changes):
+    """`config` with heads of 128 lanes, what the paged kernels take."""
+    return dataclasses.replace(config, head_dim=128, **changes)
+
+
+_LAYOUTS = [
+    # (id, config, the backend, pages of)
+    ("a TPU under auto: pages of a read block, read in place",
+     _wide(_ONE), "tpu", 128),
+    ("pallas, anywhere: the same", _wide(_ONE, attention_impl="pallas"),
+     "cpu", 128),
+    ("an expert model of one kind of layer: the same",
+     _wide(ModelConfig.olmoe_tiny(vocab_size=V)), "tpu", 128),
+    ("xla on a TPU: the gathered view would lose to the extents",
+     _wide(_ONE, attention_impl="xla"), "tpu", 0),
+    ("auto off the TPU is xla", _wide(_ONE), "cpu", 0),
+    ("under a mesh GSPMD refuses the kernel", _wide(_ONE), "mesh", 0),
+    ("an int8 cache has a read of its own",
+     _wide(_ONE, kv_cache_quant="int8"), "tpu", 0),
+    ("a latent cache (MLA) reads through XLA",
+     ModelConfig.axk1_tiny(vocab_size=V), "tpu", 0),
+    ("a pattern model rolls out on the contiguous cache",
+     _wide(_PATTERN), "tpu", 0),
+    ("a model that keeps a state: the same",
+     _wide(ModelConfig.falcon_h1_tiny(vocab_size=V)), "tpu", 0),
+    ("a model that generates by blocks has no rollout",
+     _wide(ModelConfig.sdar_tiny(vocab_size=V)), "tpu", 0),
+    ("heads of 64 lanes: the kernels take whole rows of 128",
+     dataclasses.replace(_ONE, head_dim=64), "tpu", 0),
+    ("heads of 16, under pallas too", dataclasses.replace(
+        _ONE, attention_impl="pallas"), "cpu", 0),
+]
+
+
+@pytest.mark.parametrize("config, backend, pages",
+                         [pytest.param(*row[1:], id=row[0])
+                          for row in _LAYOUTS])
+def test_the_rollouts_own_cache_takes_the_layout_its_rule_names(
+        config, backend, pages, monkeypatch):
+    """`decode_loop_page_size`: pages exactly where a decode step over the
+    identity table is `attention_form`'s `"paged_decode"` AND the kernels
+    take the pool's geometry; and the loop, its extents and what is
+    reported of it all go by it (`sampler._loop_page_size`)."""
+    from jax.sharding import Mesh
+
+    from nanorlhf_tpu.sampler import sampler as S
+    from nanorlhf_tpu.sampler.sampler import SamplingParams
+
+    if backend == "mesh":
+        config = dataclasses.replace(config, spmd_mesh=Mesh(
+            np.asarray(jax.devices()[:2]), ("data",)))
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "cpu" if backend == "cpu" else "tpu")
+    assert M.decode_loop_page_size(config) == pages
+    if pages:
+        assert M.attention_form(config, 1, cached=True, paged=True,
+                                decode=True, cache_len=768) == "paged_decode"
+    assert S._loop_page_size(config, 0) == pages
+    assert S._loop_page_size(config, 16) == 16      # the caller's stands
+    if config.block_generation:
+        return
+    sampling = SamplingParams(max_tokens=512)
+    assert S.kv_in_place(config, sampling, 64) == int(pages > 0)
+    loops = S._read_loops(config, 256, 512)
+    # one loop over pages, and wherever the contiguous read bounds itself
+    assert (loops == [(768, 512)]) == (
+        pages > 0 or config.kv_cache_quant == "int8"
+        or bool(config.kv_lora_rank)
+        or M.use_decode_kernel(config.attention_impl, 768))
+    for other in (dict(spec_k=2), dict(compaction_segments=2),
+                  dict(page_size=128, decode_rows=8)):
+        assert S.kv_in_place(config, dataclasses.replace(
+            sampling, **other), 64) == 0

@@ -188,6 +188,9 @@ def test_golden_rows_and_parameters_reproduce(name, tmp_path_factory):
         # a counter the golden's parent did not write (ISSUE 33): these
         # caches are one block, so the rollout read all of it
         assert have.pop("rollout/attn_read_frac", 1.0) == 1.0
+        # ... and one that says the loop kept no pages (ISSUE 52: off the
+        # TPU its cache stays contiguous)
+        assert have.pop("rollout/kv_in_place", 0) == 0
         if name.startswith("sparse"):
             # every key the parent wrote, with its value; the keys the
             # shared phases add are counted in the test below

@@ -434,6 +434,126 @@ def test_queued_sampled_rows_terminate_and_fill_contract():
 # trainer wiring: metrics rows + checkpoint/resume over the paged path
 # --------------------------------------------------------------------- #
 
+# --------------------------------------------------------------------- #
+# the loop's own choice of layout (ISSUE 52): page_size == 0 under
+# `core/model.decode_loop_page_size`
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def wide():
+    """Heads of 128 lanes, what the in-place kernels take: a dense model
+    and an expert model of one kind of layer."""
+    models = {}
+    for name, config in (("dense", ModelConfig.qwen2_tiny(vocab_size=128)),
+                         ("olmoe", ModelConfig.olmoe_tiny(vocab_size=128))):
+        config = dataclasses.replace(config, head_dim=128)
+        models[name] = (config, init_params(config, jax.random.PRNGKey(7),
+                                            jnp.float32))
+    return models
+
+
+# three rows whose left pads end in block 0, 1 and 1 of pages of 128
+_OWN_TP, _OWN_NEW, _OWN_LENS = 160, 130, (160, 20, 3)
+
+
+@pytest.mark.parametrize("case", ["greedy", "sampled_with_one_key",
+                                  "fanout_4", "olmoe_greedy"])
+def test_the_loops_own_pages_emit_the_contiguous_loops_tokens(wide, case):
+    """`generate` with `page_size == 0` where the predicate says pages
+    (`attention_impl="pallas"`: the kernels interpreted) runs ONE loop over
+    pools of 128-slot pages under the identity table, reads each row's own
+    pages in place and writes by slice, and emits the
+    tokens of the contiguous loop (`"xla"`: the extents) bit for bit, greedy,
+    sampled with one key and fanned out by 4, the captured logprobs equal to
+    float32 roundoff (an online softmax over pages against one over the
+    extent); `attn_read_frac` is the pages a count by hand gives,
+    `kv_in_place` 1, and the paged stats say what the pool held."""
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler import sampler as S
+
+    config, params = wide["olmoe" if case.startswith("olmoe") else "dense"]
+    pages = dataclasses.replace(config, attention_impl="pallas")
+    plain = dataclasses.replace(config, attention_impl="xla")
+    assert M.decode_loop_page_size(pages) == 128
+    assert M.decode_loop_page_size(plain) == M.decode_loop_page_size(config) == 0
+    Tp, new = _OWN_TP, _OWN_NEW
+    ids, mask = _left_pad([list(range(5, 5 + n)) for n in _OWN_LENS], Tp)
+    n = {"fanout_4": 4, "sampled_with_one_key": 1}.get(case, 2)
+    sampling = SamplingParams(
+        greedy="greedy" in case, temperature=0.9, n=n, max_tokens=new,
+        capture_logprobs=True)
+    key = jax.random.PRNGKey(11)
+    want, want_lp = generate(params, plain, ids, mask, key, sampling,
+                             eos_token_id=-1, pad_token_id=PAD)
+    stats = []
+    got, got_lp = generate(params, pages, ids, mask, key, sampling,
+                           eos_token_id=-1, pad_token_id=PAD,
+                           paged_stats_out=stats)
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got, np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got_lp), np.asarray(want_lp),
+                               rtol=0, atol=1e-4)
+    assert got.shape == (3 * n, new)
+    # one loop over the pages, one an extent over the contiguous cache
+    assert S._read_loops(pages, Tp, new) == [(Tp + new, new)]
+    assert S._read_loops(plain, Tp, new) == [(256, 97), (Tp + new, new)]
+    # by hand: steps 1 .. 129 write slots 160 .. 288: block 1 for 96 steps,
+    # block 2 for 33; the row without a pad reads from block 0, the others'
+    # pads end in block 1; of 3 pages a row
+    assert (-(-(Tp + new) // 128), new - 1) == (3, 129)
+    by_hand = n * ((2 * 96 + 3 * 33) + 2 * (1 * 96 + 2 * 33))
+    lens = np.asarray(_OWN_LENS)
+    frac = S.attn_read_frac(pages, sampling, Tp, got, -1, prompt_lens=lens)
+    assert frac == pytest.approx(by_hand / (129 * 3 * n * 3))
+    assert S.kv_in_place(pages, sampling, 3 * n) == 1
+    # the contiguous loop's counter goes by its extents, and says so
+    assert S.kv_in_place(plain, sampling, 3 * n) == 0
+    assert S.attn_read_frac(plain, sampling, Tp, got, -1, prompt_lens=lens
+                            ) == S.attn_read_frac(plain, sampling, Tp, got, -1)
+    (st,) = stats
+    assert (st["page_size"], st["num_pages"], st["rows"]) == (128, 9 * n, 3 * n)
+    used = n * sum(_OWN_LENS) + int((got != PAD).sum())
+    assert float(st["page_utilization"]) == pytest.approx(
+        used / (9 * n * 128))
+    plain_stats = []
+    generate(params, plain, ids[:1], mask[:1], key,
+             dataclasses.replace(sampling, max_tokens=2), eos_token_id=-1,
+             pad_token_id=PAD, paged_stats_out=plain_stats)
+    assert plain_stats == []        # a contiguous cache reports no pages
+
+
+def test_a_row_that_ends_early_stops_the_count_of_pages(wide):
+    """The loop runs until its LONGEST row ends: `attn_read_frac` over pages
+    counts the steps taken, every row at every one of them (the loop marks
+    no row dead), and a gathered view (an explicit page size off the
+    kernel's rule) reads everything."""
+    from nanorlhf_tpu.sampler import sampler as S
+
+    config, _ = wide["dense"]
+    pages = dataclasses.replace(config, attention_impl="pallas")
+    sampling = SamplingParams(max_tokens=300)
+    responses = np.full((4, 300), 7, np.int32)
+    responses[:, 40] = EOS          # two prompts x 2: every row ends at 41
+    responses[0, 40], responses[0, 99] = 7, EOS     # but one, at 100
+    lens = np.asarray([100, 200])   # starts 156 (block 1) and 56 (block 0)
+    # steps 1 .. 99 write slots 256 .. 354: block 2; of 5 pages a row
+    by_hand = 99 * 2 * (2 + 3)
+    assert S.attn_read_frac(pages, sampling, 256, responses, EOS,
+                            prompt_lens=lens) == pytest.approx(
+        by_hand / (99 * 4 * 5))
+    view = dataclasses.replace(config, attention_impl="xla")
+    assert S.attn_read_frac(
+        view, dataclasses.replace(sampling, page_size=16), 256, responses,
+        EOS, prompt_lens=lens) == 1.0
+    # an explicit page size under the kernel's rule is read in place too
+    own = dataclasses.replace(sampling, page_size=64)
+    assert S.kv_in_place(pages, own, 4) == 1
+    # slots 256 .. 354 of pages of 64: blocks 4 (64 steps) and 5 (35)
+    by_hand = 2 * (64 * (3 + 5) + 35 * (4 + 6))
+    assert S.attn_read_frac(pages, own, 256, responses, EOS, prompt_lens=lens
+                            ) == pytest.approx(by_hand / (99 * 4 * 9))
+
+
 def _paged_trainer(tmp_path, decode_rows=4):
     from nanorlhf_tpu.data import ToyTokenizer, load_prompt_dataset
     from nanorlhf_tpu.parallel import MeshConfig

@@ -3,7 +3,8 @@ write"): the row scatter (`core/model._paged_row_scatter`: the plain form,
 and the oracle here), the write by page (`_paged_page_write`: what
 `_paged_cache_update` takes for a page of tokens or more) and the decode
 step's live rows in place (ops/paged_cache_write, in interpret mode). Every
-form must leave the pool BIT-identical to the row scatter's: the write copies
+form, and the one slice the identity table's write is (ISSUE 52), must
+leave the pool BIT-identical to the row scatter's: the write copies
 values and computes nothing (the one exception is named where it is tested:
 the slot a DONE row would rewrite, which the live-row kernel skips). The chip's compiler is asked in
 tests/test_chip_compile.py."""
@@ -343,6 +344,69 @@ def test_a_decode_step_takes_the_kernel_only_where_it_can(pool_kind,
             ) == taken
     for a, b in zip(jax.jit(write(planned))(stacks, news),
                     jax.jit(write(scattered))(stacks, news)):
+        np.testing.assert_array_equal(bits(a), bits(b))
+
+
+@pytest.mark.parametrize("slot", [0, 7, 16, 37, 47])
+@pytest.mark.parametrize("pool_kind", ["bf16_pages_of_16", "bf16_pages_of_128",
+                                       "float32_pages_of_8", "pages_of_4",
+                                       "kv16"])
+def test_the_identity_tables_slot_write_is_the_row_scatters(pool_kind, slot):
+    """Under the dense identity table with every row at one slot (the
+    one-jit rollout's decode step, ISSUE 52) the write is one slice through
+    the `[L, B, nb, KV, tiles, sublanes, hd]` view of the pool
+    (`_identity_slot_write`), whatever the page holds in tiles: the pool
+    comes out the row scatter's bit for bit, in the layer asked for and no
+    other, and `decode_step(identity_table=True)` hands `_cache_write` that
+    form wherever it would have handed it a live-row plan."""
+    import dataclasses
+
+    from nanorlhf_tpu.core import ModelConfig
+    from nanorlhf_tpu.sampler.paged.pages import full_table
+
+    P, KV, dtype = {"bf16_pages_of_16": (16, 2, jnp.bfloat16),
+                    "bf16_pages_of_128": (128, 2, jnp.bfloat16),
+                    "float32_pages_of_8": (8, 2, jnp.float32),
+                    "pages_of_4": (4, 2, jnp.bfloat16),
+                    "kv16": (16, 16, jnp.bfloat16)}[pool_kind]
+    B, hd = 3, 128
+    nb = -(-48 // P)
+    table = full_table(B, nb)
+    stacks = tuple(noise(i, (L, B * nb, KV, P, hd), dtype) for i in (0, 1))
+    news = tuple(noise(i, (B, KV, 1, hd), dtype) for i in (2, 3))
+    at = jnp.int32(slot)
+    for pool, new in zip(stacks, news):
+        want = jax.jit(M._paged_row_scatter, static_argnums=5)(
+            pool, new, jnp.int32(LAYER), table, at, P)
+        got = jax.jit(M._identity_slot_write)(
+            pool, new, jnp.int32(LAYER), at)
+        np.testing.assert_array_equal(bits(got), bits(want))
+        assert not np.array_equal(bits(got[LAYER]), bits(pool[LAYER]))
+        np.testing.assert_array_equal(bits(got[0]), bits(pool[0]))
+
+    def view(cfg, identity):
+        key_mask = jnp.broadcast_to(jnp.arange(nb * P)[None, :] <= slot,
+                                    (B, nb * P))
+        return M._kind_views(
+            cfg, key_mask[:, None, None, :],
+            lambda: jnp.full((B, 1), slot), kv_caches=stacks, index=at,
+            decode=(jnp.zeros((B,), jnp.int32), jnp.full((B,), slot + 1)),
+            page_table=table, page_size=P, identity_table=identity)[0]
+
+    cfg = dataclasses.replace(ModelConfig.qwen2_tiny(),
+                              attention_impl="pallas")
+    taken = pool_kind != "pages_of_4"    # what the live-row kernel takes
+    assert (view(cfg, True).write_plan is M.IDENTITY_SLOT) == taken
+    assert (view(cfg, False).write_plan is not None) == taken
+    assert view(cfg, False).write_plan is not M.IDENTITY_SLOT
+    xla = dataclasses.replace(cfg, attention_impl="xla")
+    assert view(xla, True).write_plan is None
+    write = lambda view: lambda s, nw: M._cache_write(   # noqa: E731
+        s, nw, jnp.int32(LAYER), view)
+    jaxpr = str(jax.make_jaxpr(write(view(cfg, True)))(stacks, news))
+    assert "pallas_call" not in jaxpr and "scatter" not in jaxpr or not taken
+    for a, b in zip(jax.jit(write(view(cfg, True)))(stacks, news),
+                    jax.jit(write(view(xla, False)))(stacks, news)):
         np.testing.assert_array_equal(bits(a), bits(b))
 
 

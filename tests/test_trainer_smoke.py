@@ -78,6 +78,8 @@ def test_reinforce_smoke(tmp_path):
             for line in open(tmp_path / "reinforce" / "metrics.jsonl")]
     assert [r["rollout/attn_read_frac"] for r in rows if "episode" in r] == [
         1.0, 1.0]
+    # ... and on the CPU its cache is contiguous (ISSUE 52)
+    assert [r["rollout/kv_in_place"] for r in rows if "episode" in r] == [0, 0]
     assert (tmp_path / "reinforce" / "checkpoint-2").exists()
 
 

@@ -16,10 +16,11 @@ while the call is traced. `empty_us` is the same call with no live row (the
 wrapper's pad and slice, the launch, the zeroed outputs), so
 `us_item = (call_us - empty_us) / items`; `pct_bytes` is the K and V bytes of
 the slots inside the rows' bounds at the chip's bandwidth
-(benchmark/harness/peaks.json) over `call_us - empty_us`. The `rollout` case
-also times XLA's masked read of a contiguous cache cut to the same extent
-(what the one-jit rollout runs, ROADMAP S3). One JSON line a case on stdout,
-all of them in `chiprun_out/paged_read/`.
+(benchmark/harness/peaks.json) over `call_us - empty_us`. The `rollout` cases
+also time XLA's masked read of a contiguous cache cut to the extent that
+step reads there (`xla_extent_us`: what the one-jit rollout runs wherever
+its cache is not paged, `core/model.decode_read_extents`). One JSON line a
+case on stdout, all of them in `chiprun_out/paged_read/`.
 """
 import contextlib
 import json
@@ -46,6 +47,19 @@ def hbm_bytes_per_s():
     with open(os.path.join(ROOT, "benchmark", "harness", "peaks.json")) as f:
         return json.load(f)[jax.devices()[0].device_kind]["hbm_bytes_per_s"]
 
+# A step of the one-jit rollout over its pages (ISSUE 52): prompts of 64-256
+# tokens left-padded to 256, 512 new tokens, so 6 pages a row; at `filled`
+# slots written a contiguous cache is read up to `extent`. The start, the
+# middle and the end of a response: ~2.7, 4.7 and 5.7 pages a row.
+ROLLOUT_STEPS = {"start": (264, 512), "mean": (520, 640), "end": (760, 768)}
+
+
+def rollout_step(name):
+    """`(filled, extent)` of a `[olmoe.]rollout.<step>` case, else None."""
+    head, _, step = name.rpartition(".")
+    return ROLLOUT_STEPS.get(step) if head.endswith("rollout") else None
+
+
 # (name, layers, pages, kv heads, query rows a kv head, rows, table blocks,
 #  live rows, pages a live row)
 CASES = [
@@ -66,6 +80,10 @@ CASES = [
     ("olmoe", 16, 400, 16, 1, 64, 6, 32, 5),
     # the one-jit rollout's shape (ROADMAP S3): 64 rows, 416 of 608 slots
     ("rollout", 28, 400, 2, 6, 64, 5, 64, 5),
+    # the same loop step by step, and at OLMoE's geometry (a page an item)
+    *[("rollout." + at, 28, 384, 2, 6, 64, 6, 64, 6) for at in ROLLOUT_STEPS],
+    *[("olmoe.rollout." + at, 16, 384, 16, 1, 64, 6, 64, 6)
+      for at in ROLLOUT_STEPS],
 ]
 
 
@@ -82,6 +100,9 @@ def build(name, L, N, KV, G, B, nb, live, pages):
         if name == "rollout":   # a left-padded prompt, 416 slots filled
             start[r] = 192 - (29 * j) % 160
             filled[r] = start[r] + 416
+        elif rollout_step(name):    # its left pad, every row at one slot
+            start[r] = (29 * j) % 193
+            filled[r] = rollout_step(name)[0]
         else:
             filled[r] = pages * P - (37 * j) % P
     mask = np.zeros(B, bool)
@@ -163,9 +184,10 @@ def copies_stubbed():
 
 
 def xla_extent_read_us(c, extent=608, layers=4):
-    """XLA's masked read of a contiguous cache cut to the rollout's longest
-    extent (`core/model.decode_read_extents`: 608 of 768 slots), the plain
-    form the one-jit rollout runs, timed the same way."""
+    """XLA's masked read of a contiguous cache cut to `extent` slots
+    (`core/model.decode_read_extents`; 608 of 768 is the mean of the
+    rollout's three), the plain form of the one-jit rollout's read, timed
+    the same way."""
     B, KV = c["q"].shape[0], c["k_pool"].shape[2]
     stacks = [jax.random.normal(key, (layers, B, KV, extent, HD), jnp.bfloat16)
               for key in jax.random.split(jax.random.PRNGKey(1), 2)]
@@ -199,6 +221,9 @@ def case(spec):
         row["compute_only_us"] = time_us(read, c, plan)
     if name == "rollout":
         row["xla_extent_us"] = xla_extent_read_us(c)
+    elif rollout_step(name):
+        row["extent"] = rollout_step(name)[1]
+        row["xla_extent_us"] = xla_extent_read_us(c, row["extent"])
     return row
 
 
