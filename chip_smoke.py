@@ -5,6 +5,7 @@
     python chip_smoke.py --olmoe      # one chip:   the sparse-expert layer
     python chip_smoke.py --axk1       # one chip:   latent attention + a chip's share
     python chip_smoke.py --falcon-h1  # one chip:   a state-space mixer beside attention
+    python chip_smoke.py --sala       # one chip:   lightning and sparse-attention layers
 
 One process. It pins no platform: the first thing it does after `import jax`
 is read `jax.devices()`, print what it found, and exit non-zero unless that is
@@ -61,6 +62,17 @@ scan in chunks over the whole row), then a prefill in TWO pieces whose second
 takes both state leaves over and teacher-forced single-token steps through
 the contiguous cache (`phase_falcon_h1`). The paged session at these widths
 is the benchmark cell `serve-falcon-h1-assist` itself.
+
+`--sala` runs MiniCPM-SALA alone, as one pipeline stage holds it
+(`benchmark/configs/minicpm-sala-l8.json`: published widths, 8 layers, the
+whole vocabulary; 5.64 GB of bf16 weights under the file's `assumed.init`),
+against `benchmark/harness/reference_sala.py`: rows of which ONE is past
+`dense_len` (so that both branches of the sparse layer run), the scoring
+forward, then a prefill in two pieces whose second takes the lightning state
+and the compressed keys over and teacher-forced single-token steps, each a
+selection and a pass over the state, through the contiguous cache
+(`phase_sala`). The paged session at these widths is the benchmark cell
+`serve-sala-docchat` itself.
 
 Sizes live in `Sizes`; a rehearsal on the CPU imports this module and passes
 smaller ones (tests and scratch scripts steer, the program grows no option).
@@ -138,6 +150,12 @@ class Sizes:
     fh1_piece: int = 1024
     fh1_decode: int = 24           # single-token passes over the state
     fh1_last: int = 64             # logits compared on the last positions
+    sala_config: str = "benchmark/configs/minicpm-sala-l8.json"
+    sala_rows: int = 2
+    sala_prompt: int = 8300        # a row of it past dense_len (8,192), the
+                                   # other row left-padded to a quarter of it
+    sala_piece: int = 8192         # prefill: a piece of 8,192 and one of 108
+    sala_decode: int = 16          # single-token steps: a selection each
 
 
 def emit(phase: str, **fields) -> None:
@@ -1189,8 +1207,120 @@ def phase_falcon_h1(sz: Sizes, meter: Meter) -> None:
                  scoring=scoring, contiguous=contiguous)
 
 
+def phase_sala(sz: Sizes, meter: Meter) -> None:
+    """MiniCPM-SALA through the normal path against the plain float32
+    reference, under `bf16_agreement`'s rule on logits (`phase_falcon_h1`'s).
+    One row holds the whole prompt and selects (past `dense_len`), the other
+    is left-padded to a quarter of it and is read dense, in one batch. The
+    contiguous cache takes the prompt in two pieces (`prefill`, then a
+    `decode_verify` that takes the lightning state and the compressed keys
+    over, told the prompt's length) and then a selection, a read of the
+    chosen blocks and a pass over the state a step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from drivers import serve_sala_ref
+    from harness import reference_sala
+
+    from nanorlhf_tpu.core import (ModelConfig, decode_step, init_kv_cache,
+                                   init_params, padded_forward_logits, prefill)
+    from nanorlhf_tpu.core.model import decode_verify
+
+    phase = Phase("sala", meter)
+    with open(os.path.join(ROOT, sz.sala_config)) as f:
+        file = json.load(f)
+    mcfg = ModelConfig.from_hf_config(file)
+    plain_mcfg = dataclasses.replace(mcfg, attention_impl="xla")
+    check(mcfg.linear_layers + mcfg.sparse_layers == file["num_hidden_layers"]
+          and mcfg.sparse_dense_len == file["sparse_config"]["dense_len"],
+          "from_hf_config dropped a mixer or the sparse sizes")
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    key = jax.random.PRNGKey(sz.seed)
+    params = serve_sala_ref.spread(
+        jax.jit(lambda k: init_params(mcfg, k, dtype))(key),
+        file["assumed"].get("init"), sz.seed)
+    pad = 0
+    P, piece, n_new = sz.sala_prompt, sz.sala_piece, sz.sala_decode
+    T, rows = P + n_new, sz.sala_rows
+    ids = np.array(jax.random.randint(jax.random.fold_in(key, 2), (rows, T), 3,
+                                      mcfg.vocab_size))
+    ids[0, : P - P // 4] = pad      # a short row beside the one that selects
+    ids = jnp.asarray(ids, jnp.int32)
+    real = ids != pad
+    check(int(real[1, :P].sum()) >= mcfg.sparse_dense_len
+          > int(real[0].sum()), "one row past dense_len, one under it")
+
+    def within(tested, plain, ref, what):
+        return bf16_agreement(phase, tested, plain, ref,
+                              np.ones(np.shape(ref), bool), (what, "plain"))
+
+    # the positions that predict a decoded token: P - 1 .. T - 2
+    with jax.default_matmul_precision("highest"):
+        ref_last = np.asarray(jax.jit(lambda p, x, m: reference_sala.logits(
+            p, file, x, pad, last=n_new + 1, mask=m, decoded=n_new))(
+                params, ids, real))[:, :-1]
+    forward = lambda m: np.asarray(jax.jit(lambda p, x: padded_forward_logits(  # noqa: E731
+        p, m, x, pad, response_context_length=P))(params, ids), np.float32)
+    plain_last = forward(plain_mcfg)
+    scoring = within(forward(mcfg), plain_last, ref_last, "auto_scoring")
+
+    # ---- two pieces, then teacher-forced decode, the contiguous cache ----
+    # (a left-padded row's first piece starts at its first real token and
+    # is as wide as any: room for its pad tokens behind the row's last slot)
+    caches = init_kv_cache(mcfg, rows, -(-(T + piece) // 64) * 64, dtype)
+    T_max = caches[0][0].shape[3]
+    (state,) = caches[2]
+    phase.expect(state.dtype == jnp.float32 and state.shape[1:] == (
+        rows, mcfg.lightning_heads, mcfg.lightning_head_dim,
+        mcfg.lightning_head_dim), f"the state is {state.dtype}{state.shape}")
+    # (the first piece is a part of the whole prompt's call: told so)
+    km0 = jnp.zeros((rows, T_max), bool)
+    n_real = real[:, :P].sum(axis=1)
+    pieces = jax.jit(lambda p, x, pos, at, km, valid, c: decode_verify(
+        p, mcfg, x, pos, at, km, c, token_valid=valid, call_keys=n_real))
+    position = jnp.cumsum(real, axis=1) - 1
+    first = jnp.argmax(real, axis=1).astype(jnp.int32)
+    lg = None
+    key_mask = km0
+    for lo, hi in ((0, piece), (piece, P)):
+        # a row's tokens before its first real one are not valid, and a row
+        # that has none yet starts later (its `fill` is its first real slot)
+        at = jnp.clip(first, lo, hi).astype(jnp.int32)
+        width = hi - lo
+        gather = at[:, None] + jnp.arange(width)[None]
+        toks = jnp.take_along_axis(ids, jnp.minimum(gather, T - 1), axis=1)
+        valid = (gather < hi) & jnp.take_along_axis(
+            real, jnp.minimum(gather, T - 1), axis=1)
+        pos = jnp.take_along_axis(position, jnp.minimum(gather, T - 1), axis=1)
+        lg, caches = pieces(params, toks, pos, at, key_mask, valid, caches)
+        key_mask = key_mask.at[:, lo:hi].set(real[:, lo:hi])
+    last_of = jnp.clip(P - 1 - jnp.clip(first, piece, P), 0, P - piece - 1)
+    got = [np.asarray(jnp.take_along_axis(
+        lg, last_of[:, None, None], axis=1)[:, 0], np.float32)]
+    step = jax.jit(lambda p, tok, pos, t, km, c: decode_step(
+        p, mcfg, tok, pos, t, km, c))
+    for t in range(P, T - 1):
+        key_mask = key_mask.at[:, t].set(True)
+        lg, caches = step(params, ids[:, t], n_real + (t - P), jnp.int32(t),
+                          key_mask, caches)
+        got.append(np.asarray(lg, np.float32))
+    del caches
+    contiguous = within(np.stack(got, axis=1), plain_last, ref_last,
+                        "contiguous_cache")
+    phase.finish(config=sz.sala_config, layers=mcfg.num_hidden_layers,
+                 hidden=mcfg.hidden_size, lightning=mcfg.linear_layers,
+                 sparse=mcfg.sparse_layers, vocab=mcfg.vocab_size,
+                 dtype=str(jnp.dtype(dtype)), rows=rows, tokens=T,
+                 real_tokens=np.asarray(n_real).tolist(),
+                 pieces=(piece, P - piece), decode_steps=n_new,
+                 scoring=scoring, contiguous=contiguous)
+
+
 def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False,
-               axk1: bool = False, falcon_h1: bool = False) -> None:
+               axk1: bool = False, falcon_h1: bool = False,
+               sala: bool = False) -> None:
     """Everything after the device gate. Raises at the first failed phase."""
     from nanorlhf_tpu import native
     from nanorlhf_tpu.core import ModelConfig
@@ -1214,6 +1344,9 @@ def run_phases(sz: Sizes, multichip: bool, olmoe: bool = False,
     if falcon_h1:
         phase_falcon_h1(sz, meter)
         return
+    if sala:
+        phase_sala(sz, meter)
+        return
     tiny = "tiny" in sz.model.lower()  # entrypoints.common.resolve_model's rule
     phase_kernels(sz, ModelConfig.qwen2_tiny(vocab_size=4096) if tiny
                   else ModelConfig.qwen2_1_5b(), meter)
@@ -1231,6 +1364,8 @@ def main(argv=None) -> int:
                         help="one chip: A.X-K1's share against its float32 reference, only")
     parser.add_argument("--falcon-h1", action="store_true",
                         help="one chip: Falcon-H1's stage against its float32 reference, only")
+    parser.add_argument("--sala", action="store_true",
+                        help="one chip: MiniCPM-SALA's stage against its float32 reference, only")
     args = parser.parse_args(argv)
 
     import jax
@@ -1248,7 +1383,8 @@ def main(argv=None) -> int:
               f"{device['count']}", file=sys.stderr)
         return 1
 
-    run_phases(Sizes(), args.multichip, args.olmoe, args.axk1, args.falcon_h1)
+    run_phases(Sizes(), args.multichip, args.olmoe, args.axk1, args.falcon_h1,
+               args.sala)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

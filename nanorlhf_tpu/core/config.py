@@ -36,6 +36,15 @@ SDAR_BLOCK_LENGTH = 4
 SDAR_MASK_TOKEN_ID = 151669
 
 
+# the layer kinds `layer_types` names (every other entry is an attention
+# layer, whose kind is its `(window, rotary)`)
+NAMED_KINDS = ("conv", "hybrid", "lightning", "sparse")
+# MiniCPM-SALA's `mixer_types` under the tree's names
+_SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+_SALA_PUBLISHED = (
+    "s" + "l" * 8 + "s" + "l" * 6 + "ss" + "l" * 4 + "s" + "l" * 6 + "sss")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int = 151936
@@ -166,6 +175,36 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)
     lm_head_multiplier: float = 1.0
+    # MiniCPM-SALA's two mixers (docs/SALA.md). `layer_types[l] ==
+    # "lightning"` is a LINEAR-attention layer (Lightning Attention-2):
+    # `lightning_heads` heads of `lightning_head_dim`, per-head q/k norms and
+    # rotary, a recurrent MATRIX state `S = lam S + k (outer) v` a head
+    # (float32, `[head_dim, head_dim]`) and no pages, a per-head output norm
+    # and a sigmoid gate; a piece's recurrence goes in chunks of `ssm_chunk`.
+    # `layer_types[l] == "sparse"` is an InfLLM-V2 layer: a GQA attention of
+    # `num_attention_heads / num_key_value_heads` without rotary whose
+    # queries, once the call they belong to holds `sparse_dense_len` keys,
+    # read `sparse_topk` blocks of `sparse_block_size` tokens only: the
+    # first `sparse_init_blocks`, the `sparse_window_size / sparse_block_size`
+    # that end at the query's own, and the best of the rest by the scores
+    # of COMPRESSED keys (means over `sparse_kernel_size` keys every
+    # `sparse_kernel_stride`, no weights), which its pages keep beside K and
+    # V. The residual branches are scaled by `scale_depth / sqrt(depth)`,
+    # `depth` the PUBLISHED one (`published_layers`; a cut's first layer is
+    # the published layer `first_published_layer`, which the lightning
+    # decays go by); 0.0 stages nothing.
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
+    scale_depth: float = 0.0
+    published_layers: int = 0
+    first_published_layer: int = 0
     # RMSNorm of q and k over each HEAD (a weight of `head_dim`), after the
     # head split and before RoPE (LFM2's q_layernorm / k_layernorm).
     qk_norm_per_head: bool = False
@@ -279,8 +318,9 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> tuple:
-        """Every layer's kind, in model order: `"conv"` or `"hybrid"`
-        (`layer_types`), or an attention layer's `(window, rotary)`."""
+        """Every layer's kind, in model order: `"conv"`, `"hybrid"`,
+        `"lightning"` or `"sparse"` (`layer_types`), or an attention layer's
+        `(window, rotary)`."""
         L = self.num_hidden_layers
         win = tuple(bool(w) for w in self.sliding_window_layout) or (False,) * L
         rot = tuple(bool(r) for r in self.rope_layout) or (True,) * L
@@ -292,7 +332,7 @@ class ModelConfig:
                 f"layer layouts of {len(kinds)} and {len(self.layer_types)} "
                 f"entries for {L} layers")
         if self.layer_types:
-            kinds = tuple(t if t in ("conv", "hybrid") else k
+            kinds = tuple(t if t in NAMED_KINDS else k
                           for t, k in zip(self.layer_types, kinds))
         return kinds
 
@@ -333,13 +373,57 @@ class ModelConfig:
     def state_layers(self) -> int:
         """Layers that keep a state a row, of either form (docs/STATE.md):
         what a session sizes, donates, resets and reports a state by."""
-        return self.conv_layers + self.ssm_layers
+        return self.conv_layers + self.ssm_layers + self.linear_layers
+
+    @property
+    def linear_layers(self) -> int:
+        """Linear-attention layers, which keep a matrix state and no pages
+        (0 in a model without; docs/SALA.md)."""
+        return sum(k == "lightning" for k in self.layer_kinds)
+
+    @property
+    def sparse_layers(self) -> int:
+        """Attention layers whose queries choose the blocks they read, and
+        whose pages keep compressed keys beside K and V (docs/SALA.md)."""
+        return sum(k == "sparse" for k in self.layer_kinds)
 
     @property
     def state_what(self) -> str:
         """The model as a refusal names it, by what keeps the state."""
-        layers = "state-space layers" if self.ssm_layers else "conv layers"
+        layers = ("state-space layers" if self.ssm_layers else
+                  "linear-attention layers" if self.linear_layers else
+                  "conv layers")
         return f"a model with {layers} ({self.model_type})"
+
+    @property
+    def residual_scale(self) -> float:
+        """What each branch is multiplied by as it joins the stream
+        (MiniCPM's `scale_depth / sqrt(num_hidden_layers)`, of the PUBLISHED
+        depth); 1.0, which stages nothing, for every other model."""
+        if not self.scale_depth:
+            return 1.0
+        depth = self.published_layers or self.num_hidden_layers
+        return self.scale_depth / depth ** 0.5
+
+    def lightning_log_decays(self, every_layer: bool = False):
+        """`[linear layers, heads]` float32 numpy: `log lam` of every
+        lightning layer in model order (docs/SALA.md): `lam[h] = exp(-s[h]
+        f(l))`, `s[h] = 2^(-8 (h + 1) / H)`, `f(l) = 1 - l / (L - 1) + 1e-5`
+        with `l` the PUBLISHED index of the layer and `L` the published
+        depth (Lightning Attention-2's slopes, MiniMax-01's layer factor).
+        Fixed, not learned: no leaf of the tree. `every_layer`: a row a
+        layer of the model, whatever its kind (a layer looks its own up by
+        its place)."""
+        import numpy as np
+
+        H = self.lightning_heads
+        depth = self.published_layers or self.num_hidden_layers
+        slopes = 2.0 ** (-8.0 * (np.arange(H) + 1) / H)
+        at = [self.first_published_layer + i
+              for i, k in enumerate(self.layer_kinds)
+              if every_layer or k == "lightning"]
+        factor = np.asarray([1.0 - l / max(depth - 1, 1) + 1e-5 for l in at])
+        return (-slopes[None, :] * factor[:, None]).astype(np.float32)
 
     @property
     def ssm_inner(self) -> int:
@@ -354,8 +438,7 @@ class ModelConfig:
     @property
     def window_layers(self) -> int:
         """Layers with a sliding window (0 without a pattern)."""
-        return sum(k not in ("conv", "hybrid") and k[0]
-                   for k in self.layer_kinds)
+        return sum(k not in NAMED_KINDS and k[0] for k in self.layer_kinds)
 
     @property
     def block_generation(self) -> bool:
@@ -652,6 +735,51 @@ class ModelConfig:
             lm_head_multiplier=0.3)
 
     @classmethod
+    def minicpm_sala(cls) -> "ModelConfig":
+        """openbmb/MiniCPM-SALA (docs/SALA.md): 32 layers, 8 sparse
+        (InfLLM-V2: 32 / 2 heads of 128, no rotary, top 64 blocks of 64) and
+        24 lightning (32 heads of 128, a matrix state), a SwiGLU of 16,384,
+        muP scales (`scale_emb` 12, `scale_depth` 1.4, logits / 16);
+        vocabulary 73,448, untied."""
+        kinds = tuple("sparse" if c == "s" else "lightning"
+                      for c in _SALA_PUBLISHED)
+        return cls(
+            vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+            num_hidden_layers=32, num_attention_heads=32,
+            num_key_value_heads=2, head_dim=128, rope_theta=1e4,
+            rms_norm_eps=1e-6, tie_word_embeddings=False,
+            max_position_embeddings=524288, attention_bias=False,
+            model_type="minicpm_sala", layer_types=kinds,
+            rope_layout=tuple(int(k == "lightning") for k in kinds),
+            qk_norm_per_head=True, attention_gate=True,
+            lightning_heads=32, lightning_head_dim=128, ssm_chunk=128,
+            embed_scale=12.0, scale_depth=1.4, published_layers=32,
+            lm_head_multiplier=256 / 4096)
+
+    @classmethod
+    def minicpm_sala_tiny(cls, vocab_size: int = 512,
+                          layers: int = 4) -> "ModelConfig":
+        """Test-size MiniCPM-SALA: `[sparse, lightning, lightning, sparse]`
+        repeated, 4 / 2 heads of 16, lightning 4 heads of 16 in chunks of 4,
+        compressed keys over 8 tokens every 4, blocks of 16, the top 4 of
+        them (the first, the 2 of a window of 32 and one free), dense under
+        96 keys; every scale at a value other than 1."""
+        kinds = (("sparse", "lightning", "lightning", "sparse")
+                 * layers)[:layers]
+        return dataclasses.replace(
+            cls.minicpm_sala(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            max_position_embeddings=1024, layer_types=kinds,
+            rope_layout=tuple(int(k == "lightning") for k in kinds),
+            lightning_heads=4, lightning_head_dim=16, ssm_chunk=4,
+            sparse_kernel_size=8, sparse_kernel_stride=4,
+            sparse_block_size=16, sparse_topk=4, sparse_init_blocks=1,
+            sparse_window_size=32, sparse_dense_len=96,
+            embed_scale=1.7, scale_depth=1.1, published_layers=2 * layers,
+            first_published_layer=1, lm_head_multiplier=0.5)
+
+    @classmethod
     def trinity_large(cls) -> "ModelConfig":
         """arcee-ai/Trinity-Large-Preview (`afmoe`): 60 GQA layers of 48 / 8
         heads of 128 in periods of [window 4,096 with rotary x 3, global
@@ -820,6 +948,8 @@ class ModelConfig:
             return cls._afmoe_from_hf(get)
         if model_type == "falcon_h1":
             return cls._falcon_h1_from_hf(get)
+        if model_type == "minicpm_sala":
+            return cls._sala_from_hf(get)
         if model_type == "sdar_moe":
             return cls._sdar_from_hf(get)
         # a window on a family this decoder builds with full attention only:
@@ -1152,6 +1282,78 @@ class ModelConfig:
             mlp_multipliers=mults["mlp_multipliers"],
             lm_head_multiplier=float(get("lm_head_multiplier", 1.0)),
         )
+
+    @classmethod
+    def _sala_from_hf(cls, get) -> "ModelConfig":
+        """MiniCPM-SALA's published keys (docs/SALA.md) plus the sizes the
+        published config does not carry (`sparse_config`, MiniCPM4.1's; a
+        cut's `published_layers` / `first_published_layer`). What the
+        layers of docs/SALA.md do not compute raises, by the key's name."""
+        def refuse(key, why):
+            raise ValueError(f"minicpm_sala: {key}={get(key)!r} is not "
+                             f"implemented ({why}; docs/SALA.md)")
+
+        if get("attn_use_rope"):
+            refuse("attn_use_rope", "the sparse layers carry no rotary")
+        if get("lightning_use_rope") is False:
+            refuse("lightning_use_rope", "the lightning layers rotate q and k")
+        if get("qk_norm") is False:
+            refuse("qk_norm", "q and k are normed a head in both mixers")
+        for key in ("use_output_gate", "attn_use_output_gate"):
+            if get(key) is False:
+                refuse(key, "both mixers' outputs go through a sigmoid gate")
+        if get("use_output_norm") is False:
+            refuse("use_output_norm", "the lightning output is normed a head")
+        if get("attention_bias"):
+            refuse("attention_bias", "the projections carry no bias")
+        heads = int(get("lightning_nh"))
+        if int(get("lightning_nkv") or heads) != heads:
+            refuse("lightning_nkv", f"lightning_nh = {heads}: a state a head")
+        if str(get("lightning_scale", "1/sqrt(d)")) != "1/sqrt(d)":
+            refuse("lightning_scale", "the output is divided by sqrt(head)")
+        if get("rope_scaling"):
+            refuse("rope_scaling", "plain rotary embedding only")
+        if str(get("hidden_act", "silu")) != "silu":
+            refuse("hidden_act", "SiLU in the MLP")
+        mixers = tuple(get("mixer_types") or ())
+        L = int(get("num_hidden_layers"))
+        if len(mixers) != L or any(m not in _SALA_MIXERS for m in mixers):
+            refuse("mixer_types", f"{L} entries, each one of "
+                   f"{sorted(_SALA_MIXERS)}")
+        kinds = tuple(_SALA_MIXERS[m] for m in mixers)
+        sparse = dict(get("sparse_config") or {})
+        sizes = {f"sparse_{key}": int(sparse[key]) for key in (
+            "kernel_size", "kernel_stride", "block_size", "topk",
+            "init_blocks", "window_size", "dense_len") if key in sparse}
+        made = dataclasses.replace(cls.minicpm_sala(), **sizes)
+        block, stride = made.sparse_block_size, made.sparse_kernel_stride
+        if (block % stride or made.sparse_kernel_size != 2 * stride
+                or made.sparse_window_size % block):
+            refuse("sparse_config", "a compressed key covers two strides, "
+                   "a block whole strides and the window whole blocks")
+        hidden = int(get("hidden_size"))
+        return dataclasses.replace(
+            made,
+            vocab_size=get("vocab_size"), hidden_size=hidden,
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=L,
+            num_attention_heads=int(get("num_attention_heads")),
+            num_key_value_heads=int(get("num_key_value_heads")),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1e4)),
+            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 524288),
+            layer_types=kinds,
+            rope_layout=tuple(int(k == "lightning") for k in kinds),
+            lightning_heads=heads,
+            lightning_head_dim=int(get("lightning_head_dim")),
+            ssm_chunk=int(get("lightning_chunk") or 128),
+            embed_scale=float(get("scale_emb", 1.0)),
+            scale_depth=float(get("scale_depth", 0.0)),
+            published_layers=int(get("published_layers") or L),
+            first_published_layer=int(get("first_published_layer") or 0),
+            lm_head_multiplier=float(get("dim_model_base", hidden)) / hidden)
 
     @staticmethod
     def _chip_share(get, who: str, experts: int, key: str) -> tuple:

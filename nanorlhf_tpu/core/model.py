@@ -192,7 +192,8 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
     def stack(start, n, width, experts):
         kinds = config.layer_kinds[start:start + n]
         nc = sum(k == "conv" for k in kinds)
-        na = n - nc
+        nl = sum(k == "lightning" for k in kinds)
+        na = n - nc - nl
         ns = sum(k == "hybrid" for k in kinds)  # (an attention layer too)
         fan = lambda *shape: normal(shape, 1.0 / jnp.sqrt(shape[-2]))  # noqa: E731
         swiglu = lambda lead, width: {  # noqa: E731
@@ -221,6 +222,10 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
                 "out_proj": {"kernel": fan(nc, D, D)}}
         if ns:
             tree["ssm"] = _init_ssm(config, ns, fan, normal, next(keys), dtype)
+        if nl:
+            from nanorlhf_tpu.core import sala
+
+            tree["lightning"] = sala.init_lightning(config, nl, fan, dtype)
         if na:
             tree.update({
                 "q_proj": {"kernel": fan(na, D, H * hd)},
@@ -1046,7 +1051,13 @@ class KindView(NamedTuple):
     write_plan: object = None   # a decode step's `PagedWritePlan`s on a TPU,
                                 # or `IDENTITY_SLOT`
     conv_ctx: tuple | None = None   # the state group's `(valid, fresh)`
+                                # (a sparse layer's group: `valid` beside
+                                # its span)
     live: object = None         # [B] bool: the rows someone listens to
+    span: tuple | None = None   # a sparse layer's `(start, keys)` [B] each:
+                                # the slot of the row's position 0 and the
+                                # keys of the call its tokens belong to
+                                # (core/sala.py)
 
 
 class LayerLeaves(NamedTuple):
@@ -1103,6 +1114,12 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
         elif kind == "conv":
             x, new_cache = _conv_operator(
                 config, x, h, layer_params["conv"], cache, layer, view)
+        elif kind == "lightning":
+            from nanorlhf_tpu.core import sala
+
+            x, new_cache = sala.lightning_operator(
+                config, x, h, layer_params["lightning"], cache, layer, view,
+                cos, sin, leaves.at)
         elif config.kv_lora_rank:
             if attn_fn is not None:
                 raise NotImplementedError(
@@ -1130,7 +1147,7 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
             with jax.named_scope("norm"):
                 ff = rms_norm(ff, layer_params["mlp_branch_norm"],
                               config.rms_norm_eps)
-        x = x + ff
+        x = x + _times(ff, config.residual_scale)
     return x, new_cache, aux
 
 
@@ -1162,7 +1179,10 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
     H, KV = config.num_attention_heads, config.num_key_value_heads
     B, T, D = x.shape
     layer_params, lora_layer = leaves.tree, leaves.lora
-    window, rotary = kind
+    # a sparse layer (docs/SALA.md): global, without rotary, and its cache
+    # group holds its compressed keys as a third leaf
+    sparse = kind == "sparse"
+    window, rotary = (False, False) if sparse else kind
     spmd = _kernel_spmd(config, H, KV)
     with jax.named_scope("attn.qkv"):
         h = _times(h, config.attention_in_multiplier)
@@ -1201,16 +1221,30 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
             "attention passes whole K and V blocks round (docs/SWA.md)")
     window = config.sliding_window if window else 0
     with contextlib.ExitStack() as scopes:
-        if patterned:
+        # (a sparse layer names its own steps, as a model of one kind does:
+        # `attn.write`, `attn.compress`, `attn.select`, `attn.read`)
+        if patterned and not sparse:
             scopes.enter_context(jax.named_scope(
                 "attn.window" if window else "attn.global"))
-        new_cache = None
+        new_cache = compressed = None
+        if sparse and cache is not None:
+            *cache, compressed = cache
         if cache is not None and attn_fn is None:
             news = (k, v)
             if view.cache == "int8":    # see init_kv_cache
                 with jax.named_scope("attn.write"):
                     news = _quantize_kv(k) + _quantize_kv(v)
-            new_cache = _cache_write(cache, news, layer, view)
+            new_cache = _cache_write(tuple(cache), news, layer, view)
+        if sparse and attn_fn is not None:
+            raise NotImplementedError(
+                "sparse-attention layers have no sequence-parallel form: a "
+                "query's chosen blocks lie on any device (docs/SALA.md)")
+        if compressed is not None:
+            from nanorlhf_tpu.core import sala
+
+            with jax.named_scope("attn.compress"):
+                compressed = sala.compress_write(
+                    config, compressed, new_cache[0], layer, view, T)
         if not patterned:
             scopes.enter_context(jax.named_scope("attn.read"))
         if attn_fn is not None:
@@ -1218,6 +1252,18 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
         elif view.cache == "int8":
             out = _int8_attention_read(config, q, k, v, view, new_cache,
                                        layer, spmd)
+        elif sparse:
+            from nanorlhf_tpu.core import sala
+
+            @jax.named_scope("attn.read")
+            def dense_read():   # a call none of whose rows selects
+                return _attention_read(config, q, k, v, view, new_cache,
+                                       layer, window, spmd)
+
+            out = sala.sparse_read(config, q, k, v, view, new_cache,
+                                   compressed, layer, dense_read)
+            if new_cache is not None:
+                new_cache = new_cache + (compressed,)
         else:
             out = _attention_read(config, q, k, v, view, new_cache, layer,
                                   window, spmd)
@@ -1240,7 +1286,7 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
             with jax.named_scope("norm"):
                 out = rms_norm(out, layer_params["attn_branch_norm"],
                                config.rms_norm_eps)
-        return x + out, new_cache
+        return x + _times(out, config.residual_scale), new_cache
 
 
 def _pack_heads(q, k, v, pack: int):
@@ -1858,9 +1904,9 @@ def _kind_group(kind) -> int:
     conv layers' state. A hybrid layer's attention keeps pages of group 0;
     its mixer's state lies in group 2 at the same index (every layer of such
     a model is hybrid: core/config.py)."""
-    if kind == "hybrid":
+    if kind in ("hybrid", "sparse"):
         return 0
-    return 2 if kind == "conv" else int(kind[0])
+    return 2 if kind in ("conv", "lightning") else int(kind[0])
 
 
 def leaves_in_place(config: ModelConfig, cached: bool,
@@ -1948,7 +1994,9 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
     if cached:
         caches = (tuple(kv_caches),) if plain else tuple(kv_caches)
     in_place = leaves_in_place(config, cached, layer_transform)
-    split = config.conv_layers > 0
+    # (the leaves of the kind that keeps a state and no pages)
+    own = "lightning" if config.linear_layers else "conv"
+    split = config.conv_layers + config.linear_layers > 0
     before = [0, 0, 0]      # layers of each group in the stacks so far
     aux = None
     for tree, lora, start, count in _layer_stacks(params):
@@ -1976,7 +2024,7 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
         per = (p, p, per_period[0] + per_period[1], per_period[2])
         if split:
             shared = dict(layer_xs)
-            trees[3] = {"conv": shared.pop("conv", None)}
+            trees[3] = {own: shared.pop(own, None)}
             trees[2] = {name: shared.pop(name) for name in _ATTENTION_LEAVES
                         if name in shared}
             trees[0] = shared
@@ -2066,7 +2114,7 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
 def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
                 decode=None, verify=None, page_table=None, page_size=0,
                 live=None, conv_ctx=None, write_at=None,
-                identity_table=False) -> tuple:
+                identity_table=False, span=None) -> tuple:
     """The call's `KindView` a cache group, from what its entrypoint knows:
     one for a model without a pattern, else `(global, window[, state])`.
 
@@ -2096,7 +2144,9 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
     `conv_ctx`: a thunk of `_conv_ctx(...)`, called last (the operations
     stand in the program in the order the entrypoints always staged them:
     the decode bounds, the masks, the verify bounds, the plans, the state's
-    context)."""
+    context). `span`: a thunk of a sparse layer's `(start, keys)`
+    (`KindView.span`), called for a model with such layers only, whose decode
+    step's read makes its own work list a layer (core/sala.py)."""
     plain = config.attention_pattern is None
     kinds = 1 if plain else 2
     window = 0 if plain else config.sliding_window
@@ -2143,11 +2193,12 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
         )
         from nanorlhf_tpu.ops.paged_cache_write import paged_write_plan
 
-        decodes = [paged_decode_plan(
-            table, first, decode[1], page_size=page_size,
-            num_pages=group[0].shape[1],
-            pages_per_item=paged_pages_per_item(group[0]), live=live)
-            for table, group, (first, _) in zip(tables, groups, decodes)]
+        if not config.sparse_layers:
+            decodes = [paged_decode_plan(
+                table, first, decode[1], page_size=page_size,
+                num_pages=group[0].shape[1],
+                pages_per_item=paged_pages_per_item(group[0]), live=live)
+                for table, group, (first, _) in zip(tables, groups, decodes)]
         plans = [None if not _paged_row_kernel_takes(group, page_size)
                  else IDENTITY_SLOT if identity_table
                  else tuple(paged_write_plan(
@@ -2169,6 +2220,8 @@ def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
                  table=table, page_size=page_size, write_plan=plan, live=live)
         for m, group, d, v, table, plan
         in zip(masks, groups, decodes, verifies, tables, plans))
+    if config.sparse_layers:
+        views = (views[0]._replace(span=span(), conv_ctx=ctx),) + views[1:]
     if config.state_layers:
         views += (KindView(
             mask=None, cache=None if kv_caches is None else "state",
@@ -2239,7 +2292,7 @@ def model_forward(
 
 def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
                         lora_scale, remat, attn_fn=None, layer_transform=None,
-                        router_stats=False):
+                        router_stats=False, context_length=None):
     """embed → rope → causal+padding mask → scanned layers. The one copy of
     this recipe; every forward entrypoint goes through it.
 
@@ -2251,6 +2304,11 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     sums of ops/moe.py's `router_stats` over the real tokens, from this very
     forward (the scan emits each layer's router record; a few reductions,
     no second forward).
+
+    `context_length`: the first so many slots are a prompt that was taken in
+    as ONE call and every later token a decode step of its own. Only a
+    sparse layer reads it (core/sala.py: a call's keys decide whether its
+    queries select); None: the row is one call.
     """
     attention_mask = attention_mask.astype(bool)
     x = _embed(config, params, input_ids)
@@ -2263,7 +2321,9 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
         causal = jnp.tril(jnp.ones((T, T), bool))
         mask = causal[None, None, :, :] & attention_mask[:, None, None, :]
     views = _kind_views(config, mask, lambda: jnp.arange(T)[None, :],
-                        conv_ctx=lambda: _conv_ctx(config, attention_mask))
+                        conv_ctx=lambda: _conv_ctx(config, attention_mask),
+                        span=lambda: _mask_span(attention_mask,
+                                                context_length))
     x, _, aux = _run_layers(config, params, x, cos, sin, views,
                             lora_scale=lora_scale, remat=remat, attn_fn=attn_fn,
                             layer_transform=layer_transform)
@@ -2272,6 +2332,22 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
 
         return x, reduce_stats(aux, attention_mask, config.num_experts)
     return x
+
+
+def _mask_span(attention_mask, context_length=None) -> tuple:
+    """`KindView.span` of rows whose real tokens are `attention_mask`'s: the
+    first one's slot, and how many there are; with `context_length`, the
+    keys of each QUERY's call [B, T]: the prompt's real tokens for a query
+    among the first `context_length` slots, what the row holds up to itself
+    for a later one (a decode step's)."""
+    start = jnp.argmax(attention_mask, axis=1).astype(jnp.int32)
+    if context_length is None:
+        return start, jnp.sum(attention_mask, axis=1, dtype=jnp.int32)
+    slot = jnp.arange(attention_mask.shape[1], dtype=jnp.int32)[None, :]
+    prompt = jnp.sum(attention_mask[:, :context_length], axis=1,
+                     dtype=jnp.int32)[:, None]
+    return start, jnp.where(slot < context_length, prompt,
+                            slot - start[:, None] + 1)
 
 
 def _block_causal(config: ModelConfig, positions, key_positions=None):
@@ -2306,6 +2382,7 @@ def _padded_hidden(
     lora_scale: float = 1.0,
     remat: bool = False,
     router_stats: bool = False,
+    context_length: int | None = None,
 ) -> jnp.ndarray:
     """Shared padding recipe → pre-final-norm hidden states [B, T, D].
 
@@ -2317,9 +2394,11 @@ def _padded_hidden(
     input_ids, attention_mask, position_ids = padding_inputs(
         query_responses, pad_token_id
     )
-    return _hidden_from_inputs(params, config, input_ids, attention_mask,
-                               position_ids, lora_scale, remat,
-                               router_stats=router_stats)
+    return _hidden_from_inputs(
+        params, config, input_ids, attention_mask, position_ids, lora_scale,
+        remat, router_stats=router_stats,
+        # (the prompt was one call, each response token a step of its own)
+        context_length=context_length)
 
 
 def padding_inputs(query_responses: jnp.ndarray, pad_token_id: int):
@@ -2354,7 +2433,8 @@ def padded_forward_logits(
     `padded_forward_hidden`: `(logits, stats)`.
     """
     x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale,
-                       remat, router_stats=router_stats)
+                       remat, router_stats=router_stats,
+                       context_length=response_context_length)
     stats = None
     if router_stats:
         x, stats = x
@@ -2455,7 +2535,7 @@ def _pattern_caches(config: ModelConfig) -> tuple:
             "reads take one table of one kind of cache and have no lower "
             "bound (docs/SWA.md, docs/STATE.md)")
     return (config.num_hidden_layers - config.window_layers
-            - config.conv_layers, config.window_layers)
+            - config.conv_layers - config.linear_layers, config.window_layers)
 
 
 def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
@@ -2468,6 +2548,10 @@ def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
     cache's type (`_ssm_operator`; docs/SSM.md). Not a page: its size does
     not grow with the row, no table addresses it, and a row's is at the
     row's own index."""
+    if config.linear_layers:    # (docs/SALA.md: a matrix a head, no tail)
+        H, hd = config.lightning_heads, config.lightning_head_dim
+        return ((jnp.zeros((config.linear_layers, rows, H, hd, hd),
+                           jnp.float32),),)
     if config.ssm_layers:
         L = config.ssm_layers
         return ((jnp.zeros((L, config.ssm_conv - 1, rows,
@@ -2478,6 +2562,26 @@ def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
         return ()
     return ((jnp.zeros((config.conv_layers, config.conv_L_cache - 1, rows,
                         config.hidden_size), dtype),),)
+
+
+def _with_compressed(config: ModelConfig, groups: tuple, slots: int,
+                     dtype, paged: bool = False) -> tuple:
+    """A pattern model's page groups, the global one with a third leaf where
+    its layers are sparse (docs/SALA.md): the compressed keys, `slots //
+    sparse_kernel_stride` a row (contiguous: laid out as K is) or a page
+    (`paged`: a page's heads and entries on one axis;
+    `sala.compress_write`)."""
+    if not config.sparse_layers:
+        return groups
+    stride = config.sparse_kernel_stride
+    if slots % stride:
+        raise ValueError(
+            f"a cache of {slots} slots a row or page does not hold whole "
+            f"strides of {stride} compressed keys (sparse_kernel_stride)")
+    L, lead, KV, _, hd = groups[0][0].shape
+    shape = ((L, lead, KV * (slots // stride), hd) if paged
+             else (L, lead, KV, slots // stride, hd))
+    return ((*groups[0], jnp.zeros(shape, dtype)),) + groups[1:]
 
 
 def _cache_heads(config: ModelConfig) -> tuple:
@@ -2508,10 +2612,11 @@ def init_kv_cache(
         # both groups whole: correct by mask, no slot saved (the paged pool
         # is where a window layer keeps a window's pages only)
         KV, hd = _cache_heads(config)
-        return tuple(
+        groups = tuple(
             tuple(jnp.zeros((n, batch, KV, max_len, hd), dtype) for _ in "kv")
-            for n in _pattern_caches(config)) + _state_group(config, batch,
-                                                             dtype)
+            for n in _pattern_caches(config))
+        return _with_compressed(config, groups, max_len, dtype) + \
+            _state_group(config, batch, dtype)
     shape = (
         config.num_hidden_layers,
         batch,
@@ -2574,11 +2679,12 @@ def init_paged_kv_cache(
                 "pages: init_paged_kv_cache(..., state_rows=rows) "
                 "(docs/STATE.md)")
         KV, hd = _cache_heads(config)
-        return tuple(
+        groups = tuple(
             tuple(jnp.zeros((n, pages, KV, page_size, hd), dtype)
                   for _ in "kv")
-            for n, pages in zip(_pattern_caches(config), num_pages)
-        ) + _state_group(config, state_rows, dtype)
+            for n, pages in zip(_pattern_caches(config), num_pages))
+        return _with_compressed(config, groups, page_size, dtype, True) + \
+            _state_group(config, state_rows, dtype)
     shape = (
         config.num_hidden_layers,
         num_pages,
@@ -2665,7 +2771,8 @@ def prefill(
         page_table=page_table, page_size=page_size,
         # (a prompt starts its rows: whatever state they held is not theirs)
         conv_ctx=lambda: _conv_ctx(config, attention_mask,
-                                   lambda: jnp.ones((B,), bool)))
+                                   lambda: jnp.ones((B,), bool)),
+        span=lambda: _mask_span(attention_mask))
     x, new_caches, _ = _run_layers(config, params, x, cos, sin, views,
                                    kv_caches, lora_scale)
     logits = _logits(config, params, x[:, -1:, :])[:, 0, :]
@@ -2731,7 +2838,9 @@ def decode_step(
         # (a row nobody listens to leaves its state as it was: it may be a
         # chunked admission between two of its pieces)
         conv_ctx=lambda: _conv_ctx(
-            config, lambda: None if live is None else live[:, None]))
+            config, lambda: None if live is None else live[:, None]),
+        # (a decode step's call is the row as it stands)
+        span=lambda: (start, filled - start))
     x, new_caches, aux = _run_layers(
         config, params, x, cos, sin, views, kv_caches, lora_scale,
         # (the experts its rows reach, where the caller asks)
@@ -2759,6 +2868,11 @@ def decode_verify(
                                   # all). Only a model with conv layers
                                   # reads it: its state stops at a row's
                                   # last real token (`_conv_operator`)
+    call_keys=None,               # [B] int32: the keys of the call these
+                                  # tokens are a piece of (a prompt's whole
+                                  # length; None: what the row holds after
+                                  # them). Only a sparse layer reads it
+                                  # (core/sala.py: `sparse_dense_len`)
 ):
     """Batched k-token verification for speculative decode
     (sampler/speculative.py): one small-T causal forward over Tq = k+1
@@ -2817,7 +2931,9 @@ def decode_verify(
         page_table=page_table, page_size=page_size,
         # a row with no valid slot before its candidates starts here
         conv_ctx=lambda: _conv_ctx(config, token_valid,
-                                   lambda: ~key_mask.any(axis=1)))
+                                   lambda: ~key_mask.any(axis=1)),
+        span=lambda: (start, (fill + Tq - start) if call_keys is None
+                      else call_keys.astype(jnp.int32)))
     x, new_caches, _ = _run_layers(config, params, x, cos, sin, views,
                                    kv_caches, lora_scale)
     if not want_logits:

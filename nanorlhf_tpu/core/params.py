@@ -326,6 +326,68 @@ def _lfm2_sd_from_params(config: ModelConfig, params: dict, put) -> None:
                 at["attn"] += 1
 
 
+# MiniCPM-SALA (docs/SALA.md; the names are ASSUMED from the family's
+# modelling code, the published checkpoint is not on this machine): a
+# layer's mixer is `self_attn` whichever its kind; (ours, theirs) of the
+# kernels and vectors of a sparse layer and of a lightning layer
+_SALA_SPARSE = (("q_proj", "q_proj"), ("k_proj", "k_proj"), ("v_proj", "v_proj"),
+                ("o_proj", "o_proj"), ("g_proj", "o_gate"))
+_SALA_LIGHTNING = (("q_proj", "q_proj"), ("k_proj", "k_proj"),
+                   ("v_proj", "v_proj"), ("o_proj", "o_proj"),
+                   ("z_proj", "z_proj"))
+_SALA_SPARSE_NORMS = (("q_norm", "q_norm"), ("k_norm", "k_norm"))
+_SALA_LIGHTNING_NORMS = _SALA_SPARSE_NORMS + (("o_norm", "o_norm"),)
+_SALA_SHARED = (("input_layernorm", "input_layernorm"),
+                ("post_attention_layernorm", "post_attention_layernorm"))
+
+
+def _sala_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    kinds = config.layer_kinds
+    w = lambda i, name: sd[f"model.layers.{i}.{name}.weight"]       # noqa: E731
+    ids = range(config.num_hidden_layers)
+    tree = {ours: cast(np.stack([w(i, theirs) for i in ids]))
+            for ours, theirs in _SALA_SHARED}
+    for name in _MLP_KEYS:
+        tree[name] = {"kernel": cast(np.stack(
+            [w(i, f"mlp.{name}").T for i in ids]))}
+    sparse = [i for i in ids if kinds[i] == "sparse"]
+    light = [i for i in ids if kinds[i] == "lightning"]
+    for ours, theirs in _SALA_SPARSE:
+        tree[ours] = {"kernel": cast(np.stack(
+            [w(i, f"self_attn.{theirs}").T for i in sparse]))}
+    for ours, theirs in _SALA_SPARSE_NORMS:
+        tree[ours] = cast(np.stack([w(i, f"self_attn.{theirs}")
+                                    for i in sparse]))
+    tree["lightning"] = {
+        **{ours: {"kernel": cast(np.stack(
+            [w(i, f"self_attn.{theirs}").T for i in light]))}
+           for ours, theirs in _SALA_LIGHTNING},
+        **{ours: cast(np.stack([w(i, f"self_attn.{theirs}") for i in light]))
+           for ours, theirs in _SALA_LIGHTNING_NORMS}}
+    return {"layers": tree}
+
+
+def _sala_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    kinds, tree = config.layer_kinds, params["layers"]
+    at = {"sparse": 0, "lightning": 0}
+    for i, kind in enumerate(kinds):
+        pre = f"model.layers.{i}."
+        for ours, theirs in _SALA_SHARED:
+            put(f"{pre}{theirs}.weight", tree[ours][i])
+        for name in _MLP_KEYS:
+            put(f"{pre}mlp.{name}.weight", tree[name]["kernel"][i].T)
+        a = at[kind]
+        at[kind] += 1
+        own = tree["lightning"] if kind == "lightning" else tree
+        kernels, norms = ((_SALA_LIGHTNING, _SALA_LIGHTNING_NORMS)
+                          if kind == "lightning"
+                          else (_SALA_SPARSE, _SALA_SPARSE_NORMS))
+        for ours, theirs in kernels:
+            put(f"{pre}self_attn.{theirs}.weight", own[ours]["kernel"][a].T)
+        for ours, theirs in norms:
+            put(f"{pre}self_attn.{theirs}.weight", own[ours][a])
+
+
 # Falcon-H1: (ours, theirs) of a layer's kernels outside its mixer, of its
 # norms, and of the mixer's vectors
 _FH1_KERNELS = (("q_proj", "self_attn.q_proj"), ("k_proj", "self_attn.k_proj"),
@@ -406,6 +468,13 @@ def params_from_hf_state_dict(
         if not config.tie_word_embeddings:
             params["lm_head"] = cast(sd["lm_head.weight"].T)
         return params
+    if config.linear_layers:
+        params = _sala_params_from_sd(config, sd, cast)
+        params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
+                      norm=cast(sd["model.norm.weight"]))
+        if not config.tie_word_embeddings:
+            params["lm_head"] = cast(sd["lm_head.weight"].T)
+        return params
     if config.ssm_layers:
         params = _falcon_h1_params_from_sd(config, sd, cast)
         params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
@@ -475,6 +544,13 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
 
     layers = params["layers"]
     linear_keys, norm_keys = _layer_keys(config)
+    if config.linear_layers:
+        _sala_sd_from_params(config, params, put)
+        put("model.embed_tokens.weight", params["embed_tokens"])
+        put("model.norm.weight", params["norm"])
+        if not config.tie_word_embeddings:
+            put("lm_head.weight", params["lm_head"].T)
+        return sd
     if config.ssm_layers:
         _falcon_h1_sd_from_params(config, params, put)
         put("model.embed_tokens.weight", params["embed_tokens"])

@@ -673,7 +673,8 @@ def _admit_sample(logits, key, *, temperature, top_p, greedy, top_k,
          static_argnames=("config", "page_size", "lora_scale"))
 @jax.named_scope("prefill")
 def _prefill_chunk_fwd(params, config, chunk_ids, positions, fill, key_mask,
-                       caches, row_table, *, page_size, lora_scale):
+                       caches, row_table, call_keys=None, *, page_size,
+                       lora_scale):
     """One KV-only prefill chunk: a `decode_verify` forward over a
     fixed-width slice of a long cold prompt, writing its KV through the
     row's block table and skipping the lm_head matmul entirely
@@ -683,7 +684,7 @@ def _prefill_chunk_fwd(params, config, chunk_ids, positions, fill, key_mask,
         params, config, chunk_ids, positions, fill, key_mask, caches,
         lora_scale=lora_scale,
         page_table=jax.tree.map(lambda t: t[None, :], row_table),
-        page_size=page_size, want_logits=False,
+        page_size=page_size, want_logits=False, call_keys=call_keys,
     )
     return caches
 
@@ -1051,6 +1052,15 @@ class DecodeSession:
         # (`serving/state_resets`, `serving/state_piece_carries`)
         self.state_resets = 0
         self.state_piece_carries = 0
+        # a model with sparse-attention layers (docs/SALA.md): the decode
+        # steps of live rows past `sparse_dense_len` (`serving/sparse_rows`),
+        # the slots those rows held and the slots the selection let a layer
+        # read of them (`sparse_topk` blocks at most)
+        self._sparse_reads = (config.sparse_topk * config.sparse_block_size
+                              if config.sparse_layers else 0)
+        self.sparse_rows = 0
+        self.sparse_slots_held = 0
+        self.sparse_slots_read = 0
         # the REAL tokens of those forwards (`dispatch_tokens` holds a
         # bucket's pads too): what a state's recurrence ran over
         self.state_tokens = 0
@@ -1457,7 +1467,8 @@ class DecodeSession:
         window twins nobody wrote: none can occur (`_install` inserts
         nothing for such a model), and one that did raises here."""
         if plan.m > 0 or plan.cow_src is not None:
-            state = "recurrent" if self.config.ssm_layers else "conv"
+            state = ("recurrent" if self.config.ssm_layers
+                     or self.config.linear_layers else "conv")
             raise NotImplementedError(
                 "a radix prefix hit on a model with window layers or a state "
                 f"({self.config.model_type}): the tree holds pages of the "
@@ -1538,7 +1549,7 @@ class DecodeSession:
                 self.params, self.config, jnp.asarray(suffix),
                 jnp.asarray(pos), jnp.asarray([start_abs], jnp.int32),
                 jnp.int32(s_real - 1), jnp.asarray(km), self.state[3],
-                row_table, page_size=self.page_size,
+                row_table, *self._call_keys(p), page_size=self.page_size,
                 lora_scale=self.lora_scale)
             self._set_pool(caches)
             self.dispatch_tokens += Sb
@@ -1661,12 +1672,21 @@ class DecodeSession:
         self._set_pool(_prefill_chunk_fwd(
             self.params, self.config, jnp.asarray(chunk), jnp.asarray(pos),
             jnp.asarray([p.next_slot], jnp.int32), jnp.asarray(km),
-            self.state[3], row_table, page_size=self.page_size,
-            lora_scale=self.lora_scale))
+            self.state[3], row_table, *self._call_keys(p),
+            page_size=self.page_size, lora_scale=self.lora_scale))
         p.next_slot += C
         self.launches += 1
         self.dispatch_tokens += C
         return None
+
+    def _call_keys(self, p: _PendingPrefill) -> tuple:
+        """What a piece of `p`'s prompt says of the call it belongs to, for
+        a model with sparse-attention layers (docs/SALA.md: a prompt selects
+        in whole or not at all, whatever the pieces it is cut into): the
+        prompt's length; nothing for every other model."""
+        if not self.config.sparse_layers:
+            return ()
+        return (jnp.asarray([self.Tp - p.pad_count], jnp.int32),)
 
     def _count_state(self, p: _PendingPrefill, start_abs: int) -> None:
         """An admission forward from slot `start_abs`, as the conv state
@@ -1907,6 +1927,12 @@ class DecodeSession:
             span = np.where(steps > s, slot - self._row_start_np + 1, 0)
             self.live_row_steps += int((steps > s).sum())
             self.global_slots_read += int(span.sum())
+            if self._sparse_reads:      # (docs/SALA.md; docs/METRICS.md)
+                past = span >= self.config.sparse_dense_len
+                self.sparse_rows += int(past.sum())
+                self.sparse_slots_held += int(span[past].sum())
+                self.sparse_slots_read += int(np.minimum(
+                    span[past], self._sparse_reads).sum())
             if window:
                 self.window_slots_read += int(np.minimum(span, window).sum())
                 self.rows_past_window += int((span > window).sum())

@@ -736,6 +736,9 @@ class ServingEngine:
             "serving/state_resets": self._sess.state_resets,
             "serving/state_piece_carries": self._sess.state_piece_carries,
             "serving/state_tokens": self._sess.state_tokens,
+            "serving/sparse_rows": self._sess.sparse_rows,
+            "serving/sparse_slots_read": self._sess.sparse_slots_read,
+            "serving/sparse_slots_held": self._sess.sparse_slots_held,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
             # the rows (generation by blocks: positions) the sampler ran

@@ -522,14 +522,17 @@ def copy_page(caches, src, dst):
          static_argnames=("config", "page_size", "lora_scale"))
 @jax.named_scope("prefill")
 def suffix_logits(params, config, suffix_ids, positions, fill, last,
-                  key_mask, caches, row_table, *, page_size, lora_scale):
+                  key_mask, caches, row_table, call_keys=None, *, page_size,
+                  lora_scale):
     """Single-row suffix prefill: a `decode_verify` forward over the
     unmatched prompt tail writes its KV at slots [fill, fill+Sb) through
     the row's block table and returns the last REAL token's next-token
     logits ([V]) — `last` indexes past the bucket-padding tail, whose
     garbage KV lands in decode-region slots that the decode loop
     overwrites before ever marking them attendable. The caller buckets
-    suffix lengths (`bucket_len`) so retraces stay logarithmic."""
+    suffix lengths (`bucket_len`) so retraces stay logarithmic. `call_keys`
+    [1]: the whole prompt's length, which a model with sparse-attention
+    layers asks of a piece (`decode_verify`)."""
     logits, caches = decode_verify(
         params, config, suffix_ids, positions, fill, key_mask, caches,
         lora_scale=lora_scale,
@@ -538,6 +541,7 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
         # (a conv state stops at the last REAL token, not the bucket's end)
         **({"token_valid": jnp.arange(suffix_ids.shape[1])[None, :] <= last}
            if config.state_layers else {}),
+        call_keys=call_keys,
     )
     return jnp.take(logits[0], last, axis=0), caches
 
@@ -556,5 +560,7 @@ def prompt_key(tokens: np.ndarray, mask: np.ndarray) -> tuple:
     """Radix key for one left-padded prompt row: the mask bit folds into
     each element so prefixes only match when their pad layout does —
     the condition for slot-identical KV."""
-    return tuple(int(t) * 2 + int(b) for t, b in
-                 zip(np.asarray(tokens), np.asarray(mask).astype(bool)))
+    # (in numpy, then one `tolist`: a Python loop over a row of 65,536 slots
+    # was 30-45 ms of every admission, PERF.md PR 53; the same ints)
+    return tuple((np.asarray(tokens).astype(np.int64) * 2
+                  + np.asarray(mask).astype(bool)).tolist())

@@ -97,6 +97,18 @@ import jax
 # chunks) or `attn.ssm.update` (a decode step's pass over the rows' state),
 # `attn.ssm.gate` (the skip, the gate and the group norm), `attn.write` (both
 # state leaves' write) and `attn.ssm.out`.
+# A lightning layer (docs/SALA.md) keeps a state and no pages: `attn.linear`
+# around `attn.linear.in` (the projections, the head norms, rotary),
+# `attn.linear.scan` (a piece's recurrence in chunks) or `attn.linear.update`
+# (a decode step's pass over the rows' state), `attn.linear.gate` (the head
+# norm and the gate), `attn.write` (the state's write at a piece) and
+# `attn.linear.out`. A sparse layer names its steps as a model of one kind
+# does (no `attn.global` around them): `attn.qkv/.write/.read/.gate/.out`
+# and, between the write and the read, `attn.compress` (the compressed keys
+# this call completed, written beside K and V) and `attn.select` (the row's
+# compressed keys read, the scores, the group sum, the pooling, the top-k; a
+# decode step's work list); `attn.read` is the read of the chosen blocks, or
+# the dense read of a call none of whose rows selects.
 DEVICE_SCOPES = (
     "prefill", "decode", "verify", "install", "score", "update", "sync",
     "embed", "norm", "attn", "attn.qkv", "attn.write", "attn.read",
@@ -104,7 +116,9 @@ DEVICE_SCOPES = (
     "attn.conv.mix", "attn.conv.out", "mlp", "head", "sample", "logprob",
     "loss", "optim", "attn.block", "sample.unmask", "attn.ssm",
     "attn.ssm.in", "attn.ssm.conv", "attn.ssm.scan", "attn.ssm.update",
-    "attn.ssm.gate", "attn.ssm.out",
+    "attn.ssm.gate", "attn.ssm.out", "attn.linear", "attn.linear.in",
+    "attn.linear.scan", "attn.linear.update", "attn.linear.gate",
+    "attn.linear.out", "attn.compress", "attn.select",
 )
 
 

@@ -1460,3 +1460,116 @@ def test_falcon_h1_decode_chunk_passes_over_the_state_in_one_kernel_on_v5e(
               if op.startswith("copy") and set(_shapes(result)) & S]
     assert not copies, "\n".join(copies)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def _sala_session_program(case, v5e):
+    """The `serve-sala-docchat` cell's decode chunk, its KV-only prefill piece
+    or its closing suffix forward, lowered for a described v5e at the
+    configuration file's own cut (MiniCPM-SALA's published widths, 8 layers,
+    the whole vocabulary) and the cell's engine sizes: `(compiled, cache
+    shapes, config)`."""
+    import json
+    import os
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.serving import radix
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "minicpm-sala-l8.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "docchat-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = (eng["rows"], eng["prompt_len"], eng["max_new_tokens"],
+                         eng["prefill_chunk"])
+    nb = (Tp + new) // PAGE
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, (R * nb, R), PAGE, jnp.bfloat16, state_rows=R))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        tables = (spec((R, nb), jnp.int32),) * 2 + (spec((R, 1), jnp.int32),)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, tables, spec((R,), jnp.float32),
+            spec((R,), jnp.float32), spec((R,), jnp.bool_),
+            spec((R,), jnp.int32), Tp=Tp, max_tokens=new, page_size=PAGE,
+            sync_every=eng["sync_every"], eos_token_id=1, pad_token_id=0,
+            temperature=1.0, top_p=1.0, greedy=False, lora_scale=1.0, top_k=64,
+            capture_logprobs=False, approx_top_k=True)
+    else:
+        row = (spec((nb,), jnp.int32),) * 2 + (spec((1,), jnp.int32),)
+        args = (params, cfg, spec((1, chunk), jnp.int32),
+                spec((1, chunk), jnp.int32), spec((1,), jnp.int32))
+        tail = (spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip), row,
+                spec((1,), jnp.int32))      # (the prompt's length: call_keys)
+        if case == "prefill_piece":
+            lowered = session._prefill_chunk_fwd.lower(
+                *args, *tail, page_size=PAGE, lora_scale=1.0)
+        else:
+            lowered = radix.suffix_logits.lower(
+                *args, spec((), jnp.int32), *tail, page_size=PAGE,
+                lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "prefill_piece", "suffix"])
+def test_sala_session_programs_fit_the_chip_with_pages_keys_and_state_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 53, asked of the chip's compiler at the `serve-sala-docchat`
+    cell's own shapes (5.64 GB of bf16 weights, 32 rows of 66,560 slots,
+    pages of 128: a pool of 16,640 pages x 2 sparse layers, 4.36 GB, their
+    compressed keys `bf16[2,16640,16,128]`, 0.14 GB, and a state of 32 rows x
+    6 lightning layers, `f32[6,32,32,128,128]`, 0.40 GB): the session's
+    decode chunk, its 1,024-token KV-only prefill piece and its closing
+    suffix forward each FIT 16 GB and alias the pool, the compressed keys
+    AND the state from their parameters to their results; no module holds a
+    `copy` of any of them (the compressed keys with their heads on an axis
+    of their own were relaid whole at both ends of a decode chunk); the T =
+    1 read is ops/sparse_attention.py's kernel (`%attn.read*`: a step's
+    work is its selection's, not `rows x table width`), the state's pass
+    `%attn.linear.update*`; a piece's dense branch is the paged flash
+    kernel."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _sala_session_program(case, v5e)
+    hlo = compiled.as_text()
+    kept = hlo_stacks([leaf for leaf in jax.tree.leaves(cache) if leaf.size])
+    assert set(kept) == {("bf16", (2, 16640, 2, PAGE, 128)),
+                         ("bf16", (2, 16640, 16, 128)),
+                         ("f32", (6, 32, 32, 128, 128))}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    head = 0.6e9 if case == "prefill_piece" else 0.0    # a piece takes no head
+    assert 10.4e9 < m.argument_size_in_bytes + head < 10.7e9
+    assert m.alias_size_in_bytes > 4.8e9        # pool, keys and state donated
+    assert peak < 12.0e9, (case, peak, m.temp_size_in_bytes)
+    comps = _computations(hlo)
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and set(_shapes(result)) & set(kept)]
+    assert not copies, "\n".join(copies)
+    calls = {re.sub(r"[.\d]+$", "", c) for c in re.findall(
+        r"%([\w.]+) = [^=\n]* custom-call\(", hlo)}
+    if case == "decode_chunk":
+        assert {"attn.read", "attn.write", "attn.linear.update"} <= calls, calls
+        # no gathered view of a row's pages: [.., 66560, 128] by slot
+        assert not re.findall(r"bf16\[\d+,2,66560,128\]", hlo)
+    else:
+        assert "paged_prefill_attention" in calls, calls

@@ -46,6 +46,7 @@ KINDS = {
     "conv": lambda: ModelConfig.lfm2_tiny(vocab_size=256),
     "gated": lambda: ModelConfig.trinity_tiny(vocab_size=256, window=16),
     "hybrid": lambda: ModelConfig.falcon_h1_tiny(vocab_size=256),
+    "sala": lambda: ModelConfig.minicpm_sala_tiny(vocab_size=256),
 }
 # what a layer's attention is made of, by kind (the older families are the
 # latent model's `mla.*` and the pattern model's `attn.global` / `.window`)
@@ -65,6 +66,12 @@ ATTENTION = {
     "hybrid": {"attn.qkv", "attn.write", "attn.global", "attn.out",
                "attn.ssm", "attn.ssm.in", "attn.ssm.conv", "attn.ssm.gate",
                "attn.ssm.out"},
+    # a lightning mixer in some layers, a sparse attention in the others
+    # (docs/SALA.md): the recurrence's two forms and a piece that selects
+    # have a test of their own
+    "sala": {"attn.qkv", "attn.write", "attn.gate", "attn.out",
+             "attn.compress", "attn.select", "attn.read", "attn.linear",
+             "attn.linear.in", "attn.linear.gate", "attn.linear.out"},
 }
 MODEL = {"embed", "norm", "attn", "mlp", "head"}
 MATMUL_HOMES = ("attn", "mlp", "head")
@@ -170,7 +177,7 @@ def test_the_one_jit_rollout_names_its_prefill_and_its_decode_loops(kind):
         missing = want - parts_of(ops, first)
         assert not missing, (first, missing)
     check_matmuls_and_innermost(ops, "decode")
-    if kind not in ("dense", "hybrid"):
+    if kind not in ("dense", "hybrid", "sala"):
         assert {"moe.router", "moe.dispatch", "moe.experts",
                 "moe.combine"} <= parts_of(ops, "decode")
     # a scope of the model is never the first of a path: some program's is
@@ -196,7 +203,7 @@ def session_programs(kind, **config):
         pages = (pages, ROWS * ring_blocks(cfg.sliding_window, PAGE, PAGE))
         table, row_table = (table,) * 2, (row_table,) * 2
     state_rows = {}
-    if kind in ("conv", "hybrid"):  # no window layer; the state's rows ride third
+    if kind in ("conv", "hybrid", "sala"):  # no window layer; the state's rows ride third
         pages, state_rows = (pages, ROWS), {"state_rows": ROWS}
         table = (table,) * 2 + (spec((ROWS, 1), jnp.int32),)
         row_table = (row_table,) * 2 + (spec((1,), jnp.int32),)
@@ -270,6 +277,33 @@ def test_a_hybrid_layers_recurrence_is_a_scan_in_a_piece_an_update_in_a_step():
         assert not any("attn.ssm.update" in s for s in scopes), name
 
 
+def test_a_lightning_layer_scans_a_piece_and_updates_a_step_a_sparse_one_selects():
+    """docs/SALA.md: the lightning recurrence runs under `attn.linear.scan`
+    in a prefill piece and a suffix forward, under `attn.linear.update` in a
+    decode chunk, both inside `attn/attn.linear`; a sparse layer writes its
+    compressed keys under `attn.compress`, selects under `attn.select` and
+    reads under `attn.read` in every program that has a cache, with no
+    `attn.global` around them (its steps are named as a model of one kind
+    names them)."""
+    lowered = session_programs("sala")
+    chunk = {scope for _, _, scope in ops_of(lowered["chunk"])}
+    assert "decode/attn/attn.linear/attn.linear.update" in chunk
+    assert not any("attn.linear.scan" in s for s in chunk)
+    for part in ("attn.compress", "attn.select", "attn.read"):
+        assert f"decode/attn/{part}" in chunk, part
+    assert not any("attn.global" in s for s in chunk)
+    for name in ("piece", "suffix"):
+        scopes = {scope for _, _, scope in ops_of(lowered[name])}
+        assert "prefill/attn/attn.linear/attn.linear.scan" in scopes, name
+        assert "prefill/attn/attn.linear/attn.write" in scopes, name
+        assert not any("attn.linear.update" in s for s in scopes), name
+        for part in ("attn.compress", "attn.select", "attn.read"):
+            assert any(s.startswith("prefill/attn/" + part)
+                       for s in scopes), (name, part)
+    assert {"attn.linear", "attn.linear.scan", "attn.linear.update",
+            "attn.compress", "attn.select"} <= set(DEVICE_SCOPES)
+
+
 def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
     """ISSUE 37: under the decode read's rule (`"pallas"`; interpret mode
     here, so the kernel's body is XLA ops that carry its scope) a piece's
@@ -294,7 +328,7 @@ def test_a_pattern_models_piece_reads_its_pages_under_a_scope_of_its_own():
 
 
 @pytest.mark.parametrize("kind", ["dense", "latent", "pattern", "conv",
-                                  "gated", "hybrid"])
+                                  "gated", "hybrid", "sala"])
 def test_a_pieces_write_by_page_carries_attn_write(kind):
     """ISSUE 41: a piece of a page or more writes its K and V by PAGE
     (`core/model._paged_page_write`: the touched pages gathered, patched and
@@ -309,7 +343,8 @@ def test_a_pieces_write_by_page_carries_attn_write(kind):
             "prefill/attn/attn.window/attn.write"}
     homes = {"pattern": both, "gated": both,
              "conv": {"prefill/attn/attn.global/attn.write"},
-             "hybrid": {"prefill/attn/attn.global/attn.write"}}.get(
+             "hybrid": {"prefill/attn/attn.global/attn.write"},
+             "sala": {"prefill/attn/attn.write"}}.get(
         kind, {"prefill/attn/attn.write"})
     for part in ("gather", "select_n", "scatter"):
         found = {scope for _, name, scope in ops

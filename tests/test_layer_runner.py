@@ -47,14 +47,16 @@ def loop_layers(config, params, x, cos, sin, views, kv_caches=None,
         if cached or not plain or M.use_expert_kernel(config):
             experts = tree.pop("experts", None)
         own = [0, 0]    # this stack's attention layers and conv layers so far
+        # (the leaves of the kind that keeps a state and no pages)
+        stateful = "lightning" if config.linear_layers else "conv"
         auxes = []
         for at, kind in enumerate(config.layer_kinds[start:start + count]):
             g = M._kind_group(kind)
             layer_params = {}
             for name, leaf in tree.items():
-                mine = name == "conv" or name in M._ATTENTION_LEAVES
-                if config.conv_layers and mine:
-                    if (name == "conv") == (g == 2):
+                mine = name == stateful or name in M._ATTENTION_LEAVES
+                if config.conv_layers + config.linear_layers and mine:
+                    if (name == stateful) == (g == 2):
                         layer_params[name] = jax.tree.map(
                             lambda a: a[own[int(g == 2)]], leaf)
                 else:
@@ -252,7 +254,7 @@ def _two_period_pattern(monkeypatch, model, lora):
     def pool():
         return init_paged_kv_cache(
             cfg, pages, P, jnp.float32,
-            **({"state_rows": B} if cfg.conv_layers else {}))
+            **({"state_rows": B} if cfg.state_layers else {}))
 
     @jax.disable_jit()
     def served():
@@ -315,7 +317,8 @@ def _two_period_pattern(monkeypatch, model, lora):
 _KINDS = {"qwen2": ModelConfig.qwen2_tiny, "olmoe": ModelConfig.olmoe_tiny,
           "axk1": ModelConfig.axk1_tiny,
           "smallthinker": ModelConfig.smallthinker_tiny,
-          "lfm2": ModelConfig.lfm2_tiny, "trinity": ModelConfig.trinity_tiny}
+          "lfm2": ModelConfig.lfm2_tiny, "trinity": ModelConfig.trinity_tiny,
+          "sala": ModelConfig.minicpm_sala_tiny}
 
 
 def _every_kind(monkeypatch, kind, cache):
@@ -338,7 +341,7 @@ def _every_kind(monkeypatch, kind, cache):
         if cache == "paged":
             caches = init_paged_kv_cache(
                 cfg, pages, P, jnp.float32,
-                **({"state_rows": B} if cfg.conv_layers else {}))
+                **({"state_rows": B} if cfg.state_layers else {}))
             lg, caches = prefill(params, cfg, ids[:, :T],
                                  jnp.asarray(valid[:, :T]), caches,
                                  logical_len=T_max, **kw)
@@ -513,6 +516,8 @@ _LAYOUTS = [
      _wide(_PATTERN), "tpu", 0),
     ("a model that keeps a state: the same",
      _wide(ModelConfig.falcon_h1_tiny(vocab_size=V)), "tpu", 0),
+    ("lightning and sparse-attention layers: the same",
+     _wide(ModelConfig.minicpm_sala_tiny(vocab_size=V)), "tpu", 0),
     ("a model that generates by blocks has no rollout",
      _wide(ModelConfig.sdar_tiny(vocab_size=V)), "tpu", 0),
     ("heads of 64 lanes: the kernels take whole rows of 128",

@@ -497,7 +497,7 @@ def _two_rows(cfg):
     if cfg.attention_pattern is None:
         return jnp.asarray(ids), valid, pos, table, B * nb
     tabs = (table, table) + (
-        (jnp.arange(B, dtype=jnp.int32)[:, None],) if cfg.conv_layers else ())
+        (jnp.arange(B, dtype=jnp.int32)[:, None],) if cfg.state_layers else ())
     return jnp.asarray(ids), valid, pos, tabs, (B * nb, B * nb)
 
 
