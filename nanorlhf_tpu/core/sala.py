@@ -340,6 +340,55 @@ def select_blocks(config, q, kc, t):
     return idx.astype(jnp.int32), vals >= 0
 
 
+def _row_keys(config, kc_stack, layer, table, start, page_size: int):
+    """One row's compressed keys out of the paged cache, `[1, KV, Nc, hd]`
+    in POSITION order, read through its `table` [nb] from the page of its
+    FIRST key on: key j lies at compressed slot `start // stride + j`
+    (`compress_write`), so the pages shift by index and the entries inside a
+    page by a slice one page wide. What lies past the row's table has not
+    ended for any query of the row (`select_blocks`)."""
+    per = page_size // config.sparse_kernel_stride
+    nb = table.shape[0]
+    c0 = start // config.sparse_kernel_stride
+    blk = jnp.minimum(c0 // per + jnp.arange(nb + 1, dtype=jnp.int32), nb - 1)
+    got = kc_stack[layer, jnp.minimum(table[blk], kc_stack.shape[1] - 1)]
+    hd = got.shape[-1]                              # [nb + 1, KV per, hd]
+    got = got.reshape(nb + 1, -1, per, hd).transpose(1, 0, 2, 3)
+    return jax.lax.dynamic_slice_in_dim(
+        got.reshape(1, got.shape[0], (nb + 1) * per, hd), c0 % per, nb * per,
+        axis=2)
+
+
+def select_needed(config, q, kc_stack, layer, view, t, need):
+    """A paged decode step's selection over the rows that will USE it,
+    `need` [B] bool (the rows `sparse_decode_plan` gives their chosen
+    blocks' items): `(idx, ok)` `[B, KV, 1, k]`, what `select_blocks` gives
+    a needed row, zeros and False for every other.
+
+    A loop over the needed rows, as many trips as there are: a trip gathers
+    ONE row's compressed keys through its table, scores, pools and ranks
+    them, and writes the row's place. A step none of whose rows selects
+    takes no trip."""
+    start, _ = view.span
+    per = view.page_size // config.sparse_kernel_stride
+    shape = (q.shape[0], kc_stack.shape[2] // per, 1, min(
+        config.sparse_topk, _blocks_of(config, view.table.shape[1] * per)))
+    order = jnp.argsort(~need, stable=True).astype(jnp.int32)
+
+    def one(i, found):
+        row = order[i]
+        at = lambda a: jax.lax.dynamic_slice_in_dim(a, row, 1)  # noqa: E731
+        kc = _row_keys(config, kc_stack, layer, view.table[row], start[row],
+                       view.page_size)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(buf, a, row, 0)
+                     for buf, a in zip(found, select_blocks(
+                         config, at(q), kc, at(t))))
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(need, dtype=jnp.int32), one,
+        (jnp.zeros(shape, jnp.int32), jnp.zeros(shape, bool)))
+
+
 def _in_query_blocks(fn, Tq: int, per_query_bytes: int, *arrays):
     """`fn(*arrays)` over blocks of queries (every array's axis 2) such that
     one block's float32 scores stay under `_SCORE_BYTES`; fn's result has
@@ -484,12 +533,14 @@ def sparse_read(config, q, k, v, view, new_cache, kc_stack, layer,
     if paged and decode and T == 1 and M.use_paged_decode_kernel(config):
         # the chosen blocks' pages, read from the stacks in place
         from nanorlhf_tpu.ops.sparse_attention import (
-            sparse_decode_plan, sparse_paged_decode_attention,
+            plan_rows, sparse_decode_plan, sparse_paged_decode_attention,
         )
 
         with jax.named_scope("attn.select"):
-            kc = compressed_keys(config, kc_stack, layer, view)
-            idx, ok = select_blocks(config, q, kc, t)
+            need = selects & plan_rows(start, filled, view.live, view.table,
+                                       page_size=view.page_size,
+                                       num_pages=new_cache[0].shape[1])
+            idx, ok = select_needed(config, q, kc_stack, layer, view, t, need)
             plan = sparse_decode_plan(
                 config, idx[:, :, 0], ok[:, :, 0], start, filled, selects,
                 view.live, view.table, page_size=view.page_size,

@@ -67,6 +67,16 @@ def plan_items(config, page_size: int) -> tuple:
                   items(config.sparse_dense_len))
 
 
+def plan_rows(start, filled, live, table, *, page_size: int, num_pages: int):
+    """[B] bool, the rows a step's work list has items for: something valid
+    (`filled > start`), the row's last block not the sentinel (a released
+    row's is), and `live` ([B] bool or None)."""
+    last_blk = jnp.clip((filled - 1) // page_size, 0, table.shape[1] - 1)
+    has = (filled > start) & (
+        jnp.take_along_axis(table, last_blk[:, None], axis=1)[:, 0] < num_pages)
+    return has if live is None else has & live
+
+
 def sparse_decode_plan(config, idx, ok, start, filled, selects, live, table,
                        *, page_size: int, num_pages: int) -> SparseDecodePlan:
     """The work list of one sparse layer's decode step. `idx`, `ok` [B, KV,
@@ -84,10 +94,8 @@ def sparse_decode_plan(config, idx, ok, start, filled, selects, live, table,
     i32 = jnp.int32
     start, filled = start.astype(i32), filled.astype(i32)
     last_blk = jnp.clip((filled - 1) // P, 0, nb - 1)
-    has = (filled > start) & (
-        jnp.take_along_axis(table, last_blk[:, None], axis=1)[:, 0] < num_pages)
-    if live is not None:
-        has = has & live
+    has = plan_rows(start, filled, live, table, page_size=P,
+                    num_pages=num_pages)
     own = (filled - 1 - start) // block                     # [B] the query's
     near = jnp.maximum(own - local + 1, 0)                  # first local block
     # a selecting pair: its chosen blocks outside the local window, one item

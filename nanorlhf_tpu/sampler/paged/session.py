@@ -1061,6 +1061,14 @@ class DecodeSession:
         self.sparse_rows = 0
         self.sparse_slots_held = 0
         self.sparse_slots_read = 0
+        # the rows a sparse layer's decode selection ran over (the trips of
+        # `core/sala.select_needed`'s loop: the step's selecting rows, by
+        # its own rule over the host's record of each row's length) and the
+        # resident rows, which it ran over before it had a list, summed
+        # over steps and sparse layers (`serving/select_rows_run`,
+        # `serving/select_rows_resident`)
+        self.select_rows_run = 0
+        self.select_rows_resident = 0
         # the REAL tokens of those forwards (`dispatch_tokens` holds a
         # bucket's pads too): what a state's recurrence ran over
         self.state_tokens = 0
@@ -1929,10 +1937,13 @@ class DecodeSession:
             self.global_slots_read += int(span.sum())
             if self._sparse_reads:      # (docs/SALA.md; docs/METRICS.md)
                 past = span >= self.config.sparse_dense_len
+                layers = self.config.sparse_layers
                 self.sparse_rows += int(past.sum())
                 self.sparse_slots_held += int(span[past].sum())
                 self.sparse_slots_read += int(np.minimum(
                     span[past], self._sparse_reads).sum())
+                self.select_rows_run += layers * int(past.sum())
+                self.select_rows_resident += layers * self.rows
             if window:
                 self.window_slots_read += int(np.minimum(span, window).sum())
                 self.rows_past_window += int((span > window).sum())

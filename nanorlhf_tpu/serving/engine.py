@@ -739,6 +739,8 @@ class ServingEngine:
             "serving/sparse_rows": self._sess.sparse_rows,
             "serving/sparse_slots_read": self._sess.sparse_slots_read,
             "serving/sparse_slots_held": self._sess.sparse_slots_held,
+            "serving/select_rows_run": self._sess.select_rows_run,
+            "serving/select_rows_resident": self._sess.select_rows_resident,
             "serving/decode_steps": self._sess.iterations(),
             "serving/held_experts_hit": self._sess.held_experts_hit,
             # the rows (generation by blocks: positions) the sampler ran

@@ -1541,7 +1541,8 @@ def test_sala_session_programs_fit_the_chip_with_pages_keys_and_state_in_place_o
     1 read is ops/sparse_attention.py's kernel (`%attn.read*`: a step's
     work is its selection's, not `rows x table width`), the state's pass
     `%attn.linear.update*`; a piece's dense branch is the paged flash
-    kernel."""
+    kernel. The decode chunk's selection is a loop over the rows that select
+    (ISSUE 54)."""
     import re
 
     from test_cache_carry import _computations, _shapes, hlo_stacks
@@ -1571,5 +1572,17 @@ def test_sala_session_programs_fit_the_chip_with_pages_keys_and_state_in_place_o
         assert {"attn.read", "attn.write", "attn.linear.update"} <= calls, calls
         # no gathered view of a row's pages: [.., 66560, 128] by slot
         assert not re.findall(r"bf16\[\d+,2,66560,128\]", hlo)
+        # ISSUE 54: the selection runs a row at a time over the rows that
+        # select (`sala.select_needed`): `attn.select` names a sort of ONE
+        # row's 1,040 block scores and a gather of its 521 pages of
+        # compressed keys; nothing ranks or gathers all 32 resident rows',
+        # and the loop's operands (the 0.14 GB stack among them) cost no
+        # temporaries beyond PR 53's 0.75 GB (and no `copy`, above)
+        select = [line for line in hlo.split("\n") if "attn.select" in line]
+        assert any(" sort(" in line and "f32[1,2,1,1040]" in line
+                   for line in select)
+        assert any("bf16[521,16,128]" in line for line in select)
+        assert not re.findall(r"f32\[32,2,1,1040\]|bf16\[16640,16,128\]", hlo)
+        assert m.temp_size_in_bytes <= 0.75e9, m.temp_size_in_bytes
     else:
         assert "paged_prefill_attention" in calls, calls

@@ -515,6 +515,80 @@ def test_the_kernel_reads_the_plans_runs(params):
     assert float(jnp.abs(got[2:]).max()) == 0.0
 
 
+# ------------------------------------------- the selection's list of rows
+
+def _all_rows_selection(config, q, kc_stack, layer, view, t, need):
+    """What a step's selection was before it had a list of rows (ISSUE 54):
+    every resident row's compressed keys gathered, scored and ranked."""
+    kc = sala.compressed_keys(config, kc_stack, layer, view)
+    return sala.select_blocks(config, q, kc, t)
+
+
+@pytest.mark.parametrize("selecting, dead, released", [
+    ((), (), ()),                       # no row selects: the loop takes no trip
+    ((3,), (), ()),
+    ((1, 6), (), ()),
+    ((0, 2, 7), (), ()),
+    ((0, 1, 2, 5), (), ()),
+    ((0, 1, 2, 5, 7), (), ()),
+    (tuple(range(8)), (), ()),          # every row
+    ((2, 4), (4,), ()),                 # a selecting row that is not live
+    ((2, 4, 5), (), (5,)),              # one whose pages are released
+    ((7, 1, 3, 0), (1,), (7,)),         # both, the rows in no order
+    ((6,), (0, 1, 2, 3, 4, 5, 7), ()),  # the one live row
+])
+def test_the_row_listed_selection_hands_the_plan_what_all_rows_did(
+        selecting, dead, released):
+    """`select_needed` against the selection over all resident rows, through
+    `sparse_decode_plan`: the same pairs, and the same items (block, `lo`,
+    `hi`) bit for bit, for rows at scattered pages, starts and lengths; a
+    row the plan gives no chosen block's items reads zeros and False."""
+    from nanorlhf_tpu.ops import sparse_attention as sa
+
+    cfg = CFG
+    B, P, nb = 8, 8, 24
+    KV, H, hd = cfg.num_key_value_heads, cfg.num_attention_heads, cfg.head_dim
+    N = B * nb
+    rng = np.random.default_rng(len(selecting) + 10 * len(dead))
+    kc_stack = jnp.asarray(rng.normal(size=(
+        2, N, KV * P // cfg.sparse_kernel_stride, hd)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, H, 1, hd)) * 3, jnp.float32)
+    rows = np.arange(B)
+    table = rng.permutation(N).reshape(B, nb).astype(np.int32)
+    table[list(released)] = N
+    start = rng.integers(0, 40, B).astype(np.int32)
+    keys = np.where(np.isin(rows, selecting),
+                    rng.integers(cfg.sparse_dense_len, nb * P - 40, B),
+                    rng.integers(1, cfg.sparse_dense_len, B)).astype(np.int32)
+    start, filled = jnp.asarray(start), jnp.asarray(start + keys)
+    view = M.KindView(mask=None, table=jnp.asarray(table), page_size=P,
+                      span=(start, jnp.asarray(keys)),
+                      live=jnp.asarray(~np.isin(rows, dead)))
+    t = (filled - 1 - start)[:, None]
+    selects = jnp.asarray(keys >= cfg.sparse_dense_len)
+    need = selects & sa.plan_rows(start, filled, view.live, view.table,
+                                  page_size=P, num_pages=N)
+    used = sorted(set(selecting) - set(dead) - set(released))
+    assert np.flatnonzero(np.asarray(need)).tolist() == used
+    idx0, ok0 = _all_rows_selection(cfg, q, kc_stack, 1, view, t, need)
+    idx1, ok1 = jax.jit(lambda: sala.select_needed(
+        cfg, q, kc_stack, 1, view, t, need))()
+    plan0, plan1 = (sa.sparse_decode_plan(
+        cfg, idx[:, :, 0], ok[:, :, 0], start, filled, selects, view.live,
+        view.table, page_size=P, num_pages=N)
+        for idx, ok in ((idx0, ok0), (idx1, ok1)))
+    needed = np.asarray(need)
+    for got, want in ((idx1, idx0), (ok1, ok0)):
+        np.testing.assert_array_equal(np.asarray(got)[needed],
+                                      np.asarray(want)[needed])
+        assert not np.asarray(got)[~needed].any()
+    n = int(plan0.pair_off[-1])
+    np.testing.assert_array_equal(np.asarray(plan1.pair_off),
+                                  np.asarray(plan0.pair_off))
+    for a, b in zip(plan1[1:5], plan0[1:5]):    # (past n: no pair's items)
+        np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b)[:n])
+
+
 # ------------------------------------------------------------- the session
 
 def session(params, cfg=CFG, **kw):
@@ -591,6 +665,10 @@ def test_session_pieces_pads_reuse_and_chunks_follow_the_reference(params, impl)
     assert sess.sparse_rows == 23 + 9 + 14
     assert sess.sparse_slots_read == 64 * sess.sparse_rows
     assert sess.sparse_slots_held > 2 * sess.sparse_slots_read - 64 * 30
+    # the selection ran over the selecting rows, a trip a row a sparse layer
+    assert sess.select_rows_run == 2 * sess.sparse_rows
+    assert sess.select_rows_resident == 2 * 3 * sess.iterations()
+    assert sess.select_rows_run <= sess.select_rows_resident
 
 
 def test_a_state_not_carried_leaves_the_reference(params, monkeypatch):
@@ -602,6 +680,35 @@ def test_a_state_not_carried_leaves_the_reference(params, monkeypatch):
             None if fresh is None else lambda: jnp.ones_like(fresh())))
     prompts, answers = serve(session(params, cfg), *WAVES[0], 0)
     assert max(g.max() for g in gaps(params, prompts, answers)) > 0.02
+
+
+def test_a_chunk_over_the_listed_rows_is_the_chunk_over_all_rows(
+        params, monkeypatch):
+    """The session under the kernel (interpreted), its selection over the
+    rows that select against the selection over every resident row: the same
+    tokens, and the same compressed keys left in the cache."""
+    cfg = dataclasses.replace(CFG, attention_impl="pallas")
+    traced = []
+
+    def every_row(*args):
+        traced.append(1)
+        return _all_rows_selection(*args)
+
+    def wave(cfg):
+        sess = session(params, cfg)
+        _, answers = serve(sess, *WAVES[0], 0)
+        return answers, np.asarray(sess.state[3][0][2]), sess
+
+    answers, keys, sess = wave(cfg)
+    monkeypatch.setattr(sala, "select_needed", every_row)
+    # (another static argument, so the chunk is traced again)
+    before, keys_before, _ = wave(dataclasses.replace(
+        cfg, max_position_embeddings=1002))
+    assert traced
+    for a, b in zip(answers, before):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(keys, keys_before)
+    assert sess.select_rows_run == 2 * sess.sparse_rows > 0
 
 
 def test_engine_serves_counts_and_takes_no_prefix_hit(params):
@@ -627,6 +734,24 @@ def test_engine_serves_counts_and_takes_no_prefix_hit(params):
     assert m["serving/sparse_rows"] == 2 * 7
     assert m["serving/sparse_slots_read"] == 64 * 14
     assert m["serving/sparse_slots_held"] == 2 * sum(range(111, 118))
+    assert m["serving/select_rows_run"] == 2 * 2 * 7
+    assert m["serving/select_rows_resident"] == 2 * 2 * m["serving/decode_steps"]
+
+
+def test_a_model_without_sparse_layers_counts_no_selection():
+    from nanorlhf_tpu.serving.engine import ServingEngine
+
+    config = ModelConfig.qwen2_tiny(vocab_size=V)
+    dense = init_params(config, jax.random.PRNGKey(7), jnp.float32)
+    with ServingEngine(dense, config, eos_token_id=V + 5, pad_token_id=PAD,
+                       page_size=4, prompt_len=12, max_new_tokens=8, rows=2,
+                       sync_every=2) as engine:
+        req, _ = engine.submit(np.arange(3, 12), greedy=True, max_tokens=5)
+        assert len(list(engine.stream(req))) == 5
+        m = engine.metrics()
+    assert m["serving/decode_steps"] > 0
+    assert m["serving/select_rows_run"] == m["serving/select_rows_resident"] == 0
+    assert m["serving/sparse_rows"] == 0
 
 
 # ---------------------------------------------------------------- refusals
