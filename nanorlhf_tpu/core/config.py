@@ -226,6 +226,17 @@ class ModelConfig:
     attention_gate: bool = False
     branch_norms: bool = False
     embed_scale: float = 1.0
+    # A looped model (Ouro, docs/OURO.md): the whole stack of
+    # `num_hidden_layers` layers is ONE shared block that every token passes
+    # `loop_passes` times, the final norm after each pass (so the head does
+    # not norm again), and pass `t` of layer `l` keeps keys and values of
+    # its own: the cache holds `cache_layers` layers, index `t * L + l`.
+    # Every token runs every pass: the published exit threshold of 1 (a
+    # threshold below it, at which rows would leave a forward at different
+    # passes, `from_hf_config` refuses by name). The tree carries the exit
+    # gate's leaf (`early_exit_gate`) for the checkpoint; no forward reads it.
+    # 1: every other model, whose programs hold no trace of the loop.
+    loop_passes: int = 1
     # KV heads the cache keeps side by side in one row of lanes: 2 packs
     # heads of 64 into pages 128 lanes wide, which is what the paged kernels
     # read (core/model.py `_pack_heads`; docs/STATE.md). 1: a head a row.
@@ -287,6 +298,34 @@ class ModelConfig:
     spmd_mesh: object = None            # jax.sharding.Mesh | None
     spmd_batch_axes: tuple = ()         # e.g. ("data", "fsdp")
     spmd_head_axis: Optional[str] = None  # e.g. "tensor"
+
+    def __post_init__(self):
+        if self.loop_passes < 1:
+            raise ValueError(f"loop_passes={self.loop_passes}: at least 1")
+        if self.loop_passes > 1 and (
+                self.attention_pattern is not None or self.num_experts
+                or self.kv_lora_rank or self.block_length):
+            raise NotImplementedError(
+                f"loop_passes={self.loop_passes} on a model with a layer "
+                "pattern, experts, latent attention or block generation "
+                f"({self.model_type}): the loop is built for a plain stack "
+                "of one kind of layer (docs/OURO.md)")
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the KV cache: a slot a pass a layer (docs/OURO.md). The
+        ONE place the product is taken."""
+        return self.loop_passes * self.num_hidden_layers
+
+    def refuse_loop(self, who: str) -> None:
+        """Raises NotImplementedError, by the model's name, where `who` has
+        no form for a stack that is passed several times."""
+        if self.loop_passes > 1:
+            raise NotImplementedError(
+                f"{who} on a looped model ({self.model_type}, loop_passes="
+                f"{self.loop_passes}): not built or tested for a stack "
+                "every token passes several times (docs/OURO.md \"Refused "
+                "by name\")")
 
     @property
     def actual_head_dim(self) -> int:
@@ -884,6 +923,29 @@ class ModelConfig:
             mask_token_id=vocab_size - 1)
 
     @classmethod
+    def ouro_2_6b(cls) -> "ModelConfig":
+        """ByteDance/Ouro-2.6B (docs/OURO.md): 48 MHA layers of 16 heads of
+        128 with four norms a layer, passed four times, untied head."""
+        return cls(
+            vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+            num_hidden_layers=48, num_attention_heads=16,
+            num_key_value_heads=16, head_dim=128, rope_theta=1_000_000.0,
+            rms_norm_eps=1e-6, tie_word_embeddings=False,
+            max_position_embeddings=65536, attention_bias=False,
+            model_type="ouro", branch_norms=True, loop_passes=4)
+
+    @classmethod
+    def ouro_tiny(cls, vocab_size: int = 512, layers: int = 2,
+                  passes: int = 3) -> "ModelConfig":
+        """Test-size Ouro: 2 layers passed 3 times (so that no count stands
+        in for another), heads of 16."""
+        return dataclasses.replace(
+            cls.ouro_2_6b(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=96, num_hidden_layers=layers,
+            num_attention_heads=4, num_key_value_heads=4, head_dim=16,
+            max_position_embeddings=1024, loop_passes=passes)
+
+    @classmethod
     def llama3_2_1b(cls) -> "ModelConfig":
         """Llama-3.2-1B geometry — the Llama side of the same decoder
         (no attention biases, untied-by-default in larger family members)."""
@@ -952,6 +1014,15 @@ class ModelConfig:
             return cls._sala_from_hf(get)
         if model_type == "sdar_moe":
             return cls._sdar_from_hf(get)
+        if model_type == "ouro":
+            return cls._ouro_from_hf(get)
+        if int(get("total_ut_steps") or 1) > 1:
+            raise ValueError(
+                f"model_type={model_type!r} with total_ut_steps="
+                f"{get('total_ut_steps')!r}: a stack that every token passes "
+                "several times is built for model_type 'ouro' only "
+                "(docs/OURO.md); one pass under this config would be "
+                "another model under its name")
         # a window on a family this decoder builds with full attention only:
         # Qwen2's `use_sliding_window` (with `sliding_window`, `layer_types`
         # or `max_window_layers`), Mistral's bare `sliding_window`
@@ -999,6 +1070,50 @@ class ModelConfig:
             norm_topk_prob=bool(get("norm_topk_prob", False)),
             qk_norm=olmoe,
         )
+
+    @classmethod
+    def _ouro_from_hf(cls, get) -> "ModelConfig":
+        """Ouro's `config.json` (`model_type: ouro`, docs/OURO.md): Qwen2's
+        key set with `total_ut_steps` (the passes) and `early_exit_threshold`
+        (the exit gate's `q`). What the file has no key for (the four norms'
+        places, the norm inside the loop, the gate, a cache slot a pass a
+        layer) is the published modelling file's, listed in docs/OURO.md."""
+        def refuse(key, why):
+            raise ValueError(f"model_type='ouro' with {key}="
+                             f"{get(key)!r}: {why} (docs/OURO.md)")
+
+        if float(get("early_exit_threshold", 1.0)) < 1.0:
+            refuse("early_exit_threshold",
+                   "below 1 rows leave a forward at different passes, and "
+                   "the session's chunk program runs every row through "
+                   "every pass: what the skipped passes' cache slots then "
+                   "hold is not built")
+        if get("use_sliding_window") or "sliding_attention" in (
+                get("layer_types") or ()):
+            refuse("use_sliding_window", "the published model has no window")
+        if get("rope_scaling") is not None:
+            refuse("rope_scaling", "the published model has plain rotary")
+        if str(get("hidden_act", "silu")) != "silu":
+            refuse("hidden_act", "the MLP is SwiGLU")
+        if get("attention_bias") or get("mlp_bias"):
+            refuse("attention_bias", "the published layers carry no bias")
+        expert_keys = [k for k in _EXPERT_KEYS if get(k)]
+        if expert_keys:
+            raise ValueError(f"model_type='ouro' with expert keys "
+                             f"{expert_keys}: the model is dense")
+        return cls(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            intermediate_size=get("intermediate_size"),
+            num_hidden_layers=get("num_hidden_layers"),
+            num_attention_heads=get("num_attention_heads"),
+            num_key_value_heads=get("num_key_value_heads"),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1_000_000.0)),
+            rms_norm_eps=get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=bool(get("tie_word_embeddings", False)),
+            max_position_embeddings=get("max_position_embeddings", 65536),
+            attention_bias=False, model_type="ouro", branch_norms=True,
+            loop_passes=int(get("total_ut_steps") or 1))
 
     @classmethod
     def _sdar_from_hf(cls, get) -> "ModelConfig":

@@ -11,6 +11,8 @@ time from the `ModelConfig` and the tree. Every forward is the same few boxes:
 
     _embed
       -> _run_layers            the ONE function that scans layers
+           (a looped model, docs/OURO.md: `loop_passes` times over all of
+           the following, the final norm closing each pass)
            for each stack       (`_layer_stacks`: `dense_layers`, `layers`)
              lax.scan over the periods of its pattern (`stack_pattern`;
              a model without a pattern: one kind, a layer a trip)
@@ -151,6 +153,13 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict
     if config.qk_norm_per_head:     # over each head's `hd` (`_attention`)
         params["layers"]["q_norm"] = jnp.ones((L, hd), dtype)
         params["layers"]["k_norm"] = jnp.ones((L, hd), dtype)
+    if config.branch_norms:     # (a plain stack with four norms a layer: Ouro)
+        params["layers"]["attn_branch_norm"] = jnp.ones((L, D), dtype)
+        params["layers"]["mlp_branch_norm"] = jnp.ones((L, D), dtype)
+    if config.loop_passes > 1:  # a looped model's exit gate (docs/OURO.md)
+        params["early_exit_gate"] = {
+            "kernel": dense(next(keys), (D, 1)),
+            "bias": jnp.zeros((1,), dtype)}
     return params
 
 
@@ -1192,7 +1201,11 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
         gate = (_proj(h, layer_params, lora_layer, "g_proj", lora_scale)
                 if config.attention_gate else None)
-        if leaves.in_place:     # a value is what it was: only where it lies
+        # (a looped model fences too: with heads x head_dim = hidden the
+        # chip's compiler relaid the three [48, 2048, 2048] stacks whole at
+        # every call, a decode chunk's included, 1.2 GB of temporaries;
+        # compiled for a described v5e, PR 55)
+        if leaves.in_place or config.loop_passes > 1:
             q, k, v, gate = jax.lax.optimization_barrier((q, k, v, gate))
         if config.qk_norm:
             # OLMoE: over the whole projection width, before the head split
@@ -1974,6 +1987,14 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
     runs inside the scan body before the layer math: the FSDP hook: scanned
     param slices enter as shards and are all-gathered one layer at a time.
 
+    A LOOPED model (`config.loop_passes` > 1, docs/OURO.md) passes the whole
+    of this `loop_passes` times: an outer `lax.scan` over the passes
+    around the layer scan, `x` and the caches in ITS carry too, a layer's
+    cache index `pass * L + i` (a slot a pass a layer), and the model's final
+    norm after each pass (`_close_pass`: the head then norms no more,
+    `_final_norm`). With one pass none of it is staged: every other model's
+    program is what it was.
+
     Returns `(x, the updated caches | None, aux)`; `aux` is the expert
     stack's stacked router record (`_mlp`), from the uncached forward always
     and from a cached one where `cached_aux` asks for it (else None).
@@ -1997,9 +2018,20 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
     # (the leaves of the kind that keeps a state and no pages)
     own = "lightning" if config.linear_layers else "conv"
     split = config.conv_layers + config.linear_layers > 0
-    before = [0, 0, 0]      # layers of each group in the stacks so far
-    aux = None
-    for tree, lora, start, count in _layer_stacks(params):
+
+    def one_pass(x, caches, offset=None):
+        """Every stack's scan, once; `offset`: what a looped model's pass
+        adds to a layer's cache index."""
+        before = [0, 0, 0]      # layers of each group in the stacks so far
+        aux = None
+        for stack in _layer_stacks(params):
+            x, caches, stack_aux = one_stack(x, caches, before, offset, *stack)
+            aux = aux if stack_aux is None else stack_aux
+        return x, caches, aux
+
+    def one_stack(x, caches, before, offset, tree, lora, start, count):
+        """One stack's scan: `(x, caches, its stacked aux | None)`; `before`
+        (the layers of each cache group in the stacks so far) moves on."""
         pattern = config.stack_pattern(start, count)
         p = len(pattern)
         n = count // p
@@ -2040,6 +2072,8 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
             index = None        # nothing of such a layer is addressed by it
         elif plain and cached and start:
             index = index + start       # over every layer: the cache's
+        if offset is not None and index is not None:
+            index = index + offset      # a looped model's slot a pass a layer
         first = tuple(before)
 
         def body(carry, inp):
@@ -2101,14 +2135,41 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
             body = _rematerialized(config, body)
         (x, caches), stack_aux = jax.lax.scan(
             body, (x, caches), (*xs[:2], index, *xs[2:]))
-        if stack_aux is not None:   # a pattern's [n / p, p, ...] -> [n, ...]
-            aux = stack_aux if plain else jax.tree.map(
+        if stack_aux is not None and not plain:     # [n / p, p, ...] -> [n, ...]
+            stack_aux = jax.tree.map(
                 lambda a: a.reshape((n * p,) + a.shape[2:]), stack_aux)
         for g in range(3):
             before[g] += n * per_period[g]
+        return x, caches, stack_aux
+
+    if config.loop_passes == 1:
+        x, caches, aux = one_pass(x, caches)
+    else:
+        def pass_body(carry, t):
+            y, c, _ = one_pass(*carry, _pass_cache_offset(config, t))
+            return (_close_pass(config, params, y), c), None
+
+        (x, caches), aux = jax.lax.scan(     # (no experts: `aux` is None)
+            pass_body, (x, caches),
+            jnp.arange(config.loop_passes, dtype=jnp.int32))
     if cached and plain:
         (caches,) = caches
     return x, caches, aux
+
+
+def _pass_cache_offset(config: ModelConfig, t):
+    """What pass `t` (traced, from 0) of a looped model adds to a layer's
+    cache index: a slot a pass a layer, pass `t` of layer `l` at `t * L + l`
+    (docs/OURO.md; with 0 the passes would share a slot a layer, the paper's
+    "last-step reuse", which tests and benchmark/tools/loop_control.py lay
+    in here to show that the comparison refuses it)."""
+    return t * config.num_hidden_layers
+
+
+def _close_pass(config: ModelConfig, params: dict, y):
+    """The final norm, closing a pass of a looped model."""
+    with jax.named_scope("norm"):
+        return rms_norm(y, params["norm"], config.rms_norm_eps)
 
 
 def _kind_views(config: ModelConfig, mask, q_slot, *, kv_caches=None, index=0,
@@ -2256,9 +2317,18 @@ def unembedding_weight(config: ModelConfig, params: dict) -> jnp.ndarray:
     return params["lm_head"]
 
 
+def _final_norm(config: ModelConfig, params: dict, x: jnp.ndarray):
+    """The model's final norm, where the layers' runner has not applied it:
+    a looped model norms after every pass, the last one too (`_run_layers`),
+    and its head takes that state as it is (docs/OURO.md)."""
+    if config.loop_passes > 1:
+        return x
+    return rms_norm(x, params["norm"], config.rms_norm_eps)
+
+
 def _logits(config: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("head"):
-        x = rms_norm(x, params["norm"], config.rms_norm_eps)
+        x = _final_norm(config, params, x)
         return _times(x @ unembedding_weight(config, params),
                       config.lm_head_multiplier)
 
@@ -2475,7 +2545,7 @@ def padded_forward_hidden(
     if response_context_length is not None:
         x = x[:, response_context_length - 1 : -1]
     with jax.named_scope("head"):   # its matmul is `fused_logprob`'s
-        x = rms_norm(x, params["norm"], config.rms_norm_eps)
+        x = _final_norm(config, params, x)
     return (x, stats) if router_stats else x
 
 
@@ -2505,7 +2575,7 @@ def score_forward(
     """
     x = _padded_hidden(params, config, query_responses, pad_token_id, lora_scale, remat)
     with jax.named_scope("head"):
-        x = rms_norm(x, params["norm"], config.rms_norm_eps)
+        x = _final_norm(config, params, x)
         return (x.astype(jnp.float32) @ params["score"].astype(jnp.float32))
 
 
@@ -2617,8 +2687,10 @@ def init_kv_cache(
             for n in _pattern_caches(config))
         return _with_compressed(config, groups, max_len, dtype) + \
             _state_group(config, batch, dtype)
+    if config.kv_cache_quant == "int8":
+        config.refuse_loop("kv_cache_quant='int8'")
     shape = (
-        config.num_hidden_layers,
+        config.cache_layers,    # (a looped model: a slot a pass a layer)
         batch,
         config.num_key_value_heads,
         max_len,
@@ -2685,8 +2757,10 @@ def init_paged_kv_cache(
             for n, pages in zip(_pattern_caches(config), num_pages))
         return _with_compressed(config, groups, page_size, dtype, True) + \
             _state_group(config, state_rows, dtype)
+    if config.kv_cache_quant == "int8":
+        config.refuse_loop("kv_cache_quant='int8'")
     shape = (
-        config.num_hidden_layers,
+        config.cache_layers,
         num_pages,
         config.num_key_value_heads,
         page_size,

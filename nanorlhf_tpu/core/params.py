@@ -87,6 +87,12 @@ _NORM_KEYS = (
     ("post_attention_layernorm", "post_attention_layernorm"),
 )
 _QK_NORM_KEYS = (("q_norm", "self_attn.q_norm"), ("k_norm", "self_attn.k_norm"))
+# A plain stack's branch norms (`config.branch_norms` outside Trinity's own
+# table below) carry Ouro's names, the one family that has them there
+# (docs/OURO.md): the second norm of each pair norms its BRANCH
+_BRANCH_NORM_KEYS = (("attn_branch_norm", "input_layernorm_2"),
+                     ("mlp_branch_norm", "post_attention_layernorm_2"))
+_EXIT_GATE = "model.early_exit_gate"    # a looped model's (`loop_passes` > 1)
 
 
 def _expert_names(config: ModelConfig):
@@ -109,6 +115,8 @@ def _layer_keys(config: ModelConfig):
     # names, a weight of `head_dim` a layer: assumed, docs/BLOCKDIFF.md)
     norm = _NORM_KEYS + (_QK_NORM_KEYS if config.qk_norm
                          or config.model_type == "sdar_moe" else ())
+    if config.branch_norms:
+        norm = norm + _BRANCH_NORM_KEYS
     return linear, norm
 
 
@@ -525,6 +533,10 @@ def params_from_hf_state_dict(
     if not config.tie_word_embeddings:
         # some HF checkpoints omit lm_head when tied; require it when untied
         params["lm_head"] = cast(sd["lm_head.weight"].T)
+    if config.loop_passes > 1:      # `nn.Linear(hidden, 1)`, with bias
+        params["early_exit_gate"] = {
+            "kernel": cast(sd[f"{_EXIT_GATE}.weight"].T),
+            "bias": cast(sd[f"{_EXIT_GATE}.bias"])}
     return params
 
 
@@ -588,6 +600,9 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
     put("model.norm.weight", params["norm"])
     if not config.tie_word_embeddings:
         put("lm_head.weight", params["lm_head"].T)
+    if config.loop_passes > 1:
+        put(f"{_EXIT_GATE}.weight", params["early_exit_gate"]["kernel"].T)
+        put(f"{_EXIT_GATE}.bias", params["early_exit_gate"]["bias"])
     return sd
 
 
@@ -646,7 +661,7 @@ def export_hf_checkpoint(
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
         "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe",
-        "afmoe", "sdar_moe", "falcon_h1") else (
+        "afmoe", "sdar_moe", "falcon_h1", "ouro") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
@@ -654,7 +669,8 @@ def export_hf_checkpoint(
             "lfm2_moe": "Lfm2MoeForCausalLM",
             "afmoe": "AfmoeForCausalLM",
             "sdar_moe": "SDARMoeForCausalLM",
-            "falcon_h1": "FalconH1ForCausalLM"}[family]
+            "falcon_h1": "FalconH1ForCausalLM",
+            "ouro": "OuroForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -712,6 +728,11 @@ def export_hf_checkpoint(
             norm_eps=config.rms_norm_eps,
             rope_parameters={"rope_theta": config.rope_theta,
                              "rope_type": "default"})
+    elif family == "ouro":
+        hf_config.update(
+            total_ut_steps=config.loop_passes,
+            early_exit_threshold=1.0,   # (the one `_ouro_from_hf` takes)
+            use_sliding_window=False, sliding_window=None, rope_scaling=None)
     elif family == "falcon_h1":
         hf_config.update(
             attn_layer_indices=None, rope_scaling=None,

@@ -952,6 +952,12 @@ class DecodeSession:
         # slots are written and read like any block's, so they are real)
         self.T_max = self.Tp + self.max_tokens + self.block
         self.nb = blocks_per_row(self.T_max, self.page_size)
+        # a looped model (docs/OURO.md): every pass of every layer keeps a
+        # slot of its own (`cache_layers`), under this one table
+        if self.spec_k:
+            config.refuse_loop(f"speculative decode (spec_k={self.spec_k})")
+        if config.spmd_mesh is not None:
+            config.refuse_loop("a mesh under a decode session")
         # a model with window layers (docs/SWA.md): a second pool and table
         # for them, a ring of pages a row
         self.window_layers = config.window_layers
@@ -1964,6 +1970,14 @@ class DecodeSession:
     # ------------------------------------------------------------- #
     # release / introspection
     # ------------------------------------------------------------- #
+
+    @property
+    def pool_reserved_slots(self) -> int:
+        """The slots the live rows' pages reserved, summed over the decode
+        steps counted so far: a row claims its whole budget's `nb` pages at
+        admission (`serving/pool_reserved_slots`; the slots of them that
+        held a token are `global_slots_read`, `serving/pool_live_slots`)."""
+        return self.live_row_steps * self.nb * self.page_size
 
     def iterations(self) -> int:
         """Decode/verify iterations so far: the carry's own counter as

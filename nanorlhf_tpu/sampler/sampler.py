@@ -214,6 +214,10 @@ def compose_check(sampling: SamplingParams, *,
         with spec_k > 0 (no state rollback) or page_size > 0 (the rollout
         scheduler keeps no state that is not a page).
 
+      * a looped model (`config.loop_passes` > 1, docs/OURO.md) with
+        spec_k > 0, an int8 cache or a mesh: none is built or tested for a
+        stack that every token passes several times.
+
       * a model that generates by diffusion over blocks
         (`config.block_generation`, docs/BLOCKDIFF.md) under ANY rollout:
         every path here takes one token a row a step in order and keeps
@@ -224,6 +228,15 @@ def compose_check(sampling: SamplingParams, *,
     the per_row flag the engine sets, not on SamplingParams."""
     if config is not None:
         config.refuse_block_generation("the rollout sampler")
+    if config is not None and config.loop_passes > 1:
+        # (docs/OURO.md "Refused by name": a looped model rolls out on the
+        # exact cache of one device, contiguous or paged)
+        if sampling.spec_k > 0:
+            config.refuse_loop(f"speculative decode (spec_k={sampling.spec_k})")
+        if config.kv_cache_quant == "int8":
+            config.refuse_loop("kv_cache_quant='int8'")
+        if config.spmd_mesh is not None:
+            config.refuse_loop("a rollout under a mesh")
     if config is not None and config.state_layers and sampling.spec_k > 0:
         raise NotImplementedError(
             f"speculative decode (spec_k={sampling.spec_k}) on "

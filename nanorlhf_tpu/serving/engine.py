@@ -713,6 +713,10 @@ class ServingEngine:
             "serving/pool_donated": self._sess.pool_donated,
             "serving/kv_bytes_per_token": self._sess.kv_bytes_per_token,
             "serving/latent_cache": self._sess.latent_cache,
+            "serving/loop_passes_per_token": self._sess.config.loop_passes,
+            "serving/cache_layers": self._sess.config.cache_layers,
+            "serving/pool_reserved_slots": self._sess.pool_reserved_slots,
+            "serving/pool_live_slots": self._sess.global_slots_read,
             # a page pool of two kinds (docs/SWA.md): 0 window layers and an
             # empty window pool for every model without them
             "serving/window_layers": self._sess.window_layers,

@@ -68,16 +68,19 @@ def peak_flops_per_chip(device_kind: str, backend: str) -> tuple[float, bool]:
         "PEAK_FLOPS_PER_CHIP (telemetry/mfu.py) with its source")
 
 
-def flops_param_count(params: dict) -> int:
+def flops_param_count(params: dict, loop_passes: int = 1) -> int:
     """Parameter count for the 2N-per-token FLOPs model: the base policy
     tree without LoRA adapters (adapter FLOPs are a rounding error at
     production ranks, and excluding them keeps fused/LoRA configs on the
-    same denominator as full fine-tuning)."""
+    same denominator as full fine-tuning). A looped model's token passes the
+    layer stacks `loop_passes` times (docs/OURO.md), so their parameters
+    count that often and the embedding and the head once."""
     import jax
     import numpy as np
 
     return sum(
         int(np.prod(x.shape))
+        * (loop_passes if k in ("layers", "dense_layers") else 1)
         for k, v in params.items() if k != "lora"
         for x in jax.tree.leaves(v)
     )

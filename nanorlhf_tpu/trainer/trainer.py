@@ -695,7 +695,8 @@ class RLTrainer:
         )
         self._telemetry_dir = config.telemetry_dir or config.output_dir
         # analytic model-FLOPs inputs (telemetry/mfu.py's napkin model)
-        self._flops_params = flops_param_count(self.params)
+        self._flops_params = flops_param_count(self.params,
+                                                self.mcfg.loop_passes)
         self._peak_flops, self._peak_flops_known = peak_flops_per_chip(
             jax.devices()[0].device_kind, jax.default_backend()
         )

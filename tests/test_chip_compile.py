@@ -1586,3 +1586,126 @@ def test_sala_session_programs_fit_the_chip_with_pages_keys_and_state_in_place_o
         assert m.temp_size_in_bytes <= 0.75e9, m.temp_size_in_bytes
     else:
         assert "paged_prefill_attention" in calls, calls
+
+
+def _ouro_session_program(case, v5e):
+    """The `serve-ouro-tutor` cell's decode chunk or its largest admission
+    forward (a 256-token prompt, one piece), lowered for a described v5e at
+    the configuration file's own sizes (Ouro-2.6B whole: 48 layers passed 4
+    times, 192 cache layers) and the cell's engine sizes: `(compiled, cache
+    shapes, config)`."""
+    import json
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.serving import radix
+
+    bench = os.path.join(REPO, "benchmark")
+    with open(os.path.join(bench, "configs", "ouro-2.6b.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "tutor-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new = eng["rows"], eng["prompt_len"], eng["max_new_tokens"]
+    nb = (Tp + new) // PAGE
+    # (one row's spare pages: the radix pool's at headroom 0)
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, R * nb + nb, PAGE, jnp.bfloat16))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, spec((R, nb), jnp.int32),
+            spec((R,), jnp.float32), spec((R,), jnp.float32),
+            spec((R,), jnp.bool_), spec((R,), jnp.int32), Tp=Tp,
+            max_tokens=new, page_size=PAGE, sync_every=eng["sync_every"],
+            eos_token_id=1, pad_token_id=0, temperature=1.0, top_p=1.0,
+            greedy=False, lora_scale=1.0, top_k=64, capture_logprobs=False,
+            approx_top_k=True)
+    else:
+        lowered = radix.suffix_logits.lower(
+            params, cfg, spec((1, Tp), jnp.int32), spec((1, Tp), jnp.int32),
+            spec((1,), jnp.int32), spec((), jnp.int32),
+            spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip),
+            spec((nb,), jnp.int32), page_size=PAGE, lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", ["decode_chunk", "admission"])
+def test_ouro_session_programs_carry_the_pool_through_both_loops_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 55, asked of the chip's compiler at the `serve-ouro-tutor`
+    cell's own shapes (5.34 GB of bf16 weights; 8 rows of 640 slots, pages of
+    128 and one row's spare, each pool leaf `bf16[192,45,16,128,128]`, 4.53
+    GB): the pool rides
+    TWO nested loop carries now (the passes around the layers, and in the
+    chunk the decode steps around both). The session's decode chunk and its
+    256-token admission forward each alias both pool leaves from their
+    parameters to their results, hold no `copy` of a leaf anywhere (neither
+    loop's carry sets one down), read the pages through the in-place kernel
+    (the chunk; the admission gathers its own row's 5 pages), and FIT: arguments
+    + temporaries + results less what they alias under 15 GB."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _ouro_session_program(case, v5e)
+    assert (cfg.loop_passes, cfg.cache_layers) == (4, 192)
+    hlo = compiled.as_text()
+    (pool,) = hlo_stacks(cache)
+    assert pool == ("bf16", (192, 45, 16, PAGE, 128))
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    pool_bytes = 2 * 2 * int(np.prod(pool[1]))
+    assert pool_bytes == 45 * 192 * 2 ** 20 == 9_059_696_640
+    assert 14.3e9 < m.argument_size_in_bytes < 14.5e9     # weights + pool
+    assert m.alias_size_in_bytes >= pool_bytes            # both leaves donated
+    assert peak < 15e9, (case, peak, m.temp_size_in_bytes)
+    assert m.temp_size_in_bytes < 1.0e9, (case, m.temp_size_in_bytes)
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo.splitlines()[0])}
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo, re.M).group(1)
+    leaves = {int(rest.split(")")[0]) for _, result, op, rest in comps[entry]
+              if op == "parameter" and _shapes(result)[:1] == [pool]}
+    assert len(leaves) == 2 and leaves <= aliased, (leaves, aliased)
+    copies = [f"{name}: {result} {op}" for name, instrs in comps.items()
+              for _, result, op, _ in instrs
+              if op.startswith("copy") and pool in _shapes(result)]
+    assert not copies, "\n".join(copies)
+    # a layer's slab of the pool ([45, 16, 128, 128]) is never set down
+    slabs = [f"{name}: {result} {op}" for name, instrs in comps.items()
+             for _, result, op, _ in instrs
+             if not result.startswith("(") and _shapes(result)
+             and _shapes(result)[0][1] == pool[1][1:]]
+    assert not slabs, "\n".join(slabs)
+    calls = [line.strip().split(" ")[0] for line in hlo.splitlines()
+             if re.match(r"\s*%(attn\.|paged_prefill)[\w.]* = \S+ custom-call\(",
+                         line)]
+    if case == "decode_chunk":
+        assert any(c.startswith("%attn.read") for c in calls), calls
+        assert _live_row_write_calls(hlo) == 1
+    else:
+        # (a model of one kind reads an admission's pages as
+        # `serve-1.5b-chat` does: the row's own 5 pages gathered, never the
+        # pool; its 256 tokens go in as whole pages under `attn.write`)
+        assert hlo.count("/attn.write/scatter") >= 2
+        assert not re.findall(r"bf16\[45,16,640,128\]", hlo)
+    # the loop is in the program: the pool rides the passes' carry round the
+    # layers' (and in the chunk the decode steps' round both)
+    loops = [name for name, instrs in comps.items() for _, result, op, _ in instrs
+             if op == "while" and _shapes(result).count(pool) == 2]
+    assert len(loops) == (3 if case == "decode_chunk" else 2), loops

@@ -33,7 +33,10 @@ def loop_layers(config, params, x, cos, sin, views, kv_caches=None,
     laid end to end: a `_layer_body`, `_cache_write` or `_layer_slab` that
     read or wrote another layer's slab under the scan shows against this.
     The expert kernels are addressed in place where the runner addresses
-    them in place."""
+    them in place. A looped model (docs/OURO.md) goes through its stack
+    `loop_passes` times, the final norm after each, and a pass's layers see
+    the one-layer stacks that FOLLOW the pass before's: a slot a pass a
+    layer, laid end to end."""
     cached = kv_caches is not None
     plain = config.attention_pattern is None
     groups = ()
@@ -41,42 +44,45 @@ def loop_layers(config, params, x, cos, sin, views, kv_caches=None,
         groups = (tuple(kv_caches),) if plain else tuple(kv_caches)
     written = [[] for _ in groups]  # each group's one-layer stacks, in order
     seen, aux = [0, 0, 0], None     # layers of each cache group so far
-    for tree, lora, start, count in M._layer_stacks(params):
-        tree = dict(tree)
-        experts = None
-        if cached or not plain or M.use_expert_kernel(config):
-            experts = tree.pop("experts", None)
-        own = [0, 0]    # this stack's attention layers and conv layers so far
-        # (the leaves of the kind that keeps a state and no pages)
-        stateful = "lightning" if config.linear_layers else "conv"
-        auxes = []
-        for at, kind in enumerate(config.layer_kinds[start:start + count]):
-            g = M._kind_group(kind)
-            layer_params = {}
-            for name, leaf in tree.items():
-                mine = name == stateful or name in M._ATTENTION_LEAVES
-                if config.conv_layers + config.linear_layers and mine:
-                    if (name == stateful) == (g == 2):
-                        layer_params[name] = jax.tree.map(
-                            lambda a: a[own[int(g == 2)]], leaf)
-                else:
-                    layer_params[name] = jax.tree.map(lambda a: a[at], leaf)
-            own_cache = None
-            if cached:
-                own_cache = tuple(c[seen[g]:seen[g] + 1] for c in groups[g])
-            x, cache, layer_aux = M._layer_body(
-                config, x, M.LayerLeaves(
-                    layer_params, jax.tree.map(lambda a: a[at], lora),
-                    experts, at),
-                0, kind, views[g], own_cache, cos, sin, lora_scale, attn_fn)
-            if cached:
-                written[g].append(cache)
-            auxes.append(layer_aux)
-            seen[g] += 1
-            own[int(g == 2)] += 1
-        if not cached or cached_aux:
-            stacked = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
-            aux = aux if stacked is None else stacked
+    for _ in range(config.loop_passes):
+        for tree, lora, start, count in M._layer_stacks(params):
+            tree = dict(tree)
+            experts = None
+            if cached or not plain or M.use_expert_kernel(config):
+                experts = tree.pop("experts", None)
+            own = [0, 0]    # this stack's attention layers and conv layers so far
+            # (the leaves of the kind that keeps a state and no pages)
+            stateful = "lightning" if config.linear_layers else "conv"
+            auxes = []
+            for at, kind in enumerate(config.layer_kinds[start:start + count]):
+                g = M._kind_group(kind)
+                layer_params = {}
+                for name, leaf in tree.items():
+                    mine = name == stateful or name in M._ATTENTION_LEAVES
+                    if config.conv_layers + config.linear_layers and mine:
+                        if (name == stateful) == (g == 2):
+                            layer_params[name] = jax.tree.map(
+                                lambda a: a[own[int(g == 2)]], leaf)
+                    else:
+                        layer_params[name] = jax.tree.map(lambda a: a[at], leaf)
+                own_cache = None
+                if cached:
+                    own_cache = tuple(c[seen[g]:seen[g] + 1] for c in groups[g])
+                x, cache, layer_aux = M._layer_body(
+                    config, x, M.LayerLeaves(
+                        layer_params, jax.tree.map(lambda a: a[at], lora),
+                        experts, at),
+                    0, kind, views[g], own_cache, cos, sin, lora_scale, attn_fn)
+                if cached:
+                    written[g].append(cache)
+                auxes.append(layer_aux)
+                seen[g] += 1
+                own[int(g == 2)] += 1
+            if not cached or cached_aux:
+                stacked = jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+                aux = aux if stacked is None else stacked
+        if config.loop_passes > 1:
+            x = M.rms_norm(x, params["norm"], config.rms_norm_eps)
     if not cached:
         return x, None, aux
     # (a group none of whose layers ran, a model's empty stacks, stays)
@@ -318,7 +324,8 @@ _KINDS = {"qwen2": ModelConfig.qwen2_tiny, "olmoe": ModelConfig.olmoe_tiny,
           "axk1": ModelConfig.axk1_tiny,
           "smallthinker": ModelConfig.smallthinker_tiny,
           "lfm2": ModelConfig.lfm2_tiny, "trinity": ModelConfig.trinity_tiny,
-          "sala": ModelConfig.minicpm_sala_tiny}
+          "sala": ModelConfig.minicpm_sala_tiny,
+          "ouro": ModelConfig.ouro_tiny}
 
 
 def _every_kind(monkeypatch, kind, cache):
