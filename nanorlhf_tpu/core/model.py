@@ -2925,6 +2925,16 @@ def decode_step(
     return logits, new_caches
 
 
+# The positions `decode_verify(logits_at=)` hands the head for the one a row
+# that is sampled: a sublane's worth that ends at it. A one-row product is no
+# matmul to the TPU's compiler: it multiplies in bf16 on the vector unit and
+# sums the rounded products, 1.6e-3 of the logits' scale from the row the
+# whole bucket's matmul gave. Eight rows are that matmul, bit for bit, at the
+# same weight stream (3.73 against 3.61 ms at Falcon-H1's 5,120 x 261,120
+# head, 14.79 over 1,024 positions: PERF.md section 6, PR 56).
+_HEAD_ROWS = 8
+
+
 def decode_verify(
     params: dict,
     config: ModelConfig,
@@ -2947,6 +2957,10 @@ def decode_verify(
                                   # length; None: what the row holds after
                                   # them). Only a sparse layer reads it
                                   # (core/sala.py: `sparse_dense_len`)
+    logits_at=None,               # [B] int32: the ONE candidate a row whose
+                                  # logits the caller samples (None: all Tq).
+                                  # The head then runs over `_HEAD_ROWS`
+                                  # positions that end at it, not over Tq
 ):
     """Batched k-token verification for speculative decode
     (sampler/speculative.py): one small-T causal forward over Tq = k+1
@@ -2971,6 +2985,11 @@ def decode_verify(
     (None, new caches) — the chunked-prefill path (sampler/paged/session.py)
     runs every non-final prompt chunk purely for its KV writes, and at LLM
     vocabularies the unread [B, Tq, V] projection would dominate the chunk.
+    `logits_at` [B] returns (logits [B, V], new caches), row b's those of
+    candidate `logits_at[b]`: an admission's closing forward
+    (serving/radix.py::suffix_logits) samples one position of its bucket, so
+    the head is a weight stream over `_HEAD_ROWS` positions a row and no
+    [B, Tq, V] product.
     """
     B, Tq = tokens.shape
     # the logical width is the key_mask width — equal to the slab's T_max on
@@ -3012,6 +3031,16 @@ def decode_verify(
                                    kv_caches, lora_scale)
     if not want_logits:
         return None, new_caches
+    if logits_at is not None:
+        # before the head: its norm, weight and multiplier see the kept rows
+        n = min(_HEAD_ROWS, Tq)
+        at = logits_at.astype(jnp.int32)
+        first = jnp.clip(at - (n - 1), 0, Tq - n)
+        x = jax.vmap(lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, n))(
+            x, first)
+        logits = _logits(config, params, x)                  # [B, n, V]
+        return jax.vmap(lambda row, i: jax.lax.dynamic_index_in_dim(
+            row, i, keepdims=False))(logits, at - first), new_caches
     return _logits(config, params, x), new_caches
 
 

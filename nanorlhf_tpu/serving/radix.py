@@ -529,7 +529,9 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
     the row's block table and returns the last REAL token's next-token
     logits ([V]) — `last` indexes past the bucket-padding tail, whose
     garbage KV lands in decode-region slots that the decode loop
-    overwrites before ever marking them attendable. The caller buckets
+    overwrites before ever marking them attendable. The head is asked for
+    position `last` alone (`decode_verify(logits_at=)`): a weight stream,
+    not the bucket's `[Sb, V]` product. The caller buckets
     suffix lengths (`bucket_len`) so retraces stay logarithmic. `call_keys`
     [1]: the whole prompt's length, which a model with sparse-attention
     layers asks of a piece (`decode_verify`)."""
@@ -542,8 +544,9 @@ def suffix_logits(params, config, suffix_ids, positions, fill, last,
         **({"token_valid": jnp.arange(suffix_ids.shape[1])[None, :] <= last}
            if config.state_layers else {}),
         call_keys=call_keys,
+        logits_at=jnp.reshape(last, (1,)),
     )
-    return jnp.take(logits[0], last, axis=0), caches
+    return logits[0], caches
 
 
 def bucket_len(n: int, cap: int) -> int:

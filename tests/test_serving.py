@@ -13,8 +13,10 @@ step under NANORLHF_LOCK_CHECK=1, so every engine/radix lock acquisition
 is order-checked live.
 """
 
+import dataclasses
 import http.client
 import json
+import re
 import time
 import urllib.error
 import urllib.request
@@ -26,7 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from nanorlhf_tpu.core import ModelConfig, init_params
-from nanorlhf_tpu.core.model import init_paged_kv_cache, prefill
+from nanorlhf_tpu.core.model import (
+    decode_verify, init_kv_cache, init_paged_kv_cache, prefill,
+)
 from nanorlhf_tpu.sampler import SamplingParams, generate
 from nanorlhf_tpu.sampler.paged.pages import (
     init_page_state, release_row,
@@ -290,6 +294,143 @@ def test_suffix_logits_match_full_prefill(tiny):
     hidden = km.copy()
     hidden[0, m - 1] = False
     assert np.abs(np.asarray(suffix(hidden)) - want).max() > 1e4 * tol
+
+
+# --------------------------------------------------------------------- #
+# the closing forward's head sees the one position it samples (ISSUE 56):
+# the [V] row is row `last` of the all-positions product, for every kind
+# of model the session serves
+# --------------------------------------------------------------------- #
+
+def _one_position_configs():
+    tiny = ModelConfig.qwen2_tiny(vocab_size=128)
+    return {
+        "gqa_tied": tiny,
+        "mla": ModelConfig.axk1_tiny(vocab_size=128),
+        "conv_state": ModelConfig.lfm2_tiny(vocab_size=128, layers=4),
+        "ssm_state": ModelConfig.falcon_h1_tiny(vocab_size=128),
+        "sparse": ModelConfig.minicpm_sala_tiny(vocab_size=128),
+        "looped": ModelConfig.ouro_tiny(vocab_size=128),
+        "head_multiplier": dataclasses.replace(
+            tiny, tie_word_embeddings=False, lm_head_multiplier=0.37),
+    }
+
+
+def _cold_row(config, Sb, s_real, P=8):
+    """A cold row's closing forward as `session._admit_now` hands it to
+    `suffix_logits`: `((ids, positions, fill), key mask, caches, row table,
+    (call_keys,) or ())` for a bucket of `Sb` tokens of which `s_real` are
+    the prompt's."""
+    nb = 2 * Sb // P
+    table = jnp.arange(nb, dtype=jnp.int32)
+    if config.attention_pattern is None:
+        caches = init_paged_kv_cache(config, nb, P, jnp.float32)
+    else:
+        state = config.state_layers
+        caches = init_paged_kv_cache(
+            config, (nb, 1), P, jnp.float32,
+            **({"state_rows": 1} if state else {}))
+        table = ((table, jnp.zeros((1,), jnp.int32))
+                 + ((jnp.zeros((1,), jnp.int32),) if state else ()))
+    ids = np.zeros((1, Sb), np.int32)
+    ids[0, :s_real] = np.random.default_rng(Sb + s_real).integers(
+        4, 128, s_real)
+    args = (jnp.asarray(ids), jnp.arange(Sb, dtype=jnp.int32)[None],
+            jnp.zeros((1,), jnp.int32))
+    keys = ((jnp.asarray([s_real], jnp.int32),)
+            if config.sparse_layers else ())
+    return args, jnp.zeros((1, 2 * Sb), bool), caches, table, keys
+
+
+@pytest.mark.parametrize("where", ["inside", "end"])
+@pytest.mark.parametrize("kind", list(_one_position_configs()))
+def test_suffix_logits_are_the_sampled_row_of_every_position(kind, where):
+    """`suffix_logits` hands the head position `last` alone
+    (`decode_verify(logits_at=)`); what comes back is row `last` of the
+    product over the whole bucket, to 1e-5 of the logits' scale (two
+    programs), with `last` before the bucket's pads and at its end: the
+    final norm (a looped model has none), a tied or untied head and its
+    multiplier see the kept row as they saw every row. The sparse model's
+    bucket holds more keys than `sparse_dense_len`, so the row selects."""
+    config = _one_position_configs()[kind]
+    params = init_params(config, jax.random.PRNGKey(3), jnp.float32)
+    if "norm" in params:        # (ones as initialised: a norm left out
+        params = {**params,     # would read the same)
+                  "norm": 1.0 + 0.3 * jax.random.normal(
+                      jax.random.PRNGKey(4), params["norm"].shape)}
+    Sb = 128 if config.sparse_layers else 16
+    s_real = Sb if where == "end" else Sb - 5
+    args, km, caches, table, keys = _cold_row(config, Sb, s_real)
+    last = s_real - 1
+    got, _ = suffix_logits(params, config, *args, jnp.int32(last), km,
+                           caches, table, *keys, page_size=8, lora_scale=1.0)
+    # (jitted, as the suffix program is: run eagerly, a looped model's three
+    # passes alone are 7e-5 of the scale from either program)
+    every, _ = jax.jit(lambda p, *a: decode_verify(
+        p, config, *a,
+        page_table=jax.tree.map(lambda t: t[None, :], table), page_size=8,
+        **({"token_valid": jnp.arange(Sb)[None, :] <= last}
+           if config.state_layers else {}),
+        **({"call_keys": keys[0]} if keys else {})))(
+            params, *args, km, caches)
+    assert got.shape == (128,) and every.shape == (1, Sb, 128)
+    want = np.asarray(every[0, last])
+    tol = 1e-5 * np.abs(want).max()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=tol)
+    # (and no other row's: its neighbour is far off)
+    assert np.abs(np.asarray(every[0, last - 1]) - want).max() > 1e3 * tol
+
+
+def test_logits_at_takes_a_position_a_row(tiny):
+    """`decode_verify(logits_at=)` over several rows, each its own position,
+    the first under a sublane's worth of rows from the start (the slice of
+    `_HEAD_ROWS` positions is then clipped to the candidates' start)."""
+    config, params = tiny
+    B, Tq, T_max = 3, 16, 32
+    toks = jnp.asarray(np.random.default_rng(0).integers(4, 128, (B, Tq)),
+                       jnp.int32)
+    args = (toks, jnp.broadcast_to(jnp.arange(Tq)[None], (B, Tq)),
+            jnp.zeros((B,), jnp.int32), jnp.zeros((B, T_max), bool),
+            init_kv_cache(config, B, T_max, jnp.float32))
+    every, _ = decode_verify(params, config, *args)
+    at = jnp.asarray([2, 15, 9], jnp.int32)
+    got, _ = decode_verify(params, config, *args, logits_at=at)
+    assert got.shape == (B, 128)
+    want = np.asarray(every)[np.arange(B), np.asarray(at)]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def _arrays_by_vocabulary(text, V):
+    """The elements beside the vocabulary axis of every array type in a
+    lowered program that has one: `tensor<1x128x131xf32>` -> 128."""
+    sizes = set()
+    for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]\w*>", text):
+        dims = [int(d) for d in dims.split("x") if d]
+        if V in dims:
+            sizes.add(int(np.prod(dims)) // V)
+    return sizes
+
+
+def test_suffix_program_holds_no_bucket_of_logits():
+    """The lowered closing forward holds no array of `Sb x V` elements (the
+    largest beside the vocabulary axis is the head's weight, `D` wide),
+    while `decode_verify` as speculative verification calls it still makes
+    and returns `[B, Tq, V]`."""
+    V, Sb = 131, 128
+    config = ModelConfig.qwen2_tiny(vocab_size=V)
+    params = init_params(config, jax.random.PRNGKey(0), jnp.float32)
+    args, km, caches, table, _ = _cold_row(config, Sb, Sb - 3)
+    suffix = suffix_logits.lower(
+        params, config, *args, jnp.int32(Sb - 4), km, caches, table,
+        page_size=8, lora_scale=1.0).as_text()
+    held = _arrays_by_vocabulary(suffix, V)
+    assert held and max(held) == config.hidden_size < Sb, held
+    verify = jax.jit(lambda p, *a: decode_verify(
+        p, config, *a, page_table=table[None], page_size=8)).lower(
+            params, *args, km, caches)
+    assert Sb in _arrays_by_vocabulary(verify.as_text(), V)
+    assert verify.out_info[0].shape == (1, Sb, V)
 
 
 # --------------------------------------------------------------------- #
