@@ -45,6 +45,106 @@ _SALA_PUBLISHED = (
     "s" + "l" * 8 + "s" + "l" * 6 + "ss" + "l" * 4 + "s" + "l" * 6 + "sss")
 
 
+# The kinds of model a config can be (`ModelConfig.traits`), in the order a
+# refusal names them: the phrase it uses and the document it points to. A new
+# kind of model adds one entry here, one line to `traits`, and its name to the
+# rows of MECHANISMS that are built for it: every other row refuses it.
+TRAITS = {
+    "window": ("a model with window layers", "docs/SWA.md"),
+    "sparse": ("a model with sparse-attention layers", "docs/SALA.md"),
+    "conv": ("a model with conv layers", "docs/STATE.md"),
+    "ssm": ("a model with state-space layers", "docs/SSM.md"),
+    "linear": ("a model with linear-attention layers", "docs/SALA.md"),
+    "latent": ("a model with latent attention", "docs/MLA.md"),
+    "block": ("a model that generates by diffusion over blocks",
+              "docs/BLOCKDIFF.md"),
+    "loop": ("a looped model", "docs/OURO.md"),
+}
+
+
+def _takes(kinds: str, why: str) -> tuple:
+    return frozenset(kinds.split()), why
+
+
+# What each mechanism takes: the kinds it is built and tested for, and why it
+# takes no other (docs/PAGED_CACHE.md "Which mechanism takes which kind of
+# model" renders this table). An allow-list: `ModelConfig.require` refuses a
+# kind a row does not list, so a kind nobody thought of is refused everywhere.
+# A model of no kind (one stack of global attention layers, with experts or
+# without) is taken by every row.
+MECHANISMS = {
+    "a rollout": _takes(
+        "window sparse conv ssm linear latent loop",
+        "every rollout path takes one token a row a step in order and keeps "
+        "next-token log-probabilities; a model that generates otherwise is "
+        "served through a serving-mode DecodeSession only"),
+    "decode_step": _takes(
+        "window sparse conv ssm linear latent loop",
+        "it yields one token a row a forward, in order"),
+    # the paged one-jit rollout's identity table, the rollout scheduler
+    # (a session with per_row=False) and a session without a RadixCache
+    "a page pool of one kind": _takes(
+        "latent loop",
+        "the identity table of the paged one-jit rollout and the rollout "
+        "scheduler's batched bootstrap and device free list hand on pages "
+        "of one pool: they keep no state that is not a page, and no page "
+        "pool of two kinds (num_pages=(global, window), which the decode "
+        "session builds). Such a model rolls out on the contiguous cache "
+        "and decodes in a serving session only (per_row=True, pages handed "
+        "out by the engine's RadixCache)"),
+    "the serving session": _takes(
+        "window sparse conv ssm linear latent block loop",
+        "a kind it does not list may keep pages or a state a row that the "
+        "session would not size, reset or hand on"),
+    "a radix prefix hit": _takes(
+        "latent loop",
+        "the tree holds pages of the global kind only: no window pages, no "
+        "snapshot of the conv state and no snapshot of the recurrent state "
+        "at the prefix's end, and no block that has not committed; a "
+        "session inserts no page of such a model, so none can occur"),
+    "speculative decode": _takes(
+        "latent",
+        "a verify forward writes every candidate: a state is advanced past "
+        "them all and a rejected draft needs it rolled back to the last "
+        "accepted token (no such rollback is built), the verify reads have "
+        "no window and no pass of a loop, and a block is no run of "
+        "next-token drafts; a model with a pattern of layers decodes in a "
+        "serving session only, without speculation"),
+    # (a latent cache raises ValueError first: `model._latent_cache_shape`)
+    "kv_cache_quant='int8'": _takes(
+        "",
+        "the int8 reads take one table of one kind of (k, v) cache, with no "
+        "lower bound, no block read and no slot a pass"),
+    "a rollout under a mesh": _takes(
+        "window sparse conv ssm linear latent",
+        "not built or tested for a stack every token passes several times"),
+    "a mesh under a decode session": _takes(
+        "window sparse latent",
+        "a state's rows have no sharding rule and its updates no "
+        "partitioned form, nor have the block read and the rows' block "
+        "state, nor is a loop of passes built or tested under one"),
+    "the sequence-parallel forward": _takes(
+        "block",
+        "ring attention exchanges whole per-head K and V blocks: it has no "
+        "window, no latent, no chosen blocks that lie on any device, no "
+        "state and no loop of passes"),
+    "a LoRA adapter": _takes(
+        "window sparse conv latent loop",
+        "nothing trains the model here (a state-space or linear-attention "
+        "mixer's scan has no backward, and its projections are no target; "
+        "no RL objective is published for a policy that generates by "
+        "blocks)"),
+    "training": _takes(
+        "window sparse latent loop",
+        "training a model with conv, state-space or linear-attention layers "
+        "is not built (the update's packed rows have no boundary the "
+        "convolution stops at, its backward under the sparse trainer's "
+        "packing is not written, nor the recurrent scans' at all, and a mesh "
+        "has no rule for the state), and no RL objective is published for a "
+        "policy that generates by blocks; the model is served"),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int = 151936
@@ -317,15 +417,36 @@ class ModelConfig:
         ONE place the product is taken."""
         return self.loop_passes * self.num_hidden_layers
 
-    def refuse_loop(self, who: str) -> None:
-        """Raises NotImplementedError, by the model's name, where `who` has
-        no form for a stack that is passed several times."""
-        if self.loop_passes > 1:
+    @property
+    def traits(self) -> frozenset:
+        """The kinds of model this config is (keys of TRAITS): what a
+        mechanism must list to take it (`require`)."""
+        kinds = set(self.layer_kinds)
+        is_ = {
+            "window": any(k not in NAMED_KINDS and k != (False, True)
+                          for k in kinds),
+            "sparse": "sparse" in kinds,
+            "conv": "conv" in kinds,
+            "ssm": "hybrid" in kinds,
+            "linear": "lightning" in kinds,
+            "latent": self.kv_lora_rank > 0,
+            "block": self.block_length > 0,
+            "loop": self.loop_passes > 1,
+        }
+        return frozenset(t for t, has in is_.items() if has)
+
+    def require(self, who: str, mechanism: str = "") -> None:
+        """Raises NotImplementedError where `mechanism` (a row of
+        MECHANISMS; `who` itself where it is one) does not take every kind
+        this model is, naming `who`, the kinds and their documents."""
+        takes, why = MECHANISMS[mechanism or who]
+        lacking = self.traits - takes
+        if lacking:
+            phrases, docs = zip(*(TRAITS[t] for t in
+                                  sorted(lacking, key=list(TRAITS).index)))
             raise NotImplementedError(
-                f"{who} on a looped model ({self.model_type}, loop_passes="
-                f"{self.loop_passes}): not built or tested for a stack "
-                "every token passes several times (docs/OURO.md \"Refused "
-                "by name\")")
+                f"{who} on {' and '.join(phrases)} ({self.model_type}): "
+                f"{why} ({', '.join(dict.fromkeys(docs))})")
 
     @property
     def actual_head_dim(self) -> int:
@@ -427,14 +548,6 @@ class ModelConfig:
         return sum(k == "sparse" for k in self.layer_kinds)
 
     @property
-    def state_what(self) -> str:
-        """The model as a refusal names it, by what keeps the state."""
-        layers = ("state-space layers" if self.ssm_layers else
-                  "linear-attention layers" if self.linear_layers else
-                  "conv layers")
-        return f"a model with {layers} ({self.model_type})"
-
-    @property
     def residual_scale(self) -> float:
         """What each branch is multiplied by as it joins the stream
         (MiniCPM's `scale_depth / sqrt(num_hidden_layers)`, of the PUBLISHED
@@ -485,21 +598,8 @@ class ModelConfig:
         forward in order (docs/BLOCKDIFF.md): a forward yields 0 to
         `block_length` tokens a row, and everything that assumes one token
         a row a step (the contiguous rollout, the trainers, speculation,
-        `decode_step`) refuses the model by name (`refuse_block_generation`)."""
+        `decode_step`) refuses the model by name (`require`)."""
         return self.block_length > 0
-
-    def refuse_block_generation(self, who: str) -> None:
-        """Raises NotImplementedError, by the model's name, where `who`
-        takes one token a row a forward in order."""
-        if self.block_generation:
-            raise NotImplementedError(
-                f"{who} on a model that generates by diffusion over blocks "
-                f"({self.model_type}, block_length={self.block_length}): a "
-                "forward there yields 0 to block_length tokens a row, each "
-                "conditioned on the tokens of its block unmasked so far, and "
-                "a rollout's log-probability is no product of next-token "
-                "terms; it is served through a serving-mode DecodeSession "
-                "only (docs/BLOCKDIFF.md)")
 
     @property
     def live_rows_dispatch(self) -> bool:

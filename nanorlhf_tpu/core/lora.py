@@ -105,14 +105,7 @@ def init_lora_params(
     config: ModelConfig, lora: LoraConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> dict:
     """A ~ N(0, 1/r) (kaiming-ish), B = 0 → adapter starts as identity."""
-    # (an adapter is a trainer's: nothing trains such a model here)
-    config.refuse_block_generation("a LoRA adapter")
-    if config.ssm_layers or config.linear_layers:
-        raise NotImplementedError(
-            f"a LoRA adapter on {config.state_what}: nothing trains the "
-            "model here (the mixer's scan has no backward), and its "
-            "projections (`ssm.in_proj` / `ssm.out_proj`, `lightning.*`) "
-            "are no target (docs/SSM.md, docs/SALA.md)")
+    config.require("a LoRA adapter")
     if config.kv_lora_rank or (config.num_dense_layers
                                and not config.conv_layers):
         # a model of two stacks whose attention leaves lie over every layer

@@ -1130,10 +1130,6 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
                 config, x, h, layer_params["lightning"], cache, layer, view,
                 cos, sin, leaves.at)
         elif config.kv_lora_rank:
-            if attn_fn is not None:
-                raise NotImplementedError(
-                    "latent attention has no sequence-parallel form: ring "
-                    "attention exchanges per-head K and V (docs/MLA.md)")
             from nanorlhf_tpu.core.mla import mla_attention
 
             out, new_cache = mla_attention(
@@ -1228,10 +1224,6 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
     # (harness/attn_trace.py reads a decode read by it); a model of one
     # kind writes beside `attn.read`
     patterned = config.attention_pattern is not None
-    if patterned and attn_fn is not None:
-        raise NotImplementedError(
-            "window layers have no sequence-parallel form: ring "
-            "attention passes whole K and V blocks round (docs/SWA.md)")
     window = config.sliding_window if window else 0
     with contextlib.ExitStack() as scopes:
         # (a sparse layer names its own steps, as a model of one kind does:
@@ -1248,10 +1240,6 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
                 with jax.named_scope("attn.write"):
                     news = _quantize_kv(k) + _quantize_kv(v)
             new_cache = _cache_write(tuple(cache), news, layer, view)
-        if sparse and attn_fn is not None:
-            raise NotImplementedError(
-                "sparse-attention layers have no sequence-parallel form: a "
-                "query's chosen blocks lie on any device (docs/SALA.md)")
         if compressed is not None:
             from nanorlhf_tpu.core import sala
 
@@ -2380,6 +2368,8 @@ def _hidden_from_inputs(params, config, input_ids, attention_mask, position_ids,
     sparse layer reads it (core/sala.py: a call's keys decide whether its
     queries select); None: the row is one call.
     """
+    if attn_fn is not None:
+        config.require("the sequence-parallel forward")
     attention_mask = attention_mask.astype(bool)
     x = _embed(config, params, input_ids)
     T = input_ids.shape[1]
@@ -2595,15 +2585,19 @@ def _latent_cache_shape(config: ModelConfig, rows: int, slots: int) -> tuple:
     return (config.num_hidden_layers, rows, 1, slots, config.latent_width)
 
 
+def _int8_cache(config: ModelConfig) -> bool:
+    """Whether the (k, v) cache being built stores int8 values and scales:
+    where its spec is chosen (a latent cache has raised before,
+    `_latent_cache_shape`)."""
+    if config.kv_cache_quant != "int8":
+        return False
+    config.require("kv_cache_quant='int8'")
+    return True
+
+
 def _pattern_caches(config: ModelConfig) -> tuple:
     """(global layers, window layers) of a pattern model: the depths of its
-    two groups of cache stacks. It has no int8 form."""
-    if config.kv_cache_quant == "int8":
-        raise NotImplementedError(
-            "kv_cache_quant='int8' on a model with window layers or a "
-            f"state ({config.model_type}) is not implemented: the int8 "
-            "reads take one table of one kind of cache and have no lower "
-            "bound (docs/SWA.md, docs/STATE.md)")
+    two groups of cache stacks."""
     return (config.num_hidden_layers - config.window_layers
             - config.conv_layers - config.linear_layers, config.window_layers)
 
@@ -2678,6 +2672,7 @@ def init_kv_cache(
     """
     if config.kv_lora_rank:
         return (jnp.zeros(_latent_cache_shape(config, batch, max_len), dtype),)
+    int8 = _int8_cache(config)
     if config.attention_pattern is not None:
         # both groups whole: correct by mask, no slot saved (the paged pool
         # is where a window layer keeps a window's pages only)
@@ -2687,8 +2682,6 @@ def init_kv_cache(
             for n in _pattern_caches(config))
         return _with_compressed(config, groups, max_len, dtype) + \
             _state_group(config, batch, dtype)
-    if config.kv_cache_quant == "int8":
-        config.refuse_loop("kv_cache_quant='int8'")
     shape = (
         config.cache_layers,    # (a looped model: a slot a pass a layer)
         batch,
@@ -2696,7 +2689,7 @@ def init_kv_cache(
         max_len,
         config.actual_head_dim,
     )
-    if config.kv_cache_quant == "int8":
+    if int8:
         sshape = shape[:3] + (8, max_len)
         return (
             jnp.zeros(shape, jnp.int8), jnp.ones(sshape, jnp.bfloat16),
@@ -2735,19 +2728,17 @@ def init_paged_kv_cache(
         _latent_cache_shape(config, num_pages, page_size)    # what raises
         return tuple(jnp.zeros(shape, dtype) for shape in
                      mla.paged_cache_shapes(config, num_pages, page_size))
+    int8 = _int8_cache(config)
     if config.attention_pattern is not None:
         # a pool a kind, `num_pages = (global pages, window pages)`: the
         # window layers' holds a window's pages a row, not a row's budget
         if isinstance(num_pages, int):
-            raise NotImplementedError(
-                "a model with window layers has a page pool of two kinds "
-                "(num_pages=(global, window)), which the decode session "
-                "builds; the monolithic paged rollout (page_size > 0 with "
-                "one identity table) is not built for it: use the "
-                "contiguous cache (docs/SWA.md)")
+            config.require("one page pool (num_pages an int)",
+                           "a page pool of one kind")
         if config.state_layers and state_rows <= 0:
             raise ValueError(
-                f"{config.state_what} keeps a state a row beside its "
+                "a model with conv, state-space or linear-attention layers "
+                f"({config.model_type}) keeps a state a row beside its "
                 "pages: init_paged_kv_cache(..., state_rows=rows) "
                 "(docs/STATE.md)")
         KV, hd = _cache_heads(config)
@@ -2757,8 +2748,6 @@ def init_paged_kv_cache(
             for n, pages in zip(_pattern_caches(config), num_pages))
         return _with_compressed(config, groups, page_size, dtype, True) + \
             _state_group(config, state_rows, dtype)
-    if config.kv_cache_quant == "int8":
-        config.refuse_loop("kv_cache_quant='int8'")
     shape = (
         config.cache_layers,
         num_pages,
@@ -2766,7 +2755,7 @@ def init_paged_kv_cache(
         page_size,
         config.actual_head_dim,
     )
-    if config.kv_cache_quant == "int8":
+    if int8:
         sshape = shape[:3] + (8, page_size)
         return (
             jnp.zeros(shape, jnp.int8), jnp.ones(sshape, jnp.bfloat16),
@@ -2887,7 +2876,7 @@ def decode_step(
     """One autoregressive decode step. Returns (logits [B, V], new caches),
     and with `count_experts` a third, [] int32: `moe_mlp`'s `reached`, summed
     over the layers."""
-    config.refuse_block_generation("decode_step (one token a row a step)")
+    config.require("decode_step")
     B = token.shape[0]
     if extent is not None and extent < key_mask.shape[1]:
         # the mask's width is what the XLA read goes by (`_attention_read`); the

@@ -36,7 +36,7 @@ def build_config(sequence_parallel: int = 1,
 
     `rollout_spec_k > 0` turns on draft-free speculative rollout decode
     (sampler/speculative.py, distribution-exact); composes with every knob
-    above except rollout_compaction_segments.
+    above.
 
     `status_port != 0` serves the live run-health endpoints /metrics ·
     /healthz · /statusz on that port (-1 = ephemeral; docs/OBSERVABILITY.md

@@ -56,7 +56,6 @@ def _sp_hidden_local(params, config: ModelConfig, input_ids, attention_mask,
                      attn_impl: str = "xla"):
     """Runs inside shard_map: the shared forward recipe up to the final
     hidden states, attention routed around the ring."""
-    config.refuse_loop("the sequence-parallel forward")
     key_valid = attention_mask.astype(bool)
     ring_attn = _ring_attn_fn(key_valid, axis_name, attn_impl,
                               input_ids.shape[1])
@@ -149,7 +148,6 @@ def _sp_fsdp_forward_local(config, specs, sp_axis, fsdp_axis, lora_scale, remat,
     lm_head lazily after the scan (ZeRO-3 execution model). Gradients flow
     back through all_gather's transpose (reduce-scatter), so grads come out
     sharded exactly like the params."""
-    config.refuse_loop("the sequence-parallel FSDP forward")
     key_valid = attention_mask.astype(bool)
     ring_attn = _ring_attn_fn(key_valid, sp_axis, attn_impl,
                               input_ids.shape[1])

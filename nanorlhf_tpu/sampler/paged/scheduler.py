@@ -2,9 +2,8 @@
 
 The monolithic rollout loop sizes its batch to the WHOLE prompt set and runs
 until the slowest row finishes — a long-tail length distribution leaves most
-rows idle (emitting pads) for most of the loop. Compaction
-(sampler/compaction.py) approximated the fix by shrinking the batch between
-segments; this module does the real thing, the way continuous-batching
+rows idle (emitting pads) for most of the loop. This module recycles a
+finished row's pages to a queued prompt, the way continuous-batching
 servers (vLLM-style) do, but host-driven and offline-batch shaped.
 
 Since the decode-session refactor the mechanism lives in
@@ -186,7 +185,7 @@ def generate_tokens_queued(
     `swap_installs`, and `swap_wait_s`. With no mid-rollout publish the
     poll returns None every chunk and the token stream is bit-identical
     to `weight_refresh=None` (the PRNG stream never sees the callback)."""
-    config.refuse_block_generation("the paged rollout scheduler")
+    config.require("the paged rollout scheduler", "a page pool of one kind")
     Q, Tp = prompt_ids.shape
     R = min(int(decode_rows), Q)
     P = int(page_size)

@@ -799,37 +799,6 @@ class _Flight:
     rows: Optional[jax.Array] = None
 
 
-def _refuse_block_compositions(config, *, per_row, spec_k, prefix_cache,
-                               prefill_chunk) -> None:
-    """What a session of a model that generates by blocks is not built for,
-    by the model's name (docs/BLOCKDIFF.md "what is left")."""
-    what = (f"a model that generates by diffusion over blocks "
-            f"({config.model_type}, block_length={config.block_length})")
-    if spec_k:
-        config.refuse_block_generation(
-            f"speculative decode (spec_k={spec_k})")
-    if not per_row:
-        config.refuse_block_generation(
-            "the paged rollout scheduler (per_row=False)")
-    if prefix_cache is None or not getattr(prefix_cache, "enabled", False):
-        raise NotImplementedError(
-            f"{what} decodes in a serving session whose pages the engine's "
-            "RadixCache hands out (it takes no hit there)")
-    if config.kv_cache_quant == "int8":
-        raise NotImplementedError(
-            f"kv_cache_quant='int8' on {what}: the block read has no int8 "
-            "form (docs/BLOCKDIFF.md)")
-    if config.spmd_mesh is not None:
-        raise NotImplementedError(
-            f"a mesh under a serving session of {what}: the block read and "
-            "the rows' block state have no partitioned form")
-    if prefill_chunk % config.block_length:
-        raise ValueError(
-            f"prefill_chunk={prefill_chunk} on {what}: a prefill piece ends "
-            "on a block's end, so the chunk is a multiple of the block "
-            "length")
-
-
 class DecodeSession:
     """One resident decode batch with uniform per-row state.
 
@@ -908,10 +877,26 @@ class DecodeSession:
         # a model that generates by blocks (docs/BLOCKDIFF.md): its block
         # length, 0 for every autoregressive model
         self.block = int(config.block_length)
-        if self.block:
-            _refuse_block_compositions(
-                config, per_row=per_row, spec_k=spec_k,
-                prefix_cache=prefix_cache, prefill_chunk=prefill_chunk)
+        # which kind of model each mechanism takes: core/config.MECHANISMS
+        if per_row:
+            config.require("the serving session")
+        else:
+            config.require("the rollout scheduler (per_row=False)",
+                           "a page pool of one kind")
+        if spec_k:
+            config.require(f"speculative decode (spec_k={spec_k})",
+                           "speculative decode")
+        if config.spmd_mesh is not None:
+            config.require("a mesh under a decode session")
+        if prefix_cache is None or not getattr(prefix_cache, "enabled", False):
+            config.require("a decode session without a RadixCache",
+                           "a page pool of one kind")
+        if self.block and prefill_chunk % self.block:
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} on a model that generates by "
+                f"blocks of {self.block} ({config.model_type}): a prefill "
+                "piece ends on a block's end, so the chunk is a multiple of "
+                "the block length")
         if per_row and capture_logprobs:
             raise ValueError(
                 "capture_logprobs is incompatible with per-row sampling "
@@ -952,12 +937,8 @@ class DecodeSession:
         # slots are written and read like any block's, so they are real)
         self.T_max = self.Tp + self.max_tokens + self.block
         self.nb = blocks_per_row(self.T_max, self.page_size)
-        # a looped model (docs/OURO.md): every pass of every layer keeps a
-        # slot of its own (`cache_layers`), under this one table
-        if self.spec_k:
-            config.refuse_loop(f"speculative decode (spec_k={self.spec_k})")
-        if config.spmd_mesh is not None:
-            config.refuse_loop("a mesh under a decode session")
+        # (a looped model, docs/OURO.md: every pass of every layer keeps a
+        # slot of its own, `cache_layers`, under this one table)
         # a model with window layers (docs/SWA.md): a second pool and table
         # for them, a ring of pages a row
         self.window_layers = config.window_layers
@@ -966,39 +947,6 @@ class DecodeSession:
         # docs/SSM.md): a state a row beside the pages, which only this
         # serving session keeps right
         self.state_layers = config.state_layers
-        if self.state_layers:
-            what = config.state_what
-            if self.spec:
-                raise NotImplementedError(
-                    f"speculative decode (spec_k={spec_k}) on {what}: a "
-                    "verify forward advances the state past every "
-                    "candidate, and a rejected draft needs it rolled back to "
-                    "the last accepted token; no such rollback is built "
-                    "(docs/STATE.md)")
-            if not self.per_row:
-                raise NotImplementedError(
-                    f"the paged rollout scheduler on {what}: its batched "
-                    "bootstrap and device free list hand rows on without "
-                    "resetting or carrying a state that is not a page; the "
-                    "serving session does (per_row=True; docs/STATE.md)")
-            if config.kv_cache_quant == "int8":
-                raise NotImplementedError(
-                    f"kv_cache_quant='int8' on {what}: the int8 reads take "
-                    "one table of one kind of cache (docs/STATE.md)")
-            if config.spmd_mesh is not None:
-                raise NotImplementedError(
-                    f"a mesh under a serving session of {what}: the state's "
-                    "rows have no sharding rule and its updates no "
-                    "partitioned form (docs/STATE.md)")
-        if patterned and (self.spec or not self.per_row
-                          or prefix_cache is None
-                          or not getattr(prefix_cache, "enabled", False)):
-            raise NotImplementedError(
-                f"a model with window layers ({config.model_type}) decodes "
-                "in a serving session only (per_row=True, pages handed out "
-                "by the engine's RadixCache): speculative decode and the "
-                "rollout scheduler's device free list are not built for a "
-                "page pool of two kinds (docs/SWA.md)")
 
         self._radix = prefix_cache if (
             prefix_cache is not None
@@ -1401,13 +1349,8 @@ class DecodeSession:
                 if self.seed_window:
                     seed = self._radix.matched_continuation(
                         kelems, self.seed_window)
-                if self.block and (plan.m > 0 or plan.cow_src is not None):
-                    raise NotImplementedError(
-                        "a radix prefix hit on a model that generates by "
-                        f"blocks ({self.config.model_type}): a row's pages "
-                        "are never inserted (the prompt's tail has no K/V "
-                        "until its block commits), so none can occur "
-                        "(docs/BLOCKDIFF.md)")
+                if plan.m > 0 or plan.cow_src is not None:
+                    self.config.require("a radix prefix hit")
                 self.table_np[r] = plan.row_pages
                 if self._ring is not None:
                     self._claim_ring(r, plan, pad_count, budget)
@@ -1479,16 +1422,7 @@ class DecodeSession:
         first prompt token to its budget's last slot. The radix tree holds no
         window state, so a prefix hit would hand the row global pages whose
         window twins nobody wrote: none can occur (`_install` inserts
-        nothing for such a model), and one that did raises here."""
-        if plan.m > 0 or plan.cow_src is not None:
-            state = ("recurrent" if self.config.ssm_layers
-                     or self.config.linear_layers else "conv")
-            raise NotImplementedError(
-                "a radix prefix hit on a model with window layers or a state "
-                f"({self.config.model_type}): the tree holds pages of the "
-                "global kind only, no window pages (docs/SWA.md) and no "
-                f"snapshot of the {state} state at the prefix's end "
-                "(docs/STATE.md)")
+        nothing for such a model), and one that did has raised (`admit`)."""
         last = self.Tp + (self.max_tokens if budget is None else int(budget)) - 1
         try:
             self._ring.claim(r, pad_count // self.page_size,

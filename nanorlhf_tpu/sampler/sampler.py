@@ -83,21 +83,6 @@ class SamplingParams:
     # in the reference's vLLM path too). Set False for the exact candidate
     # set (full-sort cost on TPU).
     approx_top_k: bool = True
-    # LEGACY (contiguous-layout-only) straggler lever — prefer `page_size`.
-    # >0 enables compacting decode (sampler/compaction.py): the loop runs in
-    # this many segments, and between segments finished rows are flushed and
-    # live rows gathered into a smaller power-of-two batch — a batch-shrink
-    # approximation of continuous batching that the paged KV cache
-    # supersedes: `page_size` > 0 with `decode_rows` > 0 recycles finished
-    # rows' cache pages to QUEUED prompts mid-loop (true continuous
-    # batching) and, unlike compaction, composes with spec_k. 0 = monolithic
-    # single-jit loop (bit-stable row streams, fully async dispatch).
-    # Mutually exclusive with spec_k > 0 AND with page_size > 0: compaction's
-    # row gather assumes every live row sits at the same decode step (shared
-    # cache-slot layout), which per-row accept lengths / per-row fill breaks
-    # — `compose_check` raises on either combination (the one legality
-    # matrix every decode entry point routes through).
-    compaction_segments: int = 0
     # >0 switches the KV cache to the PAGED layout (sampler/paged/,
     # docs/PAGED_CACHE.md): K/V live in a global pool of page_size-token
     # pages addressed through a per-row block table instead of a per-row
@@ -107,17 +92,17 @@ class SamplingParams:
     # writes) and kv_cache_quant="int8" (paged scale pools). Pick
     # page_size >= 128 on real TPUs (lane-tile alignment for the paged
     # kernels' int8 scale blocks); CPU tests run any size via interpret
-    # mode. 0 = the loop's own choice: the queued, speculative and
-    # compacting loops keep contiguous slabs, the monolithic one-jit loop
-    # lays its private cache out whichever way its decode read is cheaper
-    # (`_loop_page_size`: the same tokens either way).
+    # mode. 0 = the loop's own choice: the queued and speculative loops keep
+    # contiguous slabs, the monolithic one-jit loop lays its private cache
+    # out whichever way its decode read is cheaper (`_loop_page_size`: the
+    # same tokens either way).
     page_size: int = 0
     # page_size > 0 only: >0 enables CONTINUOUS BATCHING — the decode loop
     # runs `decode_rows` resident rows over a page pool sized for exactly
     # that many rows, and when a row EOSes mid-loop its pages are released
     # and the next queued prompt is prefilled into the freed pool
-    # (sampler/paged/scheduler.py). The long-tail win compaction
-    # approximated, without its same-step restriction: works with spec_k.
+    # (sampler/paged/scheduler.py): the long-tail win, and it works with
+    # spec_k.
     # Host-driven (one sync per chunk of decode iterations); row streams
     # are NOT bit-identical to the monolithic loop (admission re-keys the
     # PRNG per row). n > 1 fanout falls back to repeated-prompt prefill on
@@ -136,9 +121,8 @@ class SamplingParams:
     # variates instead of one categorical per step). capture_logprobs
     # reuses the verify logits, so accepted tokens still carry
     # full-distribution logprobs. 0 = this loop, bit-for-bit untouched.
-    # Incompatible with compaction_segments > 0 (see above); composes with
-    # page_size > 0 (paged verify writes) including the continuous-batching
-    # decode_rows path — the modern replacement for that exclusion.
+    # Composes with page_size > 0 (paged verify writes) including the
+    # continuous-batching decode_rows path.
     spec_k: int = 0
     # n-gram context length the drafter matches on (spec_k > 0 only):
     # smaller = more matches (higher draft rate, lower precision), larger =
@@ -175,26 +159,18 @@ class SamplingParams:
 
 def compose_check(sampling: SamplingParams, *,
                   prefix_cache: bool = False, config=None) -> None:
-    """THE decode-feature composition gate: raises ValueError on every
-    remaining-illegal combination, with the reason. Every entry point that
-    assembles decode features (generate() below, the trainer's config
-    validation) routes through this one function, so the legality matrix
-    lives in exactly one place.
+    """THE decode-feature composition gate. Every entry point that assembles
+    decode features (generate() below, the trainer's config validation)
+    routes through this one function.
 
-    Since the decode-session refactor (sampler/paged/session.py) the
-    features compose by default — spec decode under the radix prefix
-    cache, chunked prefill under either, serving's per-row sampling on
-    the same loop (docs/PAGED_CACHE.md has the full feature×feature
-    matrix). What remains illegal, and why:
+    Which mechanism takes which kind of model is one table,
+    `core/config.MECHANISMS`: a call here for each mechanism the
+    `SamplingParams` and the config select (`ModelConfig.require`, which
+    raises NotImplementedError by the model's name). Option against option,
+    the features compose by default since the decode session
+    (sampler/paged/session.py; docs/PAGED_CACHE.md has the matrix). What
+    remains illegal raises ValueError:
 
-      * compaction_segments > 0 with page_size > 0 — compaction is the
-        legacy contiguous-layout straggler lever; its between-segment row
-        gather assumes per-row [T_max] slabs, which the paged layout's
-        block-table indirection doesn't have. The paged cache with
-        decode_rows > 0 is its replacement, not its peer.
-      * compaction_segments > 0 with spec_k > 0 — the gather also assumes
-        every live row sits at the same decode step (shared cache-slot
-        layout), which per-row accept lengths break.
       * prefix_cache without continuous batching (page_size > 0 AND
         decode_rows > 0) — the radix cache lives at the ADMISSION point;
         the monolithic one-jit paths prefill the whole batch at trace
@@ -204,81 +180,22 @@ def compose_check(sampling: SamplingParams, *,
         long admission; the monolithic paths have neither residents nor
         admissions.
 
-      * a model with window layers (`config.attention_pattern`,
-        docs/SWA.md) with spec_k > 0 or page_size > 0 — its page pool of
-        two kinds is built by the serving session only, and the verify
-        kernels have no window; rollouts take the contiguous cache, where
-        the window is a mask.
-      * a model that keeps a state (`config.state_layers`: conv layers,
-        docs/STATE.md; state-space layers, docs/SSM.md)
-        with spec_k > 0 (no state rollback) or page_size > 0 (the rollout
-        scheduler keeps no state that is not a page).
-
-      * a looped model (`config.loop_passes` > 1, docs/OURO.md) with
-        spec_k > 0, an int8 cache or a mesh: none is built or tested for a
-        stack that every token passes several times.
-
-      * a model that generates by diffusion over blocks
-        (`config.block_generation`, docs/BLOCKDIFF.md) under ANY rollout:
-        every path here takes one token a row a step in order and keeps
-        next-token logprobs; such a model is served (`serving/engine.py`).
-
     Per-row serving constraints (spec requires static greedy, no logprob
     capture) are enforced by DecodeSession's constructor — they depend on
     the per_row flag the engine sets, not on SamplingParams."""
     if config is not None:
-        config.refuse_block_generation("the rollout sampler")
-    if config is not None and config.loop_passes > 1:
-        # (docs/OURO.md "Refused by name": a looped model rolls out on the
-        # exact cache of one device, contiguous or paged)
+        config.require("the rollout sampler", "a rollout")
         if sampling.spec_k > 0:
-            config.refuse_loop(f"speculative decode (spec_k={sampling.spec_k})")
+            config.require(f"speculative decode (spec_k={sampling.spec_k})",
+                           "speculative decode")
+        if sampling.page_size > 0:
+            config.require(
+                f"the paged rollout paths (page_size={sampling.page_size})",
+                "a page pool of one kind")
         if config.kv_cache_quant == "int8":
-            config.refuse_loop("kv_cache_quant='int8'")
+            config.require("kv_cache_quant='int8'")
         if config.spmd_mesh is not None:
-            config.refuse_loop("a rollout under a mesh")
-    if config is not None and config.state_layers and sampling.spec_k > 0:
-        raise NotImplementedError(
-            f"speculative decode (spec_k={sampling.spec_k}) on "
-            f"{config.state_what}: a verify forward advances "
-            "the state past every candidate and no rollback to the last "
-            "accepted token is built (docs/STATE.md)")
-    if config is not None and config.state_layers and sampling.page_size > 0:
-        raise NotImplementedError(
-            f"the paged rollout paths (page_size={sampling.page_size}) on "
-            f"{config.state_what}: the monolithic "
-            "paged rollout and the rollout scheduler keep no state that is "
-            "not a page; rollouts take the contiguous cache, serving the "
-            "session (docs/STATE.md)")
-    if config is not None and config.attention_pattern is not None and (
-            sampling.spec_k > 0 or sampling.page_size > 0):
-        raise NotImplementedError(
-            f"a model with window layers ({config.model_type}; docs/SWA.md, "
-            "docs/AFMOE.md) rolls out on the contiguous cache only: "
-            "speculative decode (spec_k > 0) and the paged rollout paths "
-            "(page_size > 0) are not built for a page pool of two kinds; "
-            "the serving session is")
-    if sampling.page_size > 0 and sampling.compaction_segments > 0:
-        raise ValueError(
-            "page_size > 0 is incompatible with compaction_segments > 0: "
-            "compaction is the legacy contiguous-layout straggler lever "
-            "(same-step row gathers over per-row slabs), and the paged "
-            "cache replaces it outright — set decode_rows > 0 for true "
-            "continuous batching over recycled pages instead of batch "
-            "shrinking (sampler/paged/scheduler.py)."
-        )
-    if sampling.spec_k > 0 and sampling.compaction_segments > 0:
-        raise ValueError(
-            "spec_k > 0 is incompatible with compaction_segments > 0: "
-            "compacting decode gathers rows under the assumption that "
-            "every live row sits at the same decode step (shared "
-            "cache-slot layout, sampler/compaction.py), which "
-            "speculative decode's per-row accept lengths break. "
-            "Compaction is legacy — the preferred straggler fix is the "
-            "paged cache (SamplingParams.page_size > 0 with "
-            "decode_rows > 0), whose continuous batching COMPOSES with "
-            "spec_k instead of excluding it."
-        )
+            config.require("a rollout under a mesh")
     queued_capable = sampling.page_size > 0 and sampling.decode_rows > 0
     if prefix_cache and not queued_capable:
         raise ValueError(
@@ -496,7 +413,7 @@ def generate_tokens(
     same loop over the paged KV layout (dense identity block table — no
     recycling here; see sampler/paged/scheduler.py for that); 0 leaves the
     layout to the loop (`_loop_page_size`)."""
-    config.refuse_block_generation("the one-jit rollout (generate_tokens)")
+    config.require("the one-jit rollout (generate_tokens)", "a rollout")
     Tp = prompt_ids.shape[1]
     page_size = _loop_page_size(config, page_size)
     state = _prefill_state(
@@ -551,10 +468,9 @@ def _queued(sampling: SamplingParams, rows: int) -> bool:
 
 def _monolithic(sampling: SamplingParams, rows: int) -> bool:
     """Whether `generate` runs a call of `rows` rows in all through the
-    monolithic loop (`generate_tokens`), not the queued, the speculative or
-    the compacting one."""
-    return not (_queued(sampling, rows) or sampling.spec_k > 0
-                or sampling.compaction_segments > 0)
+    monolithic loop (`generate_tokens`), not the queued or the speculative
+    one."""
+    return not (_queued(sampling, rows) or sampling.spec_k > 0)
 
 
 def kv_in_place(config, sampling: SamplingParams, rows: int) -> int:
@@ -597,7 +513,7 @@ def attn_read_frac(config, sampling, prompt_width: int, responses,
     slot, over the table's `rows x ceil(T_max / P)`; `prompt_lens` is a real
     length a PROMPT (each N consecutive rows), and every row counts at every
     step (the loop marks none dead). 1.0 wherever the loop names no bound: a
-    cache of one block, the compacting, queued and speculative loops, a
+    cache of one block, the queued and speculative loops, a
     paged cache read as the gathered view, and a call that took no step.
     `responses` is the call's [rows, max_tokens] result on the HOST: the
     loop ran until its longest row ended, one step a token after the
@@ -636,8 +552,7 @@ def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
                    page_size=0):
     """Prefill + first sampled token → the decode-loop carry state:
     (step, out, lp_out, caches, key_mask, done, cur_tok, prompt_len, key).
-    Per-step sampling keys are fold_in(key, step), so a segment boundary
-    (compaction.py) resumes the identical stream.
+    Per-step sampling keys are fold_in(key, step).
 
     `prompt_fanout` N: the prompts arrive UN-repeated; prefill runs on the
     [B] rows once, then the first logits, prompt KV, and per-row metadata
@@ -736,8 +651,7 @@ def _prefill_state(params, config, prompt_ids, prompt_mask, key, *,
 def _decode_body(params, config, state, *, Tp, max_tokens, eos_token_id,
                  pad_token_id, temperature, top_p, greedy, lora_scale, top_k,
                  capture_logprobs, approx_top_k, page_size=0, extent=None):
-    """One decode step over the carry state (shared by the monolithic
-    while_loop above and the segmented/compacting loop). `page_size` > 0:
+    """One decode step over the carry state. `page_size` > 0:
     the caches in the carry are paged pools; the dense identity table is a
     shape-derived constant (pool pages // batch rows), so the carry layout
     is unchanged. `extent` (the monolithic loop only): the static bound
@@ -780,7 +694,6 @@ def generate(
     eos_token_id: int,
     pad_token_id: int,
     lora_scale: float = 1.0,
-    batch_sharding=None,
     spec_stats_out: list | None = None,
     tracer=None,
     paged_stats_out: list | None = None,
@@ -790,9 +703,6 @@ def generate(
 ) -> jnp.ndarray:
     """vllm_generate-contract entry: [B*N, max_tokens], N consecutive per
     prompt; (tokens, logprobs) when `sampling.capture_logprobs`.
-
-    `batch_sharding` (optional NamedSharding over the batch axes) is only
-    consumed by the compacting path, which re-lays-out gathered carries.
 
     `spec_stats_out` (spec_k > 0 only): a caller-provided list the
     speculative path appends its per-call stats dict to (device scalars:
@@ -885,20 +795,6 @@ def generate(
                                 pad_token_id, paged_stats_out,
                                 sampling.page_size)
         return result
-    if sampling.compaction_segments > 0:
-        from nanorlhf_tpu.sampler.compaction import generate_tokens_compact
-
-        return generate_tokens_compact(
-            params, config, prompt_ids, prompt_mask, key,
-            max_tokens=sampling.max_tokens, eos_token_id=eos_token_id,
-            pad_token_id=pad_token_id, segments=sampling.compaction_segments,
-            temperature=sampling.temperature, top_p=sampling.top_p,
-            greedy=sampling.greedy, lora_scale=lora_scale,
-            top_k=sampling.top_k, capture_logprobs=sampling.capture_logprobs,
-            approx_top_k=sampling.approx_top_k,
-            batch_sharding=batch_sharding,
-            prompt_fanout=fanout,
-        )
     result = generate_tokens(
         params,
         config,

@@ -48,11 +48,8 @@ drops at the table-routed scatter instead of clobbering anything, and the
 dropped positions sit beyond `max_tokens - n_gen`, which the emission
 clamp truncates anyway — see docs/PAGED_CACHE.md for the bound.
 
-Interaction with compaction (sampler/compaction.py): mutually exclusive —
-compaction's row gather assumes all rows share the same step alignment,
-which per-row accept lengths break; `generate` raises on the combination.
-The paged cache (SamplingParams.page_size) is the replacement straggler
-lever and COMPOSES with this path: monolithic paged verify here, and the
+The paged cache (SamplingParams.page_size) is the straggler lever and
+COMPOSES with this path: monolithic paged verify here, and the
 continuous-batching scheduler (sampler/paged/scheduler.py) reuses
 `_draft_fn`/`_verify_fn` directly with a live block table.
 
@@ -358,7 +355,8 @@ def generate_tokens_spec(
     per token; `row_steps` counts live (row, verify-step) pairs, so
     emitted/row_steps is mean tokens per row per dispatch (monolithic:
     identically 1)."""
-    config.refuse_block_generation(f"speculative decode (spec_k={spec_k})")
+    config.require(f"speculative decode (spec_k={spec_k})",
+                   "speculative decode")
     Tp = prompt_ids.shape[1]
     base = _prefill_state(
         params, config, prompt_ids, prompt_mask, key,
@@ -417,7 +415,7 @@ def _generate_spec_instrumented(params, config, prompt_ids, prompt_mask, key,
     pieces, one iteration per host step, with real per-iteration
     "rollout.draft"/"rollout.verify" spans on the "rollout" track
     (docs/OBSERVABILITY.md). Costs one device sync per verify step — the
-    observability trade, mirroring compaction's per-segment sync; the
+    observability trade; the
     default (tracer off) path is the fully-async jitted while_loop."""
     Tp = prompt_ids.shape[1]
     spec_k, spec_ngram = kw["spec_k"], kw["spec_ngram"]

@@ -87,9 +87,7 @@ class RLConfig:
     # worst case (acceptance ~0) pays ~one verify forward per token.
     # Per-update acceptance lands in rollout/draft_acceptance /
     # rollout/accepted_per_step (docs/METRICS.md). 0 = off (the monolithic
-    # loop, bit-for-bit untouched). Incompatible with
-    # rollout_compaction_segments > 0 — `generate` raises (compaction's
-    # row gather assumes step-aligned rows).
+    # loop, bit-for-bit untouched).
     rollout_spec_k: int = 0
     # n-gram context the drafter matches on (rollout_spec_k > 0 only)
     rollout_spec_ngram: int = 3
@@ -314,14 +312,6 @@ class RLConfig:
     # (scoring/training have no cache); same off-policy-tolerance story as
     # rollout_quant.
     kv_cache_quant: str = "none"  # none | int8
-    # LEGACY (contiguous layout only) — prefer rollout_page_size. >0:
-    # rollouts use compacting decode (sampler/compaction.py) with this many
-    # segments — finished rows are flushed at segment boundaries and live
-    # rows gathered into a smaller power-of-two batch. A batch-shrink
-    # approximation of continuous batching that the paged KV cache
-    # supersedes; mutually exclusive with rollout_page_size > 0 and with
-    # rollout_spec_k > 0.
-    rollout_compaction_segments: int = 0
     # >0: the rollout KV cache switches to the PAGED layout (sampler/paged/,
     # docs/PAGED_CACHE.md) — K/V in a global pool of this-many-token pages
     # addressed through per-row block tables. On its own a pure re-layout
@@ -334,13 +324,13 @@ class RLConfig:
     # wherever a decode step then reads each row's own in place (one TPU
     # device, a plain bf16 cache: core/model.decode_loop_page_size; the
     # same tokens, `rollout/kv_in_place` says which) and contiguous
-    # elsewhere; the speculative and compacting loops stay contiguous.
+    # elsewhere; the speculative loop stays contiguous.
     rollout_page_size: int = 0
     # rollout_page_size > 0 only. >0: continuous batching — only this many
     # rows are RESIDENT in the decode loop; when a row emits EOS its pages
     # are released and the next queued prompt is prefilled into the freed
     # pool mid-loop (sampler/paged/scheduler.py). Fixes the long-tail
-    # straggler cost compaction approximated, works with spec_k, feeds the
+    # straggler cost, works with spec_k, feeds the
     # rollout/page_* metrics + /statusz "pages" + lineage lease events.
     # 0 (or >= the rollout batch) = monolithic paged loop.
     rollout_decode_rows: int = 0

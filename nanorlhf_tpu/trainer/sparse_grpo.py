@@ -85,7 +85,6 @@ class SparseGRPOTrainer(RLTrainer):
 
     def _refuse_unsupported(self):
         """What the shared loop carries and the sparse phases cannot yet."""
-        self.mcfg.refuse_block_generation("SparseGRPOTrainer")
         if self._env_multi_turn:
             # single-turn envs work (RLTrainer unwraps them into a plain
             # reward callable, which _call_reward dispatches unchanged);

@@ -397,17 +397,7 @@ class RLTrainer:
     ):
         self.cfg = config
         self.mcfg = model_config
-        # (no published RL objective for a policy that generates by blocks:
-        # docs/BLOCKDIFF.md "what is left")
-        self.mcfg.refuse_block_generation(f"training ({type(self).__name__})")
-        if self.mcfg.state_layers:
-            raise NotImplementedError(
-                f"training {self.mcfg.state_what} "
-                "is not built: the update's packed rows have no boundary "
-                "the convolution stops at, its backward under the sparse "
-                "trainer's packing is not written (nor the state-space "
-                "scan's at all), and a mesh has no rule "
-                "for the state (docs/STATE.md); the model is served")
+        self.mcfg.require(f"training ({type(self).__name__})", "training")
         self.tokenizer = tokenizer
         self.reward_func = reward_func
         self.algo = config.algo
@@ -746,7 +736,6 @@ class RLTrainer:
         # seeds the drafter from the radix continuation).
         compose_check(
             SamplingParams(
-                compaction_segments=config.rollout_compaction_segments,
                 page_size=config.rollout_page_size,
                 decode_rows=config.rollout_decode_rows,
                 spec_k=config.rollout_spec_k,
@@ -881,13 +870,8 @@ class RLTrainer:
                 "kv_cache_quant='int8' with a latent-attention model: the "
                 "cache is one latent a token, which has no int8 form "
                 "(core/model._latent_cache_shape, docs/MLA.md)")
-        if (config.kv_cache_quant == "int8"
-                and self.mcfg.attention_pattern is not None):
-            raise NotImplementedError(
-                "kv_cache_quant='int8' with a model with window layers "
-                f"({self.mcfg.model_type}): the "
-                "int8 reads have no window bound (core/model._pattern_caches, "
-                "docs/SWA.md)")
+        if config.kv_cache_quant == "int8":
+            self.mcfg.require("kv_cache_quant='int8'")
         import dataclasses as _dc
 
         self._rollout_mcfg = (
@@ -1843,7 +1827,6 @@ class RLTrainer:
         sampling = SamplingParams(
             temperature=cfg.temperature, top_p=cfg.top_p, n=n,
             max_tokens=cfg.response_length, capture_logprobs=capture,
-            compaction_segments=cfg.rollout_compaction_segments,
             top_k=cfg.rollout_top_k, approx_top_k=cfg.rollout_approx_top_k,
             shared_prompt_prefill=cfg.rollout_shared_prefill,
             spec_k=cfg.rollout_spec_k, spec_ngram=cfg.rollout_spec_ngram,
@@ -2069,7 +2052,7 @@ class RLTrainer:
         gen_out = generate(
             gen_params, self._rollout_mcfg, queries_j, prompt_mask, gen_key,
             sampling, eos_token_id=eos_id, pad_token_id=pad_id,
-            lora_scale=self.lora_scale, batch_sharding=bs,
+            lora_scale=self.lora_scale,
             spec_stats_out=spec_stats, tracer=self.tracer,
             paged_stats_out=paged_stats, latency=self.latency,
             prefix_cache=self.prefix_cache,

@@ -569,7 +569,6 @@ def test_the_rollouts_own_cache_takes_the_layout_its_rule_names(
         pages > 0 or config.kv_cache_quant == "int8"
         or bool(config.kv_lora_rank)
         or M.use_decode_kernel(config.attention_impl, 768))
-    for other in (dict(spec_k=2), dict(compaction_segments=2),
-                  dict(page_size=128, decode_rows=8)):
+    for other in (dict(spec_k=2), dict(page_size=128, decode_rows=8)):
         assert S.kv_in_place(config, dataclasses.replace(
             sampling, **other), 64) == 0

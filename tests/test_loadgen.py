@@ -33,6 +33,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -268,10 +269,14 @@ def test_config_validation():
 # drain-then-remove on a real fake-dispatch fleet (jax-free)
 # --------------------------------------------------------------------- #
 
-def _fleet(n_workers=2, dispatch_s=0.05, n_batches=1000):
+def _fleet(n_workers=2, dispatch_s=0.05, n_batches=1000, hold=None):
+    """`hold`: `(index, event)`, every dispatch of that index waits for the
+    event."""
     batches = iter(range(n_batches))
 
     def dispatch(index, queries, tree, worker_id):
+        if hold is not None and index == hold[0]:
+            hold[1].wait(timeout=30.0)
         time.sleep(dispatch_s)
         return {"index": index, "worker": worker_id}
 
@@ -306,20 +311,30 @@ def test_drain_remove_never_strands_a_lease():
 
 
 def test_abrupt_remove_still_reassigns():
-    orch = _fleet(n_workers=2, dispatch_s=0.2)
+    # index 1 is on the lease of index 0 (lease_size=2): its holder is in
+    # the middle of that lease when it is removed, and stays there until
+    # the lease has been granted again. A revoked holder that finished
+    # BEFORE the new grant would commit, and nothing would be reassigned.
+    release = threading.Event()
+    orch = _fleet(n_workers=2, dispatch_s=0.02, hold=(1, release))
+    coord = orch.coordinator
     try:
         orch.publish({})
         first = orch.get()
+        assert first.index == 0
         victim = first.payload["worker"]
         orch.remove_worker(victim)      # default: abrupt, revoke + reassign
-        deadline = time.monotonic() + 10.0
-        while (orch.coordinator.counters["reassigned_leases"] == 0
-               and time.monotonic() < deadline):
-            time.sleep(0.02)
-        assert orch.coordinator.counters["reassigned_leases"] >= 1
-        seen = [orch.get().index for _ in range(4)]
-        assert seen == sorted(seen)
+        with coord._cond:               # every grant notifies it
+            assert coord._cond.wait_for(
+                lambda: coord.counters["reassigned_leases"] >= 1,
+                timeout=30.0)
+        release.set()
+        got = [orch.get() for _ in range(4)]
+        assert [g.index for g in got] == [1, 2, 3, 4]
+        # the revoked holder's own result of index 1 lost to the new grant
+        assert got[0].payload["worker"] != victim
     finally:
+        release.set()
         orch.close()
 
 

@@ -310,11 +310,6 @@ def test_paged_sampled_stream_bit_identical(tiny):
     np.testing.assert_array_equal(np.asarray(mono), np.asarray(paged))
 
 
-def test_paged_with_compaction_raises(tiny):
-    with pytest.raises(ValueError, match="page_size"):
-        _gen(tiny, page_size=8, compaction_segments=2)
-
-
 def test_cache_extra_gated_to_contiguous(tiny):
     """The spec path's cache_extra slack must NOT inflate the paged pool:
     pool pages == B * ceil((Tp+max_tokens)/P) exactly, slack or not —

@@ -17,6 +17,7 @@ import dataclasses
 import http.client
 import json
 import re
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -932,9 +933,20 @@ def _serial_streams(tiny, prefill_chunk, sync_every):
 def beat_run(tiny, request):
     """Seven requests, more than rows, all submitted at once: streams, the
     engine's metrics after it has drained, and the loop's lifetime."""
-    eng = _chaos_engine(tiny, sync_every=2, prefill_chunk=request.param,
-                        seed=0)
-    t0 = time.perf_counter()            # the loop thread has just started
+    # the clock starts BEFORE the loop thread does (the constructor's last
+    # act): read after the constructor returned, it started late by however
+    # long the new thread kept this one off the cores
+    started, start = {}, threading.Thread.start
+
+    def stamped_start(thread):
+        started[thread.name] = time.perf_counter()
+        start(thread)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(threading.Thread, "start", stamped_start)
+        eng = _chaos_engine(tiny, sync_every=2, prefill_chunk=request.param,
+                            seed=0)
+    t0 = started["serving-engine"]
     try:
         reqs = [eng.submit(toks, greedy=True, max_tokens=budget)[0]
                 for toks, budget in BEAT_REQUESTS]

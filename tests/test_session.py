@@ -356,8 +356,6 @@ def test_in_place_read_serves_the_gathered_views_tokens(tiny):
 # --------------------------------------------------------------------- #
 
 ILLEGAL = [
-    (dict(page_size=4, compaction_segments=2), False, "page_size"),
-    (dict(spec_k=2, compaction_segments=2), False, "spec_k"),
     (dict(), True, "continuous batching"),
     (dict(page_size=4), True, "continuous batching"),
     (dict(prefill_chunk=4), False, "prefill_chunk"),
@@ -368,7 +366,6 @@ LEGAL = [
     dict(page_size=4, decode_rows=2, spec_k=3),
     dict(page_size=4, decode_rows=2, prefill_chunk=4, spec_k=3),
     dict(page_size=4, spec_k=3),
-    dict(compaction_segments=2),
 ]
 
 

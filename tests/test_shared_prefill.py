@@ -93,16 +93,6 @@ def test_greedy_fanout(model):
             np.testing.assert_array_equal(rows[p, 0], rows[p, j])
 
 
-def test_compaction_path(model):
-    """Segmented/compacting decode accepts the fanout (same distribution;
-    identical streams BEFORE the first compaction, so a segment width the
-    batch never compacts under reproduces the monolithic tokens)."""
-    a = _gen(model, True, compaction_segments=2)
-    b = _gen(model, False, compaction_segments=2)
-    assert a.shape == b.shape == (16, 10)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_n1_unaffected(model):
     cfg, params = model
     ids, mask = _prompts()

@@ -4,7 +4,7 @@ Pins the ISSUE-5 acceptance contract: greedy spec streams bit-identical to
 the monolithic loop on the CPU mesh, rejection sampling distribution-exact
 (small-vocab enumeration), per-row cache-length/key_mask consistency after
 mixed accept lengths, capture_logprobs parity, EOS inside an accepted
-draft, the compaction guard, and the k-query verify kernel vs its oracle.
+draft, and the k-query verify kernel vs its oracle.
 
 The deterministic oracle is the "cycle model": tied embeddings off, every
 layer zeroed, orthogonal embedding rows, and lm_head wired so the logits
@@ -448,11 +448,6 @@ def test_verify_kernel_interpret_matches_oracle(rng):
 # --------------------------------------------------------------------- #
 # wiring: guard, stats plumbing, instrumented driver
 # --------------------------------------------------------------------- #
-
-def test_spec_with_compaction_raises(tiny):
-    with pytest.raises(ValueError, match="compaction"):
-        _gen(tiny, spec_k=2, compaction_segments=2)
-
 
 def test_instrumented_driver_matches_and_emits_spans(tiny):
     from nanorlhf_tpu.telemetry import SpanTracer

@@ -10,7 +10,6 @@ levers (ROADMAP.md S3):
   int8_weights  — rollout_quant="int8" weight-only base projections
   int8_kv       — kv_cache_quant="int8" + q8 decode kernel
   int8_both     — both quantizations
-  compact4      — rollout_compaction_segments=4 (continuous-batching analogue)
   spec{2,4,8}   — speculative decode (sampler/speculative.py): n-gram draft
                   + batched k-token verify at spec_k ∈ {2,4,8}, nucleus
                   sampling (the spec_k=0 nucleus baseline IS approx_topk)
@@ -102,7 +101,6 @@ def main():
             "int8_weights": None,  # filled below (lazy quantize)
             "int8_kv": dict(base, mcfg=kv_cfg),
             "int8_both": None,
-            "compact4": dict(base, sp_kw={"compaction_segments": 4}),
             "n4_shared": dict(base, sp_kw={"n": 4}),
             "n4_repeat": dict(base, sp_kw={"n": 4,
                                            "shared_prompt_prefill": False}),
