@@ -44,6 +44,9 @@ NEG_INF = -2.0**30
 _SCORE_BYTES = 256 << 20
 # keys a block of `_attend_chosen`'s walk holds over arrays at hand
 _KEY_BLOCK = 1024
+# queries (`B KV Tq`) from which `select_blocks` takes its top k by
+# `top_blocks`; below, by `lax.top_k` (tools/bench_block_pick.py)
+_PICK_QUERIES = 16
 
 
 # --------------------------------------------------------------------------- #
@@ -312,7 +315,10 @@ def select_blocks(config, q, kc, t):
     block / stride: pool `per + 1`, stride `per`, pad 1), and the top k are
     taken with the first `sparse_init_blocks` and the `sparse_window_size /
     sparse_block_size` blocks that end at the query's own forced in. Equal
-    scores: the lower block first (`lax.top_k`)."""
+    scores: the lower block first. The top k are `lax.top_k`'s, ties and
+    order: by `top_blocks` where the call ranks `_PICK_QUERIES` queries (`B
+    KV Tq`) or more, by `lax.top_k` itself (on the chip a full sort of the
+    blocks) below, where that sort is the cheaper; the same bits either way."""
     stride, ksize = config.sparse_kernel_stride, config.sparse_kernel_size
     block = config.sparse_block_size
     per, local = block // stride, config.sparse_window_size // block
@@ -336,8 +342,55 @@ def select_blocks(config, q, kc, t):
     forced = seen & ((b < config.sparse_init_blocks) | (b > own - local))
     ranked = jnp.where(forced[:, None], 2.0 * H,
                        jnp.where(seen[:, None], score, -1.0))
-    vals, idx = jax.lax.top_k(ranked, min(config.sparse_topk, NB))
+    k = min(config.sparse_topk, NB)
+    pick = top_blocks if B * KV * Tq >= _PICK_QUERIES else jax.lax.top_k
+    vals, idx = pick(ranked, k)
     return idx.astype(jnp.int32), vals >= 0
+
+
+def top_blocks(ranked, k: int):
+    """`jax.lax.top_k(ranked, k)` bit for bit, `(vals, idx)` `[..., k]` (the
+    values descending, equal ones the lower index first), by SELECTION over
+    the last axis `[..., NB]` and not by sorting it (`k <= NB`; no NaN, no
+    -0.0: `select_blocks`' scores are -1, `[0, H]` and `2 H`):
+
+    1. the floats become unsigned keys of the same order (the sign flip);
+    2. each query's k-th largest key is found exactly by a search over the
+       key's 32 bits, the highest first: a bit stays set where at least k
+       keys reach the candidate;
+    3. every key above that threshold is taken, and of those equal to it the
+       lowest-numbered as far as k goes (counts before a block: a matmul
+       with a triangle of ones, exact in float32);
+    4. the taken blocks go to k places in block order (a `[..., k, NB]`
+       compare-and-reduce) and a sort of width k orders the pairs by (value
+       descending, block ascending)."""
+    NB = ranked.shape[-1]
+    u32, i32 = jnp.uint32, jnp.int32
+    bits = jax.lax.bitcast_convert_type(ranked, u32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | u32(1 << 31))
+
+    def bit(i, thr):
+        cand = thr | (u32(1 << 31) >> i.astype(u32))
+        reach = jnp.sum(key >= cand[..., None], axis=-1, dtype=i32)
+        return jnp.where(reach >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros(ranked.shape[:-1], u32))
+    above, tied = key > thr[..., None], key == thr[..., None]
+    b = jnp.arange(NB, dtype=i32)
+    upto = (b[:, None] <= b[None]).astype(jnp.bfloat16)
+
+    def count(m):       # [..., NB] bool: how many hold up to and at a block
+        return jnp.einsum("...n,nm->...m", m.astype(jnp.bfloat16), upto,
+                          preferred_element_type=jnp.float32).astype(i32)
+
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=i32)
+    taken = above | (tied & (count(tied) <= room))
+    place = jnp.where(taken, count(taken) - 1, -1)
+    at = place[..., None, :] == jnp.arange(k, dtype=i32)[:, None]
+    idx = jnp.sum(jnp.where(at, b, 0), axis=-1)                 # [..., k]
+    vals = jnp.max(jnp.where(at, ranked[..., None, :], -jnp.inf), axis=-1)
+    down, idx = jax.lax.sort((-vals, idx), num_keys=2)
+    return -down, idx
 
 
 def _row_keys(config, kc_stack, layer, table, start, page_size: int):

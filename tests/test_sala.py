@@ -293,6 +293,72 @@ def test_the_selection_is_the_references(params, ids):
     assert (got[0] & ~newest).any()     # (a free block lies further back)
 
 
+def _block_scores(case, NB, Tq=8, H=32.0):
+    """`[1, 2, Tq, NB]` scores of the kinds `select_blocks` ranks: -1 (not
+    seen), `[0, H]` (free) and `2 H` (forced)."""
+    rng = np.random.default_rng(NB)
+    x = rng.random((1, 2, Tq, NB)).astype(np.float32) * 3
+    if case == "eighths":           # heavy ties, at the threshold too
+        x = np.round(x * 8) / 8
+    elif case == "forced_run":      # more forced blocks than k
+        x[..., 5:5 + min(100, NB - 5)] = 2 * H
+    elif case == "few":             # fewer candidates than k
+        x[..., 20:] = -1.0
+    elif case == "zeros":           # every free score 0.0
+        x[:] = 0.0
+        x[..., :3] = 2 * H
+        x[..., NB // 2:] = -1.0
+    elif case == "pad":             # a padded query: everything -1
+        x[:] = -1.0
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("NB", [1040, 40])
+@pytest.mark.parametrize("case", ["random", "eighths", "forced_run", "few",
+                                  "zeros", "pad"])
+def test_top_blocks_is_top_k_bit_for_bit(case, NB):
+    """ISSUE 58: `sala.top_blocks` (a threshold search, a placement and a
+    sort of width k) gives `jax.lax.top_k`'s values AND indices bit for bit
+    at the cell's 1,040 blocks and at fewer blocks than `sparse_topk`."""
+    x, k = _block_scores(case, NB), min(64, NB)
+    vals, idx = jax.jit(sala.top_blocks, static_argnums=1)(x, k)
+    want, at = jax.lax.top_k(x, k)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(at))
+    np.testing.assert_array_equal(np.asarray(vals).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+    assert idx.dtype == jnp.int32 and vals.dtype == jnp.float32
+    if case == "few":
+        assert (np.asarray(vals)[..., :20] >= 0).all()
+        assert (np.asarray(vals)[..., 20:] == -1).all()
+
+
+@pytest.mark.parametrize("Tq, takes", [(2, "top_k"), (16, "top_blocks")])
+def test_select_blocks_picks_by_the_queries_it_ranks(params, ids, monkeypatch,
+                                                     Tq, takes):
+    """The shape rule (`sala._PICK_QUERIES`, from tools/bench_block_pick.py's
+    timings on the chip): below it `select_blocks` ranks with `lax.top_k`,
+    from it on with `top_blocks`; either side gives what the other would."""
+    q, k, t, _, _ = ref.first_layer_selection(params, HF, ids[2:3], PAD, Tq)
+    kc = sala.compress_at_hand(CFG, k[None], jnp.zeros((1,), jnp.int32))
+    assert (kc.shape[1] * Tq >= sala._PICK_QUERIES) == (takes == "top_blocks")
+    ran = []
+
+    def noting(name, pick):
+        return lambda x, k: ran.append(name) or pick(x, k)
+
+    monkeypatch.setattr(sala, "top_blocks", noting("top_blocks", sala.top_blocks))
+    monkeypatch.setattr(jax.lax, "top_k", noting("top_k", jax.lax.top_k))
+    got = sala.select_blocks(CFG, q[None], kc, t[None])
+    assert ran == [takes]
+    monkeypatch.setattr(sala, "_PICK_QUERIES",
+                        0 if takes == "top_k" else 1 << 30)
+    other = sala.select_blocks(CFG, q[None], kc, t[None])
+    assert ran[1:] == ["top_k" if takes == "top_blocks" else "top_blocks"]
+    for a, b in zip(got, other):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(got[1]).any()
+
+
 def test_contiguous_prefill_and_decode_are_the_reference(params, ids, sound):
     B, T_max = ids.shape
     mask = ids != PAD
