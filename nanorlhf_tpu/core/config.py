@@ -38,7 +38,7 @@ SDAR_MASK_TOKEN_ID = 151669
 
 # the layer kinds `layer_types` names (every other entry is an attention
 # layer, whose kind is its `(window, rotary)`)
-NAMED_KINDS = ("conv", "hybrid", "lightning", "sparse")
+NAMED_KINDS = ("conv", "hybrid", "mamba", "lightning", "sparse")
 # MiniCPM-SALA's `mixer_types` under the tree's names
 _SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
 _SALA_PUBLISHED = (
@@ -261,6 +261,18 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_chunk: int = 128
+    # Granite 4.0-H's layer (docs/GRANITE_H.md): `layer_types[l] == "mamba"`
+    # is the same mixer ALONE in its layer (a state and no pages); the
+    # layers beside it are global attention without rotary. Its multipliers,
+    # the published keys: `attention_multiplier` is the softmax's scale IN
+    # PLACE of 1 / sqrt(head_dim) (0.0: that default, which stages nothing),
+    # `residual_multiplier` what every branch is multiplied by as it joins
+    # the stream (0.0: none; `residual_scale`). `shared_intermediate_size`:
+    # the shared expert's width where it is a number of its own and not
+    # `n_shared_experts` routed widths (0: that product).
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 0.0
+    shared_intermediate_size: int = 0
     # Falcon-H1's muP multipliers, the published keys under the tree's
     # names, applied where the published code applies them: on the
     # attention's input, on k, on its output; on the mixer's input, on the
@@ -422,12 +434,15 @@ class ModelConfig:
         """The kinds of model this config is (keys of TRAITS): what a
         mechanism must list to take it (`require`)."""
         kinds = set(self.layer_kinds)
+        named = bool(kinds & set(NAMED_KINDS))
         is_ = {
-            "window": any(k not in NAMED_KINDS and k != (False, True)
-                          for k in kinds),
+            # (an attention layer without rotary makes a model a pattern
+            # model as a window does, unless a named kind already has)
+            "window": any(k not in NAMED_KINDS and (
+                k[0] or (k != (False, True) and not named)) for k in kinds),
             "sparse": "sparse" in kinds,
             "conv": "conv" in kinds,
-            "ssm": "hybrid" in kinds,
+            "ssm": "hybrid" in kinds or "mamba" in kinds,
             "linear": "lightning" in kinds,
             "latent": self.kv_lora_rank > 0,
             "block": self.block_length > 0,
@@ -478,7 +493,7 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> tuple:
-        """Every layer's kind, in model order: `"conv"`, `"hybrid"`,
+        """Every layer's kind, in model order: `"conv"`, `"hybrid"`, `"mamba"`,
         `"lightning"` or `"sparse"` (`layer_types`), or an attention layer's
         `(window, rotary)`."""
         L = self.num_hidden_layers
@@ -525,9 +540,22 @@ class ModelConfig:
 
     @property
     def ssm_layers(self) -> int:
-        """Layers with a state-space mixer beside their attention, which
-        keep a state AND pages (0 in a model without)."""
-        return sum(k == "hybrid" for k in self.layer_kinds)
+        """Layers with a state-space mixer, which the state's two stacks are
+        sized by (0 in a model without): beside an attention (`"hybrid"`: a
+        state AND pages) or alone (`"mamba"`: `mamba_layers`)."""
+        return sum(k in ("hybrid", "mamba") for k in self.layer_kinds)
+
+    @property
+    def mamba_layers(self) -> int:
+        """Layers whose mixer stands ALONE: a state and no pages."""
+        return sum(k == "mamba" for k in self.layer_kinds)
+
+    @property
+    def page_layers(self) -> int:
+        """Layers that keep pages a pass, of either kind of pool: what
+        `kv_bytes_per_token` counts."""
+        return (self.num_hidden_layers - self.conv_layers
+                - self.linear_layers - self.mamba_layers)
 
     @property
     def state_layers(self) -> int:
@@ -551,7 +579,10 @@ class ModelConfig:
     def residual_scale(self) -> float:
         """What each branch is multiplied by as it joins the stream
         (MiniCPM's `scale_depth / sqrt(num_hidden_layers)`, of the PUBLISHED
-        depth); 1.0, which stages nothing, for every other model."""
+        depth; Granite's `residual_multiplier`); 1.0, which stages nothing,
+        for every other model."""
+        if self.residual_multiplier:
+            return self.residual_multiplier
         if not self.scale_depth:
             return 1.0
         depth = self.published_layers or self.num_hidden_layers
@@ -576,6 +607,22 @@ class ModelConfig:
               if every_layer or k == "lightning"]
         factor = np.asarray([1.0 - l / max(depth - 1, 1) + 1e-5 for l in at])
         return (-slopes[None, :] * factor[:, None]).astype(np.float32)
+
+    @property
+    def query_multiplier(self) -> float:
+        """What q is multiplied by so that the reads' own 1 / sqrt(head_dim)
+        makes `attention_multiplier` of it; 1.0, which stages nothing,
+        without one."""
+        if not self.attention_multiplier:
+            return 1.0
+        return self.attention_multiplier * self.actual_head_dim ** 0.5
+
+    @property
+    def shared_expert_width(self) -> int:
+        """The shared experts' one SwiGLU: a width of its own, or
+        `n_shared_experts` routed widths."""
+        return (self.shared_intermediate_size
+                or self.n_shared_experts * self.expert_width)
 
     @property
     def ssm_inner(self) -> int:
@@ -874,6 +921,55 @@ class ModelConfig:
             lm_head_multiplier=0.3)
 
     @classmethod
+    def granite_h_small(cls) -> "ModelConfig":
+        """ibm-granite/granite-4.0-h-small (docs/GRANITE_H.md): 40 layers,
+        nine Mamba-2 mixers ALONE in their layers (128 heads of 64, one group
+        of state 128, 4 taps, chunks of 256) to every global attention layer
+        (GQA 32 / 8 x 128, no rotary, scale 1 / 128), 72 experts of 768 ten
+        a token and a shared expert of 1,536 after every one, tied
+        vocabulary of 100,352."""
+        period = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+        return cls(
+            vocab_size=100352, hidden_size=4096, intermediate_size=768,
+            num_hidden_layers=40, num_attention_heads=32,
+            num_key_value_heads=8, rope_theta=1e4,
+            rms_norm_eps=1e-5, tie_word_embeddings=True,
+            max_position_embeddings=131072, attention_bias=False,
+            model_type="granitemoehybrid", layer_types=period * 4,
+            rope_layout=(0,) * 40,
+            ssm_heads=128, ssm_head_dim=64, ssm_groups=1, ssm_state=128,
+            ssm_conv=4, ssm_chunk=256,
+            num_experts=72, num_experts_per_tok=10, norm_topk_prob=True,
+            n_shared_experts=1, shared_intermediate_size=1536,
+            embed_scale=12.0, attention_multiplier=0.0078125,
+            residual_multiplier=0.22, lm_head_multiplier=1 / 16,
+            expert_token_block=1024,
+        )
+
+    @classmethod
+    def granite_h_tiny(cls, vocab_size: int = 512,
+                       experts_held: int = 0,
+                       experts_offset: int = 0) -> "ModelConfig":
+        """Test-size Granite 4.0-H: `[mamba, mamba, attention, mamba]` twice,
+        4 / 2 heads of 16, a mixer of 4 heads of 16 with ONE group of state
+        8 in chunks of 4, 8 experts of 32 three a token, a shared expert of
+        48, and every multiplier at a value of its own that is neither 1 nor
+        its published default."""
+        kinds = ("mamba", "mamba", "attention", "mamba") * 2
+        return dataclasses.replace(
+            cls.granite_h_small(), vocab_size=vocab_size, hidden_size=64,
+            intermediate_size=32, num_hidden_layers=8,
+            num_attention_heads=4, num_key_value_heads=2,
+            max_position_embeddings=1024, layer_types=kinds,
+            rope_layout=(0,) * 8,
+            ssm_heads=4, ssm_head_dim=16, ssm_groups=1, ssm_state=8,
+            ssm_chunk=4, num_experts=8, num_experts_per_tok=3,
+            shared_intermediate_size=48, embed_scale=3.0,
+            attention_multiplier=0.2, residual_multiplier=0.6,
+            lm_head_multiplier=0.25, experts_held=experts_held,
+            experts_offset=experts_offset)
+
+    @classmethod
     def minicpm_sala(cls) -> "ModelConfig":
         """openbmb/MiniCPM-SALA (docs/SALA.md): 32 layers, 8 sparse
         (InfLLM-V2: 32 / 2 heads of 128, no rotary, top 64 blocks of 64) and
@@ -1087,7 +1183,8 @@ class ModelConfig:
         """Build from a `transformers` Qwen2Config / LlamaConfig / OlmoeConfig
         (or dict), or from A.X-K1's `config.json` (`model_type: axk1`,
         DeepSeek-V3's key set; `_axk1_from_hf`), SmallThinker's, LFM2-MoE's,
-        Trinity's (`afmoe`), Falcon-H1's (`falcon_h1`, docs/SSM.md) or SDAR's (`sdar_moe`: generation by diffusion
+        Trinity's (`afmoe`), Falcon-H1's (`falcon_h1`, docs/SSM.md), Granite
+        4.0-H's (`granitemoehybrid`, docs/GRANITE_H.md) or SDAR's (`sdar_moe`: generation by diffusion
         over blocks, docs/BLOCKDIFF.md), each by a parser of its own. A config the
         decoder does not implement raises: expert keys under any other model
         type (each family routes, scales and shares in its own way), and a
@@ -1112,6 +1209,8 @@ class ModelConfig:
             return cls._falcon_h1_from_hf(get)
         if model_type == "minicpm_sala":
             return cls._sala_from_hf(get)
+        if model_type == "granitemoehybrid":
+            return cls._granite_h_from_hf(get)
         if model_type == "sdar_moe":
             return cls._sdar_from_hf(get)
         if model_type == "ouro":
@@ -1496,6 +1595,82 @@ class ModelConfig:
             ssm_out_multiplier=float(get("ssm_out_multiplier", 1.0)),
             mlp_multipliers=mults["mlp_multipliers"],
             lm_head_multiplier=float(get("lm_head_multiplier", 1.0)),
+        )
+
+    @classmethod
+    def _granite_h_from_hf(cls, get) -> "ModelConfig":
+        """Granite 4.0-H's published keys (`model_type: granitemoehybrid`,
+        docs/GRANITE_H.md), and the chip's share where the file states one
+        (`num_experts_held` / `num_experts_offset`). What the
+        layers of docs/GRANITE_H.md do not compute raises, by the key's
+        name."""
+        def refuse(key, why):
+            raise ValueError(f"granitemoehybrid: {key}={get(key)!r} is not "
+                             f"implemented ({why}; docs/GRANITE_H.md)")
+
+        if str(get("position_embedding_type", "nope")) != "nope":
+            refuse("position_embedding_type", "the attention layers carry "
+                   "no position: only 'nope'")
+        if str(get("normalization_function", "rmsnorm")) != "rmsnorm":
+            refuse("normalization_function", "every norm is an RMSNorm")
+        for key in ("attention_bias", "mamba_proj_bias"):
+            if get(key):
+                refuse(key, "the projections carry no bias")
+        if get("mamba_conv_bias") is False:
+            refuse("mamba_conv_bias", "the mixer's convolution has a bias")
+        if get("rope_scaling"):
+            refuse("rope_scaling", "no rotary embedding at all")
+        if str(get("hidden_act", "silu")) != "silu":
+            refuse("hidden_act", "SiLU in the mixer and the experts")
+        L = int(get("num_hidden_layers"))
+        types = tuple(get("layer_types") or ())
+        if len(types) != L or set(types) - {"mamba", "attention"}:
+            refuse("layer_types", f"{L} entries of 'mamba' or 'attention'")
+        heads, head = int(get("mamba_n_heads")), int(get("mamba_d_head"))
+        hidden = int(get("hidden_size"))
+        if float(get("mamba_expand") or 0) * hidden != heads * head:
+            refuse("mamba_expand", f"mamba_n_heads * mamba_d_head = "
+                   f"{heads * head} over hidden_size = {hidden}")
+        groups = int(get("mamba_n_groups"))
+        if groups < 1 or heads % groups:
+            refuse("mamba_n_groups", f"must divide mamba_n_heads = {heads}")
+        if int(get("mamba_d_conv")) < 2:
+            refuse("mamba_d_conv", "a tail of K - 1 values needs K >= 2")
+        E = int(get("num_local_experts"))
+        held, offset = cls._chip_share(get, "granitemoehybrid", E,
+                                       "num_experts")
+        return cls(
+            vocab_size=get("vocab_size"),
+            hidden_size=hidden,
+            intermediate_size=int(get("intermediate_size")),
+            num_hidden_layers=L,
+            num_attention_heads=int(get("num_attention_heads")),
+            num_key_value_heads=int(get("num_key_value_heads")),
+            head_dim=get("head_dim", None),
+            rope_theta=float(get("rope_theta", 1e4)),
+            rms_norm_eps=get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=bool(get("tie_word_embeddings", True)),
+            max_position_embeddings=get("max_position_embeddings", 131072),
+            attention_bias=False,
+            model_type="granitemoehybrid",
+            layer_types=types,
+            rope_layout=(0,) * L,
+            ssm_heads=heads, ssm_head_dim=head, ssm_groups=groups,
+            ssm_state=int(get("mamba_d_state")),
+            ssm_conv=int(get("mamba_d_conv")),
+            ssm_chunk=int(get("mamba_chunk_size") or 256),
+            num_experts=E,
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            norm_topk_prob=True,    # softmax over the chosen logits
+            n_shared_experts=1 if get("shared_intermediate_size") else 0,
+            shared_intermediate_size=int(
+                get("shared_intermediate_size") or 0),
+            experts_held=held, experts_offset=offset,
+            embed_scale=float(get("embedding_multiplier", 1.0)),
+            attention_multiplier=float(get("attention_multiplier") or 0.0),
+            residual_multiplier=float(get("residual_multiplier") or 0.0),
+            lm_head_multiplier=1.0 / float(get("logits_scaling", 1.0)),
+            expert_token_block=1024,
         )
 
     @classmethod

@@ -202,8 +202,9 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
         kinds = config.layer_kinds[start:start + n]
         nc = sum(k == "conv" for k in kinds)
         nl = sum(k == "lightning" for k in kinds)
-        na = n - nc - nl
-        ns = sum(k == "hybrid" for k in kinds)  # (an attention layer too)
+        nm = sum(k == "mamba" for k in kinds)   # (a mixer alone: no attention)
+        na = n - nc - nl - nm
+        ns = nm + sum(k == "hybrid" for k in kinds)  # (an attention layer too)
         fan = lambda *shape: normal(shape, 1.0 / jnp.sqrt(shape[-2]))  # noqa: E731
         swiglu = lambda lead, width: {  # noqa: E731
             "gate_proj": {"kernel": fan(*lead, D, width)},
@@ -221,7 +222,7 @@ def _init_stacked_model_params(config: ModelConfig, key, dtype) -> dict:
                 tree["router"]["bias"] = jnp.zeros((n, experts), jnp.float32)
             if config.n_shared_experts:
                 tree["shared_expert"] = swiglu(
-                    (n,), config.n_shared_experts * width)
+                    (n,), config.shared_expert_width)
         else:
             tree.update(swiglu((n,), width))
         if nc:
@@ -1106,7 +1107,8 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
     on the one normed state, both added into the one residual: it takes its
     pages as `view` / `cache` like any attention layer and the state group's
     pair beside them as `state = (view, cache | None)`, and its new cache is
-    the pair `(pages, state)`.
+    the pair `(pages, state)`. A `"mamba"` layer is that mixer alone
+    (docs/GRANITE_H.md): its `view` / `cache` are the state group's.
 
     Returns (x_out, new_cache_or_None, mlp_aux_or_None)."""
     layer_params = leaves.tree
@@ -1120,6 +1122,10 @@ def _layer_body(config: ModelConfig, x, leaves: LayerLeaves, layer, kind,
                 config, x, h, leaves, layer, (False, True), view, cache, cos,
                 sin, lora_scale, attn_fn)
             x, new_cache = x + mixed, (new_cache, new_state)
+        elif kind == "mamba":   # the mixer alone (docs/GRANITE_H.md)
+            mixed, new_cache = _ssm_operator(
+                config, h, layer_params["ssm"], cache, layer, view)
+            x = x + _times(mixed, config.residual_scale)
         elif kind == "conv":
             x, new_cache = _conv_operator(
                 config, x, h, layer_params["conv"], cache, layer, view)
@@ -1191,7 +1197,8 @@ def _attention(config, x, h, leaves, layer, kind, view, cache, cos, sin,
     spmd = _kernel_spmd(config, H, KV)
     with jax.named_scope("attn.qkv"):
         h = _times(h, config.attention_in_multiplier)
-        q = _proj(h, layer_params, lora_layer, "q_proj", lora_scale)
+        q = _times(_proj(h, layer_params, lora_layer, "q_proj", lora_scale),
+                   config.query_multiplier)
         k = _times(_proj(h, layer_params, lora_layer, "k_proj", lora_scale),
                    config.key_multiplier)
         v = _proj(h, layer_params, lora_layer, "v_proj", lora_scale)
@@ -1405,11 +1412,26 @@ def _conv_operator(config, x, h, conv, state_group, layer, view):
             return x + y @ conv["out_proj"]["kernel"], new_group
 
 
+def _gated_norm(config: ModelConfig, y, z, weight):
+    """The mixer's output `y` [B, T, I] (float32) times `silu(z)`, THEN an
+    RMSNorm over each group's I / G channels, times `weight` (the gate
+    before the norm, as published; benchmark/tools/mamba_control.py lays the
+    other order in here to show that the comparison refuses it)."""
+    f32 = jnp.float32
+    B, T, I = y.shape
+    y = y * jax.nn.silu(z.astype(f32))
+    y = y.reshape(B, T, config.ssm_groups, I // config.ssm_groups)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + config.rms_norm_eps)
+    return y.reshape(B, T, I) * weight.astype(f32)
+
+
 def _ssm_operator(config, h, ssm, state_group, layer, view):
-    """A hybrid layer's state-space mixer on the normed state `h`
-    (docs/SSM.md; Mamba-2 with Falcon-H1's multipliers): `(the mixer's
-    branch [B, T, D], the updated state group | None)`; the caller adds it
-    into the residual beside the attention's.
+    """A layer's state-space mixer on the normed state `h` (docs/SSM.md;
+    Mamba-2 with Falcon-H1's multipliers, every one 1.0 for a model without
+    them): `(the mixer's branch [B, T, D], the updated state group | None)`;
+    the caller adds it into the residual, beside the attention's (a
+    `"hybrid"` layer) or alone (a `"mamba"` layer, docs/GRANITE_H.md).
 
     `[z | xs | B | C | dt] = ((h ssm_in) W_in) * mup` (`W_in`'s last H
     columns are the leaf `dt_proj`: `_init_ssm`); `[xs | B | C]` through
@@ -1462,8 +1484,20 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
                 row = 0 if state_rows is None else state_rows[0, 0]
                 past = _tail_read(tail_stack, layer, row, B, fresh)
                 if T > 1:   # (a cached step passes over `S` where it lies)
+                    # a row's state is cut out of the stack seen as `[L,
+                    # rows, H P, N]` (the same tiles), so the layout the
+                    # scan wants of `[H, P, N]` is the slice's to take. Cut
+                    # out of `[L, rows, H, P, N]`, a piece of ONE chunk at
+                    # H 128 / P 64 / N 128 had the chip's compiler relay
+                    # the WHOLE stack heads-inside and back, two copies of
+                    # 1.8 GB in every admission's closing forward (compiled
+                    # for a described v5e and traced on the chip, PR 59;
+                    # fences did not hold it: a slice takes its operand's
+                    # layout)
                     before = jax.lax.dynamic_slice(
-                        s_stack, (layer, row, 0, 0, 0), (1, B, H, P, N))[0]
+                        s_stack.reshape(s_stack.shape[:2] + (H * P, N)),
+                        (layer, row, 0, 0), (1, B, H * P, N)
+                    ).reshape(B, H, P, N)
                     if fresh is not None:
                         before = jnp.where(fresh[:, None, None, None], 0,
                                            before)
@@ -1495,19 +1529,17 @@ def _ssm_operator(config, h, ssm, state_group, layer, view):
                                         config.ssm_chunk)
         with jax.named_scope("attn.ssm.gate"):
             y = y + ssm["D"].astype(f32)[:, None] * xs
-            y = y.reshape(B, T, I) * jax.nn.silu(z.astype(f32))
-            y = y.reshape(B, T, G, I // G)
-            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                                  + config.rms_norm_eps)
-            y = (y.reshape(B, T, I) * ssm["norm"].astype(f32)).astype(h.dtype)
+            y = _gated_norm(config, y.reshape(B, T, I), z,
+                            ssm["norm"]).astype(h.dtype)
         new_group = None
         if state_group is not None:
             with jax.named_scope("attn.write"):
                 new_group = (
                     _tail_write(tail_stack, seq, T, valid, layer, row),
                     s_stack if T == 1 else jax.lax.dynamic_update_slice(
-                        s_stack, after[None].astype(s_stack.dtype),
-                        (layer, row, 0, 0, 0)))
+                        s_stack.reshape(s_stack.shape[:2] + (H * P, N)),
+                        after.reshape(1, B, H * P, N).astype(s_stack.dtype),
+                        (layer, row, 0, 0)).reshape(s_stack.shape))
         with jax.named_scope("attn.ssm.out"):
             return _times(y @ ssm["out_proj"]["kernel"],
                           config.ssm_out_multiplier), new_group
@@ -1904,10 +1936,11 @@ def _kind_group(kind) -> int:
     (every layer of a model without a pattern), 1 the window layers', 2 the
     conv layers' state. A hybrid layer's attention keeps pages of group 0;
     its mixer's state lies in group 2 at the same index (every layer of such
-    a model is hybrid: core/config.py)."""
+    a model is hybrid: core/config.py). A mixer alone in its layer
+    (`"mamba"`) keeps a state and no pages, like a conv layer."""
     if kind in ("hybrid", "sparse"):
         return 0
-    return 2 if kind in ("conv", "lightning") else int(kind[0])
+    return 2 if kind in ("conv", "lightning", "mamba") else int(kind[0])
 
 
 def leaves_in_place(config: ModelConfig, cached: bool,
@@ -2004,8 +2037,10 @@ def _run_layers(config, params, x, cos, sin, views, kv_caches=None,
         caches = (tuple(kv_caches),) if plain else tuple(kv_caches)
     in_place = leaves_in_place(config, cached, layer_transform)
     # (the leaves of the kind that keeps a state and no pages)
-    own = "lightning" if config.linear_layers else "conv"
-    split = config.conv_layers + config.linear_layers > 0
+    own = ("lightning" if config.linear_layers
+           else "ssm" if config.mamba_layers else "conv")
+    split = (config.conv_layers + config.linear_layers
+             + config.mamba_layers) > 0
 
     def one_pass(x, caches, offset=None):
         """Every stack's scan, once; `offset`: what a looped model's pass
@@ -2598,15 +2633,15 @@ def _int8_cache(config: ModelConfig) -> bool:
 def _pattern_caches(config: ModelConfig) -> tuple:
     """(global layers, window layers) of a pattern model: the depths of its
     two groups of cache stacks."""
-    return (config.num_hidden_layers - config.window_layers
-            - config.conv_layers - config.linear_layers, config.window_layers)
+    return (config.page_layers - config.window_layers, config.window_layers)
 
 
 def _state_group(config: ModelConfig, rows: int, dtype) -> tuple:
     """The state group of a cache, in one of its two forms (docs/STATE.md);
     nothing for a model without a state. Conv layers: `((state,),)`, `[conv
     layers, K - 1, rows, D]`, a row's last K - 1 values of `g` a layer,
-    oldest first (`_conv_operator`). Hybrid layers: `((tail, S),)`, the
+    oldest first (`_conv_operator`). Layers with a state-space mixer
+    (`config.ssm_layers`: beside an attention or alone): `((tail, S),)`, the
     convolution's tail `[layers, K - 1, rows, I + 2 G N]` likewise and the
     recurrence's state `[layers, rows, H, P, N]` in FLOAT32 whatever the
     cache's type (`_ssm_operator`; docs/SSM.md). Not a page: its size does

@@ -449,6 +449,97 @@ def _falcon_h1_sd_from_params(config: ModelConfig, params: dict, put) -> None:
             put(f"{pre}{theirs}", ssm[ours][i])
 
 
+# Granite 4.0-H (docs/GRANITE_H.md; names assumed from the family's modelling
+# code, `GraniteMoeHybrid` on Bamba's mixer: the published checkpoint is not
+# on this machine): a layer holds `mamba.*` OR `self_attn.*`, then
+# `block_sparse_moe.{router.layer, input_linear [E, 2 F, D] ([gate; up]),
+# output_linear [E, D, F]}` and `shared_mlp.{input_linear [2 Fs, D],
+# output_linear [D, Fs]}`. A chip's share reads and writes ITS experts' rows.
+_GH_NORMS = (("input_layernorm", "input_layernorm"),
+             ("post_attention_layernorm", "post_attention_layernorm"))
+_GH_ATTENTION = (("q_proj", "self_attn.q_proj"), ("k_proj", "self_attn.k_proj"),
+                 ("v_proj", "self_attn.v_proj"), ("o_proj", "self_attn.o_proj"))
+
+
+def _granite_h_params_from_sd(config: ModelConfig, sd: dict, cast) -> dict:
+    kinds = config.layer_kinds
+    ids = range(config.num_hidden_layers)
+    mixers = [i for i in ids if kinds[i] == "mamba"]
+    attn = [i for i in ids if kinds[i] != "mamba"]
+    at = lambda i, name: sd[f"model.layers.{i}.{name}"]             # noqa: E731
+    over = lambda which, name, f=lambda a: a: cast(np.stack(        # noqa: E731
+        [f(at(i, name)) for i in which]))
+    T = lambda a: a.T                                               # noqa: E731
+    F, Fs = config.expert_width, config.shared_expert_width
+    n_held = config.num_held_experts
+    # (a share's own export holds its experts' rows alone)
+    own = lambda a: a if a.shape[0] == n_held else a[                # noqa: E731
+        config.experts_offset:config.experts_offset + n_held]
+    moe, shared = "block_sparse_moe.", "shared_mlp."
+    tree = {ours: over(ids, theirs + ".weight") for ours, theirs in _GH_NORMS}
+    tree["router"] = {"kernel": over(ids, moe + "router.layer.weight", T)}
+    tree["experts"] = {
+        "gate_proj": {"kernel": over(ids, moe + "input_linear.weight",
+                                     lambda a: own(a)[:, :F].transpose(0, 2, 1))},
+        "up_proj": {"kernel": over(ids, moe + "input_linear.weight",
+                                   lambda a: own(a)[:, F:].transpose(0, 2, 1))},
+        "down_proj": {"kernel": over(ids, moe + "output_linear.weight",
+                                     lambda a: own(a).transpose(0, 2, 1))}}
+    tree["shared_expert"] = {
+        "gate_proj": {"kernel": over(ids, shared + "input_linear.weight",
+                                     lambda a: a[:Fs].T)},
+        "up_proj": {"kernel": over(ids, shared + "input_linear.weight",
+                                   lambda a: a[Fs:].T)},
+        "down_proj": {"kernel": over(ids, shared + "output_linear.weight", T)}}
+    for ours, theirs in _GH_ATTENTION:
+        tree[ours] = {"kernel": over(attn, theirs + ".weight", T)}
+    tree["ssm"] = {
+        "in_proj": {"kernel": over(mixers, "mamba.in_proj.weight",
+                                   lambda a: a[:-config.ssm_heads].T)},
+        "dt_proj": {"kernel": over(mixers, "mamba.in_proj.weight",
+                                   lambda a: a[-config.ssm_heads:].T)},
+        "conv": {"kernel": over(mixers, "mamba.conv1d.weight",
+                                lambda a: a[:, 0, :].T),
+                 "bias": over(mixers, "mamba.conv1d.bias")},
+        "out_proj": {"kernel": over(mixers, "mamba.out_proj.weight", T)},
+        **{ours: over(mixers, theirs) for ours, theirs in _FH1_VECTORS}}
+    return {"layers": tree}
+
+
+def _granite_h_sd_from_params(config: ModelConfig, params: dict, put) -> None:
+    """(a chip's share writes `[held, ...]` stacks of ITS experts: no whole
+    checkpoint, as `export_hf_checkpoint`'s config says)"""
+    tree = params["layers"]
+    ssm, place = tree["ssm"], {"mamba": 0, "attn": 0}
+    for i, kind in enumerate(config.layer_kinds):
+        pre = f"model.layers.{i}."
+        for ours, theirs in _GH_NORMS:
+            put(f"{pre}{theirs}.weight", tree[ours][i])
+        put(f"{pre}block_sparse_moe.router.layer.weight",
+            tree["router"]["kernel"][i].T)
+        for stack, name in ((tree["experts"], "block_sparse_moe."),
+                            (tree["shared_expert"], "shared_mlp.")):
+            gate, up, down = (stack[k]["kernel"][i] for k in _MLP_KEYS)
+            put(f"{pre}{name}input_linear.weight", jnp.concatenate(
+                [jnp.swapaxes(gate, -1, -2), jnp.swapaxes(up, -1, -2)], -2))
+            put(f"{pre}{name}output_linear.weight", jnp.swapaxes(down, -1, -2))
+        if kind != "mamba":
+            a = place["attn"]
+            place["attn"] += 1
+            for ours, theirs in _GH_ATTENTION:
+                put(f"{pre}{theirs}.weight", tree[ours]["kernel"][a].T)
+            continue
+        m = place["mamba"]
+        place["mamba"] += 1
+        put(f"{pre}mamba.in_proj.weight", jnp.concatenate(
+            [ssm["in_proj"]["kernel"][m].T, ssm["dt_proj"]["kernel"][m].T]))
+        put(f"{pre}mamba.conv1d.weight", ssm["conv"]["kernel"][m].T[:, None, :])
+        put(f"{pre}mamba.conv1d.bias", ssm["conv"]["bias"][m])
+        put(f"{pre}mamba.out_proj.weight", ssm["out_proj"]["kernel"][m].T)
+        for ours, theirs in _FH1_VECTORS:
+            put(f"{pre}{theirs}", ssm[ours][m])
+
+
 def _to_np(t) -> np.ndarray:
     """torch tensor / np array → np array (bf16-safe via float32 round-trip)."""
     if hasattr(t, "detach"):
@@ -478,6 +569,13 @@ def params_from_hf_state_dict(
         return params
     if config.linear_layers:
         params = _sala_params_from_sd(config, sd, cast)
+        params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
+                      norm=cast(sd["model.norm.weight"]))
+        if not config.tie_word_embeddings:
+            params["lm_head"] = cast(sd["lm_head.weight"].T)
+        return params
+    if config.mamba_layers:
+        params = _granite_h_params_from_sd(config, sd, cast)
         params.update(embed_tokens=cast(sd["model.embed_tokens.weight"]),
                       norm=cast(sd["model.norm.weight"]))
         if not config.tie_word_embeddings:
@@ -558,6 +656,13 @@ def hf_state_dict_from_params(config: ModelConfig, params: dict,
     linear_keys, norm_keys = _layer_keys(config)
     if config.linear_layers:
         _sala_sd_from_params(config, params, put)
+        put("model.embed_tokens.weight", params["embed_tokens"])
+        put("model.norm.weight", params["norm"])
+        if not config.tie_word_embeddings:
+            put("lm_head.weight", params["lm_head"].T)
+        return sd
+    if config.mamba_layers:
+        _granite_h_sd_from_params(config, params, put)
         put("model.embed_tokens.weight", params["embed_tokens"])
         put("model.norm.weight", params["norm"])
         if not config.tie_word_embeddings:
@@ -661,7 +766,7 @@ def export_hf_checkpoint(
     # back to the attention_bias heuristic, as do random-init configs.
     family = config.model_type if config.model_type in (
         "qwen2", "llama", "olmoe", "axk1", "smallthinker", "lfm2_moe",
-        "afmoe", "sdar_moe", "falcon_h1", "ouro") else (
+        "afmoe", "sdar_moe", "falcon_h1", "ouro", "granitemoehybrid") else (
         "qwen2" if config.attention_bias else "llama")
     arch = {"qwen2": "Qwen2ForCausalLM", "llama": "LlamaForCausalLM",
             "olmoe": "OlmoeForCausalLM", "axk1": "AXK1ForCausalLM",
@@ -670,7 +775,8 @@ def export_hf_checkpoint(
             "afmoe": "AfmoeForCausalLM",
             "sdar_moe": "SDARMoeForCausalLM",
             "falcon_h1": "FalconH1ForCausalLM",
-            "ouro": "OuroForCausalLM"}[family]
+            "ouro": "OuroForCausalLM",
+            "granitemoehybrid": "GraniteMoeHybridForCausalLM"}[family]
     hf_config = {
         "architectures": [arch],
         "model_type": family,
@@ -752,6 +858,29 @@ def export_hf_checkpoint(
             ssm_out_multiplier=config.ssm_out_multiplier,
             mlp_multipliers=list(config.mlp_multipliers),
             lm_head_multiplier=config.lm_head_multiplier)
+    elif family == "granitemoehybrid":
+        del hf_config["head_dim"]
+        hf_config.update(
+            layer_types=["mamba" if k == "mamba" else "attention"
+                         for k in config.layer_kinds],
+            position_embedding_type="nope", normalization_function="rmsnorm",
+            mamba_n_heads=config.ssm_heads, mamba_d_head=config.ssm_head_dim,
+            mamba_n_groups=config.ssm_groups, mamba_d_state=config.ssm_state,
+            mamba_d_conv=config.ssm_conv, mamba_chunk_size=config.ssm_chunk,
+            mamba_expand=config.ssm_inner // config.hidden_size,
+            mamba_conv_bias=True, mamba_proj_bias=False,
+            num_local_experts=config.num_experts,
+            num_experts_per_tok=config.num_experts_per_tok,
+            shared_intermediate_size=config.shared_expert_width,
+            embedding_multiplier=config.embed_scale,
+            attention_multiplier=config.attention_multiplier,
+            residual_multiplier=config.residual_multiplier,
+            logits_scaling=1.0 / config.lm_head_multiplier,
+            rope_scaling=None)
+        if config.experts_held:     # a chip's share is no whole checkpoint
+            hf_config.update(
+                num_experts_held=config.experts_held,
+                num_experts_offset=config.experts_offset)
     elif family == "afmoe":
         L = config.num_hidden_layers
         del hf_config["attention_bias"]

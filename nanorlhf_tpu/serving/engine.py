@@ -736,7 +736,17 @@ class ServingEngine:
             # a state that is not a page (docs/STATE.md): 0 layers and bytes
             # for every model without conv layers
             "serving/state_layers": self._sess.state_layers,
+            "serving/page_layers": self._sess.config.page_layers,
             "serving/state_bytes_per_row": self._sess.state_bytes_per_row,
+            # what the live rows held of each, summed over the decode steps
+            # so far: a state a row, a page slot a token inside the bounds
+            "serving/state_live_bytes": (self._sess.live_row_steps
+                                         * self._sess.state_bytes_per_row),
+            "serving/page_live_bytes": (
+                self._sess.global_slots_read
+                * self._sess.kv_bytes_per_token_global
+                + self._sess.window_slots_read
+                * self._sess.kv_bytes_per_token_window),
             "serving/state_resets": self._sess.state_resets,
             "serving/state_piece_carries": self._sess.state_piece_carries,
             "serving/state_tokens": self._sess.state_tokens,

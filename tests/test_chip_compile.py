@@ -1381,6 +1381,149 @@ def _falcon_h1_session_program(case, v5e):
     return lowered.compile(), cache, cfg
 
 
+def _granite_h_session_program(case, v5e):
+    """The `serve-granite-h-ragchat` cell's decode chunk, its KV-only prefill
+    piece or its closing suffix forward, lowered for a described v5e at the
+    configuration file's own cut (granite-4.0-h-small's published widths, ten
+    layers, 36 of 72 experts, half of the vocabulary) and the cell's engine
+    sizes: `(compiled, cache shapes, config)`."""
+    import json
+    import os
+
+    from nanorlhf_tpu.core import ModelConfig, init_params
+    from nanorlhf_tpu.core import model as M
+    from nanorlhf_tpu.sampler.paged import session
+    from nanorlhf_tpu.serving import radix
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs",
+                           "granite-4.0-h-small-ep2-l10.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    with open(os.path.join(bench, "traffic", "ragchat-steady.json")) as f:
+        eng = json.load(f)["engine"]
+    one_chip = SingleDeviceSharding(v5e[0])
+    params = _shapes_on(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)), one_chip)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Tp, new, chunk = (eng["rows"], eng["prompt_len"], eng["max_new_tokens"],
+                         eng["prefill_chunk"])
+    if case == "suffix_bucket":     # a closing forward of a few tokens
+        chunk = 8
+    nb = (Tp + new) // PAGE
+    # (the engine's pool at headroom 0: every row's pages and one row's spare)
+    cache = jax.eval_shape(lambda: M.init_paged_kv_cache(
+        cfg, (R * nb + nb, R), PAGE, jnp.bfloat16, state_rows=R))
+    if case == "decode_chunk":
+        key = _shapes_on(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+        state = (spec((), jnp.int32), spec((R, new), jnp.int32),
+                 spec((R, new), jnp.float32), _shapes_on(cache, one_chip),
+                 spec((R, Tp + new), jnp.bool_), spec((R,), jnp.bool_),
+                 spec((R,), jnp.int32), spec((R,), jnp.int32),
+                 spec((R,), jnp.int32), key)
+        tables = (spec((R, nb), jnp.int32),) * 2 + (spec((R, 1), jnp.int32),)
+        lowered = session._serving_chunk.lower(
+            params, cfg, state, tables, spec((R,), jnp.float32),
+            spec((R,), jnp.float32), spec((R,), jnp.bool_),
+            spec((R,), jnp.int32), Tp=Tp, max_tokens=new, page_size=PAGE,
+            sync_every=eng["sync_every"], eos_token_id=1, pad_token_id=0,
+            temperature=1.0, top_p=1.0, greedy=False, lora_scale=1.0, top_k=64,
+            capture_logprobs=False, approx_top_k=True)
+    else:
+        row = (spec((nb,), jnp.int32),) * 2 + (spec((1,), jnp.int32),)
+        args = (params, cfg, spec((1, chunk), jnp.int32),
+                spec((1, chunk), jnp.int32), spec((1,), jnp.int32))
+        tail = (spec((1, Tp + new), jnp.bool_), _shapes_on(cache, one_chip), row)
+        if case == "prefill_piece":
+            lowered = session._prefill_chunk_fwd.lower(
+                *args, *tail, page_size=PAGE, lora_scale=1.0)
+        else:
+            lowered = radix.suffix_logits.lower(
+                *args, spec((), jnp.int32), *tail, page_size=PAGE,
+                lora_scale=1.0)
+    return lowered.compile(), cache, cfg
+
+
+@pytest.mark.parametrize("case", [
+    "decode_chunk", "suffix_bucket",
+    # (35-40 s of compile each: outside the tier-1 run, whose whole has 1,470 s)
+    pytest.param("prefill_piece", marks=pytest.mark.slow),
+    pytest.param("suffix", marks=pytest.mark.slow)])
+def test_granite_h_session_programs_fit_the_chip_with_pages_and_state_in_place_on_v5e(
+        case, v5e, compiled_kernels, monkeypatch):
+    """ISSUE 59, asked of the chip's compiler at the `serve-granite-h-ragchat`
+    cell's own shapes (9.51 GB of bf16 weights; 48 rows of 8,960 slots, pages
+    of 128: a pool of 3,430 pages x ONE attention layer, 1.80 GB; a state of
+    48 rows x NINE mixer layers, the tail `bf16[9,3,48,8448]` and the
+    recurrent state `f32[9,48,128,64,128]`, 1.83 GB): the session's decode
+    chunk, its 1,024-token KV-only prefill piece and its closing suffix
+    forward each stay under 15 GB and alias the pool AND both state leaves
+    from their parameters to their results; no module holds a `copy` of a
+    pool leaf or of the recurrent state; the T = 1 read is the in-place
+    kernel (`%attn.global*`, one layer of ten), a piece's the paged prefill
+    kernel, and the decode step's pass over the state is
+    `ops/ssm.ssm_update_in_place`'s call at (H, P, G, N) = (128, 64, 1, 128)
+    (`%attn.ssm.update*`, which takes the whole stack and returns it: nine a
+    step); no kernel of the mixer's input projection (`bf16[9,4096,16640]`,
+    130 whole lane tiles, `dt`'s 128 columns a leaf of their own as
+    Falcon-H1's are) is relaid: every `copy` of a weight is a prefetch in
+    the stored layout. `suffix_bucket`: the closing forward of an 8-token
+    bucket, ONE chunk of the scan, where the compiler relaid the whole
+    state heads-inside and back (two copies of 1.8 GB, 1.86 GB of
+    temporaries) until `_ssm_operator` cut a row's state out of the stack
+    seen as `[L, rows, H P, N]`."""
+    import re
+
+    from test_cache_carry import _computations, _shapes, hlo_stacks
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, cache, cfg = _granite_h_session_program(case, v5e)
+    assert (cfg.ssm_layers, cfg.page_layers, cfg.experts_held) == (9, 1, 36)
+    hlo = compiled.as_text()
+    kept = hlo_stacks([leaf for leaf in jax.tree.leaves(cache) if leaf.size])
+    S = ("f32", (9, 48, 128, 64, 128))
+    assert set(kept) == {("bf16", (1, 3430, 8, PAGE, 128)),
+                         ("bf16", (9, 3, 48, 8448)), S}
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 13.0e9 < m.argument_size_in_bytes < 13.3e9   # weights, pool, state
+    assert m.alias_size_in_bytes > 3.6e9                # pool and state donated
+    assert peak < 15.0e9, (case, peak, m.temp_size_in_bytes)
+    assert m.temp_size_in_bytes < (
+        0.1e9 if case in ("decode_chunk", "suffix_bucket") else 0.6e9)
+    comps = _computations(hlo)
+    everything = [i for instrs in comps.values() for i in instrs]
+    # (the state also as the operator sees it when it cuts a row out)
+    held = {k for k in kept if k[1] != (9, 3, 48, 8448)} | {
+        ("f32", (9, 48, 8192, 128))}
+    copies = [f"{name}: {result} {op}" for name, result, op, _ in everything
+              if op.startswith("copy") and set(_shapes(result)) & held]
+    assert not copies, "\n".join(copies)
+    # a weight's copy is its prefetch, in the layout it is stored in
+    relaid = [line.strip()[:160] for line in hlo.splitlines()
+              if re.match(r"\s*%copy[\w.\-]* = bf16\[\d+,(4096|8192),", line)
+              and not re.search(r"\{2,1,0:", line)]
+    assert not relaid, "\n".join(relaid)
+    calls = [name for name, _, op, _ in everything if op == "custom-call"]
+    reads = [c for c in calls if c.startswith(("attn.global", "attn.window",
+                                               "paged_prefill"))]
+    updates = [result for name, result, op, _ in everything
+               if op == "custom-call" and name.startswith("attn.ssm.update")]
+    if case == "decode_chunk":
+        assert reads and all(c.startswith("attn.global") for c in reads), reads
+        assert len(updates) == 9 and all(S in _shapes(r) for r in updates)
+    else:
+        assert reads and all(c.startswith("paged_prefill") for c in reads)
+        assert not updates      # a piece scans; only a step updates in place
+    assert sum(c.startswith("gmm") for c in calls) >= 27
+    # no gathered view of the row's pages: [.., 8960, 128] by slot
+    assert not re.findall(r"bf16\[\d+,8,8960,128\]", hlo)
+
+
 @pytest.mark.parametrize("case", ["decode_chunk", "prefill_piece", "suffix"])
 def test_falcon_h1_session_programs_fit_the_chip_with_pages_and_state_in_place_on_v5e(
         case, v5e, compiled_kernels, monkeypatch):

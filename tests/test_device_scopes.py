@@ -46,6 +46,7 @@ KINDS = {
     "conv": lambda: ModelConfig.lfm2_tiny(vocab_size=256),
     "gated": lambda: ModelConfig.trinity_tiny(vocab_size=256, window=16),
     "hybrid": lambda: ModelConfig.falcon_h1_tiny(vocab_size=256),
+    "mamba": lambda: ModelConfig.granite_h_tiny(vocab_size=256),
     "sala": lambda: ModelConfig.minicpm_sala_tiny(vocab_size=256),
 }
 # what a layer's attention is made of, by kind (the older families are the
@@ -66,6 +67,11 @@ ATTENTION = {
     "hybrid": {"attn.qkv", "attn.write", "attn.global", "attn.out",
                "attn.ssm", "attn.ssm.in", "attn.ssm.conv", "attn.ssm.gate",
                "attn.ssm.out"},
+    # the same mixer ALONE in its layers beside attention layers without
+    # rotary (docs/GRANITE_H.md): no scope of its own
+    "mamba": {"attn.qkv", "attn.write", "attn.global", "attn.out",
+              "attn.ssm", "attn.ssm.in", "attn.ssm.conv", "attn.ssm.gate",
+              "attn.ssm.out"},
     # a lightning mixer in some layers, a sparse attention in the others
     # (docs/SALA.md): the recurrence's two forms and a piece that selects
     # have a test of their own
@@ -203,7 +209,7 @@ def session_programs(kind, **config):
         pages = (pages, ROWS * ring_blocks(cfg.sliding_window, PAGE, PAGE))
         table, row_table = (table,) * 2, (row_table,) * 2
     state_rows = {}
-    if kind in ("conv", "hybrid", "sala"):  # no window layer; the state's rows ride third
+    if kind in ("conv", "hybrid", "mamba", "sala"):  # no window layer; the state's rows ride third
         pages, state_rows = (pages, ROWS), {"state_rows": ROWS}
         table = (table,) * 2 + (spec((ROWS, 1), jnp.int32),)
         row_table = (row_table,) * 2 + (spec((1,), jnp.int32),)

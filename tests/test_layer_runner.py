@@ -52,14 +52,17 @@ def loop_layers(config, params, x, cos, sin, views, kv_caches=None,
                 experts = tree.pop("experts", None)
             own = [0, 0]    # this stack's attention layers and conv layers so far
             # (the leaves of the kind that keeps a state and no pages)
-            stateful = "lightning" if config.linear_layers else "conv"
+            stateful = ("lightning" if config.linear_layers
+                        else "ssm" if config.mamba_layers else "conv")
+            split = (config.conv_layers + config.linear_layers
+                     + config.mamba_layers)
             auxes = []
             for at, kind in enumerate(config.layer_kinds[start:start + count]):
                 g = M._kind_group(kind)
                 layer_params = {}
                 for name, leaf in tree.items():
                     mine = name == stateful or name in M._ATTENTION_LEAVES
-                    if config.conv_layers + config.linear_layers and mine:
+                    if split and mine:
                         if (name == stateful) == (g == 2):
                             layer_params[name] = jax.tree.map(
                                 lambda a: a[own[int(g == 2)]], leaf)
@@ -325,7 +328,8 @@ _KINDS = {"qwen2": ModelConfig.qwen2_tiny, "olmoe": ModelConfig.olmoe_tiny,
           "smallthinker": ModelConfig.smallthinker_tiny,
           "lfm2": ModelConfig.lfm2_tiny, "trinity": ModelConfig.trinity_tiny,
           "sala": ModelConfig.minicpm_sala_tiny,
-          "ouro": ModelConfig.ouro_tiny}
+          "ouro": ModelConfig.ouro_tiny,
+          "granite_h": ModelConfig.granite_h_tiny}
 
 
 def _every_kind(monkeypatch, kind, cache):

@@ -15,6 +15,7 @@ PRESETS = {
     "smallthinker_tiny": {"window"},
     "lfm2_tiny": {"conv"},
     "falcon_h1_tiny": {"ssm"},
+    "granite_h_tiny": {"ssm"},
     "minicpm_sala_tiny": {"linear", "sparse"},
     "trinity_tiny": {"window"},
     "sdar_tiny": {"block"},
@@ -32,7 +33,7 @@ SAYS = {
     "loop": ("looped model", "docs/OURO.md"),
 }
 PATTERN = {"smallthinker_tiny", "trinity_tiny", "lfm2_tiny", "falcon_h1_tiny",
-           "minicpm_sala_tiny"}
+           "granite_h_tiny", "minicpm_sala_tiny"}
 # mechanism -> the presets it refuses (every other it takes)
 REFUSES = {
     "a rollout": {"sdar_tiny"},
@@ -44,12 +45,13 @@ REFUSES = {
     "kv_cache_quant='int8'": PATTERN | {"axk1_tiny", "sdar_tiny", "ouro_tiny"},
     "a rollout under a mesh": {"sdar_tiny", "ouro_tiny"},
     "a mesh under a decode session": {
-        "lfm2_tiny", "falcon_h1_tiny", "minicpm_sala_tiny", "sdar_tiny",
-        "ouro_tiny"},
+        "lfm2_tiny", "falcon_h1_tiny", "granite_h_tiny", "minicpm_sala_tiny",
+        "sdar_tiny", "ouro_tiny"},
     "the sequence-parallel forward": PATTERN | {"axk1_tiny", "ouro_tiny"},
-    "a LoRA adapter": {"falcon_h1_tiny", "minicpm_sala_tiny", "sdar_tiny"},
-    "training": {"lfm2_tiny", "falcon_h1_tiny", "minicpm_sala_tiny",
-                 "sdar_tiny"},
+    "a LoRA adapter": {"falcon_h1_tiny", "granite_h_tiny",
+                       "minicpm_sala_tiny", "sdar_tiny"},
+    "training": {"lfm2_tiny", "falcon_h1_tiny", "granite_h_tiny",
+                 "minicpm_sala_tiny", "sdar_tiny"},
 }
 
 
