@@ -96,6 +96,7 @@ from nanorlhf_tpu.sampler.sampler import (
     _prefill_state,
     _sample_token,
     _token_logprob,
+    sample_picks,
 )
 from nanorlhf_tpu.utils.donation import jit_donating
 from nanorlhf_tpu.utils.profiling import PhaseTimer
@@ -1074,6 +1075,12 @@ class DecodeSession:
         # `_over_needed`)
         self.sample_rows = 0
         self.sample_slots = 0
+        # static: the sizes of `needed_sizes` whose branch takes its
+        # candidates by selection (`serving/sample_pick_sizes`)
+        self.sample_pick_sizes = tuple(
+            s for s in needed_sizes(R * max(self.block, 1))
+            if sample_picks((s, config.vocab_size), top_k, approx_top_k)
+        ) if per_row and not self.spec else ()
         # the host's record of the carry, as of the last sync and the
         # admissions and cancels since: what other threads may read
         self._done_np = np.ones((R,), bool)

@@ -35,6 +35,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from nanorlhf_tpu.core.config import ModelConfig
 from nanorlhf_tpu.core.model import (
@@ -42,7 +43,14 @@ from nanorlhf_tpu.core.model import (
     init_paged_kv_cache, prefill, use_paged_decode_kernel,
 )
 from nanorlhf_tpu.ops.masking import guard_temperature
+from nanorlhf_tpu.ops.top_select import take_at, top_k_select
 from nanorlhf_tpu.sampler.paged.pages import full_table
+
+# rows a call scores from which `_nucleus_candidates` takes its k best of
+# `approx_max_k`'s candidates by selection (`ops/top_select.py`); below, XLA's
+# own aggregation, a sort of every candidate, is the cheaper
+# (tools/bench_sample_pick.py; PERF.md PR 60 has the table)
+_PICK_ROWS = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,16 +80,21 @@ class SamplingParams:
     # use jax.lax.approx_max_k for the top-k pre-trim: XLA lowers exact
     # lax.top_k to a FULL VOCAB SORT on TPU, which at LLM vocabularies can
     # dominate the decode step; ApproxTopK is the hardware-native O(V) path
-    # (exact on CPU). The candidate SET becomes approximate (recall 0.99 per
-    # candidate, NOT rank-restricted): a missed in-nucleus token cannot be
-    # sampled that step, and the exclusive-cumsum keep rule then undercounts,
-    # letting the boundary widen slightly past top_p. The sampling
-    # distribution therefore deviates from the exact truncated nucleus —
-    # acceptable for RL rollouts, where the ratio math scores the SAMPLED
-    # token's full-distribution logprob (exact either way; the
-    # truncated-vs-full mismatch is inherent to nucleus sampling and present
-    # in the reference's vLLM path too). Set False for the exact candidate
-    # set (full-sort cost on TPU).
+    # (exact on CPU): a partial reduce of the vocabulary to C candidates
+    # (9,600 of 151,936), of which the k best are kept in descending order.
+    # XLA's own aggregation SORTS all C for that; from `_PICK_ROWS` rows on
+    # `_nucleus_candidates` takes the unaggregated candidates and picks the
+    # k by selection instead (`ops/top_select.py`: the same set and values,
+    # equal values the lower candidate first). The candidate SET is
+    # approximate (recall 0.99 per candidate, NOT rank-restricted): a missed
+    # in-nucleus token cannot be sampled that step, and the exclusive-cumsum
+    # keep rule then undercounts, letting the boundary widen slightly past
+    # top_p. The sampling distribution therefore deviates from the exact
+    # truncated nucleus — acceptable for RL rollouts, where the ratio math
+    # scores the SAMPLED token's full-distribution logprob (exact either
+    # way; the truncated-vs-full mismatch is inherent to nucleus sampling
+    # and present in the reference's vLLM path too). Set False for the exact
+    # candidate set (full-sort cost on TPU).
     approx_top_k: bool = True
     # >0 switches the KV cache to the PAGED layout (sampler/paged/,
     # docs/PAGED_CACHE.md): K/V live in a global pool of page_size-token
@@ -271,6 +284,16 @@ def top_p_filter_bisect(logits: jnp.ndarray, top_p: float,
     return jnp.where(probs >= lo, logits, -jnp.inf)
 
 
+def sample_picks(shape, top_k: int, approx_top_k: bool) -> bool:
+    """Whether `_nucleus_candidates` over logits of `shape` `[..., V]` takes
+    its candidates by selection: where `approx_max_k` cuts the vocabulary to
+    candidates at all and the call scores `_PICK_ROWS` rows or more. Static
+    in the call's shape, as `sala._PICK_QUERIES` is."""
+    V = shape[-1]
+    rows = int(np.prod(shape[:-1]))
+    return bool(approx_top_k and 0 < top_k < V and rows >= _PICK_ROWS)
+
+
 def _nucleus_candidates(logits, top_p, top_k, approx_top_k):
     """(top_logits, top_idx, keep): the top-k candidate set plus the
     exclusive-cum nucleus keep rule over TRUE probabilities (full-vocab
@@ -283,11 +306,25 @@ def _nucleus_candidates(logits, top_p, top_k, approx_top_k):
     k = min(top_k, logits.shape[-1])
     if approx_top_k and k < logits.shape[-1]:
         # hardware-native approximate top-k (exact lax.top_k is a full-vocab
-        # sort on TPU); aggregate_to_topk (default) already returns the
-        # candidates exactly sorted descending
-        top_logits, top_idx = jax.lax.approx_max_k(
-            logits, k, recall_target=0.99
-        )
+        # sort on TPU): a partial reduce to C candidates with their
+        # vocabulary indices, and the k best of those in descending order
+        if sample_picks(logits.shape, top_k, approx_top_k):
+            # ... picked exactly out of the candidates as they are
+            cand, cand_idx = jax.lax.approx_max_k(
+                logits, k, recall_target=0.99, aggregate_to_topk=False)
+            # (row-major, both: from 128 rows on the compiler lays the pick's
+            # arrays out rows-minor and, unpinned, relaid the LOGITS to match)
+            row_major = Layout(major_to_minor=tuple(range(cand.ndim)))
+            cand, cand_idx = (with_layout_constraint(c, row_major)
+                              for c in (cand, cand_idx))
+            top_logits, pos = top_k_select(cand, k)
+            top_idx = take_at(cand_idx, pos)
+        else:
+            # ... by XLA's aggregation (`aggregate_to_topk`, the default),
+            # which sorts all C
+            top_logits, top_idx = jax.lax.approx_max_k(
+                logits, k, recall_target=0.99
+            )
     else:
         top_logits, top_idx = jax.lax.top_k(logits, k)  # descending
     lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
@@ -481,6 +518,20 @@ def kv_in_place(config, sampling: SamplingParams, rows: int) -> int:
     return int(_monolithic(sampling, rows)
                and _loop_page_size(config, sampling.page_size) > 0
                and use_paged_decode_kernel(config))
+
+
+def sample_pick(config, sampling: SamplingParams, rows: int) -> int:
+    """1 where a decode step of this `generate` call of `rows` rows in all
+    takes its sampler's candidates by selection (`sample_picks` at the rows
+    the step scores: the resident ones of the queued loop, every candidate
+    position of the speculative one), else 0: the trainer's static
+    `rollout/sample_pick`."""
+    if sampling.greedy or sampling.top_p >= 1.0:
+        return 0
+    if _queued(sampling, rows):
+        rows = sampling.decode_rows
+    return int(sample_picks((rows * (sampling.spec_k + 1), config.vocab_size),
+                            sampling.top_k, sampling.approx_top_k))
 
 
 def _read_loops(config, Tp, max_tokens, page_size=0):

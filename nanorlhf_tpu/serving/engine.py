@@ -763,6 +763,10 @@ class ServingEngine:
             # (docs/PAGED_CACHE.md "The rows a step scores")
             "serving/sample_rows": self._sess.sample_rows,
             "serving/sample_slots": self._sess.sample_slots,
+            # static, a tuple (no Prometheus series): the sizes among them
+            # whose sampler takes its candidates by selection
+            # (`sampler._PICK_ROWS`)
+            "serving/sample_pick_sizes": self._sess.sample_pick_sizes,
             "pages/shared": snap["shared_pages"],
         }
         if self.block_length:

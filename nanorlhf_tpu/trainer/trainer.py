@@ -77,7 +77,9 @@ from nanorlhf_tpu.ops.masking import (
 from nanorlhf_tpu.parallel.mesh import (MeshConfig, batch_sharding, make_mesh,
                                         shard_params)
 from nanorlhf_tpu.sampler import SamplingParams, compose_check, generate
-from nanorlhf_tpu.sampler.sampler import attn_read_frac, kv_in_place
+from nanorlhf_tpu.sampler.sampler import (
+    attn_read_frac, kv_in_place, sample_pick,
+)
 from nanorlhf_tpu.telemetry import (DEFAULT_RULES, HealthConfig,
                                     HealthMonitor, LatencyHub,
                                     LineageLedger, SLO_RULES, SpanTracer,
@@ -2301,14 +2303,17 @@ class RLTrainer:
         envp = up.envp = ro.get("env")
         if envp is None:
             # how far the rollout's decode read was bounded: every row is
-            # on the host here, before a selection cuts any; and whether
-            # the loop kept its cache in pages that it read in place
+            # on the host here, before a selection cuts any; whether the
+            # loop kept its cache in pages that it read in place; and
+            # whether its sampler took its candidates by selection
             up.extra_metrics["rollout/attn_read_frac"] = attn_read_frac(
                 self._rollout_mcfg, run.sampling, up.context_length,
                 up.responses, tok.eos_token_id,
                 prompt_lens=(np.asarray(up.queries)
                              != tok.pad_token_id).sum(axis=1))
             up.extra_metrics["rollout/kv_in_place"] = kv_in_place(
+                self._rollout_mcfg, run.sampling, up.responses.shape[0])
+            up.extra_metrics["rollout/sample_pick"] = sample_pick(
                 self._rollout_mcfg, run.sampling, up.responses.shape[0])
         with self.timer.phase("reward"):
             if envp is not None:

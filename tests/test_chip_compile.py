@@ -372,6 +372,13 @@ def test_decode_loop_carries_the_cache_in_place_on_v5e(
                   for _, result, op, _ in instrs if op.startswith("copy")
                   and _shapes(result)[:1] == list(hlo_stacks(cache))]
         assert not copies, "\n".join(copies)
+        # ISSUE 60: 64 rows take their 64 candidates of `approx_max_k`'s
+        # 9,600 by selection, so the program sorts nothing wider than 64
+        # (the aggregation's `sort f32[64,9600]` was 0.39 ms a step)
+        sorted_ = [_shapes(result) for instrs in comps.values()
+                   for _, result, op, _ in instrs if op == "sort"]
+        assert sorted_ and all(d <= 64 for shapes in sorted_
+                               for _, dims in shapes for d in dims), sorted_
     if case == "rollout_xla":
         # ISSUE 33: three decode loops, one an extent of the cache read; each
         # loop's QK fusion reads its own extent of the stack in place (no
@@ -1314,6 +1321,14 @@ def test_sdar_session_programs_fit_the_chip_in_place_on_v5e(
              if re.match(r"\s*%attn\.[\w.]* = \S+ custom-call\(", line)]
     if case == "block_chunk":
         assert calls and all(c.startswith("%attn.block") for c in calls), calls
+        # ISSUE 60: no branch of the sampler relays the logits (its pick's
+        # arrays lie rows-minor from 128 rows on; the candidates are pinned
+        # row-major so that the 78 MB of logits are not)
+        relaid = [f"{name}: {result}" for name, instrs in comps.items()
+                  for _, result, op, _ in instrs if op.startswith("copy")
+                  and any(dims[-1:] == (cfg.vocab_size,)
+                          for _, dims in _shapes(result))]
+        assert not relaid, "\n".join(relaid)
     else:       # the piece's read is XLA's walk of the key blocks
         assert not calls
     assert not re.findall(r"bf16\[\d+,4,3200,128\]", hlo)

@@ -191,6 +191,9 @@ def test_golden_rows_and_parameters_reproduce(name, tmp_path_factory):
         # ... and one that says the loop kept no pages (ISSUE 52: off the
         # TPU its cache stays contiguous)
         assert have.pop("rollout/kv_in_place", 0) == 0
+        # ... and one that says which pick the sampler took (ISSUE 60:
+        # static in the rollout's rows; the same tokens either way)
+        assert have.pop("rollout/sample_pick", 0) in (0, 1)
         if name.startswith("sparse"):
             # every key the parent wrote, with its value; the keys the
             # shared phases add are counted in the test below

@@ -80,6 +80,8 @@ def test_reinforce_smoke(tmp_path):
         1.0, 1.0]
     # ... and on the CPU its cache is contiguous (ISSUE 52)
     assert [r["rollout/kv_in_place"] for r in rows if "episode" in r] == [0, 0]
+    # ... and 16 rows sample their candidates by selection (ISSUE 60)
+    assert [r["rollout/sample_pick"] for r in rows if "episode" in r] == [1, 1]
     assert (tmp_path / "reinforce" / "checkpoint-2").exists()
 
 
