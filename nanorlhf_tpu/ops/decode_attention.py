@@ -30,6 +30,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -601,6 +602,35 @@ def paged_pages_per_item(pool) -> int:
                       _PAGED_ITEM_BYTES // (KV * P * hd * pool.dtype.itemsize)))
 
 
+def paged_item_counts(pages, pages_per_item: int) -> tuple:
+    """`(items, short items)` of `paged_decode_plan`'s work list, counted
+    on the HOST for rows that read `pages` blocks each (an integer array of
+    any shape; 0: a row without work): a row's blocks cut into items of
+    `pages_per_item`, its last one short where they do not fill it."""
+    pages = np.asarray(pages)
+    return (int((-(-pages // pages_per_item)).sum()),
+            int((pages % pages_per_item != 0).sum()))
+
+
+# items one step of the kernel's loop folds where an item is small
+_PAGED_STEP_ITEMS = 4
+
+
+def _paged_items_per_step(pool, pages_per_item: int) -> int:
+    """Work items one step of `_paged_decode_kernel`'s loop folds, from the
+    pool's geometry. An item's fold is a chain of dependent steps (scores,
+    max, exp, sum, PV) whose latency hardly follows its width, and alone in
+    its step it runs beside nothing: the copies' issue and waits sit in
+    branches around it. An item of 1 MB of K and V hides that under its
+    copies; an item of half that or less (Qwen2.5's 2 KV heads) does not
+    when it is short, so such items are folded several a step, their chains
+    in one block where the compiler runs them side by side
+    (docs/PAGED_CACHE.md "The read's cost" has the chip's numbers)."""
+    _, _, KV, P, hd = pool.shape
+    item = pages_per_item * KV * P * hd * pool.dtype.itemsize
+    return _PAGED_STEP_ITEMS if 2 * item <= _PAGED_ITEM_BYTES else 1
+
+
 def paged_decode_plan(table, start, filled, *, page_size: int, num_pages: int,
                       pages_per_item: int, live=None) -> PagedDecodePlan:
     """Work list of `paged_decode_attention` for one decode step. A row
@@ -663,16 +693,21 @@ def _paged_item_fold(q, k, v, valid, state, scale: float):
 
 def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
                          start_ref, filled_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, sem, *, scale: float, n_rows: int):
-    """One tile of rows: walk the tile's work items. An item is up to C
-    consecutive pages of one row, each page one DMA for ALL kv heads
-    ([KV, P, hd] is contiguous in the pool), fetched from the stack in HBM
-    into a ring of buffer slots, as many items ahead as there are slots to
-    spare, while the items before it are folded (`_paged_item_fold`); the
-    online softmax's state of the row in hand rides the loop."""
+                         kbuf, vbuf, sem, *, scale: float, n_rows: int,
+                         step_items: int):
+    """One tile of rows: walk the tile's work items, `step_items` a step of
+    the loop. An item is up to C consecutive pages of one row, each page one
+    DMA for ALL kv heads ([KV, P, hd] is contiguous in the pool), fetched
+    from the stack in HBM into a ring of buffer slots, as many steps' items
+    ahead as there are slots to spare, while the items before them are
+    folded (`_paged_item_fold`); the online softmax's state of the row in
+    hand rides the loop. A step's folds stand in ONE block, no branch
+    between them, so that one item's chain runs beside the next one's; the
+    rows that end in the step are written after it."""
     tile_rows, KV, Gp, _ = q_ref.shape
     slots, _, C, P, hd = kbuf.shape
-    ahead = slots - 1
+    U = step_items
+    ahead = slots // U - 1              # steps whose copies are in flight
     num_pages, nb = k_hbm.shape[1], table_ref.shape[1]
     r0 = pl.program_id(0) * tile_rows
     lo = off_ref[r0]
@@ -693,6 +728,13 @@ def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
                         pool.at[layer, page], buf.at[slot, :, c],
                         sem.at[j, slot]))
 
+    def step_pages(first, act):
+        """The same for the items of the step that starts at item `first`."""
+        for u in range(U):
+            @pl.when(first + u < hi)
+            def _item():
+                pages(first + u, act)
+
     # rows without items (dead, released, padding) read zero; a page an item
     # does not fetch is masked, so what the V buffer holds there must be finite
     o_ref[...] = jnp.zeros_like(o_ref)
@@ -700,39 +742,38 @@ def _paged_decode_kernel(layer_ref, off_ref, row_ref, blk_ref, table_ref,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     for d in range(ahead):
-        @pl.when(lo + d < hi)
-        def _first_fetch():
-            pages(lo + d, lambda copy: copy.start())
+        step_pages(lo + d * U, lambda copy: copy.start())
 
-    def item(i, state):
-        # before the wait for item i, into the slot item i - 1 has left
-        @pl.when(i + ahead < hi)
-        def _next_fetch():
-            pages(i + ahead, lambda copy: copy.start())
-
-        row, blk, slot = row_ref[i], blk_ref[i], i % slots
-        r = row - r0
-        start, filled = start_ref[row], filled_ref[row]
-        # a row's first item starts its softmax anew
-        first = blk == start // P
-        state = tuple(jnp.where(first, fresh, x) for fresh, x in
-                      zip((NEG_INF, 0.0, 0.0), state))
-
-        pages(i, lambda copy: copy.wait())
-        pos = blk * P + jax.lax.broadcasted_iota(jnp.int32, (Gp, C * P), 1)
-        state = _paged_item_fold(
-            q_ref[r], kbuf[slot].reshape(KV, C * P, hd),
-            vbuf[slot].reshape(KV, C * P, hd),
-            (pos >= start) & (pos < filled), state, scale)
-
-        @pl.when(blk + C > (filled - 1) // P)
-        def _finalize():
-            _, l, acc = state
-            o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    def step(j, state):
+        first = lo + j * U
+        # before the wait for this step's items, into the slots the step
+        # before has left
+        step_pages(first + ahead * U, lambda copy: copy.start())
+        step_pages(first, lambda copy: copy.wait())
+        ended = []
+        for u in range(U):
+            # past the tile's last item: that item again, written nowhere
+            i = jnp.minimum(first + u, hi - 1)
+            row, blk, slot = row_ref[i], blk_ref[i], i % slots
+            start, filled = start_ref[row], filled_ref[row]
+            # a row's first item starts its softmax anew
+            state = tuple(jnp.where(blk == start // P, fresh, x)
+                          for fresh, x in zip((NEG_INF, 0.0, 0.0), state))
+            pos = blk * P + jax.lax.broadcasted_iota(jnp.int32, (Gp, C * P), 1)
+            state = _paged_item_fold(
+                q_ref[row - r0], kbuf[slot].reshape(KV, C * P, hd),
+                vbuf[slot].reshape(KV, C * P, hd),
+                (pos >= start) & (pos < filled), state, scale)
+            ended.append((row - r0, state, (first + u < hi)
+                          & (blk + C > (filled - 1) // P)))
+        for r, (_, l, acc), last in ended:
+            @pl.when(last)
+            def _finalize():
+                o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
         return state
 
-    jax.lax.fori_loop(lo, hi, item, (
+    jax.lax.fori_loop(0, pl.cdiv(hi - lo, U), step, (
         jnp.full((KV, Gp, 1), NEG_INF, jnp.float32),
         jnp.zeros((KV, Gp, 1), jnp.float32),
         jnp.zeros((KV, Gp, hd), jnp.float32)))
@@ -771,8 +812,12 @@ def paged_decode_attention(
 
     qg = jnp.pad(q.reshape(B, KV, G, hd),
                  [(0, n_tiles * tile_rows - B), (0, 0), (0, Gp - G), (0, 0)])
+    U = _paged_items_per_step(k_pool, C)
+    # two steps of one item ahead (at items of 0.5 MB one item ahead left
+    # the copies exposed), one step of several
+    slots = 3 if U == 1 else 2 * U
     kernel = functools.partial(_paged_decode_kernel, scale=1.0 / (hd ** 0.5),
-                               n_rows=B)
+                               n_rows=B, step_items=U)
     rows_spec = pl.BlockSpec((tile_rows, KV, Gp, hd),
                              lambda t, *_: (t, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -781,12 +826,10 @@ def paged_decode_attention(
         in_specs=[rows_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows_spec,
-        # three buffer slots: the copies of two items run under the third's
-        # fold (at items of 0.5 MB one item ahead left the copies exposed)
         scratch_shapes=[
-            pltpu.VMEM((3, KV, C, P, hd), k_pool.dtype),
-            pltpu.VMEM((3, KV, C, P, hd), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.VMEM((slots, KV, C, P, hd), k_pool.dtype),
+            pltpu.VMEM((slots, KV, C, P, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, slots)),
         ],
     )
     out = pl.pallas_call(

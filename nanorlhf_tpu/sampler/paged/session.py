@@ -82,6 +82,9 @@ from nanorlhf_tpu.core.model import (
     _logits, attention_form, block_forward, decode_step, decode_verify,
     leaves_in_place, paged_write_forms, prefill,
 )
+from nanorlhf_tpu.ops.decode_attention import (
+    paged_item_counts, paged_pages_per_item,
+)
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.sampler.paged.pages import (
     PageState, RingPages, alloc_row, blocks_per_row, full_table, release_row,
@@ -1202,6 +1205,16 @@ class DecodeSession:
             not self.spec and paged_read(
                 max(self.block, 1), decode=True) in ("paged_decode",
                                                      "paged_block"))
+        # ... whose work list cuts a row's pages into items of this many
+        # (`serving/paged_items`, `serving/paged_short_items`, counted where
+        # the pages are: `_count_attention`); 0 where no such list is made
+        # (another read, or a sparse model's own lists of chosen blocks)
+        self._item_pages = 0
+        if self.attn_in_place and not config.sparse_layers:
+            self._item_pages = paged_pages_per_item(
+                caches0[0][0] if patterned else caches0[0])
+        self.paged_items = 0
+        self.paged_short_items = 0
         # ... and, of the forwards dispatched for chunked admissions
         # (`_prefill_tick`: the pieces and each one's closing suffix
         # forward), whether their T > 1 paged read is the flash kernel over
@@ -1877,8 +1890,12 @@ class DecodeSession:
         for s in range(its):
             slot = self.Tp + self._row_gen_np + s - 1
             last = slot // self.page_size
-            self.attn_live_pages += int(
-                np.sum(np.where(steps > s, last - first + 1, 0)))
+            pages = np.where(steps > s, last - first + 1, 0)
+            self.attn_live_pages += int(pages.sum())
+            if self._item_pages:
+                items, short = paged_item_counts(pages, self._item_pages)
+                self.paged_items += items
+                self.paged_short_items += short
             span = np.where(steps > s, slot - self._row_start_np + 1, 0)
             self.live_row_steps += int((steps > s).sum())
             self.global_slots_read += int(span.sum())

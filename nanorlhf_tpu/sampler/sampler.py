@@ -42,6 +42,9 @@ from nanorlhf_tpu.core.model import (
     decode_loop_page_size, decode_read_extents, decode_step, init_kv_cache,
     init_paged_kv_cache, prefill, use_paged_decode_kernel,
 )
+from nanorlhf_tpu.ops.decode_attention import (
+    paged_item_counts, paged_pages_per_item,
+)
 from nanorlhf_tpu.ops.masking import guard_temperature
 from nanorlhf_tpu.ops.top_select import take_at, top_k_select
 from nanorlhf_tpu.sampler.paged.pages import full_table
@@ -553,26 +556,43 @@ def _read_loops(config, Tp, max_tokens, page_size=0):
     return loops
 
 
+def _decode_steps(sampling, responses, eos_token_id: int) -> int:
+    """Decode steps the monolithic loop of one `generate` call ran, from its
+    [rows, max_tokens] result on the HOST: it ran until its longest row
+    ended, one step a token after the prefill's."""
+    ends = responses == eos_token_id
+    return int(np.where(ends.any(axis=1), ends.argmax(axis=1) + 1,
+                        responses.shape[1]).max()) - 1
+
+
+def _in_place_pages(config, sampling, prompt_width: int, steps: int,
+                    rows: int, prompt_lens):
+    """[rows, steps] pages the in-place read of the monolithic loop copies:
+    each row's blocks `[start // P, (filled - 1) // P]` from the end of its
+    left pad, `start = prompt_width - prompt_lens[row]`, to the step's slot;
+    `prompt_lens` is a real length a PROMPT (each N consecutive rows), and
+    every row counts at every step (the loop marks none dead)."""
+    P = _loop_page_size(config, sampling.page_size)
+    lens = np.asarray(prompt_lens)
+    first = (prompt_width - np.repeat(lens, rows // lens.shape[0])) // P
+    # step s writes slot Tp + s - 1, the last of its `filled`
+    last = (prompt_width + np.arange(steps)) // P
+    return last[None, :] - first[:, None] + 1
+
+
 def attn_read_frac(config, sampling, prompt_width: int, responses,
                    eos_token_id: int, prompt_lens=None) -> float:
     """Share of the cache the decode attention of one `generate` call read
     (the trainer's `rollout/attn_read_frac`), summed over its decode steps.
     Over the contiguous cache: the step's extent over the `T_max` slots.
-    Over pages read in place (`kv_in_place`): the pages the kernel copies,
-    each row's blocks `[start // P, (filled - 1) // P]` from the end of its
-    left pad, `start = prompt_width - prompt_lens[row]`, to the step's
-    slot, over the table's `rows x ceil(T_max / P)`; `prompt_lens` is a real
-    length a PROMPT (each N consecutive rows), and every row counts at every
-    step (the loop marks none dead). 1.0 wherever the loop names no bound: a
-    cache of one block, the queued and speculative loops, a
-    paged cache read as the gathered view, and a call that took no step.
-    `responses` is the call's [rows, max_tokens] result on the HOST: the
-    loop ran until its longest row ended, one step a token after the
-    prefill's, so the static layout, the prompts' lengths and that length
-    say it all: no device read."""
-    ends = responses == eos_token_id
-    steps = int(np.where(ends.any(axis=1), ends.argmax(axis=1) + 1,
-                         responses.shape[1]).max()) - 1
+    Over pages read in place (`kv_in_place`): the pages the kernel copies
+    (`_in_place_pages`) over the table's `rows x ceil(T_max / P)`. 1.0
+    wherever the loop names no bound: a cache of one block, the queued and
+    speculative loops, a paged cache read as the gathered view, and a call
+    that took no step. `responses` is the call's [rows, max_tokens] result
+    on the HOST: the static layout, the prompts' lengths and the longest
+    row's length say it all: no device read."""
+    steps = _decode_steps(sampling, responses, eos_token_id)
     rows = responses.shape[0]
     if not _monolithic(sampling, rows) or steps <= 0:
         return 1.0
@@ -581,11 +601,8 @@ def attn_read_frac(config, sampling, prompt_width: int, responses,
     if P > 0:
         if not use_paged_decode_kernel(config):     # the gathered view
             return 1.0
-        lens = np.asarray(prompt_lens)
-        first = (prompt_width - np.repeat(lens, rows // lens.shape[0])) // P
-        # step s writes slot Tp + s - 1, the last of its `filled`
-        last = (prompt_width + np.arange(steps)) // P
-        read = int((last[None, :] - first[:, None] + 1).sum())
+        read = int(_in_place_pages(config, sampling, prompt_width, steps,
+                                   rows, prompt_lens).sum())
         return read / (steps * rows * -(-T_max // P))
     read, start = 0, 1
     for extent, stop in _read_loops(config, prompt_width,
@@ -593,6 +610,30 @@ def attn_read_frac(config, sampling, prompt_width: int, responses,
         read += max(0, min(stop, steps + 1) - start) * extent
         start = stop
     return read / (steps * T_max)
+
+
+def paged_read_items(config, sampling, prompt_width: int, responses,
+                     eos_token_id: int, prompt_lens, cache_dtype):
+    """`(items, short items)` of the in-place read's work lists over one
+    `generate` call's decode steps, a layer (the trainer's
+    `rollout/paged_items`, `rollout/paged_short_items`), or None where the
+    loop does not read its pages in place (`kv_in_place` 0) or took no step.
+    An item is up to `paged_pages_per_item` pages of one row; a short one
+    holds fewer, and costs the kernel's loop what a whole one costs unless
+    it shares its step (docs/PAGED_CACHE.md "The read's cost"). Counted on
+    the host like `attn_read_frac`; `cache_dtype` is the cache's, the
+    parameters'."""
+    steps = _decode_steps(sampling, responses, eos_token_id)
+    rows = responses.shape[0]
+    if not kv_in_place(config, sampling, rows) or steps <= 0:
+        return None
+    pages = _in_place_pages(config, sampling, prompt_width, steps, rows,
+                            prompt_lens)
+    C = paged_pages_per_item(jax.ShapeDtypeStruct(
+        (1, 1, config.num_key_value_heads,
+         _loop_page_size(config, sampling.page_size), config.actual_head_dim),
+        cache_dtype))
+    return paged_item_counts(pages, C)
 
 
 @jax.named_scope("prefill")

@@ -703,6 +703,8 @@ class ServingEngine:
             "serving/attn_live_pages": self._sess.attn_live_pages,
             "serving/attn_table_pages": self._sess.attn_table_pages,
             "serving/attn_in_place": self._sess.attn_in_place,
+            "serving/paged_items": self._sess.paged_items,
+            "serving/paged_short_items": self._sess.paged_short_items,
             "serving/prefill_pieces": self._sess.prefill_pieces,
             "serving/prefill_read_in_place": self._sess.prefill_read_in_place,
             "serving/kv_write_by_page": self._sess.kv_write_by_page,

@@ -78,7 +78,7 @@ from nanorlhf_tpu.parallel.mesh import (MeshConfig, batch_sharding, make_mesh,
                                         shard_params)
 from nanorlhf_tpu.sampler import SamplingParams, compose_check, generate
 from nanorlhf_tpu.sampler.sampler import (
-    attn_read_frac, kv_in_place, sample_pick,
+    attn_read_frac, kv_in_place, paged_read_items, sample_pick,
 )
 from nanorlhf_tpu.telemetry import (DEFAULT_RULES, HealthConfig,
                                     HealthMonitor, LatencyHub,
@@ -2304,15 +2304,24 @@ class RLTrainer:
         if envp is None:
             # how far the rollout's decode read was bounded: every row is
             # on the host here, before a selection cuts any; whether the
-            # loop kept its cache in pages that it read in place; and
-            # whether its sampler took its candidates by selection
+            # loop kept its cache in pages that it read in place and, where
+            # it did, the work items that read was cut into and those that
+            # held less than an item's pages; and whether its sampler took
+            # its candidates by selection
+            prompt_lens = (np.asarray(up.queries)
+                           != tok.pad_token_id).sum(axis=1)
             up.extra_metrics["rollout/attn_read_frac"] = attn_read_frac(
                 self._rollout_mcfg, run.sampling, up.context_length,
-                up.responses, tok.eos_token_id,
-                prompt_lens=(np.asarray(up.queries)
-                             != tok.pad_token_id).sum(axis=1))
+                up.responses, tok.eos_token_id, prompt_lens=prompt_lens)
             up.extra_metrics["rollout/kv_in_place"] = kv_in_place(
                 self._rollout_mcfg, run.sampling, up.responses.shape[0])
+            items = paged_read_items(
+                self._rollout_mcfg, run.sampling, up.context_length,
+                up.responses, tok.eos_token_id, prompt_lens,
+                self.params["embed_tokens"].dtype)
+            if items is not None:
+                (up.extra_metrics["rollout/paged_items"],
+                 up.extra_metrics["rollout/paged_short_items"]) = items
             up.extra_metrics["rollout/sample_pick"] = sample_pick(
                 self._rollout_mcfg, run.sampling, up.responses.shape[0])
         with self.timer.phase("reward"):

@@ -181,6 +181,10 @@ def test_paged_decode_kernel_matches_oracle(rng, monkeypatch, item_pages,
     )
 
     monkeypatch.setattr(decode_attention, "_PAGED_ITEM_PAGES", item_pages)
+    # one item a step of the kernel's loop, what every pool but
+    # Qwen2.5-1.5B's gets on the chip (at these sizes every item is small);
+    # several a step are tests/test_decode_attention.py's (ISSUE 61)
+    monkeypatch.setattr(decode_attention, "_PAGED_STEP_ITEMS", 1)
     sub = 32 // jnp.dtype(dtype).itemsize
     row_bytes = KV * sub * -(-G // sub) * 16 * jnp.dtype(dtype).itemsize
     monkeypatch.setattr(decode_attention, "_PAGED_TILE_BYTES",
@@ -501,6 +505,15 @@ def test_the_loops_own_pages_emit_the_contiguous_loops_tokens(wide, case):
     frac = S.attn_read_frac(pages, sampling, Tp, got, -1, prompt_lens=lens)
     assert frac == pytest.approx(by_hand / (129 * 3 * n * 3))
     assert S.kv_in_place(pages, sampling, 3 * n) == 1
+    # ... cut into work items (ISSUE 61): float32 pages of 2 KV heads go
+    # four an item, of 4 heads two; under four pages every item is short,
+    # under two the items of one and three pages are
+    items = S.paged_read_items(pages, sampling, Tp, got, -1, lens, jnp.float32)
+    assert items == {2: (n * 129 * 3, n * 129 * 3),
+                     4: (n * (129 * 3 + 33), n * (33 + 2 * 96))}[
+        pages.num_key_value_heads]
+    assert S.paged_read_items(plain, sampling, Tp, got, -1, lens,
+                              jnp.float32) is None
     # the contiguous loop's counter goes by its extents, and says so
     assert S.kv_in_place(plain, sampling, 3 * n) == 0
     assert S.attn_read_frac(plain, sampling, Tp, got, -1, prompt_lens=lens
@@ -536,10 +549,16 @@ def test_a_row_that_ends_early_stops_the_count_of_pages(wide):
     assert S.attn_read_frac(pages, sampling, 256, responses, EOS,
                             prompt_lens=lens) == pytest.approx(
         by_hand / (99 * 4 * 5))
+    # two and three pages a row a step: one short item each, of four pages
+    assert S.paged_read_items(pages, sampling, 256, responses, EOS, lens,
+                              jnp.float32) == (99 * 4, 99 * 4)
     view = dataclasses.replace(config, attention_impl="xla")
     assert S.attn_read_frac(
         view, dataclasses.replace(sampling, page_size=16), 256, responses,
         EOS, prompt_lens=lens) == 1.0
+    assert S.paged_read_items(
+        view, dataclasses.replace(sampling, page_size=16), 256, responses,
+        EOS, lens, jnp.float32) is None
     # an explicit page size under the kernel's rule is read in place too
     own = dataclasses.replace(sampling, page_size=64)
     assert S.kv_in_place(pages, own, 4) == 1

@@ -581,6 +581,47 @@ def test_the_kernel_reads_the_plans_runs(params):
     assert float(jnp.abs(got[2:]).max()) == 0.0
 
 
+def test_a_dense_rows_sparse_read_is_the_dense_kernels_bit_for_bit():
+    """Rows that do not select read `[start, filled)` in items of four pages
+    from `start // P`, as `ops/decode_attention.paged_decode_attention`
+    does, through the same `_paged_item_fold` (a KV head at a time here, all
+    at once there): the same bits, before and after the dense kernel's loop
+    took four items a step (ISSUE 61 left this kernel and the fold as they
+    were); a one-page item alone, whole items and the short ones after them,
+    a row not live and a released row."""
+    from nanorlhf_tpu.ops import decode_attention as dec
+    from nanorlhf_tpu.ops import sparse_attention as sa
+
+    cfg = dataclasses.replace(CFG, head_dim=128, sparse_topk=4)
+    B, KV, G, hd, P, nb = 5, 2, 2, 128, 16, 12
+    N = B * nb
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (B, KV * G, hd), jnp.float32)
+    k_pool, v_pool = (jax.random.normal(key, (2, N, KV, P, hd), jnp.float32)
+                      for key in keys[1:])
+    table = jnp.arange(N, dtype=jnp.int32).reshape(B, nb).at[4].set(N)
+    start = jnp.asarray([5, 40, 3, 0, 0], jnp.int32)
+    filled = jnp.asarray([5 + 9, 40 + 60, 3 + 90, 100, 50], jnp.int32)
+    live = jnp.asarray([True, True, True, False, True])
+    idx = jnp.zeros((B, KV, 4), jnp.int32)
+    plan = sa.sparse_decode_plan(
+        cfg, idx, jnp.ones((B, KV, 4), bool), start, filled,
+        jnp.zeros((B,), bool), live, table, page_size=P, num_pages=N)
+    # pages of 16: one page; five, a whole item and one page; six
+    assert np.diff(np.asarray(plan.pair_off)).tolist() == [
+        1, 1, 2, 2, 2, 2, 0, 0, 0, 0]
+    sparse = sa.sparse_paged_decode_attention(q, k_pool, v_pool, 1, plan,
+                                              interpret=True)
+    assert dec.paged_pages_per_item(k_pool) == sa._ITEM_PAGES
+    assert dec._paged_items_per_step(k_pool, sa._ITEM_PAGES) == 4
+    dense = dec.paged_decode_attention(
+        q, k_pool, v_pool, jnp.int32(1), dec.paged_decode_plan(
+            table, start, filled, page_size=P, num_pages=N,
+            pages_per_item=sa._ITEM_PAGES, live=live), interpret=True)
+    np.testing.assert_array_equal(np.asarray(sparse), np.asarray(dense))
+    assert float(jnp.abs(sparse[:3]).min()) > 0 and not sparse[3:].any()
+
+
 # ------------------------------------------- the selection's list of rows
 
 def _all_rows_selection(config, q, kc_stack, layer, view, t, need):

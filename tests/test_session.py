@@ -349,6 +349,12 @@ def test_in_place_read_serves_the_gathered_views_tokens(tiny):
         assert sess.attn_live_pages == (4 * 3 + 3 * 4) + 2 * 2 + 3 * 4 == 40
         assert sess.attn_table_pages == 7 * 3 * 5
     assert (s_view.attn_in_place, s_in_place.attn_in_place) == (0, 1)
+    # ... cut into work items of four pages (ISSUE 61): a step a row is one
+    # item here, short wherever the row reads two or three blocks; the view
+    # has no items
+    assert (s_in_place.paged_items, s_in_place.paged_short_items) == (
+        7 + 2 + 3, 4 + 2)
+    assert (s_view.paged_items, s_view.paged_short_items) == (0, 0)
 
 
 # --------------------------------------------------------------------- #

@@ -34,7 +34,8 @@ TIMELINE_KEYS = ["serving/queue_wait_s_sum", "serving/queue_wait_s_count",
                  "serving/first_token_lag_s_count"]
 # what the paged decode read touches (ISSUE 28), counted by the session
 ATTENTION_KEYS = ["serving/attn_live_pages", "serving/attn_table_pages",
-                  "serving/attn_in_place"]
+                  "serving/attn_in_place", "serving/paged_items",
+                  "serving/paged_short_items"]
 # the request's decode account (ISSUE 51): the session's beats by kind, the
 # way out of a request's last token, and finished requests reduced, all and
 # the slow tenth

@@ -19,8 +19,11 @@ the slots inside the rows' bounds at the chip's bandwidth
 (benchmark/harness/peaks.json) over `call_us - empty_us`. The `rollout` cases
 also time XLA's masked read of a contiguous cache cut to the extent that
 step reads there (`xla_extent_us`: what the one-jit rollout runs wherever
-its cache is not paged, `core/model.decode_read_extents`). One JSON line a
-case on stdout, all of them in `chiprun_out/paged_read/`.
+its cache is not paged, `core/model.decode_read_extents`). The `.held<h>`
+cases give every live row one item of `h` pages, so their `us_item` is an
+item's cost by the pages it holds; `step_items` is the items a step of the
+kernel's loop folds at the case's geometry. One JSON line a case on stdout,
+all of them in `chiprun_out/paged_read/`.
 """
 import contextlib
 import json
@@ -84,6 +87,11 @@ CASES = [
     *[("rollout." + at, 28, 384, 2, 6, 64, 6, 64, 6) for at in ROLLOUT_STEPS],
     *[("olmoe.rollout." + at, 16, 384, 16, 1, 64, 6, 64, 6)
       for at in ROLLOUT_STEPS],
+    # an item's cost by the pages it holds (ISSUE 61): every live row ONE
+    # item of 1..4 pages, at the rollout's geometry (four items a step of the
+    # kernel's loop) and at LFM2's (items of 1 MB, one a step)
+    *[("rollout.held%d" % h, 28, 384, 2, 6, 64, 6, 64, h) for h in (1, 2, 3, 4)],
+    *[("lfm2.held%d" % h, 2, 2560, 4, 4, 64, 40, 40, h) for h in (1, 2, 3, 4)],
 ]
 
 
@@ -207,8 +215,9 @@ def case(spec):
     floor_us = slots * KV * HD * 2 * 2 / hbm_bytes_per_s() * 1e6
     C = dec.paged_pages_per_item(c["k_pool"])
     row = {"case": name, "L": L, "N": N, "KV": KV, "G": G, "B": B,
-           "live": live, "pages": pages, "item_pages": C, "items": items,
-           "slots": slots, "floor_us": floor_us}
+           "live": live, "pages": pages, "item_pages": C,
+           "step_items": dec._paged_items_per_step(c["k_pool"], C),
+           "items": items, "slots": slots, "floor_us": floor_us}
     row.update(check(c, plan))
     row["call_us"] = time_us(read, c, plan)
     row["empty_us"] = time_us(read, c, plan_of(c, jnp.zeros_like(c["live"])))
